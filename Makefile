@@ -29,7 +29,7 @@ lint:
 		$(PY) -m ruff check magiattention_tpu tests exps examples; \
 	else \
 		echo "ruff not installed; syntax-checking via compileall"; \
-		$(PY) -m compileall -q magiattention_tpu tests exps examples bench.py __graft_entry__.py; \
+		$(PY) -m compileall -q magiattention_tpu tests exps examples bench.py chip_smoke.py __graft_entry__.py; \
 	fi
 
 typecheck:

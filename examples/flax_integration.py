@@ -8,7 +8,11 @@ function with the standard (q, k, v) -> out signature that internally
 routes through the framework, fetching the runtime key out-of-band so the
 module graph does not need to thread it.
 
-Run (CPU mesh simulation):  python examples/flax_integration.py
+Runs on whatever ``jax.devices()`` offers and needs four of them; as a
+CPU mesh simulation:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        python examples/flax_integration.py
 """
 
 import os
@@ -32,17 +36,13 @@ def magi_attention_forward(q, k, v):
 
 
 def main() -> None:
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""
-    ):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        ).strip()
-
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    if len(jax.devices()) < 4:
+        raise RuntimeError(
+            f"needs 4 devices; jax offers {len(jax.devices())} (see the "
+            "module docstring for the CPU simulation)"
+        )
 
     import flax.linen as nn
     import jax.numpy as jnp

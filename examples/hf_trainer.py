@@ -26,7 +26,10 @@ the TPU performance story (use ``magiattention_tpu/models`` for that).
 Use ``get_magi_trainer_cls()`` to subclass/override Trainer hooks;
 ``MagiTrainer(...)`` is a convenience constructor of that class.
 
-Run a 2-step smoke train:  python examples/hf_trainer.py
+Run a 2-step smoke train on the two first devices jax offers; as a CPU
+simulation:  JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=2 python
+examples/hf_trainer.py
 """
 
 from __future__ import annotations
@@ -217,7 +220,12 @@ def MagiTrainer(*args, **kwargs):
 def main() -> None:  # pragma: no cover - exercised by tests at small size
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            f"needs 2 devices; jax offers {len(jax.devices())} (CPU "
+            "simulation: JAX_PLATFORMS=cpu "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=2)"
+        )
     import numpy as np
     import torch
     from jax.sharding import Mesh

@@ -7,8 +7,11 @@ via adaLN. Objective: rectified-flow velocity matching with independent
 per-chunk t — the Magi-1 pipeline-denoising training shape (BASELINE
 config 5, scaled down).
 
-Run (CPU sim): python examples/train_dit.py
-Real devices:  MAGI_EXAMPLE_REAL_DEVICES=1 python examples/train_dit.py
+Runs on whatever ``jax.devices()`` offers (dp*cp of them):
+    python examples/train_dit.py --dp 1 --cp 1
+CPU simulation, the caller's choice:
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        python examples/train_dit.py
 """
 
 from __future__ import annotations
@@ -37,17 +40,15 @@ def main() -> None:
     )
     n_dev = args.dp * args.cp
 
-    if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""
-    ):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n_dev}"
-        ).strip()
     import jax
 
-    if os.environ.get("MAGI_EXAMPLE_REAL_DEVICES") != "1":
-        jax.config.update("jax_platforms", "cpu")
+    if len(jax.devices()) < n_dev:
+        raise RuntimeError(
+            f"--dp {args.dp} --cp {args.cp} needs {n_dev} devices; jax "
+            f"offers {len(jax.devices())} ({jax.devices()[0].platform}). "
+            "For a CPU simulation set JAX_PLATFORMS=cpu XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_dev}"
+        )
 
     import jax.numpy as jnp
     import numpy as np

@@ -6,8 +6,8 @@ docs/autotune.md) — per-workload selection happens through the cost
 model / measure-mode cache, not a static lookup. This harness re-derives
 the candidate table empirically: for each mask family and seqlen it
 times fwd and fwd+bwd across candidate rungs and prints the winners, so
-recalibrating after a kernel change is one command on a chip window (one
-TPU process at a time — see BENCH_CACHE.json provenance). Feed the
+recalibrating after a kernel change is one command on the chip (one
+TPU process at a time). Feed the
 results three ways:
 
 - update `_AUTO_BLOCK_CONFIGS` (candidates + preference order),
@@ -59,9 +59,7 @@ def main() -> None:
 
     from magiattention_tpu.benchmarking import do_bench, enable_compile_cache
 
-    enable_compile_cache(
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache")
-    )
+    enable_compile_cache()
     from magiattention_tpu.ops import flex_flash_attn_func
     from magiattention_tpu.ops.flex_attn import (
         _MAX_SMEM_ENTRIES,

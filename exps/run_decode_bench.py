@@ -92,12 +92,11 @@ def bench_one(
         )
         return out, cache
 
-    out, cache2 = step(q, cache)  # compile + warm
-    _ = float(jnp.sum(out.astype(jnp.float32)))
+    jax.block_until_ready(step(q, cache))  # compile + warm
     t0 = time.perf_counter()
     for _ in range(reps):
         out, _ = step(q, cache)
-    _ = float(jnp.sum(out.astype(jnp.float32)))  # sync
+    jax.block_until_ready(out)
     dt = (time.perf_counter() - t0) / reps
     kv_bytes = 2 * batch * kv_len * HK * D * jnp.dtype(dtype).itemsize
     return {

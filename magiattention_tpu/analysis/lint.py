@@ -475,9 +475,9 @@ def lint_package(
 
     All four rules run over ``magiattention_tpu/``; the
     ``extra_compat_roots`` (tests/exps/examples) are checked for MAGI001
-    only — a test spelling ``from jax import shard_map`` re-breaks
-    collection on old-jax images, which is exactly the class this linter
-    exists to pin down.
+    only — a test spelling ``from jax import shard_map`` breaks at
+    collection on the next rename, which is exactly the class this
+    linter exists to pin down.
     """
     violations = lint_paths(root, _python_files(root, _PACKAGE))
     for extra in extra_compat_roots:

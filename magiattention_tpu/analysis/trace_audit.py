@@ -53,12 +53,13 @@ class AuditFailure(AssertionError):
 
 
 def _sub_jaxprs(value) -> list:
-    import jax.core as jc
+    from ..utils.compat import jaxpr_types
 
+    jaxpr_t, closed_jaxpr_t = jaxpr_types()
     out = []
-    if isinstance(value, jc.Jaxpr):
+    if isinstance(value, jaxpr_t):
         out.append(value)
-    elif isinstance(value, jc.ClosedJaxpr):
+    elif isinstance(value, closed_jaxpr_t):
         out.append(value.jaxpr)
     elif isinstance(value, (tuple, list)):
         for v in value:

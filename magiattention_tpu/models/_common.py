@@ -25,14 +25,25 @@ def masked_ce_sums(logits, labels):
     )
 
 
+def _can_place(mesh) -> bool:
+    """Whether this process can put arrays on every device of the mesh:
+    they belong to a live backend (a described AOT topology's client
+    only compiles) and to this process."""
+    devs = list(mesh.devices.flat)
+    try:
+        client = jax.devices(devs[0].platform)[0].client
+    except RuntimeError:  # no live backend of that platform
+        return False
+    me = jax.process_index()
+    return all(d.client is client and d.process_index == me for d in devs)
+
+
 def sharded_plan_tables(plan, mesh, cp_axis):
     """The plan's device tables placed P(cp_axis) — or left as host
-    constants when the mesh has non-addressable devices (AOT-compilation
-    topologies), where placement is impossible and jit embeds them."""
+    constants where placement is impossible (AOT-compilation
+    topologies, other processes' devices) and jit embeds them."""
     tables = plan.device_tables()
-    if all(
-        d.process_index == jax.process_index() for d in mesh.devices.flat
-    ):
+    if _can_place(mesh):
         spec = NamedSharding(mesh, P(cp_axis_names(cp_axis)))
         return tuple(jax.device_put(t, spec) for t in tables)
     return tuple(tables)
@@ -181,14 +192,17 @@ def make_model_train_step(model, optimizer):
         return params, opt_state, loss
 
     return jax.jit(
-        step, donate_argnums=(0, 1), compiler_options=tpu_compiler_options()
+        step,
+        donate_argnums=(0, 1),
+        compiler_options=tpu_compiler_options(model.mesh),
     )
 
 
-def tpu_compiler_options():
-    """jit compiler options for the train step: async-a2a overlap on TPU
-    (docs/overlap.md), None elsewhere (the options are TPU-only)."""
-    if jax.default_backend() == "tpu":
+def tpu_compiler_options(mesh):
+    """jit compiler options for the train step: async-a2a overlap where
+    the mesh is of TPU devices (docs/overlap.md), None elsewhere (the
+    options are TPU-only)."""
+    if mesh.devices.flat[0].platform == "tpu":
         from ..env import recommended_compiler_options
 
         return recommended_compiler_options()

@@ -318,7 +318,9 @@ class MagiDiT:
         from ._common import tpu_compiler_options
 
         return jax.jit(
-            step, donate_argnums=(0, 1), compiler_options=tpu_compiler_options()
+            step,
+            donate_argnums=(0, 1),
+            compiler_options=tpu_compiler_options(self.mesh),
         )
 
     def make_forward(self):

@@ -185,7 +185,7 @@ def _roll_p2p(x, meta, src_slot, axis, mesh, cp_axis):
     rem = np.flatnonzero(~local)
     if rem.size == 0:
         # pure permutation within ranks (e.g. shift=0): no comm at all
-        return _shard_roll_try(
+        return _shard_roll_apply(
             x, axis, mesh, names,
             local_src.reshape(cp, shard), None, None, None, shard,
         )
@@ -220,24 +220,10 @@ def _roll_p2p(x, meta, src_slot, axis, mesh, cp_axis):
     recv_valid = np.zeros((cp, shard), dtype=bool)
     recv_valid[d_r, rem % shard] = True
 
-    return _shard_roll_try(
+    return _shard_roll_apply(
         x, axis, mesh, names,
         local_src.reshape(cp, shard), send_idx, recv_sel, recv_valid, shard,
     )
-
-
-def _shard_roll_try(x, axis, mesh, names, *args):
-    """Run the shard_map roll, or return None (-> caller's gather
-    fallback) where the partial-manual program cannot be built — old-jax
-    images whose SPMD partitioner aborts on manual subgroups (the compat
-    shim refuses up front with exactly this exception; any OTHER error
-    from building/tracing the roll body still propagates)."""
-    from ..utils.compat import ShardMapUnsupported
-
-    try:
-        return _shard_roll_apply(x, axis, mesh, names, *args)
-    except ShardMapUnsupported:
-        return None
 
 
 def _shard_roll_apply(

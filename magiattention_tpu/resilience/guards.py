@@ -213,7 +213,7 @@ def consume_error_code(code, sites, *, mode: str | None = None) -> None:
             )
         except Exception:  # noqa: BLE001 — reporting must never take
             # the traced program down (e.g. callbacks unsupported in
-            # this tracing context on old jax); detection still
+            # this tracing context); detection still
             # happened, repair still applied — only the report is lost
             from ..telemetry.logger import get_logger
 

@@ -7,7 +7,7 @@ can:
 
 - **How many distinct XLA executables does this process build, and how
   expensive are they?** A process-wide :class:`CompileTracker` ingests
-  ``jax.monitoring`` compile-duration events (via the old-jax-safe
+  ``jax.monitoring`` compile-duration events (via the
   ``utils/compat.register_compile_listeners`` shim — never a hard
   dependency on the monitoring API) and keys them by *program label*:
   whatever :func:`program` context is live on the compiling thread
@@ -313,9 +313,9 @@ def _on_duration(event: str, duration: float, **_kw) -> None:
 
 def get_compile_tracker() -> CompileTracker:
     """The process-wide tracker; first call installs the monitoring
-    listeners (via the compat shim — "monitoring" on current jax, a
-    wrapped-lowering fallback on old jax, "none" when neither exists;
-    the tracker still works for directly-planted events either way)."""
+    listeners (via the compat shim — "monitoring", or "none" when the
+    hook is missing; the tracker still works for directly-planted
+    events either way)."""
     global _tracker
     if _tracker is None:
         with _tracker_lock:

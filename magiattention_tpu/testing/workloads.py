@@ -43,6 +43,16 @@ def shared_question_q_overlap(total: int, n_answers: int = 8):
     return slices
 
 
+def ranges_of(slices):
+    """``(q_ranges, k_ranges, attn_type_map)`` lists of a slice list —
+    the argument form of ``flex_flash_attn_func`` and the oracle."""
+    return (
+        [(int(s[0]), int(s[1])) for s in slices],
+        [(int(s[2]), int(s[3])) for s in slices],
+        [int(s[4]) for s in slices],
+    )
+
+
 DYNSOLVER_WORKLOADS = {
     "dense_causal": dense_causal,
     "varlen_block_causal": varlen_block_causal,

@@ -1,22 +1,14 @@
-"""jax version compatibility shims.
+"""The one home for jax API spellings that have moved between versions.
 
-The runtime targets current jax (``jax.shard_map`` stable API); CI /
-bring-up images sometimes carry an older jax where ``shard_map`` still
-lives in ``jax.experimental.shard_map`` with the ``check_rep`` spelling
-of ``check_vma``. EVERY module in this package — production runtime,
-profiler, tests — goes through this shim: ``jax.shard_map`` /
+The package targets the installed jax (``pyproject.toml`` pins the
+floor). EVERY module in this package — production runtime, profiler,
+tests — goes through this shim: ``jax.shard_map`` /
 ``pltpu.CompilerParams`` must not be spelled anywhere else in the tree
 (enforced by rule MAGI001 of ``magiattention_tpu/analysis/lint.py``),
-which is what keeps the SPMD suites runnable on old-jax images.
+so the next rename is a one-file change.
 """
 
 from __future__ import annotations
-
-
-class ShardMapUnsupported(NotImplementedError):
-    """This jax version cannot build the requested shard_map program
-    (old-jax partial-manual mode). Callers with a collective-free
-    alternative catch exactly this and degrade."""
 
 
 def shard_map(
@@ -28,119 +20,55 @@ def shard_map(
     check_vma: bool = True,
     axis_names=None,
 ):
-    """``jax.shard_map`` where available, else the
-    ``jax.experimental.shard_map`` fallback (``check_vma`` maps to the
-    old API's ``check_rep``).
-
-    ``axis_names`` (new-API partial-manual mode: only the named mesh axes
-    become manual; the rest stay under GSPMD) is supported on old jax
-    only in the degenerate every-axis-manual case. A genuinely partial
-    manual program CHECK-crashes the old SPMD partitioner
-    (spmd_partitioner.cc "IsManualSubgroup" fatal — it aborts the
-    process, not an exception), so the fallback raises
-    :class:`ShardMapUnsupported` up front; callers with a
-    collective-free alternative (``parallel/dispatch.roll``) catch
-    exactly that and degrade."""
+    """``jax.shard_map``. ``axis_names`` selects partial-manual mode:
+    only the named mesh axes become manual; the rest stay under GSPMD."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if axis_names is not None and frozenset(mesh.axis_names) - frozenset(
-        axis_names
-    ):
-        raise ShardMapUnsupported(
-            "partial-manual shard_map (axis_names a strict subset of the "
-            "mesh axes) is unsupported on this jax version: the old SPMD "
-            "partitioner fatally aborts on manual subgroups"
-        )
-    return _shard_map(
+    kwargs = {}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
+        **kwargs,
     )
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` where available, else the pre-rename
-    ``pltpu.TPUCompilerParams`` (identical fields — jax renamed the
-    dataclass without changing its schema). Lets kernels written against
-    current jax run — at least in interpret mode — on old-jax bring-up
-    images: the flex-attention kernels and the serving decode kernel
-    both launch through this, which is what keeps their test suites
-    green on images predating the rename."""
+    """``pltpu.CompilerParams`` — the flex-attention kernels and the
+    serving decode kernel both launch through this."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
+
+
+def jaxpr_types() -> tuple[type, type]:
+    """``(Jaxpr, ClosedJaxpr)`` from ``jax.extend.core`` (they left
+    ``jax.core``) — what the trace auditors walk."""
+    from jax.extend import core
+
+    return core.Jaxpr, core.ClosedJaxpr
 
 
 def register_compile_listeners(on_event, on_duration) -> str:
     """Feed XLA-compile observations to the compile tracker
-    (``telemetry/compile.py``) on whatever this jax version offers;
-    never a hard dependency and never raises. Returns the ingestion
-    mode actually wired:
-
-    - ``"monitoring"`` — current jax: ``jax.monitoring`` listeners
-      (``on_event(name)`` per event, ``on_duration(name, seconds)`` per
-      duration event; backend compiles arrive as
-      ``.../backend_compile_duration``).
-    - ``"wrapped"`` — old jax without a usable monitoring API: the
-      internal ``jax._src.dispatch.backend_compile`` is wrapped to time
-      lowerings and synthesize the duration event. Best-effort by
-      construction (private module), which is why it is the fallback.
-    - ``"none"`` — neither hook exists; the tracker still accepts
-      directly-planted events (tests, manual instrumentation).
+    (``telemetry/compile.py``) through ``jax.monitoring``
+    (``on_event(name)`` per event, ``on_duration(name, seconds)`` per
+    duration event; backend compiles arrive as
+    ``.../backend_compile_duration``). Never a hard dependency and never
+    raises. Returns the ingestion mode actually wired: ``"monitoring"``,
+    or ``"none"`` when the hook is missing — the tracker still accepts
+    directly-planted events (tests, manual instrumentation).
     """
     try:
-        from jax import monitoring as _monitoring
+        from jax import monitoring
 
-        reg_ev = getattr(_monitoring, "register_event_listener", None)
-        reg_dur = getattr(
-            _monitoring, "register_event_duration_secs_listener", None
-        )
-        if reg_dur is not None:
-            if on_event is not None and reg_ev is not None:
-                reg_ev(on_event)
-            reg_dur(on_duration)
-            return "monitoring"
-    except Exception:  # pragma: no cover — fall through to the wrap
-        pass
-    try:
-        from jax._src import dispatch as _dispatch
-
-        original = _dispatch.backend_compile
-
-        def _timed_backend_compile(*args, **kwargs):
-            import time as _time
-
-            t0 = _time.perf_counter()
-            out = original(*args, **kwargs)
-            try:
-                on_duration(
-                    "/jax/core/compile/backend_compile_duration",
-                    _time.perf_counter() - t0,
-                )
-            except Exception:
-                pass
-            return out
-
-        _dispatch.backend_compile = _timed_backend_compile
-        return "wrapped"
-    except Exception:
+        if on_event is not None:
+            monitoring.register_event_listener(on_event)
+        monitoring.register_event_duration_secs_listener(on_duration)
+        return "monitoring"
+    except (ImportError, AttributeError):
         return "none"

@@ -29,7 +29,29 @@ TPU_PEAK_SPECS = {
 }
 
 
-def _spec(generation: str) -> TpuPeakSpec:
+# ``jax.Device.device_kind`` of each generation in the table
+DEVICE_KIND_GENERATION = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v6 lite": "v6e",
+}
+
+
+def generation_of_device_kind(device_kind: str) -> str:
+    """The peak-table key of a ``device_kind`` as jax reports it; a kind
+    the table does not know is an error, not a default."""
+    gen = DEVICE_KIND_GENERATION.get(device_kind)
+    if gen is None:
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KIND_GENERATION)}"
+        )
+    return gen
+
+
+def peak_spec(generation: str) -> TpuPeakSpec:
+    """The generation's row of the peak table (unknown = error)."""
     spec = TPU_PEAK_SPECS.get(generation)
     if spec is None:
         raise ValueError(
@@ -51,7 +73,7 @@ def get_calc_cost_factor(
     FLOPs per area unit = 4 * nh_q * hd (2 matmuls); seconds = flops /
     (peak * mfu). Relative magnitudes are what the solvers consume.
     """
-    spec = _spec(generation)
+    spec = peak_spec(generation)
     eff = spec.bf16_tflops * 1e12 * (mfu if mfu is not None else spec.mfu)
     return 4.0 * num_heads_q * head_dim / eff
 
@@ -71,7 +93,7 @@ def get_comm_cost_factor(
     ``link``: 'ici' (intra-slice) or 'dcn' (inter-slice hop of the
     hierarchical cast).
     """
-    spec = _spec(generation)
+    spec = peak_spec(generation)
     bw = spec.ici_gbps if link == "ici" else spec.dcn_gbps
     return (2.0 * num_heads_kv * head_dim * bytes_per_elt) / (
         bw * 1e9 * bwu

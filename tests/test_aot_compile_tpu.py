@@ -1,0 +1,172 @@
+"""The main path's kernels, asked of the TPU compiler without a chip.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described and not attached (``v5e:2x2``): a slice not aligned to the
+tiling, too much fast memory, a kernel that cannot be partitioned are
+refused here exactly as on the chip — which interpret mode never shows.
+Nothing runs, so this says nothing about results or times. Skipped where
+the topology cannot be described.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from magiattention_tpu.ops import flex_flash_attn_func
+from magiattention_tpu.testing.workloads import ranges_of, varlen_block_causal
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _as_on_the_chip():
+    """Compile cache off: such a compile is written to the persistent
+    cache but cannot be read back without a chip, so the next run would
+    warn and compile again (guide on-chip-measurement §2.3). 64-bit mode
+    off: the suite's conftest turns it on for its fp64 oracles, the chip
+    runs without it, and Mosaic refuses the int64 index maps it makes."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with jax.enable_x64(False):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("hq,hk,d", [(32, 4, 64), (32, 8, 128)])
+def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
+    """Forward and both backward kernels, autotuner's own tiles."""
+    t = 16384
+    qr, kr, ts = ranges_of(varlen_block_causal(t))
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid=grid, interpret=False
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+    )
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+
+def _serve_cache(chip, hk, d):
+    """The smoke's serve-phase pool: 128k tokens, default page size."""
+    from magiattention_tpu import env
+    from magiattention_tpu.serving import make_paged_kv_cache
+
+    shapes = jax.eval_shape(
+        functools.partial(
+            make_paged_kv_cache, 131072 // env.page_size(), env.page_size(),
+            hk, d, max_seqs=8, max_pages_per_seq=128,
+        )
+    )
+    return jax.tree.map(lambda s: _on(chip, s.shape, s.dtype), shapes)
+
+
+@pytest.mark.parametrize("splits", [1, 4])
+def test_paged_decode(topo, splits):
+    from magiattention_tpu.serving import decode_attn_paged
+
+    hq, hk, d, b = 32, 4, 64, 4
+    chip = SingleDeviceSharding(topo.devices[0])
+    text = _compile(
+        functools.partial(
+            decode_attn_paged, num_splits=splits, interpret=False
+        ),
+        _on(chip, (b, hq, d)), _serve_cache(chip, hk, d),
+        _on(chip, (b,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_serve_prefill_continuation(topo):
+    """The serve phase's last prefill chunk: 2048 queries against the
+    8192 keys written so far (``continue_prefill_into_cache``)."""
+    from magiattention_tpu.serving.engine import continue_prefill_into_cache
+
+    hq, hk, d, t, start = 32, 4, 64, 2048, 6144
+    chip = SingleDeviceSharding(topo.devices[0])
+    text = _compile(
+        functools.partial(
+            continue_prefill_into_cache, slot=0, start=start, interpret=False
+        ),
+        _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+        _serve_cache(chip, hk, d),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_two_layer_train_step_cp4(topo):
+    """A whole optimizer step over the four described chips: the plan
+    tables cannot be placed there (``sharded_plan_tables`` leaves them
+    to jit), ``recommended_compiler_options`` is accepted, and the
+    plan's collectives are in the program."""
+    import optax
+
+    from magiattention_tpu.api import infer_varlen_mask_from_batch
+    from magiattention_tpu.models import (
+        LlamaConfig,
+        build_magi_llama,
+        init_params,
+    )
+
+    total = 2048
+    cfg = LlamaConfig(
+        vocab_size=1024, dim=256, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=64, ffn_hidden=512,
+    )
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("dp", "cp"))
+    qr, kr, ts = infer_varlen_mask_from_batch([700, 300, 1048])
+    model, _ = build_magi_llama(
+        cfg, mesh, total, qr, kr, ts, chunk_size=128, interpret=False
+    )
+    opt = optax.adamw(1e-4)
+    rep = NamedSharding(mesh, P())
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(opt.init, params)
+    params, state = jax.tree.map(
+        lambda s: _on(rep, s.shape, s.dtype), (params, state)
+    )
+    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, total), jnp.int32)
+    text = (
+        model.make_train_step(opt)
+        .lower(params, state, batch, batch, batch)
+        .compile()
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    assert "all-to-all" in text or "collective-permute" in text

@@ -7,9 +7,7 @@ matching reduce results (sum / avg / lse) and matching gradients through
 ``group_reduce_lse_m``, while tracing strictly less comm volume — and NO
 collective at all for zero-volume maps or cp=1.
 
-Uses ``utils.compat.shard_map`` so the suite runs on old-jax bring-up
-images (the production ``jax.shard_map`` spelling is exercised on
-real-TPU images).
+Uses ``utils.compat.shard_map`` like the runtime (lint rule MAGI001).
 """
 
 import functools

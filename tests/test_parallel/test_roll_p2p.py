@@ -137,13 +137,6 @@ def test_p2p_preserves_other_axis_sharding():
     """Partial-manual shard_map: a hidden dim sharded over another mesh
     axis (tp) must pass through the roll untouched — not be forced
     replicated (memory blow-up) or stripped (silent reshard)."""
-    if not hasattr(jax, "shard_map"):
-        pytest.skip(
-            "partial-manual shard_map is unbuildable on this old-jax "
-            "image (the SPMD partitioner aborts on manual subgroups; "
-            "utils/compat.shard_map refuses and roll() degrades to the "
-            "gather path, which does not preserve the tp sharding)"
-        )
     total = 1024
     qr = AttnRanges.from_ranges([(0, total)])
     meta, _, _ = make_dispatch_meta_from_qk_ranges(

@@ -98,7 +98,7 @@ class TestTrackerAccounting:
 
     def test_listener_ingestion_mode_recorded(self):
         tr = comp.get_compile_tracker()
-        assert tr.ingestion in ("monitoring", "wrapped", "none")
+        assert tr.ingestion in ("monitoring", "none")
 
     def test_duration_listener_filters_event_names(self):
         tr = comp.get_compile_tracker()

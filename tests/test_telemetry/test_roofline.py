@@ -188,6 +188,9 @@ def test_peak_table_and_override(monkeypatch):
     )
     # the jnp/CPU backends get the placeholder, not a chip number
     assert resolve_peak_tflops("v5e", "jnp") == CPU_PEAK_TFLOPS
+    # a generation the table does not know is an error, not v5e's peak
+    with pytest.raises(ValueError, match="unknown TPU generation"):
+        resolve_peak_tflops("v9z", "tpu")
     monkeypatch.setenv("MAGI_ATTENTION_PEAK_TFLOPS", "123.5")
     assert resolve_peak_tflops("v5e", "tpu") == 123.5
     assert resolve_peak_tflops("v5e", "jnp") == 123.5
