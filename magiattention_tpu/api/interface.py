@@ -19,6 +19,7 @@ Typical flow::
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import time
 from collections import OrderedDict
@@ -299,7 +300,14 @@ class DistAttnRuntimeMgr:
         """
         from ..common.forward_meta import AttnForwardMeta
 
-        out, lse, max_logits = self._attn_fn(q, k, v, sink)
+        if isinstance(q, jax.core.Tracer):
+            # jax is tracing the caller: this Python (the runtime's and
+            # the Pallas kernels') runs once a trace, and is what the
+            # span measures
+            with telemetry.span("calc_attn.trace", key=self.key):
+                out, lse, max_logits = self._attn_fn(q, k, v, sink)
+        else:
+            out, lse, max_logits = self._attn_fn(q, k, v, sink)
         return out, AttnForwardMeta(lse=lse, max_logits=max_logits)
 
 
@@ -488,7 +496,31 @@ def _resolve_overlap_config(oc, hq, hkv, head_dim, *, hier: bool = False):
 
 # plan-aware block resolution lives with the tuner (tuning/autotuner.py);
 # the keyed-runtime call sites below use it through this alias
-from ..tuning.autotuner import resolve_block_config as _resolve_block_config
+from ..tuning.autotuner import resolve_block_config
+
+
+def _resolve_block_config(*args):
+    """The autotuner's block, head-block and grid choice, as the
+    ``tile_choice`` span of the key being built."""
+    with telemetry.span("tile_choice"):
+        return resolve_block_config(*args)
+
+
+def _key_build_span(build):
+    """One ``key_build`` span round a key builder's whole call (ISSUE
+    24): the body says how the runtime cache answered
+    (``telemetry.annotate_span(cache=...)``), and the key's id, known
+    only once the key exists, reaches every span recorded under it."""
+
+    @functools.wraps(build)
+    def wrapper(*args, **kwargs):
+        with telemetry.span("key_build") as live:
+            key = build(*args, **kwargs)
+            if live is not None:
+                live.set(key=key)
+            return key
+
+    return wrapper
 
 
 def _blocking_from(
@@ -664,6 +696,7 @@ def _try_plan_reuse(
     return key
 
 
+@_key_build_span
 def magi_attn_flex_key(
     q_ranges: AttnRanges | Sequence[Sequence[int]],
     k_ranges: AttnRanges | Sequence[Sequence[int]],
@@ -836,6 +869,7 @@ def magi_attn_flex_key(
     )
     _t_lookup = time.perf_counter()
     if key in _runtime_dict:
+        telemetry.annotate_span(cache="hit")
         if not _in_canonical_resolve:
             telemetry.record_cache_access(hit=True)
             # ISSUE 16: the hit's solver cost is the lookup itself; the
@@ -858,8 +892,10 @@ def magi_attn_flex_key(
         interpret=interpret,
     )
     if reuse_key is not None:
+        telemetry.annotate_span(cache="reuse")
         _most_recent_key = reuse_key
         return reuse_key
+    telemetry.annotate_span(cache="miss")
     if not _in_canonical_resolve:
         telemetry.record_cache_access(hit=False)
 
@@ -901,16 +937,17 @@ def magi_attn_flex_key(
             block_k=env.block_k(),
             dispatch_meta=mq,
         )
-        params = make_attn_params(
-            qo_plan,
-            head_dim,
-            softcap=softcap,
-            out_dtype=out_dtype,
-            interpret=interpret,
-        )
-        qo_fn = make_qo_comm_attn_fn(
-            qo_plan, mesh, params, axis_name=cp_axis, sink=sink
-        )
+        with telemetry.span("attn_fn_build"):
+            params = make_attn_params(
+                qo_plan,
+                head_dim,
+                softcap=softcap,
+                out_dtype=out_dtype,
+                interpret=interpret,
+            )
+            qo_fn = make_qo_comm_attn_fn(
+                qo_plan, mesh, params, axis_name=cp_axis, sink=sink
+            )
 
         def attn_fn(q, k, v, sink_override=None):
             out, lse = qo_fn(q, k, v, sink_override)
@@ -945,19 +982,20 @@ def magi_attn_flex_key(
             total_seqlen_q + pad,
             plan.describe(),
         )
-    params = make_attn_params(
-        plan,
-        head_dim,
-        softcap=softcap,
-        has_sink=has_sink,
-        out_dtype=out_dtype,
-        interpret=interpret,
-        head_block=plan_head_block,
-    )
-    attn_fn = make_dist_attn_fn(
-        plan, mesh, params, axis_name=cp_axis, sink=sink,
-        with_max_logits=True,
-    )
+    with telemetry.span("attn_fn_build"):
+        params = make_attn_params(
+            plan,
+            head_dim,
+            softcap=softcap,
+            has_sink=has_sink,
+            out_dtype=out_dtype,
+            interpret=interpret,
+            head_block=plan_head_block,
+        )
+        attn_fn = make_dist_attn_fn(
+            plan, mesh, params, axis_name=cp_axis, sink=sink,
+            with_max_logits=True,
+        )
     mgr = DistAttnRuntimeMgr(
         key, mesh, mq, plan, attn_fn, dist_attn_config=dist_attn_config
     )
@@ -1000,6 +1038,7 @@ def magi_attn_varlen_key(
     )
 
 
+@_key_build_span
 def magi_attn_cross_key(
     q_ranges: AttnRanges | Sequence[Sequence[int]],
     k_ranges: AttnRanges | Sequence[Sequence[int]],
@@ -1123,12 +1162,14 @@ def magi_attn_cross_key(
     )
     _t_lookup = time.perf_counter()
     if key in _runtime_dict:
+        telemetry.annotate_span(cache="hit")
         telemetry.record_cache_access(hit=True)
         telemetry.record_plan_solver(
             time.perf_counter() - _t_lookup, cache_hit=True
         )
         _most_recent_key = key
         return key
+    telemetry.annotate_span(cache="miss")
     telemetry.record_cache_access(hit=False)
 
     from ..meta.dispatch_meta import make_cross_attn_dispatch_meta
@@ -1160,17 +1201,18 @@ def magi_attn_cross_key(
         bytes_per_elt=jnp.dtype(out_dtype).itemsize,
         generation=env.tpu_generation(),
     )
-    params = make_attn_params(
-        plan,
-        head_dim,
-        softcap=softcap,
-        out_dtype=out_dtype,
-        interpret=interpret,
-        head_block=plan_head_block,
-    )
-    attn_fn = make_dist_attn_fn(
-        plan, mesh, params, axis_name=cp_axis, with_max_logits=True
-    )
+    with telemetry.span("attn_fn_build"):
+        params = make_attn_params(
+            plan,
+            head_dim,
+            softcap=softcap,
+            out_dtype=out_dtype,
+            interpret=interpret,
+            head_block=plan_head_block,
+        )
+        attn_fn = make_dist_attn_fn(
+            plan, mesh, params, axis_name=cp_axis, with_max_logits=True
+        )
     mgr = DistAttnRuntimeMgr(
         key,
         mesh,
@@ -1187,12 +1229,14 @@ def magi_attn_cross_key(
 
 def dispatch(x: jax.Array, key: DistAttnRuntimeKey, pad_value: float = 0.0):
     """Reference api.dispatch :887."""
-    return get_runtime_mgr(key).dispatch(x, pad_value)
+    with telemetry.span("dispatch", key=key):
+        return get_runtime_mgr(key).dispatch(x, pad_value)
 
 
 def undispatch(y: jax.Array, key: DistAttnRuntimeKey):
     """Reference api.undispatch :924."""
-    return get_runtime_mgr(key).undispatch(y)
+    with telemetry.span("undispatch", key=key):
+        return get_runtime_mgr(key).undispatch(y)
 
 
 def calc_attn(q, k, v, key: DistAttnRuntimeKey, sink=None):
@@ -1224,6 +1268,7 @@ def get_xattn_args(key: DistAttnRuntimeKey) -> XAttnArgs:
     return get_runtime_mgr(key).get_xattn_args()
 
 
+@_key_build_span
 def make_flex_key_for_new_mask_after_dispatch(
     q_ranges: AttnRanges | Sequence[Sequence[int]],
     k_ranges: AttnRanges | Sequence[Sequence[int]],
@@ -1300,12 +1345,14 @@ def make_flex_key_for_new_mask_after_dispatch(
     )
     _t_lookup = time.perf_counter()
     if new_key in _runtime_dict:
+        telemetry.annotate_span(cache="hit")
         telemetry.record_cache_access(hit=True)
         telemetry.record_plan_solver(
             time.perf_counter() - _t_lookup, cache_hit=True
         )
         _most_recent_key = new_key
         return new_key
+    telemetry.annotate_span(cache="miss")
     telemetry.record_cache_access(hit=False)
 
     from ..meta.dispatch_meta import make_global_bucket_from_qk_ranges
@@ -1339,19 +1386,20 @@ def make_flex_key_for_new_mask_after_dispatch(
         bytes_per_elt=jnp.dtype(new_key.out_dtype).itemsize,
         generation=env.tpu_generation(),
     )
-    params = make_attn_params(
-        plan,
-        new_key.head_dim,
-        softcap=new_key.softcap,
-        has_sink=False,
-        out_dtype=new_key.out_dtype,
-        interpret=new_key.interpret,
-        head_block=plan_head_block,
-    )
-    attn_fn = make_dist_attn_fn(
-        plan, old_mgr.mesh, params, axis_name=new_key.cp_axis,
-        with_max_logits=True,
-    )
+    with telemetry.span("attn_fn_build"):
+        params = make_attn_params(
+            plan,
+            new_key.head_dim,
+            softcap=new_key.softcap,
+            has_sink=False,
+            out_dtype=new_key.out_dtype,
+            interpret=new_key.interpret,
+            head_block=plan_head_block,
+        )
+        attn_fn = make_dist_attn_fn(
+            plan, old_mgr.mesh, params, axis_name=new_key.cp_axis,
+            with_max_logits=True,
+        )
     _runtime_dict.put(
         new_key,
         DistAttnRuntimeMgr(

@@ -783,10 +783,9 @@ def is_cpp_backend_enabled() -> bool:
 def is_profile_mode() -> bool:
     """Default-on switch for the profiler helpers (reference
     MAGI_ATTENTION_PROFILE_MODE): ``switch_profile()`` with no explicit
-    ``trace_dir`` starts an XLA trace into :func:`trace_dir`, and
-    ``instrument_trace`` / ``add_trace_event`` annotate named scopes
-    (they are zero-cost passthroughs when this and telemetry are both
-    off)."""
+    ``trace_dir`` starts an XLA trace into :func:`trace_dir`; off, it is
+    a no-op. With telemetry on, the host's ``telemetry.span``s land in
+    that trace as ``magi:<name>`` rows."""
     return _env_bool("MAGI_ATTENTION_PROFILE_MODE", False)
 
 

@@ -268,12 +268,13 @@ def make_cross_attn_dispatch_meta(
             f"{cp_size}"
         )
 
-    bucket = make_global_bucket_from_qk_ranges(
-        q_ranges, k_ranges, attn_mask_type, total_seqlen_q, chunk_size_q
-    )
-    partitions = _solve_q_partitions(
-        bucket, num_chunks_q, cp_size, dispatch_config
-    )
+    with telemetry.span("dispatch_solve", cp=cp_size):
+        bucket = make_global_bucket_from_qk_ranges(
+            q_ranges, k_ranges, attn_mask_type, total_seqlen_q, chunk_size_q
+        )
+        partitions = _solve_q_partitions(
+            bucket, num_chunks_q, cp_size, dispatch_config
+        )
 
     meta_q = DispatchMeta(
         total_seqlen=total_seqlen_q,
@@ -330,12 +331,14 @@ def make_dispatch_meta_from_qk_ranges(
             "DispatchConfig(uneven_shard=True))"
         )
 
-    bucket = make_global_bucket_from_qk_ranges(
-        q_ranges, k_ranges, attn_mask_type, total_seqlen_q, chunk_size
-    )
-    partitions = _solve_q_partitions(
-        bucket, num_chunks, cp_size, dispatch_config
-    )
+    # chunking and the chunk-to-rank assignment: the key's dispatch solve
+    with telemetry.span("dispatch_solve", cp=cp_size):
+        bucket = make_global_bucket_from_qk_ranges(
+            q_ranges, k_ranges, attn_mask_type, total_seqlen_q, chunk_size
+        )
+        partitions = _solve_q_partitions(
+            bucket, num_chunks, cp_size, dispatch_config
+        )
 
     meta = DispatchMeta(
         total_seqlen=total_seqlen_q,

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..common.axes import cp_axis_names
 
 
@@ -49,6 +50,7 @@ def sharded_plan_tables(plan, mesh, cp_axis):
     return tuple(tables)
 
 
+@telemetry.span("plan_flex_attn")  # one span a call, as a key's key_build
 def plan_flex_attn(
     cfg,
     mesh,
@@ -123,13 +125,14 @@ def plan_flex_attn(
         overlap_config=overlap_config,
         cp_mesh_shape=cp_mesh_shape,
     )
-    attn_params = make_attn_params(
-        plan,
-        cfg.head_dim,
-        out_dtype=cfg.dtype,
-        interpret=interpret,
-        head_block=hb,
-    )
+    with telemetry.span("attn_fn_build"):
+        attn_params = make_attn_params(
+            plan,
+            cfg.head_dim,
+            out_dtype=cfg.dtype,
+            interpret=interpret,
+            head_block=hb,
+        )
     return plan, attn_params, mq
 
 
@@ -153,18 +156,19 @@ def resolve_harness_blocking(
     if block_q is None and block_k is None:
         from ..tuning.autotuner import resolve_block_config
 
-        tuned = resolve_block_config(
-            q_naive,
-            k_naive,
-            tuple(int(t) for t in attn_type_map),
-            total_seqlen,
-            total_seqlen,
-            cp_size,
-            hq,
-            hkv,
-            cfg.head_dim,
-            str(cfg.dtype),
-        )
+        with telemetry.span("tile_choice"):
+            tuned = resolve_block_config(
+                q_naive,
+                k_naive,
+                tuple(int(t) for t in attn_type_map),
+                total_seqlen,
+                total_seqlen,
+                cp_size,
+                hq,
+                hkv,
+                cfg.head_dim,
+                str(cfg.dtype),
+            )
         if tuned is not None:
             return tuned
     hb_env = env.head_block_override()

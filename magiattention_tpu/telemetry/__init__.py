@@ -64,6 +64,7 @@ from .collectors import (  # noqa: F401
     record_cache_access,
     record_comm_op,
     record_compile,
+    record_compile_cache,
     record_decode_step,
     record_degraded_path,
     record_dispatch_meta,
@@ -127,9 +128,12 @@ from .compile import (  # noqa: F401
 )
 from .events import (  # noqa: F401
     EventBuffer,
+    annotate_span,
     get_event_buffer,
+    key_id,
     record_event,
     span,
+    span_self_seconds,
     trace_metadata_events,
 )
 from .exposition import (  # noqa: F401
@@ -290,6 +294,7 @@ __all__ = [
     "StageTiming",
     "add_solver_seconds",
     "aggregate_across_mesh",
+    "annotate_span",
     "analyze_workload",
     "assert_within_budget",
     "budget_for_dtype",
@@ -317,6 +322,7 @@ __all__ = [
     "ledger_vs_measured",
     "measure_program_memory",
     "merge_chrome_traces",
+    "key_id",
     "merge_snapshots",
     "parse_prometheus_text",
     "plan_memory_ledger",
@@ -334,6 +340,7 @@ __all__ = [
     "record_cache_access",
     "record_comm_op",
     "record_compile",
+    "record_compile_cache",
     "record_decode_step",
     "record_degraded_path",
     "record_dispatch_meta",
@@ -389,6 +396,7 @@ __all__ = [
     "snapshot",
     "snapshot_delta",
     "span",
+    "span_self_seconds",
     "tiered_memory_ledger",
     "start_metrics_server",
     "stop_metrics_server",

@@ -301,6 +301,8 @@ H_DISPATCH_SOLVE_S = "magi_dispatch_solve_seconds"
 M_COMPILE_TOTAL = "magi_compile_total"  # {program=}
 H_COMPILE_S = "magi_compile_seconds"
 M_JIT_CACHE_ENTRIES = "magi_jit_cache_entries"
+# jax's persistent compilation cache, as jax.monitoring reports it
+M_COMPILE_CACHE_TOTAL = "magi_compile_cache_total"  # {result=hit|miss}
 M_SCHED_LAUNCHES = "magi_sched_launches_per_tick"
 H_PLAN_SOLVER_S = "magi_plan_solver_seconds"  # {outcome=}
 M_SOLVER_MS_SAVED = "magi_plan_solver_ms_saved_total"
@@ -1411,6 +1413,14 @@ def record_compile(
     reg.counter_inc(M_COMPILE_TOTAL, program=program)
     reg.histogram_observe(H_COMPILE_S, float(seconds))
     reg.gauge_set(M_JIT_CACHE_ENTRIES, int(total_programs))
+
+
+def record_compile_cache(result: str) -> None:
+    """One program looked up in jax's persistent compilation cache:
+    ``result`` is ``hit`` (loaded) or ``miss`` (compiled, then written)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_COMPILE_CACHE_TOTAL, result=result)
 
 
 def record_plan_solver(seconds: float, *, cache_hit: bool) -> None:

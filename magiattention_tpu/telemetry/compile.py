@@ -26,6 +26,11 @@ can:
   ``parallel/dist_attn.py``) gives host-solver seconds — the tick
   decomposition ``serving/scheduler.py`` reconciles against wall-clock.
 
+The same listeners turn jax's own account of a program's life into
+spans (ISSUE 24): ``jax.trace``, ``jax.lower``, ``jax.backend_compile``
+and ``jax.cache_load``, each with the function's name, children of
+whatever span is live (``docs/observability.md``, "Spans").
+
 Gating discipline (the telemetry-check contract): the tracker's OWN
 accumulators are plain module/instance state *outside* the metrics
 registry and always on — per-tick attribution must work in production
@@ -300,13 +305,120 @@ _tracker: CompileTracker | None = None
 _tracker_lock = threading.Lock()
 
 
+# the phases of a program's life as jax.monitoring names them -> the span
+# each becomes (ISSUE 24); jax hands every one the function's name
+_PHASE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+}
+_PHASE_NAMES = frozenset(_PHASE_SPANS.values())
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
 def _on_duration(event: str, duration: float, **_kw) -> None:
-    """The jax.monitoring duration listener: every event, filtered down
-    to the backend-compile ones. Defensive about signature growth —
-    newer jax may pass extra keyword context."""
+    """The jax.monitoring duration listener: backend compiles feed the
+    tracker; a retrieval from the persistent cache (reported from inside
+    the backend-compile phase, as a duration that has just ended)
+    becomes a ``jax.cache_load`` span. Defensive about signature growth
+    — newer jax may pass extra keyword context."""
     try:
         if any(event.endswith(s) for s in _COMPILE_EVENT_SUFFIXES):
             get_compile_tracker().note_compile(float(duration))
+        elif event == _CACHE_LOAD_EVENT:
+            from .events import current_span, record_event
+
+            live = current_span()
+            fun_name = live.attrs.get("fun_name") if live is not None else None
+            record_event(
+                "jax.cache_load",
+                time.perf_counter() - float(duration),
+                float(duration),
+                {"fun_name": fun_name} if fun_name is not None else None,
+            )
+    except Exception:  # pragma: no cover — observability must not raise
+        pass
+
+
+def _on_event(event: str, **_kw) -> None:
+    """The jax.monitoring event listener: the persistent cache's hits
+    and misses, counted (registry, gated as the rest)."""
+    try:
+        result = _CACHE_EVENTS.get(event)
+        if result is not None:
+            from .collectors import record_compile_cache
+
+            record_compile_cache(result)
+    except Exception:  # pragma: no cover — observability must not raise
+        pass
+
+
+def _live_phase():
+    """The innermost live span that is a jax phase, if any."""
+    from .events import current_span
+
+    live = current_span()
+    while live is not None and live.name not in _PHASE_NAMES:
+        live = live.parent
+    return live
+
+
+# phases that began inside the live one and are part of it (they nest
+# properly, so a count is enough to pair each end with its start)
+_folded: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "magi_folded_phases", default=0
+)
+
+
+def _on_phase_start(event: str, _start: float, **kw) -> None:
+    """A trace, a lowering or a backend compile begins: a live span, so
+    that what runs inside it (``calc_attn.trace``, the cache load) names
+    it as its parent. A phase that begins inside a live one is part of
+    it and gets no span of its own: tracing one program traces every
+    jitted function it calls, and lowering a kernel traces more —
+    thousands of them in a model step."""
+    try:
+        name = _PHASE_SPANS.get(event)
+        if name is None:
+            return
+        from . import enabled
+
+        if _live_phase() is not None:
+            _folded.set(_folded.get() + 1)
+        elif enabled():
+            from .events import begin_span
+
+            begin_span(name, {"fun_name": str(kw.get("fun_name", ""))})
+    except Exception:  # pragma: no cover — observability must not raise
+        pass
+
+
+def _on_phase_end(event: str, start: float, end: float, **kw) -> None:
+    """The phase ends: close its live span. Where it has none (telemetry
+    came on midway, or this jax reports no start), record it whole, on
+    the span buffer's clock, ending now."""
+    try:
+        name = _PHASE_SPANS.get(event)
+        if name is None:
+            return
+        from . import enabled
+        from .events import end_span, record_event
+
+        live = _live_phase()
+        if live is not None and _folded.get():
+            _folded.set(_folded.get() - 1)
+        elif live is not None and live.name == name:
+            end_span(live)
+        elif enabled():
+            dur = float(end) - float(start)
+            record_event(
+                name, time.perf_counter() - dur, dur,
+                {"fun_name": str(kw.get("fun_name", ""))},
+            )
     except Exception:  # pragma: no cover — observability must not raise
         pass
 
@@ -324,7 +436,7 @@ def get_compile_tracker() -> CompileTracker:
                 from ..utils.compat import register_compile_listeners
 
                 tracker.ingestion = register_compile_listeners(
-                    None, _on_duration
+                    _on_event, _on_duration, _on_phase_start, _on_phase_end
                 )
                 _tracker = tracker
     return _tracker

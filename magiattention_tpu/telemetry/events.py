@@ -14,13 +14,26 @@ and never grows without bound. Recording is gated by
 :func:`magiattention_tpu.telemetry.enabled` at every *call site* (the
 ``span``/``record_event`` helpers here check it too), so the disabled
 path allocates nothing.
+
+Spans form a tree (ISSUE 24): each carries its own ``id``, the ``parent``
+that caused it (the innermost span live on the thread when it began: a
+``contextvars`` chain) and, where it belongs to a runtime key, the short
+``key`` id every span of that key's life shares — all three in the Chrome
+event's ``args``. :func:`span_self_seconds` turns the tree into each
+span's own time. While jax is loaded a live span is also written as
+``jax.profiler.TraceAnnotation("magi:" + name)``, which the profiler
+keeps only while one of its sessions records: the same span then sits
+in the ``.xplane.pb`` on the profiler's clock, beside ``XLA Ops``.
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -30,6 +43,18 @@ from collections import deque
 # asserts it): silent truncation would make a reconstructed request
 # trace look complete when it is not
 DROPPED_COUNTER = "magi_trace_events_dropped_total"
+
+# what a span is called in the profiler's trace (beside the device rows)
+ANNOTATION_PREFIX = "magi:"
+
+_span_ids = itertools.count(1)  # next() is atomic under the GIL
+
+
+def key_id(key) -> str:
+    """The short id the spans of one runtime key share: eight hex digits
+    of the key's hash, stable within a process (str hashes are salted per
+    process, so never compare ids across processes)."""
+    return f"{hash(key) & 0xFFFFFFFF:08x}"
 
 
 class EventBuffer:
@@ -79,7 +104,16 @@ class EventBuffer:
         attrs: dict | None = None,
         *,
         track: str | None = None,
-    ) -> None:
+        span_id: int | None = None,
+        parent: int | None = None,
+    ) -> dict:
+        """Append one completed span and return its event. ``span_id``
+        defaults to a fresh id; ``parent`` is the id of the span that
+        caused it (None at a root)."""
+        args = dict(attrs) if attrs else {}
+        args["id"] = next(_span_ids) if span_id is None else span_id
+        if parent is not None:
+            args["parent"] = parent
         with self._lock:
             tid = (
                 self._track_tid(track)
@@ -93,9 +127,8 @@ class EventBuffer:
                 "dur": duration_s * 1e6,
                 "pid": os.getpid(),
                 "tid": tid,
+                "args": args,
             }
-            if attrs:
-                ev["args"] = dict(attrs)
             full = (
                 self._events.maxlen is not None
                 and len(self._events) >= self._events.maxlen
@@ -120,6 +153,7 @@ class EventBuffer:
                 "MAGI_ATTENTION_TELEMETRY_RING_SIZE to keep more.",
                 self._events.maxlen,
             )
+        return ev
 
     def events(self) -> list[dict]:
         with self._lock:
@@ -134,6 +168,15 @@ class EventBuffer:
             self._events.clear()
             self._dropped = 0
             self._drop_warned = False
+
+    def self_seconds(self, name: str) -> float:
+        """Summed self time of the buffered spans called ``name``: each
+        one's duration minus the part its children cover."""
+        events = self.events()
+        own = span_self_seconds(events)
+        return sum(
+            own[ev["args"]["id"]] for ev in events if ev["name"] == name
+        )
 
     def dump(self, path: str) -> str:
         """Write the buffered spans as Chrome trace-event JSON; returns
@@ -222,6 +265,130 @@ def get_event_buffer() -> EventBuffer:
     return _buffer
 
 
+def span_self_seconds(events: list[dict]) -> dict[int, float]:
+    """span id -> self seconds: a span's duration minus the union of its
+    direct children's intervals (clipped to its own). ``events`` is what
+    :meth:`EventBuffer.events` returns; a child whose parent fell out of
+    the ring counts for nobody."""
+    children: dict[int, list[tuple[float, float]]] = {}
+    for ev in events:
+        parent = ev.get("args", {}).get("parent")
+        if parent is not None:
+            children.setdefault(parent, []).append(
+                (ev["ts"], ev["ts"] + ev["dur"])
+            )
+    out: dict[int, float] = {}
+    for ev in events:
+        sid = ev.get("args", {}).get("id")
+        if sid is None:
+            continue
+        t0, t1 = ev["ts"], ev["ts"] + ev["dur"]
+        covered, edge = 0.0, t0
+        for a, b in sorted(children.get(sid, ())):
+            a, b = max(a, edge), min(b, t1)
+            if b > a:
+                covered += b - a
+                edge = b
+        out[sid] = (ev["dur"] - covered) / 1e6
+    return out
+
+
+# ---------------------------------------------------------------------------
+# live spans: the tree's parent links, and the profiler's copy
+# ---------------------------------------------------------------------------
+
+
+def _with_key_id(attrs: dict) -> dict:
+    """``key=`` may be the runtime key itself (so that a call site pays
+    no hash while telemetry is off): it is recorded as its id."""
+    key = attrs.get("key")
+    if key is not None and not isinstance(key, str):
+        attrs["key"] = key_id(key)
+    return attrs
+
+
+class LiveSpan:
+    """A span that has begun and not ended: what its children name as
+    their parent, and where attributes learned on the way are set
+    (``live.set(cache="hit")``)."""
+
+    __slots__ = ("name", "id", "parent", "attrs", "t0", "_below", "_annotation")
+
+    def __init__(self, name: str, parent: "LiveSpan | None", attrs: dict):
+        self.name = name
+        self.id = next(_span_ids)
+        self.parent = parent
+        self.attrs = _with_key_id(attrs)
+        # a key is inherited downwards at the start ...
+        if parent is not None and "key" not in attrs and "key" in parent.attrs:
+            attrs["key"] = parent.attrs["key"]
+        # ... and upwards-late at the end: the events recorded under this
+        # span, so a key learned only when it ends still reaches them
+        self._below: list[dict] = []
+        self._annotation = None
+        self.t0 = 0.0
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(_with_key_id(attrs))
+
+
+_live: contextvars.ContextVar[LiveSpan | None] = contextvars.ContextVar(
+    "magi_live_span", default=None
+)
+
+
+def current_span() -> LiveSpan | None:
+    """The innermost span live on this thread/context, if any."""
+    return _live.get()
+
+
+def annotate_span(**attrs) -> None:
+    """Set attributes on the innermost live span (nothing where none is
+    live, as with telemetry off)."""
+    live = _live.get()
+    if live is not None:
+        live.set(**attrs)
+
+
+def begin_span(name: str, attrs: dict | None = None) -> LiveSpan:
+    """Open a span by hand (the compile tracker's listeners learn of a
+    jax phase's start and end in two separate calls); pair with
+    :func:`end_span`. Call sites check :func:`telemetry.enabled`."""
+    live = LiveSpan(name, _live.get(), dict(attrs) if attrs else {})
+    _live.set(live)
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        # kept by the profiler only while one of its sessions records
+        live._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + name
+        )
+        live._annotation.__enter__()
+    live.t0 = time.perf_counter()
+    return live
+
+
+def end_span(live: LiveSpan) -> dict:
+    """Close ``live`` (and whatever was left open above it) and record
+    it; returns the event."""
+    t1 = time.perf_counter()
+    if live._annotation is not None:
+        live._annotation.__exit__(None, None, None)
+    _live.set(live.parent)
+    ev = get_event_buffer().record(
+        live.name, live.t0, t1 - live.t0, live.attrs,
+        span_id=live.id,
+        parent=live.parent.id if live.parent is not None else None,
+    )
+    key = live.attrs.get("key")
+    if key is not None:
+        for below in live._below:
+            below["args"].setdefault("key", key)
+    if live.parent is not None:
+        live.parent._below.extend(live._below)
+        live.parent._below.append(ev)
+    return ev
+
+
 def record_event(
     name: str,
     start_s: float,
@@ -230,28 +397,36 @@ def record_event(
     *,
     track: str | None = None,
 ) -> None:
-    """Append one completed span (no-op while telemetry is disabled).
-    ``track`` routes it onto a named synthetic Chrome-trace track."""
+    """Append one completed span (no-op while telemetry is disabled);
+    its parent is the innermost span live now. ``track`` routes it onto
+    a named synthetic Chrome-trace track."""
     from . import enabled
 
     if not enabled():
         return
-    get_event_buffer().record(name, start_s, duration_s, attrs, track=track)
+    parent = _live.get()
+    if parent is not None and "key" in parent.attrs:
+        attrs = {"key": parent.attrs["key"], **(attrs or {})}
+    ev = get_event_buffer().record(
+        name, start_s, duration_s, attrs, track=track,
+        parent=parent.id if parent is not None else None,
+    )
+    if parent is not None:
+        parent._below.append(ev)
 
 
 @contextlib.contextmanager
 def span(name: str, **attrs):
-    """Time a host-side region into the ring buffer. Disabled mode yields
-    immediately with no clock reads or allocation."""
+    """Time a host-side region into the ring buffer; yields the
+    :class:`LiveSpan` (None while disabled). Disabled mode yields
+    immediately with no clock reads, no allocation and no jax."""
     from . import enabled
 
     if not enabled():
-        yield
+        yield None
         return
-    t0 = time.perf_counter()
+    live = begin_span(name, attrs)
     try:
-        yield
+        yield live
     finally:
-        get_event_buffer().record(
-            name, t0, time.perf_counter() - t0, attrs or None
-        )
+        end_span(live)

@@ -6,12 +6,7 @@ from .cost import (
     get_calc_cost_factor,
     get_comm_cost_factor,
 )
-from .instrument import (
-    add_trace_event,
-    instrument_trace,
-    instrumentation_active,
-    switch_profile,
-)
+from .instrument import switch_profile
 from .vis import plot_dynamic_solution, plot_mask
 from .packing import (
     bin_cu_seqlens,
@@ -22,12 +17,9 @@ from .packing import (
 
 __all__ = [
     "TPU_PEAK_SPECS",
-    "add_trace_event",
     "bin_cu_seqlens",
     "get_calc_cost_factor",
     "get_comm_cost_factor",
-    "instrument_trace",
-    "instrumentation_active",
     "latest_step",
     "pack_corpus",
     "pack_documents",

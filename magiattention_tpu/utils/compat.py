@@ -53,15 +53,23 @@ def jaxpr_types() -> tuple[type, type]:
     return core.Jaxpr, core.ClosedJaxpr
 
 
-def register_compile_listeners(on_event, on_duration) -> str:
-    """Feed XLA-compile observations to the compile tracker
-    (``telemetry/compile.py``) through ``jax.monitoring``
-    (``on_event(name)`` per event, ``on_duration(name, seconds)`` per
-    duration event; backend compiles arrive as
-    ``.../backend_compile_duration``). Never a hard dependency and never
-    raises. Returns the ingestion mode actually wired: ``"monitoring"``,
-    or ``"none"`` when the hook is missing — the tracker still accepts
-    directly-planted events (tests, manual instrumentation).
+def register_compile_listeners(
+    on_event, on_duration, on_phase_start=None, on_phase_end=None
+) -> str:
+    """Feed jax's own account of its compile pipeline to the compile
+    tracker (``telemetry/compile.py``) through ``jax.monitoring``:
+    ``on_event(name)`` per event (the persistent cache's hits and
+    misses), ``on_duration(name, seconds)`` per duration event (backend
+    compiles arrive as ``.../backend_compile_duration``, cache
+    retrievals as ``.../cache_retrieval_time_sec``), and — where this
+    jax has them — ``on_phase_start(name, start, fun_name=)`` as a
+    trace, a lowering or a backend compile begins (jax records its start
+    as a scalar) and ``on_phase_end(name, start, end, fun_name=)`` as it
+    ends (a time span; both on ``time.time()``). Never a hard dependency
+    and never raises. Returns the ingestion mode actually wired:
+    ``"monitoring"``, or ``"none"`` when the hook is missing — the
+    tracker still accepts directly-planted events (tests, manual
+    instrumentation).
     """
     try:
         from jax import monitoring
@@ -69,6 +77,13 @@ def register_compile_listeners(on_event, on_duration) -> str:
         if on_event is not None:
             monitoring.register_event_listener(on_event)
         monitoring.register_event_duration_secs_listener(on_duration)
-        return "monitoring"
     except (ImportError, AttributeError):
         return "none"
+    for hook, callback in (
+        ("register_scalar_listener", on_phase_start),
+        ("register_event_time_span_listener", on_phase_end),
+    ):
+        register = getattr(monitoring, hook, None)
+        if callback is not None and register is not None:
+            register(callback)
+    return "monitoring"

@@ -1,105 +1,21 @@
 """Tracing / profiling instrumentation.
 
-Role of reference ``utils/nvtx.py`` (instrument_nvtx decorator,
-add_nvtx_event, switch_profile): on TPU the equivalents are
-``jax.named_scope`` (annotates traced computations so they show up in the
-XLA profiler timeline) plus ``jax.profiler`` trace sessions.
-
-Telemetry integration (ISSUE 1): when the telemetry layer is enabled,
-``instrument_trace`` / ``add_trace_event`` ALSO emit timestamped span
-events into the host-side ring buffer (``telemetry/events.py``) —
-exportable as Chrome-trace JSON via ``telemetry.dump_events`` — so host
-planning time lines up next to device traces. When telemetry AND profile
-mode are both disabled, both helpers are true zero-cost passthroughs:
-the decorator returns the original function object and the context
-manager yields without touching jax.
-
-Gating granularity: ``add_trace_event`` / ``switch_profile`` check
-:func:`instrumentation_active` per use, so flipping
-``telemetry.set_enabled`` or ``MAGI_ATTENTION_PROFILE_MODE`` mid-process
-affects them immediately. ``instrument_trace`` decides at DECORATION
-time — the zero-cost contract means a function decorated while
-instrumentation was off stays un-wrapped; enable telemetry/profile mode
-before importing (or decorating) the code you want traced.
+Role of reference ``utils/nvtx.py`` (add_nvtx_event, switch_profile): on
+TPU the equivalents are ``jax.named_scope`` (annotates traced
+computations so they show up in the XLA profiler timeline) plus
+``jax.profiler`` trace sessions. Host-side spans are ``telemetry.span``
+(``telemetry/events.py``), the package's one span primitive: while a
+session of :func:`switch_profile` records, each is also written into the
+profiler's trace as ``magi:<name>``, beside the device rows.
 """
 
 from __future__ import annotations
 
 import contextlib
-import functools
 import logging
 import threading
-import time
-from typing import Callable, Optional
 
 logger = logging.getLogger("magiattention_tpu.utils.instrument")
-
-
-def instrumentation_active() -> bool:
-    """Should scopes be annotated / spans recorded right now?"""
-    from .. import env, telemetry
-
-    return telemetry.enabled() or env.is_profile_mode()
-
-
-def instrument_trace(fn: Optional[Callable] = None, *, name: str | None = None):
-    """Decorator: wrap a function in a named scope for profiler timelines
-    (reference @nvtx.instrument_nvtx) and, with telemetry on, record a
-    host-side span per call.
-
-    Zero-cost passthrough: when telemetry and profile mode are BOTH off
-    at decoration time, the original function object is returned
-    unchanged (``instrument_trace(f) is f``) — no wrapper frame at all.
-    Decorations made while instrumentation is active keep a per-call
-    guard, so turning it off later silences them too.
-    """
-
-    def deco(f):
-        if not instrumentation_active():
-            return f  # true zero-cost: no wrapper, identical object
-        scope = name or f.__qualname__
-
-        @functools.wraps(f)
-        def wrapper(*args, **kwargs):
-            if not instrumentation_active():
-                return f(*args, **kwargs)
-            import jax
-
-            from .. import telemetry
-
-            t0 = time.perf_counter()
-            try:
-                with jax.named_scope(scope):
-                    return f(*args, **kwargs)
-            finally:
-                # record even when f raises — a span that vanishes on
-                # failure hides exactly the region being debugged
-                telemetry.record_event(
-                    scope, t0, time.perf_counter() - t0
-                )
-
-        return wrapper
-
-    return deco(fn) if fn is not None else deco
-
-
-@contextlib.contextmanager
-def add_trace_event(name: str):
-    """Context manager named-scope (reference add_nvtx_event); with
-    telemetry on the region is also recorded as a host-side span."""
-    if not instrumentation_active():
-        yield
-        return
-    import jax
-
-    from .. import telemetry
-
-    t0 = time.perf_counter()
-    try:
-        with jax.named_scope(name):
-            yield
-    finally:
-        telemetry.record_event(name, t0, time.perf_counter() - t0)
 
 
 def named_scope(name: str):
@@ -109,8 +25,8 @@ def named_scope(name: str):
     ``magi_stage0_cast``-style labels instead of anonymous fusions.
 
     Trace-time-only cost (nothing at run time, nothing recorded host-side),
-    so it is applied unconditionally — unlike :func:`add_trace_event`,
-    which also records host spans and must stay out of traced code."""
+    so it is applied unconditionally — unlike ``telemetry.span``, which
+    records host spans and must stay out of traced code."""
     import jax
 
     return jax.named_scope(name)

@@ -170,3 +170,81 @@ def test_two_layer_train_step_cp4(topo):
     )
     assert "tpu_custom_call" in text
     assert "all-to-all" in text or "collective-permute" in text
+
+
+@pytest.mark.parametrize("cp", [1, 4])
+def test_keyed_kernels_carry_role_names(topo, cp, monkeypatch):
+    """The keyed path's forward+backward program, as the benchmark's
+    attention cells compile it: every flex kernel is an HLO instruction
+    named by its role, the two roofline metrics' patterns still match
+    the scopes they matched, each per-kernel metric's pattern matches
+    one role, and the collectives carry the group cast's scope."""
+    import json
+    import re
+
+    from benchmarks import trace_reduce
+    from magiattention_tpu import api
+
+    def pattern(metric):
+        path = os.path.join(
+            os.path.dirname(__file__), "..", "benchmarks", "metrics",
+            metric + ".json",
+        )
+        with open(path) as f:
+            return re.compile(json.load(f)["source"]["pattern"])
+
+    # a described device takes no arrays: leave the plan's tables to jit
+    monkeypatch.setattr(jax, "device_put", lambda x, *_a, **_k: x)
+    api.clear_cache()
+    mesh = Mesh(np.array(topo.devices[:cp]), ("cp",))
+    sharded = NamedSharding(mesh, P("cp"))
+    t, hq, hk, d = 2048 * cp, 8, 2, 128
+    key = api.magi_attn_varlen_key(
+        [0, 500 * cp, 1300 * cp, t], t, mesh, num_heads=(hq, hk),
+        head_dim=d, out_dtype="bfloat16", interpret=False,
+    )
+
+    def fwd(q, k, v):
+        out, meta = api.calc_attn(q, k, v, key)
+        return out, meta.lse
+
+    def fwdbwd(q, k, v, d_out, d_lse):
+        _res, vjp = jax.vjp(fwd, q, k, v)
+        return vjp((d_out, d_lse))
+
+    text = _compile(
+        fwdbwd, _on(sharded, (t, hq, d)), _on(sharded, (t, hk, d)),
+        _on(sharded, (t, hk, d)), _on(sharded, (t, hq, d)),
+        _on(sharded, (t, hq), jnp.float32),
+    )
+    api.clear_cache()
+    scopes = trace_reduce.hlo_scopes(text)
+    kernels = {  # the custom calls themselves, by instruction name
+        name: scope for name, scope in scopes.items()
+        if name.startswith("magi_flex_")
+    }
+    roles = sorted(name.split(".")[0] for name in kernels)
+    assert roles == [
+        "magi_flex_dkv_kernel", "magi_flex_dq_kernel", "magi_flex_fwd_kernel",
+    ]
+    fwd_rx, bwd_rx = pattern("flex_fwd_roofline"), pattern("flex_bwd_roofline")
+    new = {
+        role: pattern(f"flex_{role}_kernel_ms") for role in ("fwd", "dq", "dkv")
+    }
+    for name, scope in kernels.items():
+        line = f"{name} {scope}"  # what trace_reduce.kernel_seconds matches
+        assert scope.endswith("/pallas_call")
+        backward = "_fwd_" not in name
+        assert bool(bwd_rx.search(line)) == backward, line
+        assert bool(fwd_rx.search(line)) == (not backward), line
+        hit = [role for role, rx in new.items() if rx.search(line)]
+        assert hit == [name.split("_")[2]], line
+    assert pattern("train_flex_kernel_share").search("magi_flex_dq_kernel.1 ")
+    # the plan's own choice of group-collective implementation (a2a at
+    # this size, hops in the benchmark's cp=4 cell)
+    collectives = [
+        scope for name, scope in scopes.items()
+        if name.startswith(("collective-permute", "all_to_all", "all-to-all"))
+    ]
+    assert bool(collectives) == (cp > 1)
+    assert all("magi_group_cast" in scope for scope in collectives)

@@ -235,7 +235,7 @@ def test_span_records_event_with_attrs():
     ev = [e for e in evs if e["name"] == "unit-span"][0]
     assert ev["ph"] == "X"
     assert ev["dur"] >= 0
-    assert ev["args"] == {"cp": 4}
+    assert ev["args"] == {"cp": 4, "id": ev["args"]["id"]}  # + its own id
 
 
 def test_plan_build_emits_span():

@@ -1,5 +1,6 @@
-"""utils/instrument.py: zero-cost passthrough gating, span emission, and
-the profile-mode default of switch_profile."""
+"""utils/instrument.py: the profile-mode default of switch_profile and
+named_scope (host spans are telemetry.span: tests/test_telemetry/
+test_span_tree.py)."""
 
 import pytest
 
@@ -14,90 +15,6 @@ def clean_telemetry():
     yield
     telemetry.set_enabled(None)
     telemetry.reset()
-
-
-def test_disabled_decorator_is_identity(monkeypatch):
-    monkeypatch.delenv("MAGI_ATTENTION_PROFILE_MODE", raising=False)
-    monkeypatch.delenv("MAGI_ATTENTION_TELEMETRY", raising=False)
-    telemetry.set_enabled(None)
-
-    def f(x):
-        return x + 1
-
-    assert instrument.instrument_trace(f) is f
-    assert instrument.instrument_trace(name="named")(f) is f
-
-
-def test_enabled_decorator_wraps_and_records():
-    telemetry.set_enabled(True)
-
-    @instrument.instrument_trace(name="traced-fn")
-    def f(x):
-        return x * 2
-
-    assert f.__wrapped__ is not None
-    assert f(3) == 6
-    evs = telemetry.get_event_buffer().events()
-    assert any(e["name"] == "traced-fn" for e in evs)
-
-
-def test_wrapper_goes_quiet_when_disabled_again():
-    telemetry.set_enabled(True)
-
-    @instrument.instrument_trace
-    def f():
-        return 1
-
-    f()
-    n = len(telemetry.get_event_buffer())
-    telemetry.set_enabled(False)
-    assert f() == 1  # still functional, just silent
-    assert len(telemetry.get_event_buffer()) == n
-
-
-def test_add_trace_event_disabled_no_events():
-    telemetry.set_enabled(False)
-    with instrument.add_trace_event("quiet"):
-        pass
-    assert len(telemetry.get_event_buffer()) == 0
-
-
-def test_add_trace_event_enabled_records():
-    telemetry.set_enabled(True)
-    with instrument.add_trace_event("loud"):
-        pass
-    assert any(
-        e["name"] == "loud"
-        for e in telemetry.get_event_buffer().events()
-    )
-
-
-def test_spans_survive_exceptions():
-    """A raising region must still land in the trace — that's exactly
-    the span being debugged."""
-    telemetry.set_enabled(True)
-
-    with pytest.raises(RuntimeError):
-        with instrument.add_trace_event("boom-ctx"):
-            raise RuntimeError("x")
-
-    @instrument.instrument_trace(name="boom-fn")
-    def f():
-        raise RuntimeError("y")
-
-    with pytest.raises(RuntimeError):
-        f()
-    names = [e["name"] for e in telemetry.get_event_buffer().events()]
-    assert "boom-ctx" in names and "boom-fn" in names
-
-
-def test_profile_mode_activates_instrumentation(monkeypatch):
-    telemetry.set_enabled(None)
-    monkeypatch.delenv("MAGI_ATTENTION_TELEMETRY", raising=False)
-    monkeypatch.setenv("MAGI_ATTENTION_PROFILE_MODE", "1")
-    assert instrument.instrumentation_active()
-    monkeypatch.setenv("MAGI_ATTENTION_PROFILE_MODE", "0")
-    assert not instrument.instrumentation_active()
 
 
 def test_switch_profile_noop_without_flag(monkeypatch):
