@@ -49,14 +49,30 @@ LANES = 128
 # (512, 768, 6) sparse). The tuner has no VMEM feasibility test of its
 # own, so the kernels ask for room instead.
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+# What the four f32 (head_block * block_q, block_k) intermediates of a
+# head-batched backward step may take of that (``_bwd_head_block``). The
+# count is a proxy for what Mosaic allocates, set by compiling for a v5e:
+# steps of 64 MiB compile ((512, 1024, 8) at 64Q/8KV hd128, (1024, 1024, 4)
+# at 32Q/8KV), steps of 128 MiB are refused, and the largest step a
+# row-major rung of the tuner asks for, (256, 1024, 2) snapped to
+# head_block 8 at group 8, is 32 MiB.
+_BWD_HB_LIVE_BYTES = _VMEM_LIMIT_BYTES // 2
 
 
-# Every pallas_call below carries name= by role, not by grid kind:
-# magi_flex_fwd_kernel, magi_flex_dq_kernel, magi_flex_dkv_kernel. The
-# name enters the custom call's jax scope (.../magi_flex_dq_kernel/
-# pallas_call), which is what a device trace and the benchmark's per-kernel
-# metrics read; keep it matching magi_\w*kernel, the roofline metrics'
-# pattern.
+def _flex_pallas_call(role: str, heads_per_step: int, body, **kwargs):
+    """Where every flex ``pallas_call`` is built (trace time). The call is
+    named by role, not by grid kind: magi_flex_fwd_kernel,
+    magi_flex_dq_kernel, magi_flex_dkv_kernel. The name enters the custom
+    call's jax scope (.../magi_flex_dq_kernel/pallas_call), which is what a
+    device trace and the benchmark's per-kernel metrics read; keep it
+    matching magi_\\w*kernel, the roofline metrics' pattern. The build is
+    counted with the q heads one grid step takes
+    (``magi_flex_kernel_build_total{kernel=, heads_per_step=}``), so a
+    snapshot says which of the per-head and head-batched forms ran."""
+    from .. import telemetry
+
+    telemetry.record_flex_kernel_build(role, heads_per_step)
+    return pl.pallas_call(body, name=f"magi_flex_{role}_kernel", **kwargs)
 
 
 def _compiler_params(*dimension_semantics: str):
@@ -79,9 +95,14 @@ GRID_KINDS = ("row_major", "sparse")
 class FlexAttnParams:
     """Static parameters closed over by the kernels (hashable).
 
-    ``head_block``: q heads processed per grid step (1 = head-per-step).
-    Batching heads amortizes per-step grid overhead — the dominant cost on
-    small tiles — at the price of head_block x VMEM. Must be 1 or a
+    ``head_block``: q heads processed per grid step (1 = head-per-step),
+    by the forward on both grids and by the row-major dq and dkv (dkv takes
+    the ``head_block // group`` kv heads' groups, so its ``group`` grid
+    dimension is inside the step). Batching heads amortizes per-step grid
+    overhead — the dominant cost on small tiles — and fetches a K/V tile
+    once for the group, at the price of head_block x VMEM: a backward
+    step too large for it stays per head (``_bwd_head_block``, a test on
+    shapes), as the sparse grid's backward always does. Must be 1 or a
     multiple of the GQA group size.
 
     ``fwd_steps``/``bwd_steps``: static inner-grid extents — the max
@@ -278,6 +299,41 @@ def _scores(q, k, scale, softcap):
     return z
 
 
+def _scores_hb(q_ref, k_ref, params: FlexAttnParams, group: int):
+    """Head-batched logits, f32 (HB, G*bq, bk): the (HBG, bq, d) q block's
+    rows are stacked per kv head, so QK^T over the HB kv heads of the step
+    is one batched MXU call."""
+    hb = k_ref.shape[0]
+    q = q_ref[...].reshape(hb, group * params.block_q, q_ref.shape[2])
+    s = jax.lax.dot_general(
+        q,
+        k_ref[...],
+        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * jnp.float32(params.scale)
+    if params.softcap > 0.0:
+        s = jnp.float32(params.softcap) * jnp.tanh(
+            s / jnp.float32(params.softcap)
+        )
+    return s
+
+
+def _mask_hb(s, mask, group: int):
+    """-inf off the entry's (bq, bk) mask, computed once per tile and
+    broadcast over the (HB, G) heads of head-batched logits."""
+    hb, rows, bk = s.shape
+    s4 = s.reshape(hb, group, rows // group, bk)
+    s4 = jnp.where(mask[None, None], s4, NEG_INF)
+    return s4.reshape(hb, rows, bk)
+
+
+def _check_head_block(hbg: int, hq: int, group: int) -> None:
+    assert hbg % group == 0 and hq % hbg == 0, (
+        f"head_block {hbg} must be a multiple of the GQA group {group} and "
+        f"divide hq {hq}"
+    )
+
+
 # ---------------------------------------------------------------------------
 # forward (head-batched variant)
 # ---------------------------------------------------------------------------
@@ -332,24 +388,11 @@ def _fwd_kernel_hb(
 
     @pl.when(j < rc[i])
     def _compute():
-        q = q_ref[...].reshape(hb, group * bq, q_ref.shape[2])
-        s = jax.lax.dot_general(
-            q,
-            k_ref[...],
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * jnp.float32(params.scale)  # (HB, G*bq, bk)
-        if params.softcap > 0.0:
-            s = jnp.float32(params.softcap) * jnp.tanh(
-                s / jnp.float32(params.softcap)
-            )
-
+        s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
             bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
         )
-        s4 = s.reshape(hb, group, bq, bk)
-        s4 = jnp.where(mask[None, None], s4, NEG_INF)
-        s = s4.reshape(hb, group * bq, bk)
+        s = _mask_hb(s, mask, group)
 
         m_prev = m_scr[:, :, :1]  # (HB, G*bq, 1)
         m_cur = jnp.max(s, axis=2, keepdims=True)
@@ -415,10 +458,7 @@ def _fwd_pallas_hb(q, k, v, sink2d, tables, params: FlexAttnParams):
     hk = k.shape[0]
     group = hq // hk
     hbg = params.head_block
-    assert hbg % group == 0 and hq % hbg == 0, (
-        f"head_block {hbg} must be a multiple of the GQA group {group} and "
-        f"divide hq {hq}"
-    )
+    _check_head_block(hbg, hq, group)
     hb = hbg // group
     bq, bk = params.block_q, params.block_k
     nq = tqp // bq
@@ -452,9 +492,10 @@ def _fwd_pallas_hb(q, k, v, sink2d, tables, params: FlexAttnParams):
             pltpu.VMEM((hb, group * bq, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "fwd",
+        hbg,
         functools.partial(_fwd_kernel_hb, params=params, group=group),
-        name="magi_flex_fwd_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
@@ -609,9 +650,10 @@ def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "fwd",
+        1,
         functools.partial(_fwd_kernel, params=params),
-        name="magi_flex_fwd_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
@@ -833,9 +875,10 @@ def _fwd_pallas_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "fwd",
+        1,
         functools.partial(_fwd_kernel_sparse, params=params),
-        name="magi_flex_fwd_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
@@ -892,23 +935,11 @@ def _fwd_kernel_hb_sparse(
         acc_scr[...] = jnp.zeros_like(acc_scr)
         mx_scr[...] = jnp.full_like(mx_scr, NEG_INF)
 
-    q_ = q_ref[...].reshape(hb, group * bq, q_ref.shape[2])
-    s = jax.lax.dot_general(
-        q_,
-        k_ref[...],
-        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * jnp.float32(params.scale)  # (HB, G*bq, bk)
-    if params.softcap > 0.0:
-        s = jnp.float32(params.softcap) * jnp.tanh(
-            s / jnp.float32(params.softcap)
-        )
+    s = _scores_hb(q_ref, k_ref, params, group)
     mask = _entry_interval_mask(
         bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
     )
-    s4 = s.reshape(hb, group, bq, bk)
-    s4 = jnp.where(mask[None, None], s4, NEG_INF)
-    s = s4.reshape(hb, group * bq, bk)
+    s = _mask_hb(s, mask, group)
 
     m_new, l_new, acc_new = _amla_update(
         s,
@@ -962,10 +993,7 @@ def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
     hk = k.shape[0]
     group = hq // hk
     hbg = params.head_block
-    assert hbg % group == 0 and hq % hbg == 0, (
-        f"head_block {hbg} must be a multiple of the GQA group {group} and "
-        f"divide hq {hq}"
-    )
+    _check_head_block(hbg, hq, group)
     hb = hbg // group
     bq, bk = params.block_q, params.block_k
     E = qblk.shape[0]
@@ -999,9 +1027,10 @@ def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
             pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "fwd",
+        hbg,
         functools.partial(_fwd_kernel_hb_sparse, params=params, group=group),
-        name="magi_flex_fwd_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
@@ -1018,26 +1047,57 @@ def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params: FlexAttnParams):
-    """Shared backward core for all four bwd kernel bodies (row-major +
-    sparse, dq + dkv): probabilities from the stored lse and the masked
-    logits, then ``ds = p * (dP - delta)`` with the softcap derivative
-    and the off-mask NaN guard. This block is numerically delicate and
-    MUST stay in lockstep across grids — one copy only."""
-    lse = lse_ref[0][:, :1]
+def _bwd_p_ds(
+    s, lse_ref, do_ref, v_ref, delta_ref, params: FlexAttnParams, hb=None
+):
+    """Shared backward core for all six bwd kernel bodies (row-major,
+    head-batched row-major and sparse; dq + dkv): probabilities from the
+    stored lse and the masked logits, then ``ds = p * (dP - delta)`` with
+    the softcap derivative and the off-mask NaN guard. This block is
+    numerically delicate and MUST stay in lockstep across grids — one
+    copy only.
+
+    ``hb=None``: the per-head kernels' (1, rows, .) blocks and 2-D ``s``.
+    ``hb=HB``: the head-batched kernels' blocks, the q-side ones
+    (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``."""
+
+    def rows(ref):
+        if hb is None:
+            return ref[0]
+        return ref[...].reshape(hb, -1, ref.shape[2])
+
+    nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
+    lse = rows(lse_ref)[..., :1]
     lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
     p = jnp.exp(s - lse_safe)
     dp = jax.lax.dot_general(
-        do_ref[0],
-        v_ref[0],
-        dimension_numbers=(((1,), (1,)), ((), ())),
+        rows(do_ref),
+        v_ref[0] if hb is None else v_ref[...],
+        dimension_numbers=(
+            ((nb + 1,), (nb + 1,)),
+            (tuple(range(nb)), tuple(range(nb))),
+        ),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta_ref[0][:, :1])
+    ds = p * (dp - rows(delta_ref)[..., :1])
     if params.softcap > 0.0:
         ds = ds * (1.0 - (s / jnp.float32(params.softcap)) ** 2)
         ds = jnp.where(jnp.isneginf(s), 0.0, ds)  # nan guard off-mask
     return p, ds
+
+
+def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
+    """q heads one row-major backward step takes: ``params.head_block``
+    (what the forward takes, and what the tuner's cost model prices every
+    kernel at), or 1 where the batched step does not fit the VMEM the
+    kernels ask for. The test is on shapes alone: the step keeps four f32
+    (head_block * block_q, block_k) intermediates live (s, p, dP, dS)."""
+    hbg = params.head_block
+    if hbg <= 1:
+        return 1
+    _check_head_block(hbg, hq, group)
+    live = 4 * 4 * hbg * params.block_q * params.block_k
+    return hbg if live <= _BWD_HB_LIVE_BYTES else 1
 
 
 def _dq_kernel(
@@ -1090,6 +1150,60 @@ def _dq_kernel(
     @pl.when(j == steps - 1)
     def _write():
         dq_ref[0] = dq_scr[...]
+
+
+def _dq_kernel_hb(
+    qblk,
+    kblk,
+    sid,
+    runs,
+    bounds,
+    rs,
+    rc,
+    q_ref,  # (HBG, bq, d)
+    k_ref,  # (HB, bk, d)
+    v_ref,
+    do_ref,  # (HBG, bq, d)
+    lse_ref,  # (HBG, bq, LANES)
+    delta_ref,
+    dq_ref,  # (HBG, bq, d)
+    dq_scr,  # (HB, G*bq, d)
+    *,
+    params: FlexAttnParams,
+    group: int,
+):
+    """Head-batched dq, the layout of :func:`_fwd_kernel_hb`: one K/V
+    tile serves the HB kv heads' G q heads each, so QK^T, dO V^T and
+    dS K are one batched MXU call each over (HB, G*bq) stacked rows."""
+    bq, bk = params.block_q, params.block_k
+    hb = k_ref.shape[0]
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    steps = pl.num_programs(2)
+    e = _clamped_entry(rs, rc, i, j)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    @pl.when(j < rc[i])
+    def _compute():
+        s = _scores_hb(q_ref, k_ref, params, group)
+        mask = _entry_interval_mask(
+            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
+        )
+        s = _mask_hb(s, mask, group)
+        _, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
+        dq_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
+            ds.astype(k_ref.dtype),
+            k_ref[...],
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(j == steps - 1)
+    def _write():
+        dq_ref[...] = dq_scr[...].reshape(dq_ref.shape)
 
 
 def _dq_kernel_sparse(
@@ -1173,9 +1287,10 @@ def _dq_pallas_sparse(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         out_specs=pl.BlockSpec((1, bq, d), qmap),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "dq",
+        1,
         functools.partial(_dq_kernel_sparse, params=params),
-        name="magi_flex_dq_kernel",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, tqp, d), jnp.float32),
         interpret=params.interpret,
@@ -1194,31 +1309,48 @@ def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     nq = tqp // bq
     steps = _resolve_steps(params.fwd_steps, qblk, nq)
     rs, rc = _row_tables(qblk, nq)
+    hbg = _bwd_head_block(params, hq, group)
 
     def qmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
         return (h, i, 0)
 
-    def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        e = _clamped_entry(rs, rc, i, j)
-        return (h // group, kb[e], 0)
+    if hbg > 1:
+        # head-batched: grid (hq/HBG, nq, steps), the forward's layout
+        hb = hbg // group
+        body = functools.partial(_dq_kernel_hb, params=params, group=group)
+        scratch = pltpu.VMEM((hb, group * bq, d), jnp.float32)
+
+        def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
+            e = _clamped_entry(rs, rc, i, j)
+            return (h, kb[e], 0)
+
+    else:
+        hb = 1
+        body = functools.partial(_dq_kernel, params=params)
+        scratch = pltpu.VMEM((bq, d), jnp.float32)
+
+        def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
+            e = _clamped_entry(rs, rc, i, j)
+            return (h // group, kb[e], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(hq, nq, steps),
+        grid=(hq // hbg, nq, steps),
         in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
+            pl.BlockSpec((hbg, bq, d), qmap),
+            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hbg, bq, d), qmap),
+            pl.BlockSpec((hbg, bq, LANES), qmap),
+            pl.BlockSpec((hbg, bq, LANES), qmap),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), qmap),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_specs=pl.BlockSpec((hbg, bq, d), qmap),
+        scratch_shapes=[scratch],
     )
-    return pl.pallas_call(
-        functools.partial(_dq_kernel, params=params),
-        name="magi_flex_dq_kernel",
+    return _flex_pallas_call(
+        "dq",
+        hbg,
+        body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, tqp, d), jnp.float32),
         interpret=params.interpret,
@@ -1296,6 +1428,73 @@ def _dkv_kernel(
     def _write():
         dk_ref[0] = dk_scr[...]
         dv_ref[0] = dv_scr[...]
+
+
+def _dkv_kernel_hb(
+    kblk,
+    qblk,
+    sid,
+    runs,
+    bounds,
+    rs,
+    rc,
+    q_ref,  # (HBG, bq, d)
+    k_ref,  # (HB, bk, d)
+    v_ref,
+    do_ref,  # (HBG, bq, d)
+    lse_ref,  # (HBG, bq, LANES)
+    delta_ref,
+    dk_ref,  # (HB, bk, d)
+    dv_ref,
+    dk_scr,
+    dv_scr,
+    *,
+    params: FlexAttnParams,
+    group: int,
+):
+    """Head-batched dkv, k-major row grid (hk/HB, nk, steps): the
+    per-head kernel's innermost ``group`` grid dimension is inside the
+    step. dv += P^T dO and dk += dS^T Q contract over the G*bq stacked
+    rows of each kv head's group; K, V and the two accumulators stay
+    resident per k block."""
+    bq, bk = params.block_q, params.block_k
+    hb = k_ref.shape[0]
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    steps = pl.num_programs(2)
+    e = _clamped_entry(rs, rc, i, j)
+
+    @pl.when(j == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(j < rc[i])
+    def _compute():
+        s = _scores_hb(q_ref, k_ref, params, group)
+        mask = _entry_interval_mask(
+            bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk
+        )
+        s = _mask_hb(s, mask, group)
+        p, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
+        rows_t = (((1,), (1,)), ((0,), (0,)))  # contract the stacked rows
+        dv_scr[...] += jax.lax.dot_general(
+            p.astype(do_ref.dtype),
+            do_ref[...].reshape(hb, group * bq, do_ref.shape[2]),
+            dimension_numbers=rows_t,
+            preferred_element_type=jnp.float32,
+        )
+        dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
+            ds.astype(q_ref.dtype),
+            q_ref[...].reshape(hb, group * bq, q_ref.shape[2]),
+            dimension_numbers=rows_t,
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(j == steps - 1)
+    def _write():
+        dk_ref[...] = dk_scr[...]
+        dv_ref[...] = dv_scr[...]
 
 
 def _dkv_kernel_sparse(
@@ -1397,9 +1596,10 @@ def _dkv_pallas_sparse(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    return _flex_pallas_call(
+        "dkv",
+        1,
         functools.partial(_dkv_kernel_sparse, params=params, group=group),
-        name="magi_flex_dkv_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
@@ -1421,37 +1621,56 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     nk = tkp // bk
     steps = _resolve_steps(params.bwd_steps, kblk, nk)
     rs, rc = _row_tables(kblk, nk)
+    hbg = _bwd_head_block(params, hq, group)
 
-    def qmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
-        e = _clamped_entry(rs, rc, i, j)
-        return (h * group + g, qb[e], 0)
+    if hbg > 1:
+        # head-batched: the group is inside the step, grid (hk/HB, nk, steps)
+        hb = hbg // group
+        body = functools.partial(_dkv_kernel_hb, params=params, group=group)
+        grid = (hk // hb, nk, steps)
+        kv_scratch = pltpu.VMEM((hb, bk, d), jnp.float32)
 
-    def kmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
-        return (h, i, 0)
+        def qmap(h, i, j, kb, qb, si, ru, bo, rs, rc):
+            e = _clamped_entry(rs, rc, i, j)
+            return (h, qb[e], 0)
+
+        def kmap(h, i, j, kb, qb, si, ru, bo, rs, rc):
+            return (h, i, 0)
+
+    else:
+        hb = 1
+        body = functools.partial(_dkv_kernel, params=params, group=group)
+        grid = (hk, nk, steps, group)
+        kv_scratch = pltpu.VMEM((bk, d), jnp.float32)
+
+        def qmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
+            e = _clamped_entry(rs, rc, i, j)
+            return (h * group + g, qb[e], 0)
+
+        def kmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
+            return (h, i, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(hk, nk, steps, group),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
+            pl.BlockSpec((hbg, bq, d), qmap),
+            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hbg, bq, d), qmap),
+            pl.BlockSpec((hbg, bq, LANES), qmap),
+            pl.BlockSpec((hbg, bq, LANES), qmap),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
+            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hb, bk, d), kmap),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
+        scratch_shapes=[kv_scratch, kv_scratch],
     )
-    return pl.pallas_call(
-        functools.partial(_dkv_kernel, params=params, group=group),
-        name="magi_flex_dkv_kernel",
+    return _flex_pallas_call(
+        "dkv",
+        hbg,
+        body,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
@@ -1459,7 +1678,7 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         ],
         interpret=params.interpret,
         compiler_params=_compiler_params(
-            "parallel", "parallel", "arbitrary", "arbitrary"
+            "parallel", "parallel", *["arbitrary"] * (len(grid) - 2)
         ),
     )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
 

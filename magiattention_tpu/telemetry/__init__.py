@@ -68,6 +68,7 @@ from .collectors import (  # noqa: F401
     record_decode_step,
     record_degraded_path,
     record_dispatch_meta,
+    record_flex_kernel_build,
     record_dispatch_solution,
     record_dynamic_solution,
     record_group_collective_build,
@@ -344,6 +345,7 @@ __all__ = [
     "record_decode_step",
     "record_degraded_path",
     "record_dispatch_meta",
+    "record_flex_kernel_build",
     "record_dispatch_solution",
     "record_dynamic_solution",
     "record_event",

@@ -114,6 +114,10 @@ M_AUTOTUNE_PREDICTED_MS = "magi_autotune_predicted_ms"
 M_AUTOTUNE_MEASURED_MS = "magi_autotune_measured_ms"
 # which rung the last decision chose and why: value 1, labels rung=/source=
 M_AUTOTUNE_CHOICE = "magi_autotune_choice"
+# flex pallas_calls built (trace time), by role and by the q heads one
+# grid step takes: {kernel=fwd|dq|dkv, heads_per_step=}. Beside the
+# head_block gauge above it says whether each kernel honoured the choice
+M_FLEX_KERNEL_BUILDS = "magi_flex_kernel_build_total"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1084,6 +1088,16 @@ def record_autotune_decision(decision) -> None:
             "fingerprint": decision.fingerprint_hash,
             "reason": decision.reason,
         },
+    )
+
+
+def record_flex_kernel_build(kernel: str, heads_per_step: int) -> None:
+    """One flex ``pallas_call`` built (``ops/flex_attn._flex_pallas_call``,
+    while jax traces the caller — never inside a compiled step)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(
+        M_FLEX_KERNEL_BUILDS, kernel=kernel, heads_per_step=heads_per_step
     )
 
 

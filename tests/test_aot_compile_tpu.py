@@ -83,6 +83,55 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize(
+    "t,hq,hk,rung",
+    [
+        (65536, 64, 8, (128, 512, 8)),
+        (16384, 32, 8, (128, 512, 8)),
+        (16384, 64, 8, (256, 1024, 8)),
+    ],
+    ids=["varlen-cell-64x8", "train-cell-32x8", "largest-tuner-step"],
+)
+def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung):
+    """The head-batched dq and dkv at the blocking the tuner gives the
+    benchmark's packed cells, (128, 512, 8) at head_dim 128: group 8 is
+    one kv head a step, group 4 two (the batched transposed contraction).
+    And at the largest step a row-major rung of the tuner asks for:
+    (256, 1024, 2), whose head_block snaps to 8 at group 8. They fit the
+    VMEM the kernels ask for, and it is the batched programs that were
+    built, not the per-head fallback."""
+    from magiattention_tpu import telemetry
+
+    d = 128
+    qr, kr, ts = ranges_of(varlen_block_causal(t))
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid="row_major", block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    reg = telemetry.get_registry()
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    reg.clear_metric("magi_flex_kernel_build_total")
+    try:
+        text = _compile(
+            jax.value_and_grad(loss, argnums=(0, 1, 2)),
+            _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+        )
+        for kernel in ("fwd", "dq", "dkv"):
+            assert reg.counter_value(
+                "magi_flex_kernel_build_total", kernel=kernel, heads_per_step=8
+            ) >= 1, kernel
+    finally:
+        reg.clear_metric("magi_flex_kernel_build_total")
+        telemetry.set_enabled(was)
+    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+
 def _serve_cache(chip, hk, d):
     """The smoke's serve-phase pool: 128k tokens, default page size."""
     from magiattention_tpu import env

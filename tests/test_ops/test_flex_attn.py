@@ -4,6 +4,8 @@ Model: reference tests/test_attn/test_flex_flash_attn.py — kernel vs oracle
 over a grid of mask scenarios × head configs × features.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -340,3 +342,97 @@ def test_auto_block_config_long_keys_short_queries():
         1024,
         1024,
     )
+
+
+# -- the head-batched backward (ISSUE 25) -----------------------------------
+# Four documents, one of each mask type, none aligned to the 64-token
+# blocks; rows 300..384 attend to nothing, so q block 5 has no entry at all.
+_HB_T = 384
+_HB_MASK = (
+    [(0, 90), (90, 170), (170, 250), (250, 300)],
+    [(0, 90), (90, 170), (150, 250), (230, 300)],
+    [F, C, I, B],
+)
+
+
+@functools.lru_cache(maxsize=None)  # the references repeat across cases
+def _hb_bwd_grads(hq, hk, head_block, softcap, traced, d=32):
+    """dq, dk, dv, dsink of a loss that reads out AND lse (a non-zero lse
+    cotangent) through the Pallas kernels at ``head_block``. ``traced``:
+    the tables are jit arguments and the grid extents come from
+    ``FlexAttnParams.fwd_steps``/``bwd_steps``, as on the keyed path."""
+    from magiattention_tpu.ops import flex_attn as fa
+
+    qr, kr, ts = _HB_MASK
+    q, k, v = _rand_qkv(_HB_T, _HB_T, hq, hk, d, seed=11)
+    rng = np.random.default_rng(12)
+    do = jnp.asarray(rng.standard_normal((_HB_T, hq, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((_HB_T, hq)), jnp.float32)
+    sink = jnp.asarray(rng.standard_normal(hq), jnp.float32)
+
+    def loss_of(out, lse):
+        return (out * do).sum() + (jnp.where(jnp.isneginf(lse), 0.0, lse) * w).sum()
+
+    if head_block is None:  # the jnp oracle
+
+        def loss(q, k, v, sink):
+            out, lse, _ = ref_attn_from_ranges(
+                q, k, v, qr, kr, ts, sink=sink, softcap=softcap
+            )
+            return loss_of(out, lse)
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, v, sink)
+
+    if not traced:
+
+        def loss(q, k, v, sink):
+            out, lse = flex_flash_attn_func(
+                q, k, v, qr, kr, ts, sink=sink, softcap=softcap,
+                block_q=64, block_k=64, head_block=head_block, interpret=True,
+            )
+            return loss_of(out, lse)
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(q, k, v, sink)
+
+    meta = build_block_meta(
+        qr, kr, [t.value for t in ts], _HB_T, _HB_T, block_q=64, block_k=64
+    )
+    params = fa.FlexAttnParams(
+        block_q=64, block_k=64, scale=d**-0.5, softcap=float(softcap),
+        has_sink=True, out_dtype="float32", interpret=True,
+        head_block=head_block, fwd_steps=meta.fwd_steps,
+        bwd_steps=meta.bwd_steps,
+    )
+
+    def loss(q, k, v, sink, ftab, btab):
+        out_h, lse_lanes, _ = fa.flex_attn_headmajor(
+            jnp.transpose(q, (1, 0, 2)), jnp.transpose(k, (1, 0, 2)),
+            jnp.transpose(v, (1, 0, 2)), ftab, btab, params, sink=sink,
+        )
+        return loss_of(jnp.transpose(out_h, (1, 0, 2)), lse_lanes[:, :, 0].T)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        q, k, v, sink, fa.fwd_tables(meta), fa.bwd_tables(meta)
+    )
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["concrete", "traced"])
+@pytest.mark.parametrize("softcap", [0.0, 8.0], ids=["nocap", "softcap"])
+@pytest.mark.parametrize("heads", [1, 2], ids=["hb=g", "hb=2g"])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_head_batched_bwd_matches_per_head_and_oracle(
+    group, heads, softcap, traced
+):
+    """dq, dk, dv, dsink of the head-batched dq / dkv kernels against the
+    per-head kernels and against the jnp oracle."""
+    hk = 2
+    hq = hk * group
+    got = _hb_bwd_grads(hq, hk, heads * group, softcap, traced)
+    per_head = _hb_bwd_grads(hq, hk, 1, softcap, traced)
+    oracle = _hb_bwd_grads(hq, hk, None, softcap, False)
+    for a, b, c, nm in zip(got, per_head, oracle, ["dq", "dk", "dv", "dsink"]):
+        assert np.isfinite(np.asarray(a)).all(), nm
+        assert_close(a, b, atol=2e-5, rtol=2e-5, msg=f"{nm} vs per-head")
+        assert_close(a, c, atol=5e-5, rtol=5e-5, msg=f"{nm} vs oracle")
+    # rows 300.. attend to nothing: their dq is exactly zero
+    assert not np.asarray(got[0])[300:].any()

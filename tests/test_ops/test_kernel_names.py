@@ -35,3 +35,100 @@ def test_lowered_fwd_bwd_holds_the_three_kernel_names(grid, head_block):
     assert set(re.findall(r"magi_flex_\w+_kernel", text)) == NAMES
     # all of them match the roofline metrics' kernel pattern
     assert all(re.fullmatch(r"magi_\w*kernel", name) for name in NAMES)
+
+
+def _pallas_calls(jaxpr):
+    """(name, grid) of every pallas_call in a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grid = tuple(eqn.params["grid_mapping"].grid)
+            found.append((eqn.params["name"], grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def _grad_fn(t, head_block, block_q, block_k):
+    qr, kr, ts = ranges_of(varlen_block_causal(t))
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid="row_major", head_block=head_block,
+            block_q=block_q, block_k=block_k, interpret=True,
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("hq,hk,head_block", [(8, 2, 4), (8, 2, 8), (4, 4, 2)])
+def test_head_block_is_the_leading_grid_dimension_of_all_three(
+    hq, hk, head_block
+):
+    """At head_block > 1 the row-major dq and dkv take head_block q heads a
+    grid step, as the forward does: one call of each name, and
+    hq // head_block resp. hk // (head_block // group) leading."""
+    t, d = 512, 64
+    q = jnp.ones((t, hq, d), jnp.float32)
+    kv = jnp.ones((t, hk, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(_grad_fn(t, head_block, 128, 128))(q, kv, kv)
+    grids = dict(_pallas_calls(jaxpr.jaxpr))
+    assert set(grids) == NAMES
+    assert len(_pallas_calls(jaxpr.jaxpr)) == 3
+    group = hq // hk
+    nq = nk = t // 128
+    assert grids["magi_flex_fwd_kernel"][:2] == (hq // head_block, nq)
+    assert grids["magi_flex_dq_kernel"][:2] == (hq // head_block, nq)
+    assert len(grids["magi_flex_dq_kernel"]) == 3
+    assert grids["magi_flex_dkv_kernel"][:2] == (
+        hk // (head_block // group), nk,
+    )
+    assert len(grids["magi_flex_dkv_kernel"]) == 3  # the group is in the step
+
+
+def _builds():
+    from magiattention_tpu import telemetry
+
+    return {
+        key: int(val)
+        for key, val in telemetry.get_registry().snapshot()["counters"].items()
+        if key.startswith("magi_flex_kernel_build_total")
+    }
+
+
+@pytest.fixture
+def telemetry_on():
+    from magiattention_tpu import telemetry
+
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    telemetry.get_registry().clear_metric("magi_flex_kernel_build_total")
+    yield
+    telemetry.get_registry().clear_metric("magi_flex_kernel_build_total")
+    telemetry.set_enabled(was)
+
+
+@pytest.mark.parametrize(
+    "head_block,block,bwd_heads",
+    [
+        (1, (128, 128), 1),
+        (4, (128, 128), 4),
+        # four f32 (4*2048, 2048) intermediates are 256 MiB: past what the
+        # kernels ask of VMEM, so the backward stays per head (by shapes)
+        (4, (2048, 2048), 1),
+    ],
+)
+def test_build_counter_carries_heads_per_step(
+    telemetry_on, head_block, block, bwd_heads
+):
+    t, hq, hk, d = 4096, 8, 2, 64
+    q = jnp.ones((t, hq, d), jnp.float32)
+    kv = jnp.ones((t, hk, d), jnp.float32)
+    jax.make_jaxpr(_grad_fn(t, head_block, *block))(q, kv, kv)
+    name = "magi_flex_kernel_build_total"
+    assert _builds() == {
+        f"{name}{{heads_per_step={head_block},kernel=fwd}}": 1,
+        f"{name}{{heads_per_step={bwd_heads},kernel=dq}}": 1,
+        f"{name}{{heads_per_step={bwd_heads},kernel=dkv}}": 1,
+    }
