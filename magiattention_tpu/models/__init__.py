@@ -8,6 +8,14 @@ from .dit import (
     init_dit_params,
 )
 from .llama import LlamaConfig, MagiLlama, build_magi_llama, init_params
+from .pattern import (
+    MagiPattern,
+    PatternConfig,
+    afmoe_config,
+    build_magi_pattern,
+    init_pattern_params,
+    llama_pattern,
+)
 from .llama_pp import (
     MagiLlamaPP,
     build_magi_llama_pp,
@@ -21,12 +29,18 @@ __all__ = [
     "MagiDiT",
     "MagiLlama",
     "MagiLlamaPP",
+    "MagiPattern",
+    "PatternConfig",
+    "afmoe_config",
     "build_magi_dit",
     "build_magi_llama",
     "build_magi_llama_pp",
+    "build_magi_pattern",
     "chunk_causal_mask",
     "init_dit_params",
     "init_params",
+    "init_pattern_params",
+    "llama_pattern",
     "init_pp_params",
     "stack_layer_params",
 ]

@@ -50,44 +50,9 @@ def sharded_plan_tables(plan, mesh, cp_axis):
     return tuple(tables)
 
 
-@telemetry.span("plan_flex_attn")  # one span a call, as a key's key_build
-def plan_flex_attn(
-    cfg,
-    mesh,
-    total_seqlen,
-    q_ranges,
-    k_ranges,
-    attn_type_map,
-    *,
-    chunk_size: int,
-    cp_axis,
-    tp_axis: str | None = None,
-    block_q: int | None = None,
-    block_k: int | None = None,
-    interpret: bool | None = None,
-    overlap_config=None,
-):
-    """Shared builder tail for every Llama-family bundle: validate tp
-    divisibility, build the dispatch meta + CP plan for one mask, and
-    derive the kernel params. Returns (plan, attn_params, dispatch_meta).
-
-    ``cp_axis`` may be an ``(inter, intra)`` mesh-axis pair: the plan is
-    then built with hierarchical 2-level comm (``cp_mesh_shape``) and the
-    runtime routes casts through the two-hop dedup path (comm/hier.py).
-    ``overlap_config`` forces the overlap degree/algorithm (default:
-    OverlapConfig(), i.e. the degree-0 merged no-overlap path; pass
-    degree=None for the auto-tuned degree)."""
-    from ..common.enum import AttnMaskType
-    from ..meta.dispatch_meta import make_dispatch_meta_from_qk_ranges
-    from ..parallel.dist_attn import build_dist_attn_plan, make_attn_params
-
-    if tp_axis is not None:
-        tp = mesh.shape[tp_axis]
-        if cfg.n_heads % tp or cfg.n_kv_heads % tp:
-            raise ValueError(
-                f"tp={tp} must divide n_heads={cfg.n_heads} and "
-                f"n_kv_heads={cfg.n_kv_heads}"
-            )
+def _cp_geometry(mesh, cp_axis):
+    """(cp_size, cp_mesh_shape) of ``cp_axis``: one mesh axis, or an
+    ``(inter, intra)`` pair (hierarchical 2-level comm)."""
     names = cp_axis_names(cp_axis)
     assert len(names) in (1, 2), (
         f"cp_axis must be one mesh axis or an (inter, intra) pair, got "
@@ -101,15 +66,31 @@ def plan_flex_attn(
         if len(names) == 2
         else None
     )
-    mq, _, bucket = make_dispatch_meta_from_qk_ranges(
-        q_ranges,
-        k_ranges,
-        [AttnMaskType(int(t)) for t in attn_type_map],
-        total_seqlen,
-        total_seqlen,
-        chunk_size=chunk_size,
-        cp_size=cp_size,
-    )
+    return cp_size, cp_mesh_shape
+
+
+def _check_tp(cfg, mesh, tp_axis) -> None:
+    if tp_axis is not None:
+        tp = mesh.shape[tp_axis]
+        if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+            raise ValueError(
+                f"tp={tp} must divide n_heads={cfg.n_heads} and "
+                f"n_kv_heads={cfg.n_kv_heads}"
+            )
+
+
+def _plan_on_dispatch(
+    cfg, mesh, mq, bucket, total_seqlen, q_ranges, k_ranges, attn_type_map,
+    *, cp_axis, tp_axis, block_q, block_k, interpret, overlap_config, kind,
+):
+    """Tile choice, CP plan and kernel parameters of ONE mask on a solved
+    dispatch (``mq``, and the mask's ``bucket`` on its chunks)."""
+    from ..parallel.dist_attn import build_dist_attn_plan, make_attn_params
+
+    cp_size, cp_mesh_shape = _cp_geometry(mesh, cp_axis)
+    if kind is not None:
+        telemetry.annotate_span(kind=kind)
+        telemetry.record_model_attn_plan(kind)
     bq, bk, hb = resolve_harness_blocking(
         cfg, mesh, tp_axis,
         q_ranges.to_naive_ranges(),
@@ -133,7 +114,102 @@ def plan_flex_attn(
             interpret=interpret,
             head_block=hb,
         )
+    return plan, attn_params
+
+
+@telemetry.span("plan_flex_attn")  # one span a call, as a key's key_build
+def plan_flex_attn(
+    cfg,
+    mesh,
+    total_seqlen,
+    q_ranges,
+    k_ranges,
+    attn_type_map,
+    *,
+    chunk_size: int,
+    cp_axis,
+    tp_axis: str | None = None,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool | None = None,
+    overlap_config=None,
+    kind: str | None = None,
+):
+    """Shared builder tail for every Llama-family bundle: validate tp
+    divisibility, build the dispatch meta + CP plan for one mask, and
+    derive the kernel params. Returns (plan, attn_params, dispatch_meta).
+
+    ``cp_axis`` may be an ``(inter, intra)`` mesh-axis pair: the plan is
+    then built with hierarchical 2-level comm (``cp_mesh_shape``) and the
+    runtime routes casts through the two-hop dedup path (comm/hier.py).
+    ``overlap_config`` forces the overlap degree/algorithm (default:
+    OverlapConfig(), i.e. the degree-0 merged no-overlap path; pass
+    degree=None for the auto-tuned degree). ``kind`` names the attention
+    kind the mask belongs to in a model with several
+    (``models/pattern.py``): it lands on the span and in
+    ``magi_model_attn_plans_total{kind=}``."""
+    from ..common.enum import AttnMaskType
+    from ..meta.dispatch_meta import make_dispatch_meta_from_qk_ranges
+
+    _check_tp(cfg, mesh, tp_axis)
+    cp_size, _ = _cp_geometry(mesh, cp_axis)
+    mq, _, bucket = make_dispatch_meta_from_qk_ranges(
+        q_ranges,
+        k_ranges,
+        [AttnMaskType(int(t)) for t in attn_type_map],
+        total_seqlen,
+        total_seqlen,
+        chunk_size=chunk_size,
+        cp_size=cp_size,
+    )
+    plan, attn_params = _plan_on_dispatch(
+        cfg, mesh, mq, bucket, total_seqlen, q_ranges, k_ranges,
+        attn_type_map,
+        cp_axis=cp_axis, tp_axis=tp_axis, block_q=block_q, block_k=block_k,
+        interpret=interpret, overlap_config=overlap_config, kind=kind,
+    )
     return plan, attn_params, mq
+
+
+@telemetry.span("plan_flex_attn")
+def plan_flex_attn_on_dispatch(
+    cfg,
+    mesh,
+    dispatch_meta,
+    q_ranges,
+    k_ranges,
+    attn_type_map,
+    *,
+    cp_axis,
+    tp_axis: str | None = None,
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool | None = None,
+    overlap_config=None,
+    kind: str | None = None,
+):
+    """Another mask on a dispatch that :func:`plan_flex_attn` solved (the
+    ``*_for_new_mask_after_dispatch`` rule of ``api/interface.py``: same
+    ``chunk_size``, same partitions, so dispatched activations are shared
+    and only the plan, the tiles and the tables differ). Returns
+    (plan, attn_params)."""
+    from ..common.enum import AttnMaskType
+    from ..meta.dispatch_meta import make_global_bucket_from_qk_ranges
+
+    _check_tp(cfg, mesh, tp_axis)
+    bucket = make_global_bucket_from_qk_ranges(
+        q_ranges,
+        k_ranges,
+        [AttnMaskType(int(t)) for t in attn_type_map],
+        dispatch_meta.total_seqlen,
+        dispatch_meta.chunk_size,
+    )
+    return _plan_on_dispatch(
+        cfg, mesh, dispatch_meta, bucket, dispatch_meta.total_seqlen,
+        q_ranges, k_ranges, attn_type_map,
+        cp_axis=cp_axis, tp_axis=tp_axis, block_q=block_q, block_k=block_k,
+        interpret=interpret, overlap_config=overlap_config, kind=kind,
+    )
 
 
 def resolve_harness_blocking(

@@ -1,0 +1,580 @@
+"""A decoder driven by a layer pattern: attention kind x FFN kind a layer.
+
+Hybrid models alternate attention kinds over one token stream (Trinity /
+AFMoE: three ``sliding_attention`` layers, window 2048, then one
+``full_attention``) and FFN kinds (leading dense layers, then sparse
+experts). Tokens do not move between layers, so the builder solves ONE
+dispatch and makes a plan, kernel parameters and tables PER ATTENTION
+KIND on it (``_common.plan_flex_attn`` for the kind that decides the
+balance, ``plan_flex_attn_on_dispatch`` for the others — the
+``*_for_new_mask_after_dispatch`` rule of ``api/interface.py``). Which
+kind a layer is comes from ``PatternConfig.layer_types`` alone.
+
+The layer is AFMoE's (``afmoe_config`` reads a published ``config.json``):
+per-head RMSNorm on q and k, rotary on the kinds in ``rope_kinds`` only,
+a sigmoid output gate on the attention, a norm before and after each
+half, the embedding scaled under muP, and a sparse-expert FFN whose
+router is float32 sigmoid, top-k on score + bias, weights from the
+scores. With every extra off — ``llama_pattern`` — a layer is
+``models/llama.py``'s, parameter names included.
+
+Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
+(dp, cp) mesh with parameters replicated, so the train step is a single
+jit (``_common.make_model_train_step``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+
+from .. import telemetry
+from ..ops.flex_attn import FlexAttnParams
+from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
+from ..utils.compat import shard_map
+from ..utils.instrument import named_scope
+from ._common import masked_ce_sums
+from .llama import _rms_norm, _rope
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+DENSE, EXPERTS = "dense", "experts"
+_SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
+
+
+@dataclasses.dataclass(frozen=True)
+class PatternConfig:
+    vocab_size: int
+    dim: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    layer_types: tuple[str, ...]  # SLIDING | FULL, a layer
+    ffn_types: tuple[str, ...]  # DENSE | EXPERTS, a layer
+    ffn_hidden: int  # the dense FFN's width
+    # keys a SLIDING layer's query sees inside its document, itself
+    # included; None: the whole document, and one plan serves every layer
+    sliding_window: int | None = None
+    rope_theta: float = 10000.0
+    rope_kinds: tuple[str, ...] = (SLIDING,)  # the others carry no position
+    qk_norm: bool = True
+    attn_gate: bool = True
+    post_norms: bool = True
+    embed_scale: float = 1.0
+    rms_eps: float = 1e-5
+    # the expert FFN
+    n_experts: int = 0  # the router's width
+    top_k: int = 0
+    expert_hidden: int = 0
+    n_shared_experts: int = 0
+    route_norm: bool = True
+    route_scale: float = 1.0
+    router_dtype: str = "float32"
+    # the experts THIS rank holds, [first, last): the router stays
+    # ``n_experts`` wide and top-k; a pair whose expert is held elsewhere
+    # contributes nothing here (in a deployment it arrives with the
+    # all-to-all's combine). None: all of them.
+    expert_range: tuple[int, int] | None = None
+    dtype: str = "bfloat16"
+    remat: bool = False
+
+    def __post_init__(self):
+        if len(self.layer_types) != len(self.ffn_types):
+            raise ValueError("layer_types and ffn_types differ in length")
+        bad = set(self.layer_types) - {SLIDING, FULL}
+        bad |= set(self.ffn_types) - {DENSE, EXPERTS}
+        if bad:
+            raise ValueError(f"unknown layer kinds {sorted(bad)}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def jnp_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        return self.expert_range or (0, self.n_experts)
+
+    def plan_kind(self, layer_type: str) -> str:
+        """The plan a layer's attention runs on."""
+        if layer_type == SLIDING and self.sliding_window is not None:
+            return SLIDING
+        return FULL
+
+    @property
+    def plan_kinds(self) -> tuple[str, ...]:
+        """The plans the model needs, the one that decides the dispatch
+        first: FULL where a layer has it (the larger area decides the
+        balance)."""
+        kinds = {self.plan_kind(t) for t in self.layer_types}
+        return tuple(k for k in (FULL, SLIDING) if k in kinds)
+
+
+def afmoe_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published AFMoE ``config.json`` (Trinity) as a pattern.
+    ``expert_range`` and ``vocab_size`` give one rank's share of an
+    expert-parallel, vocabulary-parallel deployment."""
+    n = int(hf["num_hidden_layers"])
+    types = tuple(hf["layer_types"])
+    if len(types) != n:
+        raise ValueError(f"{len(types)} layer_types for {n} layers")
+    n_dense = int(hf["num_dense_layers"])
+    dim = int(hf["hidden_size"])
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=dim,
+        n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]),
+        layer_types=types,
+        ffn_types=tuple(DENSE if i < n_dense else EXPERTS for i in range(n)),
+        ffn_hidden=int(hf["intermediate_size"]),
+        sliding_window=int(hf["sliding_window"]),
+        rope_theta=float(hf["rope_theta"]),
+        embed_scale=float(np.sqrt(dim)) if hf.get("mup_enabled") else 1.0,
+        rms_eps=float(hf["rms_norm_eps"]),
+        n_experts=int(hf["num_experts"]),
+        top_k=int(hf["num_experts_per_tok"]),
+        expert_hidden=int(hf["moe_intermediate_size"]),
+        n_shared_experts=int(hf["num_shared_experts"]),
+        route_norm=bool(hf["route_norm"]),
+        route_scale=float(hf["route_scale"]),
+        expert_range=expert_range,
+        dtype=dtype,
+        remat=remat,
+    )
+
+
+def llama_pattern(cfg) -> PatternConfig:
+    """``models/llama.py``'s decoder as a pattern: every layer (full,
+    dense), rotary everywhere, the AFMoE extras off. ``init_params`` of
+    ``llama.py`` makes its parameters."""
+    n = cfg.n_layers
+    return PatternConfig(
+        vocab_size=cfg.vocab_size, dim=cfg.dim, n_heads=cfg.n_heads,
+        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        layer_types=(FULL,) * n, ffn_types=(DENSE,) * n,
+        ffn_hidden=cfg.ffn_hidden, rope_theta=cfg.rope_theta,
+        rope_kinds=(FULL,), qk_norm=False, attn_gate=False,
+        post_norms=False, dtype=cfg.dtype, remat=cfg.remat,
+    )
+
+
+def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
+    """Parameter pytree (fp32 master weights). ``expert_bias`` is the
+    router's selection bias: a buffer, zero, no gradient reaches it."""
+    keys = jax.random.split(rng, cfg.n_layers + 2)
+    hq, hk = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    e0, e1 = cfg.held_experts
+
+    def dense(key, shape):
+        fan_in = shape[-2]
+        return jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)
+
+    def ones(*shape):
+        return jnp.ones(shape, jnp.float32)
+
+    layers = []
+    for i, ffn in enumerate(cfg.ffn_types):
+        k = jax.random.split(keys[i], 12)
+        layer = {
+            "wq": dense(k[0], (cfg.dim, hq)),
+            "wk": dense(k[1], (cfg.dim, hk)),
+            "wv": dense(k[2], (cfg.dim, hk)),
+            "wo": dense(k[3], (hq, cfg.dim)),
+            "attn_norm": ones(cfg.dim),
+            "mlp_norm": ones(cfg.dim),
+        }
+        if cfg.attn_gate:
+            layer["w_attn_gate"] = dense(k[4], (cfg.dim, hq))
+        if cfg.qk_norm:
+            layer["q_norm"] = ones(cfg.head_dim)
+            layer["k_norm"] = ones(cfg.head_dim)
+        if cfg.post_norms:
+            layer["post_attn_norm"] = ones(cfg.dim)
+            layer["post_mlp_norm"] = ones(cfg.dim)
+        if ffn == DENSE:
+            layer["w_gate"] = dense(k[5], (cfg.dim, cfg.ffn_hidden))
+            layer["w_up"] = dense(k[6], (cfg.dim, cfg.ffn_hidden))
+            layer["w_down"] = dense(k[7], (cfg.ffn_hidden, cfg.dim))
+        else:
+            eh, held = cfg.expert_hidden, e1 - e0
+            layer["w_router"] = dense(k[5], (cfg.dim, cfg.n_experts))
+            layer["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+            layer["we_gate"] = dense(k[6], (held, cfg.dim, eh))
+            layer["we_up"] = dense(k[7], (held, cfg.dim, eh))
+            layer["we_down"] = dense(k[8], (held, eh, cfg.dim))
+            if cfg.n_shared_experts:
+                sh = eh * cfg.n_shared_experts
+                layer["ws_gate"] = dense(k[9], (cfg.dim, sh))
+                layer["ws_up"] = dense(k[10], (cfg.dim, sh))
+                layer["ws_down"] = dense(k[11], (sh, cfg.dim))
+        layers.append(layer)
+    return {
+        "embed": jax.random.normal(
+            keys[-2], (cfg.vocab_size, cfg.dim), jnp.float32
+        ) * 0.02,
+        "layers": layers,
+        "final_norm": ones(cfg.dim),
+        "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size)),
+    }
+
+
+# ---------------------------------------------------------------------------
+# the layer
+# ---------------------------------------------------------------------------
+
+
+def _swiglu(h, w_gate, w_up, w_down, dt):
+    return (
+        jax.nn.silu(h @ w_gate.astype(dt)) * (h @ w_up.astype(dt))
+    ) @ w_down.astype(dt)
+
+
+def route(h, layer: dict, cfg: PatternConfig):
+    """(expert ids [t, top_k], weights [t, top_k] float32): sigmoid
+    scores in ``router_dtype``, the top k of score + bias, the scores at
+    the chosen experts over their sum (``route_norm``), times
+    ``route_scale``."""
+    rdt = jnp.dtype(cfg.router_dtype)
+    scores = jax.nn.sigmoid(
+        jnp.dot(
+            h.astype(rdt), layer["w_router"].astype(rdt),
+            precision=jax.lax.Precision.HIGHEST,  # float32 means float32
+            preferred_element_type=rdt,
+        )
+    ).astype(jnp.float32)
+    bias = jax.lax.stop_gradient(layer["expert_bias"])
+    _, idx = jax.lax.top_k(scores + bias, cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    if cfg.route_norm:
+        w = w / w.sum(axis=1, keepdims=True)
+    return idx, w * cfg.route_scale
+
+
+def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
+    """sum_k w_k expert_k(h) over the token-expert pairs whose expert
+    this rank holds; returns (y [t, dim] float32, pairs a held expert
+    [held] int32).
+
+    The pairs are sorted by expert, the held ones first, and go through a
+    grouped matmul (``jax.lax.ragged_dot``: the TPU compiler turns it
+    into a tiled kernel that visits the groups' rows only) a chunk of
+    ``2 t`` rows at a time: a token may choose up to ``top_k`` held
+    experts, so there are up to ``top_k / 2`` chunks. The first always
+    runs; a later one that no held pair reaches is skipped
+    (``lax.cond``). No pair is dropped. The matmuls follow the pairs
+    that are here; the row gather and the scatter-add round them take a
+    chunk's rows whatever it holds, so a step's time is flat in the load
+    up to ``2 t`` pairs, four times an even share (PERF.md section 6, PR
+    26: the form whose every pass follows the pairs is faster at an even
+    load and follows a drifting router by 9% inside 40 steps)."""
+    dt = cfg.jnp_dtype
+    t, k = idx.shape
+    rows = 2 * t
+    e0, e1 = cfg.held_experts
+    held = e1 - e0
+    local = idx.reshape(-1) - e0
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # pair ids
+    order = jnp.pad(order, (0, -(t * k) % rows))  # whole chunks to slice
+    counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_here = ends[-1]
+    w_flat = w.reshape(-1)
+    we_gate, we_up, we_down = (
+        layer[n].astype(dt) for n in ("we_gate", "we_up", "we_down")
+    )
+
+    @jax.checkpoint  # a chunk keeps its inputs only
+    def chunk(h, w_flat, pairs, lo):
+        sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+        valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
+        tok = pairs // k
+
+        def grouped(x, w):
+            # rows past the held pairs belong to no group: what the
+            # kernel leaves there, and what its transpose leaves in
+            # their cotangent, is not data (the chip leaves NaNs)
+            x = jnp.where(valid, x, 0)
+            return jnp.where(valid, jax.lax.ragged_dot(x, w, sizes), 0)
+
+        xs = h[tok]
+        a, b = grouped(xs, we_gate), grouped(xs, we_up)
+        o = grouped(jax.nn.silu(a) * b, we_down)
+        o = o.astype(jnp.float32) * w_flat[pairs][:, None]
+        return jnp.zeros((t, cfg.dim), jnp.float32).at[tok].add(o)
+
+    def step(y, j):
+        lo = j * rows
+        pairs = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        y = jax.lax.cond(
+            lo < n_here,
+            lambda y: y + chunk(h, w_flat, pairs, lo),
+            lambda y: y,
+            y,
+        )
+        return y, None
+
+    y = chunk(h, w_flat, order[:rows], 0)
+    n_chunks = order.shape[0] // rows
+    if n_chunks > 1:
+        y, _ = jax.lax.scan(
+            step, y, jnp.arange(1, n_chunks, dtype=jnp.int32)
+        )
+    return y, counts
+
+
+def _expert_ffn(h, layer: dict, cfg: PatternConfig):
+    dt = cfg.jnp_dtype
+    with named_scope("magi_moe_router"):
+        idx, w = route(h, layer, cfg)
+    with named_scope("magi_moe_experts"):
+        y, counts = held_expert_ffn(h, idx, w, layer, cfg)
+    y = y.astype(dt)
+    if cfg.n_shared_experts:
+        with named_scope("magi_moe_shared"):
+            y = y + _swiglu(
+                h, layer["ws_gate"], layer["ws_up"], layer["ws_down"], dt
+            )
+    return y, {"expert_idx": idx, "expert_counts": counts}
+
+
+def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
+                 attn_params, axis_name):
+    """One layer on this rank's dispatched tokens -> (x, routing stats)."""
+    dt = cfg.jnp_dtype
+    t = x.shape[0]
+    eps = cfg.rms_eps
+    kind = cfg.plan_kind(layer_type)
+    h = _rms_norm(x, layer["attn_norm"], eps)
+    q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    if cfg.qk_norm:
+        q = _rms_norm(q, layer["q_norm"], eps)
+        k = _rms_norm(k, layer["k_norm"], eps)
+    if layer_type in cfg.rope_kinds:
+        q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
+        k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
+    with named_scope("magi_attn_" + _SHORT[kind]):
+        out, _, _ = dist_attn_local(
+            q, k, v, tables[kind], plans[kind], attn_params[kind],
+            axis_name=axis_name,
+        )
+    out = out.reshape(t, -1)
+    if cfg.attn_gate:
+        out = out * jax.nn.sigmoid(h @ layer["w_attn_gate"].astype(dt))
+    out = out @ layer["wo"].astype(dt)
+    if cfg.post_norms:
+        out = _rms_norm(out, layer["post_attn_norm"], eps)
+    x = x + out
+
+    h = _rms_norm(x, layer["mlp_norm"], eps)
+    stats = {}
+    if ffn_type == DENSE:
+        out = _swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"], dt)
+    else:
+        out, stats = _expert_ffn(h, layer, cfg)
+    if cfg.post_norms:
+        out = _rms_norm(out, layer["post_mlp_norm"], eps)
+    return x + out, stats
+
+
+def forward_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
+                  attn_params, axis_name="cp"):
+    """Per-cp-rank forward over dispatched tokens -> (logits [t_loc,
+    vocab] float32, the expert layers' routing stats stacked by layer)."""
+    dt = cfg.jnp_dtype
+    x = params["embed"].astype(dt)[tokens]
+    if cfg.embed_scale != 1.0:
+        x = x * jnp.asarray(cfg.embed_scale, dt)
+    stats = []
+    for layer, layer_type, ffn_type in zip(
+        params["layers"], cfg.layer_types, cfg.ffn_types
+    ):
+        one_layer = functools.partial(
+            _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
+            tables=tables, plans=plans, attn_params=attn_params,
+            axis_name=axis_name,
+        )
+        if cfg.remat:  # save a layer's input; the rest recomputes
+            one_layer = jax.checkpoint(one_layer)
+        x, s = one_layer(x, pos, layer)
+        if s:
+            stats.append(s)
+    x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
+    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    stacked = jax.tree.map(lambda *a: jnp.stack(a), *stats) if stats else {}
+    return logits, stacked
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class MagiPattern:
+    """Config + one dispatch's plans by attention kind + mesh + step
+    makers. ``tokens`` / ``labels`` / ``pos`` are in DISPATCH order,
+    [batch, total_padded], batch on 'dp' and tokens on 'cp'."""
+
+    cfg: PatternConfig
+    mesh: Mesh
+    plans: dict[str, DistAttnPlan]
+    attn_params: dict[str, FlexAttnParams]
+    cp_axis: str | tuple[str, str] = "cp"
+    dp_axis: str = "dp"
+
+    def loss_fn(self, params, tokens, labels, pos, tables, *,
+                with_stats: bool = False):
+        """Mean next-token CE over valid (label >= 0) positions; with
+        ``with_stats`` also the expert layers' routing: ``expert_idx``
+        [batch, layers, total_padded, top_k] in dispatch order and
+        ``expert_counts`` [layers, held] summed over the mesh."""
+        cfg = self.cfg
+        tables = {k: tuple(v) for k, v in tables.items()}
+        batch = P(self.dp_axis, self.cp_axis)
+        stats_specs = (
+            {"expert_idx": P(self.dp_axis, None, self.cp_axis),
+             "expert_counts": P()}
+            if with_stats and EXPERTS in cfg.ffn_types else {}
+        )
+
+        @functools.partial(
+            shard_map,
+            mesh=self.mesh,
+            in_specs=(
+                P(), batch, batch, batch,
+                {k: (P(self.cp_axis),) * len(v) for k, v in tables.items()},
+            ),
+            out_specs=(P(), stats_specs),
+            check_vma=False,
+        )
+        def _local(params, tok, lab, pos, tabs):
+            def one(tok1, lab1, pos1):
+                logits, stats = forward_local(
+                    params, tok1, pos1, cfg, tabs, self.plans,
+                    self.attn_params, self.cp_axis,
+                )
+                return masked_ce_sums(logits, lab1), stats
+
+            # a loop, not vmap: a batched lax.cond would run both branches
+            outs = [one(*b) for b in zip(tok, lab, pos)]
+            (loss_sum, count), stats = jax.tree.map(
+                lambda *a: jnp.stack(a), *outs
+            )
+            with named_scope("magi_pattern_loss_psum"):
+                loss_sum, count = (
+                    jax.lax.psum(
+                        jax.lax.psum(v.sum(), self.cp_axis), self.dp_axis
+                    )
+                    for v in (loss_sum, count)
+                )
+            if stats_specs:
+                with named_scope("magi_pattern_stats_psum"):
+                    counts = jax.lax.psum(
+                        jax.lax.psum(
+                            stats["expert_counts"].sum(0), self.cp_axis
+                        ),
+                        self.dp_axis,
+                    )
+                stats = {
+                    "expert_idx": stats["expert_idx"],
+                    "expert_counts": counts,
+                }
+            else:
+                stats = {}
+            return loss_sum / jnp.maximum(count, 1.0), stats
+
+        loss, stats = _local(params, tokens, labels, pos, tables)
+        return (loss, stats) if with_stats else loss
+
+    def sharded_tables(self):
+        from ._common import sharded_plan_tables
+
+        return {
+            k: sharded_plan_tables(p, self.mesh, self.cp_axis)
+            for k, p in self.plans.items()
+        }
+
+    def make_train_step(self, optimizer):
+        """optax-style optimizer -> jitted (params, opt_state, batch) step."""
+        from ._common import make_model_train_step
+
+        return make_model_train_step(self, optimizer)
+
+    def record_expert_load(self, expert_counts) -> None:
+        """``expert_counts`` [layers, held] of one step, read on the
+        host: the gauges ``magi_moe_pairs_here`` /
+        ``magi_moe_load_max_over_mean`` a layer."""
+        for i, counts in enumerate(np.asarray(expert_counts)):
+            telemetry.record_moe_load(i, counts)
+
+
+def build_magi_pattern(
+    cfg: PatternConfig,
+    mesh: Mesh,
+    cu_seqlens: Sequence[int],
+    *,
+    chunk_size: int,
+    cp_axis: str | tuple[str, str] = "cp",
+    dp_axis: str = "dp",
+    block_q: int | None = None,
+    block_k: int | None = None,
+    interpret: bool | None = None,
+    overlap_config=None,
+) -> tuple[MagiPattern, Any]:
+    """Plan the CP attention of one packed sequence (documents
+    ``cu_seqlens``, causal inside each) for every attention kind of the
+    pattern, and bundle the model. Returns (model, dispatch_meta).
+
+    One dispatch solve, on the first of ``cfg.plan_kinds`` (the
+    documents' whole mask where a layer has it); every other kind's
+    plan, tiles and tables are built for its own slices on that dispatch.
+    A pattern of one kind builds one plan."""
+    from ..api.functools import infer_attn_mask_from_cu_seqlens
+    from ._common import plan_flex_attn, plan_flex_attn_on_dispatch
+
+    if isinstance(cp_axis, list):
+        cp_axis = tuple(cp_axis)
+    cu = [int(c) for c in cu_seqlens]
+
+    def mask(kind):
+        if kind == FULL:
+            return infer_attn_mask_from_cu_seqlens(cu, causal=True)
+        return infer_attn_mask_from_cu_seqlens(
+            cu, causal=False, window_size=(cfg.sliding_window - 1, 0)
+        )
+
+    common = dict(
+        cp_axis=cp_axis, block_q=block_q, block_k=block_k,
+        interpret=interpret, overlap_config=overlap_config,
+    )
+    lead, *rest = cfg.plan_kinds
+    plans, attn_params = {}, {}
+    plans[lead], attn_params[lead], meta = plan_flex_attn(
+        cfg, mesh, cu[-1], *mask(lead), chunk_size=chunk_size,
+        kind=_SHORT[lead], **common,
+    )
+    for kind in rest:
+        plans[kind], attn_params[kind] = plan_flex_attn_on_dispatch(
+            cfg, mesh, meta, *mask(kind), kind=_SHORT[kind], **common,
+        )
+    model = MagiPattern(
+        cfg=cfg, mesh=mesh, plans=plans, attn_params=attn_params,
+        cp_axis=cp_axis, dp_axis=dp_axis,
+    )
+    return model, meta

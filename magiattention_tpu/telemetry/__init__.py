@@ -69,6 +69,8 @@ from .collectors import (  # noqa: F401
     record_degraded_path,
     record_dispatch_meta,
     record_flex_kernel_build,
+    record_model_attn_plan,
+    record_moe_load,
     record_dispatch_solution,
     record_dynamic_solution,
     record_group_collective_build,
@@ -346,6 +348,8 @@ __all__ = [
     "record_degraded_path",
     "record_dispatch_meta",
     "record_flex_kernel_build",
+    "record_model_attn_plan",
+    "record_moe_load",
     "record_dispatch_solution",
     "record_dynamic_solution",
     "record_event",

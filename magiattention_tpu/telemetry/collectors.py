@@ -118,6 +118,15 @@ M_AUTOTUNE_CHOICE = "magi_autotune_choice"
 # grid step takes: {kernel=fwd|dq|dkv, heads_per_step=}. Beside the
 # head_block gauge above it says whether each kernel honoured the choice
 M_FLEX_KERNEL_BUILDS = "magi_flex_kernel_build_total"
+# attention plans a model builder made, by attention kind
+# (models/pattern.py: one dispatch, a plan per kind): {kind=sliding|full}
+M_MODEL_ATTN_PLANS = "magi_model_attn_plans_total"
+# gauges — an expert layer's load on the experts THIS rank holds, from a
+# step the caller read on the host (MagiPattern.record_expert_load):
+# token-expert pairs computed here, and the busiest held expert's pairs
+# over the mean of the held experts: {layer=}
+M_MOE_PAIRS_HERE = "magi_moe_pairs_here"
+M_MOE_LOAD_MAX_OVER_MEAN = "magi_moe_load_max_over_mean"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1098,6 +1107,30 @@ def record_flex_kernel_build(kernel: str, heads_per_step: int) -> None:
         return
     get_registry().counter_inc(
         M_FLEX_KERNEL_BUILDS, kernel=kernel, heads_per_step=heads_per_step
+    )
+
+
+def record_model_attn_plan(kind: str) -> None:
+    """One attention plan built for a model's attention ``kind``
+    (``models/_common._plan_on_dispatch``, host side)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_MODEL_ATTN_PLANS, kind=kind)
+
+
+def record_moe_load(layer: int, counts) -> None:
+    """Pairs per held expert of one expert layer in one step (host
+    values, read outside any timed window)."""
+    if not _enabled():
+        return
+    counts = [float(c) for c in counts]
+    pairs = sum(counts)
+    reg = get_registry()
+    reg.gauge_set(M_MOE_PAIRS_HERE, pairs, layer=layer)
+    reg.gauge_set(
+        M_MOE_LOAD_MAX_OVER_MEAN,
+        max(counts) * len(counts) / pairs if pairs else 0.0,
+        layer=layer,
     )
 
 
