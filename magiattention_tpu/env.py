@@ -407,7 +407,9 @@ def grid_override() -> str | None:
     """Pinned flex-kernel grid layout, or None (auto). 'row_major' keeps
     the static (heads, q-blocks, steps) grid, 'sparse' forces the
     compact occupied-entry walk (``ops/flex_attn.py`` GRID_KINDS) — the
-    A/B lever for benching the two grids at a fixed blocking."""
+    A/B lever for benching the two grids at a fixed blocking, honoured
+    by ``auto_kernel_config`` and by the keyed runtime's
+    ``make_attn_params``."""
     v = _env_str("MAGI_ATTENTION_GRID", "auto").strip().lower()
     if v in ("", "auto"):
         return None
@@ -829,6 +831,7 @@ def flags_fingerprint() -> tuple:
         is_qo_comm_enable(),
         is_hierarchical_comm_enable(),
         autotune_mode(),
+        grid_override(),
         group_coll_impl(),
         comm_pad_to(),
         guard_mode(),

@@ -59,19 +59,20 @@ _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _BWD_HB_LIVE_BYTES = _VMEM_LIMIT_BYTES // 2
 
 
-def _flex_pallas_call(role: str, heads_per_step: int, body, **kwargs):
+def _flex_pallas_call(role: str, heads_per_step: int, grid: str, body, **kwargs):
     """Where every flex ``pallas_call`` is built (trace time). The call is
     named by role, not by grid kind: magi_flex_fwd_kernel,
     magi_flex_dq_kernel, magi_flex_dkv_kernel. The name enters the custom
     call's jax scope (.../magi_flex_dq_kernel/pallas_call), which is what a
     device trace and the benchmark's per-kernel metrics read; keep it
     matching magi_\\w*kernel, the roofline metrics' pattern. The build is
-    counted with the q heads one grid step takes
-    (``magi_flex_kernel_build_total{kernel=, heads_per_step=}``), so a
-    snapshot says which of the per-head and head-batched forms ran."""
+    counted with the q heads one grid step takes and the grid it walks
+    (``magi_flex_kernel_build_total{kernel=, heads_per_step=, grid=}``), so
+    a snapshot says which of the per-head and head-batched forms ran, and
+    on which of :data:`GRID_KINDS`."""
     from .. import telemetry
 
-    telemetry.record_flex_kernel_build(role, heads_per_step)
+    telemetry.record_flex_kernel_build(role, heads_per_step, grid)
     return pl.pallas_call(body, name=f"magi_flex_{role}_kernel", **kwargs)
 
 
@@ -80,14 +81,13 @@ def _compiler_params(*dimension_semantics: str):
         dimension_semantics=dimension_semantics,
         vmem_limit_bytes=_VMEM_LIMIT_BYTES,
     )
-LOG2E = math.log2(math.e)  # base-2 softmax domain (AMLA rescaling)
-LN2 = math.log(2.0)
 # the two kernel grid layouts (FlexAttnParams.grid / the autotuner's
-# rung axis): "row_major" = the static (heads, num_blocks, steps) grid
-# (dense-optimal: static q-side index maps keep block residency
-# provable); "sparse" = the compact entry-walk grid (heads, entries)
-# that visits ONLY occupied (q-block, k-block) tiles — zero dead steps
-# on heterogeneous masks (ROADMAP item 1)
+# rung axis): "row_major" = the static (heads, num_blocks, steps) grid,
+# whose rows shorter than the longest pad with dead steps; "sparse" = the
+# compact entry-walk grid (heads, entries) that visits ONLY the entries
+# of the table — no dead steps (ROADMAP S2). One step body a kernel
+# serves both (:class:`_Walk`); the keyed runtime picks per plan
+# (``parallel/dist_attn.make_attn_params``)
 GRID_KINDS = ("row_major", "sparse")
 
 
@@ -96,24 +96,38 @@ class FlexAttnParams:
     """Static parameters closed over by the kernels (hashable).
 
     ``head_block``: q heads processed per grid step (1 = head-per-step),
-    by the forward on both grids and by the row-major dq and dkv (dkv takes
-    the ``head_block // group`` kv heads' groups, so its ``group`` grid
+    by the forward, dq and dkv on both grids (dkv takes the
+    ``head_block // group`` kv heads' groups, so its ``group`` grid
     dimension is inside the step). Batching heads amortizes per-step grid
     overhead — the dominant cost on small tiles — and fetches a K/V tile
     once for the group, at the price of head_block x VMEM: a backward
     step too large for it stays per head (``_bwd_head_block``, a test on
-    shapes), as the sparse grid's backward always does. Must be 1 or a
-    multiple of the GQA group size.
+    shapes that holds on both grids). Must be 1 or a multiple of the GQA
+    group size.
 
-    ``fwd_steps``/``bwd_steps``: static inner-grid extents — the max
-    entries on any q block (fwd/dq) resp. k block (dkv). The kernels run
-    a row-major grid (heads, num_blocks, steps) whose q-side index maps
-    are STATIC (measured round 5: the previous flat (heads, entries)
-    grid with dynamic q/out maps cost ~43% of dense throughput — 76 vs
-    132 TF/s full-64k — because Mosaic cannot prove block residency
-    across dynamically-indexed steps). 0 = derive from concrete tables
-    at launch; traced (per-rank stacked) tables require the plan builder
-    to set them host-side.
+    ``grid`` (:data:`GRID_KINDS`): ``"row_major"`` launches (heads,
+    num_blocks, steps) with static q-side index maps; ``"sparse"``
+    launches (heads, entries), one step an entry of the table, its q-side
+    maps read from the table (non-decreasing, so a block stays where it is
+    across its entries). Same step bodies, same tables, same results.
+    Measured on a v5e (PR 27, PERF.md section 6; the "round 5" reading of
+    a flat grid at 76 against 132 TF/s on dense 64k has no record and did
+    not repeat): a dead row-major step costs 0.21-0.30 us at 8 heads a
+    step, and 2.1 us in the per-head dkv, where it still moves data; the
+    compact walk adds -0.015 to +0.035 us to a live step. On the packed
+    64k cell, 79% dead, the compact grid took the forward from 131.8 to
+    109.0 ms, dq from 107.6 to 74.7, dkv from 128.4 to 96.3; on a window
+    mask with 1% dead it changed nothing (+0.4 / -0.2 / +0.2 ms of 48 /
+    32 / 40). ``make_attn_params`` counts both grids' steps for a plan
+    and prices them with those two costs
+    (``tuning/cost_model.choose_grid``); direct callers default to
+    ``"row_major"``.
+
+    ``fwd_steps``/``bwd_steps``: the row-major grid's static inner
+    extents — the max entries on any q block (fwd/dq) resp. k block
+    (dkv). 0 = derive from concrete tables at launch; traced (per-rank
+    stacked) tables require the plan builder to set them host-side. The
+    compact grid has no such extent.
     """
 
     block_q: int
@@ -126,8 +140,7 @@ class FlexAttnParams:
     head_block: int = 1
     fwd_steps: int = 0
     bwd_steps: int = 0
-    # "row_major" (static steps grid) or "sparse" (compact entry walk
-    # with AMLA mul-by-add rescaling in the forward) — see GRID_KINDS
+    # "row_major" (static steps grid) or "sparse" (compact entry walk)
     grid: str = "row_major"
 
     @property
@@ -335,6 +348,101 @@ def _check_head_block(hbg: int, hq: int, group: int) -> None:
 
 
 # ---------------------------------------------------------------------------
+# the two grids: one step body a kernel, two walks
+# ---------------------------------------------------------------------------
+
+
+class _Walk:
+    """Where a step stands in its entry table, on either grid
+    (``params.grid``, :data:`GRID_KINDS`). The step bodies of the forward,
+    dq and dkv, per head and head-batched, read four scalars from it and
+    nothing else of the grid: the entry ``e``, its major block ``i`` (a q
+    block for the forward and dq, a k block for dkv), whether the step is
+    the block's ``first()`` (initialize the accumulators) and its
+    ``last()`` (write the block).
+
+    ``row_major``: grid (heads, blocks, steps); step j of block i is
+    entry ``rs[i] + j``, clamped past the block's count, and such a dead
+    step takes its slot but skips compute (``when_live``).
+    ``sparse``: grid (heads, entries); the step IS the entry, its block
+    read from the major table, and every step is live — padded entries
+    too, whose mask is empty.
+
+    ``inner``: the grid has one more dimension inside the walk (the
+    per-head dkv's GQA group), read as ``g``. The predicates are made
+    where they are asked for, so the row-major program is traced in the
+    order it always was."""
+
+    def __init__(self, grid: str, major, rs, rc, inner: bool = False):
+        self.rs, self.rc = rs, rc
+        self.compact = grid == "sparse"
+        if self.compact:
+            self.e = pl.program_id(1)
+            self.i = major[self.e]
+            self.g = pl.program_id(2) if inner else None
+        else:
+            self.i = pl.program_id(1)
+            self.j = pl.program_id(2)
+            self.g = pl.program_id(3) if inner else None
+            self.steps = pl.num_programs(2)
+            self.e = _clamped_entry(rs, rc, self.i, self.j)
+
+    def first(self):
+        return self.e == self.rs[self.i] if self.compact else self.j == 0
+
+    def last(self):
+        if self.compact:
+            return self.e == self.rs[self.i] + self.rc[self.i] - 1
+        return self.j == self.steps - 1
+
+    def when_live(self, compute) -> None:
+        if self.compact:
+            compute()
+        else:
+            pl.when(self.j < self.rc[self.i])(compute)
+
+
+def _walk_grid(
+    grid: str, heads: int, major, num_major: int, steps: int,
+    stream_head=lambda h: h, inner: tuple[int, ...] = (),
+):
+    """(grid, index map of the blocks that stay per major block, index map
+    of the blocks streamed per entry, dimension semantics) of a launcher.
+    The seven scalar-prefetch operands arrive as (major table, minor
+    table, slice ids, runs, bounds, row starts, row counts): q-major for
+    the forward and dq, k-major for dkv. ``steps`` is the params' static
+    extent (``fwd_steps`` / ``bwd_steps``), which only the row-major grid
+    has. ``inner``: extents of grid dimensions inside the walk, and
+    ``stream_head(h, *their ids)`` the head block of the streamed operand
+    (the same as the resident one's in the head-batched layout)."""
+    n = len(inner)
+    if grid == "sparse":
+
+        def stay(h, e, *rest):
+            return (h, rest[n][e], 0)
+
+        def stream(h, e, *rest):
+            return (stream_head(h, *rest[:n]), rest[n + 1][e], 0)
+
+        return (
+            (heads, major.shape[0], *inner), stay, stream,
+            ("parallel", "arbitrary", *["arbitrary"] * n),
+        )
+
+    def stay(h, i, j, *rest):
+        return (h, i, 0)
+
+    def stream(h, i, j, *rest):
+        e = _clamped_entry(rest[n + 5], rest[n + 6], i, j)
+        return (stream_head(h, *rest[:n]), rest[n + 1][e], 0)
+
+    return (
+        (heads, num_major, _resolve_steps(steps, major, num_major), *inner),
+        stay, stream, ("parallel", "parallel", *["arbitrary"] * (n + 1)),
+    )
+
+
+# ---------------------------------------------------------------------------
 # forward (head-batched variant)
 # ---------------------------------------------------------------------------
 
@@ -367,26 +475,25 @@ def _fwd_kernel_hb(
     so the QK^T and PV products are single batched MXU calls; the mask is
     computed once per tile and broadcast over (HB, G).
 
-    Row-major grid (see :class:`FlexAttnParams`): i walks q blocks
+    Both grids (:class:`_Walk`): on the row-major one i walks q blocks
     statically, j walks that block's entries (rs[i]..rs[i]+rc[i]), steps
-    past the count clamp their k index (no DMA) and skip compute.
+    past the count clamp their k index (no DMA) and skip compute; on the
+    compact one a step is an entry.
     """
     bq, bk = params.block_q, params.block_k
     hbg = q_ref.shape[0]
     hb = k_ref.shape[0]
     h = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, qblk, rs, rc)
+    i, e = w.i, w.e
 
-    @pl.when(j == 0)
+    @pl.when(w.first())
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
@@ -411,7 +518,7 @@ def _fwd_kernel_hb(
         l_scr[:, :, :1] = l_new
         acc_scr[...] = acc
 
-    @pl.when(j == steps - 1)
+    @pl.when(w.last())
     def _finalize():
         m = m_scr[:, :, :1]
         l = l_scr[:, :, :1]
@@ -451,62 +558,6 @@ def _fwd_kernel_hb(
         )
 
 
-def _fwd_pallas_hb(q, k, v, sink2d, tables, params: FlexAttnParams):
-    """Head-batched launcher: row-major grid (hq/HBG, nq, steps)."""
-    qblk, kblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk = k.shape[0]
-    group = hq // hk
-    hbg = params.head_block
-    _check_head_block(hbg, hq, group)
-    hb = hbg // group
-    bq, bk = params.block_q, params.block_k
-    nq = tqp // bq
-    steps = _resolve_steps(params.fwd_steps, qblk, nq)
-    rs, rc = _row_tables(qblk, nq)
-
-    def qmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        return (h, i, 0)
-
-    def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        e = _clamped_entry(rs, rc, i, j)
-        return (h, kb[e], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(hq // hbg, nq, steps),
-        in_specs=[
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
-            pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
-            pltpu.VMEM((hb, group * bq, d), jnp.float32),
-        ],
-    )
-    return _flex_pallas_call(
-        "fwd",
-        hbg,
-        functools.partial(_fwd_kernel_hb, params=params, group=group),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-        ],
-        interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
-
-
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -535,18 +586,16 @@ def _fwd_kernel(
 ):
     bq, bk = params.block_q, params.block_k
     h = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, qblk, rs, rc)
+    i, e = w.i, w.e
 
-    @pl.when(j == 0)
+    @pl.when(w.first())
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         s = jnp.where(
@@ -576,7 +625,7 @@ def _fwd_kernel(
         l_scr[:, :1] = l_new
         acc_scr[...] = acc
 
-    @pl.when(j == steps - 1)
+    @pl.when(w.last())
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -606,414 +655,52 @@ def _fwd_kernel(
 def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
     """q [hq, tqp, d]; k/v [hk, tkp, d]; tables from fwd_tables().
 
-    Row-major grid (hq, nq, steps): the q/out/lse index maps are static in
-    the inner dimension, so Mosaic keeps the q block and accumulator
-    residency across a row's entries and pipelines the streamed K/V blocks
-    (the flat (hq, E) dynamic-map grid measured 76 vs 132 TF/s on dense
-    full-64k). Dead steps (j >= row count) clamp the K index — no fresh
-    DMA — and skip compute.
+    Row-major grid (hq/HBG, nq, steps): the q/out/lse index maps are
+    static in the inner dimension; dead steps (j >= row count) clamp the K
+    index — no fresh DMA — and skip compute. Compact grid (hq/HBG, E): the
+    q-side maps read the table (``qblk[e]``, non-decreasing, so a q block
+    stays where it is across its entries) and no step is dead.
     """
-    qblk, kblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk = k.shape[0]
-    group = hq // hk
-    bq, bk = params.block_q, params.block_k
-    E = qblk.shape[0]
-    nq = tqp // bq
-    steps = _resolve_steps(params.fwd_steps, qblk, nq)
-    rs, rc = _row_tables(qblk, nq)
-
-    def qmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        return (h, i, 0)
-
-    def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        e = _clamped_entry(rs, rc, i, j)
-        return (h // group, kb[e], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(hq, nq, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # sink [hq, 1]
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-    )
-    return _flex_pallas_call(
-        "fwd",
-        1,
-        functools.partial(_fwd_kernel, params=params),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-        ],
-        interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * int(E) * bq * bk * d * hq,
-            bytes_accessed=q.size * q.dtype.itemsize + 2 * k.size * k.dtype.itemsize,
-            transcendentals=int(E) * bq * bk * hq,
-        ),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
-
-
-# ---------------------------------------------------------------------------
-# forward: compact sparse grid (entry walk + AMLA mul-by-add rescaling)
-# ---------------------------------------------------------------------------
-
-
-def _amla_rescale(x, delta_exp):
-    """Multiply an f32 tensor by ``2**delta_exp`` (int32, <= 0) via an
-    integer ADD on the exponent field — AMLA's mul-by-add rescaling
-    (PAPERS.md, arxiv 2509.25224) folded into the online-softmax
-    accumulator update: with the running max quantized to integers in
-    the base-2 domain, the per-step rescale factor is an exact power of
-    two, so ``acc * alpha`` becomes ``bits(acc) + (delta << 23)`` on the
-    VPU's integer lanes instead of an FMUL. Exact for normal floats
-    (sign and mantissa untouched); values whose exponent would leave the
-    normal range flush to zero — precisely what the FMUL would round
-    them to at these magnitudes."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
-    shifted = jax.lax.bitcast_convert_type(
-        bits + delta_exp * jnp.int32(1 << 23), jnp.float32
-    )
-    exp_field = (
-        jax.lax.shift_right_logical(bits, jnp.int32(23)) & jnp.int32(0xFF)
-    )
-    ok = (exp_field + delta_exp) > 0  # stays a normal float (and x != 0)
-    return jnp.where(ok, shifted, 0.0)
-
-
-def _amla_update(s, m_prev, l_prev, acc_prev, contract):
-    """One AMLA online-softmax step shared by the sparse forward bodies.
-
-    ``s`` are natural-scale masked logits (-inf off-mask); the running
-    state lives in the base-2 domain with an INTEGER-quantized max
-    ``m`` (f32-stored, integer-valued, -inf until the row sees a live
-    entry), so the rescale ``2**(m_prev - m_new)`` applies to ``l`` and
-    ``acc`` through :func:`_amla_rescale`'s exponent add. Returns
-    ``(m_new, l_new, acc_new)``; reduction axis of ``s`` is its last.
-    ``contract(p)`` computes the probs x V product.
-    """
-    s2 = s * jnp.float32(LOG2E)
-    m_cur = jnp.ceil(jnp.max(s2, axis=-1, keepdims=True))
-    m_new = jnp.maximum(m_prev, m_cur)
-    m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-    # fresh rows (m_prev == -inf) carry zero state: rescale by 2^0
-    delta = (
-        jnp.where(m_prev == NEG_INF, m_safe, m_prev) - m_safe
-    ).astype(jnp.int32)
-    p = jnp.exp2(s2 - m_safe)
-    l_new = _amla_rescale(l_prev, delta) + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = _amla_rescale(acc_prev, delta) + contract(p)
-    return m_new, l_new, acc_new
-
-
-def _amla_finalize(m2, l, acc, sink, params: FlexAttnParams):
-    """Shared sparse-forward epilogue: fold the base-2 quantized max
-    back to the natural-scale reference logit ``mu = m2 * ln2``, apply
-    the optional sink, and emit ``(out, lse, covered)`` under the
-    uncovered convention (out=0, lse=-inf). ``sink`` is a broadcastable
-    f32 (or None)."""
-    mu = m2 * jnp.float32(LN2)
-    if params.has_sink:
-        m_tot = jnp.maximum(mu, sink)
-        m_tot_safe = jnp.where(m_tot == NEG_INF, 0.0, m_tot)
-        resc = jnp.exp(jnp.where(mu == NEG_INF, NEG_INF, mu - m_tot_safe))
-        l_tot = l * resc + jnp.exp(sink - m_tot_safe)
-        acc_fin = acc * resc
-    else:
-        m_tot_safe = jnp.where(mu == NEG_INF, 0.0, mu)
-        l_tot = l
-        acc_fin = acc
-    covered = l_tot > 0.0
-    inv = jnp.where(covered, 1.0 / jnp.where(covered, l_tot, 1.0), 0.0)
-    out = acc_fin * inv
-    lse = jnp.where(
-        covered, m_tot_safe + jnp.log(jnp.where(covered, l_tot, 1.0)), NEG_INF
-    )
-    return out, lse, covered
-
-
-def _fwd_kernel_sparse(
-    qblk,
-    kblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,
-    k_ref,
-    v_ref,
-    sink_ref,
-    out_ref,
-    lse_ref,
-    rowmax_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    mx_scr,
-    *,
-    params: FlexAttnParams,
-):
-    """Compact-grid forward: grid (hq, E) — ONE grid step per occupied
-    entry, no dead steps. Entries are q-major sorted, so a q block's
-    state initializes at its first entry (``e == rs[i]``) and the output
-    tile writes at its last (``e == rs[i] + rc[i] - 1``); dummy entries
-    (sentinel slice, fully masked) keep dead q-block rows written with
-    the uncovered convention. The online softmax runs in the base-2
-    domain with AMLA mul-by-add rescaling (:func:`_amla_update`);
-    ``mx_scr`` tracks the exact natural-scale row max separately (the
-    rowmax output contract is unchanged)."""
-    bq, bk = params.block_q, params.block_k
-    h = pl.program_id(0)
-    e = pl.program_id(1)
-    i = qblk[e]
-
-    @pl.when(e == rs[i])
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        mx_scr[...] = jnp.full_like(mx_scr, NEG_INF)
-
-    # every grid slot IS an occupied entry: compute unconditionally
-    s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-    s = jnp.where(
-        _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
-        ),
-        s,
-        NEG_INF,
-    )
-    m_new, l_new, acc_new = _amla_update(
-        s,
-        m_scr[:, :1],
-        l_scr[:, :1],
-        acc_scr[...],
-        lambda p: jax.lax.dot_general(
-            p.astype(v_ref.dtype),
-            v_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ),
-    )
-    m_scr[:, :1] = m_new
-    l_scr[:, :1] = l_new
-    acc_scr[...] = acc_new
-    mx_scr[:, :1] = jnp.maximum(
-        mx_scr[:, :1], jnp.max(s, axis=1, keepdims=True)
-    )
-
-    @pl.when(e == rs[i] + rc[i] - 1)
-    def _finalize():
-        sink = sink_ref[h, 0] if params.has_sink else None
-        out, lse, _ = _amla_finalize(
-            m_scr[:, :1], l_scr[:, :1], acc_scr[...], sink, params
-        )
-        out_ref[0] = out.astype(out_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], LANES))
-        rowmax_ref[0] = jnp.broadcast_to(
-            mx_scr[:, :1], (mx_scr.shape[0], LANES)
-        )
-
-
-def _fwd_pallas_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
-    """Sparse-grid launcher: grid (hq, E) walking the entry table
-    directly — the splash-attention-style compact grid (SNIPPETS.md [2])
-    over the shared block enumeration. The q/out index maps are dynamic
-    (``qblk[e]``) but non-decreasing, so blocks stay resident across a
-    row's consecutive entries; K/V stream per entry exactly as the
-    row-major grid's live steps do. Zero dead slots by construction."""
-    qblk, kblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk = k.shape[0]
-    group = hq // hk
-    bq, bk = params.block_q, params.block_k
-    E = qblk.shape[0]
-    nq = tqp // bq
-    rs, rc = _row_tables(qblk, nq)
-
-    def qmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h, qb[e], 0)
-
-    def kmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h // group, kb[e], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(hq, E),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # sink [hq, 1]
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
-    )
-    return _flex_pallas_call(
-        "fwd",
-        1,
-        functools.partial(_fwd_kernel_sparse, params=params),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-        ],
-        interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary"),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * int(E) * bq * bk * d * hq,
-            bytes_accessed=q.size * q.dtype.itemsize + 2 * k.size * k.dtype.itemsize,
-            transcendentals=int(E) * bq * bk * hq,
-        ),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
-
-
-def _fwd_kernel_hb_sparse(
-    qblk,
-    kblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,  # (HBG, bq, d)
-    k_ref,  # (HB, bk, d)
-    v_ref,
-    sink_ref,
-    out_ref,
-    lse_ref,
-    rowmax_ref,
-    m_scr,  # (HB, G*bq, LANES)
-    l_scr,
-    acc_scr,  # (HB, G*bq, d)
-    mx_scr,
-    *,
-    params: FlexAttnParams,
-    group: int,
-):
-    """Head-batched sparse grid: (hq/HBG, E) — the compact entry walk of
-    :func:`_fwd_kernel_sparse` at the head-batched layout of
-    :func:`_fwd_kernel_hb`, AMLA rescaling included."""
-    bq, bk = params.block_q, params.block_k
-    hbg = q_ref.shape[0]
-    hb = k_ref.shape[0]
-    h = pl.program_id(0)
-    e = pl.program_id(1)
-    i = qblk[e]
-
-    @pl.when(e == rs[i])
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        mx_scr[...] = jnp.full_like(mx_scr, NEG_INF)
-
-    s = _scores_hb(q_ref, k_ref, params, group)
-    mask = _entry_interval_mask(
-        bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
-    )
-    s = _mask_hb(s, mask, group)
-
-    m_new, l_new, acc_new = _amla_update(
-        s,
-        m_scr[:, :, :1],
-        l_scr[:, :, :1],
-        acc_scr[...],
-        lambda p: jax.lax.dot_general(
-            p.astype(v_ref.dtype),
-            v_ref[...],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ),
-    )
-    m_scr[:, :, :1] = m_new
-    l_scr[:, :, :1] = l_new
-    acc_scr[...] = acc_new
-    mx_scr[:, :, :1] = jnp.maximum(
-        mx_scr[:, :, :1], jnp.max(s, axis=2, keepdims=True)
-    )
-
-    @pl.when(e == rs[i] + rc[i] - 1)
-    def _finalize():
-        if params.has_sink:
-            sink = jnp.stack(
-                [
-                    jnp.full((bq, 1), sink_ref[h * hbg + hh, 0], jnp.float32)
-                    for hh in range(hbg)
-                ],
-                axis=0,
-            ).reshape(hb, group * bq, 1)
-        else:
-            sink = None
-        out, lse, _ = _amla_finalize(
-            m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...], sink, params
-        )
-        out_ref[...] = out.reshape(hbg, bq, out_ref.shape[2]).astype(
-            out_ref.dtype
-        )
-        lse_ref[...] = jnp.broadcast_to(
-            lse.reshape(hbg, bq, 1), (hbg, bq, LANES)
-        )
-        rowmax_ref[...] = jnp.broadcast_to(
-            mx_scr[:, :, :1].reshape(hbg, bq, 1), (hbg, bq, LANES)
-        )
-
-
-def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
-    """Head-batched sparse-grid launcher: grid (hq/HBG, E)."""
     qblk, kblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk = k.shape[0]
     group = hq // hk
     hbg = params.head_block
-    _check_head_block(hbg, hq, group)
-    hb = hbg // group
     bq, bk = params.block_q, params.block_k
     E = qblk.shape[0]
     nq = tqp // bq
     rs, rc = _row_tables(qblk, nq)
 
-    def qmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h, qb[e], 0)
-
-    def kmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h, kb[e], 0)
+    if hbg > 1:
+        # head-batched: HB kv heads and their G q heads each a step
+        _check_head_block(hbg, hq, group)
+        hb = hbg // group
+        body = functools.partial(_fwd_kernel_hb, params=params, group=group)
+        rows = (hb, group * bq)
+        k_head = lambda h: h  # noqa: E731
+        cost = None
+    else:
+        hb = 1
+        body = functools.partial(_fwd_kernel, params=params)
+        rows = (bq,)
+        k_head = lambda h: h // group  # noqa: E731
+        cost = pl.CostEstimate(
+            flops=4 * int(E) * bq * bk * d * hq,
+            bytes_accessed=q.size * q.dtype.itemsize + 2 * k.size * k.dtype.itemsize,
+            transcendentals=int(E) * bq * bk * hq,
+        )
+    grid, qmap, kmap, semantics = _walk_grid(
+        params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(hq // hbg, E),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # sink [hq, 1]
         ],
         out_specs=[
             pl.BlockSpec((hbg, bq, d), qmap),
@@ -1021,16 +708,16 @@ def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
             pl.BlockSpec((hbg, bq, LANES), qmap),
         ],
         scratch_shapes=[
-            pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
-            pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
-            pltpu.VMEM((hb, group * bq, d), jnp.float32),
-            pltpu.VMEM((hb, group * bq, LANES), jnp.float32),
+            pltpu.VMEM((*rows, LANES), jnp.float32),
+            pltpu.VMEM((*rows, LANES), jnp.float32),
+            pltpu.VMEM((*rows, d), jnp.float32),
         ],
     )
     return _flex_pallas_call(
         "fwd",
         hbg,
-        functools.partial(_fwd_kernel_hb_sparse, params=params, group=group),
+        params.grid,
+        body,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
@@ -1038,7 +725,8 @@ def _fwd_pallas_hb_sparse(q, k, v, sink2d, tables, params: FlexAttnParams):
             jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
         ],
         interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+        compiler_params=_compiler_params(*semantics),
+        cost_estimate=cost,
     )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
 
 
@@ -1120,16 +808,14 @@ def _dq_kernel(
     params: FlexAttnParams,
 ):
     bq, bk = params.block_q, params.block_k
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, qblk, rs, rc)
+    i, e = w.i, w.e
 
-    @pl.when(j == 0)
+    @pl.when(w.first())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         s = jnp.where(
@@ -1147,7 +833,7 @@ def _dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == steps - 1)
+    @pl.when(w.last())
     def _write():
         dq_ref[0] = dq_scr[...]
 
@@ -1172,21 +858,20 @@ def _dq_kernel_hb(
     params: FlexAttnParams,
     group: int,
 ):
-    """Head-batched dq, the layout of :func:`_fwd_kernel_hb`: one K/V
-    tile serves the HB kv heads' G q heads each, so QK^T, dO V^T and
-    dS K are one batched MXU call each over (HB, G*bq) stacked rows."""
+    """Head-batched dq, the layout of :func:`_fwd_kernel_hb` on either
+    grid (:class:`_Walk` over the q-major table): one K/V tile serves the
+    HB kv heads' G q heads each, so QK^T, dO V^T and dS K are one batched
+    MXU call each over (HB, G*bq) stacked rows."""
     bq, bk = params.block_q, params.block_k
     hb = k_ref.shape[0]
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, qblk, rs, rc)
+    i, e = w.i, w.e
 
-    @pl.when(j == 0)
+    @pl.when(w.first())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
@@ -1201,141 +886,40 @@ def _dq_kernel_hb(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == steps - 1)
+    @pl.when(w.last())
     def _write():
         dq_ref[...] = dq_scr[...].reshape(dq_ref.shape)
 
 
-def _dq_kernel_sparse(
-    qblk,
-    kblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    dq_ref,
-    dq_scr,
-    *,
-    params: FlexAttnParams,
-):
-    """Compact-grid dq: grid (hq, E) over the q-major entry table — the
-    sparse twin of :func:`_dq_kernel` (no online rescale in the
-    backward, so no AMLA here; the stored lse is the reference)."""
-    bq, bk = params.block_q, params.block_k
-    e = pl.program_id(1)
-    i = qblk[e]
-
-    @pl.when(e == rs[i])
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-    s = jnp.where(
-        _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
-        ),
-        s,
-        NEG_INF,
-    )
-    _, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params)
-    dq_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-        ds.astype(k_ref.dtype),
-        k_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(e == rs[i] + rc[i] - 1)
-    def _write():
-        dq_ref[0] = dq_scr[...]
-
-
-def _dq_pallas_sparse(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
-    qblk, kblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk = k.shape[0]
-    group = hq // hk
-    bq, bk = params.block_q, params.block_k
-    E = qblk.shape[0]
-    nq = tqp // bq
-    rs, rc = _row_tables(qblk, nq)
-
-    def qmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h, qb[e], 0)
-
-    def kmap(h, e, qb, kb, si, ru, bo, rs, rc):
-        return (h // group, kb[e], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(hq, E),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), qmap),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-    )
-    return _flex_pallas_call(
-        "dq",
-        1,
-        functools.partial(_dq_kernel_sparse, params=params),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hq, tqp, d), jnp.float32),
-        interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary"),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
-
-
 def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
-    if params.grid == "sparse":
-        return _dq_pallas_sparse(q, k, v, do, lse, delta, tables, params)
     qblk, kblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk = k.shape[0]
     group = hq // hk
+    hbg = _bwd_head_block(params, hq, group)
     bq, bk = params.block_q, params.block_k
     nq = tqp // bq
-    steps = _resolve_steps(params.fwd_steps, qblk, nq)
     rs, rc = _row_tables(qblk, nq)
-    hbg = _bwd_head_block(params, hq, group)
 
-    def qmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-        return (h, i, 0)
-
+    # grid (hq/HBG, nq, steps), or the compact (hq/HBG, E)
     if hbg > 1:
-        # head-batched: grid (hq/HBG, nq, steps), the forward's layout
+        # head-batched, the forward's layout
         hb = hbg // group
         body = functools.partial(_dq_kernel_hb, params=params, group=group)
         scratch = pltpu.VMEM((hb, group * bq, d), jnp.float32)
-
-        def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-            e = _clamped_entry(rs, rc, i, j)
-            return (h, kb[e], 0)
-
+        k_head = lambda h: h  # noqa: E731
     else:
         hb = 1
         body = functools.partial(_dq_kernel, params=params)
         scratch = pltpu.VMEM((bq, d), jnp.float32)
-
-        def kmap(h, i, j, qb, kb, si, ru, bo, rs, rc):
-            e = _clamped_entry(rs, rc, i, j)
-            return (h // group, kb[e], 0)
+        k_head = lambda h: h // group  # noqa: E731
+    grid, qmap, kmap, semantics = _walk_grid(
+        params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(hq // hbg, nq, steps),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hb, bk, d), kmap),
@@ -1350,11 +934,12 @@ def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     return _flex_pallas_call(
         "dq",
         hbg,
+        params.grid,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hq, tqp, d), jnp.float32),
         interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(*semantics),
     )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
 
 
@@ -1385,22 +970,20 @@ def _dkv_kernel(
     params: FlexAttnParams,
     group: int,
 ):
-    """k-major row grid (hk, nk, steps, group): the K/V blocks and dk/dv
-    accumulators stay resident per k block (static maps) while Q/dO/lse
-    stream through dynamic entry lookups."""
+    """k-major walk with the GQA group innermost, row grid (hk, nk, steps,
+    group) or compact (hk, E2, group): the K/V blocks and dk/dv
+    accumulators stay resident per k block while Q/dO/lse stream through
+    the entry lookups."""
     bq, bk = params.block_q, params.block_k
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    g = pl.program_id(3)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, kblk, rs, rc, inner=True)
+    i, e, g = w.i, w.e, w.g
 
-    @pl.when((j == 0) & (g == 0))
+    @pl.when(w.first() & (g == 0))
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         s = jnp.where(
@@ -1424,7 +1007,7 @@ def _dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when((j == steps - 1) & (g == group - 1))
+    @pl.when(w.last() & (g == group - 1))
     def _write():
         dk_ref[0] = dk_scr[...]
         dv_ref[0] = dv_scr[...]
@@ -1452,24 +1035,23 @@ def _dkv_kernel_hb(
     params: FlexAttnParams,
     group: int,
 ):
-    """Head-batched dkv, k-major row grid (hk/HB, nk, steps): the
-    per-head kernel's innermost ``group`` grid dimension is inside the
-    step. dv += P^T dO and dk += dS^T Q contract over the G*bq stacked
-    rows of each kv head's group; K, V and the two accumulators stay
-    resident per k block."""
+    """Head-batched dkv over the k-major table, row grid (hk/HB, nk,
+    steps) or compact (hk/HB, E2) (:class:`_Walk`): the per-head kernel's
+    innermost ``group`` grid dimension is inside the step. dv += P^T dO
+    and dk += dS^T Q contract over the G*bq stacked rows of each kv
+    head's group; K, V and the two accumulators stay resident per k
+    block."""
     bq, bk = params.block_q, params.block_k
     hb = k_ref.shape[0]
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    steps = pl.num_programs(2)
-    e = _clamped_entry(rs, rc, i, j)
+    w = _Walk(params.grid, kblk, rs, rc)
+    i, e = w.i, w.e
 
-    @pl.when(j == 0)
+    @pl.when(w.first())
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(j < rc[i])
+    @w.when_live
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
@@ -1491,165 +1073,40 @@ def _dkv_kernel_hb(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == steps - 1)
+    @pl.when(w.last())
     def _write():
         dk_ref[...] = dk_scr[...]
         dv_ref[...] = dv_scr[...]
 
 
-def _dkv_kernel_sparse(
-    kblk,
-    qblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    delta_ref,
-    dk_ref,
-    dv_ref,
-    dk_scr,
-    dv_scr,
-    *,
-    params: FlexAttnParams,
-    group: int,
-):
-    """Compact-grid dkv: grid (hk, E2, group) over the k-major entry
-    table — K/V and the dk/dv accumulators stay resident per k block
-    while Q/dO/lse stream through the entry walk."""
-    bq, bk = params.block_q, params.block_k
-    e = pl.program_id(1)
-    g = pl.program_id(2)
-    i = kblk[e]
-
-    @pl.when((e == rs[i]) & (g == 0))
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-    s = jnp.where(
-        _entry_interval_mask(
-            bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk
-        ),
-        s,
-        NEG_INF,
-    )
-    p, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params)
-    dv_scr[...] += jax.lax.dot_general(
-        p.astype(do_ref.dtype),
-        do_ref[0],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-        ds.astype(q_ref.dtype),
-        q_ref[0],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when((e == rs[i] + rc[i] - 1) & (g == group - 1))
-    def _write():
-        dk_ref[0] = dk_scr[...]
-        dv_ref[0] = dv_scr[...]
-
-
-def _dkv_pallas_sparse(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
-    kblk, qblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk, tkp, _ = k.shape
-    group = hq // hk
-    bq, bk = params.block_q, params.block_k
-    E = kblk.shape[0]
-    nk = tkp // bk
-    rs, rc = _row_tables(kblk, nk)
-
-    def qmap(h, e, g, kb, qb, si, ru, bo, rs, rc):
-        return (h * group + g, qb[e], 0)
-
-    def kmap(h, e, g, kb, qb, si, ru, bo, rs, rc):
-        return (h, kb[e], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(hk, E, group),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bq, d), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-            pl.BlockSpec((1, bq, LANES), qmap),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), kmap),
-            pl.BlockSpec((1, bk, d), kmap),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-    )
-    return _flex_pallas_call(
-        "dkv",
-        1,
-        functools.partial(_dkv_kernel_sparse, params=params, group=group),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
-            jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
-        ],
-        interpret=params.interpret,
-        compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary"),
-    )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
-
-
 def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
-    if params.grid == "sparse":
-        return _dkv_pallas_sparse(q, k, v, do, lse, delta, tables, params)
     kblk, qblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk, tkp, _ = k.shape
     group = hq // hk
+    hbg = _bwd_head_block(params, hq, group)
     bq, bk = params.block_q, params.block_k
     nk = tkp // bk
-    steps = _resolve_steps(params.bwd_steps, kblk, nk)
     rs, rc = _row_tables(kblk, nk)
-    hbg = _bwd_head_block(params, hq, group)
 
     if hbg > 1:
-        # head-batched: the group is inside the step, grid (hk/HB, nk, steps)
+        # head-batched: the group is inside the step, grid (hk/HB, nk,
+        # steps), or the compact (hk/HB, E2)
         hb = hbg // group
         body = functools.partial(_dkv_kernel_hb, params=params, group=group)
-        grid = (hk // hb, nk, steps)
         kv_scratch = pltpu.VMEM((hb, bk, d), jnp.float32)
-
-        def qmap(h, i, j, kb, qb, si, ru, bo, rs, rc):
-            e = _clamped_entry(rs, rc, i, j)
-            return (h, qb[e], 0)
-
-        def kmap(h, i, j, kb, qb, si, ru, bo, rs, rc):
-            return (h, i, 0)
-
+        grid, kmap, qmap, semantics = _walk_grid(
+            params.grid, hk // hb, kblk, nk, params.bwd_steps
+        )
     else:
+        # per head: the group is the innermost grid dimension
         hb = 1
         body = functools.partial(_dkv_kernel, params=params, group=group)
-        grid = (hk, nk, steps, group)
         kv_scratch = pltpu.VMEM((bk, d), jnp.float32)
-
-        def qmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
-            e = _clamped_entry(rs, rc, i, j)
-            return (h * group + g, qb[e], 0)
-
-        def kmap(h, i, j, g, kb, qb, si, ru, bo, rs, rc):
-            return (h, i, 0)
-
+        grid, kmap, qmap, semantics = _walk_grid(
+            params.grid, hk, kblk, nk, params.bwd_steps,
+            lambda h, g: h * group + g, inner=(group,),
+        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -1670,6 +1127,7 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     return _flex_pallas_call(
         "dkv",
         hbg,
+        params.grid,
         body,
         grid_spec=grid_spec,
         out_shape=[
@@ -1677,9 +1135,7 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
         ],
         interpret=params.interpret,
-        compiler_params=_compiler_params(
-            "parallel", "parallel", *["arbitrary"] * (len(grid) - 2)
-        ),
+        compiler_params=_compiler_params(*semantics),
     )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
 
 
@@ -1700,12 +1156,6 @@ def _fwd_dispatch(q, k, v, sink2d, ftab, params: FlexAttnParams):
             f"flex-attn: params.grid={params.grid!r} must be one of "
             f"{GRID_KINDS}"
         )
-    if params.grid == "sparse":
-        if params.head_block > 1:
-            return _fwd_pallas_hb_sparse(q, k, v, sink2d, ftab, params)
-        return _fwd_pallas_sparse(q, k, v, sink2d, ftab, params)
-    if params.head_block > 1:
-        return _fwd_pallas_hb(q, k, v, sink2d, ftab, params)
     return _fwd_pallas(q, k, v, sink2d, ftab, params)
 
 
@@ -2007,9 +1457,8 @@ def flex_attn_with_meta(
     """Flex attention with a prebuilt block plan. Differentiable in q/k/v/sink.
 
     ``grid`` selects the kernel grid layout (:data:`GRID_KINDS`):
-    ``"sparse"`` walks the compact occupied-entry enumeration (zero dead
-    steps, AMLA rescaling) — the heterogeneous-mask rung; ``"row_major"``
-    keeps the static steps grid the dense paths measured fastest.
+    ``"sparse"`` walks the compact occupied-entry enumeration (no dead
+    steps); ``"row_major"`` the static steps grid.
 
     Returns (out [tq, hq, d], lse [tq, hq]) plus max_logits [hq] when
     ``return_max_logits`` (max_logits is non-differentiable).

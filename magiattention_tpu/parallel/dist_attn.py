@@ -56,6 +56,7 @@ from ..meta.solver.overlap_solver import (
     simulate_overlap_timeline,
 )
 from ..ops.block_meta import (
+    SLICE_FIELDS,
     FlexAttnBlockMeta,
     Run,
     build_block_meta_general,
@@ -124,6 +125,26 @@ class StageTables:
         fs = max(max_row_count(row, nq) for row in self.fwd_qblk)
         bs = max(max_row_count(row, nk) for row in self.bwd_kblk)
         return fs, bs
+
+    def grid_steps(self, fwd_steps: int, bwd_steps: int) -> tuple[int, int, float]:
+        """Steps one head group's forward, dq and dkv launch on the
+        row-major grid (blocks x the params' static extents) and on the
+        compact one (the padded entries), the forward table walked twice
+        and the backward table once; and how many of either do work:
+        entries of a non-empty slice, the mean over ranks. Padded and
+        dummy entries name an all-masked sentinel slice: both grids
+        launch them, and they count as dead."""
+        nq = max(self.num_q_blocks, int(self.fwd_qblk.max()) + 1)
+        nk = max(self.num_k_blocks, int(self.bwd_kblk.max()) + 1)
+        row_major = 2 * nq * fwd_steps + nk * bwd_steps
+        compact = 2 * self.fwd_qblk.shape[1] + self.bwd_kblk.shape[1]
+        b = self.bounds.reshape(self.bounds.shape[0], -1, SLICE_FIELDS)
+        works = (b[..., 1] > b[..., 0]) & (b[..., 3] > b[..., 2])
+        live = sum(
+            weight * np.take_along_axis(works, sid, axis=1).sum(axis=1).mean()
+            for weight, sid in ((2, self.fwd_sid), (1, self.bwd_sid))
+        )
+        return row_major, compact, float(live)
 
     @staticmethod
     def from_rank_metas(metas: list[FlexAttnBlockMeta], kv_pad: int):
@@ -844,13 +865,17 @@ def make_attn_params(
     # plan can hand the kernels (merged / host / per-stage / qo-comm) —
     # the per-rank tables are traced at runtime, so the row-major grids
     # need these in the hashable params (FlexAttnParams.fwd_steps)
-    tabs = (
-        getattr(plan, "merged_tables", None),
-        getattr(plan, "host_tables", None),
-        getattr(plan, "tables", None),
-        *(sp.tables for sp in getattr(plan, "stages", ()) or ()),
-    )
-    return ensure_kernel_steps(
+    tabs = [
+        t
+        for t in (
+            getattr(plan, "merged_tables", None),
+            getattr(plan, "host_tables", None),
+            getattr(plan, "tables", None),
+            *(sp.tables for sp in getattr(plan, "stages", ()) or ()),
+        )
+        if t is not None
+    ]
+    params = ensure_kernel_steps(
         FlexAttnParams(
             head_block=int(head_block),
             block_q=plan.block_q,
@@ -863,6 +888,41 @@ def make_attn_params(
         ),
         tabs,
     )
+    return dataclasses.replace(params, grid=_choose_grid(params, tabs))
+
+
+def _choose_grid(params: FlexAttnParams, tabs) -> str:
+    """The grid all three kernels of a plan walk, from the same table sets
+    the static extents came from: the steps each grid would launch are
+    counted (:meth:`StageTables.grid_steps`) and priced with the two
+    per-step costs measured on the chip (``tuning/cost_model.py``).
+    ``MAGI_ATTENTION_GRID`` pins it, as it pins ``auto_kernel_config``.
+    The decision is recorded: the gauge ``magi_flex_dead_step_share`` and
+    the two prices on the live span (``attn_fn_build``)."""
+    from ..tuning.cost_model import choose_grid, price_grids
+
+    counts = [t.grid_steps(params.fwd_steps, params.bwd_steps) for t in tabs]
+    launched = {
+        "row_major": sum(c[0] for c in counts),
+        "sparse": sum(c[1] for c in counts),
+    }
+    live = sum(c[2] for c in counts)
+    grid = env.grid_override() or choose_grid(*launched.values())
+    row_major_s, compact_s = price_grids(*launched.values())
+    telemetry.annotate_span(
+        rung=(params.block_q, params.block_k, params.head_block),
+        grid=grid,
+        row_major_steps=launched["row_major"],
+        compact_steps=launched["sparse"],
+        live_steps=live,
+        row_major_dead_us=1e6 * row_major_s,
+        compact_fee_us=1e6 * compact_s,
+    )
+    if launched[grid]:
+        telemetry.record_flex_dead_step_share(
+            100.0 * (1.0 - live / launched[grid])
+        )
+    return grid
 
 
 def _hm(x, target):

@@ -68,6 +68,7 @@ from .collectors import (  # noqa: F401
     record_decode_step,
     record_degraded_path,
     record_dispatch_meta,
+    record_flex_dead_step_share,
     record_flex_kernel_build,
     record_model_attn_plan,
     record_moe_load,
@@ -347,6 +348,7 @@ __all__ = [
     "record_decode_step",
     "record_degraded_path",
     "record_dispatch_meta",
+    "record_flex_dead_step_share",
     "record_flex_kernel_build",
     "record_model_attn_plan",
     "record_moe_load",

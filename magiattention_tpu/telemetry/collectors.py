@@ -114,10 +114,15 @@ M_AUTOTUNE_PREDICTED_MS = "magi_autotune_predicted_ms"
 M_AUTOTUNE_MEASURED_MS = "magi_autotune_measured_ms"
 # which rung the last decision chose and why: value 1, labels rung=/source=
 M_AUTOTUNE_CHOICE = "magi_autotune_choice"
-# flex pallas_calls built (trace time), by role and by the q heads one
-# grid step takes: {kernel=fwd|dq|dkv, heads_per_step=}. Beside the
-# head_block gauge above it says whether each kernel honoured the choice
+# flex pallas_calls built (trace time), by role, by the q heads one grid
+# step takes and by the grid walked: {kernel=fwd|dq|dkv, heads_per_step=,
+# grid=row_major|sparse}. Beside the head_block gauge above it says
+# whether each kernel honoured the choice
 M_FLEX_KERNEL_BUILDS = "magi_flex_kernel_build_total"
+# gauge — the newest plan's grid decision (make_attn_params): percent of
+# the steps the chosen grid launches over forward, dq and dkv that do no
+# work (dead row-major steps; padded and dummy entries on both grids)
+M_FLEX_DEAD_STEP_SHARE = "magi_flex_dead_step_share"
 # attention plans a model builder made, by attention kind
 # (models/pattern.py: one dispatch, a plan per kind): {kind=sliding|full}
 M_MODEL_ATTN_PLANS = "magi_model_attn_plans_total"
@@ -1100,14 +1105,27 @@ def record_autotune_decision(decision) -> None:
     )
 
 
-def record_flex_kernel_build(kernel: str, heads_per_step: int) -> None:
+def record_flex_kernel_build(
+    kernel: str, heads_per_step: int, grid: str
+) -> None:
     """One flex ``pallas_call`` built (``ops/flex_attn._flex_pallas_call``,
     while jax traces the caller — never inside a compiled step)."""
     if not _enabled():
         return
     get_registry().counter_inc(
-        M_FLEX_KERNEL_BUILDS, kernel=kernel, heads_per_step=heads_per_step
+        M_FLEX_KERNEL_BUILDS,
+        kernel=kernel,
+        heads_per_step=heads_per_step,
+        grid=grid,
     )
+
+
+def record_flex_dead_step_share(pct: float) -> None:
+    """Share of the steps that do no work, on the grid
+    ``parallel/dist_attn.make_attn_params`` chose for a plan's kernels."""
+    if not _enabled():
+        return
+    get_registry().gauge_set(M_FLEX_DEAD_STEP_SHARE, pct)
 
 
 def record_model_attn_plan(kind: str) -> None:

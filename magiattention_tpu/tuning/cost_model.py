@@ -49,8 +49,62 @@ DEAD_STEP_OVERHEAD_S = 5.0e-8
 # the priced fee is a fraction of that bound. The asymmetry is the
 # point: dense workloads (dead slots ~0 anyway) stay on the measured
 # row-major rungs, heterogeneous masks (dead + partial-tile dominated)
-# escape to the sparse grid.
+# escape to the sparse grid. (An estimate from before the ledger: PR 27
+# measured the fee at -0.015 to +0.07 us, COMPACT_STEP_EXTRA_S below, and
+# a dead step at 0.21-0.30 us where DEAD_STEP_OVERHEAD_S has 0.05. These
+# three rank the rungs of every mask and are ROADMAP D4's to correct.)
 SPARSE_STEP_OVERHEAD_S = 1.5e-7
+# -- the grid of a built plan (``parallel/dist_attn.make_attn_params``) ----
+# Two per-step prices measured on the chip (v5e, my chip runs, PR 27;
+# PERF.md section 6), from whole kernels of the benchmark's cells at 64 q /
+# 8 kv heads, bf16, head_dim 128, traced runs of 38 s. They price the GRID
+# of a plan whose rung is already chosen and enter no rung's ranking: the
+# constants above pick the rung of every mask, and folding these in is the
+# cost model's own PR (ROADMAP D4).
+#
+# A dead row-major step: a slot past its block's entry count, which skips
+# compute. The packed 64k cell at (128, 512, 8) on both grids: forward
+# 131.77 -> 109.04 ms, dq 107.59 -> 74.71, dkv 128.36 -> 96.29 with
+# 110,144 / 110,144 / 107,104 dead steps of 8 heads gone: 0.206 / 0.299 /
+# 0.299 us, 87.68 ms over 327,392 = 0.268 us. (Per head it is dearer where
+# the dead step still moves data: the dense 64k cell's per-head dkv,
+# grid (hk, nk, steps, group), lost 272.8 ms with 132,608 dead slots,
+# 2.06 us each: a dead slot of another group member fetches that head's q
+# and dO block again.)
+DEAD_ROW_MAJOR_STEP_S = 2.7e-7
+# What the compact walk adds to a live step (q-side index maps read from
+# the table, init and write tests on table values). The window-1024 64k
+# mask pinned to (128, 512, 8), 0.8% dead, on both grids: forward 48.19 ->
+# 48.62 ms, dq 31.99 -> 31.80, dkv 40.34 -> 40.53 over 12,224 steps each:
+# +0.035 / -0.015 / +0.015 us, +0.012 us over the three. Per head at
+# (1024, 1024, 1), 8,128 steps: dq +0.069 us, dkv -0.006. Set to the
+# largest head-batched reading, the forward's.
+COMPACT_STEP_EXTRA_S = 3.5e-8
+# The compact grid is chosen only where what it saves passes the error bar
+# of those readings (the dead step's spread over the three kernels is
+# +-20%, the fee's sign changes from kernel to kernel): its fee times this
+# must stay under the dead steps' price. With the two prices above that is
+# a plan whose dead steps are more than a quarter of its entries.
+GRID_FLIP_MARGIN = 2.0
+
+
+def price_grids(row_major_steps: int, compact_steps: int) -> tuple[float, float]:
+    """Seconds a head group's forward, dq and dkv spend on what the two
+    grids do NOT share: the row-major grid's dead steps, and the compact
+    walk's fee on every step it launches. A padded entry is a step of both
+    (it computes on an empty mask), so the steps the row-major grid
+    launches beyond the compact one's are exactly its dead ones."""
+    dead = max(int(row_major_steps) - int(compact_steps), 0)
+    return dead * DEAD_ROW_MAJOR_STEP_S, compact_steps * COMPACT_STEP_EXTRA_S
+
+
+def choose_grid(row_major_steps: int, compact_steps: int) -> str:
+    """``"sparse"`` (the compact entry walk) where the dead steps cost more
+    than the walk's fee by :data:`GRID_FLIP_MARGIN`, else ``"row_major"``."""
+    row_major_s, compact_s = price_grids(row_major_steps, compact_steps)
+    return "sparse" if row_major_s > GRID_FLIP_MARGIN * compact_s else "row_major"
+
+
 # Candidates within this relative cost of the best are considered a tie
 # and resolved by the measured preference order (the analytic model is
 # deliberately not trusted below its own error bar — the static table's

@@ -83,6 +83,7 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize(
     "t,hq,hk,rung",
     [
@@ -92,14 +93,15 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
     ],
     ids=["varlen-cell-64x8", "train-cell-32x8", "largest-tuner-step"],
 )
-def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung):
-    """The head-batched dq and dkv at the blocking the tuner gives the
-    benchmark's packed cells, (128, 512, 8) at head_dim 128: group 8 is
-    one kv head a step, group 4 two (the batched transposed contraction).
-    And at the largest step a row-major rung of the tuner asks for:
-    (256, 1024, 2), whose head_block snaps to 8 at group 8. They fit the
-    VMEM the kernels ask for, and it is the batched programs that were
-    built, not the per-head fallback."""
+def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
+    """The head-batched forward, dq and dkv, on the row-major and on the
+    compact grid, at the blocking the tuner gives the benchmark's packed
+    cells, (128, 512, 8) at head_dim 128: group 8 is one kv head a step,
+    group 4 two (the batched transposed contraction). And at the largest
+    step a row-major rung of the tuner asks for: (256, 1024, 2), whose
+    head_block snaps to 8 at group 8. They fit the VMEM the kernels ask
+    for, and it is the batched programs that were built, not the per-head
+    fallback."""
     from magiattention_tpu import telemetry
 
     d = 128
@@ -108,7 +110,7 @@ def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung):
 
     def loss(q, k, v):
         out, lse = flex_flash_attn_func(
-            q, k, v, qr, kr, ts, grid="row_major", block_q=rung[0],
+            q, k, v, qr, kr, ts, grid=grid, block_q=rung[0],
             block_k=rung[1], head_block=rung[2], interpret=False,
         )
         return out.astype(jnp.float32).sum() + lse.sum()
@@ -124,7 +126,8 @@ def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung):
         )
         for kernel in ("fwd", "dq", "dkv"):
             assert reg.counter_value(
-                "magi_flex_kernel_build_total", kernel=kernel, heads_per_step=8
+                "magi_flex_kernel_build_total", kernel=kernel,
+                heads_per_step=8, grid=grid,
             ) >= 1, kernel
     finally:
         reg.clear_metric("magi_flex_kernel_build_total")

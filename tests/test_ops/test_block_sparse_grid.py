@@ -2,11 +2,10 @@
 primitive (ISSUE 15).
 
 Three-way parity on random heterogeneous masks — the compact sparse
-grid (AMLA mul-by-add rescaling) == the row-major grid == the dense
-reference — for fwd out/lse/max-logits AND grads, on both kernel
-backends (pallas-interpret and the jnp dense reference). Plus:
+grid == the row-major grid == the dense reference — for fwd
+out/lse/max-logits AND grads, on both kernel backends
+(pallas-interpret and the jnp dense reference). Plus:
 
-- exactness of the AMLA exponent-add rescale itself,
 - ``BlockEnumeration`` (flex entry tables, occupancy lists, decode
   block tables all walk through ONE primitive), with the
   occupancy-driven enumeration checked against a brute-force dense
@@ -26,7 +25,6 @@ from magiattention_tpu.ops import (
     build_block_meta_from_occupancy,
     flex_flash_attn_func,
 )
-from magiattention_tpu.ops.flex_attn import _amla_rescale
 from magiattention_tpu.telemetry.occupancy import block_occupancy_map
 from magiattention_tpu.testing import assert_close, ref_attn_from_ranges
 
@@ -164,8 +162,7 @@ def test_sparse_grid_sink_softcap_gqa_max_logits():
             atol=3e-5, rtol=3e-5, msg=f"hb={hb} lse",
         )
         if ref[2] is not None:
-            # max logits must be EXACT (tracked natural-scale, not the
-            # AMLA-quantized base-2 running max)
+            # max logits must be EXACT (the natural-scale running max)
             assert_close(ml, ref[2], atol=1e-6, rtol=1e-6, msg=f"hb={hb}")
 
 
@@ -218,40 +215,6 @@ def test_bad_grid_value_raises():
             q, k, v, [(0, 128)], [(0, 128)], [1],
             block_q=64, block_k=64, grid="diagonal",
         )
-
-
-# ---------------------------------------------------------------------------
-# AMLA rescaling
-# ---------------------------------------------------------------------------
-
-
-def test_amla_rescale_exact_power_of_two():
-    """bits + (delta << 23) == x * 2**delta exactly for normal floats,
-    including negatives; zeros stay zero; deep underflow flushes to 0."""
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(
-        np.concatenate(
-            [rng.standard_normal(64) * 10.0 ** rng.integers(-20, 20, 64),
-             np.zeros(8)]
-        ).reshape(8, 9),
-        jnp.float32,
-    )
-    for delta in (0, -1, -7, -31):
-        got = _amla_rescale(x, jnp.full(x.shape, delta, jnp.int32))
-        want = np.asarray(x, np.float64) * 2.0 ** delta
-        # exact where the result stays a normal float32
-        normal = (np.abs(want) >= np.finfo(np.float32).tiny) | (want == 0.0)
-        np.testing.assert_array_equal(
-            np.asarray(got)[normal], want.astype(np.float32)[normal]
-        )
-        # subnormal-range results flush to zero (never garbage)
-        assert np.all(np.asarray(got)[~normal] == 0.0)
-
-
-def test_amla_rescale_zero_delta_identity():
-    x = jnp.asarray([[1.5, -2.25, 0.0, 1e-30]], jnp.float32)
-    got = _amla_rescale(x, jnp.zeros(x.shape, jnp.int32))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(x))
 
 
 # ---------------------------------------------------------------------------

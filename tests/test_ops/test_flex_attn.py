@@ -13,7 +13,7 @@ import pytest
 
 from magiattention_tpu.common import AttnMaskType
 from magiattention_tpu.ops import build_block_meta, flex_flash_attn_func
-from magiattention_tpu.ops.block_meta import SLICE_FIELDS
+from magiattention_tpu.ops.block_meta import SLICE_FIELDS, pad_block_meta
 from magiattention_tpu.testing import assert_close, ref_attn_from_ranges
 
 F = AttnMaskType.FULL
@@ -356,11 +356,15 @@ _HB_MASK = (
 
 
 @functools.lru_cache(maxsize=None)  # the references repeat across cases
-def _hb_bwd_grads(hq, hk, head_block, softcap, traced, d=32):
+def _hb_bwd_grads(
+    hq, hk, head_block, softcap, traced, grid="row_major", pad=0, d=32
+):
     """dq, dk, dv, dsink of a loss that reads out AND lse (a non-zero lse
-    cotangent) through the Pallas kernels at ``head_block``. ``traced``:
-    the tables are jit arguments and the grid extents come from
-    ``FlexAttnParams.fwd_steps``/``bwd_steps``, as on the keyed path."""
+    cotangent) through the Pallas kernels at ``head_block`` on ``grid``.
+    ``traced``: the tables are jit arguments and the grid extents come from
+    ``FlexAttnParams.fwd_steps``/``bwd_steps``, as on the keyed path;
+    ``pad`` more entries a table, as ``StageTables.from_rank_metas`` pads
+    the ranks' tables to the longest (``pad_block_meta``)."""
     from magiattention_tpu.ops import flex_attn as fa
 
     qr, kr, ts = _HB_MASK
@@ -388,7 +392,8 @@ def _hb_bwd_grads(hq, hk, head_block, softcap, traced, d=32):
         def loss(q, k, v, sink):
             out, lse = flex_flash_attn_func(
                 q, k, v, qr, kr, ts, sink=sink, softcap=softcap,
-                block_q=64, block_k=64, head_block=head_block, interpret=True,
+                block_q=64, block_k=64, head_block=head_block, grid=grid,
+                interpret=True,
             )
             return loss_of(out, lse)
 
@@ -397,11 +402,16 @@ def _hb_bwd_grads(hq, hk, head_block, softcap, traced, d=32):
     meta = build_block_meta(
         qr, kr, [t.value for t in ts], _HB_T, _HB_T, block_q=64, block_k=64
     )
+    if pad:
+        meta = pad_block_meta(
+            meta, meta.num_fwd_entries + pad, meta.num_bwd_entries + pad,
+            meta.num_slices + 2,
+        )
     params = fa.FlexAttnParams(
         block_q=64, block_k=64, scale=d**-0.5, softcap=float(softcap),
         has_sink=True, out_dtype="float32", interpret=True,
         head_block=head_block, fwd_steps=meta.fwd_steps,
-        bwd_steps=meta.bwd_steps,
+        bwd_steps=meta.bwd_steps, grid=grid,
     )
 
     def loss(q, k, v, sink, ftab, btab):
@@ -416,19 +426,21 @@ def _hb_bwd_grads(hq, hk, head_block, softcap, traced, d=32):
     )
 
 
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize("traced", [False, True], ids=["concrete", "traced"])
 @pytest.mark.parametrize("softcap", [0.0, 8.0], ids=["nocap", "softcap"])
 @pytest.mark.parametrize("heads", [1, 2], ids=["hb=g", "hb=2g"])
 @pytest.mark.parametrize("group", [1, 4, 8])
 def test_head_batched_bwd_matches_per_head_and_oracle(
-    group, heads, softcap, traced
+    group, heads, softcap, traced, grid
 ):
-    """dq, dk, dv, dsink of the head-batched dq / dkv kernels against the
-    per-head kernels and against the jnp oracle."""
+    """dq, dk, dv, dsink of the head-batched dq / dkv kernels, on the
+    row-major and on the compact grid, against the per-head kernels and
+    against the jnp oracle."""
     hk = 2
     hq = hk * group
-    got = _hb_bwd_grads(hq, hk, heads * group, softcap, traced)
-    per_head = _hb_bwd_grads(hq, hk, 1, softcap, traced)
+    got = _hb_bwd_grads(hq, hk, heads * group, softcap, traced, grid)
+    per_head = _hb_bwd_grads(hq, hk, 1, softcap, traced, grid)
     oracle = _hb_bwd_grads(hq, hk, None, softcap, False)
     for a, b, c, nm in zip(got, per_head, oracle, ["dq", "dk", "dv", "dsink"]):
         assert np.isfinite(np.asarray(a)).all(), nm
@@ -436,3 +448,19 @@ def test_head_batched_bwd_matches_per_head_and_oracle(
         assert_close(a, c, atol=5e-5, rtol=5e-5, msg=f"{nm} vs oracle")
     # rows 300.. attend to nothing: their dq is exactly zero
     assert not np.asarray(got[0])[300:].any()
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("group,heads", [(1, 2), (4, 1), (8, 1)])
+def test_head_batched_kernels_on_per_rank_padded_tables(group, heads, grid):
+    """Tables padded as the ranks' tables are stacked (``pad_block_meta``:
+    sentinel-slice entries levelled over the blocks): a padded entry is a
+    live step of the compact grid, and its empty mask adds nothing."""
+    hk = 2
+    hq = hk * group
+    got = _hb_bwd_grads(hq, hk, heads * group, 0.0, True, grid, pad=13)
+    unpadded = _hb_bwd_grads(hq, hk, heads * group, 0.0, True, grid)
+    oracle = _hb_bwd_grads(hq, hk, None, 0.0, False)
+    for a, b, c, nm in zip(got, unpadded, oracle, ["dq", "dk", "dv", "dsink"]):
+        assert_close(a, b, atol=2e-6, rtol=2e-6, msg=f"{nm} vs unpadded")
+        assert_close(a, c, atol=5e-5, rtol=5e-5, msg=f"{nm} vs oracle")

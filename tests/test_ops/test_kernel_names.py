@@ -49,12 +49,12 @@ def _pallas_calls(jaxpr):
     return found
 
 
-def _grad_fn(t, head_block, block_q, block_k):
+def _grad_fn(t, head_block, block_q, block_k, grid="row_major"):
     qr, kr, ts = ranges_of(varlen_block_causal(t))
 
     def loss(q, k, v):
         out, lse = flex_flash_attn_func(
-            q, k, v, qr, kr, ts, grid="row_major", head_block=head_block,
+            q, k, v, qr, kr, ts, grid=grid, head_block=head_block,
             block_q=block_q, block_k=block_k, interpret=True,
         )
         return out.astype(jnp.float32).sum() + lse.sum()
@@ -87,6 +87,34 @@ def test_head_block_is_the_leading_grid_dimension_of_all_three(
     assert len(grids["magi_flex_dkv_kernel"]) == 3  # the group is in the step
 
 
+@pytest.mark.parametrize("hq,hk,head_block", [(8, 2, 4), (8, 2, 8), (4, 4, 2)])
+def test_compact_grid_is_head_groups_by_entries_for_all_three(
+    hq, hk, head_block
+):
+    """On the compact grid the three head-batched kernels launch one step
+    an entry of their table and nothing else: (hq // head_block, E) for
+    the forward and dq, (hk // (head_block // group), E2) for dkv."""
+    from magiattention_tpu.ops import build_block_meta
+
+    t, d = 512, 64
+    qr, kr, ts = ranges_of(varlen_block_causal(t))
+    meta = build_block_meta(qr, kr, ts, t, t, block_q=128, block_k=128)
+    q = jnp.ones((t, hq, d), jnp.float32)
+    kv = jnp.ones((t, hk, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(_grad_fn(t, head_block, 128, 128, "sparse"))(
+        q, kv, kv
+    )
+    assert len(_pallas_calls(jaxpr.jaxpr)) == 3
+    group = hq // hk
+    assert dict(_pallas_calls(jaxpr.jaxpr)) == {
+        "magi_flex_fwd_kernel": (hq // head_block, meta.num_fwd_entries),
+        "magi_flex_dq_kernel": (hq // head_block, meta.num_fwd_entries),
+        "magi_flex_dkv_kernel": (
+            hk // (head_block // group), meta.num_bwd_entries,
+        ),
+    }
+
+
 def _builds():
     from magiattention_tpu import telemetry
 
@@ -109,6 +137,7 @@ def telemetry_on():
     telemetry.set_enabled(was)
 
 
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize(
     "head_block,block,bwd_heads",
     [
@@ -119,16 +148,16 @@ def telemetry_on():
         (4, (2048, 2048), 1),
     ],
 )
-def test_build_counter_carries_heads_per_step(
-    telemetry_on, head_block, block, bwd_heads
+def test_build_counter_carries_heads_per_step_and_grid(
+    telemetry_on, head_block, block, bwd_heads, grid
 ):
     t, hq, hk, d = 4096, 8, 2, 64
     q = jnp.ones((t, hq, d), jnp.float32)
     kv = jnp.ones((t, hk, d), jnp.float32)
-    jax.make_jaxpr(_grad_fn(t, head_block, *block))(q, kv, kv)
+    jax.make_jaxpr(_grad_fn(t, head_block, *block, grid))(q, kv, kv)
     name = "magi_flex_kernel_build_total"
     assert _builds() == {
-        f"{name}{{heads_per_step={head_block},kernel=fwd}}": 1,
-        f"{name}{{heads_per_step={bwd_heads},kernel=dq}}": 1,
-        f"{name}{{heads_per_step={bwd_heads},kernel=dkv}}": 1,
+        f"{name}{{grid={grid},heads_per_step={head_block},kernel=fwd}}": 1,
+        f"{name}{{grid={grid},heads_per_step={bwd_heads},kernel=dq}}": 1,
+        f"{name}{{grid={grid},heads_per_step={bwd_heads},kernel=dkv}}": 1,
     }

@@ -560,3 +560,72 @@ def test_bf16_distributed_reasonable():
     assert_close(
         out.astype(jnp.float32), ref_out, atol=3e-2, rtol=3e-2, msg="bf16 cp4"
     )
+
+
+@pytest.mark.parametrize("head_block", [1, 2], ids=["per-head", "hb=2"])
+@pytest.mark.parametrize("degree", [0, 2])
+def test_pipeline_agrees_between_the_two_grids(degree, head_block, monkeypatch):
+    """The plan's kernels on the row-major and on the compact grid
+    (ISSUE 27: ``make_attn_params`` sets ``FlexAttnParams.grid``, here
+    pinned each way by ``MAGI_ATTENTION_GRID``) over per-rank stacked,
+    padded, traced tables at cp=4: same out, lse and gradients, and the
+    oracle's."""
+    from magiattention_tpu.meta.solver.overlap_solver import OverlapConfig
+
+    name, total, qr, kr, ts = next(
+        s for s in SCENARIOS if s[0] == "mixed_types_with_holes"
+    )
+    cp, hq, hk, d = 4, 4, 2, 64
+    mesh = _mesh(cp)
+    mq, _, bucket = make_dispatch_meta_from_qk_ranges(
+        AttnRanges.from_ranges(qr), AttnRanges.from_ranges(kr), ts, total,
+        total, chunk_size=total // (4 * cp), cp_size=cp,
+    )
+    plan = build_dist_attn_plan(
+        mq, bucket, block_q=64, block_k=64,
+        overlap_config=OverlapConfig(degree=degree, min_stage_rows=64),
+    )
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((total, hq, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((total, hk, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((total, hk, d)), jnp.float32)
+    do = jnp.asarray(rng.standard_normal((total, hq, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((total, hq)), jnp.float32)
+
+    def results(grid):
+        monkeypatch.setenv("MAGI_ATTENTION_GRID", grid)
+        params = make_attn_params(
+            plan, d, out_dtype="float32", head_block=head_block
+        )
+        assert params.grid == grid
+        attn_fn = make_dist_attn_fn(plan, mesh, params)
+
+        def fwd(q, k, v):
+            out_d, lse_d = attn_fn(
+                dispatch(q, mq), dispatch(k, mq), dispatch(v, mq)
+            )
+            return undispatch(out_d, mq), undispatch(lse_d, mq)
+
+        def loss(q, k, v):
+            out, lse = fwd(q, k, v)
+            return (out * do).sum() + (
+                jnp.where(jnp.isneginf(lse), 0.0, lse) * w
+            ).sum()
+
+        return (
+            *jax.jit(fwd)(q, k, v),
+            *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v),
+        )
+
+    row_major, compact = results("row_major"), results("sparse")
+    ref_out, ref_lse, _ = ref_attn_from_ranges(q, k, v, qr, kr, ts)
+    for a, b, nm in zip(row_major, compact, ["out", "lse", "dq", "dk", "dv"]):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b), err_msg=f"{nm}: the grids disagree"
+        )
+    assert_close(compact[0], ref_out, atol=3e-5, rtol=3e-5, msg="out")
+    finite = ~np.isneginf(np.asarray(ref_lse))
+    assert_close(
+        np.asarray(compact[1])[finite], np.asarray(ref_lse)[finite],
+        atol=3e-5, rtol=3e-5, msg="lse",
+    )

@@ -1,0 +1,181 @@
+"""The grid of a built plan (ISSUE 27): ``make_attn_params`` counts, for the
+table sets it already walks, the steps the row-major and the compact grid
+would launch over forward, dq and dkv, prices them with the two per-step
+costs of ``tuning/cost_model.py`` and sets ``FlexAttnParams.grid``. Host
+only: the plans of the benchmark's six cells are built from their own
+traffic files, nothing runs on a device."""
+
+import importlib
+import os
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from magiattention_tpu import api, telemetry
+from magiattention_tpu.ops import build_block_meta
+from magiattention_tpu.parallel.dist_attn import StageTables
+from magiattention_tpu.testing.workloads import ranges_of, varlen_block_causal
+from magiattention_tpu.tuning import cost_model
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+@pytest.fixture
+def telemetry_on():
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    api.clear_cache()
+    yield
+    api.clear_cache()
+    telemetry.set_enabled(was)
+
+
+def _decisions(build) -> list[dict]:
+    """What ``make_attn_params`` left on the ``attn_fn_build`` spans that
+    ``build()`` opened: rung, grid, the three counts, the two prices."""
+    seen = len(telemetry.get_event_buffer().events())
+    build()
+    return [
+        ev["args"]
+        for ev in telemetry.get_event_buffer().events()[seen:]
+        if ev["name"] == "attn_fn_build"
+    ]
+
+
+def _build_cell(name: str):
+    """The plan(s) of one cell of BENCHMARK.json, as its traffic kind
+    builds them (keyed API for the attention cells, the model builders
+    for the training cells)."""
+    from benchmarks import harness, masks
+
+    cell = harness.load_cell(ROOT, name)
+    cfg, tr = cell.config, cell.traffic
+    total = int(tr["total_tokens"])
+    mask = masks.build_mask(tr["mask"], total)
+    devices = jax.devices()[: cell.chips]
+    if tr["kind"] != "attn_iter":
+        kind = importlib.import_module("benchmarks.kinds." + tr["kind"])
+        return kind.Job(cfg, tr, 0, devices).build(mask)
+    mesh = Mesh(np.array(devices), ("cp",))
+    args = dict(
+        num_heads=(cfg["num_attention_heads"], cfg["num_key_value_heads"]),
+        head_dim=cfg["head_dim"], chunk_size=tr.get("chunk_size"),
+        out_dtype=cfg["dtype"], interpret=False,
+    )
+    if mask.doc_lengths:
+        return api.magi_attn_varlen_key(mask.cu_seqlens, total, mesh, **args)
+    return api.magi_attn_flex_key(
+        list(mask.q_ranges), list(mask.k_ranges), list(mask.types),
+        total, total, mesh, **args,
+    )
+
+
+# cell -> a plan each: (rung, grid, steps a head group launches over
+# forward + dq + dkv on the row-major grid, on the compact grid, and the
+# entries of a non-empty slice among them). ISSUE 27's table counts with
+# build_block_meta: 512 x 34 and 128 x 132 for the packed cell, 3,640
+# entries. The plan has one k block more (the merged KV buffer ends in the
+# group cast's receive pad: 129 x 132) and 5 of the 3,640 are padding.
+CELLS = {
+    "magi64x8-attn-64k-varlen": [
+        ((128, 512, 8), "sparse", 2 * 512 * 34 + 129 * 132, 3 * 3640, 3 * 3635),
+    ],
+    "magi64x8-attn-64k-causal": [
+        ((1024, 1024, 1), "sparse", 2 * 64 * 64 + 65 * 64, 6248, 3 * 2080),
+    ],
+    "mistral7b-train-16k-onemask": [
+        ((128, 512, 8), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
+    ],
+    "magi64x8-attn-cp4-256k-varlen": [
+        ((1024, 1024, 1), "sparse", 12060, 2448, 2306.25),
+    ],
+    "trinitymini-train-32k-packed": [
+        ((128, 512, 8), "sparse", 2 * 256 * 16 + 65 * 62, 3 * 1128, 3363),
+        ((128, 512, 8), "sparse", 6680, 2568, 2562),
+    ],
+    "magi64x8-attn-64k-swa1024": [
+        ((1024, 1024, 1), "row_major", 2 * 64 * 2 + 65 * 2, 384, 381),
+    ],
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_cell_keeps_its_rung_and_gets_the_expected_grid(
+    telemetry_on, cell, monkeypatch
+):
+    monkeypatch.delenv("MAGI_ATTENTION_GRID", raising=False)
+    got = _decisions(lambda: _build_cell(cell))
+    assert len(got) == len(CELLS[cell])
+    for args, (rung, grid, row_major, compact, live) in zip(got, CELLS[cell]):
+        assert tuple(args["rung"]) == rung
+        assert (args["row_major_steps"], args["compact_steps"]) == (
+            row_major, compact,
+        )
+        assert args["live_steps"] == pytest.approx(live)
+        assert args["grid"] == cost_model.choose_grid(row_major, compact)
+        if grid is not None:
+            assert args["grid"] == grid
+    # the gauge holds the newest plan's share, on the grid it was given
+    launched = args["compact_steps" if args["grid"] == "sparse" else "row_major_steps"]
+    assert telemetry.snapshot()["gauges"][
+        "magi_flex_dead_step_share"
+    ] == pytest.approx(100.0 * (1.0 - args["live_steps"] / launched))
+
+
+def test_the_packed_cells_dead_share_on_both_grids(telemetry_on, monkeypatch):
+    """79% of the row-major grid's steps do nothing in the packed cell,
+    and what is left on the compact one is the 5 padding entries a table;
+    ``MAGI_ATTENTION_GRID`` pins the grid here as in ``auto_kernel_config``."""
+    shares = {}
+    for grid in ("row_major", "sparse"):
+        monkeypatch.setenv("MAGI_ATTENTION_GRID", grid)
+        api.clear_cache()
+        (args,) = _decisions(
+            lambda: _build_cell("magi64x8-attn-64k-varlen")
+        )
+        assert args["grid"] == grid
+        shares[grid] = telemetry.snapshot()["gauges"]["magi_flex_dead_step_share"]
+    assert shares["row_major"] == pytest.approx(78.97, abs=0.01)
+    assert shares["sparse"] == pytest.approx(100 * 5 / 3640, abs=0.01)
+
+
+def test_stacked_per_rank_tables_count_padded_entries_as_launched():
+    """Two ranks' tables stacked to the longer one's length: the compact
+    grid launches the padded length on every rank, the row-major grid
+    blocks x the longest row of any rank, and the live count is the mean
+    of the ranks' own entries."""
+    metas = [
+        build_block_meta(*ranges_of(varlen_block_causal(t)), 2048, 2048,
+                         block_q=128, block_k=128)
+        for t in (2048, 1024)
+    ]
+    tables = StageTables.from_rank_metas(metas, 2048)
+    fs, bs = tables.kernel_steps()
+    row_major, compact, live = tables.grid_steps(fs, bs)
+    e = max(m.num_fwd_entries for m in metas)
+    e2 = max(m.num_bwd_entries for m in metas)
+    assert e > min(m.num_fwd_entries for m in metas)  # rank 1 is padded
+    assert compact == 2 * e + e2
+    assert row_major == 2 * 16 * fs + 16 * bs
+
+    def works(meta):
+        b = meta.slice_bounds.reshape(-1, 5)
+        ok = (b[:, 1] > b[:, 0]) & (b[:, 3] > b[:, 2])
+        return 2 * ok[meta.fwd_slice_id].sum() + ok[meta.bwd_slice_id].sum()
+
+    assert live == pytest.approx(np.mean([works(m) for m in metas]))
+    assert live < compact
+
+
+def test_the_flip_needs_the_saving_to_pass_the_error_bar():
+    dead, fee = cost_model.DEAD_ROW_MAJOR_STEP_S, cost_model.COMPACT_STEP_EXTRA_S
+    assert cost_model.price_grids(1000, 400) == (600 * dead, 400 * fee)
+    assert cost_model.price_grids(400, 400) == (0.0, 400 * fee)
+    assert cost_model.choose_grid(400, 400) == "row_major"
+    # break-even: dead * DEAD = MARGIN * entries * FEE
+    entries = 10_000
+    even = entries * cost_model.GRID_FLIP_MARGIN * fee / dead
+    assert cost_model.choose_grid(entries + int(even * 0.98), entries) == "row_major"
+    assert cost_model.choose_grid(entries + int(even * 1.02) + 1, entries) == "sparse"
