@@ -5,7 +5,7 @@
 
 PY ?= python
 
-.PHONY: install test test-fast test-slow lint typecheck bench-plan telemetry-check autotune-check perf-gate timeline-demo serving-check sched-check decode-bench comm-check analyze spmd-audit lifecycle-check resilience-check roofline-check roofline-report trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check check
+.PHONY: install test test-fast test-slow lint typecheck telemetry-check autotune-check timeline-demo serving-check sched-check comm-check analyze spmd-audit lifecycle-check resilience-check roofline-check trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check check
 
 install:
 	$(PY) -m pip install -e . --no-build-isolation
@@ -29,7 +29,7 @@ lint:
 		$(PY) -m ruff check magiattention_tpu tests exps examples; \
 	else \
 		echo "ruff not installed; syntax-checking via compileall"; \
-		$(PY) -m compileall -q magiattention_tpu tests exps examples bench.py chip_smoke.py __graft_entry__.py; \
+		$(PY) -m compileall -q magiattention_tpu tests exps examples chip_smoke.py __graft_entry__.py; \
 	fi
 
 typecheck:
@@ -38,10 +38,6 @@ typecheck:
 	else \
 		echo "mypy not installed; skipping (pip install -e .[dev])"; \
 	fi
-
-# host-side planning latency sweep (no devices needed)
-bench-plan:
-	$(PY) exps/run_plan_bench.py
 
 # telemetry drift guard: build a tiny CPU-backend plan with telemetry on
 # and assert the snapshot carries every metric docs/observability.md
@@ -55,14 +51,6 @@ telemetry-check:
 # an intentional recalibration)
 autotune-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_autotune_check.py
-
-# perf regression sentinel (model-safe CPU mode: pure file parsing, no
-# jax): the newest BENCH_HISTORY.jsonl values must sit inside the
-# checked-in exps/data/perf_expectations.json windows, AND an injected
-# 20% TF/s regression must be caught (--self-test asserts both). Re-seed
-# after an intentional perf change: exps/run_perf_gate.py --update
-perf-gate:
-	$(PY) exps/run_perf_gate.py --self-test
 
 # measured-timeline demo on the virtual CPU mesh: per-stage comm/compute
 # wall times, predicted-vs-measured overlap audit, cross-rank aggregate,
@@ -87,11 +75,6 @@ serving-check:
 # (exps/run_scheduler_check.py exits non-zero on any violation)
 sched-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_scheduler_check.py
-
-# split-KV decode throughput grid (tokens/s + effective KV bandwidth);
-# CPU uses the jnp reference backend, TPU the Pallas kernel
-decode-bench:
-	$(PY) exps/run_decode_bench.py
 
 # group-collective drift guard (CPU, virtual mesh): hops-vs-a2a parity
 # on a canonical skewed varlen plan (bit-identical cast recv buffer, no
@@ -236,17 +219,11 @@ plan-reuse-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_plan_reuse_check.py --self-test
 	JAX_PLATFORMS=cpu $(PY) exps/run_plan_reuse_check.py
 
-# mask-aware roofline report + occupancy JSON artifact for the 16k
-# varlen block-causal headline (docs/observability.md "Roofline &
-# occupancy"); host-side only
-roofline-report:
-	JAX_PLATFORMS=cpu $(PY) exps/run_roofline_report.py
-
 # the default check flow: syntax, static analysis, telemetry catalog +
-# timeline/aggregate semantics, autotuner rung expectations, perf gate,
+# timeline/aggregate semantics, autotuner rung expectations,
 # serving parity, shared-prefix/scheduler gate, group-collective
 # parity/volume, resilience gate, roofline/occupancy gate, request
 # tracing/exposition gate, disaggregated-serving gate, memory
 # observability gate, unified-tick gate, numerics observability gate,
 # fleet simulator + autopilot gate, plan-reuse gate — all CPU-safe
-check: lint analyze telemetry-check autotune-check perf-gate serving-check sched-check comm-check resilience-check roofline-check trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check
+check: lint analyze telemetry-check autotune-check serving-check sched-check comm-check resilience-check roofline-check trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check

@@ -4,7 +4,7 @@ Role of reference ``examples/torch_native/main.py`` (Llama FSDP+CP trainer),
 TPU-native: a (dp, cp) mesh, varlen packed batches, the key-cached dispatch
 workflow, and a jitted train step where the whole model runs inside one
 shard_map. Every ``--masks`` draws a new packed-document mask (document
-lengths from ``exps/data/doc_length_distribution.csv``) and trains
+lengths from ``testing/data/doc_length_distribution.csv``) and trains
 ``--steps`` steps on it: a new mask means a new plan and a new compiled
 step, and the script prints what each cost.
 
@@ -137,8 +137,8 @@ def packed_mask(args, mask_idx: int):
     another cp draw the same documents."""
     import numpy as np
 
-    from exps.run_dist_bench import sample_doc_cuts
     from magiattention_tpu.api import infer_varlen_mask_from_batch
+    from magiattention_tpu.testing.workloads import sample_doc_cuts
 
     cuts = sample_doc_cuts(
         args.total, np.random.default_rng([args.seed, mask_idx])

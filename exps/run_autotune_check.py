@@ -36,22 +36,21 @@ EXPECTATIONS = os.path.join(
 
 # the ranking is generation-dependent (eff_flops vs the fixed grid-step
 # overhead), so the guard pins the generation the checked-in expectations
-# and the BENCH_r05 on-chip numbers were taken on — a developer's exported
+# were taken on — a developer's exported
 # MAGI_ATTENTION_TPU_GENERATION must neither fail the check spuriously nor
 # bake another chip's ranking into the file via --update
 PINNED_GENERATION = "v5e"
 
 
 def canonical_workloads():
-    from run_kernel_bench import mask_families
+    from magiattention_tpu.testing.workloads import (
+        mask_families,
+        varlen_block_causal,
+    )
 
-    from magiattention_tpu.testing.workloads import varlen_block_causal
-
-    # the varlen entry is the EXACT mask the 8.44 TF/s headline metric
-    # (bench.py `_varlen_slices`, run_roofline_report's gate, and the
-    # seeded step-reduction ratio) is measured on — the ISSUE 15
-    # invariants below must guard that mask, not a near-relative with a
-    # different skew profile
+    # the varlen entry is the 16k packed mask the ISSUE 15 invariants
+    # below were stated on — they must guard that mask, not a
+    # near-relative with a different skew profile
     sl = varlen_block_causal(16384)
     varlen = (
         [(int(a), int(b)) for a, b, *_ in sl],

@@ -7,25 +7,17 @@ Three assertions on the hop-scheduled collectives, all CPU-safe:
    a matching sum-reduce against the legacy globally-padded a2a, on a
    real 4-device virtual mesh — and its traced program must contain no
    ``all_to_all`` at all.
-2. **Volume** on the bench headline plan (16k varlen-block-causal, cp=4,
-   the ``flex_attn_fwd_tflops_16k_varlen_block_causal_bf16`` workload):
-   hop scheduling must cut scheduled comm volume by >= 30% vs the legacy
+2. **Volume** on the 16k varlen-block-causal plan at cp=4: hop
+   scheduling must cut scheduled comm volume by >= 30% vs the legacy
    padded volume (the ISSUE 5 acceptance floor), and auto mode must pick
    hops there.
 3. **Auto-mode choice sanity**: a perfectly uniform nonlocal send map
    stays on a2a (hop scheduling saves nothing), an empty map resolves to
    hops with zero hops (no collective traced).
 
-``--seed-history`` appends the headline volume-reduction figure to
-``BENCH_HISTORY.jsonl`` as ``flex_attn_comm_volume_reduction_16k_varlen_
-block_causal`` (higher = better, legacy-padded / scheduled rows) so
-``make perf-gate`` gates scheduled-volume regressions like TF/s — run
-``exps/run_perf_gate.py --update`` afterwards to (re)seed its window.
-
 Exit codes: 0 = pass, 1 = drift/violation.
 """
 
-import argparse
 import functools
 import os
 import sys
@@ -36,13 +28,9 @@ sys.path.insert(
 
 
 def _setup_cpu_mesh_env() -> None:
-    """Force the 8-virtual-device CPU platform for SCRIPT runs only.
-    This module is also imported as a library by the live on-chip bench
-    (``bench.py`` pulls :func:`comm_probe` for its summary line and
-    history metric) — the environment must not be mutated at import
-    time there. Must run before jax initializes (every jax import below is
-    function-local, so calling this at the top of ``main`` is early
-    enough)."""
+    """Force the 8-virtual-device CPU platform. Must run before jax
+    initializes (every jax import below is function-local, so calling
+    this at the top of ``main`` is early enough)."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -51,7 +39,6 @@ def _setup_cpu_mesh_env() -> None:
         ).strip()
 
 
-HEADLINE_METRIC = "flex_attn_comm_volume_reduction_16k_varlen_block_causal"
 VOLUME_REDUCTION_FLOOR = 0.30  # ISSUE 5 acceptance criterion
 
 
@@ -85,28 +72,6 @@ def _headline_plan_meta(total: int, cp: int, impl: str):
         else:
             os.environ["MAGI_ATTENTION_GROUP_COLL_IMPL"] = prev
     return plan.merged_comm
-
-
-def comm_probe(total: int = 16384, cp: int = 4) -> dict:
-    """The bench 'comm probe' payload: true / scheduled / legacy-padded
-    rows and the auto-mode impl choice for the headline varlen plan.
-    Host-side planning only — no devices."""
-    comm = _headline_plan_meta(total, cp, "auto")
-    padded = comm.padded_rows_per_rank
-    scheduled = comm.scheduled_rows_per_rank
-    return {
-        "total": total,
-        "cp": cp,
-        "impl": comm.impl,
-        "impl_reason": comm.impl_reason,
-        "true_rows_total": comm.true_rows_total,
-        "scheduled_rows_per_rank": scheduled,
-        "padded_rows_per_rank": padded,
-        "volume_reduction": 1.0 - scheduled / padded if padded else 0.0,
-        "volume_reduction_metric": (
-            round(padded / scheduled, 3) if scheduled else float(cp)
-        ),
-    }
 
 
 def check_parity(total: int = 4096, cp: int = 4) -> list[str]:
@@ -189,7 +154,19 @@ def check_parity(total: int = 4096, cp: int = 4) -> list[str]:
 
 
 def check_volume() -> tuple[list[str], dict]:
-    probe = comm_probe()
+    """True / scheduled / legacy-padded rows and the auto-mode impl
+    choice for the 16k varlen plan. Host-side planning only."""
+    comm = _headline_plan_meta(16384, 4, "auto")
+    padded = comm.padded_rows_per_rank
+    scheduled = comm.scheduled_rows_per_rank
+    probe = {
+        "impl": comm.impl,
+        "impl_reason": comm.impl_reason,
+        "true_rows_total": comm.true_rows_total,
+        "scheduled_rows_per_rank": scheduled,
+        "padded_rows_per_rank": padded,
+        "volume_reduction": 1.0 - scheduled / padded if padded else 0.0,
+    }
     errors: list[str] = []
     if probe["impl"] != "hops":
         errors.append(
@@ -233,52 +210,8 @@ def check_auto_choice() -> list[str]:
     return errors
 
 
-def seed_history(metric_value: float) -> None:
-    """Append the comm-volume metric to BENCH_HISTORY.jsonl. The gate
-    checks the NEWEST entry only, so the seed entry carries the newest
-    entry's gated TF/s values forward unchanged (explicitly sourced) —
-    the TF/s floor stays armed until the next real bench run appends a
-    combined entry of its own."""
-    from magiattention_tpu.telemetry import baseline
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, baseline.HISTORY_FILENAME)
-    history = baseline.load_history(path)
-    prev = baseline.newest_metrics(history)
-    metrics = {
-        k: v
-        for k, v in prev.items()
-        if k.startswith("flex_attn_") and "tflops" in k
-    }
-    metrics[HEADLINE_METRIC] = metric_value
-    prev_entry = history[-1] if history else {}
-    baseline.append_history(
-        path,
-        baseline.make_history_entry(
-            source=(
-                "exps/run_comm_check.py --seed-history "
-                f"(TF/s carried forward from {prev_entry.get('source')})"
-            ),
-            metrics=metrics,
-            autotune_rung=prev_entry.get("autotune_rung"),
-        ),
-    )
-    print(f"comm-check: appended {HEADLINE_METRIC}={metric_value} -> {path}")
-    print("comm-check: now run `python exps/run_perf_gate.py --update` to "
-          "(re)seed the expectation window")
-
-
 def main() -> int:
     _setup_cpu_mesh_env()
-    p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument(
-        "--seed-history",
-        action="store_true",
-        help="append the headline volume-reduction metric to "
-        "BENCH_HISTORY.jsonl for the perf gate",
-    )
-    args = p.parse_args()
-
     failures: list[str] = []
 
     print("comm-check 1/3: hops vs a2a parity on the 4k skewed varlen plan")
@@ -306,8 +239,6 @@ def main() -> int:
     if failures:
         print(f"\ncomm-check FAILED: {len(failures)} violation(s)")
         return 1
-    if args.seed_history:
-        seed_history(probe["volume_reduction_metric"])
     print("\ncomm-check OK")
     return 0
 

@@ -266,9 +266,7 @@ def fleet_probe(
     seed: int = 7,
 ) -> dict:
     """Replay a zipf/lognormal trace through the real Scheduler with a
-    PlanReuseProbe attached; return the reuse scorecard. Shared with
-    ``bench.py`` (extras section) so the perf gate tracks the same
-    numbers this gate bounds."""
+    PlanReuseProbe attached; return the reuse scorecard."""
     from magiattention_tpu import telemetry
     from magiattention_tpu.fleet import FleetSimulator, generate_trace
     from magiattention_tpu.serving import PlanReuseProbe

@@ -22,22 +22,16 @@ scheduler on a multi-tenant trace (chunked long prompt + short tenants
    one-row demux shift (outputs rolled across tick rows) must be
    caught by the parity gate, proving the oracle actually bites.
 
-Exits non-zero on any violation. ``tick_probe()`` is the bench.py
-hook: it measures ``launches_per_tick`` and per-tick engine latency
-for the BENCH_HISTORY.jsonl trajectory.
+Exits non-zero on any violation.
 """
 
 import os
-import statistics
 import sys
 
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 if __name__ == "__main__":
-    # env shaping only when run AS the gate — bench.py imports
-    # tick_probe from an already-initialized jax process and must not
-    # have its platform/backend silently rewritten
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["MAGI_ATTENTION_KERNEL_BACKEND"] = "jnp"
 
@@ -106,7 +100,7 @@ def _submit_trace(sched: Scheduler) -> None:
 def _drive(mode: str):
     """Run the canonical trace under ``mode``; returns (schedule
     structure, per-request outputs, per-tick launch counts, per-tick
-    program labels, per-tick engine seconds)."""
+    program labels)."""
     os.environ["MAGI_ATTENTION_UNIFIED_TICK"] = mode
     os.environ["MAGI_ATTENTION_CASCADE"] = "auto"
     eng = ServingEngine(
@@ -115,7 +109,7 @@ def _drive(mode: str):
     )
     sched = Scheduler(eng, token_budget=24, chunk=PS)
     _submit_trace(sched)
-    schedule, launches, programs, engine_s = [], [], [], []
+    schedule, launches, programs = [], [], []
     ticks = 0
     while (sched.waiting or sched.num_active) and ticks < 128:
         rep = sched.step()
@@ -131,7 +125,6 @@ def _drive(mode: str):
         )
         launches.append(len(set(sched._tick_programs)))
         programs.append(tuple(sched._tick_programs))
-        engine_s.append(sched._tick_engine_s)
     if sched.waiting or sched.num_active:
         raise RuntimeError(f"trace did not drain in {ticks} ticks")
     outs = {}
@@ -142,7 +135,7 @@ def _drive(mode: str):
             else np.asarray(st.prefill_out_tail),
             [np.asarray(o) for o in st.decode_outs],
         )
-    return schedule, outs, launches, programs, engine_s
+    return schedule, outs, launches, programs
 
 
 def _compare_outputs(o_off, o_on):
@@ -174,8 +167,8 @@ def _compare_outputs(o_off, o_on):
 
 
 def check_unified_gate() -> int:
-    s_off, o_off, l_off, _, _ = _drive("off")
-    s_on, o_on, l_on, p_on, _ = _drive("on")
+    s_off, o_off, l_off, _ = _drive("off")
+    s_on, o_on, l_on, p_on = _drive("on")
 
     # 1. launches per tick
     worst = max(l_on)
@@ -267,8 +260,8 @@ def check_demux_selftest() -> int:
 
     engine_mod.unified_tick_attn = shifted
     try:
-        _, o_off, _, _, _ = _drive("off")
-        _, o_on, _, _, _ = _drive("on")
+        _, o_off, _, _ = _drive("off")
+        _, o_on, _, _ = _drive("on")
     finally:
         engine_mod.unified_tick_attn = orig
     _max_err, _bitwise, where = _compare_outputs(o_off, o_on)
@@ -279,33 +272,6 @@ def check_demux_selftest() -> int:
         )
     print(f"tick-check: planted demux off-by-one caught ({where})")
     return 0
-
-
-def tick_probe() -> dict:
-    """bench.py hook (ISSUE 17 satellite): launches-per-tick and tick
-    latency of the canonical trace under the unified path, for the
-    BENCH_HISTORY.jsonl trajectory."""
-    backup = {
-        k: os.environ.get(k)
-        for k in ("MAGI_ATTENTION_UNIFIED_TICK", "MAGI_ATTENTION_CASCADE")
-    }
-    try:
-        _, _, launches, _, engine_s = _drive("on")
-    finally:
-        for k, vv in backup.items():
-            if vv is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = vv
-    active = [s for s, n in zip(engine_s, launches) if n]
-    return {
-        "sched_launches_per_tick_unified_max": max(launches),
-        "sched_tick_latency_ms_p50": round(
-            statistics.median(active) * 1e3, 3
-        )
-        if active
-        else 0.0,
-    }
 
 
 def main() -> int:

@@ -1,29 +1,19 @@
 """Benchmark harness (reference ``magi_attention/benchmarking/``)."""
 
 from .bench import (
-    Benchmark,
     BenchResult,
-    Mark,
     MemoryRecorder,
     chained_ms,
     do_bench,
     enable_compile_cache,
-    image_grid,
     mesh_barrier,
-    perf_grid,
-    perf_report,
 )
 
 __all__ = [
-    "Benchmark",
     "BenchResult",
-    "Mark",
     "MemoryRecorder",
     "chained_ms",
     "do_bench",
     "enable_compile_cache",
-    "image_grid",
     "mesh_barrier",
-    "perf_grid",
-    "perf_report",
 ]

@@ -1,7 +1,7 @@
-"""do_bench / perf_report: timing harness for TPU.
+"""do_bench: timing harness for TPU.
 
 Role of reference ``benchmarking/bench.py`` (CUDA-event do_bench + NVML
-memory recorder + Mark/perf_report): wall-clock timing in which every
+memory recorder): wall-clock timing in which every
 measured region ends in ``jax.block_until_ready`` on the whole result
 (jax returns before the device finishes, so a timing without it measures
 the enqueue), plus jax device memory stats.
@@ -13,7 +13,7 @@ import dataclasses
 import functools
 import statistics
 import time
-from typing import Any, Callable, Sequence
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -223,134 +223,6 @@ def do_bench(
     )
 
 
-@dataclasses.dataclass(frozen=True)
-class Benchmark:
-    """Declarative benchmark grid (reference ``Benchmark``/``Mark``,
-    benchmarking/bench.py:232-767): sweep ``x_vals`` along ``x_name``, one
-    measured line per value of ``line_arg`` in ``line_vals``; the decorated
-    function receives (x_name=..., line_arg=..., **args) per cell and
-    returns a float (ms) or a dict of extra columns."""
-
-    x_name: str
-    x_vals: Sequence[Any]
-    line_arg: str
-    line_vals: Sequence[Any]
-    line_names: Sequence[str] | None = None
-    plot_name: str = "benchmark"
-    args: dict[str, Any] = dataclasses.field(default_factory=dict)
-    ylabel: str = "ms"
-
-
-class Mark:
-    """Runner bound to one Benchmark grid; produced by :func:`perf_grid`."""
-
-    def __init__(self, fn: Callable, bench: Benchmark):
-        self._fn = fn
-        self.bench = bench
-
-    def run(
-        self,
-        *,
-        print_data: bool = True,
-        save_path: str | None = None,
-        show_plots: bool = False,
-    ) -> list[dict[str, Any]]:
-        b = self.bench
-        names = list(b.line_names or [str(v) for v in b.line_vals])
-        rows: list[dict[str, Any]] = []
-        for x in b.x_vals:
-            row: dict[str, Any] = {b.x_name: x}
-            for lv, nm in zip(b.line_vals, names):
-                res = self._fn(**{b.x_name: x, b.line_arg: lv}, **b.args)
-                if isinstance(res, dict):
-                    for key, val in res.items():
-                        row[f"{nm}_{key}"] = val
-                else:
-                    row[nm] = res
-            rows.append(row)
-        if print_data:
-            print(perf_report(rows))
-        if save_path and rows:
-            import csv
-            import os
-
-            os.makedirs(save_path, exist_ok=True)
-            csv_path = os.path.join(save_path, f"{b.plot_name}.csv")
-            fields: list[str] = []  # union across rows (cells may differ)
-            for r in rows:
-                fields.extend(k for k in r if k not in fields)
-            with open(csv_path, "w", newline="") as f:
-                w = csv.DictWriter(f, fieldnames=fields, restval="")
-                w.writeheader()
-                w.writerows(rows)
-            self._plot(rows, names, save_path, show_plots)
-        return rows
-
-    def _plot(self, rows, names, save_path, show):
-        try:
-            import os
-
-            import matplotlib
-
-            matplotlib.use("Agg")
-            import matplotlib.pyplot as plt
-        except Exception:  # matplotlib optional
-            return
-        b = self.bench
-        xs = [r[b.x_name] for r in rows]
-        fig, ax = plt.subplots(figsize=(7, 4.5))
-        for nm in names:
-            if nm in rows[0]:
-                ax.plot(xs, [r[nm] for r in rows], marker="o", label=nm)
-        ax.set_xlabel(b.x_name)
-        ax.set_ylabel(b.ylabel)
-        ax.set_title(b.plot_name)
-        ax.legend()
-        ax.grid(True, alpha=0.3)
-        fig.tight_layout()
-        fig.savefig(os.path.join(save_path, f"{b.plot_name}.png"), dpi=120)
-        if show:  # pragma: no cover
-            plt.show()
-        plt.close(fig)
-
-
-def perf_grid(bench: Benchmark):
-    """Decorator: ``@perf_grid(Benchmark(...))`` -> a :class:`Mark` whose
-    ``.run(save_path=...)`` sweeps the grid, prints the table, and writes
-    CSV + PNG (reference perf_report decorator)."""
-
-    def wrap(fn: Callable) -> Mark:
-        return Mark(fn, bench)
-
-    return wrap
-
-
-def perf_report(
-    rows: Sequence[dict[str, Any]],
-    *,
-    sort_key: str | None = None,
-) -> str:
-    """Plain-text table of benchmark rows (reference Mark/perf_report)."""
-    if not rows:
-        return "(no results)"
-    cols = list(rows[0].keys())
-    if sort_key:
-        rows = sorted(rows, key=lambda r: r[sort_key])
-    widths = {
-        c: max(len(str(c)), *(len(f"{r.get(c, '')}") for r in rows))
-        for c in cols
-    }
-    lines = [
-        "  ".join(str(c).ljust(widths[c]) for c in cols),
-        "  ".join("-" * widths[c] for c in cols),
-    ]
-    for r in rows:
-        lines.append(
-            "  ".join(f"{r.get(c, '')}".ljust(widths[c]) for c in cols)
-        )
-    return "\n".join(lines)
-
-
 def enable_compile_cache() -> str:
     """Turn on the persistent XLA compilation cache and return its
     directory. The cache is placed from outside: where
@@ -371,54 +243,3 @@ def enable_compile_cache() -> str:
         cache_dir = os.path.join(checkout, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     return cache_dir
-
-
-def image_grid(
-    paths: Sequence[str],
-    out_path: str,
-    cols: int | None = None,
-) -> str | None:
-    """Tile saved benchmark plot PNGs into one grid image (role of
-    reference ``benchmarking/image_grid.py``: its make_grid collage of
-    sweep plots). ``cols=None`` picks the near-square factorization.
-    Returns ``out_path``, or None when matplotlib/PIL are unavailable or
-    no inputs exist (report tooling must never take a bench run down)."""
-    import math
-    import os
-
-    paths = [p for p in paths if os.path.exists(p)]
-    if not paths:
-        return None
-    try:
-        import matplotlib
-
-        matplotlib.use("Agg")
-        import matplotlib.image as mpimg
-        import matplotlib.pyplot as plt
-    except Exception:
-        return None
-    n = len(paths)
-    if cols is None:
-        cols = max(1, int(math.ceil(math.sqrt(n))))
-    nrows = -(-n // cols)
-    try:
-        fig, axes = plt.subplots(
-            nrows, cols, figsize=(5.5 * cols, 4.0 * nrows), squeeze=False
-        )
-        for i, ax in enumerate(axes.flat):
-            ax.axis("off")
-            if i < n:
-                ax.imshow(mpimg.imread(paths[i]))
-                ax.set_title(os.path.basename(paths[i]), fontsize=8)
-        fig.tight_layout()
-        fig.savefig(out_path, dpi=120)
-        plt.close(fig)
-    except Exception:
-        # truncated PNG, unwritable out_path, ... — report tooling must
-        # never take a bench run down
-        try:
-            plt.close("all")
-        except Exception:
-            pass
-        return None
-    return out_path

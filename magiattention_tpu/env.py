@@ -276,15 +276,6 @@ def recompile_storm_threshold() -> int:
     return v
 
 
-def perf_gate_tolerance() -> float:
-    """Fractional TF/s regression the perf gate tolerates before failing
-    (``exps/run_perf_gate.py`` / ``make perf-gate``): a run below
-    ``expectation_low * (1 - tolerance)`` fails the gate. 0.10 covers the
-    shared chip's observed run-to-run drift; tighten on dedicated
-    hardware."""
-    return _env_float("MAGI_ATTENTION_PERF_GATE_TOLERANCE", 0.10)
-
-
 def timeline_reps() -> int:
     """Timed reps per stage in the measured-timeline profiler
     (``telemetry/timeline.py``); each rep is median-filtered by the
@@ -302,13 +293,6 @@ def timeline_inner() -> int:
 def is_sanity_check_enabled() -> bool:
     """Deep invariant checks in the planners (reference env/general.py:75)."""
     return _env_bool("MAGI_ATTENTION_SANITY_CHECK")
-
-
-def is_deterministic_mode_enabled() -> bool:
-    """Informational on TPU: the entry-table kernels are deterministic by
-    construction (sequential grid, no atomics) — the property the reference
-    needs range-locks/conflict-ordering to achieve (env/general.py:181)."""
-    return _env_bool("MAGI_ATTENTION_DETERMINISTIC_MODE")
 
 
 def min_chunks_per_rank() -> int:
@@ -579,12 +563,13 @@ def tier_token_budget(tier: str) -> int:
     so NOT part of :func:`flags_fingerprint`."""
     if tier not in SERVING_TIERS:
         raise ValueError(f"tier_token_budget: unknown tier {tier!r}")
-    v = _env_int(f"MAGI_ATTENTION_TIER_BUDGET_{tier.upper()}", 256)
+    name = {
+        "prefill": "MAGI_ATTENTION_TIER_BUDGET_PREFILL",
+        "decode": "MAGI_ATTENTION_TIER_BUDGET_DECODE",
+    }[tier]
+    v = _env_int(name, 256)
     if v < 1:
-        raise ValueError(
-            f"MAGI_ATTENTION_TIER_BUDGET_{tier.upper()}={v} must be a "
-            "positive token count"
-        )
+        raise ValueError(f"{name}={v} must be a positive token count")
     return v
 
 
@@ -816,7 +801,6 @@ def recommended_compiler_options() -> dict:
 def flags_fingerprint() -> tuple:
     """The behavior-influencing flags, folded into runtime-key hashing."""
     return (
-        is_deterministic_mode_enabled(),
         kernel_backend(),
         block_q(),
         block_k(),

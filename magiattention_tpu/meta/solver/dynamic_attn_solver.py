@@ -26,7 +26,7 @@ reference family's *roles*, not its implementations):
   greedy with random restarts — comm cost is computed on the MERGED
   per-rank row sets (what group-cast actually sends), so overlapping
   cell extents on one rank are counted once. Quality evidence vs
-  KD/NCQ: exps/run_dynsolver_bench.py + docs/dynamic_solver.md.
+  KD/NCQ: docs/dynamic_solver.md.
 
 The flow-based SNF solver (role of reference snf.py/fast_snf.py) lives
 in :mod:`.snf_solver`; :func:`dynamic_solver_for` maps every
@@ -485,7 +485,7 @@ class AutoDynamicSolver:
     reference's manually-selected algorithm family, made automatic: KD
     wins dense masks (free-position cuts), NCQ wins q-overlap-heavy
     masks (zero Q/O movement), the grid solver the varlen middle ground
-    (measured: exps/run_dynsolver_bench.py, docs/dynamic_solver.md).
+    (measured: docs/dynamic_solver.md).
     """
 
     def __init__(self, comm_rows_to_area: float = 1024.0, candidates=None):

@@ -259,8 +259,8 @@ class ServingEngine:
     host loops (slot bookkeeping, reservation growth, telemetry) and are
     NOT jittable; the jit boundary is the pure ops they drive — in
     production, wrap ``append_kv`` + :func:`magi_attn_decode` in one
-    ``jax.jit`` with a donated cache (what ``exps/run_decode_bench.py``
-    measures) and keep the engine's bookkeeping outside it.
+    ``jax.jit`` with a donated cache and keep the engine's bookkeeping
+    outside it.
     """
 
     def __init__(

@@ -1753,9 +1753,8 @@ def record_fleet_knob(knob: str, value: float) -> None:
 
 
 def telemetry_summary(snapshot: dict | None = None) -> str:
-    """Human-readable block of the headline plan/comm metrics — what
-    ``bench.py`` prints per run. Works on any snapshot dict (defaults to
-    the live registry's)."""
+    """Human-readable block of the headline plan/comm metrics. Works on
+    any snapshot dict (defaults to the live registry's)."""
     if snapshot is None:
         snapshot = get_registry().snapshot()
     g = snapshot.get("gauges", {})

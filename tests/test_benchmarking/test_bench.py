@@ -1,15 +1,8 @@
-"""Benchmark harness: grid runner, CSV/plot artifacts, do_bench sanity."""
-
-import os
+"""Benchmark harness: do_bench, mesh barrier and memory recorder sanity."""
 
 import jax.numpy as jnp
 
-from magiattention_tpu.benchmarking import (
-    Benchmark,
-    do_bench,
-    perf_grid,
-    perf_report,
-)
+from magiattention_tpu.benchmarking import do_bench
 
 
 def test_do_bench_times_and_memory():
@@ -18,50 +11,6 @@ def test_do_bench_times_and_memory():
     r = do_bench(f, x, warmup=1, rep=3, inner=2, record_memory=True)
     assert r.min_ms <= r.median_ms <= r.max_ms
     assert r.tflops(1e9) > 0
-
-
-def test_perf_grid_runs_and_writes_artifacts(tmp_path):
-    calls = []
-
-    @perf_grid(
-        Benchmark(
-            x_name="seqlen",
-            x_vals=[128, 256],
-            line_arg="impl",
-            line_vals=["a", "b"],
-            plot_name="toy",
-            args={"fixed": 7},
-        )
-    )
-    def bench_fn(seqlen, impl, fixed):
-        calls.append((seqlen, impl, fixed))
-        return float(seqlen) * (1.0 if impl == "a" else 2.0)
-
-    rows = bench_fn.run(print_data=False, save_path=str(tmp_path))
-    assert calls == [
-        (128, "a", 7), (128, "b", 7), (256, "a", 7), (256, "b", 7)
-    ]
-    assert rows[0] == {"seqlen": 128, "a": 128.0, "b": 256.0}
-    assert os.path.exists(tmp_path / "toy.csv")
-    assert os.path.exists(tmp_path / "toy.png")
-    txt = perf_report(rows)
-    assert "seqlen" in txt and "256.0" in txt
-
-
-def test_perf_grid_dict_results():
-    @perf_grid(
-        Benchmark(
-            x_name="n",
-            x_vals=[1],
-            line_arg="impl",
-            line_vals=["x"],
-        )
-    )
-    def bench_fn(n, impl):
-        return {"ms": 1.5, "tflops": 2.0}
-
-    rows = bench_fn.run(print_data=False)
-    assert rows == [{"n": 1, "x_ms": 1.5, "x_tflops": 2.0}]
 
 
 def test_mesh_barrier_and_synced_bench():
@@ -105,28 +54,3 @@ def test_memory_recorder_graceful_on_cpu():
     else:
         assert res.peak_bytes_per_device
         assert res.peak_bytes == max(res.peak_bytes_per_device)
-
-
-def test_image_grid(tmp_path):
-    """Tile per-sweep plot PNGs into one report image; missing inputs are
-    skipped, empty input returns None."""
-    import pytest
-
-    matplotlib = pytest.importorskip("matplotlib")
-    matplotlib.use("Agg")
-    import matplotlib.pyplot as plt
-
-    from magiattention_tpu.benchmarking import image_grid
-
-    paths = []
-    for i in range(3):
-        f, ax = plt.subplots(figsize=(2, 1.5))
-        ax.plot([0, 1], [i, 1])
-        p = str(tmp_path / f"plot{i}.png")
-        f.savefig(p)
-        plt.close(f)
-        paths.append(p)
-    out = image_grid(paths + [str(tmp_path / "missing.png")],
-                     str(tmp_path / "grid.png"))
-    assert out is not None and (tmp_path / "grid.png").exists()
-    assert image_grid([], str(tmp_path / "empty.png")) is None

@@ -644,13 +644,3 @@ class TestWatermarkGauges:
             "magi_sched_admission_headroom",
             "magi_kvcache_free_pages",
         }
-
-    def test_history_entry_carries_peak_hbm(self):
-        from magiattention_tpu.telemetry import baseline
-
-        e = baseline.make_history_entry(
-            source="t", metrics={"m": 1.0}, peak_hbm_bytes=12345,
-        )
-        assert e["peak_hbm_bytes"] == 12345
-        e2 = baseline.make_history_entry(source="t", metrics={"m": 1.0})
-        assert "peak_hbm_bytes" not in e2

@@ -366,7 +366,7 @@ def test_rerecord_without_measurement_clears_stale_efficiency():
 def test_sparse_grid_report_has_zero_dead_slots():
     """ISSUE 15: a sparse-grid analysis prices zero dead slots (the
     compact grid's extent IS the entry count) and its dead-step gap
-    share is exactly 0 — the roofline-report acceptance condition."""
+    share is exactly 0."""
     from magiattention_tpu.telemetry.roofline import analyze_workload
 
     qr = [(0, 1000), (1000, 4096)]

@@ -6,6 +6,7 @@ from magiattention_tpu.ops.block_meta import (
     build_block_meta_general,
     identity_runs,
 )
+from magiattention_tpu.testing.workloads import mask_families
 from magiattention_tpu.tuning import estimate_entries, rank_candidates
 
 
@@ -78,15 +79,6 @@ def test_regression_16k_varlen_block_causal_escapes_dense_rung():
     """THE ISSUE 2 regression: the static table ran this at 8.4 TF/s on a
     long-seq dense rung; the shape-aware model must select a small tile
     (narrow FULL slices waste most of a 1024-wide tile)."""
-    import os
-    import sys
-
-    sys.path.insert(
-        0,
-        os.path.join(os.path.dirname(__file__), "..", "..", "exps"),
-    )
-    from run_kernel_bench import mask_families
-
     qr, kr, ts = mask_families(16384)["varlen_block_causal"]
     ranked = rank_candidates(qr, kr, ts, 8, 8)
     best = ranked[0]
@@ -101,15 +93,6 @@ def test_regression_16k_varlen_block_causal_escapes_dense_rung():
 def test_16k_swa_prefers_occupancy_over_preference():
     """VERDICT flagged 16k SWA slower in absolute ms than 32k SWA under
     the static long-seq rule; the model keeps SWA on small tiles."""
-    import os
-    import sys
-
-    sys.path.insert(
-        0,
-        os.path.join(os.path.dirname(__file__), "..", "..", "exps"),
-    )
-    from run_kernel_bench import mask_families
-
     qr, kr, ts = mask_families(16384)["swa_causal"]
     best = rank_candidates(qr, kr, ts, 8, 8)[0]
     assert best.block_q * best.block_k < 1024 * 1024
