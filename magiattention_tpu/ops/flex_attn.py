@@ -41,6 +41,11 @@ from ..utils.compat import tpu_compiler_params
 
 NEG_INF = float("-inf")
 LANES = 128
+# What the forward's mask select writes inside a step, in place of -inf: a
+# finite value far under any logit, so the online-softmax update needs no
+# -inf guard (``_fwd_update``). -inf is restored where a q block is written
+# (``_fwd_finalize``); outside the forward kernels nothing sees this value.
+_MASK = -0.7 * float(np.finfo(np.float32).max)
 # Scoped-VMEM limit handed to Mosaic with every flex kernel. Under the
 # compiler's own default (16 MiB on v5e, of 128 MiB physical) the TPU
 # compiler refuses most head-batched sparse rungs and some backward rungs
@@ -224,9 +229,10 @@ def _entry_interval_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
     allowed iff lo(r) <= cl < hi(r). Computing lo/hi as [bq, 1] columns
     costs vector math on bq elements; the tile then pays ONE iota and two
     compares. Cheap enough to apply unconditionally, which is the point:
-    the previous per-entry ``lax.cond`` on needs_mask measured 110 -> 70
-    TF/s on dense-causal 64k (round-5 morph experiment), and the full
-    2-D ``_entry_mask`` applied unconditionally measured 52.
+    a per-entry ``lax.cond`` on needs_mask and the full 2-D
+    ``_entry_mask`` applied unconditionally were both slower on
+    dense-causal 64k in an earlier round whose records are gone (not
+    measured on this tree).
     """
     rbase = e * RUN_FIELDS
     ql0 = runs[rbase + 0]
@@ -331,12 +337,13 @@ def _scores_hb(q_ref, k_ref, params: FlexAttnParams, group: int):
     return s
 
 
-def _mask_hb(s, mask, group: int):
-    """-inf off the entry's (bq, bk) mask, computed once per tile and
-    broadcast over the (HB, G) heads of head-batched logits."""
+def _mask_hb(s, mask, group: int, fill: float = NEG_INF):
+    """``fill`` (-inf; the forward's ``_MASK``) off the entry's (bq, bk)
+    mask, computed once per tile and broadcast over the (HB, G) heads of
+    head-batched logits."""
     hb, rows, bk = s.shape
     s4 = s.reshape(hb, group, rows // group, bk)
-    s4 = jnp.where(mask[None, None], s4, NEG_INF)
+    s4 = jnp.where(mask[None, None], s4, fill)
     return s4.reshape(hb, rows, bk)
 
 
@@ -443,8 +450,119 @@ def _walk_grid(
 
 
 # ---------------------------------------------------------------------------
-# forward (head-batched variant)
+# forward: the online-softmax state, one copy for both bodies
 # ---------------------------------------------------------------------------
+
+
+def _probs(s, m):
+    """``p = exp(s - m)`` of a (..., rows, bk) logit tile against the
+    lane-replicated row maximum ``m`` (..., rows, LANES), and ``p``'s
+    per-lane partial row sums (..., rows, LANES): lane c holds the sum of
+    columns c, c + 128, ... The tile is taken in static, vreg-aligned
+    slices of 128 lanes, each of the shape of ``m``: no lane broadcast of
+    ``m`` and no cross-lane reduction, element-wise work only. A tile that
+    is not a multiple of a vreg's lanes (small test blocks) broadcasts
+    lane 0 of ``m`` and puts its whole row sum in lane 0."""
+    bk = s.shape[-1]
+    if bk % LANES:
+        p = jnp.exp(s - m[..., :1])
+        lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, m.ndim - 1)
+        return p, jnp.where(lane == 0, jnp.sum(p, axis=-1, keepdims=True), 0.0)
+    chunks = [jnp.exp(s[..., c : c + LANES] - m) for c in range(0, bk, LANES)]
+    return jnp.concatenate(chunks, axis=-1), functools.reduce(jnp.add, chunks)
+
+
+def _per_row(x, like):
+    """A lane-replicated per-row value (..., rows, LANES), shaped to
+    multiply the (..., rows, d) array ``like``: itself at head_dim 128,
+    else its lane 0 as a column."""
+    return x if like.shape[-1] == LANES else x[..., :1]
+
+
+def _fwd_update(s, v, m_scr, l_scr, acc_scr):
+    """One live step of the forward's online softmax, for both forward
+    bodies (per head: 2-D ``s`` (bq, bk) and ``v`` (bk, d); head-batched:
+    (HB, G*bq, bk) and (HB, bk, d)), as :func:`_bwd_p_ds` is the one copy
+    of the backward's block. ``s`` holds :data:`_MASK` off the mask.
+
+    What a step pays per row, not per logit, is this function. On a v5e
+    the older form (one-lane ``m`` and ``l`` columns, two cross-lane
+    reductions, four ``-inf`` guards) made the forward cost 11.0 cycles a
+    logit vreg at block_k 512 and 6.1 at 1024 against the MXU's 4, where
+    dq and dkv cost the same at either width (PERF.md section 6, PR 29;
+    docs/block_sparse.md). So the state is kept cheap, and the chip priced
+    each piece (packed 64k cell, forward kernel 109.0 ms before):
+
+    - no ``-inf`` inside the step: ``m`` starts at the finite ``_MASK``,
+      so ``alpha = exp(m_prev - m_new)`` and ``p = exp(s - m_new)`` need
+      no guard. A row that has met no live column yet carries garbage in
+      ``l`` and ``acc`` (``p`` = 1 on its masked columns); the first step
+      that brings it a live column multiplies that by
+      ``exp(_MASK - m_new)``, which is exactly 0, and a row no entry
+      covers is set right where the block is written;
+    - the row sum is lazy: ``l_scr`` holds per-lane partial sums in all
+      its 128 lanes (:func:`_probs`), rescaled by ``alpha`` as the
+      accumulator is, and is reduced across lanes once a q block, in
+      :func:`_fwd_finalize`. Only the order of the float32 additions
+      differs from a per-step row sum (with the guards: 79.5 ms);
+    - the running maximum is reduced across lanes every step and is
+      exact; it is kept replicated in all 128 lanes of ``m_scr`` and
+      used at that shape: whole-vreg loads and stores (71.2 ms), and no
+      lane broadcast into the logit tile, which is taken 128 lanes at a
+      time (62.1 ms)."""
+    nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p, lane_sums = _probs(s, m_new)
+    l_scr[...] = l_scr[...] * alpha + lane_sums
+    acc_scr[...] = acc_scr[...] * _per_row(alpha, acc_scr) + jax.lax.dot_general(
+        p.astype(v.dtype),
+        v,
+        dimension_numbers=(
+            ((nb + 1,), (nb,)),
+            (tuple(range(nb)), tuple(range(nb))),
+        ),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[...] = m_new
+
+
+def _fwd_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _MASK)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
+    """What a q block writes once its last entry is done: (out f32
+    (..., rows, d), lse (..., rows, LANES), rowmax (..., rows, LANES)), the
+    two statistics replicated over lanes as the outputs store them.
+    ``sinks``: the rows' sink logits, (..., rows, 1) or one scalar, or None.
+
+    The lazy row sum is reduced across lanes here, and the public
+    convention comes back here: a row that met no live column (its ``m``
+    is still ``_MASK``) gets ``l = 0``, so ``out = 0`` and ``lse = -inf``
+    (``lse = sink`` under a sink), and ``rowmax = -inf``; the garbage in
+    its accumulator is finite and is multiplied by 0. Like the update it
+    works on (rows, LANES) values, not (rows, 1) columns (packed 64k cell:
+    62.1 -> 59.6 ms; the window cell, two entries a q block: 33.5 -> 31.0)."""
+    m = m_scr[...]
+    live = m > _MASK / 2
+    l = jnp.where(live, jnp.sum(l_scr[...], axis=-1, keepdims=True), 0.0)
+    acc = acc_scr[...]
+    if sinks is not None:
+        m_tot = jnp.maximum(m, sinks)
+        resc = jnp.exp(m - m_tot)
+        l = l * resc + jnp.exp(sinks - m_tot)
+        acc = acc * _per_row(resc, acc)
+    else:
+        m_tot = m
+    covered = l > 0.0
+    l_safe = jnp.where(covered, l, 1.0)
+    out = acc * _per_row(jnp.where(covered, 1.0 / l_safe, 0.0), acc)
+    lse = jnp.where(covered, m_tot + jnp.log(l_safe), NEG_INF)
+    return out, lse, jnp.where(live, m, NEG_INF)
 
 
 def _fwd_kernel_hb(
@@ -479,6 +597,11 @@ def _fwd_kernel_hb(
     statically, j walks that block's entries (rs[i]..rs[i]+rc[i]), steps
     past the count clamp their k index (no DMA) and skip compute; on the
     compact one a step is an entry.
+
+    The body keeps its loads, reshapes and the mask; the softmax state is
+    :func:`_fwd_update` (a lazy, per-lane row sum; the finite ``_MASK`` in
+    place of ``-inf`` inside a step) and :func:`_fwd_finalize`, where the
+    row sum is reduced and ``-inf`` comes back for rows no entry covers.
     """
     bq, bk = params.block_q, params.block_k
     hbg = q_ref.shape[0]
@@ -489,9 +612,7 @@ def _fwd_kernel_hb(
 
     @pl.when(w.first())
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _fwd_init(m_scr, l_scr, acc_scr)
 
     @w.when_live
     def _compute():
@@ -499,29 +620,13 @@ def _fwd_kernel_hb(
         mask = _entry_interval_mask(
             bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
         )
-        s = _mask_hb(s, mask, group)
-
-        m_prev = m_scr[:, :, :1]  # (HB, G*bq, 1)
-        m_cur = jnp.max(s, axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe))
-        p = jnp.exp(s - m_safe)
-        l_new = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        acc = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype),
-            v_ref[...],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+        _fwd_update(
+            _mask_hb(s, mask, group, _MASK), v_ref[...], m_scr, l_scr, acc_scr
         )
-        m_scr[:, :, :1] = m_new
-        l_scr[:, :, :1] = l_new
-        acc_scr[...] = acc
 
     @pl.when(w.last())
     def _finalize():
-        m = m_scr[:, :, :1]
-        l = l_scr[:, :, :1]
+        sinks = None
         if params.has_sink:
             # per-q-head sink: rows of q head (h*hbg + hh) use sink[hh]
             sinks = jnp.stack(
@@ -531,36 +636,12 @@ def _fwd_kernel_hb(
                 ],
                 axis=0,
             ).reshape(hb, group * bq, 1)
-            m_tot = jnp.maximum(m, sinks)
-            m_tot_safe = jnp.where(m_tot == NEG_INF, 0.0, m_tot)
-            resc = jnp.exp(jnp.where(m == NEG_INF, NEG_INF, m - m_tot_safe))
-            l_tot = l * resc + jnp.exp(sinks - m_tot_safe)
-            acc_fin = acc_scr[...] * resc
-        else:
-            m_tot_safe = jnp.where(m == NEG_INF, 0.0, m)
-            l_tot = l
-            acc_fin = acc_scr[...]
-        covered = l_tot > 0.0
-        inv = jnp.where(covered, 1.0 / jnp.where(covered, l_tot, 1.0), 0.0)
-        out_ref[...] = (
-            (acc_fin * inv)
-            .reshape(hbg, bq, out_ref.shape[2])
-            .astype(out_ref.dtype)
+        out, lse, rowmax = _fwd_finalize(m_scr, l_scr, acc_scr, sinks)
+        out_ref[...] = out.reshape(hbg, bq, out_ref.shape[2]).astype(
+            out_ref.dtype
         )
-        lse = jnp.where(
-            covered, m_tot_safe + jnp.log(jnp.where(covered, l_tot, 1.0)), NEG_INF
-        )
-        lse_ref[...] = jnp.broadcast_to(
-            lse.reshape(hbg, bq, 1), (hbg, bq, LANES)
-        )
-        rowmax_ref[...] = jnp.broadcast_to(
-            m.reshape(hbg, bq, 1), (hbg, bq, LANES)
-        )
-
-
-# ---------------------------------------------------------------------------
-# forward
-# ---------------------------------------------------------------------------
+        lse_ref[...] = lse.reshape(hbg, bq, LANES)
+        rowmax_ref[...] = rowmax.reshape(hbg, bq, LANES)
 
 
 def _fwd_kernel(
@@ -578,12 +659,15 @@ def _fwd_kernel(
     out_ref,
     lse_ref,
     rowmax_ref,
-    m_scr,
+    m_scr,  # (bq, LANES)
     l_scr,
-    acc_scr,
+    acc_scr,  # (bq, d)
     *,
     params: FlexAttnParams,
 ):
+    """Per-head forward: one q head a grid step, on both grids; the
+    softmax state is the head-batched body's (:func:`_fwd_update`,
+    :func:`_fwd_finalize`)."""
     bq, bk = params.block_q, params.block_k
     h = pl.program_id(0)
     w = _Walk(params.grid, qblk, rs, rc)
@@ -591,65 +675,27 @@ def _fwd_kernel(
 
     @pl.when(w.first())
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _fwd_init(m_scr, l_scr, acc_scr)
 
     @w.when_live
     def _compute():
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-        s = jnp.where(
-            _entry_interval_mask(
-                bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
-            ),
-            s,
-            NEG_INF,
+        mask = _entry_interval_mask(
+            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
         )
-
-        # softmax state updates on a single lane column (the scratch keeps
-        # the [bq, LANES] layout for tiling legality; only column 0 counts)
-        m_prev = m_scr[:, :1]  # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        alpha = jnp.exp(jnp.where(m_prev == NEG_INF, NEG_INF, m_prev - m_safe))
-        p = jnp.exp(s - m_safe)
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype),
-            v_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        _fwd_update(
+            jnp.where(mask, s, _MASK), v_ref[0], m_scr, l_scr, acc_scr
         )
-        m_scr[:, :1] = m_new
-        l_scr[:, :1] = l_new
-        acc_scr[...] = acc
 
     @pl.when(w.last())
     def _finalize():
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
-        if params.has_sink:
-            sink = sink_ref[h, 0]
-            m_tot = jnp.maximum(m, sink)
-            m_tot_safe = jnp.where(m_tot == NEG_INF, 0.0, m_tot)
-            resc = jnp.exp(jnp.where(m == NEG_INF, NEG_INF, m - m_tot_safe))
-            l_tot = l * resc + jnp.exp(sink - m_tot_safe)
-            acc_fin = acc_scr[...] * resc
-        else:
-            m_tot_safe = jnp.where(m == NEG_INF, 0.0, m)
-            l_tot = l
-            acc_fin = acc_scr[...]
-        covered = l_tot > 0.0
-        inv = jnp.where(covered, 1.0 / jnp.where(covered, l_tot, 1.0), 0.0)
-        out_ref[0] = (acc_fin * inv).astype(out_ref.dtype)
-        lse = jnp.where(
-            covered, m_tot_safe + jnp.log(jnp.where(covered, l_tot, 1.0)), NEG_INF
-        )
-        # lane-broadcast [bq, LANES] layout (Mosaic (8,128)-tiling legal; the
+        sink = sink_ref[h, 0] if params.has_sink else None
+        out, lse, rowmax = _fwd_finalize(m_scr, l_scr, acc_scr, sink)
+        out_ref[0] = out.astype(out_ref.dtype)
+        # lane-replicated [bq, LANES] layout (Mosaic (8,128)-tiling legal; the
         # same convention as jax's own TPU flash-attention l/m outputs)
-        lse_ref[0] = jnp.broadcast_to(lse, (lse.shape[0], LANES))
-        rowmax_ref[0] = jnp.broadcast_to(m, (m.shape[0], LANES))
+        lse_ref[0] = lse
+        rowmax_ref[0] = rowmax
 
 
 def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):

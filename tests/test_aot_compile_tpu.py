@@ -135,6 +135,44 @@ def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
     assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
 
 
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize(
+    "rung,sink,softcap",
+    [((128, 512, 8), False, 0.0), ((1024, 1024, 1), False, 0.0),
+     ((128, 512, 8), True, 30.0), ((1024, 1024, 1), True, 30.0)],
+    ids=["packed-rung", "dense-rung", "packed-rung-sink-softcap",
+         "dense-rung-sink-softcap"],
+)
+def test_forward_state_at_the_cells_rungs(topo, rung, sink, softcap, grid):
+    """The forward alone at the two rungs the benchmark's cells run,
+    (128, 512, 8) head-batched and (1024, 1024, 1) per head, 64 q / 8 kv
+    heads, head_dim 128, 64k tokens. Since ISSUE 29 both bodies share one
+    softmax-state update: the row-sum scratch holds per-lane partial sums
+    in all its 128 lanes (static 128-lane slices of the probability tile)
+    and is reduced across lanes when a q block is written, and the mask
+    select writes a finite value. The scratch shapes are what they were
+    ((rows, 128), (rows, 128), (rows, head_dim), float32); this asks the
+    chip's compiler whether it still takes the new use of them, with and
+    without the sink and softcap paths of the finalize."""
+    t, hq, hk, d = 65536, 64, 8, 128
+    qr, kr, ts = ranges_of(varlen_block_causal(t))
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def fwd(q, k, v, s):
+        return flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid=grid, block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], softcap=softcap,
+            sink=s if sink else None, return_max_logits=True,
+            interpret=False,
+        )
+
+    text = _compile(
+        fwd, _on(chip, (t, hq, d)), _on(chip, (t, hk, d)),
+        _on(chip, (t, hk, d)), _on(chip, (hq,), jnp.float32),
+    )
+    assert text.count("tpu_custom_call") == 1
+
+
 def _serve_cache(chip, hk, d):
     """The smoke's serve-phase pool: 128k tokens, default page size."""
     from magiattention_tpu import env

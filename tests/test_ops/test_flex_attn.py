@@ -464,3 +464,133 @@ def test_head_batched_kernels_on_per_rank_padded_tables(group, heads, grid):
     for a, b, c, nm in zip(got, unpadded, oracle, ["dq", "dk", "dv", "dsink"]):
         assert_close(a, b, atol=2e-6, rtol=2e-6, msg=f"{nm} vs unpadded")
         assert_close(a, c, atol=5e-5, rtol=5e-5, msg=f"{nm} vs oracle")
+
+
+# -- the forward's softmax state (ISSUE 29) ---------------------------------
+# ``_fwd_update`` keeps no -inf inside a step (a finite mask value, a lazy
+# per-lane row sum) and ``_fwd_finalize`` restores the public convention.
+# The mask puts every kind of row into one q block of 64 (blocks of 64 x
+# 128 and 64 x 256, so the row sum's lane-aligned path runs too):
+#   rows   0..32   live in k block 0, fully masked in every later entry;
+#   rows  32..64   fully masked in their first entry (entries), live in k
+#                  [256, 384) only: what they gathered before is garbage
+#                  and must be multiplied by exactly 0;
+#   rows  64..100  no slice at all, in a q block that has entries;
+#   rows 100..128  causal against k [384, 512);
+#   rows 128..192  a q block with no entry of its own.
+_STATE_T, _STATE_TK = 192, 512
+_STATE_MASK = (
+    [(0, 32), (32, 64), (100, 128)],
+    [(0, 128), (256, 384), (384, 512)],
+    [F, F, C],
+)
+_STATE_UNCOVERED = np.r_[64:100, 128:192]
+
+
+def _state_case(head_block, grid, block_k, with_sink, softcap, amp=1.0, sign=0):
+    """(kernel results, ``_fwd_jnp``'s) as dicts of out, lse, rowmax, dq,
+    dk, dv (and dsink): the Pallas forward and backward in interpret mode
+    against the dense jnp backend on the same tables. ``amp`` scales q and
+    k; ``sign`` -1 makes every logit negative."""
+    from magiattention_tpu.ops import flex_attn as fa
+
+    hq, hk, d = 4, 2, 32
+    qr, kr, ts = _STATE_MASK
+    q, k, v = _rand_qkv(_STATE_T, _STATE_TK, hq, hk, d, seed=29)
+    if sign:
+        q, k = jnp.abs(q), sign * jnp.abs(k)
+    q, k = q * amp, k * amp
+    rng = np.random.default_rng(30)
+    do = jnp.asarray(rng.standard_normal((hq, _STATE_T, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((hq, _STATE_T)), jnp.float32)
+    sink = jnp.asarray(rng.standard_normal(hq), jnp.float32)
+    meta = build_block_meta(
+        qr, kr, [t.value for t in ts], _STATE_T, _STATE_TK,
+        block_q=64, block_k=block_k,
+    )
+    params = fa.FlexAttnParams(
+        block_q=64, block_k=block_k, scale=d**-0.5, softcap=float(softcap),
+        has_sink=with_sink, out_dtype="float32", interpret=True,
+        head_block=head_block, fwd_steps=meta.fwd_steps,
+        bwd_steps=meta.bwd_steps, grid=grid,
+    )
+    ftab, btab = fa.fwd_tables(meta), fa.bwd_tables(meta)
+    qh, kh, vh = (jnp.transpose(x, (1, 0, 2)) for x in (q, k, v))
+
+    def kernel(q, k, v, sink):
+        return fa.flex_attn_headmajor(
+            q, k, v, ftab, btab, params, sink=sink if with_sink else None
+        )
+
+    def oracle(q, k, v, sink):
+        return fa._fwd_jnp(q, k, v, sink.reshape(hq, 1), ftab, params)
+
+    def run(fn):
+        def loss(q, k, v, sink):
+            out, lse_lanes, _ = fn(q, k, v, sink)
+            lse = lse_lanes[:, :, 0]
+            return (out * do).sum() + (
+                jnp.where(jnp.isneginf(lse), 0.0, lse) * w
+            ).sum()
+
+        out, lse_lanes, rowmax_lanes = fn(qh, kh, vh, sink)
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(qh, kh, vh, sink)
+        res = dict(
+            out=out, lse=lse_lanes[:, :, 0], rowmax=rowmax_lanes[:, :, 0],
+            dq=grads[0], dk=grads[1], dv=grads[2],
+        )
+        if with_sink:
+            res["dsink"] = grads[3]
+        return {n: np.asarray(x) for n, x in res.items()}
+
+    return run(kernel), run(oracle), np.asarray(sink)
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("head_block", [1, 4], ids=["per-head", "hb=4"])
+@pytest.mark.parametrize("block_k", [128, 256])
+@pytest.mark.parametrize("softcap", [0.0, 8.0], ids=["nocap", "softcap"])
+@pytest.mark.parametrize("with_sink", [False, True], ids=["nosink", "sink"])
+def test_fwd_softmax_state_rows(with_sink, softcap, block_k, head_block, grid):
+    """Rows that are masked first and live later, live first and masked
+    later, covered by nothing inside a block that has entries, and in a
+    block with none: out, lse, rowmax and every gradient are
+    ``_fwd_jnp``'s, and -inf stands exactly where the oracle has it."""
+    got, ref, sink = _state_case(head_block, grid, block_k, with_sink, softcap)
+    for nm in ref:
+        assert np.isfinite(got[nm][np.isfinite(ref[nm])]).all(), nm
+        np.testing.assert_array_equal(
+            np.isneginf(got[nm]), np.isneginf(ref[nm]), err_msg=nm
+        )
+        fin = np.isfinite(ref[nm])
+        assert_close(got[nm][fin], ref[nm][fin], atol=5e-5, rtol=5e-5, msg=nm)
+    un = _STATE_UNCOVERED
+    assert not got["out"][:, un].any() and not got["dq"][:, un].any()
+    assert np.isneginf(got["rowmax"][:, un]).all()
+    if with_sink:  # a row that attends to nothing but the sink
+        np.testing.assert_array_equal(
+            got["lse"][:, un], np.broadcast_to(sink[:, None], (4, un.size))
+        )
+    else:
+        assert np.isneginf(got["lse"][:, un]).all()
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("head_block", [1, 4], ids=["per-head", "hb=4"])
+@pytest.mark.parametrize("sign", [0, -1], ids=["mixed", "all-negative"])
+def test_fwd_finite_mask_value_never_meets_a_logit(sign, head_block, grid):
+    """Logits of about +-1e4 after the scale, the useful edge of float32
+    for a softmax: the finite in-step mask value (-2.4e38) stays far under
+    them, so a row whose every live logit is hugely negative still counts
+    as covered, and masked columns weigh exactly nothing."""
+    got, ref, _ = _state_case(head_block, grid, 256, False, 0.0, amp=50.0, sign=sign)
+    assert 3e3 < np.abs(ref["rowmax"][np.isfinite(ref["rowmax"])]).max() < 1e5
+    for nm in ("out", "lse", "rowmax"):
+        np.testing.assert_array_equal(
+            np.isneginf(got[nm]), np.isneginf(ref[nm]), err_msg=nm
+        )
+        fin = np.isfinite(ref[nm])
+        assert_close(got[nm][fin], ref[nm][fin], atol=1e-4, rtol=2e-5, msg=nm)
+    np.testing.assert_array_equal(  # the running maximum stays exact
+        got["rowmax"], ref["rowmax"]
+    )

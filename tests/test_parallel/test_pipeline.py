@@ -562,14 +562,21 @@ def test_bf16_distributed_reasonable():
     )
 
 
+@pytest.mark.parametrize("block_k", [64, 128])
 @pytest.mark.parametrize("head_block", [1, 2], ids=["per-head", "hb=2"])
 @pytest.mark.parametrize("degree", [0, 2])
-def test_pipeline_agrees_between_the_two_grids(degree, head_block, monkeypatch):
+def test_pipeline_agrees_between_the_two_grids(
+    degree, head_block, block_k, monkeypatch
+):
     """The plan's kernels on the row-major and on the compact grid
     (ISSUE 27: ``make_attn_params`` sets ``FlexAttnParams.grid``, here
     pinned each way by ``MAGI_ATTENTION_GRID``) over per-rank stacked,
     padded, traced tables at cp=4: same out, lse and gradients, and the
-    oracle's."""
+    oracle's. Degree 2 merges two stages' partials on their lse
+    (``ops/correction.py``), which needs ``lse = -inf`` on the rows a
+    stage does not cover: the forward restores it where a q block is
+    written (ISSUE 29; ``block_k`` 128 runs its per-lane row sum, 64 the
+    narrow-tile form)."""
     from magiattention_tpu.meta.solver.overlap_solver import OverlapConfig
 
     name, total, qr, kr, ts = next(
@@ -582,7 +589,7 @@ def test_pipeline_agrees_between_the_two_grids(degree, head_block, monkeypatch):
         total, chunk_size=total // (4 * cp), cp_size=cp,
     )
     plan = build_dist_attn_plan(
-        mq, bucket, block_q=64, block_k=64,
+        mq, bucket, block_q=64, block_k=block_k,
         overlap_config=OverlapConfig(degree=degree, min_stage_rows=64),
     )
     rng = np.random.default_rng(5)
@@ -625,6 +632,8 @@ def test_pipeline_agrees_between_the_two_grids(degree, head_block, monkeypatch):
         )
     assert_close(compact[0], ref_out, atol=3e-5, rtol=3e-5, msg="out")
     finite = ~np.isneginf(np.asarray(ref_lse))
+    assert not finite.all()  # the mask has holes
+    np.testing.assert_array_equal(np.isneginf(np.asarray(compact[1])), ~finite)
     assert_close(
         np.asarray(compact[1])[finite], np.asarray(ref_lse)[finite],
         atol=3e-5, rtol=3e-5, msg="lse",
