@@ -13,6 +13,7 @@ from .pattern import (
     PatternConfig,
     afmoe_config,
     build_magi_pattern,
+    glm4_moe_lite_config,
     init_pattern_params,
     llama_pattern,
 )
@@ -37,6 +38,7 @@ __all__ = [
     "build_magi_llama_pp",
     "build_magi_pattern",
     "chunk_causal_mask",
+    "glm4_moe_lite_config",
     "init_dit_params",
     "init_params",
     "init_pattern_params",

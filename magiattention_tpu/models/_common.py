@@ -88,6 +88,9 @@ def _plan_on_dispatch(
     from ..parallel.dist_attn import build_dist_attn_plan, make_attn_params
 
     cp_size, cp_mesh_shape = _cp_geometry(mesh, cp_axis)
+    telemetry.annotate_span(  # what the kernels are handed, every model's
+        heads_q=cfg.n_heads, heads_kv=cfg.n_kv_heads, head_dim=cfg.head_dim
+    )
     if kind is not None:
         telemetry.annotate_span(kind=kind)
         telemetry.record_model_attn_plan(kind)

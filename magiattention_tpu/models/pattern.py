@@ -18,6 +18,20 @@ router is float32 sigmoid, top-k on score + bias, weights from the
 scores. With every extra off — ``llama_pattern`` — a layer is
 ``models/llama.py``'s, parameter names included.
 
+A layer's attention has one of two forms on that skeleton
+(``PatternConfig.attn_form``): ``gqa``, q / k / v straight from the
+hidden state, or ``latent`` (GLM-4.7-Flash / ``glm4_moe_lite_config``,
+the DeepSeek family's multi-head latent attention): a low-rank q with a
+norm in the middle, one down-projection of the hidden state to a latent
+(norm, then an up-projection to every head's ``k_nope`` and ``v``) and
+to ONE rotary key head every query head shares, rotary on the last
+``rope_head_dim`` of a head only. After the up-projection the kernels
+see ``n_heads`` query and ``n_heads`` key-value heads of ``head_dim``.
+``n_mtp`` multi-token-prediction modules follow the trunk
+(:func:`_mtp_local`): one more layer each, fed the next token's
+embedding beside the trunk's hidden state, sharing embedding and head,
+with a loss on the token after the next.
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -25,6 +39,7 @@ jit (``_common.make_model_train_step``).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Sequence
@@ -36,6 +51,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.flex_attn import FlexAttnParams
+from ..parallel.dispatch import roll
 from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
@@ -44,6 +60,7 @@ from .llama import _rms_norm, _rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 DENSE, EXPERTS = "dense", "experts"
+GQA, LATENT = "gqa", "latent"
 _SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
 
 
@@ -82,14 +99,42 @@ class PatternConfig:
     expert_range: tuple[int, int] | None = None
     dtype: str = "bfloat16"
     remat: bool = False
+    # the attention's form, every layer's: GQA (q, k, v from the hidden
+    # state) or LATENT. Under LATENT ``head_dim`` is what the kernels see,
+    # the same for q, k and v: ``head_dim - rope_head_dim`` without
+    # position and ``rope_head_dim`` rotary, in every layer whatever
+    # ``rope_kinds`` says; ``n_kv_heads == n_heads``
+    attn_form: str = GQA
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    rope_head_dim: int = 0
+    # multi-token-prediction modules after the trunk (0 or 1 until a
+    # reference states a chain of them), each one more layer of the last
+    # trunk layer's kinds; their losses weigh ``mtp_loss_weight``
+    n_mtp: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
             raise ValueError("layer_types and ffn_types differ in length")
         bad = set(self.layer_types) - {SLIDING, FULL}
         bad |= set(self.ffn_types) - {DENSE, EXPERTS}
+        bad |= {self.attn_form} - {GQA, LATENT}
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        if self.attn_form == LATENT and not (
+            self.n_kv_heads == self.n_heads
+            and 0 < self.rope_head_dim < self.head_dim
+            and self.q_lora_rank > 0 and self.kv_lora_rank > 0
+        ):
+            raise ValueError(
+                "latent attention needs n_kv_heads == n_heads, both ranks "
+                "and 0 < rope_head_dim < head_dim"
+            )
+        if self.n_mtp > 1:
+            raise ValueError(
+                "more than one MTP module: no reference states the chain"
+            )
 
     @property
     def n_layers(self) -> int:
@@ -160,6 +205,70 @@ def afmoe_config(
     )
 
 
+def glm4_moe_lite_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``glm4_moe_lite`` ``config.json`` (GLM-4.7-Flash) as a
+    pattern: latent attention in every layer, all of them
+    ``full_attention`` (one plan), ``first_k_dense_replace`` dense layers
+    and then sparse experts under the ``noaux_tc`` router at one group
+    (``route``), ``num_nextn_predict_layers`` MTP modules.
+    ``expert_range`` and ``vocab_size`` give one rank's share, as in
+    :func:`afmoe_config`. ``mtp_loss_weight`` is no published key: a
+    configuration file may state it."""
+    n = int(hf["num_hidden_layers"])
+    n_dense = int(hf["first_k_dense_replace"])
+    heads = int(hf["num_attention_heads"])
+    nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
+    if int(hf["v_head_dim"]) != nope + rope:
+        raise ValueError(
+            f"v_head_dim {hf['v_head_dim']} differs from the key heads' "
+            f"{nope} + {rope}: the flex kernels take one head width"
+        )
+    if int(hf["num_key_value_heads"]) != heads:
+        raise ValueError(
+            "latent attention up-projects one key-value head a query head"
+        )
+    if int(hf.get("n_group", 1)) != 1 or int(hf.get("topk_group", 1)) != 1:
+        raise ValueError("group-limited routing (n_group > 1) is not built")
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=int(hf["hidden_size"]),
+        n_heads=heads,
+        n_kv_heads=heads,
+        head_dim=nope + rope,
+        layer_types=(FULL,) * n,
+        ffn_types=tuple(DENSE if i < n_dense else EXPERTS for i in range(n)),
+        ffn_hidden=int(hf["intermediate_size"]),
+        rope_theta=float(hf["rope_theta"]),
+        rope_kinds=(FULL,),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=False,
+        rms_eps=float(hf["rms_norm_eps"]),
+        n_experts=int(hf["n_routed_experts"]),
+        top_k=int(hf["num_experts_per_tok"]),
+        expert_hidden=int(hf["moe_intermediate_size"]),
+        n_shared_experts=int(hf["n_shared_experts"]),
+        route_norm=bool(hf["norm_topk_prob"]),
+        route_scale=float(hf["routed_scaling_factor"]),
+        expert_range=expert_range,
+        dtype=dtype,
+        remat=remat,
+        attn_form=LATENT,
+        q_lora_rank=int(hf["q_lora_rank"]),
+        kv_lora_rank=int(hf["kv_lora_rank"]),
+        rope_head_dim=rope,
+        n_mtp=int(hf.get("num_nextn_predict_layers", 0)),
+        mtp_loss_weight=float(hf.get("mtp_loss_weight", 0.3)),
+    )
+
+
 def llama_pattern(cfg) -> PatternConfig:
     """``models/llama.py``'s decoder as a pattern: every layer (full,
     dense), rotary everywhere, the AFMoE extras off. ``init_params`` of
@@ -175,64 +284,104 @@ def llama_pattern(cfg) -> PatternConfig:
     )
 
 
-def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
-    """Parameter pytree (fp32 master weights). ``expert_bias`` is the
-    router's selection bias: a buffer, zero, no gradient reaches it."""
-    keys = jax.random.split(rng, cfg.n_layers + 2)
+def _dense_init(key, shape):
+    return jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[-2])
+
+
+def _ones(*shape):
+    return jnp.ones(shape, jnp.float32)
+
+
+def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str) -> dict:
+    dense, ones = _dense_init, _ones
     hq, hk = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     e0, e1 = cfg.held_experts
-
-    def dense(key, shape):
-        fan_in = shape[-2]
-        return jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)
-
-    def ones(*shape):
-        return jnp.ones(shape, jnp.float32)
-
-    layers = []
-    for i, ffn in enumerate(cfg.ffn_types):
-        k = jax.random.split(keys[i], 12)
+    k = jax.random.split(key, 12)
+    if cfg.attn_form == LATENT:
+        ka = jax.random.split(jax.random.fold_in(key, 1), 5)
+        nope = cfg.head_dim - cfg.rope_head_dim
+        layer = {
+            "wq_a": dense(ka[0], (cfg.dim, cfg.q_lora_rank)),
+            "q_a_norm": ones(cfg.q_lora_rank),
+            "wq_b": dense(ka[1], (cfg.q_lora_rank, hq)),
+            # the latent's columns, then the shared rotary key's
+            "wkv_a": dense(
+                ka[2], (cfg.dim, cfg.kv_lora_rank + cfg.rope_head_dim)
+            ),
+            "kv_a_norm": ones(cfg.kv_lora_rank),
+            # a head's k_nope columns, then its v columns
+            "wkv_b": dense(
+                ka[3],
+                (cfg.kv_lora_rank, cfg.n_heads * (nope + cfg.head_dim)),
+            ),
+            "wo": dense(ka[4], (hq, cfg.dim)),
+        }
+    else:
         layer = {
             "wq": dense(k[0], (cfg.dim, hq)),
             "wk": dense(k[1], (cfg.dim, hk)),
             "wv": dense(k[2], (cfg.dim, hk)),
             "wo": dense(k[3], (hq, cfg.dim)),
-            "attn_norm": ones(cfg.dim),
-            "mlp_norm": ones(cfg.dim),
         }
-        if cfg.attn_gate:
-            layer["w_attn_gate"] = dense(k[4], (cfg.dim, hq))
-        if cfg.qk_norm:
-            layer["q_norm"] = ones(cfg.head_dim)
-            layer["k_norm"] = ones(cfg.head_dim)
-        if cfg.post_norms:
-            layer["post_attn_norm"] = ones(cfg.dim)
-            layer["post_mlp_norm"] = ones(cfg.dim)
-        if ffn == DENSE:
-            layer["w_gate"] = dense(k[5], (cfg.dim, cfg.ffn_hidden))
-            layer["w_up"] = dense(k[6], (cfg.dim, cfg.ffn_hidden))
-            layer["w_down"] = dense(k[7], (cfg.ffn_hidden, cfg.dim))
-        else:
-            eh, held = cfg.expert_hidden, e1 - e0
-            layer["w_router"] = dense(k[5], (cfg.dim, cfg.n_experts))
-            layer["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
-            layer["we_gate"] = dense(k[6], (held, cfg.dim, eh))
-            layer["we_up"] = dense(k[7], (held, cfg.dim, eh))
-            layer["we_down"] = dense(k[8], (held, eh, cfg.dim))
-            if cfg.n_shared_experts:
-                sh = eh * cfg.n_shared_experts
-                layer["ws_gate"] = dense(k[9], (cfg.dim, sh))
-                layer["ws_up"] = dense(k[10], (cfg.dim, sh))
-                layer["ws_down"] = dense(k[11], (sh, cfg.dim))
-        layers.append(layer)
-    return {
+    layer["attn_norm"] = ones(cfg.dim)
+    layer["mlp_norm"] = ones(cfg.dim)
+    if cfg.attn_gate:
+        layer["w_attn_gate"] = dense(k[4], (cfg.dim, hq))
+    if cfg.qk_norm:
+        layer["q_norm"] = ones(cfg.head_dim)
+        layer["k_norm"] = ones(cfg.head_dim)
+    if cfg.post_norms:
+        layer["post_attn_norm"] = ones(cfg.dim)
+        layer["post_mlp_norm"] = ones(cfg.dim)
+    if ffn == DENSE:
+        layer["w_gate"] = dense(k[5], (cfg.dim, cfg.ffn_hidden))
+        layer["w_up"] = dense(k[6], (cfg.dim, cfg.ffn_hidden))
+        layer["w_down"] = dense(k[7], (cfg.ffn_hidden, cfg.dim))
+    else:
+        eh, held = cfg.expert_hidden, e1 - e0
+        layer["w_router"] = dense(k[5], (cfg.dim, cfg.n_experts))
+        layer["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+        layer["we_gate"] = dense(k[6], (held, cfg.dim, eh))
+        layer["we_up"] = dense(k[7], (held, cfg.dim, eh))
+        layer["we_down"] = dense(k[8], (held, eh, cfg.dim))
+        if cfg.n_shared_experts:
+            sh = eh * cfg.n_shared_experts
+            layer["ws_gate"] = dense(k[9], (cfg.dim, sh))
+            layer["ws_up"] = dense(k[10], (cfg.dim, sh))
+            layer["ws_down"] = dense(k[11], (sh, cfg.dim))
+    return layer
+
+
+def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
+    """Parameter pytree (fp32 master weights). ``expert_bias`` is the
+    router's selection bias: a buffer, zero, no gradient reaches it.
+    ``mtp`` (where ``cfg.n_mtp``): a module's two input norms, its
+    projection of [embedding; hidden] back to ``dim``, its layer and the
+    norm before the shared head."""
+    keys = jax.random.split(rng, cfg.n_layers + 2)
+    params = {
         "embed": jax.random.normal(
             keys[-2], (cfg.vocab_size, cfg.dim), jnp.float32
         ) * 0.02,
-        "layers": layers,
-        "final_norm": ones(cfg.dim),
-        "lm_head": dense(keys[-1], (cfg.dim, cfg.vocab_size)),
+        "layers": [
+            _init_layer(keys[i], cfg, ffn)
+            for i, ffn in enumerate(cfg.ffn_types)
+        ],
+        "final_norm": _ones(cfg.dim),
+        "lm_head": _dense_init(keys[-1], (cfg.dim, cfg.vocab_size)),
     }
+    if cfg.n_mtp:
+        params["mtp"] = []
+        for j in range(cfg.n_mtp):
+            k_proj, k_layer = jax.random.split(jax.random.fold_in(rng, 1 + j))
+            params["mtp"].append({
+                "embed_norm": _ones(cfg.dim),
+                "hidden_norm": _ones(cfg.dim),
+                "eh_proj": _dense_init(k_proj, (2 * cfg.dim, cfg.dim)),
+                "layer": _init_layer(k_layer, cfg, cfg.ffn_types[-1]),
+                "final_norm": _ones(cfg.dim),
+            })
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -356,6 +505,34 @@ def _expert_ffn(h, layer: dict, cfg: PatternConfig):
     return y, {"expert_idx": idx, "expert_counts": counts}
 
 
+def _latent_qkv(h, pos, layer: dict, cfg: PatternConfig):
+    """Latent attention's q, k, v [t, n_heads, head_dim] from the normed
+    hidden state: what the kernels are handed is expanded, every head its
+    own ``k_nope`` and ``v`` and a copy of the one rotary key."""
+    dt = cfg.jnp_dtype
+    t, heads, eps = h.shape[0], cfg.n_heads, cfg.rms_eps
+    rope, nope = cfg.rope_head_dim, cfg.head_dim - cfg.rope_head_dim
+
+    def rot(x):
+        return _rope(x, pos, cfg.rope_theta, rope)
+
+    with named_scope("magi_mla_q"):
+        c_q = _rms_norm(h @ layer["wq_a"].astype(dt), layer["q_a_norm"], eps)
+        q = (c_q @ layer["wq_b"].astype(dt)).reshape(t, heads, cfg.head_dim)
+        q = jnp.concatenate([q[..., :nope], rot(q[..., nope:])], axis=-1)
+    with named_scope("magi_mla_kv"):
+        c = h @ layer["wkv_a"].astype(dt)
+        c_kv, k_rope = c[:, : cfg.kv_lora_rank], c[:, cfg.kv_lora_rank :]
+        kv = (
+            _rms_norm(c_kv, layer["kv_a_norm"], eps)
+            @ layer["wkv_b"].astype(dt)
+        ).reshape(t, heads, nope + cfg.head_dim)
+        k_rope = jnp.broadcast_to(rot(k_rope[:, None, :]), (t, heads, rope))
+        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+        v = kv[..., nope:]
+    return q, k, v
+
+
 def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
                  attn_params, axis_name):
     """One layer on this rank's dispatched tokens -> (x, routing stats)."""
@@ -364,13 +541,16 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
     eps = cfg.rms_eps
     kind = cfg.plan_kind(layer_type)
     h = _rms_norm(x, layer["attn_norm"], eps)
-    q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    if cfg.attn_form == LATENT:
+        q, k, v = _latent_qkv(h, pos, layer, cfg)
+    else:
+        q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
     if cfg.qk_norm:
         q = _rms_norm(q, layer["q_norm"], eps)
         k = _rms_norm(k, layer["k_norm"], eps)
-    if layer_type in cfg.rope_kinds:
+    if cfg.attn_form == GQA and layer_type in cfg.rope_kinds:
         q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
         k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
     with named_scope("magi_attn_" + _SHORT[kind]):
@@ -381,7 +561,9 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
     out = out.reshape(t, -1)
     if cfg.attn_gate:
         out = out * jax.nn.sigmoid(h @ layer["w_attn_gate"].astype(dt))
-    out = out @ layer["wo"].astype(dt)
+    with (named_scope("magi_mla_out") if cfg.attn_form == LATENT
+          else contextlib.nullcontext()):
+        out = out @ layer["wo"].astype(dt)
     if cfg.post_norms:
         out = _rms_norm(out, layer["post_attn_norm"], eps)
     x = x + out
@@ -397,32 +579,77 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
     return x + out, stats
 
 
-def forward_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
-                  attn_params, axis_name="cp"):
-    """Per-cp-rank forward over dispatched tokens -> (logits [t_loc,
-    vocab] float32, the expert layers' routing stats stacked by layer)."""
+def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
+               axis_name):
+    one_layer = functools.partial(
+        _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
+        tables=tables, plans=plans, attn_params=attn_params,
+        axis_name=axis_name,
+    )
+    if cfg.remat:  # save a layer's input; the rest recomputes
+        one_layer = jax.checkpoint(one_layer)
+    return one_layer
+
+
+def _embed(params, tokens, cfg: PatternConfig):
     dt = cfg.jnp_dtype
     x = params["embed"].astype(dt)[tokens]
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, dt)
+    return x
+
+
+def _head(x, norm, params, cfg: PatternConfig):
+    x = _rms_norm(x, norm, cfg.rms_eps)
+    return (x @ params["lm_head"].astype(cfg.jnp_dtype)).astype(jnp.float32)
+
+
+def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
+                 attn_params, axis_name):
+    """The layers over this rank's dispatched tokens -> (the last layer's
+    output before the final norm, the expert layers' routing stats)."""
+    x = _embed(params, tokens, cfg)
     stats = []
     for layer, layer_type, ffn_type in zip(
         params["layers"], cfg.layer_types, cfg.ffn_types
     ):
-        one_layer = functools.partial(
-            _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
-            tables=tables, plans=plans, attn_params=attn_params,
-            axis_name=axis_name,
-        )
-        if cfg.remat:  # save a layer's input; the rest recomputes
-            one_layer = jax.checkpoint(one_layer)
-        x, s = one_layer(x, pos, layer)
+        x, s = _one_layer(
+            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name
+        )(x, pos, layer)
         if s:
             stats.append(s)
-    x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    stacked = jax.tree.map(lambda *a: jnp.stack(a), *stats) if stats else {}
-    return logits, stacked
+    return x, stats
+
+
+def _mtp_local(params, x, next_tokens, pos, cfg: PatternConfig, tables,
+               plans, attn_params, axis_name):
+    """The multi-token-prediction modules after the trunk (DeepSeek-V3's
+    form, GLM-4.7-Flash's ``num_nextn_predict_layers``). Module ``j`` at
+    position ``i`` takes the hidden state before it (the trunk's last
+    layer for the first, before the final norm) and the embedding of
+    token ``i + j + 1``, ``next_tokens[j]``, both normed, concatenated
+    embedding first and projected back to ``dim``; runs one more layer of
+    the last trunk layer's kinds on the same mask and positions; and
+    predicts token ``i + j + 2`` through its own norm and the shared
+    head. -> (logits a module, routing stats a module)."""
+    dt = cfg.jnp_dtype
+    logits, stats = [], []
+    for mod, nxt in zip(params["mtp"], next_tokens):
+        e = _rms_norm(_embed(params, nxt, cfg), mod["embed_norm"], cfg.rms_eps)
+        h = _rms_norm(x, mod["hidden_norm"], cfg.rms_eps)
+        x = jnp.concatenate([e, h], axis=-1) @ mod["eh_proj"].astype(dt)
+        x, s = _one_layer(
+            cfg, cfg.layer_types[-1], cfg.ffn_types[-1], tables, plans,
+            attn_params, axis_name,
+        )(x, pos, mod["layer"])
+        if s:
+            stats.append(s)
+        logits.append(_head(x, mod["final_norm"], params, cfg))
+    return logits, stats
+
+
+def _stacked(stats):
+    return jax.tree.map(lambda *a: jnp.stack(a), *stats) if stats else {}
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -437,13 +664,16 @@ class MagiPattern:
     attn_params: dict[str, FlexAttnParams]
     cp_axis: str | tuple[str, str] = "cp"
     dp_axis: str = "dp"
+    dispatch_meta: Any = None  # the plans' dispatch: MTP targets roll on it
 
     def loss_fn(self, params, tokens, labels, pos, tables, *,
                 with_stats: bool = False):
-        """Mean next-token CE over valid (label >= 0) positions; with
-        ``with_stats`` also the expert layers' routing: ``expert_idx``
-        [batch, layers, total_padded, top_k] in dispatch order and
-        ``expert_counts`` [layers, held] summed over the mesh."""
+        """Mean next-token CE over valid (label >= 0) positions, plus
+        ``cfg.mtp_loss_weight`` x each MTP module's mean CE on its own
+        target; with ``with_stats`` also the expert layers' routing (the
+        trunk's, then the modules'): ``expert_idx`` [batch, layers,
+        total_padded, top_k] in dispatch order and ``expert_counts``
+        [layers, held] summed over the mesh."""
         cfg = self.cfg
         tables = {k: tuple(v) for k, v in tables.items()}
         batch = P(self.dp_axis, self.cp_axis)
@@ -452,37 +682,55 @@ class MagiPattern:
              "expert_counts": P()}
             if with_stats and EXPERTS in cfg.ffn_types else {}
         )
+        mtp_labels = self._mtp_labels(tokens, labels)
 
         @functools.partial(
             shard_map,
             mesh=self.mesh,
             in_specs=(
-                P(), batch, batch, batch,
+                P(), batch, batch, batch, (batch,) * cfg.n_mtp,
                 {k: (P(self.cp_axis),) * len(v) for k, v in tables.items()},
             ),
             out_specs=(P(), stats_specs),
             check_vma=False,
         )
-        def _local(params, tok, lab, pos, tabs):
-            def one(tok1, lab1, pos1):
-                logits, stats = forward_local(
-                    params, tok1, pos1, cfg, tabs, self.plans,
-                    self.attn_params, self.cp_axis,
-                )
-                return masked_ce_sums(logits, lab1), stats
+        def _local(params, tok, lab, pos, mtp_lab, tabs):
+            def one(tok1, lab1, pos1, *mtp_lab1):
+                run = (cfg, tabs, self.plans, self.attn_params, self.cp_axis)
+                x, stats = _trunk_local(params, tok1, pos1, *run)
+                logits = [_head(x, params["final_norm"], params, cfg)]
+                if cfg.n_mtp:
+                    with named_scope("magi_mtp"):
+                        # module j is fed token i + j + 1: the label, then
+                        # the module before's target
+                        fed = [
+                            jnp.maximum(t, 0) for t in (lab1, *mtp_lab1[:-1])
+                        ]
+                        more, routed = _mtp_local(params, x, fed, pos1, *run)
+                    logits, stats = logits + more, stats + routed
+                stats = _stacked(stats)
+                sums = [masked_ce_sums(logits[0], lab1)]
+                if cfg.n_mtp:
+                    with named_scope("magi_mtp"):
+                        sums += [
+                            masked_ce_sums(lg, t)
+                            for lg, t in zip(logits[1:], mtp_lab1)
+                        ]
+                return tuple(sums), stats
 
             # a loop, not vmap: a batched lax.cond would run both branches
-            outs = [one(*b) for b in zip(tok, lab, pos)]
-            (loss_sum, count), stats = jax.tree.map(
-                lambda *a: jnp.stack(a), *outs
-            )
+            outs = [one(*b) for b in zip(tok, lab, pos, *mtp_lab)]
+            sums, stats = jax.tree.map(lambda *a: jnp.stack(a), *outs)
             with named_scope("magi_pattern_loss_psum"):
-                loss_sum, count = (
-                    jax.lax.psum(
-                        jax.lax.psum(v.sum(), self.cp_axis), self.dp_axis
+                sums = [
+                    tuple(
+                        jax.lax.psum(
+                            jax.lax.psum(v.sum(), self.cp_axis), self.dp_axis
+                        )
+                        for v in pair
                     )
-                    for v in (loss_sum, count)
-                )
+                    for pair in sums
+                ]
             if stats_specs:
                 with named_scope("magi_pattern_stats_psum"):
                     counts = jax.lax.psum(
@@ -497,10 +745,39 @@ class MagiPattern:
                 }
             else:
                 stats = {}
-            return loss_sum / jnp.maximum(count, 1.0), stats
+            (loss_sum, count), *mtp_sums = sums
+            loss = loss_sum / jnp.maximum(count, 1.0)
+            for loss_sum, count in mtp_sums:
+                loss = loss + cfg.mtp_loss_weight * (
+                    loss_sum / jnp.maximum(count, 1.0)
+                )
+            return loss, stats
 
-        loss, stats = _local(params, tokens, labels, pos, tables)
+        loss, stats = _local(params, tokens, labels, pos, mtp_labels, tables)
         return (loss, stats) if with_stats else loss
+
+    def _mtp_labels(self, tokens, labels):
+        """MTP module ``j``'s target a position, in dispatch order: token
+        ``i + j + 2``, the distributed roll of the token ids by
+        ``-(j + 2)`` along the global sequence, wrapping at its end as
+        the caller's ``labels`` (a roll by -1) do; -1 where ``labels``
+        is."""
+        if not self.cfg.n_mtp:
+            return ()
+        if self.dispatch_meta is None:
+            raise ValueError("MTP modules roll the tokens: pass dispatch_meta")
+        with named_scope("magi_mtp"):
+            return tuple(
+                jnp.where(
+                    labels >= 0,
+                    roll(
+                        tokens, self.dispatch_meta, -(j + 2), axis=1,
+                        mesh=self.mesh, cp_axis=self.cp_axis,
+                    ),
+                    -1,
+                )
+                for j in range(self.cfg.n_mtp)
+            )
 
     def sharded_tables(self):
         from ._common import sharded_plan_tables
@@ -575,6 +852,11 @@ def build_magi_pattern(
         )
     model = MagiPattern(
         cfg=cfg, mesh=mesh, plans=plans, attn_params=attn_params,
-        cp_axis=cp_axis, dp_axis=dp_axis,
+        cp_axis=cp_axis, dp_axis=dp_axis, dispatch_meta=meta,
     )
+    if cfg.attn_form == LATENT:
+        telemetry.record_mla_kv_cast_width(
+            expanded=2 * cfg.n_heads * cfg.head_dim,
+            latent=cfg.kv_lora_rank + cfg.rope_head_dim,
+        )
     return model, meta

@@ -825,7 +825,10 @@ def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
     (what the forward takes, and what the tuner's cost model prices every
     kernel at), or 1 where the batched step does not fit the VMEM the
     kernels ask for. The test is on shapes alone: the step keeps four f32
-    (head_block * block_q, block_k) intermediates live (s, p, dP, dS)."""
+    (head_block * block_q, block_k) intermediates live (s, p, dP, dS). The
+    (head_block, block, head_dim) operand tiles are not counted: at
+    head_dim 256 they double, and every rung the tuner can return still
+    compiles under this test as it is (20 q = 20 kv heads, both grids)."""
     hbg = params.head_block
     if hbg <= 1:
         return 1
@@ -1591,6 +1594,14 @@ _AUTO_BLOCK_CONFIGS: tuple[tuple[int, int, int], ...] = (
     # v5e limits (16 MB scoped vmem) at head_dim 128. Larger block_k shrinks
     # the entry table (the scalar-prefetch smem arrays are ~40 B/entry
     # against a 1 MB smem budget) and amortizes grid-step overhead.
+    # At head_dim 256 and GQA group 1 (latent attention after its
+    # up-projection: 20 q = 20 kv heads, head_block snapped to 5, 4, 2) every
+    # rung here and in tuning's SPARSE_ONLY_CONFIGS compiles for a v5e on
+    # both grids under _VMEM_LIMIT_BYTES (tests/test_aot_compile_tpu.py).
+    # Fitting is not fast: at group 1 a step's K and V tiles serve block_q
+    # rows of ONE head, so a block_q of 128 reads a byte per 128 FLOPs where
+    # the chip's balance is 240, and the kernels run at the HBM's pace
+    # (PERF.md section 6, PR 30); nothing here prices that yet.
     (128, 512, 8),
     (256, 512, 4),
     (256, 1024, 2),

@@ -71,6 +71,7 @@ from .collectors import (  # noqa: F401
     record_flex_dead_step_share,
     record_flex_kernel_build,
     record_model_attn_plan,
+    record_mla_kv_cast_width,
     record_moe_load,
     record_dispatch_solution,
     record_dynamic_solution,
@@ -351,6 +352,7 @@ __all__ = [
     "record_flex_dead_step_share",
     "record_flex_kernel_build",
     "record_model_attn_plan",
+    "record_mla_kv_cast_width",
     "record_moe_load",
     "record_dispatch_solution",
     "record_dynamic_solution",

@@ -132,6 +132,11 @@ M_MODEL_ATTN_PLANS = "magi_model_attn_plans_total"
 # over the mean of the held experts: {layer=}
 M_MOE_PAIRS_HERE = "magi_moe_pairs_here"
 M_MOE_LOAD_MAX_OVER_MEAN = "magi_moe_load_max_over_mean"
+# gauge — what a key-value cast carries a token under latent attention,
+# in elements, set where a latent model is built (build_magi_pattern):
+# {form=expanded} every head's k and v as the kernels take them (what the
+# cast carries today), {form=latent} the latent and the shared rotary key
+M_MLA_KV_CAST_WIDTH = "magi_mla_kv_cast_width"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1150,6 +1155,16 @@ def record_moe_load(layer: int, counts) -> None:
         max(counts) * len(counts) / pairs if pairs else 0.0,
         layer=layer,
     )
+
+
+def record_mla_kv_cast_width(*, expanded: int, latent: int) -> None:
+    """The two widths a latent-attention model's key-value cast could
+    carry a token (``models/pattern.build_magi_pattern``, host side)."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.gauge_set(M_MLA_KV_CAST_WIDTH, float(expanded), form="expanded")
+    reg.gauge_set(M_MLA_KV_CAST_WIDTH, float(latent), form="latent")
 
 
 # ---------------------------------------------------------------------------

@@ -173,6 +173,85 @@ def test_forward_state_at_the_cells_rungs(topo, rung, sink, softcap, grid):
     assert text.count("tpu_custom_call") == 1
 
 
+def _glm_cell_mask(t):
+    """The GLM-4.7-Flash cell's packed mask at ``t`` tokens (16,384 in
+    the window, 4,096 in the check), as the benchmark builds it."""
+    import json
+
+    from benchmarks import masks
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        here, "benchmarks", "traffic", "train-16k-packed-mla.json"
+    )) as f:
+        return masks.build_mask(json.load(f)["mask"], t, index=0)
+
+
+def _tuners_rung(mask, hq, hk, d):
+    from magiattention_tpu.tuning.autotuner import resolve_block_config
+
+    return resolve_block_config(
+        [list(r) for r in mask.q_ranges], [list(r) for r in mask.k_ranges],
+        tuple(mask.types), mask.total, mask.total, 1, hq, hk, d, "bfloat16",
+    )
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize(
+    "t,rung",
+    [(16384, None), (4096, None), (16384, (256, 1024, 2)),
+     (16384, (512, 768, 4)), (16384, (1024, 1024, 1))],
+    ids=["tuner-16k", "tuner-4k-check", "widest-row-major-step",
+         "widest-compact-step", "per-head-long-rung"],
+)
+def test_latent_geometry_20_heads_of_256(topo, t, rung, grid):
+    """Forward, dq and dkv at what latent attention hands the kernels
+    after its up-projection (GLM-4.7-Flash: 20 query = 20 key-value
+    heads, GQA group 1, head_dim 256), on both grids: the rung the tuner
+    gives the cell's packed mask at the window's 16k and at the check's
+    4k, (128, 512, 8) snapped to 5 heads a step (no power of two, and at
+    group 1 five key-value heads' tiles a step), and the largest steps
+    the tuner's table holds for this geometry. K, V, dO and the
+    accumulators are twice head_dim 128's; they fit the VMEM the kernels
+    ask for, and the batched programs are the ones built."""
+    from magiattention_tpu import telemetry
+
+    hq = hk = 20
+    d = 256
+    mask = _glm_cell_mask(t)
+    if rung is None:
+        rung = _tuners_rung(mask, hq, hk, d)
+        assert rung == (128, 512, 5)
+    qr, kr = [list(r) for r in mask.q_ranges], [list(r) for r in mask.k_ranges]
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, list(mask.types), grid=grid, block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    reg = telemetry.get_registry()
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    reg.clear_metric("magi_flex_kernel_build_total")
+    try:
+        text = _compile(
+            jax.value_and_grad(loss, argnums=(0, 1, 2)),
+            _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+        )
+        for kernel in ("fwd", "dq", "dkv"):
+            assert reg.counter_value(
+                "magi_flex_kernel_build_total", kernel=kernel,
+                heads_per_step=rung[2], grid=grid,
+            ) >= 1, kernel
+    finally:
+        reg.clear_metric("magi_flex_kernel_build_total")
+        telemetry.set_enabled(was)
+    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+
+
 def _serve_cache(chip, hk, d):
     """The smoke's serve-phase pool: 128k tokens, default page size."""
     from magiattention_tpu import env

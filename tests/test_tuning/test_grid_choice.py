@@ -2,7 +2,7 @@
 table sets it already walks, the steps the row-major and the compact grid
 would launch over forward, dq and dkv, prices them with the two per-step
 costs of ``tuning/cost_model.py`` and sets ``FlexAttnParams.grid``. Host
-only: the plans of the benchmark's six cells are built from their own
+only: the plans of the benchmark's eight cells are built from their own
 traffic files, nothing runs on a device."""
 
 import importlib
@@ -97,6 +97,15 @@ CELLS = {
     ],
     "magi64x8-attn-64k-swa1024": [
         ((1024, 1024, 1), "row_major", 2 * 64 * 2 + 65 * 2, 384, 381),
+    ],
+    # the Mistral cell's mask at 20 query = 20 key-value heads of width
+    # 256 (ISSUE 30): the same tables; (128, 512, 8) snaps to 5 heads a step
+    "glm47flash-train-16k-packed": [
+        ((128, 512, 5), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
+    ],
+    # 16 chunks of 4 blocks: q block r meets 4 (r // 4 + 1) k blocks
+    "magi64x8-attn-64k-chunkcausal": [
+        ((1024, 1024, 1), "sparse", 2 * 64 * 64 + 65 * 64, 6536, 3 * 16 * 136),
     ],
 }
 
