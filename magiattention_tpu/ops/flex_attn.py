@@ -509,7 +509,9 @@ def _fwd_update(s, v, m_scr, l_scr, acc_scr):
       exact; it is kept replicated in all 128 lanes of ``m_scr`` and
       used at that shape: whole-vreg loads and stores (71.2 ms), and no
       lane broadcast into the logit tile, which is taken 128 lanes at a
-      time (62.1 ms)."""
+      time (62.1 ms). :func:`_bwd_p_ds` takes its tiles against lse and
+      delta the same way (worth under 3% of dq and dkv at this rung, 3-4%
+      per head: the backward's excess is not in its elementwise block)."""
     nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -784,16 +786,32 @@ def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
 def _bwd_p_ds(
     s, lse_ref, do_ref, v_ref, delta_ref, params: FlexAttnParams, hb=None
 ):
-    """Shared backward core for all six bwd kernel bodies (row-major,
-    head-batched row-major and sparse; dq + dkv): probabilities from the
+    """Shared backward core for all four bwd kernel bodies (dq and dkv,
+    per head and head-batched, each on both grids): probabilities from the
     stored lse and the masked logits, then ``ds = p * (dP - delta)`` with
     the softcap derivative and the off-mask NaN guard. This block is
-    numerically delicate and MUST stay in lockstep across grids — one
-    copy only.
+    numerically delicate and MUST stay in lockstep across bodies — one
+    copy only, as :func:`_fwd_update` is the forward's.
 
     ``hb=None``: the per-head kernels' (1, rows, .) blocks and 2-D ``s``.
     ``hb=HB``: the head-batched kernels' blocks, the q-side ones
-    (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``."""
+    (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``.
+
+    ``lse`` and ``delta`` arrive replicated over the 128 lanes
+    (:func:`_fwd_finalize` writes lse so, ``_flex_attn_core_bwd`` builds
+    delta so) and are used at that shape, as the forward uses its running
+    maximum (:func:`_probs`): the (rows, bk) tiles ``s`` and ``dP`` are
+    taken in static, vreg-aligned slices of 128 lanes, each of the shape
+    of the two statistics, so nothing is cut to a one-lane column and
+    broadcast back over the tile, and the uncovered-row guard is one
+    ``max`` on whole vregs. ``s`` keeps ``-inf`` off the mask:
+    ``exp(-inf - lse)`` is exactly 0 there. A tile that is not a multiple
+    of a vreg's lanes (small test blocks) is its own one slice, against
+    lane 0 of each statistic as a column, as in :func:`_probs`. These are
+    the float32 operations of the plain column form on the same values
+    (``tests/test_ops/test_flex_attn.py`` keeps it as the reference, bit
+    for bit); what the chip read: PERF.md section 6, PR 31, and
+    docs/block_sparse.md."""
 
     def rows(ref):
         if hb is None:
@@ -801,9 +819,14 @@ def _bwd_p_ds(
         return ref[...].reshape(hb, -1, ref.shape[2])
 
     nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
-    lse = rows(lse_ref)[..., :1]
-    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
-    p = jnp.exp(s - lse_safe)
+    bk = s.shape[-1]
+    width = bk if bk % LANES else LANES  # a narrow tile is its one slice
+    lse, delta = rows(lse_ref), rows(delta_ref)
+    if width != LANES:
+        lse, delta = lse[..., :1], delta[..., :1]
+    # rows no entry covers: lse = -inf, and s = -inf all along them, so
+    # any finite value here makes p exactly 0 (-inf - -inf would be nan)
+    lse = jnp.maximum(lse, jnp.float32(jnp.finfo(jnp.float32).min))
     dp = jax.lax.dot_general(
         rows(do_ref),
         v_ref[0] if hb is None else v_ref[...],
@@ -813,7 +836,11 @@ def _bwd_p_ds(
         ),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - rows(delta_ref)[..., :1])
+    p_c, ds_c = [], []
+    for c in range(0, bk, width):
+        p_c.append(jnp.exp(s[..., c : c + width] - lse))
+        ds_c.append(p_c[-1] * (dp[..., c : c + width] - delta))
+    p, ds = jnp.concatenate(p_c, axis=-1), jnp.concatenate(ds_c, axis=-1)
     if params.softcap > 0.0:
         ds = ds * (1.0 - (s / jnp.float32(params.softcap)) ** 2)
         ds = jnp.where(jnp.isneginf(s), 0.0, ds)  # nan guard off-mask

@@ -62,6 +62,25 @@ def _compile(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid, softcap=0.0) -> str:
+    """The compiled text of forward + dq + dkv (a loss that reads out and
+    lse) on ``mask`` = (q_ranges, k_ranges, types) at the pinned rung."""
+    qr, kr, ts = mask
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid=grid, block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], softcap=softcap,
+            interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    return _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+    )
+
+
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize("hq,hk,d", [(32, 4, 64), (32, 8, 128)])
 def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
@@ -104,25 +123,14 @@ def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
     fallback."""
     from magiattention_tpu import telemetry
 
-    d = 128
-    qr, kr, ts = ranges_of(varlen_block_causal(t))
     chip = SingleDeviceSharding(topo.devices[0])
-
-    def loss(q, k, v):
-        out, lse = flex_flash_attn_func(
-            q, k, v, qr, kr, ts, grid=grid, block_q=rung[0],
-            block_k=rung[1], head_block=rung[2], interpret=False,
-        )
-        return out.astype(jnp.float32).sum() + lse.sum()
-
     reg = telemetry.get_registry()
     was = telemetry.enabled()
     telemetry.set_enabled(True)
     reg.clear_metric("magi_flex_kernel_build_total")
     try:
-        text = _compile(
-            jax.value_and_grad(loss, argnums=(0, 1, 2)),
-            _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+        text = _compile_fwd_bwd(
+            chip, ranges_of(varlen_block_causal(t)), t, hq, hk, 128, rung, grid
         )
         for kernel in ("fwd", "dq", "dkv"):
             assert reg.counter_value(
@@ -171,6 +179,32 @@ def test_forward_state_at_the_cells_rungs(topo, rung, sink, softcap, grid):
         _on(chip, (t, hk, d)), _on(chip, (hq,), jnp.float32),
     )
     assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("softcap", [0.0, 30.0], ids=["nocap", "softcap"])
+@pytest.mark.parametrize(
+    "t,hq,hk,d,rung",
+    [(65536, 64, 8, 128, (128, 512, 8)), (65536, 64, 8, 128, (1024, 1024, 1)),
+     (16384, 20, 20, 256, (128, 512, 5))],
+    ids=["packed-rung", "dense-rung", "latent-rung"],
+)
+def test_backward_block_at_the_cells_rungs(topo, t, hq, hk, d, rung, softcap, grid):
+    """dq and dkv at the rungs the benchmark's cells run: head-batched
+    (128, 512, 8) and per head (1024, 1024, 1) at 64 q / 8 kv heads of
+    width 128, head-batched (128, 512, 5) at 20 / 20 heads of width 256.
+    Since ISSUE 31 the one block all four backward bodies share takes lse
+    and delta at the (rows, 128) shape their blocks arrive in and the
+    (rows, block_k) tiles s and dP in static 128-lane slices, with the
+    softcap derivative on the whole tile after: this asks the chip's
+    compiler whether it takes those slices and the concatenations at
+    every body's row stacking, with and without softcap, in the VMEM the
+    kernels ask for."""
+    text = _compile_fwd_bwd(
+        SingleDeviceSharding(topo.devices[0]),
+        ranges_of(varlen_block_causal(t)), t, hq, hk, d, rung, grid, softcap,
+    )
+    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
 
 
 def _glm_cell_mask(t):
@@ -224,22 +258,13 @@ def test_latent_geometry_20_heads_of_256(topo, t, rung, grid):
         assert rung == (128, 512, 5)
     qr, kr = [list(r) for r in mask.q_ranges], [list(r) for r in mask.k_ranges]
     chip = SingleDeviceSharding(topo.devices[0])
-
-    def loss(q, k, v):
-        out, lse = flex_flash_attn_func(
-            q, k, v, qr, kr, list(mask.types), grid=grid, block_q=rung[0],
-            block_k=rung[1], head_block=rung[2], interpret=False,
-        )
-        return out.astype(jnp.float32).sum() + lse.sum()
-
     reg = telemetry.get_registry()
     was = telemetry.enabled()
     telemetry.set_enabled(True)
     reg.clear_metric("magi_flex_kernel_build_total")
     try:
-        text = _compile(
-            jax.value_and_grad(loss, argnums=(0, 1, 2)),
-            _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+        text = _compile_fwd_bwd(
+            chip, (qr, kr, list(mask.types)), t, hq, hk, d, rung, grid
         )
         for kernel in ("fwd", "dq", "dkv"):
             assert reg.counter_value(

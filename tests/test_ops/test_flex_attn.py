@@ -487,14 +487,18 @@ _STATE_MASK = (
 _STATE_UNCOVERED = np.r_[64:100, 128:192]
 
 
-def _state_case(head_block, grid, block_k, with_sink, softcap, amp=1.0, sign=0):
+def _state_case(
+    head_block, grid, block_k, with_sink, softcap, amp=1.0, sign=0, d=32,
+    with_oracle=True,
+):
     """(kernel results, ``_fwd_jnp``'s) as dicts of out, lse, rowmax, dq,
     dk, dv (and dsink): the Pallas forward and backward in interpret mode
     against the dense jnp backend on the same tables. ``amp`` scales q and
-    k; ``sign`` -1 makes every logit negative."""
+    k; ``sign`` -1 makes every logit negative; ``with_oracle=False`` leaves the
+    second dict out (None)."""
     from magiattention_tpu.ops import flex_attn as fa
 
-    hq, hk, d = 4, 2, 32
+    hq, hk = 4, 2
     qr, kr, ts = _STATE_MASK
     q, k, v = _rand_qkv(_STATE_T, _STATE_TK, hq, hk, d, seed=29)
     if sign:
@@ -527,14 +531,15 @@ def _state_case(head_block, grid, block_k, with_sink, softcap, amp=1.0, sign=0):
 
     def run(fn):
         def loss(q, k, v, sink):
-            out, lse_lanes, _ = fn(q, k, v, sink)
+            out, lse_lanes, rowmax_lanes = fn(q, k, v, sink)
             lse = lse_lanes[:, :, 0]
             return (out * do).sum() + (
                 jnp.where(jnp.isneginf(lse), 0.0, lse) * w
-            ).sum()
+            ).sum(), (out, lse_lanes, rowmax_lanes)
 
-        out, lse_lanes, rowmax_lanes = fn(qh, kh, vh, sink)
-        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(qh, kh, vh, sink)
+        (_, (out, lse_lanes, rowmax_lanes)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True
+        )(qh, kh, vh, sink)
         res = dict(
             out=out, lse=lse_lanes[:, :, 0], rowmax=rowmax_lanes[:, :, 0],
             dq=grads[0], dk=grads[1], dv=grads[2],
@@ -543,7 +548,9 @@ def _state_case(head_block, grid, block_k, with_sink, softcap, amp=1.0, sign=0):
             res["dsink"] = grads[3]
         return {n: np.asarray(x) for n, x in res.items()}
 
-    return run(kernel), run(oracle), np.asarray(sink)
+    return (
+        run(kernel), run(oracle) if with_oracle else None, np.asarray(sink)
+    )
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -593,4 +600,124 @@ def test_fwd_finite_mask_value_never_meets_a_logit(sign, head_block, grid):
         assert_close(got[nm][fin], ref[nm][fin], atol=1e-4, rtol=2e-5, msg=nm)
     np.testing.assert_array_equal(  # the running maximum stays exact
         got["rowmax"], ref["rowmax"]
+    )
+
+
+# -- the backward's P/dS block on whole vregs (ISSUE 31) --------------------
+# ``_bwd_p_ds`` uses lse and delta at the lane-replicated (rows, 128) shape
+# they arrive in and takes the logit tile 128 lanes at a time. The same
+# float32 operations on the same values as the column form it replaced,
+# which stays here as the reference.
+
+
+def _bwd_p_ds_column(s, lse_ref, do_ref, v_ref, delta_ref, params, hb=None):
+    """The block as it was until PR 31: lane 0 of lse and of delta sliced
+    to (rows, 1) columns, the guard on the column, both broadcast over the
+    (rows, bk) tile."""
+    from magiattention_tpu.ops.flex_attn import NEG_INF
+
+    def rows(ref):
+        if hb is None:
+            return ref[0]
+        return ref[...].reshape(hb, -1, ref.shape[2])
+
+    nb = s.ndim - 2
+    lse = rows(lse_ref)[..., :1]
+    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
+    p = jnp.exp(s - lse_safe)
+    dp = jax.lax.dot_general(
+        rows(do_ref),
+        v_ref[0] if hb is None else v_ref[...],
+        dimension_numbers=(
+            ((nb + 1,), (nb + 1,)),
+            (tuple(range(nb)), tuple(range(nb))),
+        ),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - rows(delta_ref)[..., :1])
+    if params.softcap > 0.0:
+        ds = ds * (1.0 - (s / jnp.float32(params.softcap)) ** 2)
+        ds = jnp.where(jnp.isneginf(s), 0.0, ds)
+    return p, ds
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("head_block", [1, 4], ids=["per-head", "hb=4"])
+@pytest.mark.parametrize("d", [128, 256])
+@pytest.mark.parametrize("block_k", [64, 128, 256])
+@pytest.mark.parametrize("softcap", [0.0, 8.0], ids=["nocap", "softcap"])
+@pytest.mark.parametrize("with_sink", [False, True], ids=["nosink", "sink"])
+def test_bwd_block_on_whole_vregs_is_the_column_form(
+    with_sink, softcap, block_k, d, head_block, grid, monkeypatch
+):
+    """dq, dk, dv, dsink of the dq and dkv bodies (per head and
+    head-batched, both grids) bit for bit what the column form gives:
+    block_k 128 and 256 run the 128-lane slices, 64 the narrow form. The
+    loss reads lse too (``delta - dlse``), rows 64..100 and 128..192 have
+    ``lse = -inf``: their dq is exactly zero and nothing is non-finite.
+    And all of it within the oracle's tolerances."""
+    from magiattention_tpu.ops import flex_attn as fa
+
+    got, ref, _ = _state_case(head_block, grid, block_k, with_sink, softcap, d=d)
+    traced = []
+
+    def column_form(*args):
+        traced.append(1)
+        return _bwd_p_ds_column(*args)
+
+    monkeypatch.setattr(fa, "_bwd_p_ds", column_form)
+    old, _, _ = _state_case(
+        head_block, grid, block_k, with_sink, softcap, d=d, with_oracle=False
+    )
+    assert traced  # the bodies did trace the reference, not a cached program
+    grads = [nm for nm in got if nm.startswith("d")]
+    assert grads == ["dq", "dk", "dv"] + ["dsink"] * with_sink
+    for nm in grads:
+        assert np.isfinite(got[nm]).all(), nm
+        np.testing.assert_array_equal(got[nm], old[nm], err_msg=nm)
+        assert_close(got[nm], ref[nm], atol=5e-5, rtol=5e-5, msg=nm)
+    assert not got["dq"][:, _STATE_UNCOVERED].any()
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("head_block", [1, 4], ids=["per-head", "hb=4"])
+@pytest.mark.parametrize("with_sink", [False, True], ids=["nosink", "sink"])
+def test_lse_and_delta_arrive_replicated_over_the_lanes(
+    with_sink, head_block, grid, monkeypatch
+):
+    """The contract ``_bwd_p_ds`` leans on: what dq and dkv are handed as
+    lse (the forward's own output, from either forward body on either
+    grid) and as delta (built by ``_flex_attn_core_bwd``, the lse
+    cotangent folded in) is equal in all 128 lanes, on covered rows and
+    on rows no entry covers (``-inf``, or the sink)."""
+    from magiattention_tpu.ops import flex_attn as fa
+
+    seen = {}
+    dq_pallas = fa._dq_pallas
+
+    def spy(q, k, v, do, lse, delta, tables, params):
+        seen.update(lse=np.asarray(lse), delta=np.asarray(delta))
+        return dq_pallas(q, k, v, do, lse, delta, tables, params)
+
+    monkeypatch.setattr(fa, "_dq_pallas", spy)
+    got, _, sink = _state_case(
+        head_block, grid, 128, with_sink, 0.0, with_oracle=False
+    )
+    for nm in ("lse", "delta"):
+        x = seen[nm]
+        assert x.shape == (4, _STATE_T, fa.LANES) and x.dtype == np.float32
+        np.testing.assert_array_equal(
+            x, np.broadcast_to(x[..., :1], x.shape), err_msg=nm
+        )
+    np.testing.assert_array_equal(seen["lse"][..., 0], got["lse"])
+    un = _STATE_UNCOVERED
+    covered = np.setdiff1d(np.arange(_STATE_T), un)
+    assert np.isfinite(seen["lse"][:, covered]).all()
+    assert np.isfinite(seen["delta"]).all() and seen["delta"].any()
+    np.testing.assert_array_equal(
+        seen["lse"][:, un],
+        np.broadcast_to(
+            sink[:, None, None] if with_sink else -np.inf,
+            (4, un.size, fa.LANES),
+        ),
     )
