@@ -16,6 +16,7 @@ from .pattern import (
     glm4_moe_lite_config,
     init_pattern_params,
     llama_pattern,
+    ouro_config,
 )
 from .llama_pp import (
     MagiLlamaPP,
@@ -43,6 +44,7 @@ __all__ = [
     "init_params",
     "init_pattern_params",
     "llama_pattern",
+    "ouro_config",
     "init_pp_params",
     "stack_layer_params",
 ]

@@ -10,8 +10,8 @@ from .. import telemetry
 from ..common.axes import cp_axis_names
 
 
-def masked_ce_sums(logits, labels):
-    """(sum of CE over positions with label >= 0, count of them).
+def masked_ce_tokens(logits, labels):
+    """(CE a position, 0 where its label < 0; which positions are valid).
 
     The single definition of the next-token loss — MagiLlama and
     MagiLlamaPP must stay numerically identical through it.
@@ -20,10 +20,13 @@ def masked_ce_sums(logits, labels):
     valid = labels >= 0
     safe = jnp.where(valid, labels, 0)
     tok_loss = -jnp.take_along_axis(logp, safe[:, None], axis=1)[:, 0]
-    return (
-        jnp.where(valid, tok_loss, 0.0).sum(),
-        valid.sum().astype(jnp.float32),
-    )
+    return jnp.where(valid, tok_loss, 0.0), valid
+
+
+def masked_ce_sums(logits, labels):
+    """(sum of CE over positions with label >= 0, count of them)."""
+    tok_loss, valid = masked_ce_tokens(logits, labels)
+    return tok_loss.sum(), valid.sum().astype(jnp.float32)
 
 
 def _can_place(mesh) -> bool:

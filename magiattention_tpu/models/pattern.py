@@ -32,6 +32,14 @@ see ``n_heads`` query and ``n_heads`` key-value heads of ``head_dim``.
 embedding beside the trunk's hidden state, sharing embedding and head,
 with a loss on the token after the next.
 
+``n_loops > 1`` makes the decoder a looped one (Ouro / ``ouro_config``):
+the layer stack runs ``n_loops`` times on the SAME weights, the final
+norm inside the loop, as the body of one ``jax.lax.scan``
+(:func:`_looped_trunk_local`); every pass ends in the shared head and an
+exit gate, and the loss is the expectation of the exits' per-token
+losses under the gate's distribution, less ``exit_entropy_weight`` x its
+entropy (:func:`_exit_losses`, :func:`exit_log_probs`).
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -55,7 +63,7 @@ from ..parallel.dispatch import roll
 from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
-from ._common import masked_ce_sums
+from ._common import masked_ce_sums, masked_ce_tokens
 from .llama import _rms_norm, _rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -113,6 +121,12 @@ class PatternConfig:
     # trunk layer's kinds; their losses weigh ``mtp_loss_weight``
     n_mtp: int = 0
     mtp_loss_weight: float = 0.3
+    # passes through the layer stack on shared weights; above 1 every
+    # pass ends in the head and an exit gate, and the loss is the exits'
+    # expected loss less ``exit_entropy_weight`` x the exit distribution's
+    # entropy (a uniform prior over exits)
+    n_loops: int = 1
+    exit_entropy_weight: float = 0.05
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
@@ -134,6 +148,13 @@ class PatternConfig:
         if self.n_mtp > 1:
             raise ValueError(
                 "more than one MTP module: no reference states the chain"
+            )
+        if self.n_loops < 1:
+            raise ValueError(f"n_loops {self.n_loops}: at least one pass")
+        if self.n_loops > 1 and (EXPERTS in self.ffn_types or self.n_mtp):
+            raise ValueError(
+                "a looped decoder with experts or an MTP module: no "
+                "reference states one"
             )
 
     @property
@@ -269,6 +290,39 @@ def glm4_moe_lite_config(
     )
 
 
+def ouro_config(
+    hf: dict, *, dtype: str = "bfloat16", remat: bool = False
+) -> PatternConfig:
+    """A published ``ouro`` ``config.json`` (Ouro-2.6B, a looped decoder)
+    as a pattern: every layer ``full_attention`` with rotary and a dense
+    SwiGLU, a norm before and after each half, and ``total_ut_steps``
+    passes through the stack. ``exit_entropy_weight`` is no published
+    key: a configuration file may state it."""
+    n = int(hf["num_hidden_layers"])
+    if hf.get("sliding_window") is not None or hf.get("use_sliding_window"):
+        raise ValueError("an ouro configuration with a window is not built")
+    return PatternConfig(
+        vocab_size=int(hf["vocab_size"]),
+        dim=int(hf["hidden_size"]),
+        n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]),
+        layer_types=(FULL,) * n,
+        ffn_types=(DENSE,) * n,
+        ffn_hidden=int(hf["intermediate_size"]),
+        rope_theta=float(hf["rope_theta"]),
+        rope_kinds=(FULL,),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=True,
+        rms_eps=float(hf["rms_norm_eps"]),
+        dtype=dtype,
+        remat=remat,
+        n_loops=int(hf["total_ut_steps"]),
+        exit_entropy_weight=float(hf.get("exit_entropy_weight", 0.05)),
+    )
+
+
 def llama_pattern(cfg) -> PatternConfig:
     """``models/llama.py``'s decoder as a pattern: every layer (full,
     dense), rotary everywhere, the AFMoE extras off. ``init_params`` of
@@ -357,7 +411,10 @@ def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
     router's selection bias: a buffer, zero, no gradient reaches it.
     ``mtp`` (where ``cfg.n_mtp``): a module's two input norms, its
     projection of [embedding; hidden] back to ``dim``, its layer and the
-    norm before the shared head."""
+    norm before the shared head. ``exit_gate`` (where ``cfg.n_loops >
+    1``): the ``Linear(dim, 1)`` every pass's normed state goes through,
+    seeded like any dense weight (and its bias), so the exit distribution
+    is not uniform."""
     keys = jax.random.split(rng, cfg.n_layers + 2)
     params = {
         "embed": jax.random.normal(
@@ -381,6 +438,14 @@ def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
                 "layer": _init_layer(k_layer, cfg, cfg.ffn_types[-1]),
                 "final_norm": _ones(cfg.dim),
             })
+    if cfg.n_loops > 1:
+        k_w, k_b = jax.random.split(jax.random.fold_in(rng, 0x6A7E))
+        params["exit_gate"] = {
+            "w": _dense_init(k_w, (cfg.dim, 1)),
+            # half a standard normal: every exit stays live in every seed
+            # (at a bias of 2 the first exit alone would take 0.88)
+            "b": 0.5 * jax.random.normal(k_b, (1,), jnp.float32),
+        }
     return params
 
 
@@ -599,9 +664,12 @@ def _embed(params, tokens, cfg: PatternConfig):
     return x
 
 
-def _head(x, norm, params, cfg: PatternConfig):
-    x = _rms_norm(x, norm, cfg.rms_eps)
+def _logits(x, params, cfg: PatternConfig):
     return (x @ params["lm_head"].astype(cfg.jnp_dtype)).astype(jnp.float32)
+
+
+def _head(x, norm, params, cfg: PatternConfig):
+    return _logits(_rms_norm(x, norm, cfg.rms_eps), params, cfg)
 
 
 def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
@@ -619,6 +687,100 @@ def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
         if s:
             stats.append(s)
     return x, stats
+
+
+def _looped_trunk_local(params, tokens, pos, cfg: PatternConfig, tables,
+                        plans, attn_params, axis_name):
+    """The looped trunk over this rank's dispatched tokens -> the state
+    after every pass [n_loops, t, dim]: ``x_t = norm(layers(x_{t-1}))``,
+    the one final norm inside the loop, so a pass starts from the normed
+    state. The pass is the body of ONE ``lax.scan`` with the layers'
+    parameters closed over: the program holds a layer's kernels once a
+    direction whatever ``n_loops`` is, and the weights' gradient is the
+    scan's sum over passes."""
+    one_layer = [
+        _one_layer(
+            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name
+        )
+        for layer_type, ffn_type in zip(cfg.layer_types, cfg.ffn_types)
+    ]
+
+    def one_pass(x, _):
+        for fn, layer in zip(one_layer, params["layers"]):
+            x, _stats = fn(x, pos, layer)
+        x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return x, x
+
+    with named_scope("magi_loop"):
+        _, states = jax.lax.scan(
+            one_pass, _embed(params, tokens, cfg), None, length=cfg.n_loops
+        )
+    return states
+
+
+def exit_log_probs(gate_logits):
+    """log of the exit distribution a token, [exits, t] float32, from the
+    gates' logits [exits, t]: ``p_t = lambda_t prod_{j<t} (1 -
+    lambda_j)`` with ``lambda = sigmoid(logit)``, and the last exit takes
+    what is left, ``prod_{j<T} (1 - lambda_j)`` (its own gate is not
+    read). One exit: ``p_1 = 1``."""
+    early = gate_logits[:-1]
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-early), axis=0)  # log prod_{j<=t}
+    zero = jnp.zeros_like(gate_logits[:1])
+    return (
+        jnp.concatenate([zero, stayed])  # all the earlier exits passed by
+        + jnp.concatenate([jax.nn.log_sigmoid(early), zero])
+    )
+
+
+# rows of one exit that go through the head at a time: a block's float32
+# logits are ``rows x vocab_size`` (0.8 GB at 4,096 x 49,152), and the
+# backward holds two or three such blocks
+EXIT_BLOCK_ROWS = 4096
+
+
+def _exit_losses(states, labels, params, cfg: PatternConfig):
+    """(sum over valid positions of ``sum_t p_t CE_t - beta H(p)``, the
+    count of them) from the passes' normed states [exits, t, dim]: every
+    exit through the shared head, a per-token cross-entropy and the gate
+    (float32: a ``Linear(dim, 1)`` with bias), ``EXIT_BLOCK_ROWS`` rows
+    of one exit at a time (a whole exit where ``t`` is no multiple of
+    it) and recomputed in the backward (``cfg.remat``), so one block's
+    logits are alive; the distribution and the objective on [exits, t]
+    values."""
+    gate = params["exit_gate"]
+    n_exits, t, dim = states.shape
+    blocks = t // EXIT_BLOCK_ROWS if t % EXIT_BLOCK_ROWS == 0 else 1
+
+    def one_block(block):
+        x, lab = block
+        ce, _valid = masked_ce_tokens(_logits(x, params, cfg), lab)
+        g = jnp.dot(
+            x.astype(jnp.float32), gate["w"][:, 0],
+            precision=jax.lax.Precision.HIGHEST,
+        ) + gate["b"][0]
+        return ce, g
+
+    if cfg.remat:
+        one_block = jax.checkpoint(one_block)
+    ce, gate_logits = jax.lax.map(
+        one_block,
+        (
+            states.reshape(n_exits * blocks, t // blocks, dim),
+            jnp.tile(labels.reshape(blocks, t // blocks), (n_exits, 1)),
+        ),
+    )
+    ce, gate_logits = ce.reshape(n_exits, t), gate_logits.reshape(n_exits, t)
+    logp = exit_log_probs(gate_logits)
+    # H(p) = -sum_t p_t log p_t: the entropy's term goes in with the CE
+    per_token = (
+        jnp.exp(logp) * (ce + cfg.exit_entropy_weight * logp)
+    ).sum(axis=0)
+    valid = labels >= 0
+    return (
+        jnp.where(valid, per_token, 0.0).sum(),
+        valid.sum().astype(jnp.float32),
+    )
 
 
 def _mtp_local(params, x, next_tokens, pos, cfg: PatternConfig, tables,
@@ -670,8 +832,11 @@ class MagiPattern:
                 with_stats: bool = False):
         """Mean next-token CE over valid (label >= 0) positions, plus
         ``cfg.mtp_loss_weight`` x each MTP module's mean CE on its own
-        target; with ``with_stats`` also the expert layers' routing (the
-        trunk's, then the modules'): ``expert_idx`` [batch, layers,
+        target; for a looped decoder (``cfg.n_loops > 1``) the mean of
+        the exits' expected CE less ``cfg.exit_entropy_weight`` x the
+        exit distribution's entropy; with ``with_stats`` also the expert
+        layers' routing (the trunk's, then the modules'): ``expert_idx``
+        [batch, layers,
         total_padded, top_k] in dispatch order and ``expert_counts``
         [layers, held] summed over the mesh."""
         cfg = self.cfg
@@ -697,6 +862,10 @@ class MagiPattern:
         def _local(params, tok, lab, pos, mtp_lab, tabs):
             def one(tok1, lab1, pos1, *mtp_lab1):
                 run = (cfg, tabs, self.plans, self.attn_params, self.cp_axis)
+                if cfg.n_loops > 1:
+                    states = _looped_trunk_local(params, tok1, pos1, *run)
+                    with named_scope("magi_exit_head"):
+                        return (_exit_losses(states, lab1, params, cfg),), {}
                 x, stats = _trunk_local(params, tok1, pos1, *run)
                 logits = [_head(x, params["final_norm"], params, cfg)]
                 if cfg.n_mtp:
@@ -854,6 +1023,7 @@ def build_magi_pattern(
         cfg=cfg, mesh=mesh, plans=plans, attn_params=attn_params,
         cp_axis=cp_axis, dp_axis=dp_axis, dispatch_meta=meta,
     )
+    telemetry.record_model_loop(cfg.n_loops, cfg.n_layers)
     if cfg.attn_form == LATENT:
         telemetry.record_mla_kv_cast_width(
             expanded=2 * cfg.n_heads * cfg.head_dim,

@@ -72,6 +72,7 @@ from .collectors import (  # noqa: F401
     record_flex_kernel_build,
     record_model_attn_plan,
     record_mla_kv_cast_width,
+    record_model_loop,
     record_moe_load,
     record_dispatch_solution,
     record_dynamic_solution,
@@ -353,6 +354,7 @@ __all__ = [
     "record_flex_kernel_build",
     "record_model_attn_plan",
     "record_mla_kv_cast_width",
+    "record_model_loop",
     "record_moe_load",
     "record_dispatch_solution",
     "record_dynamic_solution",

@@ -137,6 +137,12 @@ M_MOE_LOAD_MAX_OVER_MEAN = "magi_moe_load_max_over_mean"
 # {form=expanded} every head's k and v as the kernels take them (what the
 # cast carries today), {form=latent} the latent and the shared rotary key
 M_MLA_KV_CAST_WIDTH = "magi_mla_kv_cast_width"
+# gauges — a pattern decoder's passes through its layer stack and the
+# layer applications a forward makes (layers x passes), set where the
+# model is built (build_magi_pattern); 1 and the layer count unless the
+# decoder is a looped one
+M_MODEL_LOOP_STEPS = "magi_model_loop_steps"
+M_MODEL_LAYER_APPLICATIONS = "magi_model_layer_applications"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1155,6 +1161,16 @@ def record_moe_load(layer: int, counts) -> None:
         max(counts) * len(counts) / pairs if pairs else 0.0,
         layer=layer,
     )
+
+
+def record_model_loop(n_loops: int, n_layers: int) -> None:
+    """A pattern decoder's passes and layer applications a forward
+    (``models/pattern.build_magi_pattern``, host side)."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.gauge_set(M_MODEL_LOOP_STEPS, float(n_loops))
+    reg.gauge_set(M_MODEL_LAYER_APPLICATIONS, float(n_loops * n_layers))
 
 
 def record_mla_kv_cast_width(*, expanded: int, latent: int) -> None:

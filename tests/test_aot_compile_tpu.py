@@ -366,6 +366,63 @@ def test_two_layer_train_step_cp4(topo):
     assert "all-to-all" in text or "collective-permute" in text
 
 
+def test_looped_train_step_holds_a_layers_kernels_once(topo):
+    """Ouro-2.6B's step at the published widths (16 query = 16 key-value
+    heads of 128, the tuner's rung for the cell's mask), 2 of its layers
+    and 4 passes, at the check's 4,096 tokens: the pass is one scan, so
+    the compiled step holds 4 x layers flex kernels (the forward in the
+    scanned pass; remat's forward, dq and dkv in its transpose) and not
+    4 x layers x passes, and it traces, differentiates and rematerialises
+    ``dist_attn_local`` inside ``scan`` + ``checkpoint`` + ``shard_map``
+    for the chip's compiler as it stands."""
+    import json
+
+    import optax
+
+    from benchmarks import masks
+    from magiattention_tpu.models.pattern import (
+        build_magi_pattern, init_pattern_params, ouro_config,
+    )
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs", "ouro-2.6b.json")) as f:
+        cfg = ouro_config(
+            dict(json.load(f), num_hidden_layers=2), remat=True
+        )
+    with open(os.path.join(
+        here, "benchmarks", "traffic", "train-16k-packed-looped.json"
+    )) as f:
+        mask = masks.build_mask(json.load(f)["mask"], 4096, index=0)
+    assert (cfg.n_loops, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads) == (
+        4, 2, 16, 16
+    )
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
+    model, _ = build_magi_pattern(
+        cfg, mesh, mask.cu_seqlens, chunk_size=512, interpret=False
+    )
+    (p,) = model.attn_params.values()
+    assert (p.block_q, p.block_k, p.head_block, p.grid) == (
+        128, 512, 8, "sparse"
+    )
+    opt = optax.adamw(3e-4)
+    rep = NamedSharding(mesh, P())
+    params = jax.eval_shape(
+        lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)
+    )
+    state = jax.eval_shape(opt.init, params)
+    params, state = jax.tree.map(
+        lambda s: _on(rep, s.shape, s.dtype), (params, state)
+    )
+    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, 4096), jnp.int32)
+    text = (
+        model.make_train_step(opt)
+        .lower(params, state, batch, batch, batch)
+        .compile()
+        .as_text()
+    )
+    assert text.count("tpu_custom_call") == 4 * cfg.n_layers
+
+
 @pytest.mark.parametrize("cp", [1, 4])
 def test_keyed_kernels_carry_role_names(topo, cp, monkeypatch):
     """The keyed path's forward+backward program, as the benchmark's

@@ -2,7 +2,7 @@
 table sets it already walks, the steps the row-major and the compact grid
 would launch over forward, dq and dkv, prices them with the two per-step
 costs of ``tuning/cost_model.py`` and sets ``FlexAttnParams.grid``. Host
-only: the plans of the benchmark's eight cells are built from their own
+only: the plans of the benchmark's ten cells are built from their own
 traffic files, nothing runs on a device."""
 
 import importlib
@@ -106,6 +106,17 @@ CELLS = {
     # 16 chunks of 4 blocks: q block r meets 4 (r // 4 + 1) k blocks
     "magi64x8-attn-64k-chunkcausal": [
         ((1024, 1024, 1), "sparse", 2 * 64 * 64 + 65 * 64, 6536, 3 * 16 * 136),
+    ],
+    # the dense mask at cp=4 (ISSUE 32): a rank's 65,536 rows against the
+    # merged buffer of up to 262,144; the tuner leaves the one-chip dense
+    # cell's (1024, 1024, 1) for (512, 2048, 1)
+    "magi64x8-attn-cp4-256k-causal": [
+        ((512, 2048, 1), "sparse", 49152, 24784, 24768),
+    ],
+    # the Mistral cell's mask at 16 query = 16 key-value heads of width
+    # 128 (ISSUE 32): the same tables, eight key-value heads a step
+    "ouro26b-train-16k-looped": [
+        ((128, 512, 8), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
     ],
 }
 
