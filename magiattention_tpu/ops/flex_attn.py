@@ -1617,8 +1617,10 @@ def _check_smem_budget(ftab, btab, tqp: int, tkp: int, params) -> None:
 
 
 _AUTO_BLOCK_CONFIGS: tuple[tuple[int, int, int], ...] = (
-    # (block_q, block_k, head_block) in preference order, all measured to fit
-    # v5e limits (16 MB scoped vmem) at head_dim 128. Larger block_k shrinks
+    # (block_q, block_k, head_block) in preference order, all compiled for a
+    # v5e at head_dim 128 under the scoped VMEM the kernels ask for
+    # (_VMEM_LIMIT_BYTES, 64 MiB; the compiler's own default of 16 MiB
+    # refuses the head-batched rungs). Larger block_k shrinks
     # the entry table (the scalar-prefetch smem arrays are ~40 B/entry
     # against a 1 MB smem budget) and amortizes grid-step overhead.
     # At head_dim 256 and GQA group 1 (latent attention after its

@@ -1112,6 +1112,9 @@ def record_autotune_decision(decision) -> None:
             "cache_layer": decision.cache_layer,
             "fingerprint": decision.fingerprint_hash,
             "reason": decision.reason,
+            "smem_entries": decision.smem_entries,
+            "smem_count": decision.smem_count,
+            "rejected_smem": decision.rejected_smem,
         },
     )
 

@@ -23,7 +23,7 @@ import dataclasses
 import math
 
 from .cache import TuningRecord, get_tuning_cache
-from .cost_model import any_feasible_rung, rank_candidates, smem_feasible
+from .cost_model import any_feasible_rung, rank_candidates, smem_entries
 from .fingerprint import make_fingerprint
 
 AUTOTUNE_MODES = ("off", "model", "measure")
@@ -47,6 +47,14 @@ class TuningDecision:
     # kernel grid layout ("row_major" | "sparse"): heterogeneous masks
     # resolve to the compact sparse entry walk (ROADMAP item 1)
     grid: str = "row_major"
+    # what the SMEM feasibility test read (flex decisions off the static
+    # table): the chosen rung's entry count, which count it is (``exact``:
+    # the global tables' own; ``bound``: per-rank tables, every slice's
+    # bounding box times the rank's share) and how many candidates the test
+    # dropped — so a record says when the test, not the price, chose
+    smem_entries: int = 0
+    smem_count: str = ""
+    rejected_smem: int = 0
 
     @property
     def config(self) -> tuple[int, int, int]:
@@ -86,7 +94,7 @@ def select_block_config(
     mode: str | None = None,
     max_block_q: int | None = None,
     max_block_k: int | None = None,
-    smem_headroom: float = 1.0,
+    cp_size: int = 1,
     measure_fn=None,
     include_sparse: bool = True,
 ) -> TuningDecision | None:
@@ -96,7 +104,9 @@ def select_block_config(
     one candidate on device (only consulted in ``measure`` mode;
     exceptions disqualify the candidate rather than failing the plan).
     ``include_sparse=False`` restricts the ranking to the row-major grid
-    (the distributed plan builder's contract).
+    (the distributed plan builder's contract). ``cp_size`` says whose
+    tables the SMEM test counts (``cost_model.smem_entries``): 1 the global
+    ones, exactly; more a rank's, by the bounding-box estimate.
 
     Returns ``None`` when the caller's ``max_block_q``/``max_block_k``
     constraints leave no candidate rung — the caller falls back to its
@@ -131,23 +141,26 @@ def select_block_config(
     cache = get_tuning_cache()
     rec, layer = cache.get(fp)
     aliased = False
-    if (
-        rec is not None
-        and not smem_feasible(
+    smem = None  # what the SMEM test reads for the cached rung, here
+    if rec is not None:
+        smem = smem_entries(
             q_ranges,
             k_ranges,
             attn_type_map,
             rec.block_q,
             rec.block_k,
-            smem_headroom,
+            cp_size,
         )
+    if (
+        smem is not None
+        and not smem.feasible
         and any_feasible_rung(
             q_ranges,
             k_ranges,
             attn_type_map,
             max_block_q=max_block_q,
             max_block_k=max_block_k,
-            smem_headroom=smem_headroom,
+            cp_size=cp_size,
         )
     ):
         # bucket-edge aliasing: the fingerprint's ~9% log2 buckets can
@@ -187,6 +200,11 @@ def select_block_config(
             measured_ms=rec.measured_ms,
             reason=f"tuning-cache {layer} hit ({rec.source} winner)",
             grid=rec.grid,
+            smem_entries=smem.entries,
+            smem_count=smem.count,
+            rejected_smem=sum(
+                not c.get("feasible", True) for c in rec.candidates
+            ),
         )
         _record(decision)
         return decision
@@ -201,7 +219,7 @@ def select_block_config(
         head_dim=head_dim,
         max_block_q=max_block_q,
         max_block_k=max_block_k,
-        smem_headroom=smem_headroom,
+        cp_size=cp_size,
         include_sparse=include_sparse,
     )
     if not scores:
@@ -287,6 +305,9 @@ def select_block_config(
         measured_ms=measured_ms,
         reason=reason,
         grid=best.grid,
+        smem_entries=best.smem_entries,
+        smem_count=best.smem_count,
+        rejected_smem=sum(not s.feasible for s in scores),
     )
     _record(decision)
     return decision
@@ -612,8 +633,10 @@ def resolve_block_config(
     test meshes) — those cases keep the pre-ISSUE-2 behavior bit-for-bit.
 
     Candidates are constrained to the per-rank shard geometry (a tile
-    wider than the rank's buffer is pure padding) and the SMEM estimate
-    is scaled to per-rank tables (global entries / cp, doubled for run
+    wider than the rank's buffer is pure padding). At cp = 1 the plan's
+    tables are the global tables and the SMEM test reads their exact entry
+    count; at cp > 1 they are per-rank, and it reads the bounding-box
+    estimate scaled to a rank (global entries / cp, doubled for run
     fragmentation). ``measure`` mode degrades to the cost model here —
     there is no way to microbenchmark a full distributed plan during key
     creation; the decision's telemetry records that. Sparse-grid rungs
@@ -640,7 +663,7 @@ def resolve_block_config(
         dtype=str(out_dtype),
         max_block_q=shard_q,
         max_block_k=shard_k,
-        smem_headroom=(1.0 if cp_size <= 1 else 2.0 / cp_size),
+        cp_size=cp_size,
         include_sparse=False,
     )
     if decision is None:

@@ -21,13 +21,16 @@ SHAPE, not just the total seqlen the old static table keyed on:
 Entry/step counts are computed EXACTLY for identity-run layouts by
 intersecting every slice with the candidate's q-block grid (vectorized
 numpy, O(num_slices * num_q_blocks) — host planning scale). Feasibility
-uses the conservative legacy upper bound (misalignment-padded rectangle
-coverage) so distributed plans with fragmented runs stay inside budget.
+reads the tables those counts describe where the plan's tables are the
+global ones (one device, cp = 1), and the legacy upper bound (every slice's
+misalignment-padded bounding box, scaled to a rank) where they are
+per-rank tables over fragmented runs: :func:`smem_entries`.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import numpy as np
 
@@ -157,7 +160,7 @@ class CandidateScore:
     head_block: int  # snapped to the workload's GQA group / hq
     entries: int  # exact tile count (identity runs), incl. dummies
     steps: int  # max entries on any q block = static inner-grid extent
-    smem_entries: int  # conservative upper bound used for feasibility
+    smem_entries: int  # the count the SMEM test read (:func:`smem_entries`)
     feasible: bool
     mxu_seconds: float
     step_seconds: float
@@ -166,6 +169,7 @@ class CandidateScore:
     grid: str = "row_major"
     live_slots: int = 0  # grid_rows * entries (slots that compute)
     dead_slots: int = 0  # clamped slots past a row's entry count
+    smem_count: str = "bound"  # which count ``smem_entries`` is: exact | bound
 
     @property
     def cost_seconds(self) -> float:
@@ -225,6 +229,11 @@ def estimate_entries(
     not pay the count twice.
     """
     q, k, t = _normalize_slices(q_ranges, k_ranges, attn_type_map)
+    return _counted_entries(q, k, t, block_q, block_k)[:3]
+
+
+def _counted_entries(q, k, t, block_q: int, block_k: int):
+    """:func:`_estimate_entries_impl`'s four counts, memoized."""
     key = (slices_digest(q, k, t), int(block_q), int(block_k))
     hit = _ENTRY_MEMO.get(key)
     if hit is None:
@@ -276,10 +285,15 @@ def slice_block_k_spans(
 
 def _estimate_entries_impl(
     q: np.ndarray, k: np.ndarray, t: np.ndarray, block_q: int, block_k: int
-) -> tuple[int, int, int]:
+) -> tuple[int, int, int, int]:
+    """(entries, steps, num_q_blocks, bwd_entries). The backward table holds
+    the same live tiles under the k blocks, with ITS dummies: one for every
+    k block, up to the mask's k extent, that no tile touches."""
     extent_q = int(q[:, 1].max()) if q.size else 0
     nq = max(_cdiv(extent_q, block_q), 1)
+    nk = max(_cdiv(int(k[:, 1].max()) if k.size else 0, block_k), 1)
     per_block = np.zeros(nq, dtype=np.int64)
+    k_cover = np.zeros(nk + 1, dtype=np.int64)  # +1 / -1 at a run's ends
     for (q0, q1), (k0, k1), mt in zip(q.tolist(), k.tolist(), t.tolist()):
         if q1 <= q0 or k1 <= k0:
             continue
@@ -287,16 +301,17 @@ def _estimate_entries_impl(
             q0, q1, k0, k1, mt, block_q
         )
         covered = k_hi > k_lo
-        nkb = np.where(
-            covered,
-            (np.maximum(k_hi, k_lo + 1) - 1) // block_k - k_lo // block_k + 1,
-            0,
-        )
-        per_block[idx] += nkb
+        first = k_lo // block_k
+        last = (np.maximum(k_hi, k_lo + 1) - 1) // block_k
+        per_block[idx] += np.where(covered, last - first + 1, 0)
+        np.add.at(k_cover, first[covered], 1)
+        np.add.at(k_cover, last[covered] + 1, -1)
     dummies = int((per_block == 0).sum())
-    entries = int(per_block.sum()) + dummies
+    live = int(per_block.sum())
+    entries = live + dummies
     steps = max(int(per_block.max()) if per_block.size else 0, 1)
-    return entries, steps, nq
+    bwd_dummies = int((np.cumsum(k_cover[:nk]) == 0).sum())
+    return entries, steps, nq, live + bwd_dummies
 
 
 def exact_mask_area(q_ranges, k_ranges, attn_type_map) -> int:
@@ -330,41 +345,69 @@ def exact_mask_area(q_ranges, k_ranges, attn_type_map) -> int:
     return hit
 
 
-def smem_feasible(
+class SmemCount(NamedTuple):
+    """What the SMEM feasibility test read for one rung."""
+
+    entries: int
+    count: str  # "exact": the global tables' own; "bound": see smem_entries
+    feasible: bool  # entries <= flex_attn._MAX_SMEM_ENTRIES
+
+
+_ENTRY_PAD = 8  # build_block_meta's default: a table's length is padded to it
+
+
+def smem_entries(
     q_ranges,
     k_ranges,
     attn_type_map,
     block_q: int,
     block_k: int,
-    smem_headroom: float = 1.0,
-) -> bool:
-    """The ranker's SMEM feasibility test for ONE rung on the EXACT
-    workload — used to re-validate tuning-cache hits: the fingerprint's
-    ~9% log2 buckets can alias a near-budget workload onto a cached winner
-    whose entry table would not fit this workload's table.
+    cp_size: int = 1,
+) -> SmemCount:
+    """The entry count the SMEM test holds one rung's tables to on the
+    EXACT workload, which count it is, and the verdict. THE one answer to
+    "does this rung's table fit": the ranker, the cache-hit re-validation
+    (``autotuner.select_block_config``) and :func:`any_feasible_rung` all
+    read it.
 
-    Memoized (digest keys) — it runs on EVERY cache hit, i.e. the keyed
-    runtime's steady-state repeat-call path, where the pre-PR cost was a
-    pure dict hit."""
+    ``cp_size`` 1: the plan's tables are the global tables (one device, or
+    cp = 1), so the count is what ``build_block_meta`` builds and the
+    launch guard (``flex_attn._check_smem_budget``) reads (``"exact"``):
+    the longer of the forward table (:func:`estimate_entries`' count: the
+    live tiles and a dummy for every q block without one) and the backward
+    table (the same tiles and a dummy for every k block without one),
+    padded as the builder pads it. A buffer longer than its mask has a
+    dummy more for every block past the mask's extent, which the slices do
+    not show; the launch guard's budget is 2,214 entries over
+    ``_MAX_SMEM_ENTRIES``, for those and for its fixed tables.
+
+    ``cp_size`` > 1: the tables are per-rank ones over fragmented runs,
+    which the global slices cannot count, so it is every slice's bounding
+    box in tiles (``flex_attn._est_entries``) times ``2 / cp`` (a rank's
+    share, doubled for run fragmentation; ``"bound"``). The box of a band
+    slice (a sliding window's BICAUSAL strip: 65,536 rows against 65,536
+    keys where a row attends 1,024) is 40 times its table.
+
+    Memoized (digest keys): the cache-hit re-validation runs on EVERY
+    hit, the keyed runtime's steady-state repeat-call path."""
     q, k, t = _normalize_slices(q_ranges, k_ranges, attn_type_map)
-    key = (
-        slices_digest(q, k, t),
-        int(block_q),
-        int(block_k),
-        int(round(smem_headroom * 1024)),
-    )
+    key = (slices_digest(q, k, t), int(block_q), int(block_k), int(cp_size))
     hit = _SMEM_MEMO.get(key)
     if hit is None:
         from ..ops.flex_attn import _MAX_SMEM_ENTRIES, _est_entries
 
-        naive = [(int(a), int(b)) for a, b in q.tolist()]
-        naive_k = [(int(a), int(b)) for a, b in k.tolist()]
-        est = int(
-            _est_entries(naive, naive_k, block_q, block_k) * smem_headroom
-        )
+        if cp_size <= 1:
+            fwd, _, _, bwd = _counted_entries(q, k, t, block_q, block_k)
+            entries = -(-max(fwd, bwd) // _ENTRY_PAD) * _ENTRY_PAD
+            which = "exact"
+        else:
+            box = _est_entries(q.tolist(), k.tolist(), block_q, block_k)
+            entries, which = int(box * (2.0 / cp_size)), "bound"
         if len(_SMEM_MEMO) >= _ENTRY_MEMO_CAP:  # crude bound, never grows
             _SMEM_MEMO.clear()
-        hit = _SMEM_MEMO[key] = est <= _MAX_SMEM_ENTRIES
+        hit = _SMEM_MEMO[key] = SmemCount(
+            entries, which, entries <= _MAX_SMEM_ENTRIES
+        )
     return hit
 
 
@@ -378,7 +421,7 @@ def any_feasible_rung(
     *,
     max_block_q: int | None = None,
     max_block_k: int | None = None,
-    smem_headroom: float = 1.0,
+    cp_size: int = 1,
 ) -> bool:
     """True when at least one candidate rung fits the exact workload's
     SMEM budget — the re-rank-on-aliased-hit escape hatch: if nothing is
@@ -386,7 +429,9 @@ def any_feasible_rung(
     from ..ops.flex_attn import _AUTO_BLOCK_CONFIGS
 
     return any(
-        smem_feasible(q_ranges, k_ranges, attn_type_map, bq, bk, smem_headroom)
+        smem_entries(
+            q_ranges, k_ranges, attn_type_map, bq, bk, cp_size
+        ).feasible
         for bq, bk, _hb in _AUTO_BLOCK_CONFIGS
         if (max_block_q is None or bq <= max_block_q)
         and (max_block_k is None or bk <= max_block_k)
@@ -422,7 +467,7 @@ def rank_candidates(
     generation: str | None = None,
     max_block_q: int | None = None,
     max_block_k: int | None = None,
-    smem_headroom: float = 1.0,
+    cp_size: int = 1,
     include_sparse: bool = True,
 ) -> list[CandidateScore]:
     """Score every candidate rung for the workload, best first.
@@ -446,8 +491,9 @@ def rank_candidates(
 
     ``max_block_q``/``max_block_k`` drop rungs larger than the caller's
     shard geometry (distributed plans: a tile wider than the per-rank
-    buffer is pure padding). ``smem_headroom`` scales the conservative
-    entry upper bound (>1 models per-rank run fragmentation).
+    buffer is pure padding). ``cp_size`` says which tables the SMEM test
+    counts (:func:`smem_entries`): 1 the global ones, exactly; more the
+    per-rank ones, by the bounding-box estimate times ``2 / cp``.
 
     Infeasible-everywhere masks return the legacy escalation order
     (wide-tile rungs first) with ``feasible=False`` throughout — callers
@@ -455,11 +501,7 @@ def rank_candidates(
     kernel's SMEM check raise a descriptive error.
     """
     from .. import env
-    from ..ops.flex_attn import (
-        _MAX_SMEM_ENTRIES,
-        _auto_head_block,
-        _est_entries,
-    )
+    from ..ops.flex_attn import _auto_head_block
 
     q, k, t = _normalize_slices(q_ranges, k_ranges, attn_type_map)
     extent = 0
@@ -469,13 +511,11 @@ def rank_candidates(
     spec = TPU_PEAK_SPECS.get(gen) or TPU_PEAK_SPECS["v5e"]
     eff_flops = spec.bf16_tflops * 1e12 * spec.mfu
     group = max(hq // max(hk, 1), 1)
-    naive = [(r[0], r[1]) for r in q.tolist()]
-    naive_k = [(r[0], r[1]) for r in k.tolist()]
 
     def score_one(bq: int, bk: int, hb_pref: int, grid: str):
         hb = _auto_head_block(hb_pref, hq, group)
         entries, steps, nq = estimate_entries(q, k, t, bq, bk)
-        smem_est = int(_est_entries(naive, naive_k, bq, bk) * smem_headroom)
+        smem = smem_entries(q, k, t, bq, bk, cp_size)
         grid_rows = max(hq // max(hb, 1), 1)
         live = grid_rows * entries
         if grid == "sparse":
@@ -491,13 +531,14 @@ def rank_candidates(
             head_block=hb,
             entries=entries,
             steps=steps,
-            smem_entries=smem_est,
-            feasible=smem_est <= _MAX_SMEM_ENTRIES,
+            smem_entries=smem.entries,
+            feasible=smem.feasible,
             mxu_seconds=mxu_s,
             step_seconds=step_s,
             grid=grid,
             live_slots=live,
             dead_slots=dead,
+            smem_count=smem.count,
         )
 
     scores: list[CandidateScore] = []

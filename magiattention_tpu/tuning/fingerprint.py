@@ -70,7 +70,10 @@ class WorkloadFingerprint:
     # share a cache slot in either direction
     sparse_rungs: int = 1
 
-    FINGERPRINT_VERSION = 3
+    # v4 (ISSUE 33): at cp = 1 the SMEM test reads the exact entry count,
+    # which admits small rungs for band masks; a (1024, 1024, 1) cached for
+    # a sliding window because nothing smaller was allowed is not served
+    FINGERPRINT_VERSION = 4
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)

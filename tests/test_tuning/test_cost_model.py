@@ -1,13 +1,31 @@
 """Cost model: exact entry counting + canonical rung choices (ISSUE 2)."""
 
+import functools
+import os
+
 import numpy as np
+import pytest
 
 from magiattention_tpu.ops.block_meta import (
+    build_block_meta,
     build_block_meta_general,
     identity_runs,
 )
+from magiattention_tpu.ops.flex_attn import (
+    _AUTO_BLOCK_CONFIGS,
+    _MAX_SMEM_ENTRIES,
+    _est_entries,
+)
 from magiattention_tpu.testing.workloads import mask_families
-from magiattention_tpu.tuning import estimate_entries, rank_candidates
+from magiattention_tpu.tuning import (
+    estimate_entries,
+    rank_candidates,
+    reset_tuning_cache,
+    select_block_config,
+)
+from magiattention_tpu.tuning.cost_model import smem_entries
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def _meta_counts(qr, kr, ts, total, bq, bk):
@@ -178,3 +196,204 @@ def _varlen_16k():
         [(s[2], s[3]) for s in sl],
         [s[4] for s in sl],
     )
+
+
+# -- the SMEM test's entry count (ISSUE 33) --------------------------------
+# the benchmark's four mask families at 65,536 tokens, and band masks of
+# three widths; the cp=4 cells' masks at 262,144
+_MASK_SPECS = {
+    "packed": "magi64x8-attn-64k-varlen",
+    "causal": "magi64x8-attn-64k-causal",
+    "chunk_causal": "magi64x8-attn-64k-chunkcausal",
+    "swa256": {"type": "swa_causal", "window": 256},
+    "swa1024": "magi64x8-attn-64k-swa1024",
+    "swa4096": {"type": "swa_causal", "window": 4096},
+    "cp4_packed": "magi64x8-attn-cp4-256k-varlen",
+    "cp4_causal": "magi64x8-attn-cp4-256k-causal",
+}
+# (exact entries, the old bounding-box bound) where the issue names them
+_PINNED = {
+    ("swa1024", 128, 512): (1524, 65172),
+    ("swa1024", 256, 512): (762, 32652),
+    ("swa1024", 1024, 1024): (127, 4164),
+    ("causal", 128, 512): (33024, 66177),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _bench_mask(family: str):
+    from benchmarks import harness, masks
+
+    spec, total = _MASK_SPECS[family], 65536
+    if isinstance(spec, str):
+        traffic = harness.load_cell(ROOT, spec).traffic
+        spec, total = traffic["mask"], int(traffic["total_tokens"])
+    m = masks.build_mask(spec, total)
+    return m.q_ranges, m.k_ranges, m.types, total
+
+
+@pytest.mark.parametrize("bq,bk", [c[:2] for c in _AUTO_BLOCK_CONFIGS])
+@pytest.mark.parametrize(
+    "family",
+    ["packed", "causal", "chunk_causal", "swa256", "swa1024", "swa4096"],
+)
+def test_at_cp1_the_smem_test_reads_the_table_build_block_meta_builds(
+    family, bq, bk
+):
+    """Where the tables are the global ones the feasibility test counts
+    what the builder builds, forward and backward (every q and k block of
+    these masks is covered, so neither table has dummies) with its padding,
+    and not every slice's bounding box, which for a band is 40x the table."""
+    qr, kr, ts, total = _bench_mask(family)
+    meta = build_block_meta(
+        qr, kr, ts, total, total, block_q=bq, block_k=bk, entry_pad=1
+    )
+    exact = estimate_entries(qr, kr, ts, bq, bk)[0]
+    assert exact == meta.num_fwd_entries == meta.num_bwd_entries
+    built = _built_entries(qr, kr, ts, total, total, bq, bk)
+    assert exact <= built < exact + 8
+    assert smem_entries(qr, kr, ts, bq, bk) == (
+        built, "exact", built <= _MAX_SMEM_ENTRIES,
+    )
+    bound = _est_entries(qr, kr, bq, bk)
+    assert built <= bound
+    # cp = 2's share of the box is the whole box: what cp = 1 read before
+    assert smem_entries(qr, kr, ts, bq, bk, 2) == (
+        bound, "bound", bound <= _MAX_SMEM_ENTRIES,
+    )
+    if (family, bq, bk) in _PINNED:
+        assert (exact, bound) == _PINNED[family, bq, bk]
+
+
+def _built_entries(qr, kr, ts, total_q, total_k, bq, bk):
+    """What the launch guard reads: the longer of the two built tables."""
+    meta = build_block_meta(qr, kr, ts, total_q, total_k, block_q=bq, block_k=bk)
+    return max(meta.num_fwd_entries, meta.num_bwd_entries)
+
+
+_DOCS = [(i * 1024, (i + 1) * 1024) for i in range(64)]
+# masks that leave most k blocks bare: (q ranges, k ranges, types, rows, keys)
+_SPARSE_KEYS = {
+    # 64 documents of 1,024 rows that all read the last 512 keys
+    "shared_suffix": (_DOCS, [(65024, 65536)] * 64, [0] * 64, 65536, 65536),
+    # ... that all read the first 512, and the last one itself as well
+    "shared_prefix": (
+        _DOCS + [_DOCS[-1]], [(0, 512)] * 64 + [_DOCS[-1]], [0] * 64 + [1],
+        65536, 65536,
+    ),
+    # keys in two islands with a gap between, rows with a gap too
+    "islands": (
+        [(0, 4096), (32768, 36864)], [(8192, 9216), (60000, 65536)], [1, 0],
+        36864, 65536,
+    ),
+}
+
+
+@pytest.mark.parametrize("bq,bk", [c[:2] for c in _AUTO_BLOCK_CONFIGS])
+@pytest.mark.parametrize("name", sorted(_SPARSE_KEYS))
+def test_the_exact_count_is_the_longer_table_with_its_dummies(name, bq, bk):
+    """A mask that leaves most k blocks bare: the backward table is the
+    long one (the live tiles and a dummy a bare k block), and the launch
+    guard reads the longer table, padded. What the ranker calls feasible
+    is what ``_check_smem_budget`` will see."""
+    qr, kr, ts, rows, keys = _SPARSE_KEYS[name]
+    fwd = estimate_entries(qr, kr, ts, bq, bk)[0]
+    built = _built_entries(qr, kr, ts, rows, keys, bq, bk)
+    got = smem_entries(qr, kr, ts, bq, bk)
+    assert (got.entries, got.count) == (built, "exact")
+    if name != "islands" and bk < 2048:  # islands: the q gap's dummies lead
+        assert got.entries > fwd + 8  # the forward count alone read too few
+
+
+def test_a_buffer_longer_than_its_mask_has_dummies_the_slices_do_not_show():
+    """The one thing the count cannot see: blocks past the mask's extent
+    (here 127 key blocks nothing reads, behind the 512 keys every row
+    reads) get a dummy each in the built table. The launch guard's budget
+    is 2,214 entries over ``_MAX_SMEM_ENTRIES`` for them."""
+    qr, kr, ts = _DOCS, [(0, 512)] * 64, [0] * 64
+    got = smem_entries(qr, kr, ts, 128, 512)
+    built = _built_entries(qr, kr, ts, 65536, 65536, 128, 512)
+    assert (got.entries, built) == (512, 512 + 127 + 1)
+
+
+def test_a_band_mask_gets_a_rung_that_fits_the_band():
+    """THE ISSUE 33 regression: window 1,024 at 65,536 on the keyed
+    runtime's arguments. (128, 512)'s table is 1,524 entries; under the
+    bound it read 65,172, every rung under a megalogit was thrown out
+    before its price was looked at, and the rest tied on (1024, 1024, 1)."""
+    qr, kr, ts, total = _bench_mask("swa1024")
+    ranked = rank_candidates(
+        qr, kr, ts, 64, 8, max_block_q=total, max_block_k=total,
+        include_sparse=False,
+    )
+    assert all(s.feasible and s.smem_count == "exact" for s in ranked)
+    best = ranked[0]
+    assert (best.block_q, best.block_k, best.head_block) == (128, 512, 8)
+    assert (best.entries, best.smem_entries) == (1524, 1528)  # padded to 8
+    # the prices did not move: the small rungs were 21-25% cheaper all along
+    cost = {(s.block_q, s.block_k): s.cost_seconds * 1e3 for s in ranked}
+    assert cost[128, 512] == pytest.approx(36.89, abs=0.01)
+    assert cost[256, 512] == pytest.approx(35.06, abs=0.01)
+    assert cost[1024, 1024] == pytest.approx(46.74, abs=0.01)
+    old = rank_candidates(
+        qr, kr, ts, 64, 8, max_block_q=total, max_block_k=total,
+        include_sparse=False, cp_size=2,  # the whole box, as cp = 1 read
+    )
+    assert [(s.block_q, s.block_k) for s in old if not s.feasible] == [
+        (256, 512), (128, 512),
+    ]
+    assert (old[0].block_q, old[0].block_k) == (1024, 1024)
+
+
+def test_a_table_that_really_passes_the_budget_stays_infeasible():
+    qr, kr, ts, _ = _bench_mask("causal")
+    assert smem_entries(qr, kr, ts, 128, 512) == (33024, "exact", False)
+    ranked = rank_candidates(qr, kr, ts, 64, 8, include_sparse=False)
+    assert [(s.block_q, s.block_k) for s in ranked if not s.feasible] == [
+        (128, 512)
+    ]
+    assert (ranked[0].block_q, ranked[0].block_k) == (1024, 1024)
+
+
+@pytest.mark.parametrize(
+    "family,verdicts,first",
+    [
+        ("cp4_packed", (False, True, True, True, True), (1024, 1024, 1)),
+        # nothing fits: the all-infeasible escalation order's widest tile
+        ("cp4_causal", (False,) * 5, (512, 2048, 1)),
+    ],
+)
+def test_per_rank_tables_keep_the_bound_and_its_verdicts(
+    family, verdicts, first
+):
+    """cp = 4 (the box times 2 / cp): the global slices cannot count a
+    rank's table, so the estimate stays every slice's bounding box times
+    the rank's share, and both cp=4 cells keep the verdicts and the rung
+    they had (whether the dense one should is ROADMAP S6's, on four chips)."""
+    qr, kr, ts, total = _bench_mask(family)
+    assert tuple(
+        smem_entries(qr, kr, ts, bq, bk, 4).feasible
+        for bq, bk, _hb in _AUTO_BLOCK_CONFIGS
+    ) == verdicts
+    ranked = rank_candidates(
+        qr, kr, ts, 64, 8, max_block_q=total // 4, max_block_k=total // 4,
+        cp_size=4, include_sparse=False,
+    )
+    for s in ranked:
+        bound = int(_est_entries(qr, kr, s.block_q, s.block_k) * 0.5)
+        assert (s.smem_entries, s.smem_count) == (bound, "bound")
+        assert s.feasible == (bound <= _MAX_SMEM_ENTRIES)
+    best = ranked[0]
+    assert (best.block_q, best.block_k, best.head_block) == first
+    # the decision's record says which count chose, and how many it dropped
+    reset_tuning_cache()
+    decision = select_block_config(
+        qr, kr, ts, 64, 8, max_block_q=total // 4, max_block_k=total // 4,
+        cp_size=4, include_sparse=False, mode="model",
+    )
+    reset_tuning_cache()
+    assert decision.config == first
+    assert (decision.smem_entries, decision.smem_count) == (
+        best.smem_entries, "bound",
+    )
+    assert decision.rejected_smem == verdicts.count(False)

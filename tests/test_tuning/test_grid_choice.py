@@ -5,6 +5,7 @@ costs of ``tuning/cost_model.py`` and sets ``FlexAttnParams.grid``. Host
 only: the plans of the benchmark's ten cells are built from their own
 traffic files, nothing runs on a device."""
 
+import dataclasses
 import importlib
 import os
 
@@ -17,7 +18,14 @@ from magiattention_tpu import api, telemetry
 from magiattention_tpu.ops import build_block_meta
 from magiattention_tpu.parallel.dist_attn import StageTables
 from magiattention_tpu.testing.workloads import ranges_of, varlen_block_causal
-from magiattention_tpu.tuning import cost_model
+from magiattention_tpu.tuning import (
+    TuningRecord,
+    WorkloadFingerprint,
+    cost_model,
+    get_tuning_cache,
+    make_fingerprint,
+    resolve_block_config,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -95,8 +103,15 @@ CELLS = {
         ((128, 512, 8), "sparse", 2 * 256 * 16 + 65 * 62, 3 * 1128, 3363),
         ((128, 512, 8), "sparse", 6680, 2568, 2562),
     ],
+    # ISSUE 33: the SMEM test counts the band's own table, so the band gets
+    # (128, 512, 8) where it had (1024, 1024, 1). The CAUSAL head's 8 q
+    # blocks meet 1, 1, 1, 1, 2, 2, 2, 2 k blocks and each of the band's 504
+    # meets 3 (1,151 keys in 512-key tiles): 12 + 1,512 = 1,524 entries, 3 a
+    # q block at most and 12 a k block; the plan's 129th k block adds a
+    # dummy, and both tables pad to 1,528. 36 of 4,620 row-major steps are
+    # dead: under the flip margin
     "magi64x8-attn-64k-swa1024": [
-        ((1024, 1024, 1), "row_major", 2 * 64 * 2 + 65 * 2, 384, 381),
+        ((128, 512, 8), "row_major", 2 * 512 * 3 + 129 * 12, 3 * 1528, 3 * 1524),
     ],
     # the Mistral cell's mask at 20 query = 20 key-value heads of width
     # 256 (ISSUE 30): the same tables; (128, 512, 8) snaps to 5 heads a step
@@ -142,6 +157,61 @@ def test_every_cell_keeps_its_rung_and_gets_the_expected_grid(
     assert telemetry.snapshot()["gauges"][
         "magi_flex_dead_step_share"
     ] == pytest.approx(100.0 * (1.0 - args["live_steps"] / launched))
+
+
+def test_a_version_3_record_for_a_band_mask_is_not_served(
+    telemetry_on, monkeypatch, tmp_path
+):
+    """A cache directory from before ISSUE 33 holds (1024, 1024, 1) for the
+    window mask, chosen when the SMEM test allowed nothing smaller. Its
+    record is a version-3 fingerprint's: under its own hash the version-4
+    key never opens it, and planted under the new key's name the stored
+    fingerprint does not match. Either way the mask is ranked anew."""
+    from benchmarks import masks
+
+    assert WorkloadFingerprint.FINGERPRINT_VERSION == 4
+    monkeypatch.setenv("MAGI_ATTENTION_AUTOTUNE_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("MAGI_ATTENTION_AUTOTUNE", raising=False)
+    total = 65536
+    m = masks.build_mask({"type": "swa_causal", "window": 1024}, total)
+    fp = make_fingerprint(
+        m.q_ranges, m.k_ranges, m.types, 64, 8, max_block_q=total,
+        max_block_k=total, include_sparse=False,
+    )
+    old_fp = dataclasses.replace(fp, version=3)
+    assert old_fp.stable_hash() != fp.stable_hash()
+    stale = TuningRecord(1024, 1024, 1, "model", 46.74, None, ())
+    cache = get_tuning_cache()
+    cache._store_disk(old_fp.stable_hash(), old_fp, stale)
+    cache._store_disk(fp.stable_hash(), old_fp, stale)  # planted
+    assert cache.get(fp) == (None, "miss")
+
+    def resolve():
+        with telemetry.span("tile_choice"):
+            return resolve_block_config(
+                m.q_ranges, m.k_ranges, m.types, total, total, 1, 64, 8,
+                128, "bfloat16",
+            )
+
+    seen = len(telemetry.get_event_buffer().events())
+    assert resolve() == (128, 512, 8)
+    assert resolve() == (128, 512, 8)  # from the cache now: the same record
+    events = telemetry.get_event_buffer().events()[seen:]
+    # the decision's record, the ``tile_choice`` span's child, says what the
+    # SMEM test read, on a miss and on a hit: 1,524 tiles, padded to 8
+    got = [ev["args"] for ev in events if ev["name"] == "autotune_decision"]
+    assert len(got) == 2
+    for args in got:
+        assert (args["smem_entries"], args["smem_count"]) == (1528, "exact")
+        assert args["rejected_smem"] == 0
+    assert [
+        ev["args"]["cache_layer"]
+        for ev in events
+        if ev["name"] == "autotune_decision"
+    ] == ["none", "memory"]
+    # and the record that replaced the planted one is a version-4 one
+    rec = get_tuning_cache()._load_disk(fp.stable_hash(), fp)
+    assert (rec.block_q, rec.block_k, rec.head_block) == (128, 512, 8)
 
 
 def test_the_packed_cells_dead_share_on_both_grids(telemetry_on, monkeypatch):
