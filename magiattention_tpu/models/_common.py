@@ -8,6 +8,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..common.axes import cp_axis_names
+from ..utils.instrument import named_scope
 
 
 def masked_ce_tokens(logits, labels):
@@ -273,8 +274,9 @@ def make_model_train_step(model, optimizer):
         loss, grads = jax.value_and_grad(model.loss_fn)(
             params, tokens, labels, pos, tables
         )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        with named_scope("magi_optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
         return params, opt_state, loss
 
     return jax.jit(

@@ -127,30 +127,35 @@ def _layer_local(
     Head counts are inferred from the (possibly sharded) weight shapes.
     """
     dt = cfg.jnp_dtype
-    h = _rms_norm(x, layer["attn_norm"])
-    t = h.shape[0]
-    q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
-    k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
+    # one part scope a half (docs/observability.md, "Device scopes"); the
+    # attention call is a sibling: a flex kernel never lies under magi_proj
+    with named_scope("magi_proj"):
+        h = _rms_norm(x, layer["attn_norm"])
+        t = h.shape[0]
+        q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
+        k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
     out, _, _ = dist_attn_local(
         q, k, v, tables, plan, attn_params, axis_name=axis_name
     )
-    attn_out = out.reshape(t, -1) @ layer["wo"].astype(dt)
-    if tp_axis is not None:
-        with named_scope("magi_llama_attn_tp_psum"):
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-    x = x + attn_out
+    with named_scope("magi_proj"):
+        attn_out = out.reshape(t, -1) @ layer["wo"].astype(dt)
+        if tp_axis is not None:
+            with named_scope("magi_llama_attn_tp_psum"):
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+        x = x + attn_out
 
-    h = _rms_norm(x, layer["mlp_norm"])
-    gate = jax.nn.silu(h @ layer["w_gate"].astype(dt))
-    up = h @ layer["w_up"].astype(dt)
-    mlp_out = (gate * up) @ layer["w_down"].astype(dt)
-    if tp_axis is not None:
-        with named_scope("magi_llama_mlp_tp_psum"):
-            mlp_out = jax.lax.psum(mlp_out, tp_axis)
-    x = x + mlp_out
+    with named_scope("magi_ffn"):
+        h = _rms_norm(x, layer["mlp_norm"])
+        gate = jax.nn.silu(h @ layer["w_gate"].astype(dt))
+        up = h @ layer["w_up"].astype(dt)
+        mlp_out = (gate * up) @ layer["w_down"].astype(dt)
+        if tp_axis is not None:
+            with named_scope("magi_llama_mlp_tp_psum"):
+                mlp_out = jax.lax.psum(mlp_out, tp_axis)
+        x = x + mlp_out
     return x
 
 
@@ -167,7 +172,8 @@ def forward_local(
 ):
     """Per-cp-rank forward over dispatched tokens -> logits [t_loc, vocab]."""
     dt = cfg.jnp_dtype
-    x = params["embed"].astype(dt)[tokens]
+    with named_scope("magi_embed"):
+        x = params["embed"].astype(dt)[tokens]
 
     def one_layer(x, pos, layer):
         return _layer_local(
@@ -180,8 +186,9 @@ def forward_local(
         one_layer = jax.checkpoint(one_layer)
     for layer in params["layers"]:
         x = one_layer(x, pos, layer)
-    x = _rms_norm(x, params["final_norm"])
-    return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
+    with named_scope("magi_head"):
+        x = _rms_norm(x, params["final_norm"])
+        return (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -259,17 +266,19 @@ class MagiLlama:
                     self.cp_axis,
                     self.tp_axis,
                 )
-                return masked_ce_sums(logits, lab1)
+                with named_scope("magi_head"):
+                    return masked_ce_sums(logits, lab1)
 
             loss_sum, count = jax.vmap(one)(tok, lab, pos)
-            with named_scope("magi_llama_loss_psum"):
+            with named_scope("magi_head"), named_scope("magi_llama_loss_psum"):
                 loss_sum = jax.lax.psum(
                     jax.lax.psum(loss_sum.sum(), self.cp_axis), self.dp_axis
                 )
                 count = jax.lax.psum(
                     jax.lax.psum(count.sum(), self.cp_axis), self.dp_axis
                 )
-            return loss_sum / jnp.maximum(count, 1.0)
+            with named_scope("magi_head"):
+                return loss_sum / jnp.maximum(count, 1.0)
 
         return _local(params, tokens, labels, pos, *tables)
 

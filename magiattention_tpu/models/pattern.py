@@ -503,24 +503,29 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     rows = 2 * t
     e0, e1 = cfg.held_experts
     held = e1 - e0
-    local = idx.reshape(-1) - e0
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)  # pair ids
-    order = jnp.pad(order, (0, -(t * k) % rows))  # whole chunks to slice
-    counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    n_here = ends[-1]
+    with named_scope("magi_moe_sort"):
+        local = idx.reshape(-1) - e0
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # pair ids
+        order = jnp.pad(order, (0, -(t * k) % rows))  # whole chunks to slice
+        counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        ends = jnp.cumsum(counts)
+        starts = ends - counts
+        n_here = ends[-1]
     w_flat = w.reshape(-1)
-    we_gate, we_up, we_down = (
-        layer[n].astype(dt) for n in ("we_gate", "we_up", "we_down")
-    )
+    with named_scope("magi_moe_matmul"):
+        we_gate, we_up, we_down = (
+            layer[n].astype(dt) for n in ("we_gate", "we_up", "we_down")
+        )
 
     @jax.checkpoint  # a chunk keeps its inputs only
     def chunk(h, w_flat, pairs, lo):
-        sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-        valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
-        tok = pairs // k
+        with named_scope("magi_moe_sort"):
+            sizes = (
+                jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+            )
+            valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
+            tok = pairs // k
 
         def grouped(x, w):
             # rows past the held pairs belong to no group: what the
@@ -529,24 +534,31 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
             x = jnp.where(valid, x, 0)
             return jnp.where(valid, jax.lax.ragged_dot(x, w, sizes), 0)
 
-        xs = h[tok]
-        a, b = grouped(xs, we_gate), grouped(xs, we_up)
-        o = grouped(jax.nn.silu(a) * b, we_down)
-        o = o.astype(jnp.float32) * w_flat[pairs][:, None]
-        return jnp.zeros((t, cfg.dim), jnp.float32).at[tok].add(o)
+        with named_scope("magi_moe_gather"):
+            xs = h[tok]
+        with named_scope("magi_moe_matmul"):
+            a, b = grouped(xs, we_gate), grouped(xs, we_up)
+            o = grouped(jax.nn.silu(a) * b, we_down)
+        with named_scope("magi_moe_scatter"):
+            o = o.astype(jnp.float32) * w_flat[pairs][:, None]
+            return jnp.zeros((t, cfg.dim), jnp.float32).at[tok].add(o)
 
     def step(y, j):
-        lo = j * rows
-        pairs = jax.lax.dynamic_slice(order, (lo,), (rows,))
-        y = jax.lax.cond(
-            lo < n_here,
-            lambda y: y + chunk(h, w_flat, pairs, lo),
-            lambda y: y,
-            y,
-        )
-        return y, None
+        with named_scope("magi_moe_sort"):
+            lo = j * rows
+            pairs = jax.lax.dynamic_slice(order, (lo,), (rows,))
+            reached = lo < n_here
 
-    y = chunk(h, w_flat, order[:rows], 0)
+        def add_chunk(y):
+            o = chunk(h, w_flat, pairs, lo)
+            with named_scope("magi_moe_scatter"):
+                return y + o
+
+        return jax.lax.cond(reached, add_chunk, lambda y: y, y), None
+
+    with named_scope("magi_moe_sort"):
+        first = order[:rows]
+    y = chunk(h, w_flat, first, 0)
     n_chunks = order.shape[0] // rows
     if n_chunks > 1:
         y, _ = jax.lax.scan(
@@ -561,7 +573,8 @@ def _expert_ffn(h, layer: dict, cfg: PatternConfig):
         idx, w = route(h, layer, cfg)
     with named_scope("magi_moe_experts"):
         y, counts = held_expert_ffn(h, idx, w, layer, cfg)
-    y = y.astype(dt)
+        with named_scope("magi_moe_scatter"):
+            y = y.astype(dt)
     if cfg.n_shared_experts:
         with named_scope("magi_moe_shared"):
             y = y + _swiglu(
@@ -605,43 +618,54 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
     t = x.shape[0]
     eps = cfg.rms_eps
     kind = cfg.plan_kind(layer_type)
-    h = _rms_norm(x, layer["attn_norm"], eps)
-    if cfg.attn_form == LATENT:
-        q, k, v = _latent_qkv(h, pos, layer, cfg)
-    else:
-        q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
-        k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
-        v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
-    if cfg.qk_norm:
-        q = _rms_norm(q, layer["q_norm"], eps)
-        k = _rms_norm(k, layer["k_norm"], eps)
-    if cfg.attn_form == GQA and layer_type in cfg.rope_kinds:
-        q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
-        k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
+    # the attention half but for the attention call, which is a sibling:
+    # a flex kernel must never lie under magi_proj
+    with named_scope("magi_proj"):
+        h = _rms_norm(x, layer["attn_norm"], eps)
+        if cfg.attn_form == LATENT:
+            q, k, v = _latent_qkv(h, pos, layer, cfg)
+        else:
+            q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
+            k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
+            v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+        if cfg.qk_norm:
+            q = _rms_norm(q, layer["q_norm"], eps)
+            k = _rms_norm(k, layer["k_norm"], eps)
+        if cfg.attn_form == GQA and layer_type in cfg.rope_kinds:
+            q = _rope(q, pos, cfg.rope_theta, cfg.head_dim)
+            k = _rope(k, pos, cfg.rope_theta, cfg.head_dim)
     with named_scope("magi_attn_" + _SHORT[kind]):
         out, _, _ = dist_attn_local(
             q, k, v, tables[kind], plans[kind], attn_params[kind],
             axis_name=axis_name,
         )
-    out = out.reshape(t, -1)
-    if cfg.attn_gate:
-        out = out * jax.nn.sigmoid(h @ layer["w_attn_gate"].astype(dt))
-    with (named_scope("magi_mla_out") if cfg.attn_form == LATENT
-          else contextlib.nullcontext()):
-        out = out @ layer["wo"].astype(dt)
-    if cfg.post_norms:
-        out = _rms_norm(out, layer["post_attn_norm"], eps)
-    x = x + out
+    with named_scope("magi_proj"):
+        out = out.reshape(t, -1)
+        if cfg.attn_gate:
+            out = out * jax.nn.sigmoid(h @ layer["w_attn_gate"].astype(dt))
+        with (named_scope("magi_mla_out") if cfg.attn_form == LATENT
+              else contextlib.nullcontext()):
+            out = out @ layer["wo"].astype(dt)
+        if cfg.post_norms:
+            out = _rms_norm(out, layer["post_attn_norm"], eps)
+        x = x + out
 
-    h = _rms_norm(x, layer["mlp_norm"], eps)
+    # the FFN half; an expert layer's magi_moe_* scopes are siblings
+    # between its two magi_ffn blocks, so magi_ffn holds no expert
+    with named_scope("magi_ffn"):
+        h = _rms_norm(x, layer["mlp_norm"], eps)
     stats = {}
     if ffn_type == DENSE:
-        out = _swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"], dt)
+        with named_scope("magi_ffn"):
+            out = _swiglu(
+                h, layer["w_gate"], layer["w_up"], layer["w_down"], dt
+            )
     else:
         out, stats = _expert_ffn(h, layer, cfg)
-    if cfg.post_norms:
-        out = _rms_norm(out, layer["post_mlp_norm"], eps)
-    return x + out, stats
+    with named_scope("magi_ffn"):
+        if cfg.post_norms:
+            out = _rms_norm(out, layer["post_mlp_norm"], eps)
+        return x + out, stats
 
 
 def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
@@ -658,9 +682,10 @@ def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
 
 def _embed(params, tokens, cfg: PatternConfig):
     dt = cfg.jnp_dtype
-    x = params["embed"].astype(dt)[tokens]
-    if cfg.embed_scale != 1.0:
-        x = x * jnp.asarray(cfg.embed_scale, dt)
+    with named_scope("magi_embed"):
+        x = params["embed"].astype(dt)[tokens]
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, dt)
     return x
 
 
@@ -669,7 +694,8 @@ def _logits(x, params, cfg: PatternConfig):
 
 
 def _head(x, norm, params, cfg: PatternConfig):
-    return _logits(_rms_norm(x, norm, cfg.rms_eps), params, cfg)
+    with named_scope("magi_head"):
+        return _logits(_rms_norm(x, norm, cfg.rms_eps), params, cfg)
 
 
 def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
@@ -708,7 +734,8 @@ def _looped_trunk_local(params, tokens, pos, cfg: PatternConfig, tables,
     def one_pass(x, _):
         for fn, layer in zip(one_layer, params["layers"]):
             x, _stats = fn(x, pos, layer)
-        x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
+        with named_scope("magi_head"):  # the head's norm, inside the loop
+            x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
         return x, x
 
     with named_scope("magi_loop"):
@@ -797,9 +824,11 @@ def _mtp_local(params, x, next_tokens, pos, cfg: PatternConfig, tables,
     dt = cfg.jnp_dtype
     logits, stats = [], []
     for mod, nxt in zip(params["mtp"], next_tokens):
-        e = _rms_norm(_embed(params, nxt, cfg), mod["embed_norm"], cfg.rms_eps)
-        h = _rms_norm(x, mod["hidden_norm"], cfg.rms_eps)
-        x = jnp.concatenate([e, h], axis=-1) @ mod["eh_proj"].astype(dt)
+        e = _embed(params, nxt, cfg)
+        with named_scope("magi_embed"):  # the rest of the module's input
+            e = _rms_norm(e, mod["embed_norm"], cfg.rms_eps)
+            h = _rms_norm(x, mod["hidden_norm"], cfg.rms_eps)
+            x = jnp.concatenate([e, h], axis=-1) @ mod["eh_proj"].astype(dt)
         x, s = _one_layer(
             cfg, cfg.layer_types[-1], cfg.ffn_types[-1], tables, plans,
             attn_params, axis_name,
@@ -848,6 +877,8 @@ class MagiPattern:
             if with_stats and EXPERTS in cfg.ffn_types else {}
         )
         mtp_labels = self._mtp_labels(tokens, labels)
+        # the part the loss's own sums belong to (docs/observability.md)
+        head_part = "magi_exit_head" if cfg.n_loops > 1 else "magi_head"
 
         @functools.partial(
             shard_map,
@@ -872,15 +903,18 @@ class MagiPattern:
                     with named_scope("magi_mtp"):
                         # module j is fed token i + j + 1: the label, then
                         # the module before's target
-                        fed = [
-                            jnp.maximum(t, 0) for t in (lab1, *mtp_lab1[:-1])
-                        ]
+                        with named_scope("magi_embed"):
+                            fed = [
+                                jnp.maximum(t, 0)
+                                for t in (lab1, *mtp_lab1[:-1])
+                            ]
                         more, routed = _mtp_local(params, x, fed, pos1, *run)
                     logits, stats = logits + more, stats + routed
                 stats = _stacked(stats)
-                sums = [masked_ce_sums(logits[0], lab1)]
+                with named_scope("magi_head"):
+                    sums = [masked_ce_sums(logits[0], lab1)]
                 if cfg.n_mtp:
-                    with named_scope("magi_mtp"):
+                    with named_scope("magi_mtp"), named_scope("magi_head"):
                         sums += [
                             masked_ce_sums(lg, t)
                             for lg, t in zip(logits[1:], mtp_lab1)
@@ -889,17 +923,19 @@ class MagiPattern:
 
             # a loop, not vmap: a batched lax.cond would run both branches
             outs = [one(*b) for b in zip(tok, lab, pos, *mtp_lab)]
-            sums, stats = jax.tree.map(lambda *a: jnp.stack(a), *outs)
-            with named_scope("magi_pattern_loss_psum"):
-                sums = [
-                    tuple(
-                        jax.lax.psum(
-                            jax.lax.psum(v.sum(), self.cp_axis), self.dp_axis
+            with named_scope(head_part):
+                sums, stats = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+                with named_scope("magi_pattern_loss_psum"):
+                    sums = [
+                        tuple(
+                            jax.lax.psum(
+                                jax.lax.psum(v.sum(), self.cp_axis),
+                                self.dp_axis,
+                            )
+                            for v in pair
                         )
-                        for v in pair
-                    )
-                    for pair in sums
-                ]
+                        for pair in sums
+                    ]
             if stats_specs:
                 with named_scope("magi_pattern_stats_psum"):
                     counts = jax.lax.psum(
@@ -914,12 +950,13 @@ class MagiPattern:
                 }
             else:
                 stats = {}
-            (loss_sum, count), *mtp_sums = sums
-            loss = loss_sum / jnp.maximum(count, 1.0)
-            for loss_sum, count in mtp_sums:
-                loss = loss + cfg.mtp_loss_weight * (
-                    loss_sum / jnp.maximum(count, 1.0)
-                )
+            with named_scope(head_part):
+                (loss_sum, count), *mtp_sums = sums
+                loss = loss_sum / jnp.maximum(count, 1.0)
+                for loss_sum, count in mtp_sums:
+                    loss = loss + cfg.mtp_loss_weight * (
+                        loss_sum / jnp.maximum(count, 1.0)
+                    )
             return loss, stats
 
         loss, stats = _local(params, tokens, labels, pos, mtp_labels, tables)
@@ -935,7 +972,7 @@ class MagiPattern:
             return ()
         if self.dispatch_meta is None:
             raise ValueError("MTP modules roll the tokens: pass dispatch_meta")
-        with named_scope("magi_mtp"):
+        with named_scope("magi_mtp"), named_scope("magi_embed"):
             return tuple(
                 jnp.where(
                     labels >= 0,

@@ -38,6 +38,7 @@ from .block_meta import (
 )
 from .block_sparse import clamped_entry, row_tables
 from ..utils.compat import tpu_compiler_params
+from ..utils.instrument import named_scope
 
 NEG_INF = float("-inf")
 LANES = 128
@@ -183,7 +184,8 @@ def _row_tables(major, num_major: int):
     (``block_sparse.row_tables``, the shared enumeration primitive; the
     decode kernel derives the same tables from its block table)."""
     maj = major if not isinstance(major, np.ndarray) else jnp.asarray(major)
-    return row_tables(maj, num_major)
+    with named_scope("magi_layout"):  # round the kernel, not of it
+        return row_tables(maj, num_major)
 
 
 # the shared clamped lookup (``block_sparse.clamped_entry``): kernel
@@ -1262,31 +1264,33 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
     # differentiable with stage-local lse (the per-stage vjp then equals the
     # reference's global-lse backward exactly). rowmax stays non-diff.
     dout, dlse_lanes, _dmax = grads
-    do = dout.astype(q.dtype)
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    # lse consumers read lane 0; sum lanes to collect the full cotangent
-    dlse = dlse_lanes.astype(jnp.float32).sum(axis=-1)
-    delta_eff = delta - dlse
-    delta_lanes = jnp.broadcast_to(delta_eff[:, :, None], lse_lanes.shape)
+    with named_scope("magi_layout"):
+        do = dout.astype(q.dtype)
+    with named_scope("magi_bwd_delta"):
+        delta = jnp.sum(
+            dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+        )
+        # lse consumers read lane 0; sum lanes to collect the full cotangent
+        dlse = dlse_lanes.astype(jnp.float32).sum(axis=-1)
+        delta_eff = delta - dlse
+        delta_lanes = jnp.broadcast_to(delta_eff[:, :, None], lse_lanes.shape)
     dq = _dq_pallas(q, k, v, do, lse_lanes, delta_lanes, ftab, params)
     dk, dv = _dkv_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
-    if params.has_sink:
-        # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
-        lse = lse_lanes[:, :, 0]
-        sink = sink2d[:, :1]
-        w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
-        dsink = -(w * delta_eff).sum(axis=1, keepdims=True)
-        dsink2d = jnp.broadcast_to(dsink, sink2d.shape).astype(sink2d.dtype)
-    else:
-        dsink2d = jnp.zeros_like(sink2d)
-    return (
-        dq.astype(q.dtype),
-        dk.astype(k.dtype),
-        dv.astype(v.dtype),
-        dsink2d,
-        _zero_tangents(ftab),
-        _zero_tangents(btab),
-    )
+    with named_scope("magi_bwd_delta"):
+        if params.has_sink:
+            # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
+            lse = lse_lanes[:, :, 0]
+            sink = sink2d[:, :1]
+            w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
+            dsink = -(w * delta_eff).sum(axis=1, keepdims=True)
+            dsink2d = jnp.broadcast_to(dsink, sink2d.shape).astype(
+                sink2d.dtype
+            )
+        else:
+            dsink2d = jnp.zeros_like(sink2d)
+    with named_scope("magi_layout"):
+        dq, dk, dv = dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq, dk, dv, dsink2d, _zero_tangents(ftab), _zero_tangents(btab)
 
 
 _flex_attn_core.defvjp(_flex_attn_core_fwd, _flex_attn_core_bwd)
@@ -1503,10 +1507,11 @@ def flex_attn_headmajor(
     from .. import env
 
     hq = q.shape[0]
-    if sink is not None:
-        sink2d = sink.astype(jnp.float32).reshape(hq, 1)
-    else:
-        sink2d = jnp.zeros((hq, 1), jnp.float32)
+    with named_scope("magi_layout"):
+        if sink is not None:
+            sink2d = sink.astype(jnp.float32).reshape(hq, 1)
+        else:
+            sink2d = jnp.zeros((hq, 1), jnp.float32)
     if env.kernel_backend() == "jnp":
         return _fwd_jnp(q, k, v, sink2d, tuple(ftab), params)
     if env.kernel_backend() == "jnp_online":
@@ -1553,9 +1558,10 @@ def flex_attn_with_meta(
 
     tqp = meta.num_q_blocks * meta.block_q
     tkp = meta.num_k_blocks * meta.block_k
-    qh = _pad_tokens(jnp.transpose(q, (1, 0, 2)), tqp, 1)
-    kh = _pad_tokens(jnp.transpose(k, (1, 0, 2)), tkp, 1)
-    vh = _pad_tokens(jnp.transpose(v, (1, 0, 2)), tkp, 1)
+    with named_scope("magi_layout"):
+        qh = _pad_tokens(jnp.transpose(q, (1, 0, 2)), tqp, 1)
+        kh = _pad_tokens(jnp.transpose(k, (1, 0, 2)), tkp, 1)
+        vh = _pad_tokens(jnp.transpose(v, (1, 0, 2)), tkp, 1)
 
     params = FlexAttnParams(
         block_q=meta.block_q,
@@ -1573,11 +1579,12 @@ def flex_attn_with_meta(
     out_h, lse_lanes, rowmax_lanes = flex_attn_headmajor(
         qh, kh, vh, fwd_tables(meta), bwd_tables(meta), params, sink=sink
     )
-    out = jnp.transpose(out_h, (1, 0, 2))[:tq]
-    lse = jnp.transpose(lse_lanes[:, :, 0], (1, 0))[:tq]
-    if return_max_logits:
-        max_logits = jnp.max(rowmax_lanes[:, :, 0], axis=1)
-        return out, lse, max_logits
+    with named_scope("magi_layout"):
+        out = jnp.transpose(out_h, (1, 0, 2))[:tq]
+        lse = jnp.transpose(lse_lanes[:, :, 0], (1, 0))[:tq]
+        if return_max_logits:
+            max_logits = jnp.max(rowmax_lanes[:, :, 0], axis=1)
+            return out, lse, max_logits
     return out, lse
 
 

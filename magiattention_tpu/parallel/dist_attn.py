@@ -65,6 +65,7 @@ from ..ops.block_meta import (
 )
 from ..ops.correction import correct_attn_out_lse
 from ..ops.flex_attn import FlexAttnParams, flex_attn_headmajor
+from ..utils.instrument import named_scope
 
 
 def _round_up(a: int, b: int) -> int:
@@ -968,10 +969,11 @@ def ensure_kernel_steps(params: FlexAttnParams, tables) -> FlexAttnParams:
 
 
 def _call_kernel(qh, k_buf, v_buf, tab_arrays, kv_pad, params, sink):
-    kh = _hm(k_buf, kv_pad)
-    vh = _hm(v_buf, kv_pad)
-    ftab = tuple(a[0] for a in tab_arrays[:4]) + (tab_arrays[8][0],)
-    btab = tuple(a[0] for a in tab_arrays[4:8]) + (tab_arrays[8][0],)
+    with named_scope("magi_layout"):
+        kh = _hm(k_buf, kv_pad)
+        vh = _hm(v_buf, kv_pad)
+        ftab = tuple(a[0] for a in tab_arrays[:4]) + (tab_arrays[8][0],)
+        btab = tuple(a[0] for a in tab_arrays[4:8]) + (tab_arrays[8][0],)
     return flex_attn_headmajor(qh, kh, vh, ftab, btab, params, sink=sink)
 
 
@@ -1049,12 +1051,18 @@ def dist_attn_local(
         (plan.merged_tables, plan.host_tables,
          *(sp.tables for sp in plan.stages)),
     )
-    qh = _hm(q, plan.shard_q_pad)
-    kv = jnp.stack([k, v], axis=1)  # one all_to_all payload for K and V
-    if env.is_backward_high_precision_reduce():
-        # fp32 payload -> the transposed dKV reduce accumulates in fp32
-        # (2x comm; reference BACKWARD_HIGH_PRECISION_REDUCE)
-        kv = kv.astype(jnp.float32)
+    # named scopes (utils/instrument.py): every operation of the call lies
+    # under exactly one part scope that a metric reads (docs/observability.md,
+    # "Device scopes"): the flex kernels by their own names, the casts,
+    # reduces and merges magi_*_cast / magi_group_* / magi_*_lse_merge, and
+    # all the rest of what runs round the kernels magi_layout
+    with named_scope("magi_layout"):
+        qh = _hm(q, plan.shard_q_pad)
+        kv = jnp.stack([k, v], axis=1)  # one all_to_all payload for K and V
+        if env.is_backward_high_precision_reduce():
+            # fp32 payload -> the transposed dKV reduce accumulates in fp32
+            # (2x comm; reference BACKWARD_HIGH_PRECISION_REDUCE)
+            kv = kv.astype(jnp.float32)
     cur = 0
 
     def take(n):
@@ -1089,27 +1097,25 @@ def dist_attn_local(
         # dist_attn.py:532 + :3168 all_reduce MAX — Muon QK-Clip support)
         return jnp.max(rowmax_lanes[:, :, 0], axis=1)
 
-    # named scopes (utils/instrument.py): every cast / kernel / merge of
-    # the overlap pipeline carries a magi_* label into the XLA metadata,
-    # so jax.profiler device traces show which stage each op belongs to
-    from ..utils.instrument import named_scope
-
     if plan.overlap_degree == 0:
         tab = take(9)
         with named_scope("magi_merged_cast"):
             recv = cast_kv(plan.merged_comm)
-        k_full = jnp.concatenate([k, recv[:, 0]], axis=0)
-        v_full = jnp.concatenate([v, recv[:, 1]], axis=0)
+        with named_scope("magi_layout"):
+            k_full = jnp.concatenate([k, recv[:, 0]], axis=0)
+            v_full = jnp.concatenate([v, recv[:, 1]], axis=0)
         with named_scope("magi_merged_kernel"):
             out_h, lse_lanes, rowmax_lanes = _call_kernel(
                 qh, k_full, v_full, tab, plan.merged_tables.kv_pad, params,
                 sink,
             )
-        out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
+        with named_scope("magi_layout"):
+            out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
         out, lse = _resilient(
             out, lse, "merged", 0, rowmax=rowmax_lanes[:, :, 0]
         )
-        res = (out, lse, _head_max(rowmax_lanes))
+        with named_scope("magi_layout"):
+            res = (out, lse, _head_max(rowmax_lanes))
         if with_guard_code:
             res = res + (code,)
         if with_census:
@@ -1133,11 +1139,13 @@ def dist_attn_local(
         out_h, lse_lanes, rowmax_lanes = _call_kernel(
             qh, k, v, host_tab, plan.host_tables.kv_pad, host_params, sink
         )
-    out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
+    with named_scope("magi_layout"):
+        out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
     out, lse = _resilient(
         out, lse, "host", 0, rowmax=rowmax_lanes[:, :, 0]
     )
-    mx = _head_max(rowmax_lanes)
+    with named_scope("magi_layout"):
+        mx = _head_max(rowmax_lanes)
 
     stage_params = dataclasses.replace(
         params, has_sink=False, out_dtype=acc_dtype
@@ -1151,14 +1159,19 @@ def dist_attn_local(
                 qh, recv[:, 0], recv[:, 1], tab, sp.tables.kv_pad,
                 stage_params, None,
             )
-        out_i, lse_i = _headmajor_to_seq(out_i_h, lse_i_lanes, plan.shard_q_len)
+        with named_scope("magi_layout"):
+            out_i, lse_i = _headmajor_to_seq(
+                out_i_h, lse_i_lanes, plan.shard_q_len
+            )
         out_i, lse_i = _resilient(
             out_i, lse_i, f"stage{i}", 1 + i, rowmax=rowmax_i[:, :, 0]
         )
         with named_scope(f"magi_stage{i}_lse_merge"):
             out, lse = correct_attn_out_lse(out, lse, out_i, lse_i)
-        mx = jnp.maximum(mx, _head_max(rowmax_i))
-    out = out.astype(params.out_jnp_dtype)
+        with named_scope("magi_layout"):
+            mx = jnp.maximum(mx, _head_max(rowmax_i))
+    with named_scope("magi_layout"):
+        out = out.astype(params.out_jnp_dtype)
     res = (out, lse, mx)
     if with_guard_code:
         res = res + (code,)
@@ -1281,6 +1294,7 @@ def make_dist_attn_fn(
         if not with_max_logits:
             return res[0], res[1]
         out, lse, mxs = res
-        return out, lse, jnp.max(mxs, axis=0)
+        with named_scope("magi_layout"):
+            return out, lse, jnp.max(mxs, axis=0)
 
     return fn

@@ -127,6 +127,7 @@ from .compile import (  # noqa: F401
     current_program,
     decode_program_label,
     get_compile_tracker,
+    key_cache_on_metadata,
     prefill_program_label,
     program,
     reset_compile_tracker,
@@ -235,9 +236,13 @@ def enabled() -> bool:
 def set_enabled(value: bool | None) -> None:
     """Force telemetry on/off (``True``/``False``) or restore env-flag
     control (``None``). Benches and tests use this; long-running jobs
-    usually just set the env var."""
+    usually just set the env var. While it is on, the persistent
+    compilation cache's key holds the program's metadata, so that a
+    traced run sees the scopes of the code it runs
+    (:func:`compile.key_cache_on_metadata`)."""
     global _enabled_override
     _enabled_override = value
+    key_cache_on_metadata(enabled())
 
 
 def snapshot() -> dict:

@@ -423,21 +423,51 @@ def _on_phase_end(event: str, start: float, end: float, **kw) -> None:
         pass
 
 
+_KEY_METADATA = "jax_compilation_cache_include_metadata_in_key"
+# jax's own value of that option while telemetry holds it on, else None
+_key_metadata_was: bool | None = None
+
+
+def key_cache_on_metadata(on: bool) -> None:
+    """While telemetry is on, the persistent compilation cache keys a
+    program on its debug info too (named scopes, source lines); when it
+    goes off, jax's own setting is back. jax strips the debug info from
+    the key by default, and a ``named_scope`` lives only there: a run
+    that reads scopes from a device trace or from ``exe.as_text()`` could
+    otherwise load an executable that older code compiled, with that
+    code's scopes, and read nothing, with no error. The cost: such a run
+    shares no cache entry with a run that has telemetry off, nor with
+    code whose lines moved (docs/observability.md, "Device scopes")."""
+    global _key_metadata_was
+    import jax
+
+    if on and _key_metadata_was is None:
+        _key_metadata_was = bool(getattr(jax.config, _KEY_METADATA))
+        jax.config.update(_KEY_METADATA, True)
+    elif not on and _key_metadata_was is not None:
+        jax.config.update(_KEY_METADATA, _key_metadata_was)
+        _key_metadata_was = None
+
+
 def get_compile_tracker() -> CompileTracker:
     """The process-wide tracker; first call installs the monitoring
     listeners (via the compat shim — "monitoring", or "none" when the
     hook is missing; the tracker still works for directly-planted
-    events either way)."""
+    events either way) and, where telemetry is on by the env flag alone,
+    keys the persistent cache on metadata (:func:`key_cache_on_metadata`;
+    ``telemetry.set_enabled`` does it for the programmatic switch)."""
     global _tracker
     if _tracker is None:
         with _tracker_lock:
             if _tracker is None:
                 tracker = CompileTracker()
+                from . import enabled
                 from ..utils.compat import register_compile_listeners
 
                 tracker.ingestion = register_compile_listeners(
                     _on_event, _on_duration, _on_phase_start, _on_phase_end
                 )
+                key_cache_on_metadata(enabled())
                 _tracker = tracker
     return _tracker
 

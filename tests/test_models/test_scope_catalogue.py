@@ -1,0 +1,201 @@
+"""Every operation the device runs lies under exactly one *part* scope
+that a per-layer metric reads (``docs/observability.md``, "Device
+scopes"): the four toy models' train steps and the bare attention call
+are compiled here, on the CPU, their ``op_name``s read as the benchmark
+reads them (``trace_reduce.hlo_scopes``), and held against the patterns
+of the metric files themselves, so a scope that is renamed, dropped or
+wrapped round another part fails here and not as a silent zero on the
+chip."""
+
+import collections
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from benchmarks import trace_reduce
+from magiattention_tpu import api
+from magiattention_tpu.models import LlamaConfig, build_magi_llama, init_params
+from magiattention_tpu.models.pattern import (
+    build_magi_pattern, init_pattern_params,
+)
+from tests.test_models import test_pattern as toy
+from tests.test_models.test_pattern_looped import _ouro
+
+METRICS = os.path.join(
+    os.path.dirname(__file__), "..", "..", "benchmarks", "metrics"
+)
+
+
+def _pattern(metric: str) -> re.Pattern:
+    with open(os.path.join(METRICS, metric + ".json")) as f:
+        return re.compile(json.load(f)["source"]["pattern"])
+
+
+# a part, by the metric that reads it
+PARTS = {
+    part: _pattern(metric)
+    for part, metric in {
+        "embed": "train_embed_share",
+        "proj": "train_proj_share",
+        "layout": "train_attn_layout_share",
+        "flex": "train_flex_kernel_share",
+        "cast": "attn_cast_fwdbwd_ms",
+        "ffn": "train_ffn_share",
+        "moe": "train_moe_share",
+        "head": "train_head_share",
+        "exit_head": "train_exit_head_share",
+        "optimizer": "train_optimizer_share",
+    }.items()
+}
+EXPERT_PARTS = {
+    part: _pattern(f"train_moe_{part}_share")
+    for part in ("sort", "gather", "matmul", "scatter")
+}
+REMAINDERS = {
+    "step": _pattern("train_unscoped_share"),
+    "attn": _pattern("attn_unscoped_fwdbwd_ms"),
+}
+# the operations that cost: jax's primitive is the last word of op_name
+HEAVY = re.compile(
+    r"/(dot_general|ragged_dot\w*|sort|gather|scatter(-add)?|pallas_call"
+    r"|custom_call)$"
+)
+
+ATTN_CALL = ["magi_layout", "magi_flex_fwd_kernel"]
+ATTN_BWD = ["magi_bwd_delta", "magi_flex_dq_kernel", "magi_flex_dkv_kernel"]
+STEP = [
+    "magi_embed", "magi_proj", "magi_ffn", "magi_optimizer",
+    "rematted_computation", *ATTN_CALL, *ATTN_BWD,
+]
+EXPERTS = [
+    "magi_moe_router", "magi_moe_experts", "magi_moe_shared", "magi_moe_sort",
+    "magi_moe_gather", "magi_moe_matmul", "magi_moe_scatter",
+]
+CASES = {
+    "llama": STEP + ["magi_head"],
+    "afmoe": STEP + EXPERTS + [
+        "magi_head", "magi_attn_sliding", "magi_attn_full",
+    ],
+    "latent+mtp": STEP + EXPERTS + [
+        "magi_head", "magi_attn_full", "magi_mla_q", "magi_mla_kv",
+        "magi_mla_out", "magi_mtp",
+        # the module's own operations take the part they are an instance of
+        r"magi_mtp\S*magi_embed", r"magi_mtp\S*magi_head",
+        r"magi_proj\S*magi_mla_q", r"magi_proj\S*magi_mla_out",
+    ],
+    "looped": STEP + [
+        "magi_loop", "magi_exit_head", "magi_attn_full",
+        r"magi_loop\S*magi_head\b",  # the final norm inside the loop
+    ],
+    "attn-fwd-cp1": ATTN_CALL,
+    "attn-fwdbwd-cp1": ATTN_CALL + ATTN_BWD,
+    "attn-fwd-cp2": ATTN_CALL + [r"magi_merged_cast\S*magi_group_cast"],
+    "attn-fwdbwd-cp2": ATTN_CALL + ATTN_BWD + [
+        r"transpose\(jvp\S*magi_merged_cast\S*magi_group_cast",
+    ],
+}
+
+
+def _step_text(name: str) -> str:
+    mesh = toy._mesh(1)
+    if name == "llama":
+        cfg = LlamaConfig(
+            vocab_size=64, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+            head_dim=16, ffn_hidden=96, dtype="float32", remat=True,
+        )
+        qr, kr, ts = api.infer_varlen_mask_from_batch(toy.DOCS)
+        model, _ = build_magi_llama(
+            cfg, mesh, toy.TOTAL, qr, kr, ts, chunk_size=toy.CHUNK
+        )
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    else:
+        cfg = {
+            "afmoe": toy.CFG, "latent+mtp": toy._glm(1)[1],
+            "looped": _ouro()[1],
+        }[name]
+        model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
+        params = init_pattern_params(jax.random.PRNGKey(0), cfg)
+    opt = optax.adamw(1e-3)
+    batch = jnp.zeros((1, toy.TOTAL), jnp.int32)
+    return (
+        model.make_train_step(opt)
+        .lower(params, opt.init(params), batch, batch, batch)
+        .compile()
+        .as_text()
+    )
+
+
+def _attn_text(name: str) -> str:
+    _attn, phase, cp = name.split("-")
+    cp = int(cp[2:])
+    total, hq, hk, d = 1024, 4, 2, 64
+    mesh = Mesh(np.array(jax.devices()[:cp]), ("cp",))
+    key = api.magi_attn_varlen_key(
+        [0, 300, 700, total], total, mesh, num_heads=(hq, hk), head_dim=d,
+        chunk_size=128, out_dtype="float32",
+    )
+    sharded = NamedSharding(mesh, P("cp"))
+    q = jax.device_put(jnp.ones((total, hq, d), jnp.float32), sharded)
+    k = jax.device_put(jnp.ones((total, hk, d), jnp.float32), sharded)
+    d_lse = jax.device_put(jnp.ones((total, hq), jnp.float32), sharded)
+
+    def fwd(q, k, v):
+        out, meta = api.calc_attn(q, k, v, key)
+        return out, meta.lse
+
+    def fwdbwd(q, k, v, d_out, d_lse):
+        _res, vjp = jax.vjp(fwd, q, k, v)
+        return vjp((d_out, d_lse))
+
+    if phase == "fwd":
+        return jax.jit(fwd).lower(q, k, k).compile().as_text()
+    return jax.jit(fwdbwd).lower(q, k, k, q, d_lse).compile().as_text()
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_every_heavy_operation_lies_under_exactly_one_part(case):
+    with jax.enable_x64(False):
+        is_attn = case.startswith("attn")
+        text = _attn_text(case) if is_attn else _step_text(case)
+    scopes = trace_reduce.hlo_scopes(text)
+    # as the metrics see an operation: "<instruction name> <scope>"
+    lines = {f"{name} {scope}" for name, scope in scopes.items()}
+    heavy = sorted(s for s in lines if HEAVY.search(s))
+    assert len(heavy) >= 4, heavy
+    seen = collections.Counter()
+    for line in heavy:
+        parts = [p for p, rx in PARTS.items() if rx.search(line)]
+        assert len(parts) == 1, (line, parts)
+        seen[parts[0]] += 1
+        # and the remainder does not count it
+        assert not REMAINDERS["attn" if is_attn else "step"].search(line)
+        if "magi_moe_experts" in line:
+            inside = [p for p, rx in EXPERT_PARTS.items() if rx.search(line)]
+            assert len(inside) == 1, (line, inside)
+    # a flex kernel is a sibling of the projections, never inside them
+    assert not [
+        s for s in lines
+        if PARTS["flex"].search(s) and PARTS["proj"].search(s)
+    ]
+    # each scope of the vocabulary occurs where the model has the part
+    for scope in CASES[case]:
+        rx = re.compile(scope)
+        assert any(rx.search(s) for s in lines), scope
+    absent = {
+        "llama": ["magi_moe_", "magi_mla_", "magi_mtp", "magi_exit_head"],
+        "afmoe": ["magi_mla_", "magi_mtp", "magi_exit_head"],
+        "latent+mtp": ["magi_exit_head", "magi_attn_sliding"],
+        "looped": ["magi_moe_", "magi_mtp"],
+    }.get(case, ["magi_proj", "magi_ffn", "magi_head", "magi_optimizer"])
+    for scope in absent:
+        assert not any(scope in s for s in lines), scope
+    if not is_attn:
+        assert {"proj", "ffn", "flex", "layout", "embed"} <= set(seen), seen
+        assert ("exit_head" if case == "looped" else "head") in seen, seen

@@ -265,3 +265,82 @@ class TestRecompileStorm:
         )
         with pytest.raises(ValueError, match="RECOMPILE_STORM"):
             env.recompile_storm_threshold()
+
+
+class TestCacheKeyHoldsMetadataWhileTelemetryIsOn:
+    """A ``named_scope`` lives in a program's debug info, which jax
+    strips from the persistent cache's key by default: a traced run could
+    load an executable older code compiled and read that code's scopes.
+    While telemetry is on the key holds the metadata (ISSUE 34)."""
+
+    OPTION = "jax_compilation_cache_include_metadata_in_key"
+
+    @staticmethod
+    def _key(scope: str) -> str:
+        """The persistent cache's key of one function lowered under
+        ``scope`` (the same source lines whatever the scope is)."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from jax._src import cache_key, compiler
+
+        from magiattention_tpu.utils.instrument import named_scope
+
+        def f(x):
+            with named_scope(scope):
+                return x * 2 + 1
+
+        devices = np.array(jax.devices()[:1])
+        return cache_key.get(
+            jax.jit(f).lower(jnp.ones(4)).compiler_ir(),
+            devices,
+            compiler.get_compile_options(num_replicas=1, num_partitions=1),
+            devices[0].client,
+        )
+
+    def test_one_key_off_two_keys_on_and_none_restores(self):
+        import jax
+
+        def keys():  # one call site: the key then holds its line too
+            return [self._key(s) for s in ("magi_a", "magi_b", "magi_a")]
+
+        was = getattr(jax.config, self.OPTION)
+        assert len(set(keys())) == 1
+        telemetry.set_enabled(True)
+        assert getattr(jax.config, self.OPTION) is True
+        a, b, a_again = keys()
+        assert a == a_again != b
+        telemetry.set_enabled(None)
+        assert getattr(jax.config, self.OPTION) == was
+        assert len(set(keys())) == 1
+
+    @pytest.mark.parametrize("jaxs_own", [False, True])
+    def test_off_gives_back_what_jax_had(self, jaxs_own):
+        import jax
+
+        was = getattr(jax.config, self.OPTION)
+        jax.config.update(self.OPTION, jaxs_own)
+        try:
+            telemetry.set_enabled(True)
+            telemetry.set_enabled(True)  # twice on: jax's value is kept
+            assert getattr(jax.config, self.OPTION) is True
+            telemetry.set_enabled(False)
+            assert getattr(jax.config, self.OPTION) is jaxs_own
+        finally:
+            telemetry.set_enabled(None)
+            jax.config.update(self.OPTION, was)
+
+    def test_the_env_flag_alone_turns_it_on_where_the_listeners_install(
+        self, monkeypatch
+    ):
+        import jax
+
+        monkeypatch.setenv("MAGI_ATTENTION_TELEMETRY", "1")
+        monkeypatch.setattr(comp, "_tracker", None)
+        try:
+            comp.get_compile_tracker()
+            assert getattr(jax.config, self.OPTION) is True
+        finally:
+            monkeypatch.delenv("MAGI_ATTENTION_TELEMETRY")
+            telemetry.set_enabled(None)
+        assert getattr(jax.config, self.OPTION) is False
