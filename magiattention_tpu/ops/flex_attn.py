@@ -1637,8 +1637,16 @@ _AUTO_BLOCK_CONFIGS: tuple[tuple[int, int, int], ...] = (
     # Fitting is not fast: at group 1 a step's K and V tiles serve block_q
     # rows of ONE head, so a block_q of 128 reads a byte per 128 FLOPs where
     # the chip's balance is 240, and the kernels run at the HBM's pace
-    # (PERF.md section 6, PR 30); nothing here prices that yet.
+    # (PERF.md section 6, PR 30). The tuner prices those bytes
+    # (tuning/cost_model.step_bytes, ISSUE 35) and passes an HBM-bound rung
+    # over for one whose block_q clears the balance.
     (128, 512, 8),
+    # block_q 256 at eight heads a step, for GQA groups under 8 (at group 8
+    # the next rung is this one already: head_block snaps to the group). On
+    # the 16k packed mask at group 1 it beat (256, 512, 4) forward + dq + dkv
+    # by 7.7% at 16 q = 16 kv x 128 and, snapped to 5 heads, by 1.5% at
+    # 20 = 20 x 256; (512, 512, 4) lost to both (PERF.md section 6, PR 35)
+    (256, 512, 8),
     (256, 512, 4),
     (256, 1024, 2),
     # square long-seq rung: best measured dense blocking on the row-major

@@ -112,6 +112,8 @@ M_AUTOTUNE_BLOCK_K = "magi_autotune_block_k"
 M_AUTOTUNE_HEAD_BLOCK = "magi_autotune_head_block"
 M_AUTOTUNE_PREDICTED_MS = "magi_autotune_predicted_ms"
 M_AUTOTUNE_MEASURED_MS = "magi_autotune_measured_ms"
+# cost-model rung decisions, by what binds the chosen rung: {bound=mxu|hbm}
+M_AUTOTUNE_DECISIONS = "magi_autotune_decisions_total"
 # which rung the last decision chose and why: value 1, labels rung=/source=
 M_AUTOTUNE_CHOICE = "magi_autotune_choice"
 # flex pallas_calls built (trace time), by role, by the q heads one grid
@@ -1104,6 +1106,8 @@ def record_autotune_decision(decision) -> None:
     reg.clear_metric(M_AUTOTUNE_CHOICE)  # one live choice series at a time
     rung = f"{decision.block_q}x{decision.block_k}x{decision.head_block}"
     reg.gauge_set(M_AUTOTUNE_CHOICE, 1, rung=rung, source=decision.source)
+    if decision.bound:  # a flex rung the cost model priced
+        reg.counter_inc(M_AUTOTUNE_DECISIONS, bound=decision.bound)
     _marker_event(
         "autotune_decision",
         {
@@ -1115,6 +1119,10 @@ def record_autotune_decision(decision) -> None:
             "smem_entries": decision.smem_entries,
             "smem_count": decision.smem_count,
             "rejected_smem": decision.rejected_smem,
+            "mxu_seconds": decision.mxu_seconds,
+            "hbm_seconds": decision.hbm_seconds,
+            "bound": decision.bound,
+            "rejected_bytes": decision.rejected_bytes,
         },
     )
 

@@ -55,6 +55,15 @@ class TuningDecision:
     smem_entries: int = 0
     smem_count: str = ""
     rejected_smem: int = 0
+    # what the operand-bytes term read (flex decisions off the static
+    # table; ISSUE 35): the chosen rung's forward MXU time and the time its
+    # K and V stream takes at the priced share of the HBM's peak, which of
+    # the two binds (``mxu`` | ``hbm``) and how many rungs that were cheaper
+    # by tiles and steps alone the term passed over as HBM-bound
+    mxu_seconds: float = 0.0
+    hbm_seconds: float = 0.0
+    bound: str = ""
+    rejected_bytes: int = 0
 
     @property
     def config(self) -> tuple[int, int, int]:
@@ -63,6 +72,35 @@ class TuningDecision:
     @property
     def kernel_config(self) -> tuple[int, int, int, str]:
         return (self.block_q, self.block_k, self.head_block, self.grid)
+
+
+def _bytes_verdict(rec: TuningRecord) -> dict:
+    """The operand-bytes fields of a decision, from the ranking's
+    candidates as a record keeps them (``CandidateScore.as_dict``): the
+    same answer on a miss and on a hit."""
+    rung = ("block_q", "block_k", "head_block", "grid")
+    chosen = next(
+        (
+            c
+            for c in rec.candidates
+            if all(c.get(f) == getattr(rec, f) for f in rung)
+        ),
+        None,
+    )
+    if chosen is None or "bound" not in chosen:
+        return {}
+    return dict(
+        mxu_seconds=chosen["mxu_seconds"],
+        hbm_seconds=chosen["hbm_seconds"],
+        bound=chosen["bound"],
+        # cheaper by tiles and steps alone, the price before the bytes
+        rejected_bytes=sum(
+            c.get("feasible", True)
+            and c.get("bound") == "hbm"
+            and c["compute_seconds"] < chosen["compute_seconds"]
+            for c in rec.candidates
+        ),
+    )
 
 
 def _static_decision(q_ranges, k_ranges, hq: int, hk: int) -> TuningDecision:
@@ -205,6 +243,7 @@ def select_block_config(
             rejected_smem=sum(
                 not c.get("feasible", True) for c in rec.candidates
             ),
+            **_bytes_verdict(rec),
         )
         _record(decision)
         return decision
@@ -217,6 +256,7 @@ def select_block_config(
         hq,
         hk,
         head_dim=head_dim,
+        dtype=dtype,
         max_block_q=max_block_q,
         max_block_k=max_block_k,
         cp_size=cp_size,
@@ -231,7 +271,8 @@ def select_block_config(
         f"cost model: {best.block_q}x{best.block_k}x{best.head_block} "
         f"({best.grid}) ~{best.cost_seconds * 1e3:.2f} ms "
         f"(mxu {best.mxu_seconds * 1e3:.2f} + grid "
-        f"{best.step_seconds * 1e3:.2f}; {best.entries} entries, "
+        f"{best.step_seconds * 1e3:.2f} + bytes "
+        f"{best.hbm_excess_seconds * 1e3:.2f}; {best.entries} entries, "
         f"steps {best.steps})"
     )
     if mode == "measure" and measure_fn is not None:
@@ -308,6 +349,7 @@ def select_block_config(
         smem_entries=best.smem_entries,
         smem_count=best.smem_count,
         rejected_smem=sum(not s.feasible for s in scores),
+        **_bytes_verdict(rec),
     )
     _record(decision)
     return decision

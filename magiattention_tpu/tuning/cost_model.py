@@ -14,6 +14,11 @@ SHAPE, not just the total seqlen the old static table keyed on:
   99.9 TF/s on 64k causal with near-identical tile FLOPs), and clamped
   dead steps (rows shorter than the static ``steps`` extent) still cost a
   reduced per-step fee;
+- **operand bytes** — a step streams fresh tiles from HBM (K and V in the
+  q-major forward and dq, q, dO and the row statistics in the k-major
+  dkv), and a kernel takes the longer of its MXU and its HBM time: at GQA
+  group 1 a ``block_q`` of 128 reads a byte per 128 FLOPs where the
+  chip's balance is 240 (:func:`step_bytes`, :data:`HBM_PRICE_SHARE`);
 - **SMEM pressure** — the scalar-prefetch entry table must fit the ~1 MB
   scalar core budget (``flex_attn._MAX_SMEM_ENTRIES``), which rules small
   tiles out for huge dense masks.
@@ -30,6 +35,7 @@ per-rank tables over fragmented runs: :func:`smem_entries`.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import NamedTuple
 
 import numpy as np
@@ -114,6 +120,52 @@ def choose_grid(row_major_steps: int, compact_steps: int) -> str:
 # on-chip measurements are).
 TIE_TOLERANCE = 0.15
 
+# -- operand bytes (ISSUE 35) ------------------------------------------------
+# The share of the HBM's peak at which a streamed byte is priced, on the
+# scale on which ``mxu_seconds`` prices a FLOP at ``TpuPeakSpec.mfu`` (0.5)
+# of the MXU's. From the chip (v5e; the GLM cell's 16k packed mask at 20 q =
+# 20 kv heads of 256, compact grid; PR 30, PR 31 and PR 35's probe, PERF.md
+# sections 5 and 6): the HBM-bound forward of (128, 512, 5) takes 6.148 ms
+# for 1,600 steps of 2.62 MB, 5.12 ms at 819 GB/s: 0.83 of the peak. The
+# MXU-bound kernels of (256, 512, 4) take 4.451 / 5.509 / 6.925 ms (forward /
+# dq / dkv) for 2.943 / 4.415 / 5.887 ms of MXU time at 197 TFLOP/s: 0.78 of
+# that peak, 1.57 x what ``mfu`` prices. 0.83 / 1.57 = 0.53; and the value
+# at which the price ratio of the two rungs is the chip's forward + dq + dkv
+# ratio (1.17; 1.13 for the whole forward+backward call) is 0.53-0.57.
+HBM_PRICE_SHARE = 0.55
+# forward : dq : dkv FLOPs of one tile (2, 3 and 4 matmuls of the forward's
+# two); a price is forward-sized, so the three kernels' excess is averaged
+# over the sum
+KERNEL_FLOP_WEIGHTS = {"fwd": 1.0, "dq": 1.5, "dkv": 2.0}
+_STAT_LANES = 128  # lse and delta reach dkv replicated over a vreg's lanes
+
+
+def step_bytes(
+    kernel: str,
+    block_q: int,
+    block_k: int,
+    head_block: int,
+    group: int,
+    head_dim: int,
+    itemsize: int,
+) -> int:
+    """Bytes one live step of ``kernel`` (``fwd`` | ``dq`` | ``dkv``) loads
+    fresh from HBM. Forward and dq walk q-major: a step brings the K and V
+    tiles of the key-value heads its ``head_block`` query heads share (a
+    per-head step, ``head_block`` 1, brings one pair whatever the group).
+    dkv walks k-major: a step brings q, dO and the two float32
+    lane-replicated statistics (lse, delta) of its query heads.
+
+    Not counted: the tiles that stay while a block's entries run (q, dO
+    and the statistics in forward and dq, K and V in dkv) and the outputs.
+    Each is brought or written once a block, so over a call they are the
+    tensors' own size whatever the rung: they move no order between rungs."""
+    if kernel == "dkv":
+        return head_block * block_q * (
+            2 * head_dim * itemsize + 2 * _STAT_LANES * 4
+        )
+    return 2 * max(head_block // group, 1) * block_k * head_dim * itemsize
+
 # Sparse-only blockings: smaller tiles than any row-major rung carries.
 # On the row-major grid small tiles lose to grid-step overhead (the
 # static ``steps`` extent multiplies every row), but the sparse walk
@@ -170,10 +222,29 @@ class CandidateScore:
     live_slots: int = 0  # grid_rows * entries (slots that compute)
     dead_slots: int = 0  # clamped slots past a row's entry count
     smem_count: str = "bound"  # which count ``smem_entries`` is: exact | bound
+    # the forward's K and V stream at the priced share of the HBM's peak:
+    # the time ``mxu_seconds`` is held against (:attr:`bound`)
+    hbm_seconds: float = 0.0
+    # what forward, dq and dkv take beyond their MXU time where the HBM's is
+    # the longer, forward-sized (0.0 wherever the bytes are slack)
+    hbm_excess_seconds: float = 0.0
+
+    @property
+    def bound(self) -> str:
+        """``hbm`` where the rung's own price says its forward steps run
+        at the HBM's pace, else ``mxu``."""
+        return "hbm" if self.hbm_seconds > self.mxu_seconds else "mxu"
+
+    @property
+    def compute_seconds(self) -> float:
+        """The price before ISSUE 35: tiles and steps, no bytes."""
+        return self.mxu_seconds + self.step_seconds
 
     @property
     def cost_seconds(self) -> float:
-        return self.mxu_seconds + self.step_seconds
+        # a kernel's time is the larger of its MXU and its HBM time:
+        # mxu + max(hbm - mxu, 0), summed over the kernels
+        return self.compute_seconds + self.hbm_excess_seconds
 
     @property
     def grid_slots(self) -> int:
@@ -184,7 +255,9 @@ class CandidateScore:
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
         d["cost_seconds"] = self.cost_seconds
+        d["compute_seconds"] = self.compute_seconds
         d["grid_slots"] = self.grid_slots
+        d["bound"] = self.bound
         return d
 
 
@@ -464,11 +537,13 @@ def rank_candidates(
     hk: int,
     *,
     head_dim: int = 128,
+    dtype: str = "bfloat16",
     generation: str | None = None,
     max_block_q: int | None = None,
     max_block_k: int | None = None,
     cp_size: int = 1,
     include_sparse: bool = True,
+    rungs=None,
 ) -> list[CandidateScore]:
     """Score every candidate rung for the workload, best first.
 
@@ -483,11 +558,25 @@ def rank_candidates(
     row-major-only ranking — the distributed plan builder's contract
     (its kernels run the row-major grid).
 
+    On top of tiles and steps a candidate pays for the bytes its live steps
+    stream from HBM (:func:`step_bytes`, ``dtype``'s width), as a roofline
+    does: each of forward, dq and dkv takes the larger of its MXU and its
+    HBM time, so the term is 0.0 wherever the bytes are slack (every rung
+    at GQA group >= 4 or ``block_q`` >= 512 a head) and the price there is
+    what it was.
+
     The returned order is cost-ascending EXCEPT that candidates within
     :data:`TIE_TOLERANCE` of the best are resolved by the measured
     preference order for the workload's extent — so dense workloads keep
     the on-chip-measured winners while shape-sensitive workloads (narrow
-    varlen blocks, SWA bands) escape to occupancy-correct rungs.
+    varlen blocks, SWA bands) escape to occupancy-correct rungs. A rung
+    whose own price says its steps run at the HBM's pace
+    (:attr:`CandidateScore.bound`) is no tie with one that does not: the
+    preference order was measured where no rung streams, and is not asked.
+
+    ``rungs``: the (block_q, block_k, head_block) table to rank in place of
+    the extent's preference order (a probe's rungs beside the chip's
+    readings of them).
 
     ``max_block_q``/``max_block_k`` drop rungs larger than the caller's
     shard geometry (distributed plans: a tile wider than the per-rank
@@ -510,11 +599,16 @@ def rank_candidates(
     gen = generation if generation is not None else env.tpu_generation()
     spec = TPU_PEAK_SPECS.get(gen) or TPU_PEAK_SPECS["v5e"]
     eff_flops = spec.bf16_tflops * 1e12 * spec.mfu
+    hbm_rate = spec.hbm_gbps * 1e9 * HBM_PRICE_SHARE
+    # ``dtype`` is the string of whatever the caller had (a dtype, its
+    # name, a scalar type's repr): its width is the number in it
+    bits = re.search(r"\d+", str(dtype))
+    itemsize = int(bits.group()) // 8 if bits else 2
     group = max(hq // max(hk, 1), 1)
 
     def score_one(bq: int, bk: int, hb_pref: int, grid: str):
         hb = _auto_head_block(hb_pref, hq, group)
-        entries, steps, nq = estimate_entries(q, k, t, bq, bk)
+        entries, steps, nq, bwd_entries = _counted_entries(q, k, t, bq, bk)
         smem = smem_entries(q, k, t, bq, bk, cp_size)
         grid_rows = max(hq // max(hb, 1), 1)
         live = grid_rows * entries
@@ -525,6 +619,17 @@ def rank_candidates(
             dead = max(grid_rows * nq * steps - live, 0)
             step_s = live * STEP_OVERHEAD_S + dead * DEAD_STEP_OVERHEAD_S
         mxu_s = 4.0 * head_dim * hq * entries * bq * bk / eff_flops
+        streamed = {
+            kern: grid_rows
+            * (bwd_entries if kern == "dkv" else entries)
+            * step_bytes(kern, bq, bk, hb, group, head_dim, itemsize)
+            / hbm_rate
+            for kern in KERNEL_FLOP_WEIGHTS
+        }
+        excess = sum(
+            max(streamed[kern] - w * mxu_s, 0.0)
+            for kern, w in KERNEL_FLOP_WEIGHTS.items()
+        ) / sum(KERNEL_FLOP_WEIGHTS.values())
         return CandidateScore(
             block_q=bq,
             block_k=bk,
@@ -539,6 +644,8 @@ def rank_candidates(
             live_slots=live,
             dead_slots=dead,
             smem_count=smem.count,
+            hbm_seconds=streamed["fwd"],
+            hbm_excess_seconds=excess,
         )
 
     scores: list[CandidateScore] = []
@@ -559,7 +666,7 @@ def rank_candidates(
         seen.add(key)
         scores.append(cand)
 
-    for bq, bk, hb_pref in _preference_order(extent):
+    for bq, bk, hb_pref in rungs or _preference_order(extent):
         # row-major FIRST: tied candidates resolve by generation order,
         # and inside the model's error bar the on-chip-measured
         # row-major rungs outrank the unmeasured sparse pricing
@@ -591,6 +698,11 @@ def rank_candidates(
     )
     tol = SPARSE_TIE_TOLERANCE if hetero else TIE_TOLERANCE
     tied = [s for s in feasible if s.cost_seconds <= best * (1.0 + tol)]
+    if any(s.bound == "mxu" for s in tied):
+        # a rung whose own price says it streams is no tie with one that
+        # does not: the preference order starts at (128, 512, 8) and was
+        # measured at GQA group 8, where nothing streams
+        tied = [s for s in tied if s.bound == "mxu"]
     if hetero and any(s.grid == "sparse" for s in tied):
         # heterogeneous regime: inside the model's error bar, minimize
         # grid steps on the sparse grid — the measured 8.44 TF/s

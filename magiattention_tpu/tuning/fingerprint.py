@@ -72,8 +72,11 @@ class WorkloadFingerprint:
 
     # v4 (ISSUE 33): at cp = 1 the SMEM test reads the exact entry count,
     # which admits small rungs for band masks; a (1024, 1024, 1) cached for
-    # a sliding window because nothing smaller was allowed is not served
-    FINGERPRINT_VERSION = 4
+    # a sliding window because nothing smaller was allowed is not served.
+    # v5 (ISSUE 35): the price holds the bytes a step streams from HBM; a
+    # (128, 512, hb) cached for a GQA group 1 mask when no bytes were priced
+    # is not served
+    FINGERPRINT_VERSION = 5
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)

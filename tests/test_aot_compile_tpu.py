@@ -186,13 +186,17 @@ def test_forward_state_at_the_cells_rungs(topo, rung, sink, softcap, grid):
 @pytest.mark.parametrize(
     "t,hq,hk,d,rung",
     [(65536, 64, 8, 128, (128, 512, 8)), (65536, 64, 8, 128, (1024, 1024, 1)),
-     (16384, 20, 20, 256, (128, 512, 5))],
-    ids=["packed-rung", "dense-rung", "latent-rung"],
+     (16384, 20, 20, 256, (128, 512, 5)), (16384, 20, 20, 256, (256, 512, 5)),
+     (16384, 16, 16, 128, (256, 512, 8))],
+    ids=["packed-rung", "dense-rung", "latent-rung-pr30", "latent-rung",
+         "looped-rung"],
 )
 def test_backward_block_at_the_cells_rungs(topo, t, hq, hk, d, rung, softcap, grid):
     """dq and dkv at the rungs the benchmark's cells run: head-batched
     (128, 512, 8) and per head (1024, 1024, 1) at 64 q / 8 kv heads of
-    width 128, head-batched (128, 512, 5) at 20 / 20 heads of width 256.
+    width 128, and at GQA group 1 head-batched (256, 512, 5) at 20 / 20
+    heads of width 256 and (256, 512, 8) at 16 / 16 of 128 (ISSUE 35; the
+    GLM cell's rung before it, (128, 512, 5), beside them).
     Since ISSUE 31 the one block all four backward bodies share takes lse
     and delta at the (rows, 128) shape their blocks arrive in and the
     (rows, block_k) tiles s and dP in static 128-lane slices, with the
@@ -230,32 +234,51 @@ def _tuners_rung(mask, hq, hk, d):
     )
 
 
+# GQA group 1 at the kernels: (query = key-value heads, head_dim, the rung
+# the tuner gives the 16k packed mask since ISSUE 35)
+_GROUP_ONE = {
+    "latent": (20, 256, (256, 512, 5)),  # GLM-4.7-Flash after up-projection
+    "looped": (16, 128, (256, 512, 8)),  # Ouro-2.6B
+}
+
+
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize(
-    "t,rung",
-    [(16384, None), (4096, None), (16384, (256, 1024, 2)),
-     (16384, (512, 768, 4)), (16384, (1024, 1024, 1))],
-    ids=["tuner-16k", "tuner-4k-check", "widest-row-major-step",
-         "widest-compact-step", "per-head-long-rung"],
+    "geometry,t,rung",
+    [("latent", 16384, None), ("latent", 4096, None),
+     ("latent", 16384, (128, 512, 5)), ("latent", 16384, (256, 512, 4)),
+     ("latent", 16384, (256, 1024, 2)), ("latent", 16384, (512, 768, 4)),
+     ("latent", 16384, (1024, 1024, 1)), ("latent", 16384, (512, 2048, 1)),
+     ("looped", 16384, None), ("looped", 4096, None),
+     ("looped", 16384, (128, 512, 8)), ("looped", 16384, (256, 512, 4)),
+     ("looped", 16384, (256, 1024, 2)), ("looped", 16384, (1024, 1024, 1))],
+    ids=["tuner-16k", "tuner-4k-check", "pr30-rung", "four-heads-a-step",
+         "widest-row-major-step", "widest-compact-step", "per-head-long-rung",
+         "escalation-rung", "looped-tuner-16k", "looped-tuner-4k-check",
+         "looped-pr32-rung", "looped-four-heads-a-step",
+         "looped-widest-row-major-step", "looped-per-head-long-rung"],
 )
-def test_latent_geometry_20_heads_of_256(topo, t, rung, grid):
-    """Forward, dq and dkv at what latent attention hands the kernels
-    after its up-projection (GLM-4.7-Flash: 20 query = 20 key-value
-    heads, GQA group 1, head_dim 256), on both grids: the rung the tuner
-    gives the cell's packed mask at the window's 16k and at the check's
-    4k, (128, 512, 8) snapped to 5 heads a step (no power of two, and at
-    group 1 five key-value heads' tiles a step), and the largest steps
-    the tuner's table holds for this geometry. K, V, dO and the
-    accumulators are twice head_dim 128's; they fit the VMEM the kernels
-    ask for, and the batched programs are the ones built."""
+def test_group_one_geometries(topo, geometry, t, rung, grid):
+    """Forward, dq and dkv at GQA group 1, where a step brings as many
+    key-value heads' tiles as it has query heads, on both grids: what
+    latent attention hands the kernels after its up-projection
+    (GLM-4.7-Flash: 20 query = 20 key-value heads of 256; no power of two,
+    so eight heads a step snap to five) and a plain MHA decoder (Ouro-2.6B:
+    16 = 16 of 128). The rung the tuner gives the cells' packed mask at the
+    window's 16k and at the check's 4k, (256, 512, 8) snapped to the
+    geometry since ISSUE 35 priced the bytes a step streams; the rung it
+    gave before; and every other step its table holds for the geometry, so
+    that whatever a mask makes it return compiles. At head_dim 256 K, V, dO
+    and the accumulators are twice head_dim 128's; they fit the VMEM the
+    kernels ask for, and the batched programs are the ones built."""
     from magiattention_tpu import telemetry
 
-    hq = hk = 20
-    d = 256
-    mask = _glm_cell_mask(t)
+    hq, d, tuned = _GROUP_ONE[geometry]
+    hk = hq
+    mask = _glm_cell_mask(t)  # the Ouro cell's mask too
     if rung is None:
         rung = _tuners_rung(mask, hq, hk, d)
-        assert rung == (128, 512, 5)
+        assert rung == tuned
     qr, kr = [list(r) for r in mask.q_ranges], [list(r) for r in mask.k_ranges]
     chip = SingleDeviceSharding(topo.devices[0])
     reg = telemetry.get_registry()
@@ -402,7 +425,7 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
     )
     (p,) = model.attn_params.values()
     assert (p.block_q, p.block_k, p.head_block, p.grid) == (
-        128, 512, 8, "sparse"
+        *_GROUP_ONE["looped"][2], "sparse"
     )
     opt = optax.adamw(3e-4)
     rep = NamedSharding(mesh, P())

@@ -23,7 +23,12 @@ from magiattention_tpu.tuning import (
     reset_tuning_cache,
     select_block_config,
 )
-from magiattention_tpu.tuning.cost_model import smem_entries
+from magiattention_tpu.tuning.cost_model import (
+    HBM_PRICE_SHARE,
+    smem_entries,
+    step_bytes,
+)
+from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -164,13 +169,20 @@ def test_sparse_rungs_have_zero_dead_slots():
 
 def test_heterogeneous_headline_resolves_to_sparse_grid():
     """The 16k varlen block-causal headline (the 8.44 TF/s regression)
-    must pick a sparse rung with >= 6x fewer grid slots than the best
-    row-major candidate, and dense 64k causal must NOT."""
+    must pick a sparse rung with >= 6x fewer grid slots than the row-major
+    (128, 512, 8) the 8.44 TF/s was measured on (at 8 q = 8 kv heads the
+    row-major ranking itself now leaves that rung: its steps stream K and V
+    at the HBM's pace, ISSUE 35), and dense 64k causal must NOT."""
     qr, kr, ts = _varlen_16k()
     best = rank_candidates(qr, kr, ts, 8, 8, generation="v5e")[0]
-    rm = rank_candidates(
-        qr, kr, ts, 8, 8, generation="v5e", include_sparse=False
-    )[0]
+    rm = next(
+        s
+        for s in rank_candidates(
+            qr, kr, ts, 8, 8, generation="v5e", include_sparse=False
+        )
+        if (s.block_q, s.block_k) == (128, 512)
+    )
+    assert rm.bound == "hbm"
     assert best.grid == "sparse"
     assert best.dead_slots == 0
     assert rm.grid_slots >= 6 * best.grid_slots
@@ -210,6 +222,8 @@ _MASK_SPECS = {
     "swa4096": {"type": "swa_causal", "window": 4096},
     "cp4_packed": "magi64x8-attn-cp4-256k-varlen",
     "cp4_causal": "magi64x8-attn-cp4-256k-causal",
+    "packed16k": "glm47flash-train-16k-packed",  # Mistral's and Ouro's too
+    "packed32k": "trinitymini-train-32k-packed",
 }
 # (exact entries, the old bounding-box bound) where the issue names them
 _PINNED = {
@@ -232,7 +246,9 @@ def _bench_mask(family: str):
     return m.q_ranges, m.k_ranges, m.types, total
 
 
-@pytest.mark.parametrize("bq,bk", [c[:2] for c in _AUTO_BLOCK_CONFIGS])
+@pytest.mark.parametrize(
+    "bq,bk", list(dict.fromkeys(c[:2] for c in _AUTO_BLOCK_CONFIGS))
+)
 @pytest.mark.parametrize(
     "family",
     ["packed", "causal", "chunk_causal", "swa256", "swa1024", "swa4096"],
@@ -373,7 +389,7 @@ def test_per_rank_tables_keep_the_bound_and_its_verdicts(
     qr, kr, ts, total = _bench_mask(family)
     assert tuple(
         smem_entries(qr, kr, ts, bq, bk, 4).feasible
-        for bq, bk, _hb in _AUTO_BLOCK_CONFIGS
+        for bq, bk in dict.fromkeys(c[:2] for c in _AUTO_BLOCK_CONFIGS)
     ) == verdicts
     ranked = rank_candidates(
         qr, kr, ts, 64, 8, max_block_q=total // 4, max_block_k=total // 4,
@@ -397,3 +413,193 @@ def test_per_rank_tables_keep_the_bound_and_its_verdicts(
         best.smem_entries, "bound",
     )
     assert decision.rejected_smem == verdicts.count(False)
+
+
+# -- the bytes a step streams from HBM (ISSUE 35) ---------------------------
+@pytest.mark.parametrize(
+    "kernel,rung,group,d,itemsize,want",
+    [
+        # q-major: K and V of the key-value heads the step's q heads share
+        ("fwd", (128, 512, 5), 1, 256, 2, 2 * 5 * 512 * 256 * 2),  # 2.62 MB
+        ("dq", (128, 512, 5), 1, 256, 2, 2_621_440),
+        ("fwd", (128, 512, 8), 1, 128, 2, 2_097_152),  # Ouro: 2.10 MB
+        ("fwd", (256, 512, 4), 1, 128, 2, 1_048_576),
+        ("fwd", (128, 512, 8), 4, 128, 2, 2 * 2 * 512 * 128 * 2),  # Mistral
+        ("fwd", (128, 512, 8), 8, 128, 2, 262_144),  # one pair a group
+        ("dq", (1024, 1024, 1), 8, 128, 2, 524_288),  # per head: one pair
+        ("fwd", (512, 2048, 1), 1, 64, 4, 2 * 2048 * 64 * 4),  # float32
+        # k-major: q, dO and the two 128-lane float32 statistics
+        ("dkv", (128, 512, 5), 1, 256, 2, 5 * 128 * (1024 + 1024)),
+        ("dkv", (256, 512, 4), 1, 128, 2, 4 * 256 * (512 + 1024)),
+        ("dkv", (128, 512, 8), 8, 128, 2, 1_572_864),
+        ("dkv", (1024, 1024, 1), 8, 128, 2, 1024 * 1536),
+    ],
+)
+def test_step_bytes_against_a_hand_count(kernel, rung, group, d, itemsize, want):
+    assert step_bytes(kernel, *rung, group, d, itemsize) == want
+
+
+# forward / dq / dkv kernel ms a call on the GLM cell's mask at 20 q = 20 kv
+# heads of 256, compact grid, v5e: PR 30 and PR 31's chip runs (PERF.md
+# section 5), and PR 35's probe of the same mask (section 6)
+_CHIP_MS_PR30 = {
+    (128, 512, 5): (6.145, 6.525, 7.125),
+    (256, 512, 4): (4.453, 5.508, 7.005),
+    (128, 1024, 5): (7.775, 8.303, 9.070),
+    (128, 512, 1): (8.847, 8.546, 9.754),
+}
+_CHIP_MS_PR35 = {
+    (128, 512, 5): (6.148, 6.524, 7.125),
+    (256, 512, 4): (4.451, 5.509, 6.925),
+    (256, 512, 5): (4.350, 5.443, 6.843),
+    (512, 512, 4): (4.463, 5.652, 7.713),
+}
+
+
+def _glm_prices(rungs):
+    qr, kr, ts, total = _bench_mask("packed16k")
+    ranked = rank_candidates(
+        qr, kr, ts, 20, 20, head_dim=256, max_block_q=total,
+        max_block_k=total, include_sparse=False, generation="v5e",
+        rungs=[(bq, bk, 8 if hb == 5 else hb) for bq, bk, hb in rungs],
+    )
+    got = {(s.block_q, s.block_k, s.head_block): s for s in ranked}
+    assert sorted(got) == sorted(rungs)  # 8 snaps to 5 of 20 heads
+    return got
+
+
+@pytest.mark.parametrize(
+    "cheaper,dearer",
+    [
+        ((256, 512, 4), (128, 512, 5)),
+        ((128, 512, 5), (128, 1024, 5)),
+        ((128, 512, 5), (128, 512, 1)),
+    ],
+)
+def test_the_price_orders_the_measured_rungs_as_the_chip_does(cheaper, dearer):
+    """ISSUE 35's bar: on PR 30's four measured rungs the model's order is
+    the chip's (forward + dq + dkv)."""
+    assert sum(_CHIP_MS_PR30[cheaper]) < sum(_CHIP_MS_PR30[dearer])
+    price = _glm_prices(list(_CHIP_MS_PR30))
+    assert price[cheaper].cost_seconds < price[dearer].cost_seconds
+
+
+def test_the_price_ratio_of_the_two_rungs_is_the_chips():
+    """(128, 512, 5) : (256, 512, 4) within 15% of the measured 1.12 (the
+    forward+backward call, 25.83 / 23.00 ms; the three kernels alone 1.167
+    in PR 30's readings and 1.172 in PR 35's); without the bytes the model
+    has the order the other way round."""
+    price = _glm_prices([(128, 512, 5), (256, 512, 4)])
+    ratio = price[128, 512, 5].cost_seconds / price[256, 512, 4].cost_seconds
+    assert ratio == pytest.approx(1.12, rel=0.15)
+    for chip in (_CHIP_MS_PR30, _CHIP_MS_PR35):
+        measured = sum(chip[128, 512, 5]) / sum(chip[256, 512, 4])
+        assert ratio == pytest.approx(measured, rel=0.15)
+    assert (
+        price[128, 512, 5].compute_seconds < price[256, 512, 4].compute_seconds
+    )
+
+
+def test_the_probes_rungs_keep_the_chips_winner():
+    """PR 35's probe: five heads a step beats four at block_q 256, and the
+    block_q-512 rung beats neither; the model agrees on all three."""
+    price = _glm_prices(list(_CHIP_MS_PR35))
+    for chip_order in (
+        [(256, 512, 5), (256, 512, 4), (512, 512, 4)],
+        [(256, 512, 4), (128, 512, 5)],
+    ):
+        for a, b in zip(chip_order, chip_order[1:]):
+            assert sum(_CHIP_MS_PR35[a]) < sum(_CHIP_MS_PR35[b])
+            assert price[a].cost_seconds < price[b].cost_seconds
+
+
+def _at_the_peak(s, mfu=TPU_PEAK_SPECS["v5e"].mfu):
+    """(hbm, mxu) seconds of a score's forward at the chip's two peaks."""
+    return s.hbm_seconds * HBM_PRICE_SHARE, s.mxu_seconds * mfu
+
+
+@pytest.mark.parametrize(
+    "hq,hk,d,rung",
+    [(20, 20, 256, (128, 512, 5)), (16, 16, 128, (128, 512, 8))],
+    ids=["glm", "ouro"],
+)
+def test_the_term_binds_for_the_parents_rung_at_group_one(hq, hk, d, rung):
+    qr, kr, ts, total = _bench_mask("packed16k")
+    ranked = rank_candidates(
+        qr, kr, ts, hq, hk, head_dim=d, max_block_q=total, max_block_k=total,
+        include_sparse=False, generation="v5e",
+    )
+    by = {(s.block_q, s.block_k, s.head_block): s for s in ranked}
+    old, best = by[rung], ranked[0]
+    hbm, mxu = _at_the_peak(old)
+    assert hbm > mxu and old.bound == "hbm" and old.hbm_excess_seconds > 0
+    # 128 FLOPs a byte against the chip's 240
+    assert mxu / hbm == pytest.approx(128 / (197e12 / 819e9), rel=1e-6)
+    # the parent's choice: cheapest by tiles and steps, first in preference
+    assert old.compute_seconds == min(s.compute_seconds for s in ranked)
+    assert (best.block_q, best.block_k) == (256, 512) and best.head_block > 4
+    assert best.bound == "mxu" and best.hbm_excess_seconds == 0.0
+    assert best.hbm_seconds < old.hbm_seconds
+    hbm, mxu = _at_the_peak(best)
+    assert mxu / hbm == pytest.approx(256 / 240.5, rel=1e-3)  # just over
+
+
+@pytest.mark.parametrize(
+    "family,hq,hk,cp",
+    [
+        ("packed", 64, 8, 1), ("causal", 64, 8, 1), ("chunk_causal", 64, 8, 1),
+        ("swa1024", 64, 8, 1), ("cp4_packed", 64, 8, 4),
+        ("cp4_causal", 64, 8, 4), ("packed16k", 32, 8, 1),
+        ("packed32k", 32, 4, 1),
+    ],
+)
+def test_the_term_is_slack_in_the_eight_other_cells(family, hq, hk, cp):
+    """At GQA group 4 or 8 a step's K and V serve the whole group: no rung
+    of the table streams, every price is what it was, and the winner's
+    forward is at least twice the balance away from the HBM's roof."""
+    qr, kr, ts, total = _bench_mask(family)
+    ranked = rank_candidates(
+        qr, kr, ts, hq, hk, max_block_q=total // cp, max_block_k=total // cp,
+        cp_size=cp, include_sparse=False, generation="v5e",
+    )
+    for s in ranked:
+        assert s.bound == "mxu" and s.hbm_excess_seconds == 0.0
+        assert s.cost_seconds == s.compute_seconds
+    hbm, mxu = _at_the_peak(ranked[0])
+    assert 2 * 240.6 * hbm < 240.6 * mxu
+
+
+@pytest.mark.parametrize("family", ["packed", "causal", "swa1024"])
+@pytest.mark.parametrize("include_sparse", [False, True])
+def test_a_group_one_mask_at_64k_gets_block_q_256_or_more(family, include_sparse):
+    """MHA at head_dim 128, 16 q = 16 kv heads: whatever the mask and the
+    grids ranked, the winner's forward clears the balance."""
+    qr, kr, ts, total = _bench_mask(family)
+    best = rank_candidates(
+        qr, kr, ts, 16, 16, max_block_q=total, max_block_k=total,
+        include_sparse=include_sparse, generation="v5e",
+    )[0]
+    assert best.feasible and best.bound == "mxu" and best.block_q >= 256
+
+
+def test_an_hbm_bound_rung_is_no_tie_with_one_that_is_not():
+    """The check's 4,096-token mask at 16 q = 16 kv heads: the three small
+    rungs are within 8% of each other, and (128, 512, 8), first in the
+    preference order, is passed over because its own price says it
+    streams. At 64 / 8 heads the same mask's tie goes to it as before."""
+    from benchmarks import harness, masks
+
+    tr = harness.load_cell(ROOT, "ouro26b-train-16k-looped").traffic
+    m = masks.build_mask(tr["mask"], 4096, index=0)
+    args = dict(max_block_q=4096, max_block_k=4096, include_sparse=False,
+                generation="v5e")
+    ranked = rank_candidates(m.q_ranges, m.k_ranges, m.types, 16, 16, **args)
+    small = [s for s in ranked if s.block_k == 512]
+    assert max(s.cost_seconds for s in small) < 1.15 * min(
+        s.cost_seconds for s in small
+    )
+    assert [(s.block_q, s.head_block, s.bound) for s in small] == [
+        (256, 8, "mxu"), (256, 4, "mxu"), (128, 8, "hbm"),
+    ]
+    gqa = rank_candidates(m.q_ranges, m.k_ranges, m.types, 64, 8, **args)
+    assert (gqa[0].block_q, gqa[0].block_k, gqa[0].head_block) == (128, 512, 8)

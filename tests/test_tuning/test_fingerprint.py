@@ -1,6 +1,7 @@
 """Workload fingerprint: stability, sensitivity, hashing (ISSUE 2)."""
 
 import numpy as np
+import pytest
 
 from magiattention_tpu.tuning import make_fingerprint
 from magiattention_tpu.tuning.fingerprint import WorkloadFingerprint, _log2_bucket
@@ -147,7 +148,7 @@ def test_fingerprint_v3_carries_sparse_rung_axes():
     with it the sparse-vs-row-major ranking) differs must not share a
     cached winner even when their aggregate statistics alias."""
     fp = make_fingerprint([(0, 4096)], [(0, 4096)], [1], 8, 8)
-    assert fp.version == WorkloadFingerprint.FINGERPRINT_VERSION == 4
+    assert fp.version == WorkloadFingerprint.FINGERPRINT_VERSION == 5
     assert fp.step_est and fp.sparse_entry_est
     # one uniform 4k doc vs 4 skewed docs with the same total: the
     # coarse aggregates may bucket together, the steps extent must not
@@ -163,3 +164,76 @@ def test_fingerprint_v3_carries_sparse_rung_axes():
     )
     assert uniform.step_est != skewed.step_est
     assert uniform.stable_hash() != skewed.stable_hash()
+
+
+@pytest.mark.parametrize(
+    "hq,hk,d,stale,fresh",
+    [
+        (20, 20, 256, (128, 512, 5), (256, 512, 5)),
+        (16, 16, 128, (128, 512, 8), (256, 512, 8)),
+    ],
+    ids=["glm", "ouro"],
+)
+def test_an_older_record_for_a_group_one_mask_is_not_served(
+    hq, hk, d, stale, fresh, monkeypatch, tmp_path
+):
+    """A cache directory the parent of ISSUE 35 filled holds (128, 512, hb)
+    for the GLM and Ouro cells' mask, chosen when no bytes were priced. It
+    is a version-4 fingerprint's record: under its own hash the version-5
+    key never opens it, and planted under the new key's name the stored
+    fingerprint does not match. Either way the mask is ranked anew, and the
+    decision's event says what the bytes term did, on the miss and on the
+    hit that follows."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmarks import masks
+    from magiattention_tpu import telemetry
+    from magiattention_tpu.tuning import (
+        TuningRecord, get_tuning_cache, reset_tuning_cache,
+        resolve_block_config,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
+    with open(os.path.join(
+        root, "benchmarks", "traffic", "train-16k-packed-mla.json"
+    )) as f:
+        m = masks.build_mask(json.load(f)["mask"], 16384, index=0)
+    monkeypatch.setenv("MAGI_ATTENTION_AUTOTUNE_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("MAGI_ATTENTION_AUTOTUNE", raising=False)
+    reset_tuning_cache()
+    fp = make_fingerprint(
+        m.q_ranges, m.k_ranges, m.types, hq, hk, head_dim=d,
+        max_block_q=16384, max_block_k=16384, include_sparse=False,
+    )
+    old_fp = dataclasses.replace(fp, version=4)
+    assert old_fp.stable_hash() != fp.stable_hash()
+    record = TuningRecord(*stale, "model", 5.99, None, ())
+    cache = get_tuning_cache()
+    cache._store_disk(old_fp.stable_hash(), old_fp, record)
+    cache._store_disk(fp.stable_hash(), old_fp, record)  # planted
+    assert cache.get(fp) == (None, "miss")
+
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    try:
+        seen = len(telemetry.get_event_buffer().events())
+        for _ in range(2):  # a miss, then the new record from memory
+            assert resolve_block_config(
+                m.q_ranges, m.k_ranges, m.types, 16384, 16384, 1, hq, hk, d,
+                "bfloat16",
+            ) == fresh
+        got = [
+            ev["args"]
+            for ev in telemetry.get_event_buffer().events()[seen:]
+            if ev["name"] == "autotune_decision"
+        ]
+    finally:
+        telemetry.set_enabled(was)
+        reset_tuning_cache()
+    assert [a["cache_layer"] for a in got] == ["none", "memory"]
+    for args in got:
+        assert (args["bound"], args["rejected_bytes"]) == ("mxu", 1)
+        assert 0 < args["hbm_seconds"] < args["mxu_seconds"]
+    assert got[0]["mxu_seconds"] == got[1]["mxu_seconds"]

@@ -40,15 +40,16 @@ def telemetry_on():
     telemetry.set_enabled(was)
 
 
-def _decisions(build) -> list[dict]:
+def _decisions(build, event: str = "attn_fn_build") -> list[dict]:
     """What ``make_attn_params`` left on the ``attn_fn_build`` spans that
-    ``build()`` opened: rung, grid, the three counts, the two prices."""
+    ``build()`` opened: rung, grid, the three counts, the two prices (or
+    the tuner on its ``autotune_decision`` events)."""
     seen = len(telemetry.get_event_buffer().events())
     build()
     return [
         ev["args"]
         for ev in telemetry.get_event_buffer().events()[seen:]
-        if ev["name"] == "attn_fn_build"
+        if ev["name"] == event
     ]
 
 
@@ -114,9 +115,12 @@ CELLS = {
         ((128, 512, 8), "row_major", 2 * 512 * 3 + 129 * 12, 3 * 1528, 3 * 1524),
     ],
     # the Mistral cell's mask at 20 query = 20 key-value heads of width
-    # 256 (ISSUE 30): the same tables; (128, 512, 8) snaps to 5 heads a step
+    # 256 (ISSUE 30). ISSUE 35: at GQA group 1 a step of (128, 512, 5)
+    # streams K and V at the HBM's pace, the price says so and the tuner
+    # returns (256, 512, 8) snapped to 5 heads a step: 64 q blocks of at
+    # most 9 entries, 33 k blocks of at most 17, 213 entries padded to 216
     "glm47flash-train-16k-packed": [
-        ((128, 512, 5), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
+        ((256, 512, 5), "sparse", 2 * 64 * 9 + 33 * 17, 3 * 216, 3 * 213),
     ],
     # 16 chunks of 4 blocks: q block r meets 4 (r // 4 + 1) k blocks
     "magi64x8-attn-64k-chunkcausal": [
@@ -129,9 +133,10 @@ CELLS = {
         ((512, 2048, 1), "sparse", 49152, 24784, 24768),
     ],
     # the Mistral cell's mask at 16 query = 16 key-value heads of width
-    # 128 (ISSUE 32): the same tables, eight key-value heads a step
+    # 128 (ISSUE 32), eight key-value heads a step; group 1 again, so
+    # since ISSUE 35 (256, 512, 8) and the GLM cell's tables
     "ouro26b-train-16k-looped": [
-        ((128, 512, 8), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
+        ((256, 512, 8), "sparse", 2 * 64 * 9 + 33 * 17, 3 * 216, 3 * 213),
     ],
 }
 
@@ -159,6 +164,88 @@ def test_every_cell_keeps_its_rung_and_gets_the_expected_grid(
     ] == pytest.approx(100.0 * (1.0 - args["live_steps"] / launched))
 
 
+# heads and head_dim at the kernels of the two cells at GQA group 1
+GROUP_ONE = {
+    "glm47flash-train-16k-packed": (20, 20, 256),
+    "ouro26b-train-16k-looped": (16, 16, 128),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_every_cells_decision_says_which_roof_binds_its_rung(
+    telemetry_on, cell, monkeypatch
+):
+    """ISSUE 35: the ``autotune_decision`` event of every plan of every cell
+    carries what the operand-bytes term read. In the eight cells at GQA
+    group 4 or 8 nothing streams: ``bound`` is ``mxu``, no rung was passed
+    over, and at the chip's own peaks the forward's HBM time is under its
+    MXU time. In the two at group 1 the chosen rung is MXU-bound too, one
+    cheaper-by-tiles-and-steps rung was passed over as HBM-bound, and the
+    chosen rung's K and V stream is under the parent's rung's."""
+    from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
+
+    monkeypatch.delenv("MAGI_ATTENTION_GRID", raising=False)
+    counted = telemetry.get_registry().counter_value(
+        "magi_autotune_decisions_total", bound="mxu"
+    )
+    got = _decisions(lambda: _build_cell(cell), "autotune_decision")
+    assert len(got) == len(CELLS[cell])
+    for args, (rung, *_rest) in zip(got, CELLS[cell]):
+        assert args["rung"] == "x".join(map(str, rung))
+        assert args["bound"] == "mxu"
+        assert args["rejected_bytes"] == (1 if cell in GROUP_ONE else 0)
+        assert (
+            args["hbm_seconds"] * cost_model.HBM_PRICE_SHARE
+            < args["mxu_seconds"] * TPU_PEAK_SPECS["v5e"].mfu
+        )
+    assert telemetry.get_registry().counter_value(
+        "magi_autotune_decisions_total", bound="mxu"
+    ) == counted + len(got)
+    if cell in GROUP_ONE:
+        from benchmarks import harness, masks
+
+        hq, hk, d = GROUP_ONE[cell]
+        tr = harness.load_cell(ROOT, cell).traffic
+        m = masks.build_mask(tr["mask"], int(tr["total_tokens"]), index=0)
+        (old,) = [
+            s
+            for s in cost_model.rank_candidates(
+                m.q_ranges, m.k_ranges, m.types, hq, hk, head_dim=d,
+                include_sparse=False,
+            )
+            if (s.block_q, s.block_k) == (128, 512)
+        ]
+        assert old.bound == "hbm" and args["hbm_seconds"] < old.hbm_seconds
+
+
+@pytest.mark.parametrize(
+    "kind,cell",
+    [
+        ("train_latent", "glm47flash-train-16k-packed"),
+        ("train_looped", "ouro26b-train-16k-looped"),
+    ],
+)
+def test_the_check_plans_the_windows_rung_and_grid(kind, cell):
+    """``correct`` of a training cell is decided on a plan of the check's
+    own at 4,096 tokens; it walks the rung and the grid the window's 16,384
+    do. (The benchmark's own tests of this name pin the rung's literal,
+    which ISSUE 35 moved; this one compares the two plans with each other.)"""
+    from benchmarks import harness, masks
+
+    job_of = importlib.import_module("benchmarks.kinds." + kind)
+    c = harness.load_cell(ROOT, cell)
+    job = job_of.Job(c.config, c.traffic, 1, jax.devices()[:1])
+    chosen = []
+    for mask in (
+        job_of.check_mask(c.traffic),
+        masks.build_mask(c.traffic["mask"], 16384, index=0),
+    ):
+        (p,) = job.build(mask)[0].attn_params.values()
+        chosen.append((p.block_q, p.block_k, p.head_block, p.grid))
+    assert chosen[0] == chosen[1]
+    assert chosen[1] == (*CELLS[cell][0][0], CELLS[cell][0][1])
+
+
 def test_a_version_3_record_for_a_band_mask_is_not_served(
     telemetry_on, monkeypatch, tmp_path
 ):
@@ -169,7 +256,7 @@ def test_a_version_3_record_for_a_band_mask_is_not_served(
     fingerprint does not match. Either way the mask is ranked anew."""
     from benchmarks import masks
 
-    assert WorkloadFingerprint.FINGERPRINT_VERSION == 4
+    assert WorkloadFingerprint.FINGERPRINT_VERSION == 5
     monkeypatch.setenv("MAGI_ATTENTION_AUTOTUNE_CACHE_DIR", str(tmp_path))
     monkeypatch.delenv("MAGI_ATTENTION_AUTOTUNE", raising=False)
     total = 65536

@@ -34,7 +34,9 @@ def test_auto_head_block_divides_the_heads_and_holds_whole_groups(
         _check_head_block(got, hq, group)  # what the kernels accept
 
 
-@pytest.mark.parametrize("hq,group", [(20, 1), (20, 4), (12, 3), (64, 8)])
+@pytest.mark.parametrize(
+    "hq,group", [(20, 1), (16, 1), (20, 4), (12, 3), (64, 8), (32, 4)]
+)
 def test_every_rung_of_the_table_snaps_to_a_valid_head_batch(hq, group):
     for _bq, _bk, pref in _AUTO_BLOCK_CONFIGS:
         hb = _auto_head_block(pref, hq, group)
@@ -52,7 +54,12 @@ def _params(bq, bk, hb):
 @pytest.mark.parametrize(
     "rung,hq,group,want",
     [
-        ((128, 512, 5), 20, 1, 5),     # the GLM cell's: 5 MiB of logit tiles
+        ((128, 512, 5), 20, 1, 5),     # the GLM cell's before ISSUE 35: 5 MiB
+        ((256, 512, 5), 20, 1, 5),     # the GLM cell's: 10 MiB of logit tiles
+        ((256, 512, 4), 20, 1, 4),     # 8 MiB
+        ((128, 512, 8), 16, 1, 8),     # the Ouro cell's before ISSUE 35: 8 MiB
+        ((256, 512, 8), 16, 1, 8),     # the Ouro cell's: 16 MiB
+        ((512, 512, 4), 16, 1, 4),     # the probe's block_q-512 rung: 16 MiB
         ((256, 1024, 2), 20, 1, 2),    # 8 MiB
         ((512, 768, 4), 20, 1, 4),     # 24 MiB
         ((1024, 1024, 5), 20, 1, 1),   # 80 MiB: stays per head
