@@ -1618,6 +1618,31 @@ def roll(x: jax.Array, key: DistAttnRuntimeKey, shift: int, axis: int = 0):
     )
 
 
+def make_shift_plan(key: DistAttnRuntimeKey, taps=(1, 2), *, cu_seqlens=None):
+    """Plan the forward shift along the documents of a key's dispatch
+    (``parallel.dispatch.make_shift_plan``): inside a ``shard_map`` over
+    the key's cp axis, ``shift_local(x, tables, plan, cp_axis)`` then
+    gives a rank's rows ``x`` shifted by every ``j`` of ``taps`` in
+    global order, ``y_j[p] = x[p - j]``, zero at a document's first ``j``
+    tokens; the rank-crossing rows of all taps ride one exchange, and the
+    backward is the shift by ``-j``. ``cu_seqlens``: the documents; by
+    default every start of the key's q ranges starts one (a packed
+    varlen key's q ranges are its documents). ``plan.device_tables()``
+    are sharded on the cp axis like a plan's tables."""
+    from ..parallel.dispatch import make_shift_plan as _plan
+
+    mgr = get_runtime_mgr(key)
+    if isinstance(mgr, BucketedDistAttnRuntimeMgr):
+        raise ValueError(
+            "a shift is not supported on a bucketed (plan-reuse) key: its "
+            "dispatch meta describes canonical coordinates, as for roll"
+        )
+    if cu_seqlens is None:
+        starts = sorted({0, *(s for s, _e in key.q_ranges)})
+        cu_seqlens = [*starts, key.total_seqlen_q]
+    return _plan(mgr.dispatch_meta, cu_seqlens, taps)
+
+
 def roll_simple(
     x: jax.Array, key: DistAttnRuntimeKey, shift: int, axis: int = 0
 ):

@@ -40,6 +40,19 @@ exit gate, and the loss is the expectation of the exits' per-token
 losses under the gate's distribution, less ``exit_entropy_weight`` x its
 entropy (:func:`_exit_losses`, :func:`exit_log_probs`).
 
+The third attention form, ``cca`` (ZAYA1 / ``zaya_config``: compressed
+convolutional attention), keeps the attention inside the projections'
+latent: q and k are mixed along the sequence by two short causal
+convolutions (depthwise, then one block a head), take the mean of each
+other's heads and an L2 norm with a learned key temperature, half the
+value heads read the token BEFORE, and the output projection follows the
+kernels with no up-projection between. A token reads its predecessors
+inside its document only, wherever dispatch put them
+(``parallel/dispatch.shift_local`` on the plan ``build_magi_pattern``
+makes beside the attention's). ZAYA1's expert half routes top-1 through
+an MLP (``router_form``) whose 256-wide input carries over from layer to
+layer: the first state beside ``x`` on the residual path (:func:`route`).
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -59,7 +72,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import telemetry
 from ..ops.flex_attn import FlexAttnParams
-from ..parallel.dispatch import roll
+from ..parallel.dispatch import (
+    make_shift_plan, roll, shift_local, shift_valid,
+)
 from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
@@ -68,7 +83,8 @@ from .llama import _rms_norm, _rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 DENSE, EXPERTS = "dense", "experts"
-GQA, LATENT = "gqa", "latent"
+GQA, LATENT, CCA = "gqa", "latent", "cca"
+SIGMOID, MLP = "sigmoid", "mlp"  # the router's forms
 _SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
 
 
@@ -100,22 +116,41 @@ class PatternConfig:
     route_norm: bool = True
     route_scale: float = 1.0
     router_dtype: str = "float32"
+    # SIGMOID: sigmoid scores of one matrix. MLP (ZAYA1): the hidden state
+    # down to ``router_hidden``, plus a learned per-channel weight times
+    # the layer before's (the state a layer hands the next), a norm, two
+    # GELU layers, softmax scores
+    router_form: str = SIGMOID
+    router_hidden: int = 0
     # the experts THIS rank holds, [first, last): the router stays
     # ``n_experts`` wide and top-k; a pair whose expert is held elsewhere
     # contributes nothing here (in a deployment it arrives with the
     # all-to-all's combine). None: all of them.
     expert_range: tuple[int, int] | None = None
+    # the held experts' grouped matmuls take a chunk's rows whatever it
+    # holds, as the gather and the scatter-add round them do: the rows past
+    # the held pairs are zeros in the last group. A step then costs the
+    # same wherever the router sends the tokens (top-1 of 16 swings a
+    # layer's held share from 2% to 100% with the seed: PERF.md section 6,
+    # PR 39), at the price of the matmuls a full chunk takes
+    flat_expert_rows: bool = False
     dtype: str = "bfloat16"
     remat: bool = False
     # the attention's form, every layer's: GQA (q, k, v from the hidden
-    # state) or LATENT. Under LATENT ``head_dim`` is what the kernels see,
-    # the same for q, k and v: ``head_dim - rope_head_dim`` without
+    # state), LATENT or CCA. Under CCA q and k are mixed by two causal
+    # convolutions of ``conv_taps`` taps along the document and the second
+    # half of the value heads reads the token before; rotary on the FIRST
+    # ``rope_head_dim`` of a head. Under LATENT ``head_dim`` is what the
+    # kernels see, the same for q, k and v: ``head_dim - rope_head_dim`` without
     # position and ``rope_head_dim`` rotary, in every layer whatever
     # ``rope_kinds`` says; ``n_kv_heads == n_heads``
     attn_form: str = GQA
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     rope_head_dim: int = 0
+    conv_taps: tuple[int, int] = (0, 0)
+    # logits on the embedding's rows: no ``lm_head`` parameter
+    tie_embeddings: bool = False
     # multi-token-prediction modules after the trunk (0 or 1 until a
     # reference states a chain of them), each one more layer of the last
     # trunk layer's kinds; their losses weigh ``mtp_loss_weight``
@@ -133,7 +168,8 @@ class PatternConfig:
             raise ValueError("layer_types and ffn_types differ in length")
         bad = set(self.layer_types) - {SLIDING, FULL}
         bad |= set(self.ffn_types) - {DENSE, EXPERTS}
-        bad |= {self.attn_form} - {GQA, LATENT}
+        bad |= {self.attn_form} - {GQA, LATENT, CCA}
+        bad |= {self.router_form} - {SIGMOID, MLP}
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
         if self.attn_form == LATENT and not (
@@ -144,6 +180,29 @@ class PatternConfig:
             raise ValueError(
                 "latent attention needs n_kv_heads == n_heads, both ranks "
                 "and 0 < rope_head_dim < head_dim"
+            )
+        if self.attn_form == CCA and not (
+            self.n_heads % self.n_kv_heads == 0
+            and self.n_kv_heads % 2 == 0
+            and min(self.conv_taps) >= 1
+            and 0 < self.rope_head_dim <= self.head_dim
+        ):
+            raise ValueError(
+                "cca needs whole query groups, an even number of key-value "
+                "heads (half read the token before), both convolutions' "
+                "taps and 0 < rope_head_dim <= head_dim"
+            )
+        if self.attn_form == CCA and (
+            self.sliding_window is not None or self.n_mtp or self.n_loops > 1
+        ):
+            raise ValueError(
+                "cca under a window, an MTP module or a loop: no reference "
+                "states one"
+            )
+        if self.router_form == MLP and (self.router_hidden < 1 or self.n_mtp):
+            raise ValueError(
+                "the MLP router needs router_hidden, and hands its state "
+                "from layer to layer: an MTP module has no layer before"
             )
         if self.n_mtp > 1:
             raise ValueError(
@@ -164,6 +223,14 @@ class PatternConfig:
     @property
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def shift_taps(self) -> tuple[int, ...]:
+        """How far back a layer reads outside the attention call: the two
+        convolutions one after the other (and the value's one token)."""
+        if self.attn_form != CCA:
+            return ()
+        return tuple(range(1, max(sum(self.conv_taps) - 2, 1) + 1))
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -290,6 +357,64 @@ def glm4_moe_lite_config(
     )
 
 
+def zaya_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``zaya`` ``config.json`` (ZAYA1-8B) as a pattern: every
+    layer ``hybrid``, compressed convolutional attention (``cca_time0``
+    and ``cca_time1`` the two kernels; rotary on ``partial_rotary_factor``
+    of a head) and then top-``num_experts_per_tok`` experts behind the
+    MLP router of ``router_hidden_size``; no window, one plan; the
+    embedding tied. ``expert_range`` and ``vocab_size`` give one rank's
+    share, as in :func:`afmoe_config`."""
+    n = int(hf["num_hidden_layers"])
+    # a cut in depth keeps the published list and runs its first n
+    if set(hf["layer_types"][:n]) != {"hybrid"} or len(hf["layer_types"]) < n:
+        raise ValueError(
+            f"layer_types {sorted(set(hf['layer_types']))} for {n} layers: "
+            "every layer of the model written down is 'hybrid'"
+        )
+    if hf.get("sliding_window") is not None:
+        raise ValueError("a zaya configuration with a window is not built")
+    head_dim = int(hf["head_dim"])
+    rope = hf["rope_parameters"]["hybrid"]
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=int(hf["hidden_size"]),
+        n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=head_dim,
+        layer_types=(FULL,) * n,
+        ffn_types=(EXPERTS,) * n,
+        ffn_hidden=0,
+        rope_theta=float(rope["rope_theta"]),
+        rope_kinds=(FULL,),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=False,
+        rms_eps=float(hf["rms_norm_eps"]),
+        n_experts=int(hf["num_experts"]),
+        top_k=int(hf["num_experts_per_tok"]),
+        expert_hidden=int(hf["moe_intermediate_size"]),
+        route_norm=False,
+        router_form=MLP,
+        router_hidden=int(hf["router_hidden_size"]),
+        expert_range=expert_range,
+        flat_expert_rows=True,
+        dtype=dtype,
+        remat=remat,
+        attn_form=CCA,
+        rope_head_dim=int(head_dim * float(rope["partial_rotary_factor"])),
+        conv_taps=(int(hf["cca_time0"]), int(hf["cca_time1"])),
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+    )
+
+
 def ouro_config(
     hf: dict, *, dtype: str = "bfloat16", remat: bool = False
 ) -> PatternConfig:
@@ -377,6 +502,24 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str) -> dict:
             "wv": dense(k[2], (cfg.dim, hk)),
             "wo": dense(k[3], (hq, cfg.dim)),
         }
+    if cfg.attn_form == CCA:
+        kc = jax.random.split(jax.random.fold_in(key, 2), 5)
+        t0, t1 = cfg.conv_taps
+        groups = cfg.n_heads + cfg.n_kv_heads
+        layer.update({
+            # q's channels, then k's: a weight a channel a tap (tap j
+            # reads the token j before), then a block a head a tap
+            "cca_conv1_w": dense(kc[0], (t0, hq + hk)),
+            "cca_conv1_b": 0.02 * jax.random.normal(kc[1], (hq + hk,)),
+            "cca_conv2_w": dense(
+                kc[2], (t1, groups, cfg.head_dim, cfg.head_dim)
+            ),
+            "cca_conv2_b": 0.02 * jax.random.normal(kc[3], (hq + hk,)),
+            # a key head's temperature
+            "cca_temp": 1.0 + 0.1 * jax.random.normal(
+                kc[4], (cfg.n_kv_heads,)
+            ),
+        })
     layer["attn_norm"] = ones(cfg.dim)
     layer["mlp_norm"] = ones(cfg.dim)
     if cfg.attn_gate:
@@ -393,7 +536,18 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str) -> dict:
         layer["w_down"] = dense(k[7], (cfg.ffn_hidden, cfg.dim))
     else:
         eh, held = cfg.expert_hidden, e1 - e0
-        layer["w_router"] = dense(k[5], (cfg.dim, cfg.n_experts))
+        if cfg.router_form == MLP:
+            kr = jax.random.split(jax.random.fold_in(key, 3), 5)
+            rh = cfg.router_hidden
+            layer["w_router_down"] = dense(kr[0], (cfg.dim, rh))
+            # the layer before's state, a weight a channel
+            layer["router_gamma"] = 0.5 + 0.1 * jax.random.normal(kr[1], (rh,))
+            layer["router_norm"] = ones(rh)
+            layer["w_router_mlp1"] = dense(kr[2], (rh, rh))
+            layer["w_router_mlp2"] = dense(kr[3], (rh, rh))
+            layer["w_router"] = dense(kr[4], (rh, cfg.n_experts))
+        else:
+            layer["w_router"] = dense(k[5], (cfg.dim, cfg.n_experts))
         layer["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
         layer["we_gate"] = dense(k[6], (held, cfg.dim, eh))
         layer["we_up"] = dense(k[7], (held, cfg.dim, eh))
@@ -414,7 +568,7 @@ def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
     norm before the shared head. ``exit_gate`` (where ``cfg.n_loops >
     1``): the ``Linear(dim, 1)`` every pass's normed state goes through,
     seeded like any dense weight (and its bias), so the exit distribution
-    is not uniform."""
+    is not uniform. No ``lm_head`` where ``cfg.tie_embeddings``."""
     keys = jax.random.split(rng, cfg.n_layers + 2)
     params = {
         "embed": jax.random.normal(
@@ -425,8 +579,9 @@ def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
             for i, ffn in enumerate(cfg.ffn_types)
         ],
         "final_norm": _ones(cfg.dim),
-        "lm_head": _dense_init(keys[-1], (cfg.dim, cfg.vocab_size)),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _dense_init(keys[-1], (cfg.dim, cfg.vocab_size))
     if cfg.n_mtp:
         params["mtp"] = []
         for j in range(cfg.n_mtp):
@@ -460,25 +615,42 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
     ) @ w_down.astype(dt)
 
 
-def route(h, layer: dict, cfg: PatternConfig):
-    """(expert ids [t, top_k], weights [t, top_k] float32): sigmoid
-    scores in ``router_dtype``, the top k of score + bias, the scores at
-    the chosen experts over their sum (``route_norm``), times
-    ``route_scale``."""
+def _router_scores(h, r, layer: dict, cfg: PatternConfig):
+    """(scores [t, n_experts] float32, the state for the next layer) in
+    ``router_dtype``. SIGMOID: the sigmoid of one matrix, no state. MLP:
+    ``r_l = h W_down + gamma r_{l-1}`` (``r`` is the layer before's, zero
+    before the first), and the softmax of two GELU layers and an output
+    matrix on its norm."""
     rdt = jnp.dtype(cfg.router_dtype)
-    scores = jax.nn.sigmoid(
-        jnp.dot(
-            h.astype(rdt), layer["w_router"].astype(rdt),
+
+    def dot(a, name):
+        return jnp.dot(
+            a.astype(rdt), layer[name].astype(rdt),
             precision=jax.lax.Precision.HIGHEST,  # float32 means float32
             preferred_element_type=rdt,
         )
-    ).astype(jnp.float32)
+
+    if cfg.router_form == SIGMOID:
+        return jax.nn.sigmoid(dot(h, "w_router")).astype(jnp.float32), r
+    r = dot(h, "w_router_down") + layer["router_gamma"].astype(rdt) * r
+    z = _rms_norm(r, layer["router_norm"], cfg.rms_eps)
+    for name in ("w_router_mlp1", "w_router_mlp2"):
+        z = jax.nn.gelu(dot(z, name), approximate=False)
+    return jax.nn.softmax(dot(z, "w_router").astype(jnp.float32), axis=-1), r
+
+
+def route(h, layer: dict, cfg: PatternConfig, r=None):
+    """(expert ids [t, top_k], weights [t, top_k] float32, the router's
+    state for the next layer): the scores (:func:`_router_scores`), the
+    top k of score + bias, the scores at the chosen experts over their
+    sum (``route_norm``), times ``route_scale``."""
+    scores, r = _router_scores(h, r, layer, cfg)
     bias = jax.lax.stop_gradient(layer["expert_bias"])
     _, idx = jax.lax.top_k(scores + bias, cfg.top_k)
     w = jnp.take_along_axis(scores, idx, axis=1)
     if cfg.route_norm:
         w = w / w.sum(axis=1, keepdims=True)
-    return idx, w * cfg.route_scale
+    return idx, w * cfg.route_scale, r
 
 
 def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
@@ -490,17 +662,19 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     grouped matmul (``jax.lax.ragged_dot``: the TPU compiler turns it
     into a tiled kernel that visits the groups' rows only) a chunk of
     ``2 t`` rows at a time: a token may choose up to ``top_k`` held
-    experts, so there are up to ``top_k / 2`` chunks. The first always
+    experts, so there are up to ``top_k / 2`` chunks (at top-1 the one
+    chunk is the ``t`` pairs there are). The first always
     runs; a later one that no held pair reaches is skipped
     (``lax.cond``). No pair is dropped. The matmuls follow the pairs
-    that are here; the row gather and the scatter-add round them take a
+    that are here (under ``flat_expert_rows`` they too take the chunk
+    whole); the row gather and the scatter-add round them take a
     chunk's rows whatever it holds, so a step's time is flat in the load
     up to ``2 t`` pairs, four times an even share (PERF.md section 6, PR
     26: the form whose every pass follows the pairs is faster at an even
     load and follows a drifting router by 9% inside 40 steps)."""
     dt = cfg.jnp_dtype
     t, k = idx.shape
-    rows = 2 * t
+    rows = min(2, k) * t
     e0, e1 = cfg.held_experts
     held = e1 - e0
     with named_scope("magi_moe_sort"):
@@ -525,6 +699,8 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
                 jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
             )
             valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
+            if cfg.flat_expert_rows:  # the zero rows: the last group's
+                sizes = sizes.at[-1].add(rows - sizes.sum())
             tok = pairs // k
 
         def grouped(x, w):
@@ -567,10 +743,10 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     return y, counts
 
 
-def _expert_ffn(h, layer: dict, cfg: PatternConfig):
+def _expert_ffn(h, layer: dict, cfg: PatternConfig, r=None):
     dt = cfg.jnp_dtype
     with named_scope("magi_moe_router"):
-        idx, w = route(h, layer, cfg)
+        idx, w, r = route(h, layer, cfg, r)
     with named_scope("magi_moe_experts"):
         y, counts = held_expert_ffn(h, idx, w, layer, cfg)
         with named_scope("magi_moe_scatter"):
@@ -580,7 +756,10 @@ def _expert_ffn(h, layer: dict, cfg: PatternConfig):
             y = y + _swiglu(
                 h, layer["ws_gate"], layer["ws_up"], layer["ws_down"], dt
             )
-    return y, {"expert_idx": idx, "expert_counts": counts}
+    stats = {"expert_idx": idx, "expert_counts": counts}
+    if r is not None:
+        stats["router_state"] = r  # the next layer's: the caller takes it
+    return y, stats
 
 
 def _latent_qkv(h, pos, layer: dict, cfg: PatternConfig):
@@ -611,9 +790,73 @@ def _latent_qkv(h, pos, layer: dict, cfg: PatternConfig):
     return q, k, v
 
 
-def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
-                 attn_params, axis_name):
-    """One layer on this rank's dispatched tokens -> (x, routing stats)."""
+def _qk_mean(q, k):
+    """What q [t, kv heads, group, head_dim] and k [t, kv heads,
+    head_dim] take of each other before the norm: a query head half of
+    itself and half of its key head, a key head half of itself and half
+    of the mean of its group's query heads."""
+    return 0.5 * (q + k[:, :, None]), 0.5 * (q.mean(axis=2) + k)
+
+
+def _cca_mix(q, k, v, layer: dict, cfg: PatternConfig, shift):
+    """Compressed convolutional attention's mixing on the projections
+    ``q`` [t, n_heads x head_dim], ``k`` and ``v`` [t, n_kv_heads x
+    head_dim] -> q, k, v a head, before rotary: the depthwise causal
+    convolution of ``[q | k]`` along the document, the grouped one (a
+    block a head), the mean of each other's heads, the L2 norm a head
+    (``sqrt(head_dim) x / |x|``, the key's times its head's
+    temperature), and the second half of the value heads read from the
+    token before. ``shift(z)`` -> (z shifted by 1, 2, ... along the
+    document, whether a slot has that predecessor): the two convolutions
+    one after the other read ``conv_taps[0] + conv_taps[1] - 2`` back, so
+    one shift of ``[q | k | v's second half]`` serves everything."""
+    f32 = jnp.float32
+    t, hd = q.shape[0], cfg.head_dim
+    t0, t1 = cfg.conv_taps
+    hq, hk = cfg.n_heads, cfg.n_kv_heads
+    group = hq // hk
+    half = hk // 2 * hd  # the value heads that stay on their token
+    c = jnp.concatenate([q, k], axis=-1)
+    shifted, has = shift(jnp.concatenate([c, v[:, half:]], axis=-1))
+    back = [c.astype(f32)] + [s[:, : c.shape[1]].astype(f32) for s in shifted]
+    a, b1 = layer["cca_conv1_w"], layer["cca_conv1_b"]
+
+    def conv1_at(i):  # the first convolution's output i tokens back
+        y = b1 + sum(a[j] * back[i + j] for j in range(t0))
+        return jnp.where(has[i - 1][:, None], y, 0.0) if i else y
+
+    # the second: one matmul a head over its taps' channels
+    c1 = jnp.stack(
+        [conv1_at(i).reshape(t, hq + hk, hd) for i in range(t1)], axis=2
+    ).astype(q.dtype)  # [t, heads, taps, head_dim]
+    c2 = jnp.einsum(
+        "thjd,jhde->the", c1, layer["cca_conv2_w"].astype(q.dtype),
+        preferred_element_type=f32,
+    ) + layer["cca_conv2_b"].reshape(hq + hk, hd)
+    mean_q, mean_k = _qk_mean(
+        back[0][:, : hq * hd].reshape(t, hk, group, hd),
+        back[0][:, hq * hd :].reshape(t, hk, hd),
+    )
+    q = c2[:, :hq] + mean_q.reshape(t, hq, hd)
+    k = c2[:, hq:] + mean_k
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=-1, keepdims=True) + cfg.rms_eps
+        )
+
+    q = unit(q).astype(v.dtype)
+    k = (unit(k) * layer["cca_temp"][:, None]).astype(v.dtype)
+    v = jnp.concatenate([v[:, :half], shifted[0][:, c.shape[1] :]], axis=-1)
+    return q, k, v.reshape(t, hk, hd)
+
+
+def _layer_local(x, pos, layer, r=None, *, cfg, layer_type, ffn_type, tables,
+                 plans, attn_params, axis_name, shift_plan=None):
+    """One layer on this rank's dispatched tokens -> (x, routing stats).
+    ``r``: an MLP router's state from the layer before; this layer's
+    leaves under ``router_state`` of the stats, for the caller to hand
+    on."""
     dt = cfg.jnp_dtype
     t = x.shape[0]
     eps = cfg.rms_eps
@@ -628,6 +871,27 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
             q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
             k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
             v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    if cfg.attn_form == CCA:
+        # a sibling of magi_proj, as the attention call is
+        with named_scope("magi_cca_mix"):
+            shift_tabs = tables["shift"]
+            q, k, v = _cca_mix(
+                q.reshape(t, -1), k.reshape(t, -1), v.reshape(t, -1),
+                layer, cfg,
+                lambda z: (
+                    shift_local(z, shift_tabs, shift_plan, axis_name),
+                    shift_valid(shift_tabs),
+                ),
+            )
+    with named_scope("magi_proj"):
+        if cfg.attn_form == CCA:
+            rope = cfg.rope_head_dim
+            q, k = (
+                jnp.concatenate(
+                    [_rope(a[..., :rope], pos, cfg.rope_theta, rope),
+                     a[..., rope:]], axis=-1,
+                ) for a in (q, k)
+            )
         if cfg.qk_norm:
             q = _rms_norm(q, layer["q_norm"], eps)
             k = _rms_norm(k, layer["k_norm"], eps)
@@ -661,7 +925,7 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
                 h, layer["w_gate"], layer["w_up"], layer["w_down"], dt
             )
     else:
-        out, stats = _expert_ffn(h, layer, cfg)
+        out, stats = _expert_ffn(h, layer, cfg, r)
     with named_scope("magi_ffn"):
         if cfg.post_norms:
             out = _rms_norm(out, layer["post_mlp_norm"], eps)
@@ -669,11 +933,11 @@ def _layer_local(x, pos, layer, cfg, layer_type, ffn_type, tables, plans,
 
 
 def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
-               axis_name):
+               axis_name, shift_plan=None):
     one_layer = functools.partial(
         _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
         tables=tables, plans=plans, attn_params=attn_params,
-        axis_name=axis_name,
+        axis_name=axis_name, shift_plan=shift_plan,
     )
     if cfg.remat:  # save a layer's input; the rest recomputes
         one_layer = jax.checkpoint(one_layer)
@@ -690,7 +954,11 @@ def _embed(params, tokens, cfg: PatternConfig):
 
 
 def _logits(x, params, cfg: PatternConfig):
-    return (x @ params["lm_head"].astype(cfg.jnp_dtype)).astype(jnp.float32)
+    if cfg.tie_embeddings:  # this rank's rows of the embedding
+        head = params["embed"].astype(cfg.jnp_dtype).T
+    else:
+        head = params["lm_head"].astype(cfg.jnp_dtype)
+    return (x @ head).astype(jnp.float32)
 
 
 def _head(x, norm, params, cfg: PatternConfig):
@@ -699,17 +967,24 @@ def _head(x, norm, params, cfg: PatternConfig):
 
 
 def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
-                 attn_params, axis_name):
+                 attn_params, axis_name, shift_plan=None):
     """The layers over this rank's dispatched tokens -> (the last layer's
-    output before the final norm, the expert layers' routing stats)."""
+    output before the final norm, the expert layers' routing stats). An
+    MLP router's state goes from layer to layer beside ``x``, zero before
+    the first."""
     x = _embed(params, tokens, cfg)
+    r = None
+    if cfg.router_form == MLP:
+        r = jnp.zeros((x.shape[0], cfg.router_hidden), cfg.router_dtype)
     stats = []
     for layer, layer_type, ffn_type in zip(
         params["layers"], cfg.layer_types, cfg.ffn_types
     ):
         x, s = _one_layer(
-            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name
-        )(x, pos, layer)
+            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name,
+            shift_plan,
+        )(x, pos, layer, r)
+        r = s.pop("router_state", None)
         if s:
             stats.append(s)
     return x, stats
@@ -856,6 +1131,8 @@ class MagiPattern:
     cp_axis: str | tuple[str, str] = "cp"
     dp_axis: str = "dp"
     dispatch_meta: Any = None  # the plans' dispatch: MTP targets roll on it
+    # the documents' forward shift on that dispatch (``cfg.shift_taps``)
+    shift_plan: Any = None
 
     def loss_fn(self, params, tokens, labels, pos, tables, *,
                 with_stats: bool = False):
@@ -897,7 +1174,9 @@ class MagiPattern:
                     states = _looped_trunk_local(params, tok1, pos1, *run)
                     with named_scope("magi_exit_head"):
                         return (_exit_losses(states, lab1, params, cfg),), {}
-                x, stats = _trunk_local(params, tok1, pos1, *run)
+                x, stats = _trunk_local(
+                    params, tok1, pos1, *run, self.shift_plan
+                )
                 logits = [_head(x, params["final_norm"], params, cfg)]
                 if cfg.n_mtp:
                     with named_scope("magi_mtp"):
@@ -988,10 +1267,15 @@ class MagiPattern:
     def sharded_tables(self):
         from ._common import sharded_plan_tables
 
-        return {
+        tables = {
             k: sharded_plan_tables(p, self.mesh, self.cp_axis)
             for k, p in self.plans.items()
         }
+        if self.shift_plan is not None:
+            tables["shift"] = sharded_plan_tables(
+                self.shift_plan, self.mesh, self.cp_axis
+            )
+        return tables
 
     def make_train_step(self, optimizer):
         """optax-style optimizer -> jitted (params, opt_state, batch) step."""
@@ -1059,6 +1343,10 @@ def build_magi_pattern(
     model = MagiPattern(
         cfg=cfg, mesh=mesh, plans=plans, attn_params=attn_params,
         cp_axis=cp_axis, dp_axis=dp_axis, dispatch_meta=meta,
+        shift_plan=(
+            make_shift_plan(meta, cu, cfg.shift_taps)
+            if cfg.shift_taps else None
+        ),
     )
     telemetry.record_model_loop(cfg.n_loops, cfg.n_layers)
     if cfg.attn_form == LATENT:

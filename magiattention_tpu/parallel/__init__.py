@@ -13,7 +13,10 @@ shift in :func:`roll` (``roll(x, meta, shift)`` vs the reference's
 ``roll(x, shift, ...)``) — check each docstring when porting a call
 site."""
 
-from .dispatch import dispatch, position_ids, roll, undispatch
+from .dispatch import (
+    ShiftPlan, dispatch, make_shift_plan, position_ids, roll, shift_local,
+    shift_valid, undispatch,
+)
 from .dist_attn import (
     DistAttnPlan,
     build_dist_attn_plan,
@@ -48,10 +51,14 @@ __all__ = [
     "dist_attn_local",
     "make_attn_params",
     "make_dist_attn_fn",
+    "make_shift_plan",
     "position_ids",
     "roll",
     "roll_func",
     "roll_simple_func",
+    "ShiftPlan",
+    "shift_local",
+    "shift_valid",
     "undispatch",
     "undispatch_func",
 ]

@@ -11,6 +11,8 @@ reduce-scatter fall out of gather transposition automatically).
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -287,3 +289,220 @@ def _shard_roll_apply(
         axis_names=set(names),
     )
     return fn(x, *tabs)
+
+
+# ---------------------------------------------------------------------------
+# the forward shift along a document, local form
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class _RowExchange:
+    """Host tables of one gather with an exchange: output ``o``, slot
+    ``n`` takes row ``src`` of input ``inputs[o]`` (any rank's) or zero.
+    ``sel`` [cp, outputs, shard]: -1 zero; below ``shard`` the rank's own
+    row; from ``shard`` on, ``shard +`` the row's place in the receive
+    buffer. ``send_idx`` / ``send_input`` [cp, cp * width]: the rows a
+    rank sends, ``width`` a destination (each row once, whatever the
+    outputs that read it). ``offsets[o]``: where every row output ``o``
+    reads is the rank's own at one constant offset (the slot ``d``
+    before), that offset: the gather is then a slice."""
+
+    inputs: tuple[int, ...]
+    sel: np.ndarray
+    send_idx: np.ndarray
+    send_input: np.ndarray
+    width: int
+    sent: int  # rows that cross ranks, all ranks'
+    offsets: tuple[int | None, ...]
+
+    @property
+    def tables(self):
+        return (self.sel, self.send_idx, self.send_input)
+
+
+def _row_exchange(cp: int, shard: int, outputs) -> _RowExchange:
+    """``outputs``: (input index, src [cp * shard] dispatch slot or -1)."""
+    n = cp * shard
+    slots = np.arange(n, dtype=np.int64)
+    dst_rank = slots // shard
+    sel = np.full((len(outputs), n), -1, np.int64)
+    remote, offsets = [], []
+    for o, (i, src) in enumerate(outputs):
+        valid = src >= 0
+        local = valid & (src // shard == dst_rank)
+        sel[o, local] = src[local] % shard
+        far = np.flatnonzero(valid & ~local)
+        remote.append(np.stack(
+            [dst_rank[far], src[far] // shard, np.full(far.size, i),
+             src[far] % shard, np.full(far.size, o), far], axis=1,
+        ))
+        back = np.unique((slots - src)[valid])
+        offsets.append(
+            int(back[0]) if back.size == 1 and not far.size else None
+        )
+    remote = np.concatenate(remote)
+    width = sent = 0
+    send_idx = np.zeros((cp, cp, 0), np.int64)
+    send_input = send_idx
+    if remote.size:
+        # a row is sent once: (destination, source, input, row), in the
+        # order sender and receiver share
+        rows, inverse = np.unique(remote[:, :4], axis=0, return_inverse=True)
+        pair = rows[:, 0] * cp + rows[:, 1]
+        first = np.searchsorted(pair, pair, side="left")
+        place = np.arange(rows.shape[0]) - first  # inside its pair's group
+        width, sent = int(place.max()) + 1, rows.shape[0]
+        send_idx = np.zeros((cp, cp, width), np.int64)
+        send_input = np.zeros((cp, cp, width), np.int64)
+        send_idx[rows[:, 1], rows[:, 0], place] = rows[:, 3]
+        send_input[rows[:, 1], rows[:, 0], place] = rows[:, 2]
+        # after the all-to-all rank d holds source s's rows at s * width
+        got = (rows[:, 1] * width + place)[inverse.reshape(-1)]
+        sel[remote[:, 4], remote[:, 5]] = shard + got
+    return _RowExchange(
+        inputs=tuple(i for i, _ in outputs),
+        sel=sel.reshape(len(outputs), cp, shard).transpose(1, 0, 2)
+        .astype(np.int32),
+        send_idx=send_idx.reshape(cp, cp * width).astype(np.int32),
+        send_input=send_input.reshape(cp, cp * width).astype(np.int32),
+        width=width,
+        sent=sent,
+        offsets=tuple(offsets),
+    )
+
+
+def _exchange_rows(xs, ex: _RowExchange, tables, axis_name):
+    """One output an entry of ``ex.inputs`` from this rank's ``xs`` (each
+    [shard, ...]) and every other rank's, in one all-to-all."""
+    from ..utils.instrument import named_scope
+
+    sel, send_idx, send_input = (t[0] for t in tables)
+    shard = xs[0].shape[0]
+    received = None
+    if ex.width:
+        send = jnp.take(xs[0], send_idx, axis=0)
+        for i, x in enumerate(xs[1:], 1):
+            mine = (send_input == i).reshape((-1,) + (1,) * (x.ndim - 1))
+            send = jnp.where(mine, jnp.take(x, send_idx, axis=0), send)
+        cp = send.shape[0] // ex.width
+        with named_scope("magi_shift_a2a"):
+            received = jax.lax.all_to_all(
+                send.reshape((cp, ex.width) + send.shape[1:]), axis_name,
+                split_axis=0, concat_axis=0, tiled=False,
+            ).reshape(send.shape)
+    outs = []
+    for o, i in enumerate(ex.inputs):
+        x, s = xs[i], sel[o]
+        at = s.reshape((shard,) + (1,) * (x.ndim - 1))
+        if ex.offsets[o] is not None:  # the rank's own rows, one offset
+            rows = jnp.roll(x, ex.offsets[o], axis=0)
+        else:
+            rows = jnp.take(x, jnp.clip(s, 0, shard - 1), axis=0)
+            if received is not None:
+                far = jnp.take(
+                    received, jnp.clip(s - shard, 0, received.shape[0] - 1),
+                    axis=0,
+                )
+                rows = jnp.where(at >= shard, far, rows)
+        outs.append(jnp.where(at >= 0, rows, jnp.zeros((), x.dtype)))
+    return outs
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class ShiftPlan:
+    """The host side of :func:`shift_local` for one dispatch and one set
+    of documents: the forward exchange (a tap an output) and its
+    transpose's (a tap's cotangent an input)."""
+
+    taps: tuple[int, ...]
+    documents: int
+    fwd: _RowExchange
+    bwd: _RowExchange
+
+    @property
+    def remote_rows(self) -> int:
+        """The rows one forward application brings from another rank, all
+        ranks', a row once whatever the taps that read it."""
+        return self.fwd.sent
+
+    def device_tables(self):
+        """Arrays with a leading ``cp`` dimension, to be sharded on the
+        cp axis as a plan's tables are."""
+        return tuple(jnp.asarray(t) for t in self.fwd.tables + self.bwd.tables)
+
+
+def make_shift_plan(
+    meta: DispatchMeta, cu_seqlens, taps=(1, 2)
+) -> ShiftPlan:
+    """Plan ``y_j[p] = x[p - j]`` for every ``j`` of ``taps`` (positive:
+    a token reads its predecessors) along the GLOBAL sequence of a
+    dispatched tensor, zero where ``p - j`` falls before the first token
+    of ``p``'s document (``cu_seqlens``: the documents' cumulative
+    lengths from 0 to ``meta.total_seqlen``). A rank's chunks need not be
+    neighbours, so a chunk's first rows read the rank that holds the
+    chunk before; every tap's rank-crossing rows ride one exchange."""
+    from .. import telemetry
+
+    taps = tuple(int(j) for j in taps)
+    if not taps or min(taps) < 1:
+        raise ValueError(f"taps {taps}: a shift reads predecessors, j >= 1")
+    cu = np.asarray(cu_seqlens, np.int64)
+    total = meta.total_seqlen
+    if cu[0] != 0 or cu[-1] != total or (np.diff(cu) < 1).any():
+        raise ValueError(
+            f"cu_seqlens {cu.tolist()} do not cut [0, {total}) into documents"
+        )
+    perm = meta.perm_idx.astype(np.int64)
+    unperm = meta.unperm_idx.astype(np.int64)
+    real = perm < total
+    p = np.where(real, perm, 0)
+    doc = np.searchsorted(cu, p, side="right") - 1
+    start, end = cu[doc], cu[doc + 1]
+
+    def slot_of(q, ok):
+        return np.where(real & ok, unperm[np.where(ok, q, 0)], -1)
+
+    fwd = _row_exchange(meta.cp_size, meta.shard_seqlen, [
+        (0, slot_of(p - j, p - j >= start)) for j in taps
+    ])
+    bwd = _row_exchange(meta.cp_size, meta.shard_seqlen, [
+        (a, slot_of(p + j, p + j < end)) for a, j in enumerate(taps)
+    ])
+    plan = ShiftPlan(taps=taps, documents=cu.size - 1, fwd=fwd, bwd=bwd)
+    telemetry.record_shift(
+        rows=plan.remote_rows, taps=taps, documents=plan.documents
+    )
+    return plan
+
+
+def shift_valid(tables):
+    """Beside :func:`shift_local`, from the same ``tables``: bool [taps,
+    shard], whether this rank's slot has a predecessor ``j`` back inside
+    its document."""
+    return tables[0][0] >= 0
+
+
+def shift_local(x, tables, plan: ShiftPlan, axis_name):
+    """Inside a ``shard_map`` over the cp axis: this rank's rows ``x``
+    [shard, ...] of a dispatched tensor -> one array a tap of
+    ``plan.taps``, ``y_j[p] = x[p - j]`` in global order, zero at a
+    document's first ``j`` tokens (:func:`make_shift_plan`). ``tables``:
+    this rank's slices of ``plan.device_tables()``. Differentiable: the
+    backward is the shift by ``-j`` with the same zeroing, one exchange
+    again."""
+    n_fwd = len(plan.fwd.tables)
+
+    @jax.custom_vjp
+    def shift(x, tables):
+        return tuple(_exchange_rows([x], plan.fwd, tables[:n_fwd], axis_name))
+
+    def shift_fwd(x, tables):
+        return shift(x, tables), tables
+
+    def shift_bwd(tables, dys):
+        parts = _exchange_rows(list(dys), plan.bwd, tables[n_fwd:], axis_name)
+        return sum(parts[1:], parts[0]), None
+
+    shift.defvjp(shift_fwd, shift_bwd)
+    return shift(x, tuple(tables))

@@ -73,6 +73,7 @@ from .collectors import (  # noqa: F401
     record_model_attn_plan,
     record_mla_kv_cast_width,
     record_model_loop,
+    record_shift,
     record_moe_load,
     record_dispatch_solution,
     record_dynamic_solution,
@@ -360,6 +361,7 @@ __all__ = [
     "record_model_attn_plan",
     "record_mla_kv_cast_width",
     "record_model_loop",
+    "record_shift",
     "record_moe_load",
     "record_dispatch_solution",
     "record_dynamic_solution",

@@ -145,6 +145,10 @@ M_MLA_KV_CAST_WIDTH = "magi_mla_kv_cast_width"
 # decoder is a looped one
 M_MODEL_LOOP_STEPS = "magi_model_loop_steps"
 M_MODEL_LAYER_APPLICATIONS = "magi_model_layer_applications"
+# counter — rows a forward shift along the documents brings from another
+# rank, all ranks' and all taps' (each row once), added where a shift is
+# planned (parallel/dispatch.make_shift_plan): 0 at cp = 1
+M_SHIFT_REMOTE_ROWS = "magi_shift_remote_rows_total"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1182,6 +1186,17 @@ def record_model_loop(n_loops: int, n_layers: int) -> None:
     reg = get_registry()
     reg.gauge_set(M_MODEL_LOOP_STEPS, float(n_loops))
     reg.gauge_set(M_MODEL_LAYER_APPLICATIONS, float(n_loops * n_layers))
+
+
+def record_shift(*, rows: int, taps, documents: int) -> None:
+    """One planned forward shift (``parallel/dispatch.make_shift_plan``,
+    host side): the rows one application brings from another rank."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_SHIFT_REMOTE_ROWS, rows)
+    _marker_event(
+        "shift", {"rows": rows, "taps": list(taps), "documents": documents}
+    )
 
 
 def record_mla_kv_cast_width(*, expanded: int, latent: int) -> None:

@@ -446,6 +446,74 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
     assert text.count("tpu_custom_call") == 4 * cfg.n_layers
 
 
+def test_cca_train_step_at_two_key_value_heads(topo):
+    """ZAYA1-8B's step at the published widths (8 query / 2 key-value
+    heads of 128, both convolutions, top-1 of 16 experts behind the MLP
+    router, the tied embedding's slice), 2 of its layers, at the check's
+    4,096 tokens: the flex kernels compile at two key-value heads on the
+    rung the cell's 16,384-token mask gets too, the shift at cp = 1 is a
+    slice (no gather under ``magi_cca_mix``), and the router's state
+    crosses ``checkpoint`` inside ``shard_map``."""
+    import json
+
+    import optax
+
+    from benchmarks import masks, trace_reduce
+    from magiattention_tpu.models.pattern import (
+        build_magi_pattern, init_pattern_params, zaya_config,
+    )
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs", "zaya1-8b.json")) as f:
+        hf = json.load(f)
+    cfg = zaya_config(
+        dict(hf, num_hidden_layers=2), remat=True,
+        expert_range=tuple(hf["experts_here"]), vocab_size=hf["vocab_here"],
+    )
+    with open(os.path.join(
+        here, "benchmarks", "traffic", "train-16k-packed-cca.json"
+    )) as f:
+        tr = json.load(f)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
+    rungs = []
+    for spec, total in ((tr["check_mask"], 4096), (tr["mask"], 16384)):
+        mask = masks.build_mask(spec, total, index=0)
+        model, _ = build_magi_pattern(
+            cfg, mesh, mask.cu_seqlens, chunk_size=512, interpret=False
+        )
+        (p,) = model.attn_params.values()
+        rungs.append((p.block_q, p.block_k, p.head_block, p.grid))
+        if total == 4096:
+            check = model
+    assert rungs[0] == rungs[1] == (128, 512, 8, "sparse")
+    assert check.shift_plan.fwd.offsets == (1, 2)
+    assert check.shift_plan.bwd.offsets == (-1, -2)
+    opt = optax.adamw(3e-4)
+    rep = NamedSharding(mesh, P())
+    params = jax.eval_shape(
+        lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)
+    )
+    state = jax.eval_shape(opt.init, params)
+    params, state = jax.tree.map(
+        lambda s: _on(rep, s.shape, s.dtype), (params, state)
+    )
+    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, 4096), jnp.int32)
+    text = (
+        check.make_train_step(opt)
+        .lower(params, state, batch, batch, batch)
+        .compile()
+        .as_text()
+    )
+    scopes = trace_reduce.hlo_scopes(text)
+    # a layer's forward, remat's forward, dq and dkv (the grouped matmuls
+    # are tpu_custom_calls too: count the flex kernels by name)
+    flex = [n for n in scopes if n.startswith("magi_flex_")]
+    assert len(flex) == 4 * cfg.n_layers, flex
+    mix = [s for s in scopes.values() if "magi_cca_mix" in s]
+    assert mix and not [s for s in mix if s.endswith("/gather")]
+    assert any("magi_moe_router" in s for s in scopes.values())
+
+
 @pytest.mark.parametrize("cp", [1, 4])
 def test_keyed_kernels_carry_role_names(topo, cp, monkeypatch):
     """The keyed path's forward+backward program, as the benchmark's

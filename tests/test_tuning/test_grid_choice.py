@@ -2,7 +2,7 @@
 table sets it already walks, the steps the row-major and the compact grid
 would launch over forward, dq and dkv, prices them with the two per-step
 costs of ``tuning/cost_model.py`` and sets ``FlexAttnParams.grid``. Host
-only: the plans of the benchmark's ten cells are built from their own
+only: the plans of the benchmark's eleven cells are built from their own
 traffic files, nothing runs on a device."""
 
 import dataclasses
@@ -138,6 +138,13 @@ CELLS = {
     "ouro26b-train-16k-looped": [
         ((256, 512, 8), "sparse", 2 * 64 * 9 + 33 * 17, 3 * 216, 3 * 213),
     ],
+    # five long documents at 8 query / 2 key-value heads of 128 (ISSUE 39):
+    # GQA group 4, Mistral's, so (128, 512, 8) and the whole head group a
+    # step; the 8,192-token document's last q block meets 16 k blocks and
+    # its first k block 64 q blocks; 764 entries padded to 768
+    "zaya1-train-16k-traces": [
+        ((128, 512, 8), "sparse", 2 * 128 * 16 + 33 * 64, 3 * 768, 3 * 764),
+    ],
 }
 
 
@@ -176,7 +183,7 @@ def test_every_cells_decision_says_which_roof_binds_its_rung(
     telemetry_on, cell, monkeypatch
 ):
     """ISSUE 35: the ``autotune_decision`` event of every plan of every cell
-    carries what the operand-bytes term read. In the eight cells at GQA
+    carries what the operand-bytes term read. In the nine cells at GQA
     group 4 or 8 nothing streams: ``bound`` is ``mxu``, no rung was passed
     over, and at the chip's own peaks the forward's HBM time is under its
     MXU time. In the two at group 1 the chosen rung is MXU-bound too, one
