@@ -27,6 +27,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.custom_derivatives import SymbolicZero
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -65,7 +66,9 @@ _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 _BWD_HB_LIVE_BYTES = _VMEM_LIMIT_BYTES // 2
 
 
-def _flex_pallas_call(role: str, heads_per_step: int, grid: str, body, **kwargs):
+def _flex_pallas_call(
+    role: str, heads_per_step: int, grid: str, body, form=None, **kwargs
+):
     """Where every flex ``pallas_call`` is built (trace time). The call is
     named by role, not by grid kind: magi_flex_fwd_kernel,
     magi_flex_dq_kernel, magi_flex_dkv_kernel. The name enters the custom
@@ -75,10 +78,14 @@ def _flex_pallas_call(role: str, heads_per_step: int, grid: str, body, **kwargs)
     counted with the q heads one grid step takes and the grid it walks
     (``magi_flex_kernel_build_total{kernel=, heads_per_step=, grid=}``), so
     a snapshot says which of the per-head and head-batched forms ran, and
-    on which of :data:`GRID_KINDS`."""
+    on which of :data:`GRID_KINDS`. ``form``: the labels that say in which
+    form a side operand crosses this kernel's boundary (the forward's
+    ``stats=compact|lanes``, dq's ``delta=kernel``; :func:`stats_form`)."""
     from .. import telemetry
 
-    telemetry.record_flex_kernel_build(role, heads_per_step, grid)
+    telemetry.record_flex_kernel_build(
+        role, heads_per_step, grid, **(form or {})
+    )
     return pl.pallas_call(body, name=f"magi_flex_{role}_kernel", **kwargs)
 
 
@@ -539,9 +546,12 @@ def _fwd_init(m_scr, l_scr, acc_scr):
 
 
 def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
-    """What a q block writes once its last entry is done: (out f32
+    """What a q block holds once its last entry is done: (out f32
     (..., rows, d), lse (..., rows, LANES), rowmax (..., rows, LANES)), the
-    two statistics replicated over lanes as the outputs store them.
+    two statistics replicated over lanes, which is the form the state is
+    kept in and the form dq and dkv read lse in (the backward's residual).
+    What is returned to the caller crosses the boundary with rows along
+    lanes instead, 1/128 of the bytes (:func:`_write_stats`).
     ``sinks``: the rows' sink logits, (..., rows, 1) or one scalar, or None.
 
     The lazy row sum is reduced across lanes here, and the public
@@ -569,6 +579,61 @@ def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
     return out, lse, jnp.where(live, m, NEG_INF)
 
 
+def stats_form(block_q: int) -> str:
+    """The form in which the per-row statistics a caller reads (lse, the
+    row maximum, the lse cotangent) cross the kernels' boundary:
+    ``"compact"``, rows along lanes, 4 bytes a row, where a q block is
+    whole vregs of rows; ``"lanes"``, every row's value in all 128 lanes
+    as the kernels keep it, 512 bytes a row, for small test blocks."""
+    return "compact" if block_q % LANES == 0 else "lanes"
+
+
+def _diag():
+    row = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    return row == jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+
+
+def _store_rows_along_lanes(ref, stats):
+    """Write the lane-replicated (heads, bq, LANES) statistics ``stats``
+    to one ``(1, 1, len(stats), heads, bq)`` block, rows along lanes: of
+    every (128, 128) tile the diagonal, reduced over sublanes against
+    ``-inf``. A select and a maximum with ``-inf``: each value arrives as
+    it is, ``-inf`` rows too."""
+    diag = _diag()
+    for n, x in enumerate(stats):
+        for h in range(x.shape[0]):
+            for c in range(0, x.shape[1], LANES):
+                ref[0, 0, n, h : h + 1, c : c + LANES] = jnp.max(
+                    jnp.where(diag, x[h, c : c + LANES, :], NEG_INF),
+                    axis=0,
+                    keepdims=True,
+                )
+
+
+def _load_rows_as_column(ref, h: int, c: int, diag):
+    """The other way: rows ``c .. c + 128`` of head ``h`` of a
+    ``(1, 1, 1, heads, bq)`` block with rows along lanes, as a (128, 1)
+    column, the same values. ``diag``: :func:`_diag`'s."""
+    row = ref[0, 0, 0, h : h + 1, c : c + LANES]
+    return jnp.max(jnp.where(diag, row, NEG_INF), axis=1, keepdims=True)
+
+
+def _write_stats(lse, rowmax, refs, compact: bool):
+    """Store a q block's (heads, bq, LANES) lse and row maximum. ``lanes``
+    form: as they are, into two (heads, bq, LANES) blocks. ``compact``
+    form: both with rows along lanes into ONE ``(1, 1, 2, heads, bq)``
+    block (on the compact grid every blocked operand costs a table lookup
+    a step: 0.9 ms a call of 233 k steps, docs/block_sparse.md), and lse
+    once more as it is where a second block asks for it: the residual dq
+    and dkv read, which only the differentiated forward writes."""
+    if not compact:
+        refs[0][...], refs[1][...] = lse, rowmax
+        return
+    _store_rows_along_lanes(refs[0], (lse, rowmax))
+    for ref in refs[1:]:
+        ref[...] = lse
+
+
 def _fwd_kernel_hb(
     qblk,
     kblk,
@@ -582,14 +647,10 @@ def _fwd_kernel_hb(
     v_ref,
     sink_ref,
     out_ref,
-    lse_ref,
-    rowmax_ref,
-    m_scr,  # (HB, G*bq, LANES)
-    l_scr,
-    acc_scr,  # (HB, G*bq, d)
-    *,
+    *refs,  # statistics (_write_stats), then m (HB, G*bq, LANES), l, acc
     params: FlexAttnParams,
     group: int,
+    compact: bool,
 ):
     """Head-batched forward: HB kv heads x their G q heads per grid step.
 
@@ -607,6 +668,7 @@ def _fwd_kernel_hb(
     place of ``-inf`` inside a step) and :func:`_fwd_finalize`, where the
     row sum is reduced and ``-inf`` comes back for rows no entry covers.
     """
+    *stat_refs, m_scr, l_scr, acc_scr = refs
     bq, bk = params.block_q, params.block_k
     hbg = q_ref.shape[0]
     hb = k_ref.shape[0]
@@ -644,8 +706,10 @@ def _fwd_kernel_hb(
         out_ref[...] = out.reshape(hbg, bq, out_ref.shape[2]).astype(
             out_ref.dtype
         )
-        lse_ref[...] = lse.reshape(hbg, bq, LANES)
-        rowmax_ref[...] = rowmax.reshape(hbg, bq, LANES)
+        _write_stats(
+            lse.reshape(hbg, bq, LANES), rowmax.reshape(hbg, bq, LANES),
+            stat_refs, compact,
+        )
 
 
 def _fwd_kernel(
@@ -661,17 +725,14 @@ def _fwd_kernel(
     v_ref,
     sink_ref,
     out_ref,
-    lse_ref,
-    rowmax_ref,
-    m_scr,  # (bq, LANES)
-    l_scr,
-    acc_scr,  # (bq, d)
-    *,
+    *refs,  # statistics (_write_stats), then m (bq, LANES), l, acc (bq, d)
     params: FlexAttnParams,
+    compact: bool,
 ):
     """Per-head forward: one q head a grid step, on both grids; the
     softmax state is the head-batched body's (:func:`_fwd_update`,
     :func:`_fwd_finalize`)."""
+    *stat_refs, m_scr, l_scr, acc_scr = refs
     bq, bk = params.block_q, params.block_k
     h = pl.program_id(0)
     w = _Walk(params.grid, qblk, rs, rc)
@@ -696,14 +757,46 @@ def _fwd_kernel(
         sink = sink_ref[h, 0] if params.has_sink else None
         out, lse, rowmax = _fwd_finalize(m_scr, l_scr, acc_scr, sink)
         out_ref[0] = out.astype(out_ref.dtype)
-        # lane-replicated [bq, LANES] layout (Mosaic (8,128)-tiling legal; the
-        # same convention as jax's own TPU flash-attention l/m outputs)
-        lse_ref[0] = lse
-        rowmax_ref[0] = rowmax
+        _write_stats(lse[None], rowmax[None], stat_refs, compact)
 
 
-def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
-    """q [hq, tqp, d]; k/v [hk, tkp, d]; tables from fwd_tables().
+def _compact_spec(n: int, hbg: int, bq: int, qmap):
+    """Block of ``n`` per-row statistics with rows along lanes: the q
+    block's ``(n, HBG, bq)``, where the (heads, q block, 0) map of the
+    q-side operands points. The block's last two dimensions are the
+    array's, which is legal at any head block."""
+    return pl.BlockSpec(
+        (1, 1, n, hbg, bq), lambda *args: (*qmap(*args)[:2], 0, 0, 0)
+    )
+
+
+def _rows_from_compact(x, hq: int, tqp: int):
+    """(hq / HBG, nq, n, HBG, bq) -> n arrays [hq, tqp]."""
+    x = jnp.transpose(x, (2, 0, 3, 1, 4))
+    return tuple(x.reshape(x.shape[0], hq, tqp))
+
+
+def _rows_to_compact(x, hbg: int, bq: int):
+    """[hq, tqp] -> (hq / HBG, nq, 1, HBG, bq)."""
+    hq, tqp = x.shape
+    x = x.reshape(hq // hbg, hbg, tqp // bq, 1, bq)
+    return jnp.transpose(x, (0, 2, 3, 1, 4))
+
+
+def _fwd_pallas(
+    q, k, v, sink2d, tables, params: FlexAttnParams, residual: bool = False
+):
+    """q [hq, tqp, d]; k/v [hk, tkp, d]; tables from fwd_tables(). Returns
+    (out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp], and lse replicated
+    over lanes [hq, tqp, LANES] if ``residual``: what dq and dkv read, else
+    None).
+
+    The two statistics leave the kernel in :func:`stats_form`'s form. In
+    the ``compact`` one the kernel writes one ``(hq / HBG, nq, 2, HBG, bq)``
+    array, rows along lanes (:func:`_compact_spec`), which XLA turns to
+    two [hq, tqp] on 4 bytes a row, and writes the lane-replicated lse
+    only as the residual. In the ``lanes`` one it writes both replicated
+    and XLA takes lane 0.
 
     Row-major grid (hq/HBG, nq, steps): the q/out/lse index maps are
     static in the inner dimension; dead steps (j >= row count) clamp the K
@@ -720,18 +813,22 @@ def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
     E = qblk.shape[0]
     nq = tqp // bq
     rs, rc = _row_tables(qblk, nq)
+    form = stats_form(bq)
+    compact = form == "compact"
 
     if hbg > 1:
         # head-batched: HB kv heads and their G q heads each a step
         _check_head_block(hbg, hq, group)
         hb = hbg // group
-        body = functools.partial(_fwd_kernel_hb, params=params, group=group)
+        body = functools.partial(
+            _fwd_kernel_hb, params=params, group=group, compact=compact
+        )
         rows = (hb, group * bq)
         k_head = lambda h: h  # noqa: E731
         cost = None
     else:
         hb = 1
-        body = functools.partial(_fwd_kernel, params=params)
+        body = functools.partial(_fwd_kernel, params=params, compact=compact)
         rows = (bq,)
         k_head = lambda h: h // group  # noqa: E731
         cost = pl.CostEstimate(
@@ -743,6 +840,19 @@ def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
         params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
     )
 
+    lanes_shape = jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32)
+    if compact:
+        stat_specs = [_compact_spec(2, hbg, bq, qmap)]
+        stat_shapes = [
+            jax.ShapeDtypeStruct((hq // hbg, nq, 2, hbg, bq), jnp.float32)
+        ]
+        if residual:
+            stat_specs.append(pl.BlockSpec((hbg, bq, LANES), qmap))
+            stat_shapes.append(lanes_shape)
+    else:
+        stat_specs = [pl.BlockSpec((hbg, bq, LANES), qmap) for _ in range(2)]
+        stat_shapes = [lanes_shape] * 2
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -752,32 +862,34 @@ def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec(memory_space=pltpu.SMEM),  # sink [hq, 1]
         ],
-        out_specs=[
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-        ],
+        out_specs=[pl.BlockSpec((hbg, bq, d), qmap), *stat_specs],
         scratch_shapes=[
             pltpu.VMEM((*rows, LANES), jnp.float32),
             pltpu.VMEM((*rows, LANES), jnp.float32),
             pltpu.VMEM((*rows, d), jnp.float32),
         ],
     )
-    return _flex_pallas_call(
+    out, *stats = _flex_pallas_call(
         "fwd",
         hbg,
         params.grid,
         body,
+        form={"stats": form},
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
+            *stat_shapes,
         ],
         interpret=params.interpret,
         compiler_params=_compiler_params(*semantics),
         cost_estimate=cost,
     )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
+    with named_scope("magi_layout"):
+        if compact:
+            lse, rowmax = _rows_from_compact(stats[0], hq, tqp)
+            return out, lse, rowmax, stats[1] if residual else None
+        lse_lanes, rowmax_lanes = stats
+        return out, lse_lanes[:, :, 0], rowmax_lanes[:, :, 0], lse_lanes
 
 
 # ---------------------------------------------------------------------------
@@ -799,9 +911,11 @@ def _bwd_p_ds(
     ``hb=HB``: the head-batched kernels' blocks, the q-side ones
     (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``.
 
-    ``lse`` and ``delta`` arrive replicated over the 128 lanes
-    (:func:`_fwd_finalize` writes lse so, ``_flex_attn_core_bwd`` builds
-    delta so) and are used at that shape, as the forward uses its running
+    ``lse`` and ``delta`` arrive replicated over the 128 lanes (the
+    differentiated forward writes lse so, its residual; dq makes delta so
+    in its block's first step, :func:`_delta_init`, reads it back from its
+    own output block and hands it to dkv) and are used at that shape, as
+    the forward uses its running
     maximum (:func:`_probs`): the (rows, bk) tiles ``s`` and ``dP`` are
     taken in static, vreg-aligned slices of 128 lanes, each of the shape
     of the two statistics, so nothing is cut to a one-lane column and
@@ -866,6 +980,42 @@ def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
     return hbg if live <= _BWD_HB_LIVE_BYTES else 1
 
 
+def _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact: bool):
+    """A q block's ``delta = sum(dO * out) - dlse`` per row, replicated
+    over lanes into dq's second output block (heads, bq, LANES): made in
+    the block's first step, where dO already is and ``out`` is brought
+    beside it, read by every step of the block (:func:`_bwd_p_ds`) and,
+    from HBM, by dkv. The lse cotangent folds into delta: with out =
+    softmax(s) @ v and lse = logsumexp(s), dL/ds = p * (dP - (delta -
+    dlse)), which is what makes multi-stage lse-merging differentiable
+    with stage-local lse. ``dlse_ref``: the cotangent's operand if there
+    is one, as a list: none where it is a symbolic zero; a
+    ``(1, 1, 1, heads, bq)`` block, rows along lanes, turned to columns
+    here once a block (``dlse_compact``); or one replicated over lanes by
+    XLA (small test blocks, :func:`stats_form`)."""
+    delta = jnp.sum(
+        do_ref[...].astype(jnp.float32) * out_ref[...].astype(jnp.float32),
+        axis=-1,
+        keepdims=True,
+    )
+    if not dlse_ref:
+        delta_ref[...] = jnp.broadcast_to(delta, delta_ref.shape)
+        return
+    (dlse_ref,) = dlse_ref
+    if not dlse_compact:
+        delta_ref[...] = delta - dlse_ref[...]
+    else:
+        heads, bq, _ = delta_ref.shape
+        diag = _diag()
+        for h in range(heads):
+            for c in range(0, bq, LANES):
+                delta_ref[h, c : c + LANES, :] = jnp.broadcast_to(
+                    delta[h, c : c + LANES]
+                    - _load_rows_as_column(dlse_ref, h, c, diag),
+                    (LANES, LANES),
+                )
+
+
 def _dq_kernel(
     qblk,
     kblk,
@@ -879,12 +1029,12 @@ def _dq_kernel(
     v_ref,
     do_ref,
     lse_ref,
-    delta_ref,
-    dq_ref,
-    dq_scr,
-    *,
+    out_ref,
+    *refs,  # [dlse], dq, delta (1, bq, LANES), the dq scratch
     params: FlexAttnParams,
+    dlse_compact: bool,
 ):
+    *dlse_ref, dq_ref, delta_ref, dq_scr = refs
     bq, bk = params.block_q, params.block_k
     w = _Walk(params.grid, qblk, rs, rc)
     i, e = w.i, w.e
@@ -892,6 +1042,7 @@ def _dq_kernel(
     @pl.when(w.first())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact)
 
     @w.when_live
     def _compute():
@@ -913,7 +1064,7 @@ def _dq_kernel(
 
     @pl.when(w.last())
     def _write():
-        dq_ref[0] = dq_scr[...]
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dq_kernel_hb(
@@ -929,17 +1080,18 @@ def _dq_kernel_hb(
     v_ref,
     do_ref,  # (HBG, bq, d)
     lse_ref,  # (HBG, bq, LANES)
-    delta_ref,
-    dq_ref,  # (HBG, bq, d)
-    dq_scr,  # (HB, G*bq, d)
-    *,
+    out_ref,  # (HBG, bq, d)
+    *refs,  # [dlse], dq (HBG, bq, d), delta (HBG, bq, LANES), the scratch
     params: FlexAttnParams,
     group: int,
+    dlse_compact: bool,
 ):
     """Head-batched dq, the layout of :func:`_fwd_kernel_hb` on either
     grid (:class:`_Walk` over the q-major table): one K/V tile serves the
     HB kv heads' G q heads each, so QK^T, dO V^T and dS K are one batched
-    MXU call each over (HB, G*bq) stacked rows."""
+    MXU call each over (HB, G*bq) stacked rows. The scratch is
+    (HB, G*bq, d) float32."""
+    *dlse_ref, dq_ref, delta_ref, dq_scr = refs
     bq, bk = params.block_q, params.block_k
     hb = k_ref.shape[0]
     w = _Walk(params.grid, qblk, rs, rc)
@@ -948,6 +1100,7 @@ def _dq_kernel_hb(
     @pl.when(w.first())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact)
 
     @w.when_live
     def _compute():
@@ -966,10 +1119,14 @@ def _dq_kernel_hb(
 
     @pl.when(w.last())
     def _write():
-        dq_ref[...] = dq_scr[...].reshape(dq_ref.shape)
+        dq_ref[...] = dq_scr[...].reshape(dq_ref.shape).astype(dq_ref.dtype)
 
 
-def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
+def _dq_pallas(q, k, v, do, lse, out, dlse, tables, params: FlexAttnParams):
+    """(dq [hq, tqp, d] in q's dtype, delta [hq, tqp, LANES] float32).
+    ``out``: the forward's, head-major, read once a q block for delta
+    (:func:`_delta_init`). ``dlse``: the lse cotangent [hq, tqp], or None
+    for a symbolic zero; it crosses in :func:`stats_form`'s form."""
     qblk, kblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk = k.shape[0]
@@ -978,23 +1135,41 @@ def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     bq, bk = params.block_q, params.block_k
     nq = tqp // bq
     rs, rc = _row_tables(qblk, nq)
+    dlse_compact = stats_form(bq) == "compact"
 
     # grid (hq/HBG, nq, steps), or the compact (hq/HBG, E)
     if hbg > 1:
         # head-batched, the forward's layout
         hb = hbg // group
-        body = functools.partial(_dq_kernel_hb, params=params, group=group)
+        body = functools.partial(
+            _dq_kernel_hb, params=params, group=group,
+            dlse_compact=dlse_compact,
+        )
         scratch = pltpu.VMEM((hb, group * bq, d), jnp.float32)
         k_head = lambda h: h  # noqa: E731
     else:
         hb = 1
-        body = functools.partial(_dq_kernel, params=params)
+        body = functools.partial(
+            _dq_kernel, params=params, dlse_compact=dlse_compact
+        )
         scratch = pltpu.VMEM((bq, d), jnp.float32)
         k_head = lambda h: h // group  # noqa: E731
     grid, qmap, kmap, semantics = _walk_grid(
         params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
     )
 
+    dlse_in, dlse_specs = [], []
+    if dlse is not None:
+        with named_scope("magi_bwd_delta"):
+            dlse = dlse.astype(jnp.float32)
+            if dlse_compact:
+                dlse_in = [_rows_to_compact(dlse, hbg, bq)]
+                dlse_specs = [_compact_spec(1, hbg, bq, qmap)]
+            else:
+                dlse_in = [
+                    jnp.broadcast_to(dlse[:, :, None], (hq, tqp, LANES))
+                ]
+                dlse_specs = [pl.BlockSpec((hbg, bq, LANES), qmap)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -1004,9 +1179,13 @@ def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
+            pl.BlockSpec((hbg, bq, d), qmap),
+            *dlse_specs,
+        ],
+        out_specs=[
+            pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
         ],
-        out_specs=pl.BlockSpec((hbg, bq, d), qmap),
         scratch_shapes=[scratch],
     )
     return _flex_pallas_call(
@@ -1014,11 +1193,15 @@ def _dq_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         hbg,
         params.grid,
         body,
+        form={"delta": "kernel"},
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hq, tqp, d), jnp.float32),
+        out_shape=[
+            jax.ShapeDtypeStruct((hq, tqp, d), q.dtype),
+            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
+        ],
         interpret=params.interpret,
         compiler_params=_compiler_params(*semantics),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
+    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, out, *dlse_in)
 
 
 # ---------------------------------------------------------------------------
@@ -1087,8 +1270,8 @@ def _dkv_kernel(
 
     @pl.when(w.last() & (g == group - 1))
     def _write():
-        dk_ref[0] = dk_scr[...]
-        dv_ref[0] = dv_scr[...]
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _dkv_kernel_hb(
@@ -1153,8 +1336,8 @@ def _dkv_kernel_hb(
 
     @pl.when(w.last())
     def _write():
-        dk_ref[...] = dk_scr[...]
-        dv_ref[...] = dv_scr[...]
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
@@ -1209,8 +1392,8 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         body,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
-            jax.ShapeDtypeStruct((hk, tkp, d), jnp.float32),
+            jax.ShapeDtypeStruct((hk, tkp, d), k.dtype),
+            jax.ShapeDtypeStruct((hk, tkp, d), v.dtype),
         ],
         interpret=params.interpret,
         compiler_params=_compiler_params(*semantics),
@@ -1228,23 +1411,33 @@ def _zero_tangents(tables):
     )
 
 
-def _fwd_dispatch(q, k, v, sink2d, ftab, params: FlexAttnParams):
+def _fwd_dispatch(
+    q, k, v, sink2d, ftab, params: FlexAttnParams, residual: bool = False
+):
     if params.grid not in GRID_KINDS:
         raise ValueError(
             f"flex-attn: params.grid={params.grid!r} must be one of "
             f"{GRID_KINDS}"
         )
-    return _fwd_pallas(q, k, v, sink2d, ftab, params)
+    return _fwd_pallas(q, k, v, sink2d, ftab, params, residual)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _flex_attn_core(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
-    return _fwd_dispatch(q, k, v, sink2d, ftab, params)
+    """(out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp]). Undifferentiated
+    (evaluation, the forward under remat) no lane-replicated statistic is
+    written at all."""
+    return _fwd_dispatch(q, k, v, sink2d, ftab, params)[:3]
 
 
 def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
-    out, lse_lanes, rowmax_lanes = _fwd_dispatch(q, k, v, sink2d, ftab, params)
-    return (out, lse_lanes, rowmax_lanes), (
+    # symbolic_zeros: every argument arrives as a CustomVJPPrimal
+    q, k, v, sink2d = q.value, k.value, v.value, sink2d.value
+    ftab, btab = (tuple(t.value for t in tab) for tab in (ftab, btab))
+    out, lse, rowmax, lse_lanes = _fwd_dispatch(
+        q, k, v, sink2d, ftab, params, residual=True
+    )
+    return (out, lse, rowmax), (
         q,
         k,
         v,
@@ -1258,23 +1451,20 @@ def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
 
 def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
     q, k, v, sink2d, out, lse_lanes, ftab, btab = residuals
-    # The lse cotangent is first-class: with out = softmax(s) @ v and
-    # lse = logsumexp(s), dL/ds = p * (dp - (delta - dlse)) — so dlse folds
-    # into the delta term. This is what makes multi-stage LSE-merging
-    # differentiable with stage-local lse (the per-stage vjp then equals the
-    # reference's global-lse backward exactly). rowmax stays non-diff.
-    dout, dlse_lanes, _dmax = grads
+    # The lse cotangent is first-class (it folds into delta, _delta_init);
+    # a model that never reads lse hands a symbolic zero, and then no
+    # operand is made for it. rowmax stays non-diff.
+    dout, dlse, _dmax = grads
     with named_scope("magi_layout"):
-        do = dout.astype(q.dtype)
-    with named_scope("magi_bwd_delta"):
-        delta = jnp.sum(
-            dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-        )
-        # lse consumers read lane 0; sum lanes to collect the full cotangent
-        dlse = dlse_lanes.astype(jnp.float32).sum(axis=-1)
-        delta_eff = delta - dlse
-        delta_lanes = jnp.broadcast_to(delta_eff[:, :, None], lse_lanes.shape)
-    dq = _dq_pallas(q, k, v, do, lse_lanes, delta_lanes, ftab, params)
+        if isinstance(dout, SymbolicZero):
+            do = jnp.zeros(out.shape, q.dtype)
+        else:
+            do = dout.astype(q.dtype)
+    if isinstance(dlse, SymbolicZero):
+        dlse = None
+    dq, delta_lanes = _dq_pallas(
+        q, k, v, do, lse_lanes, out, dlse, ftab, params
+    )
     dk, dv = _dkv_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
     with named_scope("magi_bwd_delta"):
         if params.has_sink:
@@ -1282,18 +1472,18 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
             lse = lse_lanes[:, :, 0]
             sink = sink2d[:, :1]
             w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
-            dsink = -(w * delta_eff).sum(axis=1, keepdims=True)
+            dsink = -(w * delta_lanes[:, :, 0]).sum(axis=1, keepdims=True)
             dsink2d = jnp.broadcast_to(dsink, sink2d.shape).astype(
                 sink2d.dtype
             )
         else:
             dsink2d = jnp.zeros_like(sink2d)
-    with named_scope("magi_layout"):
-        dq, dk, dv = dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
     return dq, dk, dv, dsink2d, _zero_tangents(ftab), _zero_tangents(btab)
 
 
-_flex_attn_core.defvjp(_flex_attn_core_fwd, _flex_attn_core_bwd)
+_flex_attn_core.defvjp(
+    _flex_attn_core_fwd, _flex_attn_core_bwd, symbolic_zeros=True
+)
 
 
 # ---------------------------------------------------------------------------
@@ -1367,12 +1557,12 @@ def _fwd_jnp(q, k, v, sink2d, ftab, params: FlexAttnParams):
     p = jnp.where(mask[None], jnp.exp(s - m_safe[..., None]), 0.0)
     l = p.sum(axis=-1)
     acc = jnp.einsum("hqk,hkd->hqd", p, vf.astype(acc_t))
-    return _jnp_epilogue(m, m_safe, l, acc, sink2d, params, hq, tqp)
+    return _jnp_epilogue(m, m_safe, l, acc, sink2d, params)
 
 
-def _jnp_epilogue(m, m_safe, l, acc, sink2d, params, hq, tqp):
+def _jnp_epilogue(m, m_safe, l, acc, sink2d, params):
     """Shared dense/online jnp epilogue: sink fold, uncovered rows
-    (out=0 / lse=-inf, lse=sink when has_sink), lane broadcast."""
+    (out=0 / lse=-inf, lse=sink when has_sink); lse and rowmax [hq, tqp]."""
     acc_t = m.dtype
     neg = jnp.asarray(NEG_INF, acc_t)
     if params.has_sink:
@@ -1395,11 +1585,8 @@ def _jnp_epilogue(m, m_safe, l, acc, sink2d, params, hq, tqp):
         m_tot_safe + jnp.log(jnp.where(covered, l_tot, 1.0)),
         neg,
     )
-    lse_lanes = jnp.broadcast_to(lse[..., None], (hq, tqp, LANES))
-    rowmax_lanes = jax.lax.stop_gradient(
-        jnp.broadcast_to(m[..., None], (hq, tqp, LANES))
-    ).astype(jnp.float32)
-    return out.astype(params.out_jnp_dtype), lse_lanes, rowmax_lanes
+    rowmax = jax.lax.stop_gradient(m).astype(jnp.float32)
+    return out.astype(params.out_jnp_dtype), lse, rowmax
 
 
 def _fwd_jnp_online(q, k, v, sink2d, ftab, params: FlexAttnParams):
@@ -1481,7 +1668,7 @@ def _fwd_jnp_online(q, k, v, sink2d, ftab, params: FlexAttnParams):
     # l/acc left the last step rebased to its m_new_safe, and the last
     # step's m_new IS the global max — so they are already relative to
     # m_safe here, exactly what the epilogue expects
-    return _jnp_epilogue(m, m_safe, l, acc, sink2d, params, hq, tqp)
+    return _jnp_epilogue(m, m_safe, l, acc, sink2d, params)
 
 
 def flex_attn_headmajor(
@@ -1495,7 +1682,8 @@ def flex_attn_headmajor(
 ):
     """Head-major differentiable core for the distributed runtime.
 
-    Returns (out [hq, tqp, d], lse_lanes [hq, tqp, LANES], rowmax_lanes).
+    Returns (out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp], the row
+    maximum of the masked logits, non-differentiable), from every backend.
     Table arrays may be traced (per-rank, sharded) values.
 
     ``MAGI_ATTENTION_KERNEL_BACKEND=jnp`` swaps the Pallas kernels for the
@@ -1517,7 +1705,14 @@ def flex_attn_headmajor(
     if env.kernel_backend() == "jnp_online":
         return _fwd_jnp_online(q, k, v, sink2d, tuple(ftab), params)
     _check_smem_budget(ftab, btab, q.shape[1], k.shape[1], params)
-    return _flex_attn_core(q, k, v, sink2d, tuple(ftab), tuple(btab), params)
+    ftab, btab = tuple(ftab), tuple(btab)
+    try:
+        return _flex_attn_core(q, k, v, sink2d, ftab, btab, params)
+    except NotImplementedError:
+        # a shard_map evaluated eagerly (no jit round it, nothing to
+        # differentiate) runs a custom_vjp's primal and drops its rules,
+        # but jax 0.9 refuses one registered with symbolic zeros there
+        return _fwd_dispatch(q, k, v, sink2d, ftab, params)[:3]
 
 
 def flex_attn_with_meta(
@@ -1576,15 +1771,14 @@ def flex_attn_with_meta(
         bwd_steps=meta.bwd_steps,
         grid=str(grid),
     )
-    out_h, lse_lanes, rowmax_lanes = flex_attn_headmajor(
+    out_h, lse_h, rowmax = flex_attn_headmajor(
         qh, kh, vh, fwd_tables(meta), bwd_tables(meta), params, sink=sink
     )
     with named_scope("magi_layout"):
         out = jnp.transpose(out_h, (1, 0, 2))[:tq]
-        lse = jnp.transpose(lse_lanes[:, :, 0], (1, 0))[:tq]
+        lse = jnp.transpose(lse_h, (1, 0))[:tq]
         if return_max_logits:
-            max_logits = jnp.max(rowmax_lanes[:, :, 0], axis=1)
-            return out, lse, max_logits
+            return out, lse, jnp.max(rowmax, axis=1)
     return out, lse
 
 

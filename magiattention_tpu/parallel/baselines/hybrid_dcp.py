@@ -146,10 +146,10 @@ def hybrid_dcp_attn_local(
     kg = jax.lax.all_gather(k, axis_name, tiled=True)  # [total, hk, d]
     vg = jax.lax.all_gather(v, axis_name, tiled=True)
     qh = _hm(q, plan.shard_q_pad)
-    out_h, lse_lanes, _ = _call_kernel(
+    out_h, lse_h, _ = _call_kernel(
         qh, kg, vg, tables, plan.kv_pad, params, None
     )
-    return _headmajor_to_seq(out_h, lse_lanes, plan.shard_len)
+    return _headmajor_to_seq(out_h, lse_h, plan.shard_len)
 
 
 def make_hybrid_dcp_attn_fn(

@@ -147,10 +147,10 @@ def double_ring_attn_local(
                 with named_scope("magi_loongtrain_inner_ppermute"):
                     kv = jax.lax.ppermute(kv, axis_inner, perm_in)
             tab = tables[step * 9 : (step + 1) * 9]
-            out_h, lse_lanes, _ = _call_kernel(
+            out_h, lse_h, _ = _call_kernel(
                 qh, kv[0], kv[1], tab, plan.shard_k_pad, fp32, None
             )
-            out_i, lse_i = _headmajor_to_seq(out_h, lse_lanes, plan.shard_len)
+            out_i, lse_i = _headmajor_to_seq(out_h, lse_h, plan.shard_len)
             if out is None:
                 out, lse = out_i, lse_i
             else:

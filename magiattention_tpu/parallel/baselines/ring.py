@@ -123,10 +123,10 @@ def ring_attn_local(
             with named_scope("magi_ring_kv_ppermute"):
                 kv = jax.lax.ppermute(kv, axis_name, perm)
         tab = tables[s * 9 : (s + 1) * 9]
-        out_h, lse_lanes, _ = _call_kernel(
+        out_h, lse_h, _ = _call_kernel(
             qh, kv[0], kv[1], tab, plan.shard_k_pad, fp32_params, None
         )
-        out_i, lse_i = _headmajor_to_seq(out_h, lse_lanes, plan.shard_len)
+        out_i, lse_i = _headmajor_to_seq(out_h, lse_h, plan.shard_len)
         if out is None:
             out, lse = out_i, lse_i
         else:

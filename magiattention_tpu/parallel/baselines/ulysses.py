@@ -118,10 +118,10 @@ def ulysses_attn_local(
         fwd_steps=max(params.fwd_steps, meta.fwd_steps),
         bwd_steps=max(params.bwd_steps, meta.bwd_steps),
     )
-    out_h, lse_lanes, _ = flex_attn_headmajor(
+    out_h, lse_h, _ = flex_attn_headmajor(
         qh, kh, vh, fwd_tables(meta), bwd_tables(meta), fp32_params
     )
-    out_g, lse_g = _headmajor_to_seq(out_h, lse_lanes, plan.total_seqlen)
+    out_g, lse_g = _headmajor_to_seq(out_h, lse_h, plan.total_seqlen)
     out = heads_to_seq(out_g).astype(params.out_jnp_dtype)
     # lse [total, hq/cp] -> [t_loc, hq]
     lse = heads_to_seq(lse_g[..., None])[..., 0]

@@ -64,7 +64,7 @@ from ..ops.block_meta import (
     runs_from_position_ids,
 )
 from ..ops.correction import correct_attn_out_lse
-from ..ops.flex_attn import FlexAttnParams, flex_attn_headmajor
+from ..ops.flex_attn import FlexAttnParams, flex_attn_headmajor, stats_form
 from ..utils.instrument import named_scope
 
 
@@ -913,6 +913,10 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
     telemetry.annotate_span(
         rung=(params.block_q, params.block_k, params.head_block),
         grid=grid,
+        # the form the kernels' side operands cross their boundary in
+        # (the build counter's labels of the same names)
+        stats=stats_form(params.block_q),
+        delta="kernel",
         row_major_steps=launched["row_major"],
         compact_steps=launched["sparse"],
         live_steps=live,
@@ -935,10 +939,11 @@ def _hm(x, target):
     return x
 
 
-def _headmajor_to_seq(out_h, lse_lanes, n):
-    """Kernel head-major outputs -> ([n, h, d] out, [n, h] lse)."""
+def _headmajor_to_seq(out_h, lse_h, n):
+    """Kernel head-major outputs ([h, t_pad, d] out, [h, t_pad] lse) ->
+    ([n, h, d] out, [n, h] lse)."""
     out = jnp.transpose(out_h, (1, 0, 2))[:n]
-    lse = jnp.transpose(lse_lanes[:, :, 0], (1, 0))[:n]
+    lse = jnp.transpose(lse_h, (1, 0))[:n]
     return out, lse
 
 
@@ -1091,11 +1096,11 @@ def dist_attn_local(
             k.dtype
         )
 
-    def _head_max(rowmax_lanes):
+    def _head_max(rowmax):
         # per-head max of masked logits over this rank's rows (pads carry
         # -inf); callers pmax across ranks (reference reduce_max_logits,
         # dist_attn.py:532 + :3168 all_reduce MAX — Muon QK-Clip support)
-        return jnp.max(rowmax_lanes[:, :, 0], axis=1)
+        return jnp.max(rowmax, axis=1)
 
     if plan.overlap_degree == 0:
         tab = take(9)
@@ -1105,17 +1110,15 @@ def dist_attn_local(
             k_full = jnp.concatenate([k, recv[:, 0]], axis=0)
             v_full = jnp.concatenate([v, recv[:, 1]], axis=0)
         with named_scope("magi_merged_kernel"):
-            out_h, lse_lanes, rowmax_lanes = _call_kernel(
+            out_h, lse_h, rowmax = _call_kernel(
                 qh, k_full, v_full, tab, plan.merged_tables.kv_pad, params,
                 sink,
             )
         with named_scope("magi_layout"):
-            out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
-        out, lse = _resilient(
-            out, lse, "merged", 0, rowmax=rowmax_lanes[:, :, 0]
-        )
+            out, lse = _headmajor_to_seq(out_h, lse_h, plan.shard_q_len)
+        out, lse = _resilient(out, lse, "merged", 0, rowmax=rowmax)
         with named_scope("magi_layout"):
-            res = (out, lse, _head_max(rowmax_lanes))
+            res = (out, lse, _head_max(rowmax))
         if with_guard_code:
             res = res + (code,)
         if with_census:
@@ -1136,16 +1139,14 @@ def dist_attn_local(
     host_params = dataclasses.replace(params, out_dtype=acc_dtype)
     host_tab = take(9)
     with named_scope("magi_host_stage_kernel"):
-        out_h, lse_lanes, rowmax_lanes = _call_kernel(
+        out_h, lse_h, rowmax = _call_kernel(
             qh, k, v, host_tab, plan.host_tables.kv_pad, host_params, sink
         )
     with named_scope("magi_layout"):
-        out, lse = _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
-    out, lse = _resilient(
-        out, lse, "host", 0, rowmax=rowmax_lanes[:, :, 0]
-    )
+        out, lse = _headmajor_to_seq(out_h, lse_h, plan.shard_q_len)
+    out, lse = _resilient(out, lse, "host", 0, rowmax=rowmax)
     with named_scope("magi_layout"):
-        mx = _head_max(rowmax_lanes)
+        mx = _head_max(rowmax)
 
     stage_params = dataclasses.replace(
         params, has_sink=False, out_dtype=acc_dtype
@@ -1155,16 +1156,16 @@ def dist_attn_local(
         with named_scope(f"magi_stage{i}_cast"):
             recv = cast_kv(sp.comm)
         with named_scope(f"magi_stage{i}_kernel"):
-            out_i_h, lse_i_lanes, rowmax_i = _call_kernel(
+            out_i_h, lse_i_h, rowmax_i = _call_kernel(
                 qh, recv[:, 0], recv[:, 1], tab, sp.tables.kv_pad,
                 stage_params, None,
             )
         with named_scope("magi_layout"):
             out_i, lse_i = _headmajor_to_seq(
-                out_i_h, lse_i_lanes, plan.shard_q_len
+                out_i_h, lse_i_h, plan.shard_q_len
             )
         out_i, lse_i = _resilient(
-            out_i, lse_i, f"stage{i}", 1 + i, rowmax=rowmax_i[:, :, 0]
+            out_i, lse_i, f"stage{i}", 1 + i, rowmax=rowmax_i
         )
         with named_scope(f"magi_stage{i}_lse_merge"):
             out, lse = correct_attn_out_lse(out, lse, out_i, lse_i)

@@ -344,10 +344,10 @@ def qo_comm_attn_local(
 
     fp32 = dataclasses.replace(params, out_dtype="float32")
     qh = _hm(qb, plan.q_buf_pad)
-    out_h, lse_lanes, _ = _call_kernel(
+    out_h, lse_h, _ = _call_kernel(
         qh, kvb[:, 0], kvb[:, 1], ktab, plan.kv_buf_pad, fp32, None
     )
-    out_p, lse_p = _headmajor_to_seq(out_h, lse_lanes, plan.comm_q.max_recv)
+    out_p, lse_p = _headmajor_to_seq(out_h, lse_h, plan.comm_q.max_recv)
 
     out_acc = jnp.zeros((plan.shard_len, hq, q.shape[2]), jnp.float32)
     lse_acc = jnp.full((plan.shard_len, hq), -jnp.inf, jnp.float32)

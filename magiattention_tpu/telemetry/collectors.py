@@ -1132,10 +1132,11 @@ def record_autotune_decision(decision) -> None:
 
 
 def record_flex_kernel_build(
-    kernel: str, heads_per_step: int, grid: str
+    kernel: str, heads_per_step: int, grid: str, **form: str
 ) -> None:
     """One flex ``pallas_call`` built (``ops/flex_attn._flex_pallas_call``,
-    while jax traces the caller — never inside a compiled step)."""
+    while jax traces the caller — never inside a compiled step). ``form``:
+    the forward's ``stats=compact|lanes``, dq's ``delta=kernel``."""
     if not _enabled():
         return
     get_registry().counter_inc(
@@ -1143,6 +1144,7 @@ def record_flex_kernel_build(
         kernel=kernel,
         heads_per_step=heads_per_step,
         grid=grid,
+        **form,
     )
 
 

@@ -521,7 +521,7 @@ def profile_plan_timeline(
 
         def merged_body(q_, k_, v_, recv, *tt):
             qh = _hm(q_, plan.shard_q_pad)
-            out_h, lse_lanes, _ = _call_kernel(
+            out_h, lse_h, _ = _call_kernel(
                 qh,
                 jnp.concatenate([k_, recv[:, 0]], axis=0),
                 jnp.concatenate([v_, recv[:, 1]], axis=0),
@@ -530,7 +530,7 @@ def profile_plan_timeline(
                 calc_params,
                 None,
             )
-            return _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
+            return _headmajor_to_seq(out_h, lse_h, plan.shard_q_len)
 
         calc_fn = smap(4 + 9, merged_body, n_out=2)
         recv = cast_fn(k, v, *comm_args)
@@ -553,10 +553,10 @@ def profile_plan_timeline(
 
         def host_body(q_, k_, v_, *tt):
             qh = _hm(q_, plan.shard_q_pad)
-            out_h, lse_lanes, _ = _call_kernel(
+            out_h, lse_h, _ = _call_kernel(
                 qh, k_, v_, tt, plan.host_tables.kv_pad, piece_params, None
             )
-            return _headmajor_to_seq(out_h, lse_lanes, plan.shard_q_len)
+            return _headmajor_to_seq(out_h, lse_h, plan.shard_q_len)
 
         host_fn = smap(3 + 9, host_body, n_out=2)
         acc_out, acc_lse = host_fn(q, k, v, *host_tabs)
@@ -581,12 +581,12 @@ def profile_plan_timeline(
                 q_, out_acc, lse_acc, recv, *tt, _kv_pad=sp.tables.kv_pad
             ):
                 qh = _hm(q_, plan.shard_q_pad)
-                out_h, lse_lanes, _ = _call_kernel(
+                out_h, lse_h, _ = _call_kernel(
                     qh, recv[:, 0], recv[:, 1], tt, _kv_pad,
                     piece_params, None,
                 )
                 out_i, lse_i = _headmajor_to_seq(
-                    out_h, lse_lanes, plan.shard_q_len
+                    out_h, lse_h, plan.shard_q_len
                 )
                 return correct_attn_out_lse(out_acc, lse_acc, out_i, lse_i)
 

@@ -137,7 +137,9 @@ HBM_PRICE_SHARE = 0.55
 # two); a price is forward-sized, so the three kernels' excess is averaged
 # over the sum
 KERNEL_FLOP_WEIGHTS = {"fwd": 1.0, "dq": 1.5, "dkv": 2.0}
-_STAT_LANES = 128  # lse and delta reach dkv replicated over a vreg's lanes
+# lse (the forward's residual) and delta (dq's second output) reach dkv
+# replicated over a vreg's lanes
+_STAT_LANES = 128
 
 
 def step_bytes(
@@ -156,9 +158,10 @@ def step_bytes(
     dkv walks k-major: a step brings q, dO and the two float32
     lane-replicated statistics (lse, delta) of its query heads.
 
-    Not counted: the tiles that stay while a block's entries run (q, dO
-    and the statistics in forward and dq, K and V in dkv) and the outputs.
-    Each is brought or written once a block, so over a call they are the
+    Not counted: the tiles that stay while a block's entries run (q, dO,
+    lse and, for delta, the forward's out in dq, K and V in dkv) and the
+    outputs (dq also writes delta, lane-replicated, once a q block). Each
+    is brought or written once a block, so over a call they are the
     tensors' own size whatever the rung: they move no order between rungs."""
     if kernel == "dkv":
         return head_block * block_q * (

@@ -418,11 +418,11 @@ def _hb_bwd_grads(
     )
 
     def loss(q, k, v, sink, ftab, btab):
-        out_h, lse_lanes, _ = fa.flex_attn_headmajor(
+        out_h, lse_h, _ = fa.flex_attn_headmajor(
             jnp.transpose(q, (1, 0, 2)), jnp.transpose(k, (1, 0, 2)),
             jnp.transpose(v, (1, 0, 2)), ftab, btab, params, sink=sink,
         )
-        return loss_of(jnp.transpose(out_h, (1, 0, 2)), lse_lanes[:, :, 0].T)
+        return loss_of(jnp.transpose(out_h, (1, 0, 2)), lse_h.T)
 
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(
         q, k, v, sink, fa.fwd_tables(meta), fa.bwd_tables(meta)
@@ -534,17 +534,16 @@ def _state_case(
 
     def run(fn):
         def loss(q, k, v, sink):
-            out, lse_lanes, rowmax_lanes = fn(q, k, v, sink)
-            lse = lse_lanes[:, :, 0]
+            out, lse, rowmax = fn(q, k, v, sink)
             return (out * do).sum() + (
                 jnp.where(jnp.isneginf(lse), 0.0, lse) * w
-            ).sum(), (out, lse_lanes, rowmax_lanes)
+            ).sum(), (out, lse, rowmax)
 
-        (_, (out, lse_lanes, rowmax_lanes)), grads = jax.value_and_grad(
+        (_, (out, lse, rowmax)), grads = jax.value_and_grad(
             loss, argnums=(0, 1, 2, 3), has_aux=True
         )(qh, kh, vh, sink)
         res = dict(
-            out=out, lse=lse_lanes[:, :, 0], rowmax=rowmax_lanes[:, :, 0],
+            out=out, lse=lse, rowmax=rowmax,
             dq=grads[0], dk=grads[1], dv=grads[2],
         )
         if with_sink:
@@ -689,20 +688,21 @@ def test_lse_and_delta_arrive_replicated_over_the_lanes(
     with_sink, head_block, grid, monkeypatch
 ):
     """The contract ``_bwd_p_ds`` leans on: what dq and dkv are handed as
-    lse (the forward's own output, from either forward body on either
-    grid) and as delta (built by ``_flex_attn_core_bwd``, the lse
-    cotangent folded in) is equal in all 128 lanes, on covered rows and
-    on rows no entry covers (``-inf``, or the sink)."""
+    lse (the differentiated forward's residual, from either forward body
+    on either grid) and what dkv is handed as delta (made by dq in its
+    block's first step, the lse cotangent folded in) is equal in all 128
+    lanes, on covered rows and on rows no entry covers (``-inf``, or the
+    sink)."""
     from magiattention_tpu.ops import flex_attn as fa
 
     seen = {}
-    dq_pallas = fa._dq_pallas
+    dkv_pallas = fa._dkv_pallas
 
     def spy(q, k, v, do, lse, delta, tables, params):
         seen.update(lse=np.asarray(lse), delta=np.asarray(delta))
-        return dq_pallas(q, k, v, do, lse, delta, tables, params)
+        return dkv_pallas(q, k, v, do, lse, delta, tables, params)
 
-    monkeypatch.setattr(fa, "_dq_pallas", spy)
+    monkeypatch.setattr(fa, "_dkv_pallas", spy)
     got, _, sink = _state_case(
         head_block, grid, 128, with_sink, 0.0, with_oracle=False
     )

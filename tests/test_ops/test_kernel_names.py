@@ -157,7 +157,9 @@ def test_build_counter_carries_heads_per_step_and_grid(
     jax.make_jaxpr(_grad_fn(t, head_block, *block, grid))(q, kv, kv)
     name = "magi_flex_kernel_build_total"
     assert _builds() == {
-        f"{name}{{grid={grid},heads_per_step={head_block},kernel=fwd}}": 1,
-        f"{name}{{grid={grid},heads_per_step={bwd_heads},kernel=dq}}": 1,
+        f"{name}{{grid={grid},heads_per_step={head_block},kernel=fwd,"
+        "stats=compact}": 1,
+        f"{name}{{delta=kernel,grid={grid},heads_per_step={bwd_heads},"
+        "kernel=dq}": 1,
         f"{name}{{grid={grid},heads_per_step={bwd_heads},kernel=dkv}}": 1,
     }

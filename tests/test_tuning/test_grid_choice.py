@@ -164,6 +164,9 @@ def test_every_cell_keeps_its_rung_and_gets_the_expected_grid(
         assert args["grid"] == cost_model.choose_grid(row_major, compact)
         if grid is not None:
             assert args["grid"] == grid
+        # ISSUE 40: at every cell's block_q the statistics cross the
+        # forward's boundary with rows along lanes, and dq makes delta
+        assert (args["stats"], args["delta"]) == ("compact", "kernel")
     # the gauge holds the newest plan's share, on the grid it was given
     launched = args["compact_steps" if args["grid"] == "sparse" else "row_major_steps"]
     assert telemetry.snapshot()["gauges"][
