@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..common.enum import MASK_TYPE_BITS, MAX_MASK_STEP_LOG2
 from ..telemetry import collectors as _collectors
 
 
@@ -75,7 +76,10 @@ def validate_slices(slices, total_q: int, total_k: int) -> None:
             f"slice {i}: k_range [{ks},{ke}) out of bounds for "
             f"total_seqlen_k={total_k}",
         )
-        _check(mt in (0, 1, 2, 3), f"slice {i}: unknown mask type {mt}")
+        _check(
+            0 <= mt < (MAX_MASK_STEP_LOG2 + 1) << MASK_TYPE_BITS,
+            f"slice {i}: unknown mask type {mt}",
+        )
         if mt == 3:  # bicausal: both bounds active over the whole band
             _check(
                 ke - ks >= qe - qs,

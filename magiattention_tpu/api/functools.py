@@ -299,3 +299,69 @@ def infer_attn_mask_from_sliding_window(
         global_tokens,
     )
     return AttnRanges.from_ranges(qr), AttnRanges.from_ranges(kr), ts
+
+
+def infer_block_diffusion_mask(
+    cu_seqlens: Sequence[int],
+    block: int,
+    *,
+    total_seqlen: int | None = None,
+):
+    """(q_ranges, k_ranges, types) of block-diffusion training (BD3-LM,
+    arXiv:2503.09573; SDAR, arXiv:2510.06303) over packed documents, on
+    the DOUBLED sequence ``[noisy ; clean]``: rows ``[0, L)`` are the
+    noised copy, rows ``[L, 2L)`` the clean copy, token ``i`` of either at
+    ``i`` resp. ``L + i`` (``L = total_seqlen``, default ``cu_seqlens[-1]``;
+    larger where the sequence is padded: pad rows attend nothing).
+
+    Inside a document cut into blocks of ``block`` tokens, a noisy row of
+    block b sees the noisy rows of block b (both ways) and the clean rows
+    of the blocks before b; a clean row of block b sees the clean rows of
+    blocks up to and with b, and no noisy key. With stepped bounds
+    (``AttnMaskType.with_step``) that is three slices a document:
+
+    - clean -> clean: CAUSAL at step ``block`` on the document;
+    - noisy -> clean: CAUSAL at step ``block``, the query range starting
+      one block in and the key range ending one block early (none for a
+      document of one block);
+    - noisy -> noisy: BICAUSAL at step ``block`` (the block diagonal).
+
+    The four unstepped types would take one FULL rectangle a block and
+    kind, ``3 n / block - 1`` a document of n tokens
+    (``common.mask.unstepped_slice_count``). Every document is a whole
+    number of blocks (padding one to a
+    block is the loader's) and ``block`` a power of two, else ValueError.
+    """
+    cu = [int(c) for c in cu_seqlens]
+    if not cu or cu[0] != 0 or any(a > b for a, b in zip(cu, cu[1:])):
+        raise ValueError(
+            f"invalid cu_seqlens: must start at 0 and be non-decreasing, "
+            f"got {cu}"
+        )
+    block = int(block)
+    causal = AttnMaskType.CAUSAL.with_step(block)  # checks the power of two
+    diagonal = AttnMaskType.BICAUSAL.with_step(block)
+    ragged = [(a, b) for a, b in zip(cu, cu[1:]) if (b - a) % block]
+    if ragged:
+        raise ValueError(
+            f"documents {ragged} are no whole number of blocks of {block} "
+            "tokens: pad each to a block before packing"
+        )
+    total = cu[-1] if total_seqlen is None else int(total_seqlen)
+    if total < cu[-1]:
+        raise ValueError(f"total_seqlen {total} < cu_seqlens[-1] {cu[-1]}")
+    qr, kr, ts = [], [], []
+
+    def add(q, k, t):
+        qr.append(q)
+        kr.append(k)
+        ts.append(t)
+
+    for c0, c1 in zip(cu, cu[1:]):
+        if c1 == c0:
+            continue
+        add((total + c0, total + c1), (total + c0, total + c1), causal)
+        if c1 - c0 > block:
+            add((c0 + block, c1), (total + c0, total + c1 - block), causal)
+        add((c0, c1), (c0, c1), diagonal)
+    return AttnRanges.from_ranges(qr), AttnRanges.from_ranges(kr), ts

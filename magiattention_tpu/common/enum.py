@@ -11,6 +11,8 @@ from __future__ import annotations
 import enum
 from typing import Literal, TypeAlias
 
+import numpy as np
+
 GroupReduceOp: TypeAlias = Literal["sum", "avg", "lse"]
 
 
@@ -29,6 +31,12 @@ class AttnRole(enum.Enum):
     VALUE = "value"
 
 
+# A slice's type word: the two bound bits below, log2 of the step above
+# them (0 for the unstepped types, so their words are 0..3 as before).
+MASK_TYPE_BITS = 2
+MAX_MASK_STEP_LOG2 = 15
+
+
 class AttnMaskType(enum.IntEnum):
     """Unit mask types applied per (q_range, k_range) attention slice.
 
@@ -43,12 +51,39 @@ class AttnMaskType(enum.IntEnum):
       INVCAUSAL : top-left aligned — allow iff (k - k_start) >= (q - q_start),
                   i.e. the *first* q row sees the whole k_range.
       BICAUSAL  : intersection of CAUSAL and INVCAUSAL.
+
+    A bound may move in steps of ``s`` keys every ``s`` rows instead of
+    one key a row (:meth:`with_step`, s a power of two): the two bounds
+    then compare block indices counted from the slice's aligned corner,
+      CAUSAL    : (k_end - 1 - k) // s >= (q_end - 1 - q) // s,
+      INVCAUSAL : (k - k_start) // s >= (q - q_start) // s,
+    and s = 1 is the predicate above. The stepped types are members made
+    on demand (``AttnMaskType(word)`` with ``word = type | log2(s) << 2``):
+    they pass wherever a type passes, ``int()`` of one is the kernels'
+    type word, and none of them equals one of the four named members, so
+    code that branches on a named member reads :attr:`base` and
+    :attr:`step`.
     """
 
     FULL = 0
     CAUSAL = 1
     INVCAUSAL = 2
     BICAUSAL = 3
+
+    @classmethod
+    def _missing_(cls, value):
+        if (
+            isinstance(value, (int, np.integer))
+            and 4 <= int(value) < (MAX_MASK_STEP_LOG2 + 1) << MASK_TYPE_BITS
+        ):
+            value = int(value)
+            member = int.__new__(cls, value)
+            base = cls(value & 3)
+            member._name_ = f"{base.name}_STEP{1 << (value >> MASK_TYPE_BITS)}"
+            member._value_ = value
+            # later lookups of the same word find this member
+            return cls._value2member_map_.setdefault(value, member)
+        return None
 
     @classmethod
     def from_int_type(cls, int_type: int) -> "AttnMaskType":
@@ -64,6 +99,32 @@ class AttnMaskType(enum.IntEnum):
     @property
     def is_inv_causal_bound(self) -> bool:
         return bool(self.value & 2)
+
+    @property
+    def base(self) -> "AttnMaskType":
+        """The named type whose bounds this one has (itself at step 1)."""
+        return AttnMaskType(self.value & 3)
+
+    @property
+    def step(self) -> int:
+        """Keys a bound moves at a time, every that many rows (1: a key
+        a row)."""
+        return 1 << (self.value >> MASK_TYPE_BITS)
+
+    def with_step(self, step: int) -> "AttnMaskType":
+        """This type's bounds in steps of ``step`` (a power of two).
+        FULL has no bound to step and stays FULL."""
+        step = int(step)
+        if step < 1 or step & (step - 1) or step >> MAX_MASK_STEP_LOG2 > 1:
+            raise ValueError(
+                f"a mask step is a power of two up to "
+                f"{1 << MAX_MASK_STEP_LOG2}, got {step}"
+            )
+        if self.base is AttnMaskType.FULL:
+            return AttnMaskType.FULL
+        return AttnMaskType(
+            (self.value & 3) | (step.bit_length() - 1) << MASK_TYPE_BITS
+        )
 
 
 class AttnOverlapMode(enum.Enum):

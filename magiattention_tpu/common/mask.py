@@ -29,17 +29,140 @@ def slice_mask(
 
     CAUSAL is bottom-right aligned: allow iff (k - k_end) <= (q - q_end).
     INVCAUSAL is top-left aligned: allow iff (k - k_start) >= (q - q_start).
-    BICAUSAL is their intersection; FULL is the whole rectangle.
+    BICAUSAL is their intersection; FULL is the whole rectangle. Under a
+    step s (``AttnMaskType.with_step``) the two bounds compare block
+    indices counted from the aligned corner; s = 1 is the line above.
     """
     mt = AttnMaskType(int(mask_type))
+    s = mt.step
     q = np.arange(total_q)[:, None]
     k = np.arange(total_k)[None, :]
     m = (q >= q_start) & (q < q_end) & (k >= k_start) & (k < k_end)
     if mt.is_causal_bound:
-        m &= (k - k_end) <= (q - q_end)
+        m &= (k_end - 1 - k) // s >= (q_end - 1 - q) // s
     if mt.is_inv_causal_bound:
-        m &= (k - k_start) >= (q - q_start)
+        m &= (k - k_start) // s >= (q - q_start) // s
     return m
+
+
+def row_key_bounds(
+    q: np.ndarray,
+    q_start: int,
+    q_end: int,
+    k_start: int,
+    k_end: int,
+    mask_type: AttnMaskType | int,
+) -> tuple[np.ndarray, np.ndarray]:
+    """The key interval [lo, hi) each absolute row ``q`` of the slice
+    attends (rows are taken to lie in the q range; hi <= lo: none).
+
+    This is the one place the stepped bound is written for the host: a
+    causal bound ends the interval at ``k_end - ((q_end - 1 - q) // s) * s``,
+    an inv-causal bound starts it at ``k_start + ((q - q_start) // s) * s``.
+    """
+    mt = AttnMaskType(int(mask_type))
+    s = mt.step
+    q = np.asarray(q, dtype=np.int64)
+    lo = np.full_like(q, k_start)
+    hi = np.full_like(q, k_end)
+    if mt.is_causal_bound:
+        hi = k_end - (q_end - 1 - q) // s * s
+    if mt.is_inv_causal_bound:
+        lo = k_start + (q - q_start) // s * s
+    return lo, hi
+
+
+def slice_rows(
+    q_start: int,
+    q_end: int,
+    k_start: int,
+    k_end: int,
+    mask_type: AttnMaskType | int,
+    a: int,
+    b: int,
+) -> list[tuple[int, int, int, int, AttnMaskType]]:
+    """Rows [a, b) of a slice as slices of their own, exactly:
+    ``(q_start, q_end, k_start, k_end, type)`` each, in row order.
+
+    A causal bound moves the key end with the bottom row and an
+    inv-causal bound the key start with the top row (reference
+    slice_maker.py), so at step 1 the rows are one slice of the same
+    type. A stepped bound counts its blocks from the slice's corner: where
+    the cut leaves part of a block at that corner, those rows (fewer than
+    the step) share one bound and come off as a piece without it, and the
+    rest keeps the type from a corner a whole number of blocks away.
+    Pieces that attend no key are left out.
+    """
+    mt = AttnMaskType(int(mask_type))
+    s = mt.step
+    assert q_start <= a < b <= q_end, (q_start, q_end, a, b)
+    inv, causal = mt.is_inv_causal_bound, mt.is_causal_bound
+
+    def inv_edge(x):  # first row at or after x that starts an inv block
+        return q_start + -(-(x - q_start) // s) * s
+
+    def causal_edge(y):  # last row end at or before y that ends a causal block
+        return q_end - -(-(q_end - y) // s) * s
+
+    # cut where the first whole inv block starts and the last whole causal
+    # block ends; the fewer-than-s rows outside either may still straddle
+    # one block edge of the other bound
+    cuts = {a, b}
+    ci = inv_edge(a) if inv else a
+    cc = causal_edge(b) if causal else b
+    cuts.update((ci, cc))
+    if inv and causal:
+        cuts.update((inv_edge(max(cc, a)), causal_edge(min(ci, b))))
+    cuts = sorted(c for c in cuts if a <= c <= b)
+    out = []
+    for x, y in zip(cuts[:-1], cuts[1:]):
+        ks, ke = k_start, k_end
+        inv_alive = causal_alive = False
+        if inv:
+            m, r = divmod(x - q_start, s)
+            ks = k_start + m * s
+            inv_alive = r == 0
+            assert inv_alive or y <= q_start + (m + 1) * s
+        if causal:
+            m, r = divmod(q_end - y, s)
+            ke = k_end - m * s
+            causal_alive = r == 0
+            assert causal_alive or x >= q_end - (m + 1) * s
+        if ke > ks:
+            piece = AttnMaskType(int(causal_alive) | int(inv_alive) << 1)
+            out.append((x, y, ks, ke, piece.with_step(s)))
+    return out
+
+
+def unstepped_slice_count(
+    q_ranges, k_ranges, attn_type_map: Sequence[AttnMaskType | int]
+) -> int:
+    """Slices the same mask takes from the four unstepped types: a slice
+    at step 1 is itself; a stepped one is a rectangle for every run of
+    rows that share a non-empty key interval (a block of the staircase)."""
+    n = 0
+    for (qs, qe), (ks, ke), mt in zip(q_ranges, k_ranges, attn_type_map):
+        mt = AttnMaskType(int(mt))
+        if mt.step == 1:
+            n += 1
+            continue
+        lo, hi = row_key_bounds(np.arange(qs, qe), qs, qe, ks, ke, mt)
+        live = hi > lo
+        new = np.ones(live.shape, bool)
+        new[1:] = (lo[1:] != lo[:-1]) | (hi[1:] != hi[:-1])
+        n += int((live & new).sum())
+    return n
+
+
+def _stepped_area(q_start, q_end, k_start, k_end, mt, pos=None) -> int:
+    """Area of a stepped slice (left of ``k = pos`` if given), a row at a
+    time: vectorized, host-side, and only where the step is above 1."""
+    lo, hi = row_key_bounds(
+        np.arange(q_start, q_end), q_start, q_end, k_start, k_end, mt
+    )
+    if pos is not None:
+        hi = np.minimum(hi, pos)
+    return int(np.maximum(hi - lo, 0).sum())
 
 
 def _tri_sum(lo: int, hi: int) -> int:
@@ -72,12 +195,15 @@ def slice_area(
     - INVCAUSAL (top-left): row q attends ``clamp(sk - q, 0, sk)`` keys.
     - BICAUSAL: row q attends ``clamp(min(sk-sq+q+1, sk) - max(q, 0), 0, .)``
       intersection band.
+    - A stepped type: the rows' intervals summed (:func:`row_key_bounds`).
     """
     sq = q_end - q_start
     sk = k_end - k_start
     if sq <= 0 or sk <= 0:
         return 0
     mt = AttnMaskType(int(mask_type))
+    if mt.step > 1:
+        return _stepped_area(q_start, q_end, k_start, k_end, mt)
     if mt == AttnMaskType.FULL:
         return sq * sk
 
@@ -119,6 +245,8 @@ def slice_area_left_of_k(
     if sq <= 0 or sk <= 0 or pos <= k_start:
         return 0
     mt = AttnMaskType(int(mask_type))
+    if mt.step > 1:
+        return _stepped_area(q_start, q_end, k_start, k_end, mt, pos)
     if mt == AttnMaskType.FULL:
         return sq * (min(pos, k_end) - k_start)
     if mt == AttnMaskType.CAUSAL:

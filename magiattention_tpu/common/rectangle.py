@@ -13,7 +13,7 @@ import dataclasses
 from typing import Iterator, Optional, Sequence
 
 from .enum import AttnMaskType
-from .mask import slice_area
+from .mask import row_key_bounds, slice_area, slice_rows
 from .range import AttnRange
 
 
@@ -50,14 +50,26 @@ class AttnRectangle:
         (the same transformation as chunk slicing: a causal bound moves the
         k end with the bottom row, an inv-causal bound moves the k start
         with the top row)."""
+        top, bottom = self.cut_q_multi(pos)
+        if len(top) > 1 or len(bottom) > 1:
+            raise ValueError(
+                f"cutting {self!r} at q={pos} leaves part of a block of "
+                f"{self.mask_type.step} rows at the bound's corner, which is "
+                "more than one rectangle a side: use cut_q_multi"
+            )
+        return (top[0] if top else None), (bottom[0] if bottom else None)
+
+    def cut_q_multi(
+        self, pos: int
+    ) -> tuple[list["AttnRectangle"], list["AttnRectangle"]]:
+        """:meth:`cut_q` as exact piece lists: one rectangle a side, or up
+        to three where a stepped bound's block straddles the cut."""
         qs, qe = self.q_range.start, self.q_range.end
         if pos <= qs:
-            return None, self.clone()
+            return [], [self.clone()]
         if pos >= qe:
-            return self.clone(), None
-        top = _truncate_q(self, qs, pos)
-        bottom = _truncate_q(self, pos, qe)
-        return top, bottom
+            return [self.clone()], []
+        return _truncate_q(self, qs, pos), _truncate_q(self, pos, qe)
 
     def cut_k_multi(
         self, pos: int
@@ -73,7 +85,8 @@ class AttnRectangle:
         left: list[AttnRectangle] = []
         right: list[AttnRectangle] = []
 
-        if mt == AttnMaskType.FULL:
+        step = mt.step
+        if mt.base == AttnMaskType.FULL:
             left.append(AttnRectangle(self.q_range.clone(), AttnRange(ks, pos), mt))
             right.append(AttnRectangle(self.q_range.clone(), AttnRange(pos, ke), mt))
             return left, right
@@ -81,8 +94,12 @@ class AttnRectangle:
         # crossing rows where the diagonal(s) meet k=pos
         # causal diagonal: k = q + (ke - qe)  ->  q* = pos - ke + qe
         # inv diagonal:    k = q + (ks - qs)  ->  q* = pos - ks + qs
-        if mt == AttnMaskType.CAUSAL:
-            q_cross = pos - ke + qe  # rows >= q_cross see k < pos fully
+        # (a stepped bound crosses at a whole number of blocks from its
+        # corner, so these two cuts leave one rectangle a side)
+        q_cross_c = qe - -(-(ke - pos) // step) * step
+        q_cross_i = qs + -(-(pos - ks) // step) * step
+        if mt.base == AttnMaskType.CAUSAL:
+            q_cross = q_cross_c  # rows >= q_cross see k < pos fully
             top, bottom = self.cut_q(q_cross)
             # top piece (rows < q_cross): strictly left of pos -> causal as-is
             if top is not None and not top.is_empty():
@@ -99,21 +116,21 @@ class AttnRectangle:
                 br = AttnRectangle(
                     bottom.q_range.clone(),
                     AttnRange(pos, bottom.k_range.end),
-                    AttnMaskType.CAUSAL,
+                    mt,
                 )
                 if br.area > 0:
                     right.append(br)
             return left, right
 
-        if mt == AttnMaskType.INVCAUSAL:
-            q_cross = pos - ks + qs  # rows < q_cross start left of pos
+        if mt.base == AttnMaskType.INVCAUSAL:
+            q_cross = q_cross_i  # rows < q_cross start left of pos
             top, bottom = self.cut_q(q_cross)
             if top is not None and not top.is_empty():
                 # top rows: [k_start(q), pos) inv-causal; [pos, ke) full
                 tl = AttnRectangle(
                     top.q_range.clone(),
                     AttnRange(top.k_range.start, pos),
-                    AttnMaskType.INVCAUSAL,
+                    mt,
                 )
                 if tl.area > 0:
                     left.append(tl)
@@ -126,23 +143,27 @@ class AttnRectangle:
                 rpiece = AttnRectangle(
                     bottom.q_range.clone(),
                     AttnRange(bottom.k_range.start, ke),
-                    AttnMaskType.INVCAUSAL,
+                    mt,
                 )
                 if rpiece.area > 0:
                     right.append(rpiece)
             return left, right
 
         # BICAUSAL: cut q at both crossings, pieces become causal/inv/full
-        q_cross_c = pos - ke + qe
-        q_cross_i = pos - ks + qs  # note q_cross_i <= q_cross_c (band width)
         lo, hi = sorted((q_cross_c, q_cross_i))
-        top, rest = self.cut_q(lo)
-        mid, bottom = (rest.cut_q(hi) if rest is not None else (None, None))
-        for piece in (top, mid, bottom):
-            if piece is None or piece.is_empty():
+        top, rest = self.cut_q_multi(lo)
+        pieces = list(top)
+        for r in rest:
+            mid, bottom = r.cut_q_multi(hi)
+            pieces += mid + bottom
+        for piece in pieces:
+            if piece.is_empty():
                 continue
-            # cut_q preserves BICAUSAL; each piece is clipped as a band
-            pl, pr = _bicausal_clip(piece, pos)
+            if piece.mask_type.base == AttnMaskType.BICAUSAL:
+                # each piece is clipped as a band
+                pl, pr = _bicausal_clip(piece, pos)
+            else:  # a stepped cut took a bound off part of a block
+                pl, pr = piece.cut_k_multi(pos)
             left.extend(pl)
             right.extend(pr)
         return left, right
@@ -154,16 +175,21 @@ class AttnRectangle:
         )
 
 
-def _truncate_q(rect: AttnRectangle, a: int, b: int) -> Optional[AttnRectangle]:
-    """Rows [a, b) of rect with alignment-preserving k adjustment."""
-    ks, ke = rect.k_range.start, rect.k_range.end
-    if rect.mask_type.is_causal_bound:
-        ke = ke - (rect.q_range.end - b)
-    if rect.mask_type.is_inv_causal_bound:
-        ks = ks + (a - rect.q_range.start)
-    if ke <= ks:
-        return None
-    return AttnRectangle(AttnRange(a, b), AttnRange(ks, ke), rect.mask_type)
+def _truncate_q(rect: AttnRectangle, a: int, b: int) -> list[AttnRectangle]:
+    """Rows [a, b) of rect with alignment-preserving k adjustment
+    (:func:`~.mask.slice_rows`: one piece at step 1)."""
+    return [
+        AttnRectangle(AttnRange(qa, qb), AttnRange(ks, ke), mt)
+        for qa, qb, ks, ke, mt in slice_rows(
+            rect.q_range.start,
+            rect.q_range.end,
+            rect.k_range.start,
+            rect.k_range.end,
+            rect.mask_type,
+            a,
+            b,
+        )
+    ]
 
 
 def _clip_k(rect: AttnRectangle, lo: int, hi: int) -> tuple[Optional[AttnRectangle], None]:
@@ -186,9 +212,9 @@ def _bicausal_clip(rect: AttnRectangle, pos: int):
     left: list[AttnRectangle] = []
     right: list[AttnRectangle] = []
     qs, qe = rect.q_range.start, rect.q_range.end
-    for q in range(qs, qe):  # bands are narrow; host-side only
-        lo = ks + (q - qs)
-        hi = ke - (qe - 1 - q)
+    los, his = row_key_bounds(range(qs, qe), qs, qe, ks, ke, rect.mask_type)
+    for q, lo, hi in zip(range(qs, qe), los.tolist(), his.tolist()):
+        # bands are narrow; host-side only
         if hi <= lo:
             continue
         if hi <= pos:
@@ -251,11 +277,9 @@ class AttnRectangles:
         """Partition all rectangles at the q=pos line."""
         top, bottom = AttnRectangles(), AttnRectangles()
         for r in self._rects:
-            t, b = r.cut_q(pos)
-            if t is not None:
-                top.append(t)
-            if b is not None:
-                bottom.append(b)
+            t, b = r.cut_q_multi(pos)
+            top.extend(t)
+            bottom.extend(b)
         return top, bottom
 
     def cut_k(self, pos: int) -> tuple["AttnRectangles", "AttnRectangles"]:
@@ -271,9 +295,13 @@ class AttnRectangles:
         """Area of the sub-region with q < pos (no piece construction)."""
         total = 0
         for r in self._rects:
-            t = _truncate_q(r, r.q_range.start, min(max(pos, r.q_range.start), r.q_range.end)) if pos > r.q_range.start else None
-            if t is not None:
-                total += t.area
+            if pos > r.q_range.start:
+                total += sum(
+                    t.area
+                    for t in _truncate_q(
+                        r, r.q_range.start, min(pos, r.q_range.end)
+                    )
+                )
         return total
 
     def area_left_of_k(self, pos: int) -> int:

@@ -23,6 +23,28 @@ def _row_band(qs, qe, ks, ke, mt, q):
     return lo, hi
 
 
+def _check_rows_disjoint(i, j, qi, ki, ti, qj, kj, tj, a, b) -> None:
+    """The pair's shared rows [a, b), each compared: what the envelope
+    argument below cannot do for bounds that move in steps."""
+    import numpy as np
+
+    from .mask import row_key_bounds
+
+    rows = np.arange(a, b)
+    lo_i, hi_i = row_key_bounds(rows, *qi, *ki, ti)
+    lo_j, hi_j = row_key_bounds(rows, *qj, *kj, tj)
+    hit = np.flatnonzero(np.maximum(lo_i, lo_j) < np.minimum(hi_i, hi_j))
+    if hit.size:
+        n = int(hit[0])
+        raise ValueError(
+            f"slices {i} and {j} overlap in (q, k) coverage at "
+            f"q={a + n}: k bands [{lo_i[n]},{hi_i[n]}) and "
+            f"[{lo_j[n]},{hi_j[n]}) intersect — the kernel would "
+            "double-count these keys in the softmax. Make slice coverage "
+            "disjoint."
+        )
+
+
 def check_slices_non_overlapping(
     q_ranges: AttnRanges | Sequence[Sequence[int]],
     k_ranges: AttnRanges | Sequence[Sequence[int]],
@@ -55,6 +77,9 @@ def check_slices_non_overlapping(
             a = max(qi[0], qj[0])
             b = min(qi[1], qj[1])
             if a >= b:
+                continue
+            if (ti | tj) >> 2:  # a stepped bound is no line: every row
+                _check_rows_disjoint(i, j, qi, ki, ti, qj, kj, tj, a, b)
                 continue
             # candidate rows: interval endpoints + envelope crossings
             cands = {a, b - 1}

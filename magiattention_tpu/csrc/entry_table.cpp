@@ -30,11 +30,39 @@ inline int64_t sum_clamp_linear(int64_t n, int64_t b, int64_t cap) {
   return tri_sum(b + n0, b + n1 - 1) + (n - n1) * cap;
 }
 
+// A slice's type word: bound bits 0-1, log2 of the step above them
+// (common/enum.AttnMaskType). Row q of the slice attends keys [lo, hi):
+// a causal bound ends it at ke - ((qe - 1 - q) >> ls << ls), an inv-causal
+// bound starts it at ks + ((q - qs) >> ls << ls); ls = 0 is a key a row.
+inline int64_t row_lo(int64_t q, int64_t qs, int64_t ks, int64_t mt) {
+  const int64_t ls = mt >> 2;
+  return (mt & 2) ? ks + (((q - qs) >> ls) << ls) : ks;
+}
+inline int64_t row_hi(int64_t q, int64_t qe, int64_t ke, int64_t mt) {
+  const int64_t ls = mt >> 2;
+  return (mt & 1) ? ke - (((qe - 1 - q) >> ls) << ls) : ke;
+}
+
+// area of rows [a, b) of a stepped slice with keys below kcap, a row at a
+// time (port of common/mask._stepped_area; only where the step is above 1)
+inline int64_t stepped_area(int64_t qs, int64_t qe, int64_t ks, int64_t ke,
+                            int64_t mt, int64_t a, int64_t b, int64_t kcap) {
+  int64_t area = 0;
+  for (int64_t q = a; q < b; ++q) {
+    const int64_t lo = row_lo(q, qs, ks, mt);
+    int64_t hi = row_hi(q, qe, ke, mt);
+    if (kcap < hi) hi = kcap;
+    if (hi > lo) area += hi - lo;
+  }
+  return area;
+}
+
 // exact unmasked area of one slice (port of common/mask.slice_area)
 inline int64_t slice_area_one(int64_t qs, int64_t qe, int64_t ks, int64_t ke,
                               int64_t mt) {
   const int64_t sq = qe - qs, sk = ke - ks;
   if (sq <= 0 || sk <= 0) return 0;
+  if (mt >> 2) return stepped_area(qs, qe, ks, ke, mt, qs, qe, ke);
   const bool causal = (mt & 1) != 0, inv = (mt & 2) != 0;
   if (!causal && !inv) return sq * sk;
   if (causal && !inv) {
@@ -54,6 +82,7 @@ inline int64_t area_left_q_one(int64_t qs, int64_t qe, int64_t ks, int64_t ke,
                                int64_t mt, int64_t pos) {
   if (pos <= qs) return 0;
   const int64_t b = pos < qe ? pos : qe;
+  if (mt >> 2) return stepped_area(qs, qe, ks, ke, mt, qs, b, ke);
   int64_t ke2 = ke;
   if (mt & 1) ke2 = ke - (qe - b);  // causal bound rides the bottom row
   if (ke2 <= ks) return 0;
@@ -65,6 +94,7 @@ inline int64_t area_left_k_one(int64_t qs, int64_t qe, int64_t ks, int64_t ke,
                                int64_t mt, int64_t pos) {
   const int64_t sq = qe - qs, sk = ke - ks;
   if (sq <= 0 || sk <= 0 || pos <= ks) return 0;
+  if (mt >> 2) return stepped_area(qs, qe, ks, ke, mt, qs, qe, pos);
   const bool causal = (mt & 1) != 0, inv = (mt & 2) != 0;
   const int64_t pcap = (pos < ke ? pos : ke) - ks;
   if (!causal && !inv) return sq * pcap;
@@ -174,12 +204,12 @@ int64_t magi_emit_entries(
         if (ql_hi < bq_hi) bq_hi = ql_hi;
         // k span needed by global rows [bq_lo+q_off, bq_hi+q_off)
         int64_t k_lo = ks, k_hi = ke;
-        if (causal) {
-          const int64_t h = ke - qe + (bq_hi + q_off);
+        if (causal) {  // the block's last row sees furthest right
+          const int64_t h = row_hi(bq_hi + q_off - 1, qe, ke, mt);
           if (h < k_hi) k_hi = h;
         }
-        if (inv) {
-          const int64_t l = ks + ((bq_lo + q_off) - qs);
+        if (inv) {  // its first row furthest left
+          const int64_t l = row_lo(bq_lo + q_off, qs, ks, mt);
           if (l > k_lo) k_lo = l;
         }
         if (k_hi <= k_lo) continue;
@@ -229,8 +259,6 @@ int64_t magi_slice_area_runs(
     const int64_t ke = slices[sid * 5 + 3];
     const int64_t mt = slices[sid * 5 + 4];
     if (qs >= qe || ks >= ke) continue;
-    const bool causal = (mt & 1) != 0;
-    const bool inv = (mt & 2) != 0;
     for (int64_t qi = 0; qi < n_q_runs; ++qi) {
       const int64_t q_gs = q_runs[qi * 3 + 1];
       const int64_t q_len = q_runs[qi * 3 + 2];
@@ -244,12 +272,13 @@ int64_t magi_slice_area_runs(
         const int64_t d = (ke < k_gs + k_len ? ke : k_gs + k_len);
         if (c >= d) continue;
         // rows q in [a, b): cols [max(lo(q), c), min(hi(q), d)) with
-        // lo(q) = inv ? ks + q - qs : ks, hi(q) = causal ? ke - qe + q + 1 : ke.
+        // lo(q) = inv ? ks + q - qs : ks, hi(q) = causal ? ke - qe + q + 1 : ke
+        // (in blocks of the step: row_lo / row_hi).
         // A plain per-row loop is plenty fast in native code and immune to
         // the clip-breakpoint case analysis a closed form would need.
         for (int64_t q = a; q < b; ++q) {
-          const int64_t lo_q = inv ? ks + (q - qs) : ks;
-          const int64_t hi_q = causal ? ke - qe + q + 1 : ke;
+          const int64_t lo_q = row_lo(q, qs, ks, mt);
+          const int64_t hi_q = row_hi(q, qe, ke, mt);
           const int64_t lo = lo_q > c ? lo_q : c;
           const int64_t hi = hi_q < d ? hi_q : d;
           if (hi > lo) area += hi - lo;

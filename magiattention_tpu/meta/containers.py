@@ -11,7 +11,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ..common.enum import AttnMaskType
-from ..common.mask import slice_area
+from ..common.mask import slice_area, slice_rows
 from ..common.range import AttnRange
 from ..common.ranges import AttnRanges
 
@@ -42,12 +42,12 @@ class AttnSlice:
         )
 
 
-def truncate_slice_q(
+def truncate_slice_q_pieces(
     q_range: AttnRange,
     k_range: AttnRange,
     mask_type: AttnMaskType,
     new_q: AttnRange,
-) -> Optional[AttnSlice]:
+) -> list[AttnSlice]:
     """Restrict a slice to a sub-q-interval, preserving mask alignment.
 
     The defining property of the mask types (reference slice_maker.py): when
@@ -56,18 +56,21 @@ def truncate_slice_q(
         bottom row: new_ke = ke - (qe - b);
       - an inv-causal (top-left aligned) bound moves the k *start* with the
         top row: new_ks = ks + (a - qs).
-    Returns None when the cut rows attend no keys at all.
-    """
-    a, b = new_q.start, new_q.end
-    assert q_range.start <= a and b <= q_range.end and a < b
-    ks, ke = k_range.start, k_range.end
-    if mask_type.is_causal_bound:
-        ke = ke - (q_range.end - b)
-    if mask_type.is_inv_causal_bound:
-        ks = ks + (a - q_range.start)
-    if ke <= ks:
-        return None
-    return AttnSlice(AttnRange(a, b), AttnRange(ks, ke), mask_type)
+    The rows come back as one slice (none when they attend no keys at
+    all), or as up to four where the cut leaves part of a block at a
+    stepped bound's corner (``common.mask.slice_rows``)."""
+    return [
+        AttnSlice(AttnRange(a, b), AttnRange(ks, ke), mt)
+        for a, b, ks, ke, mt in slice_rows(
+            q_range.start,
+            q_range.end,
+            k_range.start,
+            k_range.end,
+            mask_type,
+            new_q.start,
+            new_q.end,
+        )
+    ]
 
 
 @dataclass

@@ -18,7 +18,7 @@ from .. import telemetry
 from ..common.enum import AttnMaskType, DispatchAlgType
 from ..common.range import AttnRange
 from ..common.ranges import AttnRanges
-from .containers import AttnBucket, AttnChunk, truncate_slice_q
+from .containers import AttnBucket, AttnChunk, truncate_slice_q_pieces
 from .solver.dispatch_solver import (
     DispatchConfig,
     DispatchData,
@@ -161,10 +161,9 @@ def make_global_bucket_from_qk_ranges(
             qi = q_ranges[i].intersect(chunk_range)
             if qi.is_empty():
                 continue
-            s = truncate_slice_q(
+            for s in truncate_slice_q_pieces(
                 q_ranges[i], k_ranges[i], AttnMaskType(attn_mask_type[i]), qi
-            )
-            if s is not None:
+            ):
                 s.slice_id = i
                 chunk.attn_slices.append(s)
                 chunk.sample_ids.append(i)

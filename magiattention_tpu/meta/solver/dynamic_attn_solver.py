@@ -246,9 +246,9 @@ class LocalityGreedySolver:
             home, rect = stack.pop()
             if rect.area > cap and rect.q_range.seqlen > 1:
                 mid = (rect.q_range.start + rect.q_range.end) // 2
-                top, bottom = rect.cut_q(mid)
-                for piece in (top, bottom):
-                    if piece is not None and piece.area > 0:
+                top, bottom = rect.cut_q_multi(mid)
+                for piece in top + bottom:
+                    if piece.area > 0:
                         stack.append((home, piece))
             else:
                 refined.append((home, rect))

@@ -95,6 +95,22 @@ def _plan_on_dispatch(
     telemetry.annotate_span(  # what the kernels are handed, every model's
         heads_q=cfg.n_heads, heads_kv=cfg.n_kv_heads, head_dim=cfg.head_dim
     )
+    if telemetry.enabled():  # what the mask is made of
+        from ..common.enum import AttnMaskType
+        from ..common.mask import unstepped_slice_count
+
+        q_naive, k_naive = q_ranges.to_naive_ranges(), k_ranges.to_naive_ranges()
+        mask_step = max(
+            (AttnMaskType(int(t)).step for t in attn_type_map), default=1
+        )
+        telemetry.annotate_span(
+            mask_step=mask_step,
+            slices=len(attn_type_map),
+            # the slices the same mask takes at step 1
+            rectangles=unstepped_slice_count(q_naive, k_naive, attn_type_map),
+        )
+        if kind is not None:
+            telemetry.record_mask_step(kind, mask_step)
     if kind is not None:
         telemetry.annotate_span(kind=kind)
         telemetry.record_model_attn_plan(kind)
@@ -270,9 +286,12 @@ def make_model_train_step(model, optimizer):
     Works for any bundle exposing ``loss_fn`` + ``sharded_tables``."""
     tables = model.sharded_tables()
 
-    def step(params, opt_state, tokens, labels, pos):
+    def step(params, opt_state, tokens, labels, pos, weights=None):
+        # ``weights``: a row's weight on the loss, where the bundle's
+        # loss takes one (models/pattern.py under diffusion over blocks)
         loss, grads = jax.value_and_grad(model.loss_fn)(
-            params, tokens, labels, pos, tables
+            params, tokens, labels, pos, tables,
+            *(() if weights is None else (weights,)),
         )
         with named_scope("magi_optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)

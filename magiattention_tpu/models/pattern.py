@@ -53,6 +53,17 @@ makes beside the attention's). ZAYA1's expert half routes top-1 through
 an MLP (``router_form``) whose 256-wide input carries over from layer to
 layer: the first state beside ``x`` on the residual path (:func:`route`).
 
+``diffusion_block > 0`` trains the same layers by diffusion over blocks
+(SDAR / ``sdar_moe_config``; the mask of BD3-LM, arXiv:2503.09573): the
+sequence is fed twice, ``[noisy ; clean]``, 2L rows that both carry the
+token's own position, under the three-slice stepped mask of
+``api.infer_block_diffusion_mask``; the head runs on the noisy half's
+rows alone and the loss is the cross-entropy of the masked ones under
+the caller's per-row weights (1/t), over the sequence's data tokens
+(:meth:`MagiPattern.loss_fn`). SDAR's router is the third
+``router_form``: one matrix, a softmax over all experts, top-k,
+renormalised.
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -84,7 +95,7 @@ from .llama import _rms_norm, _rope
 SLIDING, FULL = "sliding_attention", "full_attention"
 DENSE, EXPERTS = "dense", "experts"
 GQA, LATENT, CCA = "gqa", "latent", "cca"
-SIGMOID, MLP = "sigmoid", "mlp"  # the router's forms
+SIGMOID, MLP, SOFTMAX = "sigmoid", "mlp", "softmax"  # the router's forms
 _SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
 
 
@@ -119,7 +130,8 @@ class PatternConfig:
     # SIGMOID: sigmoid scores of one matrix. MLP (ZAYA1): the hidden state
     # down to ``router_hidden``, plus a learned per-channel weight times
     # the layer before's (the state a layer hands the next), a norm, two
-    # GELU layers, softmax scores
+    # GELU layers, softmax scores. SOFTMAX (Qwen3-MoE, SDAR): the softmax
+    # of one matrix over all experts
     router_form: str = SIGMOID
     router_hidden: int = 0
     # the experts THIS rank holds, [first, last): the router stays
@@ -132,7 +144,8 @@ class PatternConfig:
     # the held pairs are zeros in the last group. A step then costs the
     # same wherever the router sends the tokens (top-1 of 16 swings a
     # layer's held share from 2% to 100% with the seed: PERF.md section 6,
-    # PR 39), at the price of the matmuls a full chunk takes
+    # PR 39), at the price of the matmuls a full chunk takes; past top-2
+    # every chunk runs, where the pairs' form skips one nothing reaches
     flat_expert_rows: bool = False
     dtype: str = "bfloat16"
     remat: bool = False
@@ -162,6 +175,10 @@ class PatternConfig:
     # entropy (a uniform prior over exits)
     n_loops: int = 1
     exit_entropy_weight: float = 0.05
+    # diffusion over blocks of this many tokens (a power of two; 0: next-
+    # token training): the model is fed [noisy ; clean], twice the
+    # documents' rows, under ``api.infer_block_diffusion_mask``
+    diffusion_block: int = 0
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
@@ -169,7 +186,7 @@ class PatternConfig:
         bad = set(self.layer_types) - {SLIDING, FULL}
         bad |= set(self.ffn_types) - {DENSE, EXPERTS}
         bad |= {self.attn_form} - {GQA, LATENT, CCA}
-        bad |= {self.router_form} - {SIGMOID, MLP}
+        bad |= {self.router_form} - {SIGMOID, MLP, SOFTMAX}
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
         if self.attn_form == LATENT and not (
@@ -207,6 +224,14 @@ class PatternConfig:
         if self.n_mtp > 1:
             raise ValueError(
                 "more than one MTP module: no reference states the chain"
+            )
+        if self.diffusion_block and (
+            self.sliding_window is not None or self.n_mtp
+            or self.n_loops > 1 or self.attn_form == CCA
+        ):
+            raise ValueError(
+                "diffusion over blocks under a window, an MTP module, a "
+                "loop or cca: no reference states one"
             )
         if self.n_loops < 1:
             raise ValueError(f"n_loops {self.n_loops}: at least one pass")
@@ -415,6 +440,63 @@ def zaya_config(
     )
 
 
+def sdar_moe_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``sdar_moe`` ``config.json`` (SDAR-30B-A3B) as a
+    pattern: a qk-normed GQA decoder, rotary in every layer, no window,
+    every layer ``num_experts`` experts behind the softmax router
+    (``norm_topk_prob``), no shared expert, untied head; trained by
+    diffusion over blocks of ``hf["block_length"]`` tokens (the published
+    file does not state it: the caller's configuration does).
+    ``expert_range`` and ``vocab_size`` give one rank's share, as in
+    :func:`afmoe_config`.
+
+    ``flat_expert_rows`` is set, as ISSUE 42 asks of a cell whose rate
+    spreads between seeds: every chunk of the held experts' pairs runs,
+    so a step's work does not follow the seed's router (PERF.md section
+    6, PR 42)."""
+    n = int(hf["num_hidden_layers"])
+    if hf.get("mlp_only_layers") or int(hf.get("decoder_sparse_step", 1)) != 1:
+        raise ValueError(
+            "an sdar_moe configuration with dense layers is not built"
+        )
+    if hf.get("use_sliding_window") or hf.get("sliding_window") is not None:
+        raise ValueError("an sdar_moe configuration with a window is not built")
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=int(hf["hidden_size"]),
+        n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]),
+        layer_types=(FULL,) * n,
+        ffn_types=(EXPERTS,) * n,
+        ffn_hidden=int(hf["intermediate_size"]),
+        rope_theta=float(hf["rope_theta"]),
+        rope_kinds=(FULL,),
+        qk_norm=True,
+        attn_gate=False,
+        post_norms=False,
+        rms_eps=float(hf["rms_norm_eps"]),
+        n_experts=int(hf["num_experts"]),
+        top_k=int(hf["num_experts_per_tok"]),
+        expert_hidden=int(hf["moe_intermediate_size"]),
+        route_norm=bool(hf["norm_topk_prob"]),
+        router_form=SOFTMAX,
+        expert_range=expert_range,
+        flat_expert_rows=True,
+        dtype=dtype,
+        remat=remat,
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        diffusion_block=int(hf["block_length"]),
+    )
+
+
 def ouro_config(
     hf: dict, *, dtype: str = "bfloat16", remat: bool = False
 ) -> PatternConfig:
@@ -617,7 +699,8 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
 
 def _router_scores(h, r, layer: dict, cfg: PatternConfig):
     """(scores [t, n_experts] float32, the state for the next layer) in
-    ``router_dtype``. SIGMOID: the sigmoid of one matrix, no state. MLP:
+    ``router_dtype``. SIGMOID: the sigmoid of one matrix, no state;
+    SOFTMAX: its softmax over all experts. MLP:
     ``r_l = h W_down + gamma r_{l-1}`` (``r`` is the layer before's, zero
     before the first), and the softmax of two GELU layers and an output
     matrix on its norm."""
@@ -632,6 +715,9 @@ def _router_scores(h, r, layer: dict, cfg: PatternConfig):
 
     if cfg.router_form == SIGMOID:
         return jax.nn.sigmoid(dot(h, "w_router")).astype(jnp.float32), r
+    if cfg.router_form == SOFTMAX:
+        logits = dot(h, "w_router").astype(jnp.float32)
+        return jax.nn.softmax(logits, axis=-1), r
     r = dot(h, "w_router_down") + layer["router_gamma"].astype(rdt) * r
     z = _rms_norm(r, layer["router_norm"], cfg.rms_eps)
     for name in ("w_router_mlp1", "w_router_mlp2"):
@@ -666,12 +752,16 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     chunk is the ``t`` pairs there are). The first always
     runs; a later one that no held pair reaches is skipped
     (``lax.cond``). No pair is dropped. The matmuls follow the pairs
-    that are here (under ``flat_expert_rows`` they too take the chunk
-    whole); the row gather and the scatter-add round them take a
+    that are here; the row gather and the scatter-add round them take a
     chunk's rows whatever it holds, so a step's time is flat in the load
     up to ``2 t`` pairs, four times an even share (PERF.md section 6, PR
     26: the form whose every pass follows the pairs is faster at an even
-    load and follows a drifting router by 9% inside 40 steps)."""
+    load and follows a drifting router by 9% inside 40 steps). Under
+    ``flat_expert_rows`` the matmuls too take a chunk whole and no chunk
+    is skipped: a step does the work of ``top_k t`` pairs whatever the
+    router sends here (PERF.md section 6, PR 42: a chunk costs a layer
+    21 ms of a step, and ran for one seed's router and not for
+    another's)."""
     dt = cfg.jnp_dtype
     t, k = idx.shape
     rows = min(2, k) * t
@@ -730,6 +820,8 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
             with named_scope("magi_moe_scatter"):
                 return y + o
 
+        if cfg.flat_expert_rows:  # the same work whatever reaches it
+            return add_chunk(y), None
         return jax.lax.cond(reached, add_chunk, lambda y: y, y), None
 
     with named_scope("magi_moe_sort"):
@@ -944,9 +1036,19 @@ def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
     return one_layer
 
 
+def _diffusion_io(cfg: PatternConfig):
+    """The scope of what diffusion over blocks adds around the layers: a
+    cross-cut over ``magi_embed`` / ``magi_head`` (the doubled rows'
+    embedding, the noisy half's gather before the head, the weights on
+    the loss), as ``magi_mtp`` is."""
+    if cfg.diffusion_block:
+        return named_scope("magi_diffusion_io")
+    return contextlib.nullcontext()
+
+
 def _embed(params, tokens, cfg: PatternConfig):
     dt = cfg.jnp_dtype
-    with named_scope("magi_embed"):
+    with _diffusion_io(cfg), named_scope("magi_embed"):
         x = params["embed"].astype(dt)[tokens]
         if cfg.embed_scale != 1.0:
             x = x * jnp.asarray(cfg.embed_scale, dt)
@@ -1119,6 +1221,33 @@ def _stacked(stats):
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
+class NoisyRows:
+    """Which of a rank's dispatched rows belong to the noisy half of a
+    doubled ``[noisy ; clean]`` sequence: ``rows`` [cp, n] int32, a
+    rank's local slots in dispatch order, -1 past its own count (the
+    dispatch balances the mask's area, not the halves). The head and the
+    loss of diffusion over blocks run on these rows alone."""
+
+    rows: np.ndarray
+
+    def device_tables(self):
+        return (self.rows,)
+
+
+def make_noisy_rows(meta, data_tokens: int) -> NoisyRows:
+    """The noisy half's rows (global positions below ``data_tokens``) of
+    every rank of the dispatch ``meta``."""
+    here = [
+        np.flatnonzero(np.asarray(meta.position_ids(r)) < data_tokens)
+        for r in range(meta.cp_size)
+    ]
+    rows = np.full((meta.cp_size, max(map(len, here))), -1, np.int32)
+    for r, idx in enumerate(here):
+        rows[r, : len(idx)] = idx
+    return NoisyRows(rows)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
 class MagiPattern:
     """Config + one dispatch's plans by attention kind + mesh + step
     makers. ``tokens`` / ``labels`` / ``pos`` are in DISPATCH order,
@@ -1133,8 +1262,10 @@ class MagiPattern:
     dispatch_meta: Any = None  # the plans' dispatch: MTP targets roll on it
     # the documents' forward shift on that dispatch (``cfg.shift_taps``)
     shift_plan: Any = None
+    # under ``cfg.diffusion_block``: the noisy half's rows a rank
+    noisy_rows: NoisyRows | None = None
 
-    def loss_fn(self, params, tokens, labels, pos, tables, *,
+    def loss_fn(self, params, tokens, labels, pos, tables, weights=None, *,
                 with_stats: bool = False):
         """Mean next-token CE over valid (label >= 0) positions, plus
         ``cfg.mtp_loss_weight`` x each MTP module's mean CE on its own
@@ -1144,8 +1275,24 @@ class MagiPattern:
         layers' routing (the trunk's, then the modules'): ``expert_idx``
         [batch, layers,
         total_padded, top_k] in dispatch order and ``expert_counts``
-        [layers, held] summed over the mesh."""
+        [layers, held] summed over the mesh.
+
+        Under ``cfg.diffusion_block`` the batch is the doubled sequence
+        in dispatch order: ``tokens`` the noised ids then the clean ids,
+        ``pos`` the tokens' positions twice, ``labels`` a masked noisy
+        row's own clean id and -1 everywhere else (no shift: a masked row
+        predicts its own token), ``weights`` [batch, total_padded]
+        float32 a row's weight (1/t of its block). The head runs on the
+        noisy half's rows alone, and the loss is the sum of weight x CE
+        over the labelled rows, over the number of noisy rows: the
+        sequence's tokens, masked or not."""
         cfg = self.cfg
+        if bool(cfg.diffusion_block) != (weights is not None):
+            raise ValueError(
+                "per-row weights go with diffusion over blocks, and only "
+                f"with it (diffusion_block {cfg.diffusion_block})"
+            )
+        weights = () if weights is None else (weights,)
         tables = {k: tuple(v) for k, v in tables.items()}
         batch = P(self.dp_axis, self.cp_axis)
         stats_specs = (
@@ -1161,14 +1308,37 @@ class MagiPattern:
             shard_map,
             mesh=self.mesh,
             in_specs=(
-                P(), batch, batch, batch, (batch,) * cfg.n_mtp,
+                P(), batch, batch, batch,
+                (batch,) * (cfg.n_mtp + len(weights)),
                 {k: (P(self.cp_axis),) * len(v) for k, v in tables.items()},
             ),
             out_specs=(P(), stats_specs),
             check_vma=False,
         )
         def _local(params, tok, lab, pos, mtp_lab, tabs):
+            def one_diffusion(tok1, lab1, pos1, w1):
+                x, stats = _trunk_local(
+                    params, tok1, pos1, cfg, tabs, self.plans,
+                    self.attn_params, self.cp_axis,
+                )
+                with named_scope("magi_diffusion_io"), named_scope("magi_head"):
+                    rows = tabs["noisy_rows"][0][0]
+                    here = rows >= 0
+                    rows = jnp.maximum(rows, 0)
+                    x = x[rows]
+                    lab1 = jnp.where(here, lab1[rows], -1)
+                    w1 = jnp.where(here, w1[rows], 0.0)
+                logits = _head(x, params["final_norm"], params, cfg)
+                with named_scope("magi_head"):
+                    ce, _ = masked_ce_tokens(logits, lab1)
+                    with named_scope("magi_diffusion_io"):
+                        ce = ce * w1
+                    sums = (ce.sum(), here.sum().astype(jnp.float32))
+                return (sums,), _stacked(stats)
+
             def one(tok1, lab1, pos1, *mtp_lab1):
+                if cfg.diffusion_block:
+                    return one_diffusion(tok1, lab1, pos1, *mtp_lab1)
                 run = (cfg, tabs, self.plans, self.attn_params, self.cp_axis)
                 if cfg.n_loops > 1:
                     states = _looped_trunk_local(params, tok1, pos1, *run)
@@ -1238,7 +1408,9 @@ class MagiPattern:
                     )
             return loss, stats
 
-        loss, stats = _local(params, tokens, labels, pos, mtp_labels, tables)
+        loss, stats = _local(
+            params, tokens, labels, pos, mtp_labels + weights, tables
+        )
         return (loss, stats) if with_stats else loss
 
     def _mtp_labels(self, tokens, labels):
@@ -1271,10 +1443,13 @@ class MagiPattern:
             k: sharded_plan_tables(p, self.mesh, self.cp_axis)
             for k, p in self.plans.items()
         }
-        if self.shift_plan is not None:
-            tables["shift"] = sharded_plan_tables(
-                self.shift_plan, self.mesh, self.cp_axis
-            )
+        for name, plan in (
+            ("shift", self.shift_plan), ("noisy_rows", self.noisy_rows)
+        ):
+            if plan is not None:
+                tables[name] = sharded_plan_tables(
+                    plan, self.mesh, self.cp_axis
+                )
         return tables
 
     def make_train_step(self, optimizer):
@@ -1306,20 +1481,35 @@ def build_magi_pattern(
 ) -> tuple[MagiPattern, Any]:
     """Plan the CP attention of one packed sequence (documents
     ``cu_seqlens``, causal inside each) for every attention kind of the
-    pattern, and bundle the model. Returns (model, dispatch_meta).
+    pattern, and bundle the model. Returns (model, dispatch_meta). Under
+    ``cfg.diffusion_block`` the plan and the dispatch are of the doubled
+    sequence ``[noisy ; clean]``, twice ``cu_seqlens[-1]`` rows, under
+    ``api.infer_block_diffusion_mask``.
 
     One dispatch solve, on the first of ``cfg.plan_kinds`` (the
     documents' whole mask where a layer has it); every other kind's
     plan, tiles and tables are built for its own slices on that dispatch.
     A pattern of one kind builds one plan."""
-    from ..api.functools import infer_attn_mask_from_cu_seqlens
+    from ..api.functools import (
+        infer_attn_mask_from_cu_seqlens, infer_block_diffusion_mask,
+    )
     from ._common import plan_flex_attn, plan_flex_attn_on_dispatch
 
     if isinstance(cp_axis, list):
         cp_axis = tuple(cp_axis)
     cu = [int(c) for c in cu_seqlens]
+    data_tokens = cu[-1]
+    # under diffusion over blocks the plan is the doubled sequence's
+    total = (2 if cfg.diffusion_block else 1) * data_tokens
+    if cfg.diffusion_block and data_tokens % chunk_size:
+        raise ValueError(
+            f"{data_tokens} tokens are no whole number of chunks of "
+            f"{chunk_size}: a chunk of [noisy ; clean] is of one half"
+        )
 
     def mask(kind):
+        if cfg.diffusion_block:
+            return infer_block_diffusion_mask(cu, cfg.diffusion_block)
         if kind == FULL:
             return infer_attn_mask_from_cu_seqlens(cu, causal=True)
         return infer_attn_mask_from_cu_seqlens(
@@ -1333,7 +1523,7 @@ def build_magi_pattern(
     lead, *rest = cfg.plan_kinds
     plans, attn_params = {}, {}
     plans[lead], attn_params[lead], meta = plan_flex_attn(
-        cfg, mesh, cu[-1], *mask(lead), chunk_size=chunk_size,
+        cfg, mesh, total, *mask(lead), chunk_size=chunk_size,
         kind=_SHORT[lead], **common,
     )
     for kind in rest:
@@ -1346,6 +1536,9 @@ def build_magi_pattern(
         shift_plan=(
             make_shift_plan(meta, cu, cfg.shift_taps)
             if cfg.shift_taps else None
+        ),
+        noisy_rows=(
+            make_noisy_rows(meta, data_tokens) if cfg.diffusion_block else None
         ),
     )
     telemetry.record_model_loop(cfg.n_loops, cfg.n_layers)

@@ -35,6 +35,8 @@ from typing import Sequence
 import numpy as np
 
 # Fields per slice in the flattened bounds table (global coords).
+# mask_type is the type word: bound bits 0-1, log2 of the step above them
+# (common/enum.AttnMaskType), so a stepped slice costs no field.
 SLICE_FIELDS = 5  # qs, qe, ks, ke, mask_type
 # Fields per entry in the flattened runs table (local windows + offsets).
 RUN_FIELDS = 7  # ql0, ql1, kl0, kl1, qoff, koff, needs_mask (diagnostic)
@@ -159,15 +161,33 @@ def max_row_count(major: np.ndarray, num_major: int) -> int:
     return int(np.bincount(np.asarray(major), minlength=num_major).max())
 
 
+def check_type_word(sid: int, q_range, k_range, word: int) -> None:
+    """A slice's type word holds two bound bits and, above them, log2 of
+    the step (``common.enum.AttnMaskType``); anything else is no type."""
+    from ..common.enum import MASK_TYPE_BITS, MAX_MASK_STEP_LOG2
+
+    if not 0 <= word < (MAX_MASK_STEP_LOG2 + 1) << MASK_TYPE_BITS:
+        raise ValueError(
+            f"slice {sid} (q [{int(q_range[0])}, {int(q_range[1])}), "
+            f"k [{int(k_range[0])}, {int(k_range[1])})): bad mask type word "
+            f"{word} (type {word & 3}, step 2**{word >> MASK_TYPE_BITS}; a "
+            f"step is at most 2**{MAX_MASK_STEP_LOG2})"
+        )
+
+
 def _slice_k_span(
     gq_lo: int, gq_hi: int, ks: int, ke: int, qs: int, qe: int, mask_type: int
 ) -> tuple[int, int]:
-    """Global k interval attended by global q rows [gq_lo, gq_hi) of a slice."""
+    """Global k interval attended by global q rows [gq_lo, gq_hi) of a slice.
+
+    The bounds move ``1 << ls`` keys every as many rows, in blocks counted
+    from the slice's aligned corner (ls = 0: one key a row)."""
     k_lo, k_hi = ks, ke
+    ls = mask_type >> 2
     if mask_type & 1:  # causal: k - ke <= q - qe; max row gq_hi-1
-        k_hi = min(k_hi, ke - qe + gq_hi)
+        k_hi = min(k_hi, ke - ((qe - gq_hi) >> ls << ls))
     if mask_type & 2:  # inv-causal: k - ks >= q - qs; min row gq_lo
-        k_lo = max(k_lo, ks + (gq_lo - qs))
+        k_lo = max(k_lo, ks + ((gq_lo - qs) >> ls << ls))
     return k_lo, k_hi
 
 
@@ -275,10 +295,11 @@ def _needs_mask_flags(
     full &= (gq_lo >= qs) & (gq_hi < qe) & (gk_lo >= ks) & (gk_hi < ke)
     causal = (mt & 1) != 0
     inv = (mt & 2) != 0
+    ls = mt >> 2  # a stepped bound is whole on more tiles than a diagonal
     # causal worst corner: top row, rightmost col
-    full &= ~causal | ((gk_hi - ke) <= (gq_lo - qe))
+    full &= ~causal | (gk_hi < ke - ((qe - 1 - gq_lo) >> ls << ls))
     # inv-causal worst corner: bottom row, leftmost col
-    full &= ~inv | ((gk_lo - ks) >= (gq_hi - qs))
+    full &= ~inv | (gk_lo >= ks + ((gq_hi - qs) >> ls << ls))
     full &= ~dummy
     return (~full).astype(np.int64)
 
@@ -550,11 +571,12 @@ def _sub_area(a, b, c, d, qs, qe, ks, ke, mt) -> int:
 
     Row q attends cols [lo(q), hi(q)) with lo = ks + (q - qs) under an
     inv-causal bound (else ks) and hi = ke - qe + q + 1 under a causal bound
-    (else ke); vectorized over rows (host-side planning only).
+    (else ke), either in blocks of the type word's step; vectorized over
+    rows (host-side planning only).
     """
-    q = np.arange(a, b, dtype=np.int64)
-    lo = (ks + (q - qs)) if (mt & 2) else np.full_like(q, ks)
-    hi = (ke - qe + q + 1) if (mt & 1) else np.full_like(q, ke)
+    from ..common.mask import row_key_bounds
+
+    lo, hi = row_key_bounds(np.arange(a, b), qs, qe, ks, ke, mt)
     cnt = np.minimum(hi, d) - np.maximum(lo, c)
     return int(np.maximum(cnt, 0).sum())
 
@@ -640,7 +662,7 @@ def build_block_meta(
         assert 0 <= k_arr[s, 0] <= k_arr[s, 1] <= total_k, (
             f"slice {s}: bad k_range [{k_arr[s,0]},{k_arr[s,1]})"
         )
-        assert 0 <= t_arr[s] <= 3, f"slice {s}: bad mask type {t_arr[s]}"
+        check_type_word(s, q_arr[s], k_arr[s], int(t_arr[s]))
     slices = np.concatenate(
         [q_arr, k_arr, t_arr[:, None]], axis=1
     )  # [S, 5]

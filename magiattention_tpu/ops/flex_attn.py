@@ -155,10 +155,21 @@ class FlexAttnParams:
     bwd_steps: int = 0
     # "row_major" (static steps grid) or "sparse" (compact entry walk)
     grid: str = "row_major"
+    # the largest step of any slice the tables hold (bounds_mask_step);
+    # 1 compiles the mask arithmetic of a key a row
+    mask_step: int = 1
 
     @property
     def out_jnp_dtype(self):
         return jnp.dtype(self.out_dtype)
+
+
+def bounds_mask_step(bounds) -> int:
+    """The largest step among the slices of a (concrete, host-side)
+    ``slice_bounds`` table of any leading shape: what
+    ``FlexAttnParams.mask_step`` must be for the kernels to read it."""
+    words = np.asarray(bounds).reshape(-1, SLICE_FIELDS)[:, 4]
+    return 1 << (int(words.max()) >> 2) if words.size else 1
 
 
 def _default_interpret() -> bool:
@@ -230,7 +241,9 @@ def _resolve_steps(explicit: int, major, num_major: int) -> int:
 _BIG = 1 << 30
 
 
-def _entry_interval_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
+def _entry_interval_mask(
+    bounds, runs, sid_e, e, row0, col0, bq, bk, stepped: bool = False
+):
     """Boolean [bq, bk] mask for one entry via per-row k-intervals.
 
     Every mask condition an entry can impose — run window, slice bounds,
@@ -242,6 +255,13 @@ def _entry_interval_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
     ``_entry_mask`` applied unconditionally were both slower on
     dense-causal 64k in an earlier round whose records are gone (not
     measured on this tree).
+
+    ``stepped`` (static: ``FlexAttnParams.mask_step > 1``): the plan has a
+    slice whose bounds move in blocks (``AttnMaskType.with_step``). The
+    slice's type word carries log2 of its step above the two bound bits,
+    and the block index counted from the aligned corner is one AND of the
+    row column with ``-step``; no operand, no table. Off, the arithmetic
+    below is what it was before steps existed, to the instruction.
     """
     rbase = e * RUN_FIELDS
     ql0 = runs[rbase + 0]
@@ -261,14 +281,26 @@ def _entry_interval_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
 
     rl = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)  # local rows
     row_ok = (rl >= ql0) & (rl < ql1) & (rl + qoff >= q0) & (rl + qoff < q1)
+    if stepped:
+        # floor(x / step) * step, also below zero (rows outside the slice)
+        whole = -(jnp.int32(1) << (typ >> 2))
+
+    def inv_lo():
+        if stepped:
+            return (k0 - koff) + ((rl + (qoff - q0)) & whole)
+        return rl + (qoff - q0 + k0 - koff)
+
+    def causal_hi():
+        if stepped:
+            return (k1 - koff) - (((q1 - 1 - qoff) - rl) & whole)
+        return rl + (qoff - q1 + k1 - koff + 1)
+
+    # (called in place: at step 1 the operations come in the order they
+    # always came in, and the compiler schedules them as it did)
     lo = jnp.maximum(kl0, k0 - koff)
-    lo = jnp.where(
-        is_inv, jnp.maximum(lo, rl + (qoff - q0 + k0 - koff)), lo
-    )
+    lo = jnp.where(is_inv, jnp.maximum(lo, inv_lo()), lo)
     hi = jnp.minimum(kl1, k1 - koff)
-    hi = jnp.where(
-        is_causal, jnp.minimum(hi, rl + (qoff - q1 + k1 - koff + 1)), hi
-    )
+    hi = jnp.where(is_causal, jnp.minimum(hi, causal_hi()), hi)
     lo = jnp.where(row_ok, lo, _BIG)
     hi = jnp.where(row_ok, hi, -_BIG)
     cl = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)  # local cols
@@ -306,10 +338,13 @@ def _entry_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
     mask &= (gq >= q0) & (gq < q1) & (gk >= k0) & (gk < k1)
     is_causal = (typ & 1) == 1
     is_inv = (typ & 2) == 2
+    ls = typ >> 2  # log2 of the slice's step: block indices from the corner
     # CAUSAL (bottom-right aligned): allow iff (gk - k1) <= (gq - q1)
-    mask &= jnp.logical_or(~is_causal, (gk - k1) <= (gq - q1))
+    mask &= jnp.logical_or(
+        ~is_causal, ((k1 - 1 - gk) >> ls) >= ((q1 - 1 - gq) >> ls)
+    )
     # INVCAUSAL (top-left aligned): allow iff (gk - k0) >= (gq - q0)
-    mask &= jnp.logical_or(~is_inv, (gk - k0) >= (gq - q0))
+    mask &= jnp.logical_or(~is_inv, ((gk - k0) >> ls) >= ((gq - q0) >> ls))
     return mask
 
 
@@ -684,7 +719,8 @@ def _fwd_kernel_hb(
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
+            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
+            params.mask_step > 1,
         )
         _fwd_update(
             _mask_hb(s, mask, group, _MASK), v_ref[...], m_scr, l_scr, acc_scr
@@ -746,7 +782,8 @@ def _fwd_kernel(
     def _compute():
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
+            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
+            params.mask_step > 1,
         )
         _fwd_update(
             jnp.where(mask, s, _MASK), v_ref[0], m_scr, l_scr, acc_scr
@@ -1049,7 +1086,8 @@ def _dq_kernel(
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         s = jnp.where(
             _entry_interval_mask(
-                bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
+                bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
+                params.mask_step > 1,
             ),
             s,
             NEG_INF,
@@ -1106,7 +1144,8 @@ def _dq_kernel_hb(
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk
+            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
+            params.mask_step > 1,
         )
         s = _mask_hb(s, mask, group)
         _, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
@@ -1249,7 +1288,8 @@ def _dkv_kernel(
         s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
         s = jnp.where(
             _entry_interval_mask(
-                bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk
+                bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk,
+                params.mask_step > 1,
             ),
             s,
             NEG_INF,
@@ -1316,7 +1356,8 @@ def _dkv_kernel_hb(
     def _compute():
         s = _scores_hb(q_ref, k_ref, params, group)
         mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk
+            bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk,
+            params.mask_step > 1,
         )
         s = _mask_hb(s, mask, group)
         p, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
@@ -1770,6 +1811,7 @@ def flex_attn_with_meta(
         fwd_steps=meta.fwd_steps,
         bwd_steps=meta.bwd_steps,
         grid=str(grid),
+        mask_step=bounds_mask_step(meta.slice_bounds),
     )
     out_h, lse_h, rowmax = flex_attn_headmajor(
         qh, kh, vh, fwd_tables(meta), bwd_tables(meta), params, sink=sink

@@ -56,6 +56,7 @@ from ..meta.solver.overlap_solver import (
     simulate_overlap_timeline,
 )
 from ..ops.block_meta import (
+    RUN_FIELDS,
     SLICE_FIELDS,
     FlexAttnBlockMeta,
     Run,
@@ -64,7 +65,12 @@ from ..ops.block_meta import (
     runs_from_position_ids,
 )
 from ..ops.correction import correct_attn_out_lse
-from ..ops.flex_attn import FlexAttnParams, flex_attn_headmajor, stats_form
+from ..ops.flex_attn import (
+    FlexAttnParams,
+    bounds_mask_step,
+    flex_attn_headmajor,
+    stats_form,
+)
 from ..utils.instrument import named_scope
 
 
@@ -146,6 +152,22 @@ class StageTables:
             for weight, sid in ((2, self.fwd_sid), (1, self.bwd_sid))
         )
         return row_major, compact, float(live)
+
+    def stepped_tile_steps(self) -> float:
+        """Of :meth:`grid_steps`' live steps (same weights, the mean over
+        ranks), those whose tile a stepped bound crosses: entries of a
+        slice with a step above 1 that the planner did not find whole
+        (the runs table's needs-mask flag)."""
+        b = self.bounds.reshape(self.bounds.shape[0], -1, SLICE_FIELDS)
+        stepped = (b[..., 4] >> 2) > 0
+        total = 0.0
+        for weight, sid, runs in (
+            (2, self.fwd_sid, self.fwd_runs), (1, self.bwd_sid, self.bwd_runs)
+        ):
+            binds = runs.reshape(runs.shape[0], -1, RUN_FIELDS)[..., 6] != 0
+            crossed = np.take_along_axis(stepped, sid, axis=1) & binds
+            total += weight * crossed.sum(axis=1).mean()
+        return float(total)
 
     @staticmethod
     def from_rank_metas(metas: list[FlexAttnBlockMeta], kv_pad: int):
@@ -927,6 +949,10 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
         telemetry.record_flex_dead_step_share(
             100.0 * (1.0 - live / launched[grid])
         )
+    if live:
+        telemetry.record_flex_stepped_tile_share(
+            100.0 * sum(t.stepped_tile_steps() for t in tabs) / live
+        )
     return grid
 
 
@@ -958,18 +984,28 @@ def ensure_kernel_steps(params: FlexAttnParams, tables) -> FlexAttnParams:
     different plan's tables (too-small steps would drop entries with no
     error under tracing)."""
     fs = bs = 0
+    step = 1
     for t in tables:
         if t is None:
             continue
         a, b = t.kernel_steps()
         fs = max(fs, a)
         bs = max(bs, b)
-    if params.fwd_steps >= fs and params.bwd_steps >= bs:
+        step = max(step, bounds_mask_step(t.bounds))
+    if (
+        params.fwd_steps >= fs
+        and params.bwd_steps >= bs
+        and params.mask_step >= step
+    ):
         return params
+    # mask_step rides with the extents: it too is read off the tables the
+    # kernels will walk, and too small a value would read a stepped slice
+    # as a diagonal with no error
     return dataclasses.replace(
         params,
         fwd_steps=max(params.fwd_steps, fs),
         bwd_steps=max(params.bwd_steps, bs),
+        mask_step=max(params.mask_step, step),
     )
 
 

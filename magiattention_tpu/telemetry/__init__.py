@@ -69,6 +69,8 @@ from .collectors import (  # noqa: F401
     record_degraded_path,
     record_dispatch_meta,
     record_flex_dead_step_share,
+    record_flex_stepped_tile_share,
+    record_mask_step,
     record_flex_kernel_build,
     record_model_attn_plan,
     record_mla_kv_cast_width,
@@ -357,6 +359,8 @@ __all__ = [
     "record_degraded_path",
     "record_dispatch_meta",
     "record_flex_dead_step_share",
+    "record_flex_stepped_tile_share",
+    "record_mask_step",
     "record_flex_kernel_build",
     "record_model_attn_plan",
     "record_mla_kv_cast_width",

@@ -125,6 +125,15 @@ M_FLEX_KERNEL_BUILDS = "magi_flex_kernel_build_total"
 # the steps the chosen grid launches over forward, dq and dkv that do no
 # work (dead row-major steps; padded and dummy entries on both grids)
 M_FLEX_DEAD_STEP_SHARE = "magi_flex_dead_step_share"
+# gauge — the newest plan's tables (make_attn_params): percent of the
+# live steps over forward, dq and dkv whose tile a stepped bound crosses
+# (entries of a slice with a step above 1 that are not whole: the tiles
+# that pay for the staircase); 0 for a mask with no stepped slice
+M_FLEX_STEPPED_TILE_SHARE = "magi_flex_stepped_tile_share"
+# gauge — the largest step of any slice of a model's attention mask, by
+# attention kind (models/_common._plan_on_dispatch): {kind=sliding|full};
+# 1: every bound moves a key a row
+M_MASK_STEP = "magi_mask_step"
 # attention plans a model builder made, by attention kind
 # (models/pattern.py: one dispatch, a plan per kind): {kind=sliding|full}
 M_MODEL_ATTN_PLANS = "magi_model_attn_plans_total"
@@ -1154,6 +1163,22 @@ def record_flex_dead_step_share(pct: float) -> None:
     if not _enabled():
         return
     get_registry().gauge_set(M_FLEX_DEAD_STEP_SHARE, pct)
+
+
+def record_flex_stepped_tile_share(pct: float) -> None:
+    """Share of the live steps whose tile a stepped bound crosses, of the
+    plan ``parallel/dist_attn.make_attn_params`` was handed."""
+    if not _enabled():
+        return
+    get_registry().gauge_set(M_FLEX_STEPPED_TILE_SHARE, pct)
+
+
+def record_mask_step(kind: str, step: int) -> None:
+    """The largest step among a model's mask slices of attention
+    ``kind`` (host side, at plan time)."""
+    if not _enabled():
+        return
+    get_registry().gauge_set(M_MASK_STEP, float(step), kind=kind)
 
 
 def record_model_attn_plan(kind: str) -> None:

@@ -40,6 +40,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from ..common.mask import row_key_bounds
 from ..utils.cost import TPU_PEAK_SPECS
 
 # Per-live-grid-step fixed overhead (seconds): calibrated so the modeled
@@ -352,10 +353,11 @@ def slice_block_k_spans(
     hi = np.minimum(q1, (idx + 1) * block_q)  # last row (exclusive)
     k_lo = np.full(idx.shape, k0, dtype=np.int64)
     k_hi = np.full(idx.shape, k1, dtype=np.int64)
+    ls = mt >> 2  # log2 of the slice's step (common/enum.AttnMaskType)
     if mt & 1:  # causal: k - ke <= q - qe
-        k_hi = np.minimum(k_hi, k1 - q1 + hi)
+        k_hi = np.minimum(k_hi, k1 - ((q1 - hi) >> ls << ls))
     if mt & 2:  # inv-causal: k - ks >= q - qs
-        k_lo = np.maximum(k_lo, k0 + (lo - q0))
+        k_lo = np.maximum(k_lo, k0 + ((lo - q0) >> ls << ls))
     return idx, lo, hi, k_lo, k_hi
 
 
@@ -407,13 +409,10 @@ def exact_mask_area(q_ranges, k_ranges, attn_type_map) -> int:
     if hit is None:
         total = 0
         for (q0, q1), (k0, k1), mt in zip(q.tolist(), k.tolist(), t.tolist()):
-            rows = np.arange(q0, q1, dtype=np.int64)
-            r_lo = np.full(rows.shape, k0, dtype=np.int64)
-            r_hi = np.full(rows.shape, k1, dtype=np.int64)
-            if mt & 1:  # causal: k - ke <= q - qe  (row-exact: hi row+1)
-                r_hi = np.minimum(r_hi, k1 - q1 + rows + 1)
-            if mt & 2:  # inv-causal: k - ks >= q - qs
-                r_lo = np.maximum(r_lo, k0 + (rows - q0))
+            # row-exact: the interval of every row, under the slice's step
+            r_lo, r_hi = row_key_bounds(
+                np.arange(q0, q1), q0, q1, k0, k1, mt
+            )
             total += int(np.maximum(r_hi - r_lo, 0).sum())
         if len(_ENTRY_MEMO) >= _ENTRY_MEMO_CAP:
             _ENTRY_MEMO.clear()

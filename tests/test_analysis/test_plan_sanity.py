@@ -54,6 +54,8 @@ def test_attn_slice_objects_accepted():
 
     s = AttnSlice(AttnRange(0, 64), AttnRange(0, 64), AttnMaskType.CAUSAL)
     validate_slices([s], 64, 64)
+    # a stepped type's word (ISSUE 42): 9 = CAUSAL at step 4
+    validate_slices([(0, 64, 0, 64, int(AttnMaskType.CAUSAL.with_step(4)))], 64, 64)
 
 
 @pytest.mark.parametrize(
@@ -64,7 +66,8 @@ def test_attn_slice_objects_accepted():
         (0, 64, 0, 96, 0),  # k OOB
         (8, 8, 0, 64, 0),  # empty q
         (0, 64, 16, 16, 0),  # empty k
-        (0, 64, 0, 64, 9),  # unknown type
+        (0, 64, 0, 64, 99),  # unknown type: a step past 2**15
+        (0, 64, 0, 64, -1),  # unknown type
         (0, 64, 0, 16, 3),  # bicausal with empty rows
     ],
 )

@@ -109,6 +109,24 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("rung", [(128, 512, 8), (128, 128, 1)],
+                         ids=["heads-batched", "per-head"])
+def test_stepped_bound_at_the_block_diffusion_cells_shapes(topo, grid, rung):
+    """ISSUE 42: the interval mask's block index (one ``and`` of the row
+    column with a scalar ``-step``, the step read from the slice's type
+    word) compiles in the forward, dq and dkv on both grids, at the
+    SDAR cell's head geometry (32 query / 4 key-value heads of 128) on a
+    4,096-row ``[noisy ; clean]`` mask of three documents."""
+    from magiattention_tpu.api import infer_block_diffusion_mask
+
+    qr, kr, ts = infer_block_diffusion_mask([0, 1280, 1864, 2048], 4)
+    mask = (qr.to_naive_ranges(), kr.to_naive_ranges(), [int(x) for x in ts])
+    chip = SingleDeviceSharding(topo.devices[0])
+    text = _compile_fwd_bwd(chip, mask, 4096, 32, 4, 128, rung, grid)
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize(
     "t,hq,hk,rung",
     [

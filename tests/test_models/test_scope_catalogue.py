@@ -1,6 +1,6 @@
 """Every operation the device runs lies under exactly one *part* scope
 that a per-layer metric reads (``docs/observability.md``, "Device
-scopes"): the five toy models' train steps and the bare attention call
+scopes"): the six toy models' train steps and the bare attention call
 are compiled here, on the CPU, their ``op_name``s read as the benchmark
 reads them (``trace_reduce.hlo_scopes``), and held against the patterns
 of the metric files themselves, so a scope that is renamed, dropped or
@@ -26,6 +26,7 @@ from magiattention_tpu.models.pattern import (
     build_magi_pattern, init_pattern_params,
 )
 from tests.test_models import test_pattern as toy
+from tests.test_models import test_pattern_blockdiff as blockdiff
 from tests.test_models.test_pattern_cca import _zaya
 from tests.test_models.test_pattern_looped import _ouro
 
@@ -104,6 +105,11 @@ CASES = {
         "magi_head", "magi_attn_full", "magi_cca_mix",
         r"checkpoint/magi_cca_mix",  # a sibling of magi_proj, not inside it
     ],
+    "blockdiff": STEP + [s for s in EXPERTS if s != "magi_moe_shared"] + [
+        "magi_head", "magi_attn_full",
+        # a cross-cut: its operations carry their part too
+        r"magi_diffusion_io\S*magi_embed", r"magi_diffusion_io\S*magi_head",
+    ],
     "attn-fwd-cp1": ATTN_CALL,
     "attn-fwdbwd-cp1": ATTN_CALL + ATTN_BWD + ATTN_DLSE,
     "attn-fwd-cp2": ATTN_CALL + [r"magi_merged_cast\S*magi_group_cast"],
@@ -142,6 +148,26 @@ def _step_text(name: str) -> str:
     )
 
 
+def _blockdiff_step_text() -> str:
+    """The doubled sequence's step: its batch is twice the tokens' rows
+    and takes the rows' weights."""
+    cfg = blockdiff._sdar()[1]
+    model, _ = build_magi_pattern(
+        cfg, toy._mesh(1), list(blockdiff._mask().cu_seqlens),
+        chunk_size=blockdiff.CHUNK,
+    )
+    params = init_pattern_params(jax.random.PRNGKey(0), cfg)
+    opt = optax.adamw(1e-3)
+    batch = jnp.zeros((1, 2 * blockdiff.TOKENS), jnp.int32)
+    return (
+        model.make_train_step(opt)
+        .lower(params, opt.init(params), batch, batch, batch,
+               batch.astype(jnp.float32))
+        .compile()
+        .as_text()
+    )
+
+
 def _attn_text(name: str) -> str:
     _attn, phase, cp = name.split("-")
     cp = int(cp[2:])
@@ -173,7 +199,10 @@ def _attn_text(name: str) -> str:
 def test_every_heavy_operation_lies_under_exactly_one_part(case):
     with jax.enable_x64(False):
         is_attn = case.startswith("attn")
-        text = _attn_text(case) if is_attn else _step_text(case)
+        if case == "blockdiff":
+            text = _blockdiff_step_text()
+        else:
+            text = _attn_text(case) if is_attn else _step_text(case)
     scopes = trace_reduce.hlo_scopes(text)
     # as the metrics see an operation: "<instruction name> <scope>"
     lines = {f"{name} {scope}" for name, scope in scopes.items()}
@@ -209,12 +238,16 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
         "looped": ["magi_moe_", "magi_mtp"],
         "cca": ["magi_mla_", "magi_mtp", "magi_exit_head", "magi_moe_shared",
                 "magi_proj/magi_cca_mix", "magi_cca_mix/magi_proj"],
+        "blockdiff": ["magi_mla_", "magi_mtp", "magi_exit_head",
+                      "magi_moe_shared", "magi_cca_mix"],
     }.get(case, ["magi_proj", "magi_ffn", "magi_head", "magi_optimizer"])
     for scope in absent:
         assert not any(scope in s for s in lines), scope
     if not is_attn:
         # every cca layer is an expert layer: magi_ffn holds its norm and
         # its residual add, nothing heavy
-        own = {"cca_mix", "moe"} if case == "cca" else {"ffn"}
+        own = {"cca": {"cca_mix", "moe"}, "blockdiff": {"moe"}}.get(
+            case, {"ffn"}
+        )
         assert {"proj", "flex", "layout", "embed"} | own <= set(seen), seen
         assert ("exit_head" if case == "looped" else "head") in seen, seen
