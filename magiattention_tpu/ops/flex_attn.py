@@ -4,10 +4,16 @@ TPU-native equivalent of the reference FFA CUDA kernel
 (csrc/flexible_flash_attention/, SURVEY.md §2.7 module A): attention over an
 arbitrary list of (q_range, k_range, mask_type) slices with online softmax,
 GQA, softcap, attention sink, LSE + per-row max-logit outputs, and a
-two-kernel backward (dq q-major / dkv k-major) needing no atomics: the
-sequential TPU grid walks a host-precomputed entry table (ops/block_meta.py)
-so tiles of the same output block are consecutive and accumulate in VMEM
-scratch.
+one-kernel backward needing no atomics: the sequential TPU grid walks a
+host-precomputed entry table (ops/block_meta.py) so tiles of the same
+output block are consecutive and accumulate in VMEM scratch. The forward
+walks the q-major table. The backward walks the k-major one and computes
+S, the mask, P, dP and dS once a tile (five matmuls: S, dP, dV, dK, dQ):
+dk and dv accumulate in VMEM per k block, and dq, whose q block comes
+back once a key column, is a float32 buffer in HBM that each step reads,
+adds to and writes back by its own DMAs, a tile's read started a step
+ahead (``_dq_accumulate`` keeps the orderings; docs/block_sparse.md has
+the bytes a step moves and why the walk is k-major).
 
 Entries carry run fields (local window + local->global offset), so the same
 kernels serve the distributed runtime where each rank's Q/KV buffers are
@@ -71,8 +77,8 @@ def _flex_pallas_call(
 ):
     """Where every flex ``pallas_call`` is built (trace time). The call is
     named by role, not by grid kind: magi_flex_fwd_kernel,
-    magi_flex_dq_kernel, magi_flex_dkv_kernel. The name enters the custom
-    call's jax scope (.../magi_flex_dq_kernel/pallas_call), which is what a
+    magi_flex_bwd_kernel. The name enters the custom
+    call's jax scope (.../magi_flex_bwd_kernel/pallas_call), which is what a
     device trace and the benchmark's per-kernel metrics read; keep it
     matching magi_\\w*kernel, the roofline metrics' pattern. The build is
     counted with the q heads one grid step takes and the grid it walks
@@ -80,7 +86,8 @@ def _flex_pallas_call(
     a snapshot says which of the per-head and head-batched forms ran, and
     on which of :data:`GRID_KINDS`. ``form``: the labels that say in which
     form a side operand crosses this kernel's boundary (the forward's
-    ``stats=compact|lanes``, dq's ``delta=kernel``; :func:`stats_form`)."""
+    ``stats=compact|lanes``, the backward's ``delta=xla``;
+    :func:`stats_form`)."""
     from .. import telemetry
 
     telemetry.record_flex_kernel_build(
@@ -102,6 +109,10 @@ def _compiler_params(*dimension_semantics: str):
 # serves both (:class:`_Walk`); the keyed runtime picks per plan
 # (``parallel/dist_attn.make_attn_params``)
 GRID_KINDS = ("row_major", "sparse")
+# the form of every plan's backward (the ``bwd_form`` of the ``attn_fn_build``
+# span and of ``magi_flex_bwd_form_total``): one k-major kernel. The split
+# form it replaced (dq q-major, then dkv; PR 43) ran seven matmuls a tile
+BWD_FORM = "fused"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,9 +120,9 @@ class FlexAttnParams:
     """Static parameters closed over by the kernels (hashable).
 
     ``head_block``: q heads processed per grid step (1 = head-per-step),
-    by the forward, dq and dkv on both grids (dkv takes the
-    ``head_block // group`` kv heads' groups, so its ``group`` grid
-    dimension is inside the step). Batching heads amortizes per-step grid
+    by the forward and the backward on both grids (the backward takes
+    the ``head_block // group`` kv heads' groups, so the per-head form's
+    ``group`` grid dimension is inside the step). Batching heads amortizes per-step grid
     overhead — the dominant cost on small tiles — and fetches a K/V tile
     once for the group, at the price of head_block x VMEM: a backward
     step too large for it stays per head (``_bwd_head_block``, a test on
@@ -126,10 +137,11 @@ class FlexAttnParams:
     Measured on a v5e (PR 27, PERF.md section 6; the "round 5" reading of
     a flat grid at 76 against 132 TF/s on dense 64k has no record and did
     not repeat): a dead row-major step costs 0.21-0.30 us at 8 heads a
-    step, and 2.1 us in the per-head dkv, where it still moves data; the
+    step, and 2.1 us in a per-head k-major step, where it still moves data; the
     compact walk adds -0.015 to +0.035 us to a live step. On the packed
     64k cell, 79% dead, the compact grid took the forward from 131.8 to
-    109.0 ms, dq from 107.6 to 74.7, dkv from 128.4 to 96.3; on a window
+    109.0 ms, the then two backward kernels from 107.6 to 74.7 and from
+    128.4 to 96.3; on a window
     mask with 1% dead it changed nothing (+0.4 / -0.2 / +0.2 ms of 48 /
     32 / 40). ``make_attn_params`` counts both grids' steps for a plan
     and prices them with those two costs
@@ -137,8 +149,8 @@ class FlexAttnParams:
     ``"row_major"``.
 
     ``fwd_steps``/``bwd_steps``: the row-major grid's static inner
-    extents — the max entries on any q block (fwd/dq) resp. k block
-    (dkv). 0 = derive from concrete tables at launch; traced (per-rank
+    extents — the max entries on any q block (forward) resp. k block
+    (backward). 0 = derive from concrete tables at launch; traced (per-rank
     stacked) tables require the plan builder to set them host-side. The
     compact grid has no such extent.
     """
@@ -405,10 +417,10 @@ def _check_head_block(hbg: int, hq: int, group: int) -> None:
 
 class _Walk:
     """Where a step stands in its entry table, on either grid
-    (``params.grid``, :data:`GRID_KINDS`). The step bodies of the forward,
-    dq and dkv, per head and head-batched, read four scalars from it and
-    nothing else of the grid: the entry ``e``, its major block ``i`` (a q
-    block for the forward and dq, a k block for dkv), whether the step is
+    (``params.grid``, :data:`GRID_KINDS`). The step bodies of the forward
+    and the backward, per head and head-batched, read four scalars from it
+    and nothing else of the grid: the entry ``e``, its major block ``i`` (a q
+    block for the forward, a k block for the backward), whether the step is
     the block's ``first()`` (initialize the accumulators) and its
     ``last()`` (write the block).
 
@@ -420,7 +432,7 @@ class _Walk:
     too, whose mask is empty.
 
     ``inner``: the grid has one more dimension inside the walk (the
-    per-head dkv's GQA group), read as ``g``. The predicates are made
+    per-head backward's GQA group), read as ``g``. The predicates are made
     where they are asked for, so the row-major program is traced in the
     order it always was."""
 
@@ -456,16 +468,20 @@ class _Walk:
 def _walk_grid(
     grid: str, heads: int, major, num_major: int, steps: int,
     stream_head=lambda h: h, inner: tuple[int, ...] = (),
+    blocks: str = "parallel",
 ):
     """(grid, index map of the blocks that stay per major block, index map
     of the blocks streamed per entry, dimension semantics) of a launcher.
     The seven scalar-prefetch operands arrive as (major table, minor
     table, slice ids, runs, bounds, row starts, row counts): q-major for
-    the forward and dq, k-major for dkv. ``steps`` is the params' static
+    the forward, k-major for the backward. ``steps`` is the params' static
     extent (``fwd_steps`` / ``bwd_steps``), which only the row-major grid
     has. ``inner``: extents of grid dimensions inside the walk, and
     ``stream_head(h, *their ids)`` the head block of the streamed operand
-    (the same as the resident one's in the head-batched layout)."""
+    (the same as the resident one's in the head-batched layout).
+    ``blocks``: the row-major grid's semantics of the axis that walks the
+    major blocks; ``"arbitrary"`` for the backward, whose k blocks add
+    into one dq tile (the compact grid's entry axis always is)."""
     n = len(inner)
     if grid == "sparse":
 
@@ -489,7 +505,7 @@ def _walk_grid(
 
     return (
         (heads, num_major, _resolve_steps(steps, major, num_major), *inner),
-        stay, stream, ("parallel", "parallel", *["arbitrary"] * (n + 1)),
+        stay, stream, ("parallel", blocks, *["arbitrary"] * (n + 1)),
     )
 
 
@@ -533,7 +549,7 @@ def _fwd_update(s, v, m_scr, l_scr, acc_scr):
     the older form (one-lane ``m`` and ``l`` columns, two cross-lane
     reductions, four ``-inf`` guards) made the forward cost 11.0 cycles a
     logit vreg at block_k 512 and 6.1 at 1024 against the MXU's 4, where
-    dq and dkv cost the same at either width (PERF.md section 6, PR 29;
+    the backward's kernels cost the same at either width (PERF.md section 6, PR 29;
     docs/block_sparse.md). So the state is kept cheap, and the chip priced
     each piece (packed 64k cell, forward kernel 109.0 ms before):
 
@@ -584,7 +600,7 @@ def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
     """What a q block holds once its last entry is done: (out f32
     (..., rows, d), lse (..., rows, LANES), rowmax (..., rows, LANES)), the
     two statistics replicated over lanes, which is the form the state is
-    kept in and the form dq and dkv read lse in (the backward's residual).
+    kept in and the form the backward reads lse in (its residual).
     What is returned to the caller crosses the boundary with rows along
     lanes instead, 1/128 of the bytes (:func:`_write_stats`).
     ``sinks``: the rows' sink logits, (..., rows, 1) or one scalar, or None.
@@ -645,22 +661,14 @@ def _store_rows_along_lanes(ref, stats):
                 )
 
 
-def _load_rows_as_column(ref, h: int, c: int, diag):
-    """The other way: rows ``c .. c + 128`` of head ``h`` of a
-    ``(1, 1, 1, heads, bq)`` block with rows along lanes, as a (128, 1)
-    column, the same values. ``diag``: :func:`_diag`'s."""
-    row = ref[0, 0, 0, h : h + 1, c : c + LANES]
-    return jnp.max(jnp.where(diag, row, NEG_INF), axis=1, keepdims=True)
-
-
 def _write_stats(lse, rowmax, refs, compact: bool):
     """Store a q block's (heads, bq, LANES) lse and row maximum. ``lanes``
     form: as they are, into two (heads, bq, LANES) blocks. ``compact``
     form: both with rows along lanes into ONE ``(1, 1, 2, heads, bq)``
     block (on the compact grid every blocked operand costs a table lookup
     a step: 0.9 ms a call of 233 k steps, docs/block_sparse.md), and lse
-    once more as it is where a second block asks for it: the residual dq
-    and dkv read, which only the differentiated forward writes."""
+    once more as it is where a second block asks for it: the residual the
+    backward reads, which only the differentiated forward writes."""
     if not compact:
         refs[0][...], refs[1][...] = lse, rowmax
         return
@@ -813,19 +821,12 @@ def _rows_from_compact(x, hq: int, tqp: int):
     return tuple(x.reshape(x.shape[0], hq, tqp))
 
 
-def _rows_to_compact(x, hbg: int, bq: int):
-    """[hq, tqp] -> (hq / HBG, nq, 1, HBG, bq)."""
-    hq, tqp = x.shape
-    x = x.reshape(hq // hbg, hbg, tqp // bq, 1, bq)
-    return jnp.transpose(x, (0, 2, 3, 1, 4))
-
-
 def _fwd_pallas(
     q, k, v, sink2d, tables, params: FlexAttnParams, residual: bool = False
 ):
     """q [hq, tqp, d]; k/v [hk, tkp, d]; tables from fwd_tables(). Returns
     (out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp], and lse replicated
-    over lanes [hq, tqp, LANES] if ``residual``: what dq and dkv read, else
+    over lanes [hq, tqp, LANES] if ``residual``: what the backward reads, else
     None).
 
     The two statistics leave the kernel in :func:`stats_form`'s form. In
@@ -930,30 +931,28 @@ def _fwd_pallas(
 
 
 # ---------------------------------------------------------------------------
-# backward: dq (q-major walk)
+# backward: one k-major walk; dk / dv in VMEM, dq added into HBM
 # ---------------------------------------------------------------------------
 
 
 def _bwd_p_ds(
     s, lse_ref, do_ref, v_ref, delta_ref, params: FlexAttnParams, hb=None
 ):
-    """Shared backward core for all four bwd kernel bodies (dq and dkv,
-    per head and head-batched, each on both grids): probabilities from the
-    stored lse and the masked logits, then ``ds = p * (dP - delta)`` with
-    the softcap derivative and the off-mask NaN guard. This block is
-    numerically delicate and MUST stay in lockstep across bodies — one
-    copy only, as :func:`_fwd_update` is the forward's.
+    """Shared core of both backward bodies (per head and head-batched,
+    each on both grids): probabilities from the stored lse and the masked
+    logits, then ``ds = p * (dP - delta)`` with the softcap derivative and
+    the off-mask NaN guard. This block is numerically delicate and MUST
+    stay in lockstep across bodies — one copy only, as :func:`_fwd_update`
+    is the forward's.
 
     ``hb=None``: the per-head kernels' (1, rows, .) blocks and 2-D ``s``.
     ``hb=HB``: the head-batched kernels' blocks, the q-side ones
     (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``.
 
     ``lse`` and ``delta`` arrive replicated over the 128 lanes (the
-    differentiated forward writes lse so, its residual; dq makes delta so
-    in its block's first step, :func:`_delta_init`, reads it back from its
-    own output block and hands it to dkv) and are used at that shape, as
-    the forward uses its running
-    maximum (:func:`_probs`): the (rows, bk) tiles ``s`` and ``dP`` are
+    differentiated forward writes lse so, its residual; delta is made so
+    before the kernel, :func:`_bwd_delta`) and are used at that shape, as
+    the forward uses its running maximum (:func:`_probs`): the (rows, bk) tiles ``s`` and ``dP`` are
     taken in static, vreg-aligned slices of 128 lanes, each of the shape
     of the two statistics, so nothing is cut to a one-lane column and
     broadcast back over the tile, and the uncovered-row guard is one
@@ -1017,238 +1016,79 @@ def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
     return hbg if live <= _BWD_HB_LIVE_BYTES else 1
 
 
-def _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact: bool):
-    """A q block's ``delta = sum(dO * out) - dlse`` per row, replicated
-    over lanes into dq's second output block (heads, bq, LANES): made in
-    the block's first step, where dO already is and ``out`` is brought
-    beside it, read by every step of the block (:func:`_bwd_p_ds`) and,
-    from HBM, by dkv. The lse cotangent folds into delta: with out =
-    softmax(s) @ v and lse = logsumexp(s), dL/ds = p * (dP - (delta -
-    dlse)), which is what makes multi-stage lse-merging differentiable
-    with stage-local lse. ``dlse_ref``: the cotangent's operand if there
-    is one, as a list: none where it is a symbolic zero; a
-    ``(1, 1, 1, heads, bq)`` block, rows along lanes, turned to columns
-    here once a block (``dlse_compact``); or one replicated over lanes by
-    XLA (small test blocks, :func:`stats_form`)."""
-    delta = jnp.sum(
-        do_ref[...].astype(jnp.float32) * out_ref[...].astype(jnp.float32),
-        axis=-1,
-        keepdims=True,
-    )
-    if not dlse_ref:
-        delta_ref[...] = jnp.broadcast_to(delta, delta_ref.shape)
-        return
-    (dlse_ref,) = dlse_ref
-    if not dlse_compact:
-        delta_ref[...] = delta - dlse_ref[...]
-    else:
-        heads, bq, _ = delta_ref.shape
-        diag = _diag()
-        for h in range(heads):
-            for c in range(0, bq, LANES):
-                delta_ref[h, c : c + LANES, :] = jnp.broadcast_to(
-                    delta[h, c : c + LANES]
-                    - _load_rows_as_column(dlse_ref, h, c, diag),
-                    (LANES, LANES),
-                )
+def _dq_accumulate(dq_hbm, buf, sem, st, add, *, tile, nxt, first, last, bq):
+    """One live step's share of dq, on a walk that does not keep a q block
+    in place: the float32 tile ``tile = (first head, q block)`` of the
+    ``[hq, tqp, d]`` buffer ``dq_hbm`` is waited for (its read into one of
+    ``buf``'s two VMEM slots was started by the step before), ``add(slot
+    ref)`` adds this step's ``scale * dS K`` to it, its write-back is
+    started, and the read of ``nxt``, the next live step's tile, is started
+    into the other slot. The steps of one core run in order, so the buffer
+    in HBM needs no atomics; two orderings are kept by the step itself:
+
+    - a read of a tile never starts while a write to the same tile is in
+      flight: where ``nxt == tile`` (a column boundary, two slices on one
+      tile, a mask with one q block) the tile stays in its slot and makes
+      no round trip; a tile written a step earlier has been waited for
+      (below) before any read starts;
+    - a slot is read into only when its last write-back has landed.
+
+    ``first`` / ``last``: the first and the last live step of this head
+    block's walk. The first reads its own tile, the last waits for its own
+    write: a head block begins and ends with nothing in flight, so the
+    head axis may be ``parallel``. ``st`` (SMEM, 3 words) carries the slot,
+    whether the tile is already in it, and whether the other slot's write
+    is in flight; ``sem`` is ``DMA((2, 2))``: reads, writes x slot."""
+    heads = buf.shape[1]
+
+    def hbm(t):
+        head0, qb = t
+        rows = pl.ds(pl.multiple_of(qb * bq, bq), bq)
+        return dq_hbm.at[pl.ds(head0, heads), rows]
+
+    def read(t, slot):
+        return pltpu.make_async_copy(hbm(t), buf.at[slot], sem.at[0, slot])
+
+    def write(t, slot):
+        return pltpu.make_async_copy(buf.at[slot], hbm(t), sem.at[1, slot])
+
+    @pl.when(first)
+    def _start():
+        st[0] = 0
+        st[1] = 0
+        st[2] = 0
+        read(tile, 0).start()
+
+    cur = st[0]
+
+    @pl.when(st[1] == 0)
+    def _arrive():
+        read(tile, cur).wait()
+
+    add(buf.at[cur])
+    keep = jnp.logical_not(last) & (nxt[0] == tile[0]) & (nxt[1] == tile[1])
+    st[1] = keep.astype(jnp.int32)
+
+    @pl.when(jnp.logical_not(keep))
+    def _leave():
+        write(tile, cur).start()
+
+        @pl.when(st[2] == 1)
+        def _landed():
+            write(tile, 1 - cur).wait()
+
+        @pl.when(last)
+        def _drain():
+            write(tile, cur).wait()
+
+        @pl.when(jnp.logical_not(last))
+        def _ahead():
+            read(nxt, 1 - cur).start()
+            st[0] = 1 - cur
+            st[2] = 1
 
 
-def _dq_kernel(
-    qblk,
-    kblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    lse_ref,
-    out_ref,
-    *refs,  # [dlse], dq, delta (1, bq, LANES), the dq scratch
-    params: FlexAttnParams,
-    dlse_compact: bool,
-):
-    *dlse_ref, dq_ref, delta_ref, dq_scr = refs
-    bq, bk = params.block_q, params.block_k
-    w = _Walk(params.grid, qblk, rs, rc)
-    i, e = w.i, w.e
-
-    @pl.when(w.first())
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact)
-
-    @w.when_live
-    def _compute():
-        s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-        s = jnp.where(
-            _entry_interval_mask(
-                bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
-                params.mask_step > 1,
-            ),
-            s,
-            NEG_INF,
-        )
-        _, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params)
-        dq_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds.astype(k_ref.dtype),
-            k_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(w.last())
-    def _write():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _dq_kernel_hb(
-    qblk,
-    kblk,
-    sid,
-    runs,
-    bounds,
-    rs,
-    rc,
-    q_ref,  # (HBG, bq, d)
-    k_ref,  # (HB, bk, d)
-    v_ref,
-    do_ref,  # (HBG, bq, d)
-    lse_ref,  # (HBG, bq, LANES)
-    out_ref,  # (HBG, bq, d)
-    *refs,  # [dlse], dq (HBG, bq, d), delta (HBG, bq, LANES), the scratch
-    params: FlexAttnParams,
-    group: int,
-    dlse_compact: bool,
-):
-    """Head-batched dq, the layout of :func:`_fwd_kernel_hb` on either
-    grid (:class:`_Walk` over the q-major table): one K/V tile serves the
-    HB kv heads' G q heads each, so QK^T, dO V^T and dS K are one batched
-    MXU call each over (HB, G*bq) stacked rows. The scratch is
-    (HB, G*bq, d) float32."""
-    *dlse_ref, dq_ref, delta_ref, dq_scr = refs
-    bq, bk = params.block_q, params.block_k
-    hb = k_ref.shape[0]
-    w = _Walk(params.grid, qblk, rs, rc)
-    i, e = w.i, w.e
-
-    @pl.when(w.first())
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        _delta_init(do_ref, out_ref, dlse_ref, delta_ref, dlse_compact)
-
-    @w.when_live
-    def _compute():
-        s = _scores_hb(q_ref, k_ref, params, group)
-        mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, i * bq, kblk[e] * bk, bq, bk,
-            params.mask_step > 1,
-        )
-        s = _mask_hb(s, mask, group)
-        _, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
-        dq_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds.astype(k_ref.dtype),
-            k_ref[...],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(w.last())
-    def _write():
-        dq_ref[...] = dq_scr[...].reshape(dq_ref.shape).astype(dq_ref.dtype)
-
-
-def _dq_pallas(q, k, v, do, lse, out, dlse, tables, params: FlexAttnParams):
-    """(dq [hq, tqp, d] in q's dtype, delta [hq, tqp, LANES] float32).
-    ``out``: the forward's, head-major, read once a q block for delta
-    (:func:`_delta_init`). ``dlse``: the lse cotangent [hq, tqp], or None
-    for a symbolic zero; it crosses in :func:`stats_form`'s form."""
-    qblk, kblk, sid, runs, bounds = tables
-    hq, tqp, d = q.shape
-    hk = k.shape[0]
-    group = hq // hk
-    hbg = _bwd_head_block(params, hq, group)
-    bq, bk = params.block_q, params.block_k
-    nq = tqp // bq
-    rs, rc = _row_tables(qblk, nq)
-    dlse_compact = stats_form(bq) == "compact"
-
-    # grid (hq/HBG, nq, steps), or the compact (hq/HBG, E)
-    if hbg > 1:
-        # head-batched, the forward's layout
-        hb = hbg // group
-        body = functools.partial(
-            _dq_kernel_hb, params=params, group=group,
-            dlse_compact=dlse_compact,
-        )
-        scratch = pltpu.VMEM((hb, group * bq, d), jnp.float32)
-        k_head = lambda h: h  # noqa: E731
-    else:
-        hb = 1
-        body = functools.partial(
-            _dq_kernel, params=params, dlse_compact=dlse_compact
-        )
-        scratch = pltpu.VMEM((bq, d), jnp.float32)
-        k_head = lambda h: h // group  # noqa: E731
-    grid, qmap, kmap, semantics = _walk_grid(
-        params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
-    )
-
-    dlse_in, dlse_specs = [], []
-    if dlse is not None:
-        with named_scope("magi_bwd_delta"):
-            dlse = dlse.astype(jnp.float32)
-            if dlse_compact:
-                dlse_in = [_rows_to_compact(dlse, hbg, bq)]
-                dlse_specs = [_compact_spec(1, hbg, bq, qmap)]
-            else:
-                dlse_in = [
-                    jnp.broadcast_to(dlse[:, :, None], (hq, tqp, LANES))
-                ]
-                dlse_specs = [pl.BlockSpec((hbg, bq, LANES), qmap)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-            pl.BlockSpec((hbg, bq, d), qmap),
-            *dlse_specs,
-        ],
-        out_specs=[
-            pl.BlockSpec((hbg, bq, d), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-        ],
-        scratch_shapes=[scratch],
-    )
-    return _flex_pallas_call(
-        "dq",
-        hbg,
-        params.grid,
-        body,
-        form={"delta": "kernel"},
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hq, tqp, d), q.dtype),
-            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32),
-        ],
-        interpret=params.interpret,
-        compiler_params=_compiler_params(*semantics),
-    )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, out, *dlse_in)
-
-
-# ---------------------------------------------------------------------------
-# backward: dk/dv (k-major walk; GQA group = innermost grid dim)
-# ---------------------------------------------------------------------------
-
-
-def _dkv_kernel(
+def _bwd_kernel(
     kblk,
     qblk,
     sid,
@@ -1262,21 +1102,31 @@ def _dkv_kernel(
     do_ref,
     lse_ref,
     delta_ref,
+    _dq_zeros,  # aliased to dq_hbm
     dk_ref,
     dv_ref,
+    dq_hbm,  # [hq, tqp, d] float32, in HBM
     dk_scr,
     dv_scr,
+    dq_buf,  # (2, 1, bq, d) float32
+    dq_sem,
+    dq_st,
     *,
     params: FlexAttnParams,
     group: int,
 ):
-    """k-major walk with the GQA group innermost, row grid (hk, nk, steps,
-    group) or compact (hk, E2, group): the K/V blocks and dk/dv
-    accumulators stay resident per k block while Q/dO/lse stream through
-    the entry lookups."""
+    """The whole backward of one tile, per head: a k-major walk with the
+    GQA group innermost, row grid (hk, nk, steps, group) or compact (hk,
+    E2, group). The K/V blocks and the dk/dv accumulators stay resident per
+    k block while Q/dO/lse/delta stream through the entry lookups, and
+    ``scale * dS K`` is added to the tile's q rows of dq in HBM
+    (:func:`_dq_accumulate`): S, the mask, P, dP and dS are computed once
+    a tile."""
     bq, bk = params.block_q, params.block_k
     w = _Walk(params.grid, kblk, rs, rc, inner=True)
     i, e, g = w.i, w.e, w.g
+    h = pl.program_id(0)
+    n_entries = kblk.shape[0]
 
     @pl.when(w.first() & (g == 0))
     def _init():
@@ -1301,11 +1151,35 @@ def _dkv_kernel(
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        ds = ds.astype(q_ref.dtype)
         dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds.astype(q_ref.dtype),
+            ds,
             q_ref[0],
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+        )
+
+        def add(tile_ref):
+            d = k_ref.shape[2]  # the tile's lanes past d are padding
+            tile_ref[0, :, :d] += jnp.float32(params.scale) * jax.lax.dot_general(
+                ds,
+                k_ref[0],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+        wraps = g == group - 1
+        e_next = jnp.minimum(e + 1, n_entries - 1)
+        _dq_accumulate(
+            dq_hbm, dq_buf, dq_sem, dq_st, add,
+            tile=(h * group + g, qblk[e]),
+            nxt=(
+                h * group + jnp.where(wraps, 0, g + 1),
+                jnp.where(wraps, qblk[e_next], qblk[e]),
+            ),
+            first=(e == 0) & (g == 0),
+            last=(e == n_entries - 1) & wraps,
+            bq=bq,
         )
 
     @pl.when(w.last() & (g == group - 1))
@@ -1314,7 +1188,7 @@ def _dkv_kernel(
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _dkv_kernel_hb(
+def _bwd_kernel_hb(
     kblk,
     qblk,
     sid,
@@ -1328,24 +1202,32 @@ def _dkv_kernel_hb(
     do_ref,  # (HBG, bq, d)
     lse_ref,  # (HBG, bq, LANES)
     delta_ref,
+    _dq_zeros,  # aliased to dq_hbm
     dk_ref,  # (HB, bk, d)
     dv_ref,
+    dq_hbm,  # [hq, tqp, d] float32, in HBM
     dk_scr,
     dv_scr,
+    dq_buf,  # (2, HBG, bq, d) float32
+    dq_sem,
+    dq_st,
     *,
     params: FlexAttnParams,
     group: int,
 ):
-    """Head-batched dkv over the k-major table, row grid (hk/HB, nk,
-    steps) or compact (hk/HB, E2) (:class:`_Walk`): the per-head kernel's
-    innermost ``group`` grid dimension is inside the step. dv += P^T dO
-    and dk += dS^T Q contract over the G*bq stacked rows of each kv
-    head's group; K, V and the two accumulators stay resident per k
-    block."""
+    """Head-batched form of :func:`_bwd_kernel`, the layout of
+    :func:`_fwd_kernel_hb`, row grid (hk/HB, nk, steps) or compact (hk/HB,
+    E2): the group is inside the step. dv += P^T dO and dk += dS^T Q
+    contract over the G*bq stacked rows of each kv head's group, dS K is
+    one batched MXU call over the same rows; K, V and the two accumulators
+    stay resident per k block."""
     bq, bk = params.block_q, params.block_k
     hb = k_ref.shape[0]
+    hbg = q_ref.shape[0]
     w = _Walk(params.grid, kblk, rs, rc)
     i, e = w.i, w.e
+    h = pl.program_id(0)
+    n_entries = kblk.shape[0]
 
     @pl.when(w.first())
     def _init():
@@ -1368,11 +1250,31 @@ def _dkv_kernel_hb(
             dimension_numbers=rows_t,
             preferred_element_type=jnp.float32,
         )
+        ds = ds.astype(q_ref.dtype)
         dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds.astype(q_ref.dtype),
+            ds,
             q_ref[...].reshape(hb, group * bq, q_ref.shape[2]),
             dimension_numbers=rows_t,
             preferred_element_type=jnp.float32,
+        )
+
+        def add(tile_ref):
+            dq = jnp.float32(params.scale) * jax.lax.dot_general(
+                ds,
+                k_ref[...],
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )
+            d = dq.shape[-1]  # the tile's lanes past d are padding
+            tile_ref[:, :, :d] += dq.reshape(hbg, bq, d)
+
+        _dq_accumulate(
+            dq_hbm, dq_buf, dq_sem, dq_st, add,
+            tile=(h * hbg, qblk[e]),
+            nxt=(h * hbg, qblk[jnp.minimum(e + 1, n_entries - 1)]),
+            first=e == 0,
+            last=e == n_entries - 1,
+            bq=bq,
         )
 
     @pl.when(w.last())
@@ -1381,7 +1283,13 @@ def _dkv_kernel_hb(
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
+def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
+    """(dk, dv in their inputs' dtype, dq [hq, tqp, d] float32) from one
+    kernel over the k-major table. dq is an operand in HBM, zero-filled
+    and aliased to the output; the axis that walks k blocks is
+    ``arbitrary`` on both grids (two k blocks add into one dq tile). A
+    tile is copied whole vregs of lanes at a time, so at a head_dim that
+    is no multiple of 128 the buffer is that much wider and cut here."""
     kblk, qblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk, tkp, _ = k.shape
@@ -1392,23 +1300,24 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     rs, rc = _row_tables(kblk, nk)
 
     if hbg > 1:
-        # head-batched: the group is inside the step, grid (hk/HB, nk,
-        # steps), or the compact (hk/HB, E2)
         hb = hbg // group
-        body = functools.partial(_dkv_kernel_hb, params=params, group=group)
+        body = functools.partial(_bwd_kernel_hb, params=params, group=group)
         kv_scratch = pltpu.VMEM((hb, bk, d), jnp.float32)
         grid, kmap, qmap, semantics = _walk_grid(
-            params.grid, hk // hb, kblk, nk, params.bwd_steps
+            params.grid, hk // hb, kblk, nk, params.bwd_steps,
+            blocks="arbitrary",
         )
     else:
-        # per head: the group is the innermost grid dimension
         hb = 1
-        body = functools.partial(_dkv_kernel, params=params, group=group)
+        body = functools.partial(_bwd_kernel, params=params, group=group)
         kv_scratch = pltpu.VMEM((bk, d), jnp.float32)
         grid, kmap, qmap, semantics = _walk_grid(
             params.grid, hk, kblk, nk, params.bwd_steps,
-            lambda h, g: h * group + g, inner=(group,),
+            lambda h, g: h * group + g, inner=(group,), blocks="arbitrary",
         )
+    dq_shape = (hq, tqp, -(-d // LANES) * LANES)
+    with named_scope("magi_layout"):
+        dq_zeros = jnp.zeros(dq_shape, jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -1419,26 +1328,56 @@ def _dkv_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        scratch_shapes=[kv_scratch, kv_scratch],
+        scratch_shapes=[
+            kv_scratch,
+            kv_scratch,
+            pltpu.VMEM((2, hbg, bq, dq_shape[2]), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((3,), jnp.int32),
+        ],
     )
-    return _flex_pallas_call(
-        "dkv",
+    dk, dv, dq = _flex_pallas_call(
+        "bwd",
         hbg,
         params.grid,
         body,
+        form={"delta": "xla"},
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), k.dtype),
             jax.ShapeDtypeStruct((hk, tkp, d), v.dtype),
+            jax.ShapeDtypeStruct(dq_shape, jnp.float32),
         ],
+        # operand 13 (after the seven tables and q, k, v, dO, lse, delta)
+        input_output_aliases={13: 2},
         interpret=params.interpret,
         compiler_params=_compiler_params(*semantics),
-    )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta)
+    )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta, dq_zeros)
+    return dk, dv, dq[:, :, :d]
+
+
+def _bwd_delta(do, out, dlse):
+    """``delta = rowsum(dO * out) - dlse`` [hq, tqp] float32 (the lse
+    cotangent folds into it: with out = softmax(s) @ v and lse =
+    logsumexp(s), dL/ds = p * (dP - (delta - dlse)), which is what makes
+    multi-stage lse-merging differentiable with stage-local lse), and the
+    same replicated over lanes, the form the kernel reads it in
+    (:func:`_bwd_p_ds`). On the k-major walk a q block has no first step
+    to make it in, so it is made before the kernel."""
+    with named_scope("magi_bwd_delta"):
+        delta = jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+        )
+        if dlse is not None:
+            delta = delta - dlse.astype(jnp.float32)
+        return delta, jnp.broadcast_to(delta[:, :, None], (*delta.shape, LANES))
 
 
 # ---------------------------------------------------------------------------
@@ -1492,9 +1431,9 @@ def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
 
 def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
     q, k, v, sink2d, out, lse_lanes, ftab, btab = residuals
-    # The lse cotangent is first-class (it folds into delta, _delta_init);
-    # a model that never reads lse hands a symbolic zero, and then no
-    # operand is made for it. rowmax stays non-diff.
+    # The lse cotangent is first-class (it folds into delta, _bwd_delta);
+    # a model that never reads lse hands a symbolic zero, and then nothing
+    # is subtracted. rowmax stays non-diff.
     dout, dlse, _dmax = grads
     with named_scope("magi_layout"):
         if isinstance(dout, SymbolicZero):
@@ -1503,17 +1442,17 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
             do = dout.astype(q.dtype)
     if isinstance(dlse, SymbolicZero):
         dlse = None
-    dq, delta_lanes = _dq_pallas(
-        q, k, v, do, lse_lanes, out, dlse, ftab, params
-    )
-    dk, dv = _dkv_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
+    delta, delta_lanes = _bwd_delta(do, out, dlse)
+    dk, dv, dq = _bwd_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
+    with named_scope("magi_layout"):
+        dq = dq.astype(q.dtype)  # the one rounding of the float32 sums
     with named_scope("magi_bwd_delta"):
         if params.has_sink:
             # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
             lse = lse_lanes[:, :, 0]
             sink = sink2d[:, :1]
             w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
-            dsink = -(w * delta_lanes[:, :, 0]).sum(axis=1, keepdims=True)
+            dsink = -(w * delta).sum(axis=1, keepdims=True)
             dsink2d = jnp.broadcast_to(dsink, sink2d.shape).astype(
                 sink2d.dtype
             )

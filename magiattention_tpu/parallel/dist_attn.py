@@ -66,6 +66,7 @@ from ..ops.block_meta import (
 )
 from ..ops.correction import correct_attn_out_lse
 from ..ops.flex_attn import (
+    BWD_FORM,
     FlexAttnParams,
     bounds_mask_step,
     flex_attn_headmajor,
@@ -134,10 +135,14 @@ class StageTables:
         return fs, bs
 
     def grid_steps(self, fwd_steps: int, bwd_steps: int) -> tuple[int, int, float]:
-        """Steps one head group's forward, dq and dkv launch on the
-        row-major grid (blocks x the params' static extents) and on the
-        compact one (the padded entries), the forward table walked twice
-        and the backward table once; and how many of either do work:
+        """Steps one head group's kernels launch on the row-major grid
+        (blocks x the params' static extents) and on the compact one (the
+        padded entries), the forward table walked twice (under remat a
+        training step runs the forward twice; before PR 43 the second walk
+        was dq's) and the backward table once: the weights the grid's two
+        prices were measured with (``tuning/cost_model.py``), kept so that
+        no plan's grid moves with the backward's form. And how many of
+        either do work:
         entries of a non-empty slice, the mean over ranks. Padded and
         dummy entries name an all-masked sentinel slice: both grids
         launch them, and they count as dead."""
@@ -915,7 +920,7 @@ def make_attn_params(
 
 
 def _choose_grid(params: FlexAttnParams, tabs) -> str:
-    """The grid all three kernels of a plan walk, from the same table sets
+    """The grid both kernels of a plan walk, from the same table sets
     the static extents came from: the steps each grid would launch are
     counted (:meth:`StageTables.grid_steps`) and priced with the two
     per-step costs measured on the chip (``tuning/cost_model.py``).
@@ -936,15 +941,18 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
         rung=(params.block_q, params.block_k, params.head_block),
         grid=grid,
         # the form the kernels' side operands cross their boundary in
-        # (the build counter's labels of the same names)
+        # (the build counter's labels of the same names), and the form of
+        # the backward: one k-major kernel, delta made before it
         stats=stats_form(params.block_q),
-        delta="kernel",
+        delta="xla",
+        bwd_form=BWD_FORM,
         row_major_steps=launched["row_major"],
         compact_steps=launched["sparse"],
         live_steps=live,
         row_major_dead_us=1e6 * row_major_s,
         compact_fee_us=1e6 * compact_s,
     )
+    telemetry.record_flex_bwd_form(BWD_FORM)
     if launched[grid]:
         telemetry.record_flex_dead_step_share(
             100.0 * (1.0 - live / launched[grid])

@@ -68,6 +68,7 @@ from .collectors import (  # noqa: F401
     record_decode_step,
     record_degraded_path,
     record_dispatch_meta,
+    record_flex_bwd_form,
     record_flex_dead_step_share,
     record_flex_stepped_tile_share,
     record_mask_step,
@@ -358,6 +359,7 @@ __all__ = [
     "record_decode_step",
     "record_degraded_path",
     "record_dispatch_meta",
+    "record_flex_bwd_form",
     "record_flex_dead_step_share",
     "record_flex_stepped_tile_share",
     "record_mask_step",

@@ -117,10 +117,15 @@ M_AUTOTUNE_DECISIONS = "magi_autotune_decisions_total"
 # which rung the last decision chose and why: value 1, labels rung=/source=
 M_AUTOTUNE_CHOICE = "magi_autotune_choice"
 # flex pallas_calls built (trace time), by role, by the q heads one grid
-# step takes and by the grid walked: {kernel=fwd|dq|dkv, heads_per_step=,
+# step takes and by the grid walked: {kernel=fwd|bwd, heads_per_step=,
 # grid=row_major|sparse}. Beside the head_block gauge above it says
 # whether each kernel honoured the choice
 M_FLEX_KERNEL_BUILDS = "magi_flex_kernel_build_total"
+# plans by the form of their backward (make_attn_params): {form=fused}, the
+# one k-major kernel that adds dq into HBM. Before PR 43 every plan ran
+# the split form (dq q-major, then dkv); the label is kept so that a later
+# form can be read against this one
+M_FLEX_BWD_FORM = "magi_flex_bwd_form_total"
 # gauge — the newest plan's grid decision (make_attn_params): percent of
 # the steps the chosen grid launches over forward, dq and dkv that do no
 # work (dead row-major steps; padded and dummy entries on both grids)
@@ -1109,6 +1114,8 @@ def record_autotune_decision(decision) -> None:
     which rung it chose and why."""
     if not _enabled():
         return
+    from ..ops.flex_attn import BWD_FORM
+
     reg = get_registry()
     reg.gauge_set(M_AUTOTUNE_BLOCK_Q, decision.block_q)
     reg.gauge_set(M_AUTOTUNE_BLOCK_K, decision.block_k)
@@ -1136,6 +1143,9 @@ def record_autotune_decision(decision) -> None:
             "hbm_seconds": decision.hbm_seconds,
             "bound": decision.bound,
             "rejected_bytes": decision.rejected_bytes,
+            # the form of the backward the rung's kernels run: one value
+            # since PR 43, kept so that a later form reads against it
+            "bwd_form": BWD_FORM,
         },
     )
 
@@ -1145,7 +1155,7 @@ def record_flex_kernel_build(
 ) -> None:
     """One flex ``pallas_call`` built (``ops/flex_attn._flex_pallas_call``,
     while jax traces the caller — never inside a compiled step). ``form``:
-    the forward's ``stats=compact|lanes``, dq's ``delta=kernel``."""
+    the forward's ``stats=compact|lanes``, the backward's ``delta=xla``."""
     if not _enabled():
         return
     get_registry().counter_inc(
@@ -1155,6 +1165,14 @@ def record_flex_kernel_build(
         grid=grid,
         **form,
     )
+
+
+def record_flex_bwd_form(form: str) -> None:
+    """One plan's backward form, where ``make_attn_params`` gives the plan
+    its kernels' parameters."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_FLEX_BWD_FORM, form=form)
 
 
 def record_flex_dead_step_share(pct: float) -> None:

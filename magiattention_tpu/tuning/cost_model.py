@@ -134,11 +134,17 @@ TIE_TOLERANCE = 0.15
 # at which the price ratio of the two rungs is the chip's forward + dq + dkv
 # ratio (1.17; 1.13 for the whole forward+backward call) is 0.53-0.57.
 HBM_PRICE_SHARE = 0.55
-# forward : dq : dkv FLOPs of one tile (2, 3 and 4 matmuls of the forward's
-# two); a price is forward-sized, so the three kernels' excess is averaged
-# over the sum
-KERNEL_FLOP_WEIGHTS = {"fwd": 1.0, "dq": 1.5, "dkv": 2.0}
-# lse (the forward's residual) and delta (dq's second output) reach dkv
+# FLOPs of one tile in units of the forward's two matmuls: the forward, the
+# fused backward ``bwd`` (five: S, dP, dV, dK, dQ), and the q-major dq (three)
+# and k-major dkv (four) it replaced (PR 43), which each computed S and dP
+KERNEL_FLOP_WEIGHTS = {"fwd": 1.0, "dq": 1.5, "dkv": 2.0, "bwd": 2.5}
+# What a rung's price sums (``rank_candidates``): the three kernels its
+# constants were calibrated on. Pricing the fused backward in their place
+# re-ranks every mask, which is the cost model's own PR (ROADMAP D4); the
+# HBM-bound rungs are the same either way (a k-major step's bytes follow
+# ``block_q`` x heads in ``dkv`` and in ``bwd`` alike).
+RANKED_KERNELS = ("fwd", "dq", "dkv")
+# lse (the forward's residual) and delta reach the k-major backward
 # replicated over a vreg's lanes
 _STAT_LANES = 128
 
@@ -152,22 +158,26 @@ def step_bytes(
     head_dim: int,
     itemsize: int,
 ) -> int:
-    """Bytes one live step of ``kernel`` (``fwd`` | ``dq`` | ``dkv``) loads
-    fresh from HBM. Forward and dq walk q-major: a step brings the K and V
-    tiles of the key-value heads its ``head_block`` query heads share (a
-    per-head step, ``head_block`` 1, brings one pair whatever the group).
-    dkv walks k-major: a step brings q, dO and the two float32
-    lane-replicated statistics (lse, delta) of its query heads.
+    """Bytes one live step of ``kernel`` (``fwd`` | ``bwd``, and the
+    ``dq`` | ``dkv`` the ranking was calibrated on) moves to and from HBM.
+    The forward (and dq) walk q-major: a step brings the K and V tiles of
+    the key-value heads its ``head_block`` query heads share (a per-head
+    step, ``head_block`` 1, brings one pair whatever the group). The
+    backward walks k-major: a step brings q, dO and the two float32
+    lane-replicated statistics (lse, delta) of its query heads (``dkv``),
+    and ``bwd`` also reads and writes their float32 dq tile: at head_dim
+    128 in bf16 2,560 bytes a row and head, 0.5 x ``block_k`` FLOPs a byte
+    whatever the GQA group.
 
-    Not counted: the tiles that stay while a block's entries run (q, dO,
-    lse and, for delta, the forward's out in dq, K and V in dkv) and the
-    outputs (dq also writes delta, lane-replicated, once a q block). Each
-    is brought or written once a block, so over a call they are the
-    tensors' own size whatever the rung: they move no order between rungs."""
-    if kernel == "dkv":
-        return head_block * block_q * (
-            2 * head_dim * itemsize + 2 * _STAT_LANES * 4
-        )
+    Not counted: the tiles that stay while a block's entries run (q in the
+    forward; K, V, dk and dv in the backward). Each is brought or written
+    once a block, so over a call they are the tensors' own size whatever
+    the rung: they move no order between rungs."""
+    if kernel in ("dkv", "bwd"):
+        row = 2 * head_dim * itemsize + 2 * _STAT_LANES * 4
+        if kernel == "bwd":
+            row += 2 * head_dim * 4  # the float32 dq tile, in and out
+        return head_block * block_q * row
     return 2 * max(head_block // group, 1) * block_k * head_dim * itemsize
 
 # Sparse-only blockings: smaller tiles than any row-major rung carries.
@@ -626,12 +636,12 @@ def rank_candidates(
             * (bwd_entries if kern == "dkv" else entries)
             * step_bytes(kern, bq, bk, hb, group, head_dim, itemsize)
             / hbm_rate
-            for kern in KERNEL_FLOP_WEIGHTS
+            for kern in RANKED_KERNELS
         }
         excess = sum(
-            max(streamed[kern] - w * mxu_s, 0.0)
-            for kern, w in KERNEL_FLOP_WEIGHTS.items()
-        ) / sum(KERNEL_FLOP_WEIGHTS.values())
+            max(streamed[kern] - KERNEL_FLOP_WEIGHTS[kern] * mxu_s, 0.0)
+            for kern in RANKED_KERNELS
+        ) / sum(KERNEL_FLOP_WEIGHTS[kern] for kern in RANKED_KERNELS)
         return CandidateScore(
             block_q=bq,
             block_k=bk,

@@ -63,13 +63,13 @@ def _compile(fn, *args) -> str:
 
 
 # the build counter's labels at the cells' blockings (block_q a multiple of
-# 128): the statistics cross the forward's boundary with rows along lanes,
-# and dq makes delta itself (ISSUE 40)
-_FORMS = {"fwd": {"stats": "compact"}, "dq": {"delta": "kernel"}, "dkv": {}}
+# 128): the statistics cross the forward's boundary with rows along lanes
+# (ISSUE 40), and delta is made before the one backward kernel (ISSUE 43)
+_FORMS = {"fwd": {"stats": "compact"}, "bwd": {"delta": "xla"}}
 
 
 def _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid, softcap=0.0) -> str:
-    """The compiled text of forward + dq + dkv (a loss that reads out and
+    """The compiled text of forward + backward (a loss that reads out and
     lse) on ``mask`` = (q_ranges, k_ranges, types) at the pinned rung."""
     qr, kr, ts = mask
 
@@ -105,7 +105,7 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
         jax.value_and_grad(loss, argnums=(0, 1, 2)),
         _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
     )
-    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+    assert text.count("tpu_custom_call") >= 2  # fwd, bwd
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -114,7 +114,7 @@ def test_flex_fwd_bwd_16k_varlen(topo, grid, hq, hk, d):
 def test_stepped_bound_at_the_block_diffusion_cells_shapes(topo, grid, rung):
     """ISSUE 42: the interval mask's block index (one ``and`` of the row
     column with a scalar ``-step``, the step read from the slice's type
-    word) compiles in the forward, dq and dkv on both grids, at the
+    word) compiles in the forward and the backward on both grids, at the
     SDAR cell's head geometry (32 query / 4 key-value heads of 128) on a
     4,096-row ``[noisy ; clean]`` mask of three documents."""
     from magiattention_tpu.api import infer_block_diffusion_mask
@@ -123,7 +123,7 @@ def test_stepped_bound_at_the_block_diffusion_cells_shapes(topo, grid, rung):
     mask = (qr.to_naive_ranges(), kr.to_naive_ranges(), [int(x) for x in ts])
     chip = SingleDeviceSharding(topo.devices[0])
     text = _compile_fwd_bwd(chip, mask, 4096, 32, 4, 128, rung, grid)
-    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dkv
+    assert text.count("tpu_custom_call") >= 2  # fwd, bwd
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -137,7 +137,7 @@ def test_stepped_bound_at_the_block_diffusion_cells_shapes(topo, grid, rung):
     ids=["varlen-cell-64x8", "train-cell-32x8", "largest-tuner-step"],
 )
 def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
-    """The head-batched forward, dq and dkv, on the row-major and on the
+    """The head-batched forward and backward, on the row-major and on the
     compact grid, at the blocking the tuner gives the benchmark's packed
     cells, (128, 512, 8) at head_dim 128: group 8 is one kv head a step,
     group 4 two (the batched transposed contraction). And at the largest
@@ -164,7 +164,7 @@ def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
     finally:
         reg.clear_metric("magi_flex_kernel_build_total")
         telemetry.set_enabled(was)
-    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -216,7 +216,7 @@ def test_forward_state_at_the_cells_rungs(topo, rung, sink, softcap, grid):
          "looped-rung"],
 )
 def test_backward_block_at_the_cells_rungs(topo, t, hq, hk, d, rung, softcap, grid):
-    """dq and dkv at the rungs the benchmark's cells run: head-batched
+    """The backward at the rungs the benchmark's cells run: head-batched
     (128, 512, 8) and per head (1024, 1024, 1) at 64 q / 8 kv heads of
     width 128, and at GQA group 1 head-batched (256, 512, 5) at 20 / 20
     heads of width 256 and (256, 512, 8) at 16 / 16 of 128 (ISSUE 35; the
@@ -232,7 +232,7 @@ def test_backward_block_at_the_cells_rungs(topo, t, hq, hk, d, rung, softcap, gr
         SingleDeviceSharding(topo.devices[0]),
         ranges_of(varlen_block_causal(t)), t, hq, hk, d, rung, grid, softcap,
     )
-    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
 def _glm_cell_mask(t):
@@ -283,7 +283,7 @@ _GROUP_ONE = {
          "looped-widest-row-major-step", "looped-per-head-long-rung"],
 )
 def test_group_one_geometries(topo, geometry, t, rung, grid):
-    """Forward, dq and dkv at GQA group 1, where a step brings as many
+    """Forward and backward at GQA group 1, where a step brings as many
     key-value heads' tiles as it has query heads, on both grids: what
     latent attention hands the kernels after its up-projection
     (GLM-4.7-Flash: 20 query = 20 key-value heads of 256; no power of two,
@@ -321,7 +321,7 @@ def test_group_one_geometries(topo, geometry, t, rung, grid):
     finally:
         reg.clear_metric("magi_flex_kernel_build_total")
         telemetry.set_enabled(was)
-    assert text.count("tpu_custom_call") == 3  # fwd, dq, dkv
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
 def _serve_cache(chip, hk, d):
@@ -417,9 +417,9 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
     """Ouro-2.6B's step at the published widths (16 query = 16 key-value
     heads of 128, the tuner's rung for the cell's mask), 2 of its layers
     and 4 passes, at the check's 4,096 tokens: the pass is one scan, so
-    the compiled step holds 4 x layers flex kernels (the forward in the
-    scanned pass; remat's forward, dq and dkv in its transpose) and not
-    4 x layers x passes, and it traces, differentiates and rematerialises
+    the compiled step holds 3 x layers flex kernels (the forward in the
+    scanned pass; remat's forward and the backward in its transpose) and
+    not 3 x layers x passes, and it traces, differentiates and rematerialises
     ``dist_attn_local`` inside ``scan`` + ``checkpoint`` + ``shard_map``
     for the chip's compiler as it stands."""
     import json
@@ -467,7 +467,7 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
         .compile()
         .as_text()
     )
-    assert text.count("tpu_custom_call") == 4 * cfg.n_layers
+    assert text.count("tpu_custom_call") == 3 * cfg.n_layers
 
 
 def test_cca_train_step_at_two_key_value_heads(topo):
@@ -529,10 +529,10 @@ def test_cca_train_step_at_two_key_value_heads(topo):
         .as_text()
     )
     scopes = trace_reduce.hlo_scopes(text)
-    # a layer's forward, remat's forward, dq and dkv (the grouped matmuls
-    # are tpu_custom_calls too: count the flex kernels by name)
+    # a layer's forward, remat's forward and the backward (the grouped
+    # matmuls are tpu_custom_calls too: count the flex kernels by name)
     flex = [n for n in scopes if n.startswith("magi_flex_")]
-    assert len(flex) == 4 * cfg.n_layers, flex
+    assert len(flex) == 3 * cfg.n_layers, flex
     mix = [s for s in scopes.values() if "magi_cca_mix" in s]
     assert mix and not [s for s in mix if s.endswith("/gather")]
     assert any("magi_moe_router" in s for s in scopes.values())
@@ -590,12 +590,11 @@ def test_keyed_kernels_carry_role_names(topo, cp, monkeypatch):
         if name.startswith("magi_flex_")
     }
     roles = sorted(name.split(".")[0] for name in kernels)
-    assert roles == [
-        "magi_flex_dkv_kernel", "magi_flex_dq_kernel", "magi_flex_fwd_kernel",
-    ]
+    assert roles == ["magi_flex_bwd_kernel", "magi_flex_fwd_kernel"]
     fwd_rx, bwd_rx = pattern("flex_fwd_roofline"), pattern("flex_bwd_roofline")
     new = {
-        role: pattern(f"flex_{role}_kernel_ms") for role in ("fwd", "dq", "dkv")
+        role: pattern(f"flex_{role}_kernel_ms")
+        for role in ("fwd", "bwd", "dq", "dkv")  # the last two: silent now
     }
     for name, scope in kernels.items():
         line = f"{name} {scope}"  # what trace_reduce.kernel_seconds matches
@@ -605,7 +604,7 @@ def test_keyed_kernels_carry_role_names(topo, cp, monkeypatch):
         assert bool(fwd_rx.search(line)) == (not backward), line
         hit = [role for role, rx in new.items() if rx.search(line)]
         assert hit == [name.split("_")[2]], line
-    assert pattern("train_flex_kernel_share").search("magi_flex_dq_kernel.1 ")
+    assert pattern("train_flex_kernel_share").search("magi_flex_bwd_kernel.1 ")
     # the plan's own choice of group-collective implementation (a2a at
     # this size, hops in the benchmark's cp=4 cell)
     collectives = [

@@ -114,16 +114,16 @@ def _pallas_calls(cfg, params):
 def test_the_program_does_not_grow_with_the_passes(params):
     """A layer's kernels are in the loss-and-gradient program once a
     direction whatever ``n_loops`` is (the forward in the scanned pass;
-    remat's forward, dq and dkv in its transpose), and so is every other
-    equation: two passes and four trace the same program but for the
-    scans' lengths. (The step the chip's compiler makes of the published
-    widths holds 4 x layers ``tpu_custom_call``s:
+    remat's forward and the one backward kernel in its transpose), and so
+    is every other equation: two passes and four trace the same program
+    but for the scans' lengths. (The step the chip's compiler makes of the
+    published widths holds 3 x layers ``tpu_custom_call``s:
     tests/test_aot_compile_tpu.py.)"""
     with jax.enable_x64(False):
         two, four = (
             _pallas_calls(_ouro(total_ut_steps=n)[1], params) for n in (2, 4)
         )
-    assert four["pallas_call"] == 4 * 2
+    assert four["pallas_call"] == 3 * 2
     assert two == four
 
 

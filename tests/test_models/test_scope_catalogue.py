@@ -72,10 +72,10 @@ HEAVY = re.compile(
 )
 
 ATTN_CALL = ["magi_layout", "magi_flex_fwd_kernel"]
-ATTN_BWD = ["magi_flex_dq_kernel", "magi_flex_dkv_kernel"]
-# magi_bwd_delta holds what is left of delta in XLA since dq makes it
-# (ISSUE 40): the lse cotangent's way to dq and the sink's gradient. The
-# bare call below hands an lse cotangent; a model step has neither
+ATTN_BWD = ["magi_flex_bwd_kernel"]
+# magi_bwd_delta: delta, made before the one backward kernel (ISSUE 43: on
+# the k-major walk a q block has no first step to make it in), the lse
+# cotangent folded in, and the sink's gradient
 ATTN_DLSE = ["magi_bwd_delta"]
 STEP = [
     "magi_embed", "magi_proj", "magi_ffn", "magi_optimizer",

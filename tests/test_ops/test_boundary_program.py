@@ -1,8 +1,8 @@
 """What a keyed attention call's program holds round its kernels (ISSUE
 40): no XLA pass that only reformats a kernel's side operand. The forward
-makes no lane-replicated statistic at all; forward+backward makes none in
-XLA (delta is dq's, the lse cotangent reaches it with rows along lanes),
-and no float32 gradient is rounded outside the kernel that wrote it."""
+makes no lane-replicated statistic at all. Forward+backward (ISSUE 43: one
+backward kernel over the k-major table) makes delta before the kernel and
+rounds the float32 dq after it, each once, and nothing else."""
 
 import jax
 import jax.numpy as jnp
@@ -78,33 +78,48 @@ def test_forward_holds_no_lane_replicated_statistic(programs):
             assert not (len(shape) == 3 and shape[-1] == LANES), eqn
 
 
-def test_forward_backward_reformats_no_side_operand_in_xla(programs):
+def test_forward_backward_makes_delta_and_rounds_dq_once_in_xla(programs):
+    """ISSUE 43: the backward is one k-major kernel. A q block has no first
+    step on that walk, so delta is made before the kernel, once, in the
+    lane-replicated form the kernel reads; dq leaves the kernel as the
+    float32 buffer it was summed in and is rounded once. Nothing else is
+    reformatted in XLA: dk and dv leave in the inputs' dtype, the lse
+    cotangent folds into delta on 4 bytes a row."""
     _, fwdbwd = programs
     names = [e.params["name"] for e in _kernels(fwdbwd)]
-    assert sorted(names) == [
-        "magi_flex_dkv_kernel", "magi_flex_dq_kernel", "magi_flex_fwd_kernel",
-    ]
+    assert sorted(names) == ["magi_flex_bwd_kernel", "magi_flex_fwd_kernel"]
+    (bwd,) = [e for e in _kernels(fwdbwd) if e.params["name"].endswith("bwd_kernel")]
+    dk, dv, dq = (v.aval for v in bwd.outvars)
+    # (whole vregs of lanes a tile: head_dim 64 is padded to 128 and cut)
+    assert dq.dtype == jnp.float32 and dq.shape[0] == HQ
+    assert dq.shape[2] == -(-D // LANES) * LANES
+    assert dk.dtype == dv.dtype == jnp.bfloat16
     grad_sized = HK * TOTAL * D  # dk and dv; dq is larger
+    to_lanes, rounded = [], []
     for eqn in _outside_kernels(fwdbwd):
         if eqn.primitive.name == "broadcast_in_dim":
             # (a scalar's fill is no operand's reformat: the zeros jax
-            # makes for the residuals' places, which nothing reads)
+            # makes for the residuals' places and for dq's buffer)
             (out,) = eqn.outvars
-            assert not (
+            if (
                 eqn.invars[0].aval.ndim
                 and out.aval.shape[-1:] == (LANES,)
                 and out.aval.dtype == jnp.float32
                 and out.aval.ndim >= 3
-            ), eqn
+            ):
+                to_lanes.append(out.aval.shape)
         if eqn.primitive.name == "convert_element_type":
             (x,), (out,) = eqn.invars, eqn.outvars
-            assert not (
+            if (
                 x.aval.dtype == jnp.float32
                 and out.aval.dtype == jnp.bfloat16
                 and out.aval.size >= grad_sized
-            ), eqn
-    # the lane-replicated arrays that are left are the kernels' own: lse,
-    # the backward's residual, and delta, dq's second output
+            ):
+                rounded.append(out.aval.shape)
+    assert to_lanes == [(HQ, dq.shape[1], LANES)]  # delta
+    assert rounded == [(*dq.shape[:2], D)]
+
+    # the lane-replicated arrays are lse, the forward's residual, and delta
     def makes(eqn):  # not hands on: a shard_map, the custom_vjp's call
         if eqn.primitive.name == "broadcast_in_dim":
             return bool(eqn.invars[0].aval.ndim)
@@ -117,5 +132,6 @@ def test_forward_backward_reformats_no_side_operand_in_xla(programs):
         for e in _outside_kernels(fwdbwd)
         for v in e.outvars
         if v.aval.shape[-1:] == (LANES,) and v.aval.ndim == 3 and makes(e)
+        and v is not bwd.outvars[2]  # dq's padded lanes are no statistic
     ]
-    assert lanes == ["pallas_call"] * 2
+    assert sorted(lanes) == ["broadcast_in_dim", "pallas_call"]

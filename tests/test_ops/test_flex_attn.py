@@ -687,22 +687,22 @@ def test_bwd_block_on_whole_vregs_is_the_column_form(
 def test_lse_and_delta_arrive_replicated_over_the_lanes(
     with_sink, head_block, grid, monkeypatch
 ):
-    """The contract ``_bwd_p_ds`` leans on: what dq and dkv are handed as
-    lse (the differentiated forward's residual, from either forward body
-    on either grid) and what dkv is handed as delta (made by dq in its
-    block's first step, the lse cotangent folded in) is equal in all 128
+    """The contract ``_bwd_p_ds`` leans on: what the backward kernel is
+    handed as lse (the differentiated forward's residual, from either
+    forward body on either grid) and as delta (made before the kernel,
+    ``_bwd_delta``, the lse cotangent folded in) is equal in all 128
     lanes, on covered rows and on rows no entry covers (``-inf``, or the
     sink)."""
     from magiattention_tpu.ops import flex_attn as fa
 
     seen = {}
-    dkv_pallas = fa._dkv_pallas
+    bwd_pallas = fa._bwd_pallas
 
     def spy(q, k, v, do, lse, delta, tables, params):
         seen.update(lse=np.asarray(lse), delta=np.asarray(delta))
-        return dkv_pallas(q, k, v, do, lse, delta, tables, params)
+        return bwd_pallas(q, k, v, do, lse, delta, tables, params)
 
-    monkeypatch.setattr(fa, "_dkv_pallas", spy)
+    monkeypatch.setattr(fa, "_bwd_pallas", spy)
     got, _, sink = _state_case(
         head_block, grid, 128, with_sink, 0.0, with_oracle=False
     )

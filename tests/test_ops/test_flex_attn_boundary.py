@@ -1,9 +1,11 @@
 """What crosses the flex kernels' boundary (ISSUE 40), CPU interpret mode.
 
 A side operand crosses once, in the form its consumer wants: lse and the row
-maximum leave the forward with rows along lanes, dq / dk / dv leave in the
-inputs' dtype, delta is made inside dq. Each against the form it replaced,
-which stays here as the reference. (A file of its own: the driver hands a
+maximum leave the forward with rows along lanes, dk / dv leave in the
+inputs' dtype. Since ISSUE 43 the backward is one k-major kernel: dq leaves
+it as the float32 buffer it was summed in and is rounded once, delta is made
+before the kernel (a q block has no first step on that walk). Each against
+the form it replaced, which stays here as the reference. (A file of its own: the driver hands a
 test file to one worker, and ``test_flex_attn.py`` is the longest already.)
 """
 
@@ -136,9 +138,10 @@ def _edge_grads(operands, use_lse, dtype):
 def test_grads_written_in_bf16_are_the_float32_ones_cast(
     hq, hk, head_block, block_q, grid, monkeypatch
 ):
-    """dq, dk, dv leave the kernels in the inputs' dtype: the float32
+    """dk and dv leave the kernel in the inputs' dtype: the float32
     accumulator rounded once where it is stored, bit for bit what a
-    float32 output rounded by XLA gave."""
+    float32 output rounded by XLA gave. dq leaves it in float32 (the sums
+    live in HBM) and XLA rounds it once: the same values either way."""
     from magiattention_tpu.ops import flex_attn as fa
 
     operands = _edge_operands(
@@ -152,6 +155,9 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
         if role == "fwd":
             return build(role, heads, grid_kind, body, form, **kwargs)
         shapes = kwargs["out_shape"]
+        assert [s.dtype for s in shapes] == [
+            jnp.bfloat16, jnp.bfloat16, jnp.float32  # dk, dv, dq
+        ]
         kwargs["out_shape"] = [
             jax.ShapeDtypeStruct(s.shape, jnp.float32) for s in shapes
         ]
@@ -163,7 +169,7 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
 
     monkeypatch.setattr(fa, "_flex_pallas_call", float32_then_cast)
     old, _, _ = _edge_grads(operands, True, jnp.bfloat16)
-    assert widened == ["dq", "dkv"]
+    assert widened == ["bwd"]
     for a, b, nm in zip(got[:3], old[:3], ["dq", "dk", "dv"]):
         assert a.dtype == jnp.bfloat16, nm
         assert np.isfinite(np.asarray(a, np.float32)).all(), nm
@@ -177,34 +183,35 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
 @pytest.mark.parametrize("block_q", [64, 128, 256])
 @pytest.mark.parametrize("hq,hk,head_block", _EDGE_HEADS, ids=_EDGE_IDS)
 @pytest.mark.parametrize("use_lse", [False, True], ids=["zero-dlse", "dlse"])
-def test_delta_is_made_in_dq(
+def test_delta_is_made_before_the_kernel(
     use_lse, hq, hk, head_block, block_q, grid, monkeypatch
 ):
-    """dq makes ``delta = sum(dO * out) - dlse`` itself and hands it to
-    dkv replicated over lanes: against the XLA form, with an lse cotangent
-    that is a symbolic zero (no operand reaches the kernel) and with one
-    that is not (rows along lanes where a q block is whole vregs, lanes
-    from XLA at block_q 64); dq, dk, dv, dsink against the jnp backend."""
+    """``delta = sum(dO * out) - dlse`` is made once, before the one
+    backward kernel, and handed to it replicated over lanes: with an lse
+    cotangent that is a symbolic zero (nothing is subtracted) and with one
+    that is not; dq, dk, dv, dsink against the jnp backend."""
     from magiattention_tpu.ops import flex_attn as fa
 
     operands = _edge_operands(
         hq, hk, head_block, grid, block_q, True, jnp.float32
     )
     seen = {}
-    dq_pallas = fa._dq_pallas
+    bwd_delta = fa._bwd_delta
 
-    def spy(q, k, v, do, lse, out, dlse, tables, params):
-        res = dq_pallas(q, k, v, do, lse, out, dlse, tables, params)
-        seen.update(do=do, out=out, dlse=dlse, delta=np.asarray(res[1]))
+    def spy(do, out, dlse):
+        res = bwd_delta(do, out, dlse)
+        seen.update(do=do, out=out, dlse=dlse, rows=np.asarray(res[0]),
+                    delta=np.asarray(res[1]))
         return res
 
-    monkeypatch.setattr(fa, "_dq_pallas", spy)
+    monkeypatch.setattr(fa, "_bwd_delta", spy)
     got, do, w = _edge_grads(operands, use_lse, jnp.float32)
     delta = seen["delta"]
     assert delta.shape == (hq, _EDGE_T, fa.LANES) and delta.dtype == np.float32
     np.testing.assert_array_equal(
         delta, np.broadcast_to(delta[..., :1], delta.shape)
     )
+    np.testing.assert_array_equal(delta[..., 0], seen["rows"])
     want = np.asarray(jnp.sum(seen["do"] * seen["out"], axis=-1))
     if use_lse:
         np.testing.assert_array_equal(np.asarray(seen["dlse"]), np.asarray(w))

@@ -1,6 +1,6 @@
 """Every flex kernel carries its role's name (ISSUE 24): the lowered
-text of a forward+backward holds the three names, whatever the grid
-kind and the head block. (The scopes and instruction names of the
+text of a forward+backward holds the two names (three before ISSUE 43
+made dq and dkv one kernel), whatever the grid kind and the head block. (The scopes and instruction names of the
 compiled TPU program: tests/test_aot_compile_tpu.py.)"""
 
 import re
@@ -12,12 +12,12 @@ import pytest
 from magiattention_tpu.ops import flex_flash_attn_func
 from magiattention_tpu.testing.workloads import ranges_of, varlen_block_causal
 
-NAMES = {"magi_flex_fwd_kernel", "magi_flex_dq_kernel", "magi_flex_dkv_kernel"}
+NAMES = {"magi_flex_fwd_kernel", "magi_flex_bwd_kernel"}
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize("head_block", [1, 2])
-def test_lowered_fwd_bwd_holds_the_three_kernel_names(grid, head_block):
+def test_lowered_fwd_bwd_holds_the_kernel_names(grid, head_block):
     t, hq, hk, d = 512, 4, 2, 64
     qr, kr, ts = ranges_of(varlen_block_causal(t))
 
@@ -63,10 +63,10 @@ def _grad_fn(t, head_block, block_q, block_k, grid="row_major"):
 
 
 @pytest.mark.parametrize("hq,hk,head_block", [(8, 2, 4), (8, 2, 8), (4, 4, 2)])
-def test_head_block_is_the_leading_grid_dimension_of_all_three(
+def test_head_block_is_the_leading_grid_dimension_of_both(
     hq, hk, head_block
 ):
-    """At head_block > 1 the row-major dq and dkv take head_block q heads a
+    """At head_block > 1 the row-major backward takes head_block q heads a
     grid step, as the forward does: one call of each name, and
     hq // head_block resp. hk // (head_block // group) leading."""
     t, d = 512, 64
@@ -75,25 +75,24 @@ def test_head_block_is_the_leading_grid_dimension_of_all_three(
     jaxpr = jax.make_jaxpr(_grad_fn(t, head_block, 128, 128))(q, kv, kv)
     grids = dict(_pallas_calls(jaxpr.jaxpr))
     assert set(grids) == NAMES
-    assert len(_pallas_calls(jaxpr.jaxpr)) == 3
+    assert len(_pallas_calls(jaxpr.jaxpr)) == 2
     group = hq // hk
     nq = nk = t // 128
     assert grids["magi_flex_fwd_kernel"][:2] == (hq // head_block, nq)
-    assert grids["magi_flex_dq_kernel"][:2] == (hq // head_block, nq)
-    assert len(grids["magi_flex_dq_kernel"]) == 3
-    assert grids["magi_flex_dkv_kernel"][:2] == (
+    assert len(grids["magi_flex_fwd_kernel"]) == 3
+    assert grids["magi_flex_bwd_kernel"][:2] == (
         hk // (head_block // group), nk,
     )
-    assert len(grids["magi_flex_dkv_kernel"]) == 3  # the group is in the step
+    assert len(grids["magi_flex_bwd_kernel"]) == 3  # the group is in the step
 
 
 @pytest.mark.parametrize("hq,hk,head_block", [(8, 2, 4), (8, 2, 8), (4, 4, 2)])
-def test_compact_grid_is_head_groups_by_entries_for_all_three(
+def test_compact_grid_is_head_groups_by_entries_for_both(
     hq, hk, head_block
 ):
-    """On the compact grid the three head-batched kernels launch one step
+    """On the compact grid the two head-batched kernels launch one step
     an entry of their table and nothing else: (hq // head_block, E) for
-    the forward and dq, (hk // (head_block // group), E2) for dkv."""
+    the forward, (hk // (head_block // group), E2) for the backward."""
     from magiattention_tpu.ops import build_block_meta
 
     t, d = 512, 64
@@ -104,12 +103,11 @@ def test_compact_grid_is_head_groups_by_entries_for_all_three(
     jaxpr = jax.make_jaxpr(_grad_fn(t, head_block, 128, 128, "sparse"))(
         q, kv, kv
     )
-    assert len(_pallas_calls(jaxpr.jaxpr)) == 3
+    assert len(_pallas_calls(jaxpr.jaxpr)) == 2
     group = hq // hk
     assert dict(_pallas_calls(jaxpr.jaxpr)) == {
         "magi_flex_fwd_kernel": (hq // head_block, meta.num_fwd_entries),
-        "magi_flex_dq_kernel": (hq // head_block, meta.num_fwd_entries),
-        "magi_flex_dkv_kernel": (
+        "magi_flex_bwd_kernel": (
             hk // (head_block // group), meta.num_bwd_entries,
         ),
     }
@@ -159,7 +157,6 @@ def test_build_counter_carries_heads_per_step_and_grid(
     assert _builds() == {
         f"{name}{{grid={grid},heads_per_step={head_block},kernel=fwd,"
         "stats=compact}": 1,
-        f"{name}{{delta=kernel,grid={grid},heads_per_step={bwd_heads},"
-        "kernel=dq}": 1,
-        f"{name}{{grid={grid},heads_per_step={bwd_heads},kernel=dkv}}": 1,
+        f"{name}{{delta=xla,grid={grid},heads_per_step={bwd_heads},"
+        "kernel=bwd}": 1,
     }

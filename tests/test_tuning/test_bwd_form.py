@@ -1,0 +1,133 @@
+"""The backward's form (ISSUE 43): one k-major kernel that computes S, dP
+and dS once a tile, keeps dk / dv in VMEM and adds dq into a float32 buffer
+in HBM. What the cost model says a step of it moves, at the rungs the
+benchmark's twelve cells run; and the form each cell's plan records, read
+from the plan's own span (host only: nothing runs on a device)."""
+
+import json
+import os
+
+import pytest
+
+from magiattention_tpu import api, telemetry
+from magiattention_tpu.ops.flex_attn import BWD_FORM
+from magiattention_tpu.tuning import cost_model
+from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
+
+from .test_grid_choice import ROOT, _build_cell, _decisions, telemetry_on  # noqa: F401
+
+# (rung, GQA group, head_dim) -> bytes a live step moves, and FLOPs a byte
+RUNGS = {
+    "dense 64k, chunk-causal, cp4 packed": ((1024, 1024, 1), 8, 128, 2_621_440, 512),
+    "packed 64k, window, Trinity, SDAR": ((128, 512, 8), 8, 128, 2_621_440, 256),
+    "Mistral, ZAYA": ((128, 512, 8), 4, 128, 2_621_440, 256),
+    "Ouro": ((256, 512, 8), 1, 128, 5_242_880, 256),
+    "GLM": ((256, 512, 5), 1, 256, 5_242_880, 320),
+    "cp4 dense": ((512, 2048, 1), 8, 128, 1_310_720, 1024),
+}
+
+
+@pytest.mark.parametrize("cells", list(RUNGS))
+def test_step_bytes_of_the_fused_backward_at_the_rungs_in_use(cells):
+    """A q row and head: q and dO in bf16 (4d bytes), lse and delta in
+    float32 over 128 lanes (1,024), the float32 dq tile in and out (8d):
+    2,560 bytes at head_dim 128, so 0.5 x block_k FLOPs a byte whatever
+    the GQA group (10 x rows x block_k x d FLOPs a step)."""
+    (bq, bk, hb), group, d, want, flops_a_byte = RUNGS[cells]
+    got = cost_model.step_bytes("bwd", bq, bk, hb, group, d, 2)
+    assert got == want == hb * bq * (4 * d + 1024 + 8 * d)
+    assert 10 * hb * bq * bk * d / got == flops_a_byte
+    # what it adds to the k-major step it replaced: the dq tile, both ways
+    assert got - cost_model.step_bytes("dkv", bq, bk, hb, group, d, 2) == (
+        hb * bq * 8 * d
+    )
+    assert cost_model.KERNEL_FLOP_WEIGHTS["bwd"] == 2.5
+
+
+def test_the_ranking_prices_what_it_was_calibrated_on():
+    """``bwd`` is priced by ``step_bytes`` and weighed, and enters no
+    rung's ranking: that re-ranks every mask (ROADMAP D4)."""
+    assert cost_model.RANKED_KERNELS == ("fwd", "dq", "dkv")
+    assert set(cost_model.RANKED_KERNELS) < set(cost_model.KERNEL_FLOP_WEIGHTS)
+
+
+def _cells():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+def _heads(cell):
+    """(q heads, kv heads, head_dim) at the kernels of a cell's plan, from
+    its ``plan_flex_attn`` / ``key_build`` span's own attributes where the
+    model has them, else from the cell's configuration."""
+    from benchmarks import harness
+
+    cfg = harness.load_cell(ROOT, cell).config
+    hq = cfg["num_attention_heads"]
+    hk = cfg.get("num_key_value_heads", hq)
+    if "qk_nope_head_dim" in cfg:  # the latent form: 20 = 20 heads of 256
+        return hq, hq, cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    if "cca_num_q_heads" in cfg:
+        return cfg["cca_num_q_heads"], cfg["cca_num_kv_heads"], cfg["head_dim"]
+    return hq, hk, cfg["head_dim"] if "head_dim" in cfg else cfg["hidden_size"] // hq
+
+
+@pytest.mark.parametrize("cell", _cells())
+def test_every_cells_plan_takes_the_fused_backward(telemetry_on, cell, monkeypatch):
+    """All twelve cells: the ``attn_fn_build`` span of every plan carries
+    ``bwd_form`` and the counter counts the plan. And from the plan's own
+    rung: a fused step's larger of MXU and HBM time, at the chip's peaks,
+    is under the sum of the two steps it replaced (dq's and dkv's), which
+    is why one form serves every geometry the cells bring."""
+    monkeypatch.delenv("MAGI_ATTENTION_GRID", raising=False)
+    reg = telemetry.get_registry()
+    before = reg.counter_value("magi_flex_bwd_form_total", form=BWD_FORM)
+    got = _decisions(lambda: _build_cell(cell))
+    assert got and BWD_FORM == "fused"
+    assert reg.counter_value(
+        "magi_flex_bwd_form_total", form=BWD_FORM
+    ) == before + len(got)
+    hq, hk, d = _heads(cell)
+    spec = TPU_PEAK_SPECS["v5e"]
+    for args in got:
+        assert args["bwd_form"] == BWD_FORM
+        bq, bk, hb = args["rung"]
+        group = hq // hk
+        tile_s = 4 * hb * bq * bk * d / (spec.bf16_tflops * 1e12)
+
+        def step_s(kernel):
+            return max(
+                cost_model.KERNEL_FLOP_WEIGHTS[kernel] * tile_s,
+                cost_model.step_bytes(kernel, bq, bk, hb, group, d, 2)
+                / (spec.hbm_gbps * 1e9),
+            )
+
+        assert step_s("bwd") < 0.85 * (step_s("dq") + step_s("dkv")), args
+
+
+def test_the_dense_cells_plan_reads_fused(telemetry_on):
+    """64 / 8 heads of 128, 65,536 tokens, causal: the claimed cell."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("cp",))
+    total = 65536
+    got = _decisions(
+        lambda: api.magi_attn_flex_key(
+            [(0, total)], [(0, total)], [1], total, total, mesh,
+            num_heads=(64, 8), head_dim=128, out_dtype="bfloat16",
+            interpret=False,
+        )
+    )
+    (args,) = got
+    assert args["bwd_form"] == "fused" and tuple(args["rung"]) == (1024, 1024, 1)
+    (tuned,) = _decisions(
+        lambda: (api.clear_cache(), api.magi_attn_flex_key(
+            [(0, total)], [(0, total)], [1], total, total, mesh,
+            num_heads=(64, 8), head_dim=128, out_dtype="bfloat16",
+            interpret=False,
+        )),
+        "autotune_decision",
+    )
+    assert tuned["bwd_form"] == "fused"
