@@ -19,8 +19,10 @@ sequence shards and remote-KV buffer layouts then need no mask rewriting at
 all — the moral replacement for slice_maker.py's trapezoid case analysis.
 
 Tables are built in both orientations:
-- q-major (sorted by q-block): forward + dq backward kernels,
-- k-major (sorted by k-block): dkv backward kernel.
+- q-major (sorted by q-block): the forward kernel,
+- k-major (sorted by k-block): the backward kernel (dk / dv per k block in
+  VMEM; each entry marked as the first / last visit of its q block, whose
+  dq sums the walk keeps in HBM: :func:`mark_q_visits`).
 
 Every q-block (resp. k-block) has at least one entry — a dummy all-masked
 entry pointing at the sentinel slice — so output tiles are always written
@@ -39,7 +41,16 @@ import numpy as np
 # (common/enum.AttnMaskType), so a stepped slice costs no field.
 SLICE_FIELDS = 5  # qs, qe, ks, ke, mask_type
 # Fields per entry in the flattened runs table (local windows + offsets).
-RUN_FIELDS = 7  # ql0, ql1, kl0, kl1, qoff, koff, needs_mask (diagnostic)
+# The seventh word is a flag word no kernel's mask reads. Bit 0, in both
+# tables: the planner did not find the tile whole ("needs mask", a plan
+# diagnostic). Bits 1 and 2, in the k-major table alone: the entry is the
+# first resp. the last visit of its q block in table order, which on the
+# backward's walk is the order in time (:func:`mark_q_visits`; the
+# backward's dq protocol acts on them, ``flex_attn._dq_accumulate``).
+RUN_FIELDS = 7  # ql0, ql1, kl0, kl1, qoff, koff, flags
+NEEDS_MASK = 1
+FIRST_VISIT = 2
+LAST_VISIT = 4
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -114,13 +125,13 @@ class FlexAttnBlockMeta:
     num_slices: int
     total_area: int  # exact unmasked pair count within this rank's plan
 
-    # q-major table (forward / dq)
+    # q-major table (the forward)
     fwd_q_block: np.ndarray  # [E]
     fwd_k_block: np.ndarray  # [E]
     fwd_slice_id: np.ndarray  # [E]
     fwd_runs: np.ndarray  # [E * RUN_FIELDS]
 
-    # k-major table (dkv)
+    # k-major table (the backward)
     bwd_k_block: np.ndarray  # [E2]
     bwd_q_block: np.ndarray  # [E2]
     bwd_slice_id: np.ndarray  # [E2]
@@ -302,6 +313,37 @@ def _needs_mask_flags(
     full &= ~inv | (gk_lo >= ks + ((gq_hi - qs) >> ls << ls))
     full &= ~dummy
     return (~full).astype(np.int64)
+
+
+def mark_q_visits(q_block: np.ndarray, runs: np.ndarray) -> np.ndarray:
+    """The k-major runs table with each entry's visit bits set from the
+    table as it will be walked, i.e. after every padding: FIRST_VISIT on
+    the first entry that names a q block, LAST_VISIT on the last one (an
+    entry may be both). Every entry counts as a visit of the q block it
+    names, pads and dummies too (they name q block 0 under the sentinel
+    slice and add exact zeros), so the walk's first entry is always a
+    first visit and the kernel needs no third kind of step. Bits already
+    there (a meta that is padded again) are recomputed; bit 0 stays."""
+    qb = np.asarray(q_block, dtype=np.int64)
+    words = np.array(runs, dtype=np.int32).reshape(-1, RUN_FIELDS)
+    n = qb.shape[0]
+    first = np.zeros(n, bool)
+    last = np.zeros(n, bool)
+    # first occurrences; the last ones are the first of the reversal
+    first[np.unique(qb, return_index=True)[1]] = True
+    last[n - 1 - np.unique(qb[::-1], return_index=True)[1]] = True
+    words[:, 6] = (
+        (words[:, 6] & NEEDS_MASK) | first * FIRST_VISIT | last * LAST_VISIT
+    )
+    return words.reshape(-1)
+
+
+def q_visit_counts(q_block: np.ndarray, num_q_blocks: int) -> tuple[int, int]:
+    """(q blocks a k-major table names in some entry, q blocks it names in
+    none) of one rank's table. A block no entry names is never written by
+    the backward's walk: its dq has to come back as zeros from a fill."""
+    named = np.unique(np.asarray(q_block)).size
+    return named, max(int(num_q_blocks), named) - named
 
 
 def _distribute_pad_majors(
@@ -538,6 +580,7 @@ def assemble_block_meta(
 
     fwd = _pad_table(fwd, pad_entries_to, nq)
     bwd = _pad_table(bwd, pad_bwd_entries_to, nk)
+    bwd = (*bwd[:3], mark_q_visits(bwd[1], bwd[3]))
 
     n_slices_store = S if num_slices_padded is None else num_slices_padded
     assert n_slices_store >= S
@@ -622,6 +665,7 @@ def pad_block_meta(
         S,
         meta.num_k_blocks,
     )
+    br = mark_q_visits(bq, br)  # the pads changed who is first and last
     bounds = np.zeros(((num_slices_padded + 1) * SLICE_FIELDS,), np.int32)
     bounds[: meta.slice_bounds.shape[0]] = meta.slice_bounds
     return dataclasses.replace(

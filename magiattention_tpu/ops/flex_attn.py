@@ -10,10 +10,13 @@ output block are consecutive and accumulate in VMEM scratch. The forward
 walks the q-major table. The backward walks the k-major one and computes
 S, the mask, P, dP and dS once a tile (five matmuls: S, dP, dV, dK, dQ):
 dk and dv accumulate in VMEM per k block, and dq, whose q block comes
-back once a key column, is a float32 buffer in HBM that each step reads,
-adds to and writes back by its own DMAs, a tile's read started a step
-ahead (``_dq_accumulate`` keeps the orderings; docs/block_sparse.md has
-the bytes a step moves and why the walk is k-major).
+back once a key column, keeps its float32 sums in a scratch buffer in HBM
+that the steps move by their own DMAs, a tile's read started a step ahead:
+the table marks each q block's first and last visit, so the first reads
+nothing and the last writes the result in the inputs' dtype (no rounding
+pass after the kernel; ``_dq_accumulate`` keeps the orderings;
+docs/block_sparse.md has the bytes a step moves and why the walk is
+k-major).
 
 Entries carry run fields (local window + local->global offset), so the same
 kernels serve the distributed runtime where each rank's Q/KV buffers are
@@ -38,10 +41,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .block_meta import (
+    FIRST_VISIT,
+    LAST_VISIT,
     RUN_FIELDS,
     SLICE_FIELDS,
     FlexAttnBlockMeta,
     build_block_meta,
+    q_visit_counts,
 )
 from .block_sparse import clamped_entry, row_tables
 from ..utils.compat import tpu_compiler_params
@@ -86,8 +92,8 @@ def _flex_pallas_call(
     a snapshot says which of the per-head and head-batched forms ran, and
     on which of :data:`GRID_KINDS`. ``form``: the labels that say in which
     form a side operand crosses this kernel's boundary (the forward's
-    ``stats=compact|lanes``, the backward's ``delta=xla``;
-    :func:`stats_form`)."""
+    ``stats=compact|lanes``, the backward's ``delta=xla`` and
+    ``dq=visits|zero_filled``; :func:`stats_form`, :func:`dq_form`)."""
     from .. import telemetry
 
     telemetry.record_flex_kernel_build(
@@ -170,6 +176,10 @@ class FlexAttnParams:
     # the largest step of any slice the tables hold (bounds_mask_step);
     # 1 compiles the mask arithmetic of a key a row
     mask_step: int = 1
+    # q blocks that the k-major tables name in no entry, over every rank
+    # and table set these params serve, counted by the plan builder on
+    # the host beside bwd_steps (dq_form). None = not counted
+    bwd_unnamed_q: int | None = None
 
     @property
     def out_jnp_dtype(self):
@@ -999,6 +1009,34 @@ def _bwd_p_ds(
     return p, ds
 
 
+def _dq_step(qblk, runs, e, head0, g=0, group: int = 1):
+    """Where a live backward step stands in dq's walk, as
+    :func:`_dq_accumulate` takes it: the step of entry ``e`` of the k-major
+    table ``(qblk, runs)`` whose dq tile starts at head ``head0 + g``. The
+    per-head body walks a kv head's ``group`` q heads innermost, so each
+    entry is ``group`` steps on ``group`` tiles, and its visit bits are
+    theirs all; the head-batched body's step is the entry. The next live
+    step is the next entry's on both grids: a dead row-major step runs
+    none of this, and every k block has an entry."""
+    n = qblk.shape[0]
+    e_next = jnp.minimum(e + 1, n - 1)
+    if group == 1:
+        wraps, at, head_next = True, e_next, head0
+    else:
+        wraps = g == group - 1
+        at = jnp.where(wraps, e_next, e)
+        head_next = head0 + jnp.where(wraps, 0, g + 1)
+    flag_word = lambda entry: runs[entry * RUN_FIELDS + 6]  # noqa: E731
+    return dict(
+        tile=(head0 + g, qblk[e]),
+        nxt=(head_next, qblk[at]),
+        flags=flag_word(e),
+        nxt_flags=flag_word(at),
+        first=(e == 0) & (g == 0),
+        last=(e == n - 1) & wraps,
+    )
+
+
 def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
     """q heads one row-major backward step takes: ``params.head_block``
     (what the forward takes, and what the tuner's cost model prices every
@@ -1016,76 +1054,144 @@ def _bwd_head_block(params: FlexAttnParams, hq: int, group: int) -> int:
     return hbg if live <= _BWD_HB_LIVE_BYTES else 1
 
 
-def _dq_accumulate(dq_hbm, buf, sem, st, add, *, tile, nxt, first, last, bq):
+def _dq_accumulate(
+    dq_acc, dq_out, buf, stage, sem, st, contribution, *, tile, nxt, flags,
+    nxt_flags, first, last, bq, d,
+):
     """One live step's share of dq, on a walk that does not keep a q block
-    in place: the float32 tile ``tile = (first head, q block)`` of the
-    ``[hq, tqp, d]`` buffer ``dq_hbm`` is waited for (its read into one of
-    ``buf``'s two VMEM slots was started by the step before), ``add(slot
-    ref)`` adds this step's ``scale * dS K`` to it, its write-back is
-    started, and the read of ``nxt``, the next live step's tile, is started
-    into the other slot. The steps of one core run in order, so the buffer
-    in HBM needs no atomics; two orderings are kept by the step itself:
+    in place. ``tile = (first head, q block)`` names this step's tile of
+    the two ``[hq, tqp, d]`` buffers in HBM: ``dq_acc``, float32 scratch
+    that only this walk reads, and ``dq_out``, the result in the inputs'
+    dtype. ``flags`` is the entry's flag word of the k-major runs table
+    (``block_meta.mark_q_visits``): whether this is the FIRST_VISIT and
+    whether it is the LAST_VISIT of the q block in table order, which is
+    the order in time, because the steps of one core run in order (and so
+    the buffers need no atomics). ``nxt`` / ``nxt_flags``: the same of the
+    next live step. What a step does:
+
+    - the tile's sums so far come into one of ``buf``'s two float32 VMEM
+      slots: on a first visit there are none and nothing is read
+      (``dq_acc`` is read only where this walk has written); else the read
+      that the step before started is waited for;
+    - ``contribution()``, this step's ``scale * dS K`` (heads, bq, ``d``),
+      is added to them in the slot: stored as it is on a first visit,
+      which as a float32 value is the ``0 + x`` a zero-filled tile gave;
+    - on a last visit the slot is rounded to the inputs' dtype into one of
+      ``stage``'s two slots, whose write to ``dq_out`` is started, and
+      nothing goes back to ``dq_acc`` (a tile visited once never touches
+      it); else the slot's float32 write-back is started;
+    - the read of ``nxt`` is started into the other slot, unless its visit
+      is a first one.
+
+    The orderings the step keeps itself:
 
     - a read of a tile never starts while a write to the same tile is in
       flight: where ``nxt == tile`` (a column boundary, two slices on one
       tile, a mask with one q block) the tile stays in its slot and makes
       no round trip; a tile written a step earlier has been waited for
       (below) before any read starts;
-    - a slot is read into only when its last write-back has landed.
+    - a slot of ``buf`` is read or stored into only when its last
+      write-back has landed, and a slot of ``stage`` is rounded into only
+      when the write started from it two last visits ago has.
 
     ``first`` / ``last``: the first and the last live step of this head
-    block's walk. The first reads its own tile, the last waits for its own
-    write: a head block begins and ends with nothing in flight, so the
-    head axis may be ``parallel``. ``st`` (SMEM, 3 words) carries the slot,
-    whether the tile is already in it, and whether the other slot's write
-    is in flight; ``sem`` is ``DMA((2, 2))``: reads, writes x slot."""
+    block's walk. The first entry of a table is a first visit, so the walk
+    starts without a read; the last step waits for every write still in
+    flight: a head block begins and ends with nothing in flight, so the
+    head axis may be ``parallel``. ``st`` (SMEM, 4 words) carries the
+    slot, whether the tile is already in it, whether the other slot's
+    write-back is in flight, and the count of writes to ``dq_out`` started
+    (its parity is the staging slot); ``sem`` is ``DMA((3, 2))``: reads,
+    write-backs, result writes x slot."""
     heads = buf.shape[1]
 
-    def hbm(t):
+    def rows(hbm, t):
         head0, qb = t
-        rows = pl.ds(pl.multiple_of(qb * bq, bq), bq)
-        return dq_hbm.at[pl.ds(head0, heads), rows]
+        return hbm.at[
+            pl.ds(head0, heads), pl.ds(pl.multiple_of(qb * bq, bq), bq)
+        ]
 
     def read(t, slot):
-        return pltpu.make_async_copy(hbm(t), buf.at[slot], sem.at[0, slot])
+        return pltpu.make_async_copy(
+            rows(dq_acc, t), buf.at[slot], sem.at[0, slot]
+        )
 
-    def write(t, slot):
-        return pltpu.make_async_copy(buf.at[slot], hbm(t), sem.at[1, slot])
+    def write_back(t, slot):
+        return pltpu.make_async_copy(
+            buf.at[slot], rows(dq_acc, t), sem.at[1, slot]
+        )
+
+    def write_out(t, slot):
+        return pltpu.make_async_copy(
+            stage.at[slot], rows(dq_out, t), sem.at[2, slot]
+        )
 
     @pl.when(first)
     def _start():
-        st[0] = 0
-        st[1] = 0
-        st[2] = 0
-        read(tile, 0).start()
+        for word in range(4):
+            st[word] = 0
 
     cur = st[0]
+    first_visit = (flags & FIRST_VISIT) != 0
+    last_visit = (flags & LAST_VISIT) != 0
 
-    @pl.when(st[1] == 0)
-    def _arrive():
+    @pl.when(jnp.logical_not(first_visit) & (st[1] == 0))
+    def _arrive():  # (a first visit is never a tile kept from the step before)
         read(tile, cur).wait()
 
-    add(buf.at[cur])
+    # One pass over the tile's vregs whatever the bits are, in line with the
+    # step's other matmuls: a first visit's sums are the product itself (as
+    # a float32 value the ``0 + x`` a zero-filled tile gave) and the select
+    # drops whatever the slot held. On the chip the select costs nothing,
+    # a slot zeroed first or the sum inside a branch does (PERF.md section
+    # 6, PR 44). The tile's lanes past ``d`` are padding that nobody reads:
+    # the result is cut to ``d`` where it leaves the launcher
+    sums = (cur, slice(None), slice(None), slice(0, d))
+    x = contribution()
+    buf[sums] = jnp.where(first_visit, x, buf[sums] + x)
+    # the next step on the same tile is a later visit, so this is no last
     keep = jnp.logical_not(last) & (nxt[0] == tile[0]) & (nxt[1] == tile[1])
     st[1] = keep.astype(jnp.int32)
+    leaving = jnp.logical_not(keep)
 
-    @pl.when(jnp.logical_not(keep))
+    @pl.when(leaving & jnp.logical_not(last_visit))
+    def _back():
+        write_back(tile, cur).start()
+
+    @pl.when(leaving & last_visit)
+    def _result():
+        n_out = st[3]
+        slot = n_out & 1
+
+        @pl.when(n_out >= 2)
+        def _staged():
+            write_out(tile, slot).wait()
+
+        stage[slot] = buf[cur].astype(stage.dtype)
+        write_out(tile, slot).start()
+        st[3] = n_out + 1
+
+    @pl.when(leaving)
     def _leave():
-        write(tile, cur).start()
-
         @pl.when(st[2] == 1)
         def _landed():
-            write(tile, 1 - cur).wait()
+            write_back(tile, 1 - cur).wait()
 
         @pl.when(last)
         def _drain():
-            write(tile, cur).wait()
+            # (the table's last entry is a last visit: no write-back here)
+            for back in (1, 2):  # the result writes not yet waited for
 
-        @pl.when(jnp.logical_not(last))
+                @pl.when(st[3] >= back)
+                def _out():
+                    write_out(tile, (st[3] - back) & 1).wait()
+
+        @pl.when(jnp.logical_not(last) & ((nxt_flags & FIRST_VISIT) == 0))
         def _ahead():
             read(nxt, 1 - cur).start()
-            st[0] = 1 - cur
-            st[2] = 1
+
+        st[0] = 1 - cur
+        st[2] = jnp.logical_not(last_visit).astype(jnp.int32)
 
 
 def _bwd_kernel(
@@ -1102,13 +1208,14 @@ def _bwd_kernel(
     do_ref,
     lse_ref,
     delta_ref,
-    _dq_zeros,  # aliased to dq_hbm
     dk_ref,
     dv_ref,
-    dq_hbm,  # [hq, tqp, d] float32, in HBM
+    dq_out,  # [hq, tqp, d] in the inputs' dtype, in HBM
+    dq_acc,  # [hq, tqp, d] float32, in HBM: scratch of this walk
     dk_scr,
     dv_scr,
     dq_buf,  # (2, 1, bq, d) float32
+    dq_stage,  # (2, 1, bq, d) in the inputs' dtype
     dq_sem,
     dq_st,
     *,
@@ -1126,7 +1233,6 @@ def _bwd_kernel(
     w = _Walk(params.grid, kblk, rs, rc, inner=True)
     i, e, g = w.i, w.e, w.g
     h = pl.program_id(0)
-    n_entries = kblk.shape[0]
 
     @pl.when(w.first() & (g == 0))
     def _init():
@@ -1159,27 +1265,18 @@ def _bwd_kernel(
             preferred_element_type=jnp.float32,
         )
 
-        def add(tile_ref):
-            d = k_ref.shape[2]  # the tile's lanes past d are padding
-            tile_ref[0, :, :d] += jnp.float32(params.scale) * jax.lax.dot_general(
+        def dq():
+            return jnp.float32(params.scale) * jax.lax.dot_general(
                 ds,
                 k_ref[0],
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )
+            )[None]
 
-        wraps = g == group - 1
-        e_next = jnp.minimum(e + 1, n_entries - 1)
         _dq_accumulate(
-            dq_hbm, dq_buf, dq_sem, dq_st, add,
-            tile=(h * group + g, qblk[e]),
-            nxt=(
-                h * group + jnp.where(wraps, 0, g + 1),
-                jnp.where(wraps, qblk[e_next], qblk[e]),
-            ),
-            first=(e == 0) & (g == 0),
-            last=(e == n_entries - 1) & wraps,
-            bq=bq,
+            dq_acc, dq_out, dq_buf, dq_stage, dq_sem, dq_st, dq, bq=bq,
+            d=k_ref.shape[2],
+            **_dq_step(qblk, runs, e, h * group, g, group),
         )
 
     @pl.when(w.last() & (g == group - 1))
@@ -1202,13 +1299,14 @@ def _bwd_kernel_hb(
     do_ref,  # (HBG, bq, d)
     lse_ref,  # (HBG, bq, LANES)
     delta_ref,
-    _dq_zeros,  # aliased to dq_hbm
     dk_ref,  # (HB, bk, d)
     dv_ref,
-    dq_hbm,  # [hq, tqp, d] float32, in HBM
+    dq_out,  # [hq, tqp, d] in the inputs' dtype, in HBM
+    dq_acc,  # [hq, tqp, d] float32, in HBM: scratch of this walk
     dk_scr,
     dv_scr,
     dq_buf,  # (2, HBG, bq, d) float32
+    dq_stage,  # (2, HBG, bq, d) in the inputs' dtype
     dq_sem,
     dq_st,
     *,
@@ -1227,7 +1325,6 @@ def _bwd_kernel_hb(
     w = _Walk(params.grid, kblk, rs, rc)
     i, e = w.i, w.e
     h = pl.program_id(0)
-    n_entries = kblk.shape[0]
 
     @pl.when(w.first())
     def _init():
@@ -1258,23 +1355,18 @@ def _bwd_kernel_hb(
             preferred_element_type=jnp.float32,
         )
 
-        def add(tile_ref):
-            dq = jnp.float32(params.scale) * jax.lax.dot_general(
+        def dq():
+            return jnp.float32(params.scale) * jax.lax.dot_general(
                 ds,
                 k_ref[...],
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            )
-            d = dq.shape[-1]  # the tile's lanes past d are padding
-            tile_ref[:, :, :d] += dq.reshape(hbg, bq, d)
+            ).reshape(hbg, bq, -1)
 
         _dq_accumulate(
-            dq_hbm, dq_buf, dq_sem, dq_st, add,
-            tile=(h * hbg, qblk[e]),
-            nxt=(h * hbg, qblk[jnp.minimum(e + 1, n_entries - 1)]),
-            first=e == 0,
-            last=e == n_entries - 1,
-            bq=bq,
+            dq_acc, dq_out, dq_buf, dq_stage, dq_sem, dq_st, dq, bq=bq,
+            d=k_ref.shape[2],
+            **_dq_step(qblk, runs, e, h * hbg),
         )
 
     @pl.when(w.last())
@@ -1283,13 +1375,37 @@ def _bwd_kernel_hb(
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def dq_form(params: FlexAttnParams, q_block, num_q_blocks: int) -> str:
+    """Where the backward's dq output gets the rows that no entry of the
+    k-major table names (a remote stage at cp > 1, rows outside every
+    slice): ``"visits"``, the table names every q block, and each is
+    written by its last visit alone; ``"zero_filled"``, some block is left
+    out, and the output is aliased to a zero fill in the inputs' dtype.
+    A fact of the plan, read on the host: ``params.bwd_unnamed_q`` where
+    the plan builder counted it (per-rank tables are traced here), else
+    the concrete table; a traced table nobody counted takes the fill."""
+    unnamed = params.bwd_unnamed_q
+    if unnamed is None:
+        if isinstance(q_block, jax.core.Tracer):
+            return "zero_filled"
+        unnamed = q_visit_counts(np.asarray(q_block), num_q_blocks)[1]
+    return "zero_filled" if unnamed else "visits"
+
+
 def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
-    """(dk, dv in their inputs' dtype, dq [hq, tqp, d] float32) from one
-    kernel over the k-major table. dq is an operand in HBM, zero-filled
-    and aliased to the output; the axis that walks k blocks is
-    ``arbitrary`` on both grids (two k blocks add into one dq tile). A
-    tile is copied whole vregs of lanes at a time, so at a head_dim that
-    is no multiple of 128 the buffer is that much wider and cut here."""
+    """(dq, dk, dv in their inputs' dtype) from one kernel over the k-major
+    table. dq is two buffers ``[hq, tqp, d]`` in HBM (``memory_space=ANY``)
+    that the steps move by their own DMAs (:func:`_dq_accumulate`): the
+    float32 sums, scratch of the walk (written on a q block's visits but
+    the last, read on all but the first, dropped here; aliased to an
+    operand nobody has written, see below), and the result in the inputs'
+    dtype, which each q block's last visit writes: aliased to dO where the
+    table names every q block, to a zero fill of its own under
+    :func:`dq_form` ``"zero_filled"``. The axis that walks k blocks is
+    ``arbitrary`` on both grids (two k blocks add into one dq tile). A tile
+    is copied whole vregs of lanes at a time, so at a head_dim that is no
+    multiple of 128 the buffers are that much wider and the result is cut
+    here."""
     kblk, qblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk, tkp, _ = k.shape
@@ -1316,8 +1432,36 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             lambda h, g: h * group + g, inner=(group,), blocks="arbitrary",
         )
     dq_shape = (hq, tqp, -(-d // LANES) * LANES)
+    form = dq_form(params, qblk, tqp // bq)
+    operands = [kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta]
+    n_blocked = len(operands)
+    # Operands in HBM that only give two outputs their buffers (aliased,
+    # never read through these refs: the body is not handed them). The
+    # float32 sums get a buffer nobody has written (``lax.empty``: XLA's
+    # AllocateBuffer on the chip, no pass; zeros under the interpreter):
+    # no step reads what this walk has not written. An operand all the
+    # same, and not a bare output: with a bare output the SDAR cell's
+    # check compiles to another program throughout (memory-space
+    # assignment, the forward's too) that reads its gradients 7x further
+    # off on the chip whatever the step does; with this operand the
+    # program is the zero-filled one's but for the fill (PERF.md section
+    # 6, PR 44)
     with named_scope("magi_layout"):
-        dq_zeros = jnp.zeros(dq_shape, jnp.float32)
+        operands.append(jax.lax.empty(dq_shape, jnp.float32))
+        aliases = {n_blocked: 3}
+        if form == "zero_filled":
+            operands.append(jnp.zeros(dq_shape, q.dtype))
+            aliases[n_blocked + 1] = 2
+    if form == "visits" and do.shape == dq_shape:
+        # nothing to fill: the result takes dO's place in HBM. A q block's
+        # last visit is the last step that reads its dO tile (fetched
+        # before the step, never again: no later entry names the block),
+        # and dO is dead after the kernel, so the two never meet; without
+        # it the program holds the float32 sums, the result and every
+        # operand at once, one dq in the inputs' dtype more than before
+        aliases[10] = 2  # (the seven tables, q, k, v, then dO)
+    kernel, n_fills = body, len(operands) - n_blocked
+    body = lambda *refs: kernel(*refs[:n_blocked], *refs[n_blocked + n_fills :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=grid,
@@ -1328,39 +1472,41 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
-            pl.BlockSpec(memory_space=pl.ANY),
+            *[pl.BlockSpec(memory_space=pl.ANY)] * n_fills,
         ],
         out_specs=[
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             kv_scratch,
             kv_scratch,
             pltpu.VMEM((2, hbg, bq, dq_shape[2]), jnp.float32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((3,), jnp.int32),
+            pltpu.VMEM((2, hbg, bq, dq_shape[2]), q.dtype),
+            pltpu.SemaphoreType.DMA((3, 2)),
+            pltpu.SMEM((4,), jnp.int32),
         ],
     )
-    dk, dv, dq = _flex_pallas_call(
+    dk, dv, dq, _scratch = _flex_pallas_call(
         "bwd",
         hbg,
         params.grid,
         body,
-        form={"delta": "xla"},
+        form={"delta": "xla", "dq": form},
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), k.dtype),
             jax.ShapeDtypeStruct((hk, tkp, d), v.dtype),
+            jax.ShapeDtypeStruct(dq_shape, q.dtype),
             jax.ShapeDtypeStruct(dq_shape, jnp.float32),
         ],
-        # operand 13 (after the seven tables and q, k, v, dO, lse, delta)
-        input_output_aliases={13: 2},
+        input_output_aliases=aliases,
         interpret=params.interpret,
         compiler_params=_compiler_params(*semantics),
-    )(kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta, dq_zeros)
-    return dk, dv, dq[:, :, :d]
+    )(*operands)
+    return dq[:, :, :d], dk, dv
 
 
 def _bwd_delta(do, out, dlse):
@@ -1443,9 +1589,7 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
     if isinstance(dlse, SymbolicZero):
         dlse = None
     delta, delta_lanes = _bwd_delta(do, out, dlse)
-    dk, dv, dq = _bwd_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
-    with named_scope("magi_layout"):
-        dq = dq.astype(q.dtype)  # the one rounding of the float32 sums
+    dq, dk, dv = _bwd_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
     with named_scope("magi_bwd_delta"):
         if params.has_sink:
             # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
@@ -1751,6 +1895,7 @@ def flex_attn_with_meta(
         bwd_steps=meta.bwd_steps,
         grid=str(grid),
         mask_step=bounds_mask_step(meta.slice_bounds),
+        bwd_unnamed_q=q_visit_counts(meta.bwd_q_block, meta.num_q_blocks)[1],
     )
     out_h, lse_h, rowmax = flex_attn_headmajor(
         qh, kh, vh, fwd_tables(meta), bwd_tables(meta), params, sink=sink

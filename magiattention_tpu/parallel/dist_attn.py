@@ -56,12 +56,14 @@ from ..meta.solver.overlap_solver import (
     simulate_overlap_timeline,
 )
 from ..ops.block_meta import (
+    NEEDS_MASK,
     RUN_FIELDS,
     SLICE_FIELDS,
     FlexAttnBlockMeta,
     Run,
     build_block_meta_general,
     pad_block_meta,
+    q_visit_counts,
     runs_from_position_ids,
 )
 from ..ops.correction import correct_attn_out_lse
@@ -158,6 +160,24 @@ class StageTables:
         )
         return row_major, compact, float(live)
 
+    def q_visits(self) -> tuple[int, int, int]:
+        """What the backward's dq protocol meets on these k-major tables
+        (``ops/flex_attn._dq_accumulate``), summed over ranks: (entries,
+        q blocks some entry names, q blocks none names). Every entry is a
+        visit of the q block it names, and a block no entry of a rank's
+        table names (a stage whose keys reach only some of the rank's
+        rows) is never written by that rank's walk: one such block in
+        any rank makes the stage's kernel take its dq output aliased to a
+        zero fill (``FlexAttnParams.bwd_unnamed_q``)."""
+        # (block counts unknown, a legacy construction: every rank fills)
+        nq = self.num_q_blocks or int(self.bwd_qblk.max()) + 2
+        counts = [q_visit_counts(row, nq) for row in self.bwd_qblk]
+        return (
+            int(self.bwd_qblk.size),
+            sum(c[0] for c in counts),
+            sum(c[1] for c in counts),
+        )
+
     def stepped_tile_steps(self) -> float:
         """Of :meth:`grid_steps`' live steps (same weights, the mean over
         ranks), those whose tile a stepped bound crosses: entries of a
@@ -169,7 +189,8 @@ class StageTables:
         for weight, sid, runs in (
             (2, self.fwd_sid, self.fwd_runs), (1, self.bwd_sid, self.bwd_runs)
         ):
-            binds = runs.reshape(runs.shape[0], -1, RUN_FIELDS)[..., 6] != 0
+            words = runs.reshape(runs.shape[0], -1, RUN_FIELDS)[..., 6]
+            binds = (words & NEEDS_MASK) != 0  # the k-major word has more bits
             crossed = np.take_along_axis(stepped, sid, axis=1) & binds
             total += weight * crossed.sum(axis=1).mean()
         return float(total)
@@ -935,6 +956,8 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
         "sparse": sum(c[1] for c in counts),
     }
     live = sum(c[2] for c in counts)
+    visits = [t.q_visits() for t in tabs]
+    visited = sum(v[1] for v in visits)
     grid = env.grid_override() or choose_grid(*launched.values())
     row_major_s, compact_s = price_grids(*launched.values())
     telemetry.annotate_span(
@@ -946,6 +969,15 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
         stats=stats_form(params.block_q),
         delta="xla",
         bwd_form=BWD_FORM,
+        # what the backward's dq protocol meets on the k-major tables
+        # (ops/flex_attn._dq_accumulate): the visits a q tile gets in the
+        # mean (each moves it once; the first reads nothing, the last
+        # writes the result), and the q blocks that no entry of some
+        # rank's table names, which make that call's output a zero fill
+        dq_visits_per_tile=(
+            sum(v[0] for v in visits) / visited if visited else 0.0
+        ),
+        dq_unnamed_q_blocks=sum(v[2] for v in visits),
         row_major_steps=launched["row_major"],
         compact_steps=launched["sparse"],
         live_steps=live,
@@ -991,7 +1023,7 @@ def ensure_kernel_steps(params: FlexAttnParams, tables) -> FlexAttnParams:
     alone — so params built for one plan cannot silently under-cover a
     different plan's tables (too-small steps would drop entries with no
     error under tracing)."""
-    fs = bs = 0
+    fs = bs = unnamed = 0
     step = 1
     for t in tables:
         if t is None:
@@ -1000,21 +1032,35 @@ def ensure_kernel_steps(params: FlexAttnParams, tables) -> FlexAttnParams:
         fs = max(fs, a)
         bs = max(bs, b)
         step = max(step, bounds_mask_step(t.bounds))
+        unnamed += t.q_visits()[2]
     if (
         params.fwd_steps >= fs
         and params.bwd_steps >= bs
         and params.mask_step >= step
+        and params.bwd_unnamed_q is not None
+        and params.bwd_unnamed_q >= unnamed
     ):
         return params
     # mask_step rides with the extents: it too is read off the tables the
     # kernels will walk, and too small a value would read a stepped slice
-    # as a diagonal with no error
+    # as a diagonal with no error. So does the count of q blocks that some
+    # k-major table leaves out (ops/flex_attn.dq_form): at 0 the backward
+    # fills nothing, and a block no visit writes would come back unset
     return dataclasses.replace(
         params,
         fwd_steps=max(params.fwd_steps, fs),
         bwd_steps=max(params.bwd_steps, bs),
         mask_step=max(params.mask_step, step),
+        bwd_unnamed_q=max(params.bwd_unnamed_q or 0, unnamed),
     )
+
+
+def _for_tables(params: FlexAttnParams, tables: StageTables) -> FlexAttnParams:
+    """``params`` (whose extents cover the whole plan) for the one kernel
+    call that walks ``tables``: the count of unnamed q blocks is this
+    stage's own, so a stage that names every block fills nothing though
+    another stage of the plan leaves some out."""
+    return dataclasses.replace(params, bwd_unnamed_q=tables.q_visits()[2])
 
 
 def _call_kernel(qh, k_buf, v_buf, tab_arrays, kv_pad, params, sink):
@@ -1184,7 +1230,8 @@ def dist_attn_local(
     host_tab = take(9)
     with named_scope("magi_host_stage_kernel"):
         out_h, lse_h, rowmax = _call_kernel(
-            qh, k, v, host_tab, plan.host_tables.kv_pad, host_params, sink
+            qh, k, v, host_tab, plan.host_tables.kv_pad,
+            _for_tables(host_params, plan.host_tables), sink,
         )
     with named_scope("magi_layout"):
         out, lse = _headmajor_to_seq(out_h, lse_h, plan.shard_q_len)
@@ -1202,7 +1249,7 @@ def dist_attn_local(
         with named_scope(f"magi_stage{i}_kernel"):
             out_i_h, lse_i_h, rowmax_i = _call_kernel(
                 qh, recv[:, 0], recv[:, 1], tab, sp.tables.kv_pad,
-                stage_params, None,
+                _for_tables(stage_params, sp.tables), None,
             )
         with named_scope("magi_layout"):
             out_i, lse_i = _headmajor_to_seq(

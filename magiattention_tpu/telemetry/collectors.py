@@ -1155,7 +1155,8 @@ def record_flex_kernel_build(
 ) -> None:
     """One flex ``pallas_call`` built (``ops/flex_attn._flex_pallas_call``,
     while jax traces the caller — never inside a compiled step). ``form``:
-    the forward's ``stats=compact|lanes``, the backward's ``delta=xla``."""
+    the forward's ``stats=compact|lanes``, the backward's ``delta=xla`` and
+    ``dq=visits|zero_filled``."""
     if not _enabled():
         return
     get_registry().counter_inc(

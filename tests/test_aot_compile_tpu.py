@@ -64,8 +64,10 @@ def _compile(fn, *args) -> str:
 
 # the build counter's labels at the cells' blockings (block_q a multiple of
 # 128): the statistics cross the forward's boundary with rows along lanes
-# (ISSUE 40), and delta is made before the one backward kernel (ISSUE 43)
-_FORMS = {"fwd": {"stats": "compact"}, "bwd": {"delta": "xla"}}
+# (ISSUE 40), delta is made before the one backward kernel (ISSUE 43), and
+# every q block of these masks has a key, so dq is written by its blocks'
+# last visits and nothing is zero-filled (ISSUE 44)
+_FORMS = {"fwd": {"stats": "compact"}, "bwd": {"delta": "xla", "dq": "visits"}}
 
 
 def _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid, softcap=0.0) -> str:
@@ -124,6 +126,44 @@ def test_stepped_bound_at_the_block_diffusion_cells_shapes(topo, grid, rung):
     chip = SingleDeviceSharding(topo.devices[0])
     text = _compile_fwd_bwd(chip, mask, 4096, 32, 4, 128, rung, grid)
     assert text.count("tpu_custom_call") >= 2  # fwd, bwd
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize(
+    "hq,hk,d,rung",
+    [(64, 8, 128, (128, 512, 8)), (64, 8, 128, (1024, 1024, 1)),
+     (20, 20, 256, (256, 512, 5))],
+    ids=["heads-batched", "per-head", "latent-20x256"],
+)
+def test_zero_filled_dq_where_the_table_leaves_q_blocks_out(
+    topo, hq, hk, d, rung, grid
+):
+    """ISSUE 44: the other form of the backward's dq output. On a mask
+    whose second half of the rows has no key the k-major table names half
+    the q blocks, the kernel's dq output is aliased to a zero fill in the
+    inputs' dtype (one more operand in ``memory_space=ANY``), and the
+    build counter says ``dq=zero_filled``: it compiles at the cells' rungs
+    and head geometries as the ``visits`` form does in the tests beside
+    this one."""
+    from magiattention_tpu import telemetry
+
+    t = 16384
+    mask = ([(0, t // 2)], [(0, t // 2)], [1])
+    chip = SingleDeviceSharding(topo.devices[0])
+    reg = telemetry.get_registry()
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    reg.clear_metric("magi_flex_kernel_build_total")
+    try:
+        text = _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid)
+        assert reg.counter_value(
+            "magi_flex_kernel_build_total", kernel="bwd", grid=grid,
+            heads_per_step=rung[2], delta="xla", dq="zero_filled",
+        ) == 1
+    finally:
+        reg.clear_metric("magi_flex_kernel_build_total")
+        telemetry.set_enabled(was)
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])

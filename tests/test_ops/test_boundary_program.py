@@ -1,8 +1,9 @@
 """What a keyed attention call's program holds round its kernels (ISSUE
 40): no XLA pass that only reformats a kernel's side operand. The forward
 makes no lane-replicated statistic at all. Forward+backward (ISSUE 43: one
-backward kernel over the k-major table) makes delta before the kernel and
-rounds the float32 dq after it, each once, and nothing else."""
+backward kernel over the k-major table) makes delta before the kernel,
+once, and nothing else: since ISSUE 44 dq's float32 buffer is neither
+zero-filled before the kernel nor rounded after it."""
 
 import jax
 import jax.numpy as jnp
@@ -78,28 +79,44 @@ def test_forward_holds_no_lane_replicated_statistic(programs):
             assert not (len(shape) == 3 and shape[-1] == LANES), eqn
 
 
-def test_forward_backward_makes_delta_and_rounds_dq_once_in_xla(programs):
+def test_forward_backward_makes_delta_once_and_nothing_of_dq_in_xla(programs):
     """ISSUE 43: the backward is one k-major kernel. A q block has no first
     step on that walk, so delta is made before the kernel, once, in the
-    lane-replicated form the kernel reads; dq leaves the kernel as the
-    float32 buffer it was summed in and is rounded once. Nothing else is
-    reformatted in XLA: dk and dv leave in the inputs' dtype, the lse
-    cotangent folds into delta on 4 bytes a row."""
+    lane-replicated form the kernel reads. ISSUE 44: dq leaves the kernel
+    in the inputs' dtype, written by each q block's last visit; the
+    float32 buffer it is summed in is an output nobody reads, aliased to
+    an operand nobody has written (``lax.empty``: no fill), the result has
+    no fill behind it (this mask names every q block), and no tensor-sized
+    rounding is left in XLA. dk and dv leave in the
+    inputs' dtype, the lse cotangent folds into delta on 4 bytes a row."""
     _, fwdbwd = programs
     names = [e.params["name"] for e in _kernels(fwdbwd)]
     assert sorted(names) == ["magi_flex_bwd_kernel", "magi_flex_fwd_kernel"]
     (bwd,) = [e for e in _kernels(fwdbwd) if e.params["name"].endswith("bwd_kernel")]
-    dk, dv, dq = (v.aval for v in bwd.outvars)
+    dk, dv, dq, acc = (v.aval for v in bwd.outvars)
     # (whole vregs of lanes a tile: head_dim 64 is padded to 128 and cut)
-    assert dq.dtype == jnp.float32 and dq.shape[0] == HQ
+    assert dq.shape == acc.shape and dq.shape[0] == HQ
     assert dq.shape[2] == -(-D // LANES) * LANES
-    assert dk.dtype == dv.dtype == jnp.bfloat16
+    assert dk.dtype == dv.dtype == dq.dtype == jnp.bfloat16
+    assert acc.dtype == jnp.float32
+    # the float32 sums' buffer comes in as an operand nobody has written
+    # (13, aliased to the fourth output; PERF.md section 6, PR 44, says why
+    # it is an operand at all); the result has no fill behind it: this
+    # mask names every q block
+    assert dict(bwd.params["input_output_aliases"]) == {13: 3}
+    assert len(bwd.invars) == 7 + 6 + 1  # tables; q, k, v, dO, lse, delta
+    (made,) = [
+        e for e in _outside_kernels(fwdbwd) if bwd.invars[13] in e.outvars
+    ]
+    assert made.primitive.name == "empty"  # no pass on the chip
+    used = {id(v) for e in _outside_kernels(fwdbwd) for v in e.invars}
+    assert id(bwd.outvars[3]) not in used  # scratch: dropped where it leaves
     grad_sized = HK * TOTAL * D  # dk and dv; dq is larger
     to_lanes, rounded = [], []
     for eqn in _outside_kernels(fwdbwd):
         if eqn.primitive.name == "broadcast_in_dim":
             # (a scalar's fill is no operand's reformat: the zeros jax
-            # makes for the residuals' places and for dq's buffer)
+            # makes for the residuals' places; the kernel takes none)
             (out,) = eqn.outvars
             if (
                 eqn.invars[0].aval.ndim
@@ -117,7 +134,7 @@ def test_forward_backward_makes_delta_and_rounds_dq_once_in_xla(programs):
             ):
                 rounded.append(out.aval.shape)
     assert to_lanes == [(HQ, dq.shape[1], LANES)]  # delta
-    assert rounded == [(*dq.shape[:2], D)]
+    assert rounded == []
 
     # the lane-replicated arrays are lse, the forward's residual, and delta
     def makes(eqn):  # not hands on: a shard_map, the custom_vjp's call
@@ -132,6 +149,7 @@ def test_forward_backward_makes_delta_and_rounds_dq_once_in_xla(programs):
         for e in _outside_kernels(fwdbwd)
         for v in e.outvars
         if v.aval.shape[-1:] == (LANES,) and v.aval.ndim == 3 and makes(e)
-        and v is not bwd.outvars[2]  # dq's padded lanes are no statistic
+        # dq's padded lanes are no statistic, nor is its sums' buffer
+        and v not in (*bwd.outvars[2:], bwd.invars[13])
     ]
     assert sorted(lanes) == ["broadcast_in_dim", "pallas_call"]

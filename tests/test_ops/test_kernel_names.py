@@ -157,6 +157,7 @@ def test_build_counter_carries_heads_per_step_and_grid(
     assert _builds() == {
         f"{name}{{grid={grid},heads_per_step={head_block},kernel=fwd,"
         "stats=compact}": 1,
-        f"{name}{{delta=xla,grid={grid},heads_per_step={bwd_heads},"
-        "kernel=bwd}": 1,
+        # (ISSUE 44: the dense causal table names every q block)
+        f"{name}{{delta=xla,dq=visits,grid={grid},"
+        f"heads_per_step={bwd_heads},kernel=bwd}}": 1,
     }

@@ -364,9 +364,15 @@ def _fingerprint(cell_name):
             q, k, t, total, total, block_q=rung[0], block_k=rung[1]
         )
         h = hashlib.sha256()
+        # ISSUE 44 put each q block's first / last visit into bits 1 and 2
+        # of the k-major table's flag word: without them the tables are the
+        # parent's to the byte (bit 0, "needs mask", is what it was)
+        bwd_runs = meta.bwd_runs.reshape(-1, bm.RUN_FIELDS).copy()
+        assert (bwd_runs[:, 6] >> 3 == 0).all()
+        bwd_runs[:, 6] &= bm.NEEDS_MASK
         for a in (meta.fwd_q_block, meta.fwd_k_block, meta.fwd_slice_id,
                   meta.fwd_runs, meta.bwd_k_block, meta.bwd_q_block,
-                  meta.bwd_slice_id, meta.bwd_runs, meta.slice_bounds):
+                  meta.bwd_slice_id, bwd_runs.reshape(-1), meta.slice_bounds):
             h.update(np.ascontiguousarray(a).tobytes())
             h.update(b"|")
         out[f"{cell_name}/{kind}"] = {
@@ -423,8 +429,9 @@ def test_step_one_traces_the_parents_kernels(program):
     character for character, so at step 1 the chip's compiler is handed
     what it was handed before steps existed. ``fwd``: the forward, still
     the program of the commit before steps (fadb98e). ``fwd_bwd``: with
-    the backward, whose golden is ISSUE 43's tree: that PR made dq and dkv
-    one kernel, so the older text cannot come back."""
+    the backward, whose golden is ISSUE 44's tree: ISSUE 43 made dq and dkv
+    one kernel and ISSUE 44 changed that kernel's dq protocol (and took two
+    XLA passes from round it), so the older texts cannot come back."""
     texts = _step_one_programs(program == "fwd_bwd")
     assert sum(map(len, texts)) == KERNELS[program]["chars"]
     assert hashlib.sha256("\n".join(texts).encode()).hexdigest() == (

@@ -51,6 +51,25 @@ def test_the_ranking_prices_what_it_was_calibrated_on():
     assert set(cost_model.RANKED_KERNELS) < set(cost_model.KERNEL_FLOP_WEIGHTS)
 
 
+# visits a q tile gets in the mean on each plan's k-major tables (entries,
+# pads and dummies with them, over the q blocks they name): each moves the
+# tile's float32 sums once; the first reads nothing, the last writes dq
+DQ_VISITS = {
+    "magi64x8-attn-64k-varlen": [7.109],
+    "magi64x8-attn-64k-causal": [32.625],
+    "mistral7b-train-16k-onemask": [3.125],
+    "magi64x8-attn-cp4-256k-varlen": [12.75],
+    "trinitymini-train-32k-packed": [4.406, 3.344],
+    "magi64x8-attn-64k-swa1024": [2.984],
+    "glm47flash-train-16k-packed": [3.375],
+    "magi64x8-attn-64k-chunkcausal": [34.125],
+    "magi64x8-attn-cp4-256k-causal": [64.625],
+    "ouro26b-train-16k-looped": [3.375],
+    "zaya1-train-16k-traces": [6.0],
+    "sdar30b-train-16k-blockdiff": [5.875],
+}
+
+
 def _cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return [w["name"] for w in json.load(f)["workloads"]]
@@ -91,6 +110,14 @@ def test_every_cells_plan_takes_the_fused_backward(telemetry_on, cell, monkeypat
     spec = TPU_PEAK_SPECS["v5e"]
     for args in got:
         assert args["bwd_form"] == BWD_FORM
+        # ISSUE 44: what dq's protocol meets on the plan's k-major tables.
+        # Every row of every cell's masks has a key (and both cp=4 cells
+        # run the merged path), so no rank's table leaves a q block out
+        # and no cell's backward fills anything: dq=visits everywhere
+        assert args["dq_unnamed_q_blocks"] == 0
+        assert args["dq_visits_per_tile"] == pytest.approx(
+            DQ_VISITS[cell][got.index(args)], abs=5e-4
+        )
         bq, bk, hb = args["rung"]
         group = hq // hk
         tile_s = 4 * hb * bq * bk * d / (spec.bf16_tflops * 1e12)
@@ -103,6 +130,12 @@ def test_every_cells_plan_takes_the_fused_backward(telemetry_on, cell, monkeypat
             )
 
         assert step_s("bwd") < 0.85 * (step_s("dq") + step_s("dkv")), args
+    if cell == "sdar30b-train-16k-blockdiff":
+        # the share's one reader of the flag word takes bit 0 alone: the
+        # k-major word's visit bits left the cell's reading where it was
+        assert telemetry.snapshot()["gauges"][
+            "magi_flex_stepped_tile_share"
+        ] == pytest.approx(25.806, abs=5e-4)
 
 
 def test_the_dense_cells_plan_reads_fused(telemetry_on):
