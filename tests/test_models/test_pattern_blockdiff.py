@@ -19,7 +19,7 @@ from magiattention_tpu.models.pattern import (
     sdar_moe_config,
 )
 from magiattention_tpu.parallel import dispatch
-from tests.test_models.test_pattern import _mesh, _worst
+from tests.test_models.pattern_harness import _mesh, _worst
 
 # the published widths in ratio: 8 query heads on 2 key-value heads of 16,
 # 16 experts top-4, eight of them here, blocks of 4

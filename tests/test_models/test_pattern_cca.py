@@ -23,8 +23,9 @@ from magiattention_tpu.models.pattern import (
 )
 from magiattention_tpu.parallel import dispatch, undispatch
 from magiattention_tpu.utils.compat import shard_map
-from tests.test_models.test_pattern import (
+from tests.test_models.pattern_harness import (
     CHUNK, CU, DOCS, TOTAL, _allow_full, _mesh, _model_loss_and_grads, _worst,
+    computed_once,
 )
 
 # the published widths in ratio: 8 query heads on 2 key-value heads, both
@@ -50,6 +51,7 @@ def _zaya(dtype="float32", **keys):
     )
 
 
+@computed_once
 def _reference(hf, params, tokens_g, **kw):
     toks = jnp.asarray(tokens_g, jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -93,7 +95,7 @@ def test_loss_and_every_gradient_match_the_reference(params, cp):
     try:
         with jax.enable_x64(False):
             loss, grads, tokens_g, model, meta = _model_loss_and_grads(
-                cfg, cp, params
+                cfg, cp, params  # run here: the counter counts this build
             )
             want, want_grads = _reference(hf, params, tokens_g)
         crossed = reg.counter_value("magi_shift_remote_rows_total")

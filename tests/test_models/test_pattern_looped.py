@@ -20,10 +20,12 @@ from magiattention_tpu.models.pattern import (
     init_pattern_params, ouro_config,
 )
 from tests.test_benchmarks import looped_faults
-from tests.test_models.test_pattern import (
-    CFG, CHUNK, CU, TOTAL, _allow_full, _census, _glm, _mesh,
-    _model_loss_and_grads, _pin, _worst,
+from tests.test_models.pattern_harness import (
+    CHUNK, CU, TOTAL, _allow_full, _census, _mesh, _model_loss_and_grads,
+    _pin, _worst, computed_once, unfaulted_loss_and_grads,
 )
+from tests.test_models.test_pattern import CFG
+from tests.test_models.test_pattern_latent import _glm
 
 # the published widths in ratio: 4 query = 4 key-value heads, a SwiGLU of
 # 2.75 x hidden, 4 passes through 2 layers
@@ -40,6 +42,7 @@ def _ouro(dtype="float32", **keys):
     return hf, ouro_config(hf, dtype=dtype, remat=True)
 
 
+@computed_once
 def _reference(hf, params, tokens_g, **kw):
     toks = jnp.asarray(tokens_g, jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -64,7 +67,7 @@ def test_looped_loss_and_every_gradient_match_the_reference(params, cp):
     hf, cfg = _ouro()
     assert set(params["exit_gate"]) == {"w", "b"}
     with jax.enable_x64(False):
-        loss, grads, tokens_g, _model, _meta = _model_loss_and_grads(
+        loss, grads, tokens_g, _model, _meta = unfaulted_loss_and_grads(
             cfg, cp, params
         )
         want, want_grads = _reference(hf, params, tokens_g)
@@ -93,7 +96,7 @@ def test_the_scanned_pass_is_the_unrolled_loop(params, remat, monkeypatch):
     _hf, cfg = _ouro()
     cfg = dataclasses.replace(cfg, remat=remat)
     with jax.enable_x64(False):
-        loss, grads, *_ = _model_loss_and_grads(cfg, 2, params)
+        loss, grads, *_ = unfaulted_loss_and_grads(cfg, 2, params)
         monkeypatch.setattr(
             pattern, "_looped_trunk_local", looped_faults.unrolled_trunk
         )

@@ -25,9 +25,11 @@ from magiattention_tpu.models import LlamaConfig, build_magi_llama, init_params
 from magiattention_tpu.models.pattern import (
     build_magi_pattern, init_pattern_params,
 )
-from tests.test_models import test_pattern as toy
+from tests.test_models import pattern_harness as toy
+from tests.test_models.test_pattern import CFG as AFMOE
 from tests.test_models import test_pattern_blockdiff as blockdiff
 from tests.test_models.test_pattern_cca import _zaya
+from tests.test_models.test_pattern_latent import _glm
 from tests.test_models.test_pattern_looped import _ouro
 
 METRICS = os.path.join(
@@ -133,7 +135,7 @@ def _step_text(name: str) -> str:
         params = init_params(jax.random.PRNGKey(0), cfg)
     else:
         cfg = {
-            "afmoe": toy.CFG, "latent+mtp": toy._glm(1)[1],
+            "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
             "looped": _ouro()[1], "cca": _zaya()[1],
         }[name]
         model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)

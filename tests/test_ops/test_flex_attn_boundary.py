@@ -3,10 +3,11 @@
 A side operand crosses once, in the form its consumer wants: lse and the row
 maximum leave the forward with rows along lanes, dk / dv leave in the
 inputs' dtype. Since ISSUE 43 the backward is one k-major kernel: dq leaves
-it as the float32 buffer it was summed in and is rounded once, delta is made
-before the kernel (a q block has no first step on that walk). Each against
-the form it replaced, which stays here as the reference. (A file of its own: the driver hands a
-test file to one worker, and ``test_flex_attn.py`` is the longest already.)
+it in the inputs' dtype too (ISSUE 44), delta is made before the kernel (a q
+block has no first step on that walk). Each against the form it replaced,
+which stays here as the reference. Cases come from ``kernel_cases.run`` (mask
+``edge``), which records what the backward kernel was handed; the side of a
+comparison that patches the kernel module is traced here.
 """
 
 import jax
@@ -14,58 +15,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from magiattention_tpu.common import AttnMaskType
-from magiattention_tpu.ops import build_block_meta
 from magiattention_tpu.testing import assert_close
 
-F = AttnMaskType.FULL
-C = AttnMaskType.CAUSAL
+from .kernel_cases import (
+    KernelCase, launch_args, operands, run, trace, uncovered_rows,
+)
 
-
-def _rand_qkv(tq, tk, hq, hk, d, seed):
-    rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.standard_normal((tq, hq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((tk, hk, d)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((tk, hk, d)), jnp.float32)
-    return q, k, v
-
-
-#   rows   0..150  full against k [0, 300);
-#   rows 150..260  causal against k [100, 512);
-#   rows 260..300  no slice at all, in a q block that has entries;
-#   rows 300..500  full against k [0, 512);
-#   rows 500..768  nothing: the tail of a block, then blocks with no entry.
-_EDGE_T, _EDGE_TK = 768, 512
-_EDGE_MASK = ([(0, 150), (150, 260), (300, 500)],
-              [(0, 300), (100, 512), (0, 512)], [F, C, F])
-_EDGE_UNCOVERED = np.r_[260:300, 500:768]
+_EDGE_T = 768
+_EDGE_UNCOVERED = uncovered_rows("edge")
+assert (_EDGE_UNCOVERED == np.r_[260:300, 500:768]).all()
 # (hq, hk, head_block): per head; 5 heads a step at group 1 (the latent
 # form's snap); 8 heads a step, two kv heads' groups of 4
 _EDGE_HEADS = [(4, 2, 1), (5, 5, 5), (8, 2, 8)]
 _EDGE_IDS = ["per-head", "hb=5", "hb=8"]
 
 
-def _edge_operands(hq, hk, head_block, grid, block_q, with_sink, dtype, d=32):
-    from magiattention_tpu.ops import flex_attn as fa
-
-    qr, kr, ts = _EDGE_MASK
-    q, k, v = _rand_qkv(_EDGE_T, _EDGE_TK, hq, hk, d, seed=41)
-    meta = build_block_meta(
-        qr, kr, [t.value for t in ts], _EDGE_T, _EDGE_TK,
-        block_q=block_q, block_k=128,
+def _edge(hq, hk, head_block, grid, block_q, with_sink=True, **more):
+    return KernelCase(
+        "edge", hq=hq, hk=hk, d=32, block_q=block_q, block_k=128,
+        head_block=head_block, grid=grid, sink=with_sink, **more,
     )
-    params = fa.FlexAttnParams(
-        block_q=block_q, block_k=128, scale=d**-0.5, softcap=0.0,
-        has_sink=with_sink, out_dtype=str(jnp.dtype(dtype)), interpret=True,
-        head_block=head_block, fwd_steps=meta.fwd_steps,
-        bwd_steps=meta.bwd_steps, grid=grid,
-    )
-    rng = np.random.default_rng(43)
-    sink = jnp.asarray(rng.standard_normal(hq), jnp.float32)
-    qh, kh, vh = (
-        jnp.transpose(x, (1, 0, 2)).astype(dtype) for x in (q, k, v)
-    )
-    return qh, kh, vh, sink, fa.fwd_tables(meta), fa.bwd_tables(meta), params
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -82,21 +51,26 @@ def test_compact_stats_are_lane_0_of_the_replicated_ones(
     entry covers; the undifferentiated forward writes no residual."""
     from magiattention_tpu.ops import flex_attn as fa
 
-    qh, kh, vh, sink, ftab, _, params = _edge_operands(
-        hq, hk, head_block, grid, block_q, with_sink, jnp.float32
-    )
+    case = _edge(hq, hk, head_block, grid, block_q, with_sink, watch=True)
+    q, k, v, sink, ftab, _btab, params = launch_args(case)
     sink2d = sink.reshape(hq, 1)
     assert fa.stats_form(block_q) == "compact"
-    got = fa._fwd_pallas(qh, kh, vh, sink2d, ftab, params, residual=True)
-    assert fa._fwd_pallas(qh, kh, vh, sink2d, ftab, params)[3] is None
+    got, _, seen = run(case)  # the differentiated forward: with its residual
+    out, lse, rowmax, lse_lanes = (
+        got["out"], got["lse"], got["rowmax"], seen["lse_lanes"]
+    )
+    assert jax.eval_shape(
+        lambda: fa._fwd_pallas(q, k, v, sink2d, ftab, params)
+    )[3] is None
     monkeypatch.setattr(fa, "stats_form", lambda block_q: "lanes")
-    old = fa._fwd_pallas(qh, kh, vh, sink2d, ftab, params)
-    out, lse, rowmax, lse_lanes = (np.asarray(x) for x in got)
+    old = fa._fwd_pallas(q, k, v, sink2d, ftab, params)
     assert lse.shape == rowmax.shape == (hq, _EDGE_T)
     assert lse_lanes.shape == (hq, _EDGE_T, fa.LANES)
     np.testing.assert_array_equal(lse, lse_lanes[:, :, 0])
-    for a, b, nm in zip(got, old, ["out", "lse", "rowmax", "lse_lanes"]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=nm)
+    for a, b, nm in zip(
+        (out, lse, rowmax, lse_lanes), old, ["out", "lse", "rowmax", "lse_lanes"]
+    ):
+        np.testing.assert_array_equal(a, np.asarray(b), err_msg=nm)
     un = _EDGE_UNCOVERED
     assert np.isneginf(rowmax[:, un]).all()
     covered = np.setdiff1d(np.arange(_EDGE_T), un)
@@ -108,28 +82,6 @@ def test_compact_stats_are_lane_0_of_the_replicated_ones(
         )
     else:
         assert np.isneginf(lse[:, un]).all()
-
-
-def _edge_grads(operands, use_lse, dtype):
-    """(dq, dk, dv, dsink) of a loss on out (and, ``use_lse``, on lse)."""
-    from magiattention_tpu.ops import flex_attn as fa
-
-    qh, kh, vh, sink, ftab, btab, params = operands
-    rng = np.random.default_rng(47)
-    do = jnp.asarray(rng.standard_normal(qh.shape), dtype)
-    w = jnp.asarray(rng.standard_normal(qh.shape[:2]), jnp.float32)
-
-    def loss(q, k, v, sink):
-        out, lse, _ = fa.flex_attn_headmajor(
-            q, k, v, ftab, btab, params,
-            sink=sink if params.has_sink else None,
-        )
-        res = (out.astype(jnp.float32) * do.astype(jnp.float32)).sum()
-        if use_lse:
-            res += (jnp.where(jnp.isneginf(lse), 0.0, lse) * w).sum()
-        return res
-
-    return jax.grad(loss, argnums=(0, 1, 2, 3))(qh, kh, vh, sink), do, w
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -151,10 +103,8 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
 
     from magiattention_tpu.ops import flex_attn as fa
 
-    operands = _edge_operands(
-        hq, hk, head_block, grid, block_q, True, jnp.bfloat16
-    )
-    got, _, _ = _edge_grads(operands, True, jnp.bfloat16)
+    case = _edge(hq, hk, head_block, grid, block_q, dtype="bfloat16")
+    got = run(case).got
     build = fa._flex_pallas_call
     widened = []
 
@@ -198,15 +148,16 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
         ]
 
     monkeypatch.setattr(fa, "_flex_pallas_call", float32_then_cast)
-    old, _, _ = _edge_grads(operands, True, jnp.bfloat16)
+    old, _ = trace(case)
     assert widened == ["bwd"]
-    for a, b, nm in zip(got[:3], old[:3], ["dq", "dk", "dv"]):
-        assert a.dtype == jnp.bfloat16, nm
-        assert np.isfinite(np.asarray(a, np.float32)).all(), nm
+    for nm in ("dq", "dk", "dv"):
+        assert got[nm].dtype == jnp.bfloat16, nm
+        assert np.isfinite(np.asarray(got[nm], np.float32)).all(), nm
         np.testing.assert_array_equal(
-            np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=nm
+            np.asarray(got[nm], np.float32), np.asarray(old[nm], np.float32),
+            err_msg=nm,
         )
-    assert not np.asarray(got[0], np.float32)[:, _EDGE_UNCOVERED].any()
+    assert not np.asarray(got["dq"], np.float32)[:, _EDGE_UNCOVERED].any()
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -214,7 +165,7 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
 @pytest.mark.parametrize("hq,hk,head_block", _EDGE_HEADS, ids=_EDGE_IDS)
 @pytest.mark.parametrize("use_lse", [False, True], ids=["zero-dlse", "dlse"])
 def test_delta_is_made_before_the_kernel(
-    use_lse, hq, hk, head_block, block_q, grid, monkeypatch
+    use_lse, hq, hk, head_block, block_q, grid
 ):
     """``delta = sum(dO * out) - dlse`` is made once, before the one
     backward kernel, and handed to it replicated over lanes: with an lse
@@ -222,41 +173,27 @@ def test_delta_is_made_before_the_kernel(
     that is not; dq, dk, dv, dsink against the jnp backend."""
     from magiattention_tpu.ops import flex_attn as fa
 
-    operands = _edge_operands(
-        hq, hk, head_block, grid, block_q, True, jnp.float32
+    case = _edge(
+        hq, hk, head_block, grid, block_q, use_lse=use_lse, watch=True
     )
-    seen = {}
-    bwd_delta = fa._bwd_delta
-
-    def spy(do, out, dlse):
-        res = bwd_delta(do, out, dlse)
-        seen.update(do=do, out=out, dlse=dlse, rows=np.asarray(res[0]),
-                    delta=np.asarray(res[1]))
-        return res
-
-    monkeypatch.setattr(fa, "_bwd_delta", spy)
-    got, do, w = _edge_grads(operands, use_lse, jnp.float32)
+    got, ref, seen = run(case)
+    x = operands(case)
     delta = seen["delta"]
     assert delta.shape == (hq, _EDGE_T, fa.LANES) and delta.dtype == np.float32
     np.testing.assert_array_equal(
         delta, np.broadcast_to(delta[..., :1], delta.shape)
     )
-    np.testing.assert_array_equal(delta[..., 0], seen["rows"])
-    want = np.asarray(jnp.sum(seen["do"] * seen["out"], axis=-1))
+    np.testing.assert_array_equal(delta[..., 0], seen["delta_rows"])
+    want = np.sum(x["do"] * got["out"], axis=-1)
     if use_lse:
-        np.testing.assert_array_equal(np.asarray(seen["dlse"]), np.asarray(w))
-        want = want - np.asarray(w)
+        np.testing.assert_array_equal(seen["dlse"], x["w"])
+        want = want - x["w"]
     else:
         assert seen["dlse"] is None
     assert_close(delta[..., 0], want, atol=1e-5, rtol=1e-5, msg="delta")
     if use_lse:  # rows with out = 0: delta is the cotangent alone, exactly
-        np.testing.assert_array_equal(
-            delta[:, 500:, 0], -np.asarray(w)[:, 500:]
-        )
-
-    monkeypatch.setenv("MAGI_ATTENTION_KERNEL_BACKEND", "jnp")
-    ref, _, _ = _edge_grads(operands, use_lse, jnp.float32)
-    for a, b, nm in zip(got, ref, ["dq", "dk", "dv", "dsink"]):
-        assert np.isfinite(np.asarray(a)).all(), nm
-        assert_close(a, b, atol=5e-5, rtol=5e-5, msg=nm)
-    assert not np.asarray(got[0])[:, _EDGE_UNCOVERED].any()
+        np.testing.assert_array_equal(delta[:, 500:, 0], -x["w"][:, 500:])
+    for nm in ("dq", "dk", "dv", "dsink"):
+        assert np.isfinite(got[nm]).all(), nm
+        assert_close(got[nm], ref[nm], atol=5e-5, rtol=5e-5, msg=nm)
+    assert not got["dq"][:, _EDGE_UNCOVERED].any()

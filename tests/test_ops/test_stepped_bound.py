@@ -243,17 +243,6 @@ def test_the_kernels_two_masks_agree(s, base, r):
     assert (got == want).all()
 
 
-def _attn_case(s):
-    """One mask of the three bounded types at step ``s`` and a FULL
-    slice, over ragged ranges that share q rows and k columns."""
-    c, i, b = (T(x).with_step(s) for x in (1, 2, 3))
-    return (
-        [(0, 40), (40, 83), (83, 126), (5, 33)],
-        [(3, 61), (10, 115), (70, 128), (64, 100)],
-        [c, i, b, T.FULL],
-    )
-
-
 @pytest.mark.parametrize("backend,grid,head_block", [
     ("pallas", "row_major", 1), ("pallas", "sparse", 2),
     ("pallas", "row_major", 4), ("jnp", "row_major", 1),
@@ -263,17 +252,59 @@ def _attn_case(s):
 def test_kernels_against_the_dense_softmax(s, backend, grid, head_block,
                                            monkeypatch):
     """out / lse / dq / dk / dv of the Pallas kernels (interpret, both
-    grids, heads batched or not) and of both jnp backends."""
-    from magiattention_tpu.ops import flex_flash_attn_func
+    grids, heads batched or not) and of both jnp backends, on one mask of
+    the three bounded types at step ``s`` and a FULL slice
+    (``kernel_cases.MASKS``). The Pallas kernels against the dense jnp
+    backend on the same tables (``kernel_cases.run``), and that backend,
+    as the online one, against the dense softmax over the ranges: the
+    chain has no link that is not compared. The Pallas forward alone (the
+    build without the backward's residual) against the differentiated one
+    and against the dense softmax directly."""
     from magiattention_tpu.testing import ref_attn_from_ranges
 
-    monkeypatch.setenv("MAGI_ATTENTION_KERNEL_BACKEND", backend)
-    rng = np.random.default_rng(s)
-    t = 128
-    q = jnp.asarray(rng.standard_normal((t, 4, 16)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((t, 2, 16)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((t, 2, 16)), jnp.float32)
-    for name, (qr, kr, ts) in {"mixed": _attn_case(s)}.items():
+    from .kernel_cases import (
+        MASKS, KernelCase, forward_alone, operands, run,
+    )
+
+    mask = f"stepped_mixed_s{s}"
+    if backend == "pallas":
+        case = KernelCase(
+            mask, hq=4, hk=2, d=16, block_q=32, block_k=32, grid=grid,
+            head_block=head_block, sink=False, seed=s,
+        )
+        got, want, _ = run(case)
+        # the forward nobody differentiates (no residual is built) is the
+        # differentiated one bit for bit, and the dense softmax over the
+        # ranges, which reads no table
+        alone, ops = forward_alone(case), operands(case)
+        for nm in ("out", "lse"):
+            np.testing.assert_array_equal(alone[nm], got[nm], err_msg=nm)
+        _, _tk, qr, kr, ts = MASKS[mask]
+        dense_out, dense_lse, _ = ref_attn_from_ranges(
+            *(jnp.transpose(ops[n], (1, 0, 2)) for n in "qkv"), qr, kr, ts
+        )
+        dense_out, dense_lse = jnp.transpose(dense_out, (1, 0, 2)), dense_lse.T
+        dense_live = ~np.isneginf(np.asarray(dense_lse))
+        np.testing.assert_allclose(alone["out"], dense_out, atol=3e-5, rtol=3e-5)
+        assert (np.isneginf(alone["lse"]) == ~dense_live).all(), mask
+        np.testing.assert_allclose(
+            alone["lse"][dense_live], np.asarray(dense_lse)[dense_live],
+            atol=3e-5, rtol=3e-5,
+        )
+        (o, lse), (ro, rlse) = (
+            [x[n] for n in ("out", "lse")] for x in (got, want)
+        )
+        grads = [[x[n] for n in ("dq", "dk", "dv")] for x in (got, want)]
+    else:
+        from magiattention_tpu.ops import flex_flash_attn_func
+
+        monkeypatch.setenv("MAGI_ATTENTION_KERNEL_BACKEND", backend)
+        rng = np.random.default_rng(s)
+        t, _tk, qr, kr, ts = MASKS[mask]
+        q = jnp.asarray(rng.standard_normal((t, 4, 16)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((t, 2, 16)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((t, 2, 16)), jnp.float32)
+
         def ours(q, k, v):
             return flex_flash_attn_func(
                 q, k, v, qr, kr, ts, block_q=32, block_k=32, grid=grid,
@@ -291,18 +322,20 @@ def test_kernels_against_the_dense_softmax(s, backend, grid, head_block,
             return f
 
         (o, lse), (ro, rlse) = ours(q, k, v), dense(q, k, v)
-        np.testing.assert_allclose(o, ro, atol=3e-5, rtol=3e-5, err_msg=name)
-        live = ~np.isneginf(np.asarray(rlse))
-        assert (np.isneginf(np.asarray(lse)) == ~live).all(), name
+        grads = [
+            jax.grad(loss(fn), argnums=(0, 1, 2))(q, k, v)
+            for fn in (ours, dense)
+        ]
+    np.testing.assert_allclose(o, ro, atol=3e-5, rtol=3e-5, err_msg=mask)
+    live = ~np.isneginf(np.asarray(rlse))
+    assert (np.isneginf(np.asarray(lse)) == ~live).all(), mask
+    np.testing.assert_allclose(
+        np.asarray(lse)[live], np.asarray(rlse)[live], atol=3e-5, rtol=3e-5
+    )
+    for a, b, which in zip(*grads, "qkv"):
         np.testing.assert_allclose(
-            np.asarray(lse)[live], np.asarray(rlse)[live], atol=3e-5, rtol=3e-5
+            a, b, atol=2e-4, rtol=2e-4, err_msg=f"{mask} d{which}"
         )
-        got = jax.grad(loss(ours), argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
-        for a, b, which in zip(got, want, "qkv"):
-            np.testing.assert_allclose(
-                a, b, atol=2e-4, rtol=2e-4, err_msg=f"{name} d{which}"
-            )
 
 
 def test_overlapping_stepped_slices_are_refused():
