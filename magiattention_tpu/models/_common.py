@@ -133,6 +133,9 @@ def _plan_on_dispatch(
         attn_params = make_attn_params(
             plan,
             cfg.head_dim,
+            # a model whose kernel heads are wider than its own
+            # (pattern.KernelHeads) keeps its own softmax scale
+            scale=getattr(cfg, "softmax_scale", None),
             out_dtype=cfg.dtype,
             interpret=interpret,
             head_block=hb,

@@ -64,6 +64,23 @@ the caller's per-row weights (1/t), over the sequence's data tokens
 ``router_form``: one matrix, a softmax over all experts, top-k,
 renormalised.
 
+A decoder-hybrid-decoder (SambaY; Phi-4-mini-flash-reasoning /
+``phi4flash_config``) brings three layer kinds that are not attention
+over their own q, k, v, beside ``sliding_attention`` and
+``full_attention``: ``state_space`` (the Mamba-1 mixer of
+``models/ssm.py``), ``gated_memory`` (a unit that gates the LAST mixer's
+scan output, the memory ``m``) and ``cross_attention`` (a query alone, on
+the keys and values of the last ``full_attention`` layer before it). What
+a layer hands on, ``m``, ``(k, v)`` and the MLP router's state, is one
+carry beside ``x`` (:func:`_layer_local`): a layer's ``checkpoint`` takes
+it as an input and gives it as an output, so it is kept, never
+recomputed, and its cotangent is the sum over its readers. Its attention
+form is ``diff`` (the Differential Transformer's, arXiv:2410.05258):
+adjacent heads pair, two softmaxes on one value pair, their difference
+under a learned ``lambda`` through a norm of its own
+(:func:`_diff_heads`, :func:`_diff_combine`); LayerNorm with a bias in
+place of the RMS norm (``norm_form``), no position encoding.
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -90,11 +107,15 @@ from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
 from ._common import masked_ce_sums, masked_ce_tokens
+from . import ssm
 from .llama import _rms_norm, _rope
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# the kinds that are not attention over their own q, k, v
+SSM, GMU, CROSS = "state_space", "gated_memory", "cross_attention"
 DENSE, EXPERTS = "dense", "experts"
-GQA, LATENT, CCA = "gqa", "latent", "cca"
+GQA, LATENT, CCA, DIFF = "gqa", "latent", "cca", "diff"
+RMS, LAYER = "rms", "layer"  # the norm's forms
 SIGMOID, MLP, SOFTMAX = "sigmoid", "mlp", "softmax"  # the router's forms
 _SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
 
@@ -106,7 +127,8 @@ class PatternConfig:
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    layer_types: tuple[str, ...]  # SLIDING | FULL, a layer
+    # SLIDING | FULL, a layer; under DIFF also SSM | GMU | CROSS
+    layer_types: tuple[str, ...]
     ffn_types: tuple[str, ...]  # DENSE | EXPERTS, a layer
     ffn_hidden: int  # the dense FFN's width
     # keys a SLIDING layer's query sees inside its document, itself
@@ -179,16 +201,32 @@ class PatternConfig:
     # token training): the model is fed [noisy ; clean], twice the
     # documents' rows, under ``api.infer_block_diffusion_mask``
     diffusion_block: int = 0
+    # RMS, or LAYER: LayerNorm with a weight and a bias (``<name>_b``)
+    norm_form: str = RMS
+    attn_bias: bool = False  # a bias on q, k, v and the output projection
+    # the Mamba-1 mixer of an SSM layer and the width of the memory a GMU
+    # gates: channels, states a channel, the convolution's taps, the rank
+    # of the step's projection
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_dt_rank: int = 0
+    scan_state_dtype: str = "float32"  # bfloat16: the benchmark's control
+    # a layer's index in the published model where the layers are a cut
+    # of it (DIFF's lambda_init reads it); (): the layer's own place
+    layer_index: tuple[int, ...] = ()
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
             raise ValueError("layer_types and ffn_types differ in length")
-        bad = set(self.layer_types) - {SLIDING, FULL}
+        bad = set(self.layer_types) - {SLIDING, FULL, SSM, GMU, CROSS}
         bad |= set(self.ffn_types) - {DENSE, EXPERTS}
-        bad |= {self.attn_form} - {GQA, LATENT, CCA}
+        bad |= {self.attn_form} - {GQA, LATENT, CCA, DIFF}
         bad |= {self.router_form} - {SIGMOID, MLP, SOFTMAX}
+        bad |= {self.norm_form} - {RMS, LAYER}
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
+        self._check_handed_on()
         if self.attn_form == LATENT and not (
             self.n_kv_heads == self.n_heads
             and 0 < self.rope_head_dim < self.head_dim
@@ -241,9 +279,81 @@ class PatternConfig:
                 "reference states one"
             )
 
+    def _check_handed_on(self):
+        kinds = self.layer_types
+        if self.attn_form != DIFF:
+            if set(kinds) & {SSM, GMU, CROSS}:
+                raise ValueError(
+                    "state-space, gated-memory and cross layers go with "
+                    "attn_form 'diff': no reference states another"
+                )
+            return
+        if self.layer_index and len(self.layer_index) != len(kinds):
+            raise ValueError("layer_index and layer_types differ in length")
+        if (
+            self.n_heads % self.n_kv_heads or self.n_kv_heads % 2
+            or self.qk_norm or self.attn_gate or self.post_norms
+            or self.rope_kinds or self.n_mtp or self.n_loops > 1
+            or self.diffusion_block or EXPERTS in self.ffn_types
+        ):
+            raise ValueError(
+                "differential attention pairs adjacent heads (an even "
+                "number of key-value heads, whole query groups) and is "
+                "stated without qk-norm, gate, post-norms, rotary, MTP, "
+                "loops, diffusion or experts"
+            )
+        for i, kind in enumerate(kinds):
+            if kind == CROSS and FULL not in kinds[:i]:
+                raise ValueError(
+                    f"layer {i} is cross attention with no full_attention "
+                    "layer before it to make its keys and values"
+                )
+            if kind == GMU and SSM not in kinds[:i]:
+                raise ValueError(
+                    f"layer {i} is a gated memory unit with no state-space "
+                    "layer before it to make its memory"
+                )
+        if SSM in kinds and min(
+            self.ssm_inner, self.ssm_state, self.ssm_conv, self.ssm_dt_rank
+        ) < 1:
+            raise ValueError("a state-space layer needs the mixer's four sizes")
+        if GMU in kinds and self.ssm_inner < 1:
+            raise ValueError("a gated memory unit needs ssm_inner")
+
     @property
     def n_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def memory_layer(self) -> int | None:
+        """The layer whose scan output the gated memory units read: the
+        last state-space layer before the first of them."""
+        if GMU not in self.layer_types:
+            return None
+        first = self.layer_types.index(GMU)
+        return max(i for i in range(first) if self.layer_types[i] == SSM)
+
+    @property
+    def kv_layer(self) -> int | None:
+        """The layer whose keys and values the cross layers read: the
+        last full-attention layer before the first of them."""
+        if CROSS not in self.layer_types:
+            return None
+        first = self.layer_types.index(CROSS)
+        return max(i for i in range(first) if self.layer_types[i] == FULL)
+
+    @property
+    def kernel_heads(self):
+        """What the flex kernels are planned and tuned for: this
+        configuration, or under DIFF its two head widths made one
+        (:class:`KernelHeads`)."""
+        if self.attn_form != DIFF:
+            return self
+        return KernelHeads(
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            head_dim=2 * self.head_dim, dtype=self.dtype,
+            softmax_scale=self.head_dim ** -0.5,
+        )
 
     @property
     def jnp_dtype(self):
@@ -253,6 +363,8 @@ class PatternConfig:
     def shift_taps(self) -> tuple[int, ...]:
         """How far back a layer reads outside the attention call: the two
         convolutions one after the other (and the value's one token)."""
+        if SSM in self.layer_types:  # the mixer's causal convolution
+            return tuple(range(1, self.ssm_conv))
         if self.attn_form != CCA:
             return ()
         return tuple(range(1, max(sum(self.conv_taps) - 2, 1) + 1))
@@ -261,8 +373,10 @@ class PatternConfig:
     def held_experts(self) -> tuple[int, int]:
         return self.expert_range or (0, self.n_experts)
 
-    def plan_kind(self, layer_type: str) -> str:
-        """The plan a layer's attention runs on."""
+    def plan_kind(self, layer_type: str) -> str | None:
+        """The plan a layer's attention runs on; None: no attention."""
+        if layer_type in (SSM, GMU):
+            return None
         if layer_type == SLIDING and self.sliding_window is not None:
             return SLIDING
         return FULL
@@ -274,6 +388,21 @@ class PatternConfig:
         balance)."""
         kinds = {self.plan_kind(t) for t in self.layer_types}
         return tuple(k for k in (FULL, SLIDING) if k in kinds)
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelHeads:
+    """The heads the flex kernels see where they are not the model's own
+    (``_common.plan_flex_attn`` reads these five). Under DIFF a query
+    and a key head of ``head_dim`` lanes ride a kernel head of twice
+    that, zeros in the other half, beside a value pair that fills it;
+    the softmax scale stays the published head's."""
+
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    dtype: str
+    softmax_scale: float
 
 
 def afmoe_config(
@@ -530,6 +659,75 @@ def ouro_config(
     )
 
 
+def phi4flash_kinds(n_layers: int, mb_per_layer: int) -> tuple[str, ...]:
+    """A published ``phi4flash`` depth as layer kinds: the self-decoder's
+    first half alternates a Mamba mixer (every ``mb_per_layer``-th layer,
+    from 0) with window attention; the mixer at the half hands on its
+    scan, the layer after it is the one full attention and hands on its
+    keys and values; from there gated memory units alternate with cross
+    attention."""
+    half = n_layers // 2
+
+    def kind(i):
+        if i % mb_per_layer == 0:
+            return SSM if i <= half else GMU
+        return SLIDING if i < half else FULL if i == half + 1 else CROSS
+
+    return tuple(kind(i) for i in range(n_layers))
+
+
+def phi4flash_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    vocab_size: int | None = None,
+    layers: Sequence[int] | None = None,
+) -> PatternConfig:
+    """A published ``phi4flash`` ``config.json``
+    (Phi-4-mini-flash-reasoning) as a pattern: :func:`phi4flash_kinds`
+    from ``num_hidden_layers`` and ``mb_per_layer``, differential
+    attention at ``hidden_size / num_attention_heads`` a head, LayerNorm
+    with a bias, no position encoding, a dense SwiGLU in every layer, the
+    embedding tied. ``layers`` keeps those published layers alone, each
+    with its published index (the depth read is then
+    ``num_hidden_layers_published``, where the file states it);
+    ``vocab_size`` gives one rank's share of the rows. The mixer's sizes
+    are no published keys: ``hf`` may state ``d_state``, ``d_conv``,
+    ``expand`` and ``dt_rank`` (Mamba-1's defaults otherwise)."""
+    depth = int(hf.get("num_hidden_layers_published", hf["num_hidden_layers"]))
+    kinds = phi4flash_kinds(depth, int(hf["mb_per_layer"]))
+    keep = tuple(range(depth)) if layers is None else tuple(layers)
+    dim, heads = int(hf["hidden_size"]), int(hf["num_attention_heads"])
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=dim,
+        n_heads=heads,
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=dim // heads,
+        layer_types=tuple(kinds[i] for i in keep),
+        ffn_types=(DENSE,) * len(keep),
+        ffn_hidden=int(hf["intermediate_size"]),
+        sliding_window=int(hf["sliding_window"]),
+        rope_kinds=(),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=False,
+        rms_eps=float(hf["layer_norm_eps"]),
+        dtype=dtype,
+        remat=remat,
+        attn_form=DIFF,
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        norm_form=LAYER,
+        attn_bias=True,
+        ssm_inner=int(hf.get("expand", 2)) * dim,
+        ssm_state=int(hf.get("d_state", 16)),
+        ssm_conv=int(hf.get("d_conv", 4)),
+        ssm_dt_rank=int(hf.get("dt_rank", -(-dim // 16))),
+        layer_index=keep,
+    )
+
+
 def llama_pattern(cfg) -> PatternConfig:
     """``models/llama.py``'s decoder as a pattern: every layer (full,
     dense), rotary everywhere, the AFMoE extras off. ``init_params`` of
@@ -553,12 +751,22 @@ def _ones(*shape):
     return jnp.ones(shape, jnp.float32)
 
 
-def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str) -> dict:
+def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str,
+                layer_type: str = FULL) -> dict:
     dense, ones = _dense_init, _ones
     hq, hk = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
     e0, e1 = cfg.held_experts
     k = jax.random.split(key, 12)
-    if cfg.attn_form == LATENT:
+    if layer_type == SSM:
+        layer = ssm.init_mamba(jax.random.fold_in(key, 4), cfg)
+    elif layer_type == GMU:
+        layer = ssm.init_gmu(jax.random.fold_in(key, 4), cfg)
+    elif layer_type == CROSS:  # a query alone
+        layer = {
+            "wq": dense(k[0], (cfg.dim, hq)),
+            "wo": dense(k[3], (hq, cfg.dim)),
+        }
+    elif cfg.attn_form == LATENT:
         ka = jax.random.split(jax.random.fold_in(key, 1), 5)
         nope = cfg.head_dim - cfg.rope_head_dim
         layer = {
@@ -602,8 +810,30 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str) -> dict:
                 kc[4], (cfg.n_kv_heads,)
             ),
         })
+    if cfg.attn_form == DIFF and layer_type not in (SSM, GMU):
+        kd = jax.random.split(jax.random.fold_in(key, 5), 4 + 4)
+        # lambda's four vectors N(0, DIFF_LAMBDA_STD): lambda leaves
+        # lambda_init and carries gradient; the norm of a pair's
+        # difference, 2 heads wide
+        for name, kk in zip(
+            ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"), kd
+        ):
+            layer[name] = DIFF_LAMBDA_STD * jax.random.normal(
+                kk, (cfg.head_dim,), jnp.float32
+            )
+        layer["diff_norm"] = ones(2 * cfg.head_dim)
+        if cfg.attn_bias:
+            for name, kk in zip(("bq", "bk", "bv", "bo"), kd[4:]):
+                w = layer.get("w" + name[1])
+                if w is not None:
+                    layer[name] = 0.02 * jax.random.normal(
+                        kk, (w.shape[1],), jnp.float32
+                    )
     layer["attn_norm"] = ones(cfg.dim)
     layer["mlp_norm"] = ones(cfg.dim)
+    if cfg.norm_form == LAYER:
+        layer["attn_norm_b"] = jnp.zeros((cfg.dim,), jnp.float32)
+        layer["mlp_norm_b"] = jnp.zeros((cfg.dim,), jnp.float32)
     if cfg.attn_gate:
         layer["w_attn_gate"] = dense(k[4], (cfg.dim, hq))
     if cfg.qk_norm:
@@ -657,11 +887,15 @@ def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
             keys[-2], (cfg.vocab_size, cfg.dim), jnp.float32
         ) * 0.02,
         "layers": [
-            _init_layer(keys[i], cfg, ffn)
-            for i, ffn in enumerate(cfg.ffn_types)
+            _init_layer(keys[i], cfg, ffn, kind)
+            for i, (ffn, kind) in enumerate(
+                zip(cfg.ffn_types, cfg.layer_types)
+            )
         ],
         "final_norm": _ones(cfg.dim),
     }
+    if cfg.norm_form == LAYER:
+        params["final_norm_b"] = jnp.zeros((cfg.dim,), jnp.float32)
     if not cfg.tie_embeddings:
         params["lm_head"] = _dense_init(keys[-1], (cfg.dim, cfg.vocab_size))
     if cfg.n_mtp:
@@ -943,22 +1177,172 @@ def _cca_mix(q, k, v, layer: dict, cfg: PatternConfig, shift):
     return q, k, v.reshape(t, hk, hd)
 
 
-def _layer_local(x, pos, layer, r=None, *, cfg, layer_type, ffn_type, tables,
-                 plans, attn_params, axis_name, shift_plan=None):
-    """One layer on this rank's dispatched tokens -> (x, routing stats).
-    ``r``: an MLP router's state from the layer before; this layer's
-    leaves under ``router_state`` of the stats, for the caller to hand
-    on."""
+def _norm(x, w: dict, name: str, cfg: PatternConfig):
+    """The norm ``name`` of ``w``: RMS, or LayerNorm with its bias
+    ``<name>_b`` (statistics in float32 either way)."""
+    if cfg.norm_form == RMS:
+        return _rms_norm(x, w[name], cfg.rms_eps)
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    y = (x32 * jax.lax.rsqrt(var + cfg.rms_eps)).astype(x.dtype)
+    return y * w[name].astype(x.dtype) + w[name + "_b"].astype(x.dtype)
+
+
+def _diff_q(q, cfg: PatternConfig):
+    """Differential attention's query [t, n_heads x head_dim] as the
+    kernels take it, [t, n_heads, 2 head_dim]: a head's lanes, then as
+    many zeros, the heads ordered so that a kernel GQA group reads its
+    own key: published head ``2 (g i + jj) + s`` (pair ``g i + jj``, half
+    ``s``, ``g`` query heads a key head) is kernel head ``(2 i + s) g +
+    jj``, in the group of kernel key head ``2 i + s``."""
+    t, d = q.shape[0], cfg.head_dim
+    pairs, g = cfg.n_kv_heads // 2, cfg.n_heads // cfg.n_kv_heads
+    q = q.reshape(t, pairs, g, 2, d).transpose(0, 1, 3, 2, 4)
+    return jnp.pad(q.reshape(t, cfg.n_heads, d), ((0, 0), (0, 0), (0, d)))
+
+
+def _diff_kv(k, v, cfg: PatternConfig):
+    """Its keys and values [t, n_kv_heads x head_dim] as the kernels take
+    them, [t, n_kv_heads, 2 head_dim]: kernel key head ``m`` is published
+    key ``m`` and zeros; its value is the pair ``[v_{2i} | v_{2i+1}]``,
+    ``i = m // 2``, which both of the pair's keys weigh."""
+    t, d = k.shape[0], cfg.head_dim
+    k = jnp.pad(k.reshape(t, cfg.n_kv_heads, d), ((0, 0), (0, 0), (0, d)))
+    v = jnp.repeat(v.reshape(t, cfg.n_kv_heads // 2, 2 * d), 2, axis=1)
+    return k, v
+
+
+# The seed's spread of lambda's four vectors. lambda = exp(l_q1 . l_k1) -
+# exp(l_q2 . l_k2) + lambda_init leaves lambda_init (0.79 to 0.80 past
+# layer 14) by a draw of spread 11.3 x the square of this at 64 lanes:
+# 0.028 here, so 1 - lambda stays above 0.08 at four spreads. The
+# Differential Transformer's own 0.1 puts lambda within 0.02 of 1 in a
+# layer on one seed in twenty, and there a document's first tokens, whose
+# two maps hardly differ yet (a1 = a2 on its first), leave a1 - lambda a2
+# under bf16's step of a1 and a2: one token's sub-norm then carries more
+# of wq's gradient than the 4,095 others and no bf16 program reads it
+# (PERF.md section 6, PR 46).
+DIFF_LAMBDA_STD = 0.05
+
+
+def diff_lambda_init(index: int) -> float:
+    """``lambda_init`` of the Differential Transformer at a layer's
+    (published) index, counted from 0."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * index))
+
+
+def _diff_combine(out, layer: dict, cfg: PatternConfig, index: int):
+    """The kernels' output [t, n_heads, 2 head_dim], in :func:`_diff_q`'s
+    order, -> [t, n_heads x head_dim]: ``(1 - lambda_init) x
+    RMSNorm(a1 - lambda a2)`` a query pair, ``lambda = exp(l_q1 . l_k1)
+    - exp(l_q2 . l_k2) + lambda_init``, in float32."""
+    f32 = jnp.float32
+    t, d = out.shape[0], cfg.head_dim
+    pairs, g = cfg.n_kv_heads // 2, cfg.n_heads // cfg.n_kv_heads
+    out = out.reshape(t, pairs, 2, g, 2 * d).astype(f32)
+    lam_init = diff_lambda_init(index)
+    lam = (
+        jnp.exp(jnp.sum(layer["lambda_q1"] * layer["lambda_k1"]))
+        - jnp.exp(jnp.sum(layer["lambda_q2"] * layer["lambda_k2"]))
+        + lam_init
+    )
+    x = out[:, :, 0] - lam * out[:, :, 1]  # [t, pairs, g, 2 d]
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + cfg.rms_eps)
+    x = x * layer["diff_norm"] * (1.0 - lam_init)
+    return x.reshape(t, -1).astype(cfg.jnp_dtype)
+
+
+def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
+                 tables, plans, attn_params, axis_name, shift_plan=None,
+                 index=None):
+    """One layer on this rank's dispatched tokens -> (x, stats).
+    ``carry``: what the layers before handed on, a dict: ``r`` an MLP
+    router's state, ``m`` the memory (the scan output of
+    ``cfg.memory_layer``), ``kv`` the keys and values of
+    ``cfg.kv_layer`` as the kernels take them. The carry this layer
+    hands on, what it was handed and what it made, leaves under
+    ``carry`` of the stats for the caller to pass to the next.
+    ``index``: the layer's place in ``cfg.layer_types``."""
+    dt = cfg.jnp_dtype
+    carry = dict(carry or {})
+    if layer_type == SSM:
+        with named_scope("magi_proj"):
+            h = _norm(x, layer, "attn_norm", cfg)
+        shift_tabs = tables["shift"]
+        out, m = ssm.mamba_mixer(
+            h, layer, cfg,
+            lambda u: shift_local(u, shift_tabs, shift_plan, axis_name),
+            ~shift_valid(shift_tabs)[0],
+            # the scan's kernels run where the flex kernels do
+            interpret=next(iter(attn_params.values())).interpret,
+        )
+        if index == cfg.memory_layer:
+            carry["m"] = m
+        with named_scope("magi_proj"):
+            x = x + out
+    elif layer_type == GMU:
+        with named_scope("magi_gmu"):
+            h = _norm(x, layer, "attn_norm", cfg)
+            x = x + ssm.gmu(h, carry["m"], layer, cfg)
+    else:
+        x = _attention_half(
+            x, pos, layer, carry, cfg=cfg, layer_type=layer_type,
+            tables=tables, plans=plans, attn_params=attn_params,
+            axis_name=axis_name, shift_plan=shift_plan, index=index,
+        )
+
+    # the FFN half; an expert layer's magi_moe_* scopes are siblings
+    # between its two magi_ffn blocks, so magi_ffn holds no expert
+    with named_scope("magi_ffn"):
+        h = _norm(x, layer, "mlp_norm", cfg)
+    stats = {}
+    if ffn_type == DENSE:
+        with named_scope("magi_ffn"):
+            out = _swiglu(
+                h, layer["w_gate"], layer["w_up"], layer["w_down"], dt
+            )
+    else:
+        out, stats = _expert_ffn(h, layer, cfg, carry.get("r"))
+        if "router_state" in stats:
+            carry["r"] = stats.pop("router_state")
+    if carry:
+        stats["carry"] = carry
+    with named_scope("magi_ffn"):
+        if cfg.post_norms:
+            out = _rms_norm(out, layer["post_mlp_norm"], cfg.rms_eps)
+        return x + out, stats
+
+
+def _attention_half(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
+                    attn_params, axis_name, shift_plan, index):
+    """``x`` + the attention half of a SLIDING, FULL or CROSS layer; the
+    keys and values ``cfg.kv_layer`` makes go into ``carry``."""
     dt = cfg.jnp_dtype
     t = x.shape[0]
     eps = cfg.rms_eps
     kind = cfg.plan_kind(layer_type)
+
+    def proj(h, name):
+        y = h @ layer["w" + name].astype(dt)
+        if cfg.attn_bias:
+            y = y + layer["b" + name].astype(dt)
+        return y
+
     # the attention half but for the attention call, which is a sibling:
     # a flex kernel must never lie under magi_proj
     with named_scope("magi_proj"):
-        h = _rms_norm(x, layer["attn_norm"], eps)
+        h = _norm(x, layer, "attn_norm", cfg)
         if cfg.attn_form == LATENT:
             q, k, v = _latent_qkv(h, pos, layer, cfg)
+        elif cfg.attn_form == DIFF:
+            q = _diff_q(proj(h, "q"), cfg)
+            if layer_type == CROSS:
+                k, v = carry["kv"]
+            else:
+                k, v = _diff_kv(proj(h, "k"), proj(h, "v"), cfg)
+                if index == cfg.kv_layer:
+                    carry["kv"] = (k, v)
         else:
             q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
             k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
@@ -995,41 +1379,33 @@ def _layer_local(x, pos, layer, r=None, *, cfg, layer_type, ffn_type, tables,
             q, k, v, tables[kind], plans[kind], attn_params[kind],
             axis_name=axis_name,
         )
+    if cfg.attn_form == DIFF:
+        # a sibling of magi_proj, as the attention call is
+        with named_scope("magi_diff_combine"):
+            place = cfg.layer_index[index] if cfg.layer_index else index
+            out = _diff_combine(out, layer, cfg, place)
     with named_scope("magi_proj"):
         out = out.reshape(t, -1)
         if cfg.attn_gate:
             out = out * jax.nn.sigmoid(h @ layer["w_attn_gate"].astype(dt))
         with (named_scope("magi_mla_out") if cfg.attn_form == LATENT
               else contextlib.nullcontext()):
-            out = out @ layer["wo"].astype(dt)
+            out = proj(out, "o")
         if cfg.post_norms:
             out = _rms_norm(out, layer["post_attn_norm"], eps)
-        x = x + out
-
-    # the FFN half; an expert layer's magi_moe_* scopes are siblings
-    # between its two magi_ffn blocks, so magi_ffn holds no expert
-    with named_scope("magi_ffn"):
-        h = _rms_norm(x, layer["mlp_norm"], eps)
-    stats = {}
-    if ffn_type == DENSE:
-        with named_scope("magi_ffn"):
-            out = _swiglu(
-                h, layer["w_gate"], layer["w_up"], layer["w_down"], dt
-            )
-    else:
-        out, stats = _expert_ffn(h, layer, cfg, r)
-    with named_scope("magi_ffn"):
-        if cfg.post_norms:
-            out = _rms_norm(out, layer["post_mlp_norm"], eps)
-        return x + out, stats
+        return x + out
 
 
 def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
-               axis_name, shift_plan=None):
+               axis_name, shift_plan=None, index=None):
+    """``(x, pos, layer, carry=None) -> (x, stats)`` of one layer
+    (:func:`_layer_local`). Under ``cfg.remat`` a layer keeps its inputs,
+    the carry among them, and recomputes the rest: what an earlier layer
+    handed on is never made again."""
     one_layer = functools.partial(
         _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
         tables=tables, plans=plans, attn_params=attn_params,
-        axis_name=axis_name, shift_plan=shift_plan,
+        axis_name=axis_name, shift_plan=shift_plan, index=index,
     )
     if cfg.remat:  # save a layer's input; the rest recomputes
         one_layer = jax.checkpoint(one_layer)
@@ -1063,30 +1439,35 @@ def _logits(x, params, cfg: PatternConfig):
     return (x @ head).astype(jnp.float32)
 
 
-def _head(x, norm, params, cfg: PatternConfig):
+def _head(x, w: dict, params, cfg: PatternConfig):
+    """Logits of ``x`` through ``w``'s ``final_norm`` (the trunk's: the
+    parameters; an MTP module's: the module)."""
     with named_scope("magi_head"):
-        return _logits(_rms_norm(x, norm, cfg.rms_eps), params, cfg)
+        return _logits(_norm(x, w, "final_norm", cfg), params, cfg)
 
 
 def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
                  attn_params, axis_name, shift_plan=None):
     """The layers over this rank's dispatched tokens -> (the last layer's
-    output before the final norm, the expert layers' routing stats). An
-    MLP router's state goes from layer to layer beside ``x``, zero before
-    the first."""
+    output before the final norm, the expert layers' routing stats).
+    What a layer hands on goes from layer to layer beside ``x`` as one
+    carry (:func:`_layer_local`): an MLP router's state, zero before the
+    first layer, the memory and the shared keys and values."""
     x = _embed(params, tokens, cfg)
-    r = None
+    carry = {}
     if cfg.router_form == MLP:
-        r = jnp.zeros((x.shape[0], cfg.router_hidden), cfg.router_dtype)
+        carry["r"] = jnp.zeros(
+            (x.shape[0], cfg.router_hidden), cfg.router_dtype
+        )
     stats = []
-    for layer, layer_type, ffn_type in zip(
+    for i, (layer, layer_type, ffn_type) in enumerate(zip(
         params["layers"], cfg.layer_types, cfg.ffn_types
-    ):
+    )):
         x, s = _one_layer(
             cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name,
-            shift_plan,
-        )(x, pos, layer, r)
-        r = s.pop("router_state", None)
+            shift_plan, i,
+        )(x, pos, layer, carry)
+        carry = s.pop("carry", {})
         if s:
             stats.append(s)
     return x, stats
@@ -1212,7 +1593,7 @@ def _mtp_local(params, x, next_tokens, pos, cfg: PatternConfig, tables,
         )(x, pos, mod["layer"])
         if s:
             stats.append(s)
-        logits.append(_head(x, mod["final_norm"], params, cfg))
+        logits.append(_head(x, mod, params, cfg))
     return logits, stats
 
 
@@ -1328,7 +1709,7 @@ class MagiPattern:
                     x = x[rows]
                     lab1 = jnp.where(here, lab1[rows], -1)
                     w1 = jnp.where(here, w1[rows], 0.0)
-                logits = _head(x, params["final_norm"], params, cfg)
+                logits = _head(x, params, params, cfg)
                 with named_scope("magi_head"):
                     ce, _ = masked_ce_tokens(logits, lab1)
                     with named_scope("magi_diffusion_io"):
@@ -1347,7 +1728,7 @@ class MagiPattern:
                 x, stats = _trunk_local(
                     params, tok1, pos1, *run, self.shift_plan
                 )
-                logits = [_head(x, params["final_norm"], params, cfg)]
+                logits = [_head(x, params, params, cfg)]
                 if cfg.n_mtp:
                     with named_scope("magi_mtp"):
                         # module j is fed token i + j + 1: the label, then
@@ -1466,6 +1847,13 @@ class MagiPattern:
             telemetry.record_moe_load(i, counts)
 
 
+def _cast_rows(plan) -> int:
+    """Rows all ranks' key-value casts of one attention call carry."""
+    if plan is None or plan.cp_size == 1:
+        return 0
+    return int(plan.comm.scheduled_rows_total)
+
+
 def build_magi_pattern(
     cfg: PatternConfig,
     mesh: Mesh,
@@ -1493,12 +1881,22 @@ def build_magi_pattern(
     from ..api.functools import (
         infer_attn_mask_from_cu_seqlens, infer_block_diffusion_mask,
     )
-    from ._common import plan_flex_attn, plan_flex_attn_on_dispatch
+    from ._common import (
+        _cp_geometry, plan_flex_attn, plan_flex_attn_on_dispatch,
+    )
 
     if isinstance(cp_axis, list):
         cp_axis = tuple(cp_axis)
     cu = [int(c) for c in cu_seqlens]
     data_tokens = cu[-1]
+    cp_size, _ = _cp_geometry(mesh, cp_axis)
+    if SSM in cfg.layer_types and cp_size > 1:
+        raise NotImplementedError(
+            f"a state-space layer at cp = {cp_size}: the scan runs on one "
+            "rank's rows in sequence order; the hand-over of its state "
+            "between ranks and a dispatch that keeps a document's chunks "
+            "in order are ROADMAP R8"
+        )
     # under diffusion over blocks the plan is the doubled sequence's
     total = (2 if cfg.diffusion_block else 1) * data_tokens
     if cfg.diffusion_block and data_tokens % chunk_size:
@@ -1522,13 +1920,21 @@ def build_magi_pattern(
     )
     lead, *rest = cfg.plan_kinds
     plans, attn_params = {}, {}
+    heads = cfg.kernel_heads
     plans[lead], attn_params[lead], meta = plan_flex_attn(
-        cfg, mesh, total, *mask(lead), chunk_size=chunk_size,
+        heads, mesh, total, *mask(lead), chunk_size=chunk_size,
         kind=_SHORT[lead], **common,
     )
     for kind in rest:
         plans[kind], attn_params[kind] = plan_flex_attn_on_dispatch(
-            cfg, mesh, meta, *mask(kind), kind=_SHORT[kind], **common,
+            heads, mesh, meta, *mask(kind), kind=_SHORT[kind], **common,
+        )
+    if SSM in cfg.layer_types and not np.array_equal(
+        np.asarray(meta.perm_idx), np.arange(total)
+    ):
+        raise NotImplementedError(
+            "a state-space layer on a dispatch that moves rows: the scan "
+            "runs on rows in sequence order (ROADMAP R8)"
         )
     model = MagiPattern(
         cfg=cfg, mesh=mesh, plans=plans, attn_params=attn_params,
@@ -1542,6 +1948,17 @@ def build_magi_pattern(
         ),
     )
     telemetry.record_model_loop(cfg.n_loops, cfg.n_layers)
+    if cfg.attn_form == DIFF:
+        readers = cfg.layer_types.count(CROSS)
+        telemetry.record_handed_on(
+            documents=len(cu) - 1 if SSM in cfg.layer_types else None,
+            kv_readers=readers,
+            # every reader's attention call casts the handed-on pair
+            # again: the rows the FULL plan's casts bring a rank, once a
+            # reader (none at cp = 1)
+            recast_rows=readers * _cast_rows(plans.get(FULL)),
+            pad_lane_share=0.5,
+        )
     if cfg.attn_form == LATENT:
         telemetry.record_mla_kv_cast_width(
             expanded=2 * cfg.n_heads * cfg.head_dim,

@@ -74,7 +74,9 @@ from .collectors import (  # noqa: F401
     record_mask_step,
     record_flex_kernel_build,
     record_model_attn_plan,
+    record_handed_on,
     record_mla_kv_cast_width,
+    record_ssm_scan,
     record_model_loop,
     record_shift,
     record_moe_load,
@@ -365,7 +367,9 @@ __all__ = [
     "record_mask_step",
     "record_flex_kernel_build",
     "record_model_attn_plan",
+    "record_handed_on",
     "record_mla_kv_cast_width",
+    "record_ssm_scan",
     "record_model_loop",
     "record_shift",
     "record_moe_load",

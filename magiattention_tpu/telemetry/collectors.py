@@ -163,6 +163,23 @@ M_MODEL_LAYER_APPLICATIONS = "magi_model_layer_applications"
 # rank, all ranks' and all taps' (each row once), added where a shift is
 # planned (parallel/dispatch.make_shift_plan): 0 at cp = 1
 M_SHIFT_REMOTE_ROWS = "magi_shift_remote_rows_total"
+# the selective scan (ops/selective_scan.py) and what a pattern decoder's
+# layers hand on (models/pattern.py). counter — scan calls traced:
+# {phase=fwd|bwd}; gauges — the documents whose first row zeroes the
+# state, set where the model is built; a call's chunks and the bytes of
+# the chunk boundaries' states its forward keeps for the backward
+M_SSM_SCAN_CALLS = "magi_ssm_scan_calls_total"
+M_SSM_DOCUMENTS = "magi_ssm_documents"
+M_SSM_CHUNKS = "magi_ssm_chunks"
+M_SSM_STATE_BYTES = "magi_ssm_state_bytes"
+# gauge — layers that read the keys and values one layer handed on;
+# counter — rows of that pair a rank's casts carried for a reader after
+# the first (0 at cp = 1: every reader casts the pair again, ROADMAP R11);
+# gauge — the share of the q and k lanes the flex kernels are handed that
+# is padding (0.5 where 64-wide heads ride 128-wide kernel heads)
+M_SHARED_KV_READERS = "magi_shared_kv_readers"
+M_SHARED_KV_RECAST_ROWS = "magi_shared_kv_recast_rows_total"
+M_FLEX_PAD_LANE_SHARE = "magi_flex_pad_lane_share"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1243,6 +1260,36 @@ def record_shift(*, rows: int, taps, documents: int) -> None:
     _marker_event(
         "shift", {"rows": rows, "taps": list(taps), "documents": documents}
     )
+
+
+def record_ssm_scan(phase: str, *, chunks: int, state_bytes: int) -> None:
+    """One selective scan traced (``ops/selective_scan.py``, trace time:
+    once a compiled program, like the named scopes)."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.counter_inc(M_SSM_SCAN_CALLS, 1, phase=phase)
+    reg.gauge_set(M_SSM_CHUNKS, float(chunks))
+    reg.gauge_set(M_SSM_STATE_BYTES, float(state_bytes))
+
+
+def record_handed_on(
+    *, documents: int | None, kv_readers: int, recast_rows: int,
+    pad_lane_share: float,
+) -> None:
+    """What a pattern decoder's layers hand on
+    (``models/pattern.build_magi_pattern``, host side): the documents a
+    scan resets at (None: no state-space layer), the readers of the
+    shared keys and values and the rows their casts carry again, the
+    padded share of the kernels' q and k lanes."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    if documents is not None:
+        reg.gauge_set(M_SSM_DOCUMENTS, float(documents))
+    reg.gauge_set(M_SHARED_KV_READERS, float(kv_readers))
+    reg.counter_inc(M_SHARED_KV_RECAST_ROWS, recast_rows)
+    reg.gauge_set(M_FLEX_PAD_LANE_SHARE, float(pad_lane_share))
 
 
 def record_mla_kv_cast_width(*, expanded: int, latent: int) -> None:

@@ -315,3 +315,33 @@ def test_group_one_geometries(topo, geometry, t, rung, grid):
         reg.clear_metric("magi_flex_kernel_build_total")
         telemetry.set_enabled(was)
     assert text.count("tpu_custom_call") == 2  # fwd, bwd
+
+
+@pytest.mark.parametrize("chunk,block", [(128, 1024), (256, 512)])
+def test_selective_scan_at_the_sambay_cells_size(topo, chunk, block):
+    """The selective scan's kernel pair (ISSUE 46) at the Phi-4-mini-flash
+    cell's 16,384 rows of 5,120 channels and 16 states, operands in the
+    cell's dtypes: the token loop's 8-row reads (a dynamic row index the
+    chip's compiler takes only as a multiple of 8), the chunk's states in
+    VMEM and the lane sums on the MXU compile; interpret mode shows none
+    of the three."""
+    from magiattention_tpu.ops.selective_scan import selective_scan
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    t, ch, n = 16384, 5120, 16
+    args = (
+        _on(chip, (t, ch)), _on(chip, (t, ch), jnp.float32),
+        _on(chip, (ch, n), jnp.float32), _on(chip, (t, n)), _on(chip, (t, n)),
+        _on(chip, (ch,), jnp.float32), _on(chip, (t,), jnp.bool_),
+    )
+
+    def loss(u, delta, a, b, c, d, start):
+        y = selective_scan(
+            u, delta, a, b, c, d, start, chunk=chunk, channel_block=block,
+            interpret=False,
+        )
+        return y.astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), *args)
+    for kernel in ("magi_ssm_scan_fwd_kernel", "magi_ssm_scan_bwd_kernel"):
+        assert kernel in text, kernel

@@ -1,7 +1,7 @@
 """Every operation the device runs lies under exactly one *part* scope
 that a per-layer metric reads (``docs/observability.md``, "Device
 scopes"): the six toy models' train steps and the bare attention call
-are compiled here, on the CPU, their ``op_name``s read as the benchmark
+(seven with the decoder-hybrid-decoder's) are compiled here, on the CPU, their ``op_name``s read as the benchmark
 reads them (``trace_reduce.hlo_scopes``), and held against the patterns
 of the metric files themselves, so a scope that is renamed, dropped or
 wrapped round another part fails here and not as a silent zero on the
@@ -31,6 +31,7 @@ from tests.test_models import test_pattern_blockdiff as blockdiff
 from tests.test_models.test_pattern_cca import _zaya
 from tests.test_models.test_pattern_latent import _glm
 from tests.test_models.test_pattern_looped import _ouro
+from tests.test_models.test_pattern_sambay import _sambay
 
 METRICS = os.path.join(
     os.path.dirname(__file__), "..", "..", "benchmarks", "metrics"
@@ -56,6 +57,9 @@ PARTS = {
         "head": "train_head_share",
         "exit_head": "train_exit_head_share",
         "cca_mix": "train_cca_mix_share",
+        "ssm_scan": "train_ssm_scan_share",
+        "ssm_mix": "train_ssm_mix_share",
+        "diff_combine": "train_diff_combine_share",
         "optimizer": "train_optimizer_share",
     }.items()
 }
@@ -63,6 +67,9 @@ EXPERT_PARTS = {
     part: _pattern(f"train_moe_{part}_share")
     for part in ("sort", "gather", "matmul", "scatter")
 }
+# parts newer than the remainder's pattern, which is the benchmark's and
+# would read them too: their cells are not on its list (PERF.md section 7)
+NEWER_THAN_THE_REMAINDER = {"cca_mix", "ssm_scan", "ssm_mix", "diff_combine"}
 REMAINDERS = {
     "step": _pattern("train_unscoped_share"),
     "attn": _pattern("attn_unscoped_fwdbwd_ms"),
@@ -107,6 +114,13 @@ CASES = {
         "magi_head", "magi_attn_full", "magi_cca_mix",
         r"checkpoint/magi_cca_mix",  # a sibling of magi_proj, not inside it
     ],
+    "sambay": STEP + [
+        "magi_head", "magi_attn_sliding", "magi_attn_full",
+        "magi_ssm_scan_fwd_kernel", "magi_ssm_scan_bwd_kernel",
+        # siblings of magi_proj, as the attention call is, not inside it
+        r"checkpoint/magi_ssm_scan", r"checkpoint/magi_ssm_mix",
+        r"checkpoint/magi_gmu", r"checkpoint/magi_diff_combine",
+    ],
     "blockdiff": STEP + [s for s in EXPERTS if s != "magi_moe_shared"] + [
         "magi_head", "magi_attn_full",
         # a cross-cut: its operations carry their part too
@@ -136,7 +150,7 @@ def _step_text(name: str) -> str:
     else:
         cfg = {
             "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
-            "looped": _ouro()[1], "cca": _zaya()[1],
+            "looped": _ouro()[1], "cca": _zaya()[1], "sambay": _sambay()[1],
         }[name]
         model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
         params = init_pattern_params(jax.random.PRNGKey(0), cfg)
@@ -215,12 +229,11 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
         parts = [p for p, rx in PARTS.items() if rx.search(line)]
         assert len(parts) == 1, (line, parts)
         seen[parts[0]] += 1
-        # and the remainder does not count it (but for the mix, a part
-        # newer than the remainder's pattern, which is the benchmark's:
-        # PERF.md section 7)
+        # and the remainder does not count it (but for the parts newer
+        # than the remainder's pattern)
         assert bool(
             REMAINDERS["attn" if is_attn else "step"].search(line)
-        ) == (parts == ["cca_mix"])
+        ) == (parts[0] in NEWER_THAN_THE_REMAINDER)
         if "magi_moe_experts" in line:
             inside = [p for p, rx in EXPERT_PARTS.items() if rx.search(line)]
             assert len(inside) == 1, (line, inside)
@@ -242,14 +255,18 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
                 "magi_proj/magi_cca_mix", "magi_cca_mix/magi_proj"],
         "blockdiff": ["magi_mla_", "magi_mtp", "magi_exit_head",
                       "magi_moe_shared", "magi_cca_mix"],
+        "sambay": ["magi_moe_", "magi_mla_", "magi_mtp", "magi_exit_head",
+                   "magi_cca_mix", "magi_proj/magi_ssm", "magi_proj/magi_gmu",
+                   "magi_proj/magi_diff_combine", "magi_ssm_mix/magi_proj"],
     }.get(case, ["magi_proj", "magi_ffn", "magi_head", "magi_optimizer"])
     for scope in absent:
         assert not any(scope in s for s in lines), scope
     if not is_attn:
         # every cca layer is an expert layer: magi_ffn holds its norm and
         # its residual add, nothing heavy
-        own = {"cca": {"cca_mix", "moe"}, "blockdiff": {"moe"}}.get(
-            case, {"ffn"}
-        )
+        own = {
+            "cca": {"cca_mix", "moe"}, "blockdiff": {"moe"},
+            "sambay": {"ffn", "ssm_scan", "ssm_mix"},
+        }.get(case, {"ffn"})
         assert {"proj", "flex", "layout", "embed"} | own <= set(seen), seen
         assert ("exit_head" if case == "looped" else "head") in seen, seen

@@ -67,6 +67,8 @@ DQ_VISITS = {
     "ouro26b-train-16k-looped": [3.375],
     "zaya1-train-16k-traces": [6.0],
     "sdar30b-train-16k-blockdiff": [5.875],
+    # the full plan (ZAYA's mask), then the window of 512: one block_k
+    "phi4flash-train-16k-traces": [6.0, 1.9375],
 }
 
 
@@ -86,6 +88,8 @@ def _heads(cell):
     hk = cfg.get("num_key_value_heads", hq)
     if "qk_nope_head_dim" in cfg:  # the latent form: 20 = 20 heads of 256
         return hq, hq, cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    if cfg.get("model_type") == "phi4flash":  # 64-wide heads on 128 lanes
+        return hq, hk, 2 * (cfg["hidden_size"] // hq)
     if "cca_num_q_heads" in cfg:
         return cfg["cca_num_q_heads"], cfg["cca_num_kv_heads"], cfg["head_dim"]
     return hq, hk, cfg["head_dim"] if "head_dim" in cfg else cfg["hidden_size"] // hq
@@ -93,7 +97,7 @@ def _heads(cell):
 
 @pytest.mark.parametrize("cell", _cells())
 def test_every_cells_plan_takes_the_fused_backward(telemetry_on, cell, monkeypatch):
-    """All twelve cells: the ``attn_fn_build`` span of every plan carries
+    """Every cell: the ``attn_fn_build`` span of every plan carries
     ``bwd_form`` and the counter counts the plan. And from the plan's own
     rung: a fused step's larger of MXU and HBM time, at the chip's peaks,
     is under the sum of the two steps it replaced (dq's and dkv's), which
