@@ -286,7 +286,27 @@ def resolve_harness_blocking(
 def make_model_train_step(model, optimizer):
     """optax-style optimizer -> jitted (params, opt_state, batch) step.
 
-    Works for any bundle exposing ``loss_fn`` + ``sharded_tables``."""
+    Works for any bundle exposing ``loss_fn`` + ``sharded_tables``.
+
+    A weight matrix's update is not computed inside the matmul that makes
+    its gradient: every 2-D gradient leaf goes through
+    ``jax.lax.optimization_barrier`` by itself before ``optimizer.update``.
+    On one chip nothing else stands between the two (in a data-parallel
+    or sharded job a collective does), and the TPU compiler then fuses
+    AdamW into the weight-gradient matmul as three float32 outputs (the
+    new weight, ``mu``, ``nu``); those shrink the tile the matmul can
+    keep, and it ran at 73-96 TFLOP/s where the same shapes' forward and
+    input-gradient matmuls ran at 158-170: 185 ms of Mistral-7B's 589 ms
+    step on a v5e, 115 ms with the update apart (PERF.md, PR 47). A
+    barrier a leaf and not one round the tree: each gradient is retired
+    as it comes, where one barrier would hold every float32 gradient at
+    once (2.8 GB there). Matrices only: a matrix's gradient is one
+    matmul's output, while a stacked leaf's (held experts,
+    ``[experts, in, out]``) is a sum over row chunks that its update
+    reads in the same pass, and a barrier there costs a float32 write
+    and read of the whole leaf for nothing (5 ms of ZAYA1's 314). So the
+    ``magi_optimizer`` scope reads the whole update, about 28 bytes a
+    parameter at the HBM's pace."""
     tables = model.sharded_tables()
 
     def step(params, opt_state, tokens, labels, pos, weights=None):
@@ -295,6 +315,10 @@ def make_model_train_step(model, optimizer):
         loss, grads = jax.value_and_grad(model.loss_fn)(
             params, tokens, labels, pos, tables,
             *(() if weights is None else (weights,)),
+        )
+        grads = jax.tree.map(
+            lambda g: jax.lax.optimization_barrier(g) if g.ndim == 2 else g,
+            grads,
         )
         with named_scope("magi_optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)

@@ -5,6 +5,7 @@ cells' rungs, ``tests/test_aot_train_steps_tpu.py`` whole serve and train
 steps (split in ISSUE 45: one file was a worker's 396 s)."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs land in /tmp
 
@@ -57,3 +58,19 @@ def _on(sharding, shape, dtype=jnp.bfloat16):
 
 def _compile(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def update_fusions(hlo_text: str) -> tuple[list[str], int]:
+    """(the fused computations of a compiled step that hold both a
+    ``convolution`` and an instruction whose ``op_name`` lies under
+    ``magi_optimizer``: a weight's update inside the matmul of its
+    gradient; how many instructions lie under that scope at all)."""
+    fused = [
+        m.group(1)
+        for m in re.finditer(
+            r"^%?(fused_computation[\w.\-]*) .*?\{\n(.*?)^\}",
+            hlo_text, re.MULTILINE | re.DOTALL,
+        )
+        if " convolution(" in m.group(2) and "magi_optimizer" in m.group(2)
+    ]
+    return fused, len(re.findall(r'op_name="[^"]*magi_optimizer', hlo_text))

@@ -14,7 +14,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from .aot import _GROUP_ONE, _as_on_the_chip, _compile, _on, topo  # noqa: F401
+from .aot import (  # noqa: F401
+    _GROUP_ONE, _as_on_the_chip, _compile, _on, topo, update_fusions,
+)
 
 
 def _serve_cache(chip, hk, d):
@@ -64,6 +66,32 @@ def test_serve_prefill_continuation(topo):
     assert "tpu_custom_call" in text
 
 
+def _benchmark_json(*parts):
+    import json
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", *parts)) as f:
+        return json.load(f)
+
+
+def _compile_train_step(model, init, opt, rows: int):
+    """The model's optimizer step compiled for its described mesh: the
+    parameters of ``init`` and the optimizer's state replicated, one
+    ``(1, rows)`` row of token ids, labels and positions."""
+    rep = NamedSharding(model.mesh, P())
+    params = jax.eval_shape(init)
+    state = jax.eval_shape(opt.init, params)
+    params, state = jax.tree.map(
+        lambda s: _on(rep, s.shape, s.dtype), (params, state)
+    )
+    batch = _on(NamedSharding(model.mesh, P("dp", "cp")), (1, rows), jnp.int32)
+    return (
+        model.make_train_step(opt)
+        .lower(params, state, batch, batch, batch)
+        .compile()
+    )
+
+
 def test_two_layer_train_step_cp4(topo):
     """A whole optimizer step over the four described chips: the plan
     tables cannot be placed there (``sharded_plan_tables`` leaves them
@@ -88,22 +116,111 @@ def test_two_layer_train_step_cp4(topo):
     model, _ = build_magi_llama(
         cfg, mesh, total, qr, kr, ts, chunk_size=128, interpret=False
     )
-    opt = optax.adamw(1e-4)
-    rep = NamedSharding(mesh, P())
-    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    state = jax.eval_shape(opt.init, params)
-    params, state = jax.tree.map(
-        lambda s: _on(rep, s.shape, s.dtype), (params, state)
-    )
-    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, total), jnp.int32)
-    text = (
-        model.make_train_step(opt)
-        .lower(params, state, batch, batch, batch)
-        .compile()
-        .as_text()
-    )
+    text = _compile_train_step(
+        model, lambda: init_params(jax.random.PRNGKey(0), cfg),
+        optax.adamw(1e-4), total,
+    ).as_text()
     assert "tpu_custom_call" in text
     assert "all-to-all" in text or "collective-permute" in text
+
+
+def _dense_step(mesh):
+    """Mistral-7B's step as its cell runs it: the published widths, 2
+    layers, remat, the cell's 16,384-token packed mask."""
+    from benchmarks import masks
+    from benchmarks.kinds.train_stream import _llama_config
+    from magiattention_tpu.common import AttnRanges
+    from magiattention_tpu.models import build_magi_llama, init_params
+
+    tr = _benchmark_json("traffic", "train-16k-onemask.json")
+    cfg = _llama_config(_benchmark_json("configs", "mistral-7b-v0.3.json"), tr)
+    total = int(tr["total_tokens"])
+    mask = masks.build_mask(tr["mask"], total, index=0)
+    model, _ = build_magi_llama(
+        cfg, mesh, total,
+        AttnRanges.from_ranges(list(mask.q_ranges)),
+        AttnRanges.from_ranges(list(mask.k_ranges)),
+        list(mask.types), chunk_size=int(tr["chunk_size"]), interpret=False,
+    )
+    return model, (lambda: init_params(jax.random.PRNGKey(0), cfg)), total
+
+
+def _experts_step(mesh):
+    """Trinity-Mini's step at the published widths, its rank's share of
+    the experts and the vocabulary, 2 of its layers (a dense one, one
+    with held experts and the shared expert), at the check's 4,096
+    tokens."""
+    from benchmarks import masks
+    from magiattention_tpu.models.pattern import (
+        afmoe_config, build_magi_pattern, init_pattern_params,
+    )
+
+    hf = _benchmark_json("configs", "trinity-mini.json")
+    tr = _benchmark_json("traffic", "train-32k-packed-swa-global.json")
+    cfg = afmoe_config(
+        dict(hf, num_hidden_layers=2,
+             layer_types=["sliding_attention", "full_attention"]),
+        remat=True, expert_range=tuple(hf["experts_here"]),
+        vocab_size=hf["vocab_here"],
+    )
+    total = int(tr["check_tokens"])
+    mask = masks.build_mask(tr["check_mask"], total, index=0)
+    model, _ = build_magi_pattern(
+        cfg, mesh, mask.cu_seqlens, chunk_size=int(tr["chunk_size"]),
+        interpret=False,
+    )
+    return (
+        model, (lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)),
+        total,
+    )
+
+
+_STEPS = {"dense": _dense_step, "experts": _experts_step}
+
+
+@functools.cache
+def _update_in_step(topo, which: str, barrier: bool):
+    """(``update_fusions`` of the compiled AdamW step's text, its
+    temporaries' bytes) on one described chip; ``barrier`` False builds
+    the same step with ``make_model_train_step``'s barrier taken out."""
+    import optax
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
+    model, init, total = _STEPS[which](mesh)
+    with pytest.MonkeyPatch.context() as patch:
+        if not barrier:
+            patch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+        exe = _compile_train_step(model, init, optax.adamw(3e-4), total)
+    return update_fusions(exe.as_text()), exe.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("which", list(_STEPS))
+def test_no_update_inside_a_gradients_matmul(topo, which):
+    """A weight matrix's AdamW update is not computed inside the matmul
+    that makes its gradient (ISSUE 47: three float32 outputs on such a
+    matmul halved its pace on the chip): no fused computation of the step
+    holds a ``convolution`` and an instruction under ``magi_optimizer``
+    (the held experts' stacked leaves, which take no barrier, never were
+    in one: their gradient is a sum over row chunks), and the update is
+    still there under its scope."""
+    (fused, under_scope), _temp = _update_in_step(topo, which, True)
+    assert fused == []
+    assert under_scope > 0
+
+
+def test_the_gradients_barrier_is_what_keeps_the_update_out(topo):
+    """The dense step without the barrier is the program the check above
+    refuses (were it not, that check would hold nothing: 15 such fused
+    computations at ISSUE 47), and a barrier a matrix keeps no gradient
+    alive longer than it has to: the temporaries are within 1% (one
+    barrier round the whole tree would hold every float32 gradient at
+    once, 2.8 GB). The sparse-expert step is not compiled a second time
+    for this (45 s; it too held such fusions without the barrier when
+    this was written, as the Trinity cell's step does: PERF.md, PR 47)."""
+    _fusions, temp = _update_in_step(topo, "dense", True)
+    (fused_without, _n), temp_without = _update_in_step(topo, "dense", False)
+    assert fused_without
+    assert abs(temp - temp_without) <= 0.01 * temp_without, (temp, temp_without)
 
 
 def test_looped_train_step_holds_a_layers_kernels_once(topo):
@@ -115,8 +232,6 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
     not 3 x layers x passes, and it traces, differentiates and rematerialises
     ``dist_attn_local`` inside ``scan`` + ``checkpoint`` + ``shard_map``
     for the chip's compiler as it stands."""
-    import json
-
     import optax
 
     from benchmarks import masks
@@ -124,15 +239,14 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
         build_magi_pattern, init_pattern_params, ouro_config,
     )
 
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmarks", "configs", "ouro-2.6b.json")) as f:
-        cfg = ouro_config(
-            dict(json.load(f), num_hidden_layers=2), remat=True
-        )
-    with open(os.path.join(
-        here, "benchmarks", "traffic", "train-16k-packed-looped.json"
-    )) as f:
-        mask = masks.build_mask(json.load(f)["mask"], 4096, index=0)
+    cfg = ouro_config(
+        dict(_benchmark_json("configs", "ouro-2.6b.json"), num_hidden_layers=2),
+        remat=True,
+    )
+    mask = masks.build_mask(
+        _benchmark_json("traffic", "train-16k-packed-looped.json")["mask"],
+        4096, index=0,
+    )
     assert (cfg.n_loops, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads) == (
         4, 2, 16, 16
     )
@@ -144,22 +258,10 @@ def test_looped_train_step_holds_a_layers_kernels_once(topo):
     assert (p.block_q, p.block_k, p.head_block, p.grid) == (
         *_GROUP_ONE["looped"][2], "sparse"
     )
-    opt = optax.adamw(3e-4)
-    rep = NamedSharding(mesh, P())
-    params = jax.eval_shape(
-        lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)
-    )
-    state = jax.eval_shape(opt.init, params)
-    params, state = jax.tree.map(
-        lambda s: _on(rep, s.shape, s.dtype), (params, state)
-    )
-    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, 4096), jnp.int32)
-    text = (
-        model.make_train_step(opt)
-        .lower(params, state, batch, batch, batch)
-        .compile()
-        .as_text()
-    )
+    text = _compile_train_step(
+        model, lambda: init_pattern_params(jax.random.PRNGKey(0), cfg),
+        optax.adamw(3e-4), 4096,
+    ).as_text()
     assert text.count("tpu_custom_call") == 3 * cfg.n_layers
 
 
@@ -171,8 +273,6 @@ def test_cca_train_step_at_two_key_value_heads(topo):
     rung the cell's 16,384-token mask gets too, the shift at cp = 1 is a
     slice (no gather under ``magi_cca_mix``), and the router's state
     crosses ``checkpoint`` inside ``shard_map``."""
-    import json
-
     import optax
 
     from benchmarks import masks, trace_reduce
@@ -180,17 +280,12 @@ def test_cca_train_step_at_two_key_value_heads(topo):
         build_magi_pattern, init_pattern_params, zaya_config,
     )
 
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmarks", "configs", "zaya1-8b.json")) as f:
-        hf = json.load(f)
+    hf = _benchmark_json("configs", "zaya1-8b.json")
     cfg = zaya_config(
         dict(hf, num_hidden_layers=2), remat=True,
         expert_range=tuple(hf["experts_here"]), vocab_size=hf["vocab_here"],
     )
-    with open(os.path.join(
-        here, "benchmarks", "traffic", "train-16k-packed-cca.json"
-    )) as f:
-        tr = json.load(f)
+    tr = _benchmark_json("traffic", "train-16k-packed-cca.json")
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
     rungs = []
     for spec, total in ((tr["check_mask"], 4096), (tr["mask"], 16384)):
@@ -205,22 +300,10 @@ def test_cca_train_step_at_two_key_value_heads(topo):
     assert rungs[0] == rungs[1] == (128, 512, 8, "sparse")
     assert check.shift_plan.fwd.offsets == (1, 2)
     assert check.shift_plan.bwd.offsets == (-1, -2)
-    opt = optax.adamw(3e-4)
-    rep = NamedSharding(mesh, P())
-    params = jax.eval_shape(
-        lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)
-    )
-    state = jax.eval_shape(opt.init, params)
-    params, state = jax.tree.map(
-        lambda s: _on(rep, s.shape, s.dtype), (params, state)
-    )
-    batch = _on(NamedSharding(mesh, P("dp", "cp")), (1, 4096), jnp.int32)
-    text = (
-        check.make_train_step(opt)
-        .lower(params, state, batch, batch, batch)
-        .compile()
-        .as_text()
-    )
+    text = _compile_train_step(
+        check, lambda: init_pattern_params(jax.random.PRNGKey(0), cfg),
+        optax.adamw(3e-4), 4096,
+    ).as_text()
     scopes = trace_reduce.hlo_scopes(text)
     # a layer's forward, remat's forward and the backward (the grouped
     # matmuls are tpu_custom_calls too: count the flex kernels by name)

@@ -135,7 +135,10 @@ CASES = {
 }
 
 
-def _step_text(name: str) -> str:
+def toy_model(name: str):
+    """(a toy model on one device, its parameters): the dense decoder or
+    a pattern form (``tests/test_models/test_train_step.py`` takes its
+    models here too)."""
     mesh = toy._mesh(1)
     if name == "llama":
         cfg = LlamaConfig(
@@ -146,14 +149,17 @@ def _step_text(name: str) -> str:
         model, _ = build_magi_llama(
             cfg, mesh, toy.TOTAL, qr, kr, ts, chunk_size=toy.CHUNK
         )
-        params = init_params(jax.random.PRNGKey(0), cfg)
-    else:
-        cfg = {
-            "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
-            "looped": _ouro()[1], "cca": _zaya()[1], "sambay": _sambay()[1],
-        }[name]
-        model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
-        params = init_pattern_params(jax.random.PRNGKey(0), cfg)
+        return model, init_params(jax.random.PRNGKey(0), cfg)
+    cfg = {
+        "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
+        "looped": _ouro()[1], "cca": _zaya()[1], "sambay": _sambay()[1],
+    }[name]
+    model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
+    return model, init_pattern_params(jax.random.PRNGKey(0), cfg)
+
+
+def _step_text(name: str) -> str:
+    model, params = toy_model(name)
     opt = optax.adamw(1e-3)
     batch = jnp.zeros((1, toy.TOTAL), jnp.int32)
     return (
