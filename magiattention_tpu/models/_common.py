@@ -2,12 +2,15 @@
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import telemetry
 from ..common.axes import cp_axis_names
+from ..ops.flex_attn import KEPT_NAMES
 from ..utils.instrument import named_scope
 
 
@@ -28,6 +31,41 @@ def masked_ce_sums(logits, labels):
     """(sum of CE over positions with label >= 0, count of them)."""
     tok_loss, valid = masked_ce_tokens(logits, labels)
     return tok_loss.sum(), valid.sum().astype(jnp.float32)
+
+
+def layer_under_remat(
+    make_layer, attn_params, kinds, *, remat: bool, applied_once: bool = True
+):
+    """``make_layer(attn_params)``, the layer function of a trunk, as
+    ``cfg.remat`` has it. ``attn_params``: the layer's ``FlexAttnParams``,
+    one or a dict of them; ``kinds``: the attention kind of each, in the
+    same form, as the counters name it.
+
+    Without ``remat`` the layer as it is. With it the layer under
+    ``jax.checkpoint``, which keeps the layer's inputs and, of a layer the
+    trunk applies once a step, its attention calls' out and compact lse:
+    the calls are told so (``FlexAttnParams.kept``) here where the policy
+    that saves their names is set, so the two cannot disagree. The
+    backward's recomputation then remakes the norm, the projections, rotary
+    and the layouts, which the backward kernel needs anyway, and launches
+    no forward kernel: a step runs it once a layer, not twice.
+
+    ``applied_once=False`` is a layer inside a scan over passes (the looped
+    trunk): what it kept would be held once a pass on shared weights, the
+    bytes times the passes, so it keeps its inputs alone and recomputes the
+    forward kernel (24 applications of 68 MB against 1.06 GB of room in the
+    looped cell; PERF.md section 6, PR 48)."""
+    if not remat:
+        return make_layer(attn_params)
+    if not applied_once:
+        return jax.checkpoint(make_layer(attn_params))
+    kept = jax.tree.map(
+        lambda p, kind: dataclasses.replace(p, kept=kind), attn_params, kinds
+    )
+    return jax.checkpoint(
+        make_layer(kept),
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES),
+    )
 
 
 def _can_place(mesh) -> bool:

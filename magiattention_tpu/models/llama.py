@@ -27,7 +27,7 @@ from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
 from ..ops.flex_attn import FlexAttnParams
-from ._common import masked_ce_sums
+from ._common import layer_under_remat, masked_ce_sums
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,8 +42,10 @@ class LlamaConfig:
     rope_theta: float = 500000.0
     dtype: str = "bfloat16"
     # rematerialize each decoder layer in backward (jax.checkpoint):
-    # activation memory drops from O(layers x t_loc x dim) to
-    # O(t_loc x dim) at ~1/3 extra FLOPs — the standard long-context
+    # activation memory drops from O(layers x t_loc x dim) to a layer's
+    # input and its attention output (O(t_loc x dim) each a layer) at ~1/3
+    # extra matmul FLOPs; the attention kernel is not run again
+    # (_common.layer_under_remat) — the standard long-context
     # memory/compute trade on TPU (HBM is the usual bottleneck)
     remat: bool = False
 
@@ -175,15 +177,14 @@ def forward_local(
     with named_scope("magi_embed"):
         x = params["embed"].astype(dt)[tokens]
 
-    def one_layer(x, pos, layer):
-        return _layer_local(
+    # under cfg.remat a layer saves its input and its attention call's out
+    # and lse; the rest (projections, rotary, FFN) recomputes in backward
+    one_layer = layer_under_remat(
+        lambda attn_params: lambda x, pos, layer: _layer_local(
             x, pos, layer, cfg, tables, plan, attn_params, axis_name, tp_axis
-        )
-
-    if cfg.remat:
-        # save only each layer's input; everything inside (attention,
-        # kernels, FFN) recomputes in backward
-        one_layer = jax.checkpoint(one_layer)
+        ),
+        attn_params, "full", remat=cfg.remat,
+    )
     for layer in params["layers"]:
         x = one_layer(x, pos, layer)
     with named_scope("magi_head"):

@@ -106,7 +106,7 @@ from ..parallel.dispatch import (
 from ..parallel.dist_attn import DistAttnPlan, dist_attn_local
 from ..utils.compat import shard_map
 from ..utils.instrument import named_scope
-from ._common import masked_ce_sums, masked_ce_tokens
+from ._common import layer_under_remat, masked_ce_sums, masked_ce_tokens
 from . import ssm
 from .llama import _rms_norm, _rope
 
@@ -1397,19 +1397,22 @@ def _attention_half(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
 
 
 def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
-               axis_name, shift_plan=None, index=None):
+               axis_name, shift_plan=None, index=None, applied_once=True):
     """``(x, pos, layer, carry=None) -> (x, stats)`` of one layer
     (:func:`_layer_local`). Under ``cfg.remat`` a layer keeps its inputs,
-    the carry among them, and recomputes the rest: what an earlier layer
-    handed on is never made again."""
-    one_layer = functools.partial(
-        _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
-        tables=tables, plans=plans, attn_params=attn_params,
-        axis_name=axis_name, shift_plan=shift_plan, index=index,
+    the carry among them, and (``applied_once``: every trunk but the
+    looped one) its attention call's out and lse, and recomputes the rest
+    (``_common.layer_under_remat``): what an earlier layer handed on is
+    never made again, and the forward kernel runs once."""
+    return layer_under_remat(
+        lambda attn_params: functools.partial(
+            _layer_local, cfg=cfg, layer_type=layer_type, ffn_type=ffn_type,
+            tables=tables, plans=plans, attn_params=attn_params,
+            axis_name=axis_name, shift_plan=shift_plan, index=index,
+        ),
+        attn_params, {kind: _SHORT[kind] for kind in attn_params},
+        remat=cfg.remat, applied_once=applied_once,
     )
-    if cfg.remat:  # save a layer's input; the rest recomputes
-        one_layer = jax.checkpoint(one_layer)
-    return one_layer
 
 
 def _diffusion_io(cfg: PatternConfig):
@@ -1483,8 +1486,9 @@ def _looped_trunk_local(params, tokens, pos, cfg: PatternConfig, tables,
     direction whatever ``n_loops`` is, and the weights' gradient is the
     scan's sum over passes."""
     one_layer = [
-        _one_layer(
-            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name
+        _one_layer(  # applied n_loops times: a pass keeps its inputs alone
+            cfg, layer_type, ffn_type, tables, plans, attn_params, axis_name,
+            applied_once=False,
         )
         for layer_type, ffn_type in zip(cfg.layer_types, cfg.ffn_types)
     ]

@@ -36,6 +36,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.custom_derivatives import SymbolicZero
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -180,6 +181,12 @@ class FlexAttnParams:
     # and table set these params serve, counted by the plan builder on
     # the host beside bwd_steps (dq_form). None = not counted
     bwd_unnamed_q: int | None = None
+    # the attention kind of a layer whose ``jax.checkpoint`` keeps this
+    # call's out and compact lse (models/_common.layer_under_remat, the
+    # only place that sets it, beside the policy that saves KEPT_NAMES);
+    # "": the call stands alone and its residual is the kernel's
+    # lane-replicated lse (_flex_attn_core_fwd)
+    kept: str = ""
 
     @property
     def out_jnp_dtype(self):
@@ -1548,35 +1555,62 @@ def _fwd_dispatch(
     return _fwd_pallas(q, k, v, sink2d, ftab, params, residual)
 
 
+# The names a kept call gives its out and its lse [hq, tqp]
+# (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
+# is ``save_only_these_names(*KEPT_NAMES)`` saves those two and nothing else
+# of the layer, so its recomputation remakes q, k and v and finds the
+# forward kernel's outputs there: that ``pallas_call`` is dead code in it
+KEPT_NAMES = ("magi_flex_out", "magi_flex_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _flex_attn_core(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
     """(out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp]). Undifferentiated
-    (evaluation, the forward under remat) no lane-replicated statistic is
-    written at all."""
+    (evaluation; a checkpointed layer's first forward, and where
+    ``params.kept`` its only one) no lane-replicated statistic is written
+    at all."""
     return _fwd_dispatch(q, k, v, sink2d, ftab, params)[:3]
 
 
 def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
+    """The residual beside q, k, v and out: the lse replicated over lanes,
+    as the differentiated forward kernel writes it and the backward kernel
+    reads it; under ``params.kept`` the compact lse, 1 / 128 of that, which
+    the checkpoint round the layer saves with out by name."""
     # symbolic_zeros: every argument arrives as a CustomVJPPrimal
     q, k, v, sink2d = q.value, k.value, v.value, sink2d.value
     ftab, btab = (tuple(t.value for t in tab) for tab in (ftab, btab))
-    out, lse, rowmax, lse_lanes = _fwd_dispatch(
-        q, k, v, sink2d, ftab, params, residual=True
+    out, lse, rowmax, lse_res = _fwd_dispatch(
+        q, k, v, sink2d, ftab, params, residual=not params.kept
     )
+    if params.kept:
+        from .. import telemetry
+
+        telemetry.record_flex_forward_kept(params.kept)
+        # the primal outputs are the named arrays too: what reads out
+        # after the call reads the saved one in the recomputation
+        out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
+        lse_res = lse
     return (out, lse, rowmax), (
         q,
         k,
         v,
         sink2d,
         out,
-        lse_lanes,
+        lse_res,
         ftab,
         btab,
     )
 
 
 def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
-    q, k, v, sink2d, out, lse_lanes, ftab, btab = residuals
+    q, k, v, sink2d, out, lse_res, ftab, btab = residuals
+    if params.kept:  # the compact lse: the kernel's operand is made here
+        lse = lse_res
+        with named_scope("magi_layout"):
+            lse_lanes = jnp.broadcast_to(lse[:, :, None], (*lse.shape, LANES))
+    else:
+        lse_lanes = lse_res
     # The lse cotangent is first-class (it folds into delta, _bwd_delta);
     # a model that never reads lse hands a symbolic zero, and then nothing
     # is subtracted. rowmax stays non-diff.
@@ -1593,7 +1627,8 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
     with named_scope("magi_bwd_delta"):
         if params.has_sink:
             # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
-            lse = lse_lanes[:, :, 0]
+            if not params.kept:
+                lse = lse_lanes[:, :, 0]
             sink = sink2d[:, :1]
             w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
             dsink = -(w * delta).sum(axis=1, keepdims=True)

@@ -139,9 +139,10 @@ class StageTables:
     def grid_steps(self, fwd_steps: int, bwd_steps: int) -> tuple[int, int, float]:
         """Steps one head group's kernels launch on the row-major grid
         (blocks x the params' static extents) and on the compact one (the
-        padded entries), the forward table walked twice (under remat a
-        training step runs the forward twice; before PR 43 the second walk
-        was dq's) and the backward table once: the weights the grid's two
+        padded entries), the forward table walked twice (before PR 43 the
+        second walk was dq's; until PR 48 a training step under remat ran
+        the forward twice, as the looped trunk's still does) and the
+        backward table once: the weights the grid's two
         prices were measured with (``tuning/cost_model.py``), kept so that
         no plan's grid moves with the backward's form. And how many of
         either do work:

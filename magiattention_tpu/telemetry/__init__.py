@@ -69,6 +69,7 @@ from .collectors import (  # noqa: F401
     record_degraded_path,
     record_dispatch_meta,
     record_flex_bwd_form,
+    record_flex_forward_kept,
     record_flex_dead_step_share,
     record_flex_stepped_tile_share,
     record_mask_step,
@@ -362,6 +363,7 @@ __all__ = [
     "record_degraded_path",
     "record_dispatch_meta",
     "record_flex_bwd_form",
+    "record_flex_forward_kept",
     "record_flex_dead_step_share",
     "record_flex_stepped_tile_share",
     "record_mask_step",

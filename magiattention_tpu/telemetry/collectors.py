@@ -142,6 +142,12 @@ M_MASK_STEP = "magi_mask_step"
 # attention plans a model builder made, by attention kind
 # (models/pattern.py: one dispatch, a plan per kind): {kind=sliding|full}
 M_MODEL_ATTN_PLANS = "magi_model_attn_plans_total"
+# attention calls whose forward rule was traced in the kept form
+# (ops/flex_attn._flex_attn_core_fwd under FlexAttnParams.kept), by the
+# layer's attention kind: {kind=sliding|full}. A gradient traced once
+# counts a trunk's once-applied attention layers; the looped trunk's
+# layers, kept by nobody, count 0
+M_FLEX_FORWARD_KEPT = "magi_flex_forward_kept_total"
 # gauges — an expert layer's load on the experts THIS rank holds, from a
 # step the caller read on the host (MagiPattern.record_expert_load):
 # token-expert pairs computed here, and the busiest held expert's pairs
@@ -1223,6 +1229,15 @@ def record_model_attn_plan(kind: str) -> None:
     if not _enabled():
         return
     get_registry().counter_inc(M_MODEL_ATTN_PLANS, kind=kind)
+
+
+def record_flex_forward_kept(kind: str) -> None:
+    """One attention call of kind ``kind`` differentiated in the kept form
+    (``ops/flex_attn._flex_attn_core_fwd``, while jax traces the gradient:
+    never inside a compiled step)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_FLEX_FORWARD_KEPT, kind=kind)
 
 
 def record_moe_load(layer: int, counts) -> None:

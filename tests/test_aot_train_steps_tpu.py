@@ -305,10 +305,12 @@ def test_cca_train_step_at_two_key_value_heads(topo):
         optax.adamw(3e-4), 4096,
     ).as_text()
     scopes = trace_reduce.hlo_scopes(text)
-    # a layer's forward, remat's forward and the backward (the grouped
-    # matmuls are tpu_custom_calls too: count the flex kernels by name)
+    # a layer's forward and its backward: the checkpoint keeps the call's
+    # out and lse, so remat launches no forward of its own (ISSUE 48; the
+    # grouped matmuls are tpu_custom_calls too: count the flex kernels by
+    # name)
     flex = [n for n in scopes if n.startswith("magi_flex_")]
-    assert len(flex) == 3 * cfg.n_layers, flex
+    assert len(flex) == 2 * cfg.n_layers, flex
     mix = [s for s in scopes.values() if "magi_cca_mix" in s]
     assert mix and not [s for s in mix if s.endswith("/gather")]
     assert any("magi_moe_router" in s for s in scopes.values())

@@ -107,6 +107,27 @@ def _census(jaxpr, into=None):
     return into
 
 
+def _kernels_by_name(jaxpr, into=None):
+    """``pallas_call`` equations by the kernel's name, through every nested
+    jaxpr but the kernels' own bodies: a scan's body counts once."""
+    into = collections.Counter() if into is None else into
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            into[eqn.params["name"]] += 1
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernels_by_name(sub, into)
+    return into
+
+
+def _gradient_jaxpr(model, params):
+    """The loss-and-gradient program of a built model, traced once."""
+    batch = jnp.zeros((1, TOTAL), jnp.int32)
+    return jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(
+        params, batch, batch, batch, model.sharded_tables()
+    ).jaxpr
+
+
 def _pin(cfg, params, monkeypatch):
     """(parameter names and shapes, the loss-and-gradient program's
     equations by primitive with the attention call stubbed out: what
@@ -120,12 +141,9 @@ def _pin(cfg, params, monkeypatch):
     )
     with jax.enable_x64(False):
         model, _meta = build_magi_pattern(cfg, _mesh(1), CU, chunk_size=CHUNK)
-        batch = jnp.zeros((1, TOTAL), jnp.int32)
-        jaxpr = jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(
-            params, batch, batch, batch, model.sharded_tables()
-        )
+        jaxpr = _gradient_jaxpr(model, params)
     shapes = {
         jax.tree_util.keystr(k): tuple(v.shape)
         for k, v in jax.tree_util.tree_leaves_with_path(params)
     }
-    return shapes, dict(_census(jaxpr.jaxpr))
+    return shapes, dict(_census(jaxpr))

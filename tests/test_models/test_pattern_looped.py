@@ -21,8 +21,9 @@ from magiattention_tpu.models.pattern import (
 )
 from tests.test_benchmarks import looped_faults
 from tests.test_models.pattern_harness import (
-    CHUNK, CU, TOTAL, _allow_full, _census, _mesh, _model_loss_and_grads,
-    _pin, _worst, computed_once, unfaulted_loss_and_grads,
+    CHUNK, CU, TOTAL, _allow_full, _census, _gradient_jaxpr, _mesh,
+    _model_loss_and_grads, _pin, _worst, computed_once,
+    unfaulted_loss_and_grads,
 )
 from tests.test_models.test_pattern import CFG
 from tests.test_models.test_pattern_latent import _glm
@@ -107,11 +108,7 @@ def test_the_scanned_pass_is_the_unrolled_loop(params, remat, monkeypatch):
 
 def _pallas_calls(cfg, params):
     model, _meta = build_magi_pattern(cfg, _mesh(1), CU, chunk_size=CHUNK)
-    batch = jnp.zeros((1, TOTAL), jnp.int32)
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(model.loss_fn))(
-        params, batch, batch, batch, model.sharded_tables()
-    )
-    return _census(jaxpr.jaxpr)
+    return _census(_gradient_jaxpr(model, params))
 
 
 def test_the_program_does_not_grow_with_the_passes(params):

@@ -194,6 +194,10 @@ class KernelCase:
     # arrays kept for the process's life, so only the tests that read it
     # ask. A file's tests that share configurations all ask, or none
     watch: bool = False
+    # ``FlexAttnParams.kept``: the form a checkpointed layer's call takes
+    # (the residual is the compact lse, named with out; the backward makes
+    # the lanes). The values are the bare call's
+    kept: str = ""
     # q and k scaled by ``amp``; ``sign`` -1 makes every logit negative
     amp: float = 1.0
     sign: int = 0
@@ -206,11 +210,11 @@ class KernelCase:
     def oracle_key(self) -> "KernelCase":
         """The case with what the dense jnp backend cannot see set to its
         default: which body walks which grid, the dtype the kernels round
-        to, the sentinel entries the tables are padded with, and whether
-        anyone watches the kernels."""
+        to, the sentinel entries the tables are padded with, whether
+        anyone watches the kernels, and which residual the call keeps."""
         return dataclasses.replace(
             self, head_block=1, grid="row_major", dtype="float32",
-            traced=False, pad=0, entry_pad=8, watch=False,
+            traced=False, pad=0, entry_pad=8, watch=False, kept="",
         )
 
 
@@ -276,7 +280,7 @@ def _params(case: KernelCase, meta):
         softcap=float(case.softcap), has_sink=case.sink, out_dtype=case.dtype,
         interpret=True, head_block=case.head_block,
         fwd_steps=meta.fwd_steps, bwd_steps=meta.bwd_steps, grid=case.grid,
-        mask_step=fa.bounds_mask_step(meta.slice_bounds),
+        mask_step=fa.bounds_mask_step(meta.slice_bounds), kept=case.kept,
     )
 
 
