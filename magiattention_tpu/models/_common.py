@@ -130,8 +130,12 @@ def _plan_on_dispatch(
     from ..parallel.dist_attn import build_dist_attn_plan, make_attn_params
 
     cp_size, cp_mesh_shape = _cp_geometry(mesh, cp_axis)
+    # the value heads' width where the kernels' is not ``head_dim``
+    # (pattern.KernelHeads under latent attention); None: one width
+    v_head_dim = getattr(cfg, "v_head_dim", None) or None
     telemetry.annotate_span(  # what the kernels are handed, every model's
-        heads_q=cfg.n_heads, heads_kv=cfg.n_kv_heads, head_dim=cfg.head_dim
+        heads_q=cfg.n_heads, heads_kv=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        v_head_dim=v_head_dim or cfg.head_dim,
     )
     if telemetry.enabled():  # what the mask is made of
         from ..common.enum import AttnMaskType
@@ -157,7 +161,7 @@ def _plan_on_dispatch(
         q_ranges.to_naive_ranges(),
         k_ranges.to_naive_ranges(),
         attn_type_map,
-        total_seqlen, cp_size, block_q, block_k,
+        total_seqlen, cp_size, block_q, block_k, v_head_dim,
     )
     plan = build_dist_attn_plan(
         mq,
@@ -177,6 +181,7 @@ def _plan_on_dispatch(
             out_dtype=cfg.dtype,
             interpret=interpret,
             head_block=hb,
+            v_head_dim=v_head_dim,
         )
     return plan, attn_params
 
@@ -278,7 +283,7 @@ def plan_flex_attn_on_dispatch(
 
 def resolve_harness_blocking(
     cfg, mesh, tp_axis, q_naive, k_naive, attn_type_map,
-    total_seqlen, cp_size, block_q, block_k,
+    total_seqlen, cp_size, block_q, block_k, v_head_dim=None,
 ) -> tuple[int, int, int]:
     """(block_q, block_k, head_block) for a model-harness plan — ONE
     policy shared by every bundle builder (ISSUE 2): caller args win;
@@ -308,6 +313,7 @@ def resolve_harness_blocking(
                 hkv,
                 cfg.head_dim,
                 str(cfg.dtype),
+                v_head_dim,
             )
         if tuned is not None:
             return tuned

@@ -81,6 +81,19 @@ under a learned ``lambda`` through a norm of its own
 (:func:`_diff_heads`, :func:`_diff_combine`); LayerNorm with a bias in
 place of the RMS norm (``norm_form``), no position encoding.
 
+``hc_mult > 0`` widens the residual path to that many streams under
+manifold-constrained hyper-connections (Xing4.0 / ``xing4_config``;
+arXiv:2512.24880 over arXiv:2409.19606): the state between half-layers is
+``[t, hc_mult x dim]``, stream ``i`` in lanes ``[i dim, (i + 1) dim)``;
+each half-layer reads ONE hidden state as a learned per-token mix of the
+streams and writes back through a per-token ``hc_mult x hc_mult`` matrix
+that 20 Sinkhorn rounds make doubly stochastic (:func:`_mhc_coef`,
+:func:`_mhc_read`, :func:`_mhc_write`; docs/hyper_connections.md). The
+same configuration's latent attention has value heads narrower than its
+key heads (``v_head_dim`` 128 beside 128 + 64): the kernels take both
+widths (``KernelHeads.v_head_dim``), and YaRN stretches the rotary lanes
+(``rope_yarn``).
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -175,10 +188,11 @@ class PatternConfig:
     # state), LATENT or CCA. Under CCA q and k are mixed by two causal
     # convolutions of ``conv_taps`` taps along the document and the second
     # half of the value heads reads the token before; rotary on the FIRST
-    # ``rope_head_dim`` of a head. Under LATENT ``head_dim`` is what the
-    # kernels see, the same for q, k and v: ``head_dim - rope_head_dim`` without
-    # position and ``rope_head_dim`` rotary, in every layer whatever
-    # ``rope_kinds`` says; ``n_kv_heads == n_heads``
+    # ``rope_head_dim`` of a head. Under LATENT ``head_dim`` is a query's
+    # and a key's width (a value's too unless ``v_head_dim`` says another):
+    # ``head_dim - rope_head_dim`` without position and ``rope_head_dim``
+    # rotary, in every layer whatever ``rope_kinds`` says;
+    # ``n_kv_heads == n_heads``
     attn_form: str = GQA
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -215,6 +229,24 @@ class PatternConfig:
     # a layer's index in the published model where the layers are a cut
     # of it (DIFF's lambda_init reads it); (): the layer's own place
     layer_index: tuple[int, ...] = ()
+    # LATENT: the value heads' width where it is not ``head_dim`` (the key
+    # heads': DeepSeek-V3's 128 beside 128 + 64); 0: ``head_dim``
+    v_head_dim: int = 0
+    # YaRN on the rotary lanes (arXiv:2309.00071): (factor, beta_fast,
+    # beta_slow, original_max_position_embeddings); None: plain rotary
+    rope_yarn: tuple[float, float, float, int] | None = None
+    # the attention's softmax scale where it is not ``head_dim ** -0.5``
+    # (YaRN's ``mscale`` squared rides on it)
+    softmax_scale: float | None = None
+    # residual streams under manifold-constrained hyper-connections; 0: one
+    # stream, the plain residual path. The Sinkhorn rounds that make a
+    # half-layer's stream-to-stream matrix doubly stochastic, the epsilon
+    # under their sums, and the clamp on the matrix's logits
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple[float, float] = (-30.0, 30.0)
+    hc_dtype: str = "float32"  # bfloat16: the tests' and the check's control
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
@@ -270,6 +302,19 @@ class PatternConfig:
             raise ValueError(
                 "diffusion over blocks under a window, an MTP module, a "
                 "loop or cca: no reference states one"
+            )
+        if self.v_head_dim and self.attn_form != LATENT:
+            raise ValueError(
+                "v_head_dim goes with latent attention: the other forms "
+                "cut q, k and v out of one width"
+            )
+        if self.hc_mult and (
+            self.attn_form != LATENT or self.n_loops > 1
+            or self.diffusion_block or self.post_norms
+        ):
+            raise ValueError(
+                "residual streams (hc_mult) are stated for the latent form "
+                "without loops, diffusion or post-norms"
             )
         if self.n_loops < 1:
             raise ValueError(f"n_loops {self.n_loops}: at least one pass")
@@ -347,6 +392,13 @@ class PatternConfig:
         """What the flex kernels are planned and tuned for: this
         configuration, or under DIFF its two head widths made one
         (:class:`KernelHeads`)."""
+        if self.attn_form == LATENT and self.v_head_dim:
+            return KernelHeads(
+                n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+                head_dim=self.kernel_qk_lanes, dtype=self.dtype,
+                softmax_scale=self.softmax_scale or self.head_dim ** -0.5,
+                v_head_dim=self.v_head_dim,
+            )
         if self.attn_form != DIFF:
             return self
         return KernelHeads(
@@ -354,6 +406,18 @@ class PatternConfig:
             head_dim=2 * self.head_dim, dtype=self.dtype,
             softmax_scale=self.head_dim ** -0.5,
         )
+
+    @property
+    def kernel_qk_lanes(self) -> int:
+        """The lanes q and k ride into the kernels on under LATENT with a
+        value width of its own: ``head_dim`` up to a multiple of
+        :data:`LATENT_QK_LANES`, zeros past the head's own (the logits are
+        the head's; the published 192 is not padded)."""
+        return -(-self.head_dim // LATENT_QK_LANES) * LATENT_QK_LANES
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
 
     @property
     def jnp_dtype(self):
@@ -393,16 +457,28 @@ class PatternConfig:
 @dataclasses.dataclass(frozen=True)
 class KernelHeads:
     """The heads the flex kernels see where they are not the model's own
-    (``_common.plan_flex_attn`` reads these five). Under DIFF a query
+    (``_common.plan_flex_attn`` reads these six). Under DIFF a query
     and a key head of ``head_dim`` lanes ride a kernel head of twice
     that, zeros in the other half, beside a value pair that fills it;
-    the softmax scale stays the published head's."""
+    the softmax scale stays the published head's. Under LATENT with a
+    value width of its own q and k are ``head_dim`` lanes (the published
+    192), v and out ``v_head_dim``."""
 
     n_heads: int
     n_kv_heads: int
     head_dim: int
     dtype: str
     softmax_scale: float
+    v_head_dim: int = 0  # 0: head_dim
+
+
+# What q and k of a latent head with a value width of its own are padded
+# to a multiple of before the kernels: half a vreg's lanes, so the published
+# 192 rides as it is, a block of one and a half vregs. On the chip that read
+# 0.78% more tokens a second than 192 on 256 lanes, zeros in the last 64
+# (12,496 against 12,399 on one seed, 655.9 against 660.9 ms a step; my chip
+# runs, PR 49, PERF.md section 6); 128 here is the padded form.
+LATENT_QK_LANES = 64
 
 
 def afmoe_config(
@@ -454,31 +530,31 @@ def glm4_moe_lite_config(
     remat: bool = False,
     expert_range: tuple[int, int] | None = None,
     vocab_size: int | None = None,
+    **more,
 ) -> PatternConfig:
     """A published ``glm4_moe_lite`` ``config.json`` (GLM-4.7-Flash) as a
     pattern: latent attention in every layer, all of them
     ``full_attention`` (one plan), ``first_k_dense_replace`` dense layers
     and then sparse experts under the ``noaux_tc`` router at one group
-    (``route``), ``num_nextn_predict_layers`` MTP modules.
-    ``expert_range`` and ``vocab_size`` give one rank's share, as in
-    :func:`afmoe_config`. ``mtp_loss_weight`` is no published key: a
-    configuration file may state it."""
+    (``route``), ``num_nextn_predict_layers`` MTP modules. A
+    ``v_head_dim`` that is not the key heads' ``qk_nope_head_dim +
+    qk_rope_head_dim`` is the value heads' own width (DeepSeek-V3's 128
+    beside 192). ``expert_range`` and ``vocab_size`` give one rank's
+    share, as in :func:`afmoe_config`. ``mtp_loss_weight`` is no published
+    key: a configuration file may state it. ``more``: fields another
+    configuration of the family sets on top (:func:`xing4_config`)."""
     n = int(hf["num_hidden_layers"])
     n_dense = int(hf["first_k_dense_replace"])
     heads = int(hf["num_attention_heads"])
     nope, rope = int(hf["qk_nope_head_dim"]), int(hf["qk_rope_head_dim"])
-    if int(hf["v_head_dim"]) != nope + rope:
-        raise ValueError(
-            f"v_head_dim {hf['v_head_dim']} differs from the key heads' "
-            f"{nope} + {rope}: the flex kernels take one head width"
-        )
+    v_dim = int(hf["v_head_dim"])
     if int(hf["num_key_value_heads"]) != heads:
         raise ValueError(
             "latent attention up-projects one key-value head a query head"
         )
     if int(hf.get("n_group", 1)) != 1 or int(hf.get("topk_group", 1)) != 1:
         raise ValueError("group-limited routing (n_group > 1) is not built")
-    return PatternConfig(
+    return PatternConfig(**{**dict(
         vocab_size=int(vocab_size or hf["vocab_size"]),
         dim=int(hf["hidden_size"]),
         n_heads=heads,
@@ -506,8 +582,78 @@ def glm4_moe_lite_config(
         q_lora_rank=int(hf["q_lora_rank"]),
         kv_lora_rank=int(hf["kv_lora_rank"]),
         rope_head_dim=rope,
+        v_head_dim=0 if v_dim == nope + rope else v_dim,
         n_mtp=int(hf.get("num_nextn_predict_layers", 0)),
         mtp_loss_weight=float(hf.get("mtp_loss_weight", 0.3)),
+    ), **more})
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 at a
+    factor of 1 or less)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def xing4_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``xing4_0`` ``config.json`` (Xing4.0-29B-A4B) as a
+    pattern: :func:`glm4_moe_lite_config`'s decoder (latent attention, the
+    ``noaux_tc`` router, MTP modules) with value heads of ``v_head_dim``,
+    ``hc_mult`` residual streams under manifold-constrained
+    hyper-connections (``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min`` / ``_max``) and YaRN ``rope_scaling`` on the
+    rotary lanes. As DeepSeek-V3 has it, ``mscale`` over ``mscale_all_dim``
+    scales cos and sin (1 here, and anything else raises: no reference
+    states it) and ``mscale_all_dim``'s factor squared goes on the softmax
+    scale.
+
+    ``flat_expert_rows`` is set, as ISSUE 49 asks of a cell whose rate
+    spreads between seeds: under AdamW at 3e-4 the seeded router leaves the
+    held eighth of the experts inside a window (4,400 pairs a layer on the
+    seed's weights, 130 to 465 as the window closes), so a step's grouped
+    matmuls would follow the seed and the step count; with every chunk run
+    whole they take the ``top_k t`` pair rows a rank of the deployment
+    computes (PERF.md section 6, PR 49)."""
+    more = dict(
+        flat_expert_rows=True,
+        hc_mult=int(hf["hc_mult"]),
+        hc_sinkhorn_iters=int(hf["hc_sinkhorn_iters"]),
+        hc_eps=float(hf["hc_eps"]),
+        hc_clamp=(
+            float(hf["mhc_h_res_clamp_min"]), float(hf["mhc_h_res_clamp_max"])
+        ),
+    )
+    scaling = hf.get("rope_scaling")
+    if scaling:
+        if scaling["type"] != "yarn":
+            raise ValueError(f"rope_scaling {scaling['type']!r} is not built")
+        factor = float(scaling["factor"])
+        all_dim = float(scaling.get("mscale_all_dim", 0.0))
+        if yarn_mscale(factor, float(scaling.get("mscale", 1.0))) != (
+            yarn_mscale(factor, all_dim)
+        ):
+            raise ValueError(
+                "YaRN with mscale != mscale_all_dim scales cos and sin: no "
+                "reference states it"
+            )
+        head_dim = int(hf["qk_nope_head_dim"]) + int(hf["qk_rope_head_dim"])
+        more.update(
+            rope_yarn=(
+                factor, float(scaling["beta_fast"]),
+                float(scaling["beta_slow"]),
+                int(scaling["original_max_position_embeddings"]),
+            ),
+            softmax_scale=head_dim ** -0.5 * yarn_mscale(factor, all_dim) ** 2,
+        )
+    return glm4_moe_lite_config(
+        hf, dtype=dtype, remat=remat, expert_range=expert_range,
+        vocab_size=vocab_size, **more,
     )
 
 
@@ -781,9 +927,9 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str,
             # a head's k_nope columns, then its v columns
             "wkv_b": dense(
                 ka[3],
-                (cfg.kv_lora_rank, cfg.n_heads * (nope + cfg.head_dim)),
+                (cfg.kv_lora_rank, cfg.n_heads * (nope + cfg.value_dim)),
             ),
-            "wo": dense(ka[4], (hq, cfg.dim)),
+            "wo": dense(ka[4], (cfg.n_heads * cfg.value_dim, cfg.dim)),
         }
     else:
         layer = {
@@ -869,7 +1015,42 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str,
             layer["ws_gate"] = dense(k[9], (cfg.dim, sh))
             layer["ws_up"] = dense(k[10], (cfg.dim, sh))
             layer["ws_down"] = dense(k[11], (sh, cfg.dim))
+    if cfg.hc_mult:
+        for j, half in enumerate(("hc_attn", "hc_ffn")):
+            layer[half] = _init_mhc(jax.random.fold_in(key, 6 + j), cfg)
     return layer
+
+
+# The seed's draws of a half-layer's stream mixer (no published key states
+# them; "assumed" in the configuration's file). ``phi`` is drawn so that
+# ``m = x_hat phi / rms(x_hat)`` spreads by HC_M_STD whatever the width:
+# N(0, HC_M_STD / sqrt(n dim)), which is N(0, 0.02) at the published 4 x
+# 3,584. With ``alpha`` 0.5 and 4 on the diagonal of ``b``'s matrix part the
+# mixing matrix of the seed's weights is neither the identity nor uniform
+# (it leaves both by more than 0.05 in the mean) and differs from token to
+# token, so a program that dropped the matrix, or made one for all tokens,
+# would not pass the check.
+HC_M_STD = 2.4
+HC_ALPHA = 0.5
+HC_B_RES_DIAG = 4.0
+
+
+def _init_mhc(key: jax.Array, cfg: PatternConfig) -> dict:
+    """One half-layer's mixer, float32: ``phi`` [n dim, n^2 + 2 n] (columns:
+    ``H_pre``'s n, ``H_post``'s n, then ``H_res``'s n x n row by row),
+    ``b`` of that length, ``alpha`` [3] (one gate a group of columns)."""
+    n = cfg.hc_mult
+    width = n * cfg.dim
+    k_phi, k_b = jax.random.split(key)
+    b = 0.02 * jax.random.normal(k_b, (n * n + 2 * n,), jnp.float32)
+    b = b.at[2 * n :].add(HC_B_RES_DIAG * jnp.eye(n).reshape(-1))
+    return {
+        "phi": (HC_M_STD / np.sqrt(width)) * jax.random.normal(
+            k_phi, (width, n * n + 2 * n), jnp.float32
+        ),
+        "b": b,
+        "alpha": jnp.full((3,), HC_ALPHA, jnp.float32),
+    }
 
 
 def init_pattern_params(rng: jax.Array, cfg: PatternConfig) -> dict:
@@ -1089,31 +1270,80 @@ def _expert_ffn(h, layer: dict, cfg: PatternConfig, r=None):
 
 
 def _latent_qkv(h, pos, layer: dict, cfg: PatternConfig):
-    """Latent attention's q, k, v [t, n_heads, head_dim] from the normed
-    hidden state: what the kernels are handed is expanded, every head its
-    own ``k_nope`` and ``v`` and a copy of the one rotary key."""
+    """Latent attention's q, k [t, n_heads, head_dim] and v [t, n_heads,
+    value_dim] from the normed hidden state: what the kernels are handed
+    is expanded, every head its own ``k_nope`` and ``v`` and a copy of the
+    one rotary key. With a value width of its own q and k come on
+    ``cfg.kernel_qk_lanes`` lanes, zeros past ``head_dim`` where that is
+    more."""
     dt = cfg.jnp_dtype
     t, heads, eps = h.shape[0], cfg.n_heads, cfg.rms_eps
     rope, nope = cfg.rope_head_dim, cfg.head_dim - cfg.rope_head_dim
+    pad = cfg.kernel_qk_lanes - cfg.head_dim if cfg.v_head_dim else 0
+    zeros = [jnp.zeros((t, heads, pad), dt)] if pad else []
 
-    def rot(x):
-        return _rope(x, pos, cfg.rope_theta, rope)
+    if cfg.rope_yarn is None:
+        def rot(x):
+            return _rope(x, pos, cfg.rope_theta, rope)
+    else:
+        freqs = jnp.asarray(yarn_freqs(cfg.rope_theta, rope, *cfg.rope_yarn))
+
+        def rot(x):
+            return _rope_at(x, pos, freqs)
 
     with named_scope("magi_mla_q"):
         c_q = _rms_norm(h @ layer["wq_a"].astype(dt), layer["q_a_norm"], eps)
         q = (c_q @ layer["wq_b"].astype(dt)).reshape(t, heads, cfg.head_dim)
-        q = jnp.concatenate([q[..., :nope], rot(q[..., nope:])], axis=-1)
+        q = jnp.concatenate(
+            [q[..., :nope], rot(q[..., nope:]), *zeros], axis=-1
+        )
     with named_scope("magi_mla_kv"):
         c = h @ layer["wkv_a"].astype(dt)
         c_kv, k_rope = c[:, : cfg.kv_lora_rank], c[:, cfg.kv_lora_rank :]
         kv = (
             _rms_norm(c_kv, layer["kv_a_norm"], eps)
             @ layer["wkv_b"].astype(dt)
-        ).reshape(t, heads, nope + cfg.head_dim)
+        ).reshape(t, heads, nope + cfg.value_dim)
         k_rope = jnp.broadcast_to(rot(k_rope[:, None, :]), (t, heads, rope))
-        k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], k_rope, *zeros], axis=-1)
         v = kv[..., nope:]
     return q, k, v
+
+
+def yarn_freqs(theta: float, rope_dim: int, factor: float, beta_fast: float,
+               beta_slow: float, original_max: int) -> np.ndarray:
+    """The rotary lanes' angular frequencies under YaRN (arXiv:2309.00071,
+    as DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding`` has them), float32
+    [rope_dim / 2]: ``f_j = theta^(-2j / rope_dim)``; a pair that turns more
+    than ``beta_fast`` times inside the original context keeps ``f_j``, one
+    that turns fewer than ``beta_slow`` times takes ``f_j / factor``, and
+    between the two pair indices (the first rounded down, the second up,
+    inside [0, rope_dim - 1]) a linear ramp blends them."""
+    half = rope_dim // 2
+    j = np.arange(half, dtype=np.float64)
+    f = theta ** (-j / half)
+
+    def pair_turning(beta):  # the pair index that turns beta times
+        return rope_dim * np.log(original_max / (2 * np.pi * beta)) / (
+            2 * np.log(theta)
+        )
+
+    lo = max(int(np.floor(pair_turning(beta_fast))), 0)
+    hi = min(int(np.ceil(pair_turning(beta_slow))), rope_dim - 1)
+    ramp = np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (f * (1.0 - ramp) + f / factor * ramp).astype(np.float32)
+
+
+def _rope_at(x, pos, freqs):
+    """``llama._rope``'s half-split rotation of x [t, h, 2 len(freqs)] at
+    given angular frequencies."""
+    half = freqs.shape[0]
+    angles = pos.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos, sin = jnp.cos(angles)[:, None, :], jnp.sin(angles)[:, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
+    ).astype(x.dtype)
 
 
 def _qk_mean(q, k):
@@ -1263,9 +1493,31 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
     ``cfg.kv_layer`` as the kernels take them. The carry this layer
     hands on, what it was handed and what it made, leaves under
     ``carry`` of the stats for the caller to pass to the next.
-    ``index``: the layer's place in ``cfg.layer_types``."""
-    dt = cfg.jnp_dtype
+    ``index``: the layer's place in ``cfg.layer_types``. Under
+    ``cfg.hc_mult`` ``x`` is the streams' state [t, hc_mult x dim] and each
+    half reads and writes it through its mixer (:func:`_mhc_half`)."""
     carry = dict(carry or {})
+    attn = dict(
+        cfg=cfg, layer_type=layer_type, tables=tables, plans=plans,
+        attn_params=attn_params, axis_name=axis_name, shift_plan=shift_plan,
+        index=index,
+    )
+    if cfg.hc_mult:
+        x = _mhc_half(
+            x, layer["hc_attn"], cfg,
+            lambda u: _attention_out(u, pos, layer, carry, **attn),
+        )
+        stats = {}
+
+        def ffn(u):
+            out, more = _ffn_out(u, layer, carry, cfg=cfg, ffn_type=ffn_type)
+            stats.update(more)
+            return out
+
+        x = _mhc_half(x, layer["hc_ffn"], cfg, ffn)
+        if carry:
+            stats["carry"] = carry
+        return x, stats
     if layer_type == SSM:
         with named_scope("magi_proj"):
             h = _norm(x, layer, "attn_norm", cfg)
@@ -1286,14 +1538,21 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
             h = _norm(x, layer, "attn_norm", cfg)
             x = x + ssm.gmu(h, carry["m"], layer, cfg)
     else:
-        x = _attention_half(
-            x, pos, layer, carry, cfg=cfg, layer_type=layer_type,
-            tables=tables, plans=plans, attn_params=attn_params,
-            axis_name=axis_name, shift_plan=shift_plan, index=index,
-        )
+        x = _attention_half(x, pos, layer, carry, **attn)
 
-    # the FFN half; an expert layer's magi_moe_* scopes are siblings
-    # between its two magi_ffn blocks, so magi_ffn holds no expert
+    out, stats = _ffn_out(x, layer, carry, cfg=cfg, ffn_type=ffn_type)
+    if carry:
+        stats["carry"] = carry
+    with named_scope("magi_ffn"):
+        return x + out, stats
+
+
+def _ffn_out(x, layer, carry, *, cfg, ffn_type):
+    """(the FFN half's output on ``x``, norm first, before the residual
+    sum; the expert layer's routing stats). An MLP router's state goes
+    into ``carry``. An expert layer's magi_moe_* scopes are siblings
+    between its two magi_ffn blocks, so magi_ffn holds no expert."""
+    dt = cfg.jnp_dtype
     with named_scope("magi_ffn"):
         h = _norm(x, layer, "mlp_norm", cfg)
     stats = {}
@@ -1306,18 +1565,129 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
         out, stats = _expert_ffn(h, layer, cfg, carry.get("r"))
         if "router_state" in stats:
             carry["r"] = stats.pop("router_state")
-    if carry:
-        stats["carry"] = carry
     with named_scope("magi_ffn"):
         if cfg.post_norms:
             out = _rms_norm(out, layer["post_mlp_norm"], cfg.rms_eps)
-        return x + out, stats
+    return out, stats
 
 
-def _attention_half(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
-                    attn_params, axis_name, shift_plan, index):
-    """``x`` + the attention half of a SLIDING, FULL or CROSS layer; the
-    keys and values ``cfg.kv_layer`` makes go into ``carry``."""
+# ---------------------------------------------------------------------------
+# manifold-constrained hyper-connections: the residual path as streams
+# ---------------------------------------------------------------------------
+
+
+def _mhc_coef(x, w: dict, cfg: PatternConfig):
+    """A half-layer's per-token coefficients from the streams' state ``x``
+    [t, n dim]: (``h_pre`` [n, t], ``h_post`` [n, t], ``h_res`` [n, n, t]),
+    in ``cfg.hc_dtype`` (float32; tokens along the last axis, so that the
+    n x n matrices of a sequence are whole vregs and not one padded tile a
+    token).
+
+    ``m = (x phi) rsqrt(mean(x^2) + eps)``: the projection first, the
+    norm's factor after (one pass over ``x`` gives both; the norm's weight
+    is folded into ``phi``). ``h_pre = sigmoid(alpha_1 m[:n] + b[:n])``,
+    ``h_post = 2 sigmoid(alpha_2 m[n:2n] + b[n:2n])``; the rest, an n x n
+    matrix row by row, times ``alpha_3`` plus ``b``'s, clamped, through
+    ``exp`` and ``hc_sinkhorn_iters`` rounds of (rows over their sum +
+    ``hc_eps``, then columns over theirs): doubly stochastic, so the write
+    neither grows nor shrinks what the streams carry."""
+    n, cdt = cfg.hc_mult, jnp.dtype(cfg.hc_dtype)
+    t = x.shape[0]
+    with named_scope("magi_mhc_coef"):
+        xc = x.astype(cdt)
+        m = jax.lax.dot_general(  # [n^2 + 2n, t]
+            w["phi"].astype(cdt), xc, (((0,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # float32 means float32
+            preferred_element_type=cdt,
+        )
+        m = m * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1) + cfg.rms_eps)[None]
+        a, b = w["alpha"].astype(cdt), w["b"].astype(cdt)[:, None]
+        h_pre = jax.nn.sigmoid(a[0] * m[:n] + b[:n])
+        h_post = 2.0 * jax.nn.sigmoid(a[1] * m[n : 2 * n] + b[n : 2 * n])
+        lo, hi = cfg.hc_clamp
+        mat = jnp.exp(jnp.clip(a[2] * m[2 * n :] + b[2 * n :], lo, hi))
+        mat = mat.reshape(n, n, t)
+        eps = jnp.asarray(cfg.hc_eps, cdt)
+        for _ in range(cfg.hc_sinkhorn_iters):
+            mat = mat / (mat.sum(axis=1, keepdims=True) + eps)  # rows
+            mat = mat / (mat.sum(axis=0, keepdims=True) + eps)  # columns
+    return h_pre, h_post, mat
+
+
+def _streams(x, n: int):
+    """The n streams [t, dim] of a state [t, n dim]."""
+    dim = x.shape[1] // n
+    return [x[:, i * dim : (i + 1) * dim] for i in range(n)]
+
+
+def _mhc_read(x, h_pre, cfg: PatternConfig):
+    """``u = sum_i h_pre[i] x_i`` [t, dim]: what a half-layer's function
+    sees of the streams, summed in float32, in the model's dtype."""
+    with named_scope("magi_mhc_read"):
+        u = sum(
+            h[:, None].astype(jnp.float32) * xi.astype(jnp.float32)
+            for h, xi in zip(h_pre, _streams(x, cfg.hc_mult))
+        )
+        return u.astype(cfg.jnp_dtype)
+
+
+def _mhc_write(x, y, h_post, h_res, cfg: PatternConfig):
+    """``x'_i = sum_j h_res[i, j] x_j + h_post[i] y``: the streams mixed by
+    the doubly stochastic matrix, plus the half-layer's output spread over
+    them; summed in float32, kept in the model's dtype."""
+    f32 = jnp.float32
+    with named_scope("magi_mhc_write"):
+        xs = [xi.astype(f32) for xi in _streams(x, cfg.hc_mult)]
+        y = y.astype(f32)
+        return jnp.concatenate(
+            [
+                (
+                    sum(h_res[i, j][:, None].astype(f32) * xj
+                        for j, xj in enumerate(xs))
+                    + h_post[i][:, None].astype(f32) * y
+                ).astype(cfg.jnp_dtype)
+                for i in range(cfg.hc_mult)
+            ],
+            axis=-1,
+        )
+
+
+def _mhc_half(x, w: dict, cfg: PatternConfig, fn):
+    """One half-layer on the streams: the coefficients, the read, ``fn``
+    (which norms its input itself and returns the half's output before any
+    residual sum) and the write."""
+    h_pre, h_post, h_res = _mhc_coef(x, w, cfg)
+    return _mhc_write(x, fn(_mhc_read(x, h_pre, cfg)), h_post, h_res, cfg)
+
+
+def _mhc_widen(x, cfg: PatternConfig):
+    """A hidden state [t, dim] as the streams' first state: every stream
+    a copy (arXiv:2409.19606 section 3)."""
+    with named_scope("magi_mhc_write"):
+        return jnp.tile(x, (1, cfg.hc_mult))
+
+
+def _mhc_sum(x, cfg: PatternConfig):
+    """The streams' state read out as one hidden state: their sum."""
+    with named_scope("magi_mhc_read"):
+        return sum(
+            xi.astype(jnp.float32) for xi in _streams(x, cfg.hc_mult)
+        ).astype(cfg.jnp_dtype)
+
+
+def _attention_half(x, pos, layer, carry, **how):
+    """``x`` + the attention half of a SLIDING, FULL or CROSS layer
+    (:func:`_attention_out`)."""
+    out = _attention_out(x, pos, layer, carry, **how)
+    with named_scope("magi_proj"):
+        return x + out
+
+
+def _attention_out(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
+                   attn_params, axis_name, shift_plan, index):
+    """The attention half of a SLIDING, FULL or CROSS layer on ``x``, norm
+    first, before the residual sum; the keys and values ``cfg.kv_layer``
+    makes go into ``carry``."""
     dt = cfg.jnp_dtype
     t = x.shape[0]
     eps = cfg.rms_eps
@@ -1393,7 +1763,7 @@ def _attention_half(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
             out = proj(out, "o")
         if cfg.post_norms:
             out = _rms_norm(out, layer["post_attn_norm"], eps)
-        return x + out
+    return out
 
 
 def _one_layer(cfg, layer_type, ffn_type, tables, plans, attn_params,
@@ -1457,6 +1827,8 @@ def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
     carry (:func:`_layer_local`): an MLP router's state, zero before the
     first layer, the memory and the shared keys and values."""
     x = _embed(params, tokens, cfg)
+    if cfg.hc_mult:
+        x = _mhc_widen(x, cfg)
     carry = {}
     if cfg.router_form == MLP:
         carry["r"] = jnp.zeros(
@@ -1473,6 +1845,8 @@ def _trunk_local(params, tokens, pos, cfg: PatternConfig, tables, plans,
         carry = s.pop("carry", {})
         if s:
             stats.append(s)
+    if cfg.hc_mult:
+        x = _mhc_sum(x, cfg)
     return x, stats
 
 
@@ -1591,10 +1965,14 @@ def _mtp_local(params, x, next_tokens, pos, cfg: PatternConfig, tables,
             e = _rms_norm(e, mod["embed_norm"], cfg.rms_eps)
             h = _rms_norm(x, mod["hidden_norm"], cfg.rms_eps)
             x = jnp.concatenate([e, h], axis=-1) @ mod["eh_proj"].astype(dt)
+        if cfg.hc_mult:  # the module's layer has streams of its own
+            x = _mhc_widen(x, cfg)
         x, s = _one_layer(
             cfg, cfg.layer_types[-1], cfg.ffn_types[-1], tables, plans,
             attn_params, axis_name,
         )(x, pos, mod["layer"])
+        if cfg.hc_mult:
+            x = _mhc_sum(x, cfg)
         if s:
             stats.append(s)
         logits.append(_head(x, mod, params, cfg))
@@ -1965,7 +2343,32 @@ def build_magi_pattern(
         )
     if cfg.attn_form == LATENT:
         telemetry.record_mla_kv_cast_width(
-            expanded=2 * cfg.n_heads * cfg.head_dim,
+            expanded=cfg.n_heads * (cfg.head_dim + cfg.value_dim),
             latent=cfg.kv_lora_rank + cfg.rope_head_dim,
         )
+    if cfg.hc_mult:
+        telemetry.record_mhc(
+            streams=cfg.hc_mult, sinkhorn_iters=cfg.hc_sinkhorn_iters,
+            stream_bytes=mhc_stream_bytes(cfg, data_tokens),
+            pad_lane_share=(
+                1.0 - cfg.head_dim / cfg.kernel_qk_lanes
+                if cfg.v_head_dim else 0.0
+            ),
+        )
     return model, meta
+
+
+def mhc_stream_bytes(cfg: PatternConfig, tokens: int) -> int:
+    """The bytes the stream mix of one training step moves at the least,
+    all half-layers (the MTP modules' too). A state is ``tokens x hc_mult x
+    dim`` in the model's dtype, a hidden state 1 / ``hc_mult`` of it.
+    Forward, a half-layer reads the state twice (its coefficients need a
+    whole row before the read can weigh it; the write reads it again),
+    writes it once, writes ``u`` and reads ``y``; backward it reads the
+    state and the new state's cotangent, writes the state's, reads
+    ``u``'s and writes ``y``'s; under ``cfg.remat`` the forward runs
+    twice."""
+    state = tokens * cfg.hc_mult * cfg.dim * cfg.jnp_dtype.itemsize
+    one = 3 * state + 2 * state // cfg.hc_mult
+    halves = 2 * (cfg.n_layers + cfg.n_mtp)
+    return halves * one * (3 if cfg.remat else 2)

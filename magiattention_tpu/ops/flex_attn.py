@@ -94,13 +94,21 @@ def _flex_pallas_call(
     on which of :data:`GRID_KINDS`. ``form``: the labels that say in which
     form a side operand crosses this kernel's boundary (the forward's
     ``stats=compact|lanes``, the backward's ``delta=xla`` and
-    ``dq=visits|zero_filled``; :func:`stats_form`, :func:`dq_form`)."""
+    ``dq=visits|zero_filled``; :func:`stats_form`, :func:`dq_form`), and
+    ``v_head_dim`` where v is not as wide as k."""
     from .. import telemetry
 
     telemetry.record_flex_kernel_build(
         role, heads_per_step, grid, **(form or {})
     )
     return pl.pallas_call(body, name=f"magi_flex_{role}_kernel", **kwargs)
+
+
+def _value_width_label(d: int, dv: int) -> dict:
+    """The build counter's ``v_head_dim`` label: there only where the value
+    width is not the key width, so every series of a plan at one width is
+    the series it was."""
+    return {} if dv == d else {"v_head_dim": str(dv)}
 
 
 def _compiler_params(*dimension_semantics: str):
@@ -841,10 +849,13 @@ def _rows_from_compact(x, hq: int, tqp: int):
 def _fwd_pallas(
     q, k, v, sink2d, tables, params: FlexAttnParams, residual: bool = False
 ):
-    """q [hq, tqp, d]; k/v [hk, tkp, d]; tables from fwd_tables(). Returns
-    (out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp], and lse replicated
-    over lanes [hq, tqp, LANES] if ``residual``: what the backward reads, else
-    None).
+    """q [hq, tqp, d]; k [hk, tkp, d]; v [hk, tkp, dv]; tables from
+    fwd_tables(). Returns (out [hq, tqp, dv], lse [hq, tqp], rowmax
+    [hq, tqp], and lse replicated over lanes [hq, tqp, LANES] if
+    ``residual``: what the backward reads, else None). The value width
+    ``dv`` is v's own (latent attention's 128 beside keys of 192): v, out
+    and the accumulator take it, q and k the key width ``d``; a step's
+    ``P V`` is then ``dv`` lanes wide and its ``Q K^T`` ``d`` deep.
 
     The two statistics leave the kernel in :func:`stats_form`'s form. In
     the ``compact`` one the kernel writes one ``(hq / HBG, nq, 2, HBG, bq)``
@@ -861,7 +872,7 @@ def _fwd_pallas(
     """
     qblk, kblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
-    hk = k.shape[0]
+    hk, dv = k.shape[0], v.shape[2]
     group = hq // hk
     hbg = params.head_block
     bq, bk = params.block_q, params.block_k
@@ -887,8 +898,9 @@ def _fwd_pallas(
         rows = (bq,)
         k_head = lambda h: h // group  # noqa: E731
         cost = pl.CostEstimate(
-            flops=4 * int(E) * bq * bk * d * hq,
-            bytes_accessed=q.size * q.dtype.itemsize + 2 * k.size * k.dtype.itemsize,
+            flops=2 * int(E) * bq * bk * (d + dv) * hq,
+            bytes_accessed=q.size * q.dtype.itemsize
+            + (k.size + v.size) * k.dtype.itemsize,
             transcendentals=int(E) * bq * bk * hq,
         )
     grid, qmap, kmap, semantics = _walk_grid(
@@ -914,14 +926,14 @@ def _fwd_pallas(
         in_specs=[
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hb, bk, dv), kmap),
             pl.BlockSpec(memory_space=pltpu.SMEM),  # sink [hq, 1]
         ],
-        out_specs=[pl.BlockSpec((hbg, bq, d), qmap), *stat_specs],
+        out_specs=[pl.BlockSpec((hbg, bq, dv), qmap), *stat_specs],
         scratch_shapes=[
             pltpu.VMEM((*rows, LANES), jnp.float32),
             pltpu.VMEM((*rows, LANES), jnp.float32),
-            pltpu.VMEM((*rows, d), jnp.float32),
+            pltpu.VMEM((*rows, dv), jnp.float32),
         ],
     )
     out, *stats = _flex_pallas_call(
@@ -929,10 +941,10 @@ def _fwd_pallas(
         hbg,
         params.grid,
         body,
-        form={"stats": form},
+        form={"stats": form, **_value_width_label(d, dv)},
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((hq, tqp, d), params.out_jnp_dtype),
+            jax.ShapeDtypeStruct((hq, tqp, dv), params.out_jnp_dtype),
             *stat_shapes,
         ],
         interpret=params.interpret,
@@ -1412,10 +1424,13 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     ``arbitrary`` on both grids (two k blocks add into one dq tile). A tile
     is copied whole vregs of lanes at a time, so at a head_dim that is no
     multiple of 128 the buffers are that much wider and the result is cut
-    here."""
+    here. v, dO and dv are ``v.shape[2]`` wide, q, k, dq and dk ``d``
+    (:func:`_fwd_pallas`): dP = dO V^T contracts the value width, and dO's
+    buffer serves dq's result only where the two widths are one."""
     kblk, qblk, sid, runs, bounds = tables
     hq, tqp, d = q.shape
     hk, tkp, _ = k.shape
+    dv = v.shape[2]
     group = hq // hk
     hbg = _bwd_head_block(params, hq, group)
     bq, bk = params.block_q, params.block_k
@@ -1425,7 +1440,9 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     if hbg > 1:
         hb = hbg // group
         body = functools.partial(_bwd_kernel_hb, params=params, group=group)
-        kv_scratch = pltpu.VMEM((hb, bk, d), jnp.float32)
+        kv_scratch = [
+            pltpu.VMEM((hb, bk, w), jnp.float32) for w in (d, dv)
+        ]
         grid, kmap, qmap, semantics = _walk_grid(
             params.grid, hk // hb, kblk, nk, params.bwd_steps,
             blocks="arbitrary",
@@ -1433,7 +1450,7 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     else:
         hb = 1
         body = functools.partial(_bwd_kernel, params=params, group=group)
-        kv_scratch = pltpu.VMEM((bk, d), jnp.float32)
+        kv_scratch = [pltpu.VMEM((bk, w), jnp.float32) for w in (d, dv)]
         grid, kmap, qmap, semantics = _walk_grid(
             params.grid, hk, kblk, nk, params.bwd_steps,
             lambda h, g: h * group + g, inner=(group,), blocks="arbitrary",
@@ -1475,21 +1492,20 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         in_specs=[
             pl.BlockSpec((hbg, bq, d), qmap),
             pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hbg, bq, d), qmap),
+            pl.BlockSpec((hb, bk, dv), kmap),
+            pl.BlockSpec((hbg, bq, dv), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
             pl.BlockSpec((hbg, bq, LANES), qmap),
             *[pl.BlockSpec(memory_space=pl.ANY)] * n_fills,
         ],
         out_specs=[
             pl.BlockSpec((hb, bk, d), kmap),
-            pl.BlockSpec((hb, bk, d), kmap),
+            pl.BlockSpec((hb, bk, dv), kmap),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            kv_scratch,
-            kv_scratch,
+            *kv_scratch,
             pltpu.VMEM((2, hbg, bq, dq_shape[2]), jnp.float32),
             pltpu.VMEM((2, hbg, bq, dq_shape[2]), q.dtype),
             pltpu.SemaphoreType.DMA((3, 2)),
@@ -1501,11 +1517,11 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         hbg,
         params.grid,
         body,
-        form={"delta": "xla", "dq": form},
+        form={"delta": "xla", "dq": form, **_value_width_label(d, dv)},
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), k.dtype),
-            jax.ShapeDtypeStruct((hk, tkp, d), v.dtype),
+            jax.ShapeDtypeStruct((hk, tkp, dv), v.dtype),
             jax.ShapeDtypeStruct(dq_shape, q.dtype),
             jax.ShapeDtypeStruct(dq_shape, jnp.float32),
         ],
@@ -1811,14 +1827,14 @@ def _fwd_jnp_online(q, k, v, sink2d, ftab, params: FlexAttnParams):
         l_new = l * resc + p.sum(axis=-1)
         pv = jnp.einsum(
             "hgqk,hkd->hgqd", p.reshape(hk, group, tqp, bk), vb
-        ).reshape(hq, tqp, d)
+        ).reshape(hq, tqp, vf.shape[2])
         acc_new = acc * resc[..., None] + pv
         return (m_new, l_new, acc_new), None
 
     init = (
         jnp.full((hq, tqp), neg, acc_t),
         jnp.zeros((hq, tqp), acc_t),
-        jnp.zeros((hq, tqp, d), acc_t),
+        jnp.zeros((hq, tqp, vf.shape[2]), acc_t),
     )
     (m, l, acc), _ = jax.lax.scan(
         step, init, jnp.arange(tkp // bk, dtype=jnp.int32)

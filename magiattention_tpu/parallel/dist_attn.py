@@ -906,9 +906,16 @@ def make_attn_params(
     out_dtype="bfloat16",
     interpret: bool | None = None,
     head_block: int = 1,
+    v_head_dim: int | None = None,
 ) -> FlexAttnParams:
+    """A plan's kernel parameters. ``head_dim``: the width of q and k, which
+    gives the default softmax scale; ``v_head_dim``: that of v and out where
+    it is another (the kernels read both off their operands: it goes on the
+    ``attn_fn_build`` span here and nowhere into the parameters, so a plan at
+    one width has the parameters it had)."""
     if scale is None:
         scale = 1.0 / math.sqrt(head_dim)
+    telemetry.annotate_span(v_head_dim=v_head_dim or head_dim)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     # plan-wide static inner-grid extents: max over every table set the
@@ -1154,7 +1161,15 @@ def dist_attn_local(
     # all the rest of what runs round the kernels magi_layout
     with named_scope("magi_layout"):
         qh = _hm(q, plan.shard_q_pad)
-        kv = jnp.stack([k, v], axis=1)  # one all_to_all payload for K and V
+        # one all_to_all payload for K and V: stacked where they are one
+        # width (the program every plan had), else [k | v] along the last
+        # axis, split on arrival (latent attention's keys of 192 or 256
+        # lanes beside values of 128)
+        one_width = k.shape[-1] == v.shape[-1]
+        kv = (
+            jnp.stack([k, v], axis=1) if one_width
+            else jnp.concatenate([k, v], axis=-1)
+        )
         if env.is_backward_high_precision_reduce():
             # fp32 payload -> the transposed dKV reduce accumulates in fp32
             # (2x comm; reference BACKWARD_HIGH_PRECISION_REDUCE)
@@ -1187,6 +1202,11 @@ def dist_attn_local(
             k.dtype
         )
 
+    def split_kv(recv):
+        if one_width:
+            return recv[:, 0], recv[:, 1]
+        return recv[..., : k.shape[-1]], recv[..., k.shape[-1] :]
+
     def _head_max(rowmax):
         # per-head max of masked logits over this rank's rows (pads carry
         # -inf); callers pmax across ranks (reference reduce_max_logits,
@@ -1198,8 +1218,9 @@ def dist_attn_local(
         with named_scope("magi_merged_cast"):
             recv = cast_kv(plan.merged_comm)
         with named_scope("magi_layout"):
-            k_full = jnp.concatenate([k, recv[:, 0]], axis=0)
-            v_full = jnp.concatenate([v, recv[:, 1]], axis=0)
+            k_recv, v_recv = split_kv(recv)
+            k_full = jnp.concatenate([k, k_recv], axis=0)
+            v_full = jnp.concatenate([v, v_recv], axis=0)
         with named_scope("magi_merged_kernel"):
             out_h, lse_h, rowmax = _call_kernel(
                 qh, k_full, v_full, tab, plan.merged_tables.kv_pad, params,
@@ -1249,7 +1270,7 @@ def dist_attn_local(
             recv = cast_kv(sp.comm)
         with named_scope(f"magi_stage{i}_kernel"):
             out_i_h, lse_i_h, rowmax_i = _call_kernel(
-                qh, recv[:, 0], recv[:, 1], tab, sp.tables.kv_pad,
+                qh, *split_kv(recv), tab, sp.tables.kv_pad,
                 _for_tables(stage_params, sp.tables), None,
             )
         with named_scope("magi_layout"):

@@ -76,6 +76,7 @@ from .collectors import (  # noqa: F401
     record_flex_kernel_build,
     record_model_attn_plan,
     record_handed_on,
+    record_mhc,
     record_mla_kv_cast_width,
     record_ssm_scan,
     record_model_loop,
@@ -370,6 +371,7 @@ __all__ = [
     "record_flex_kernel_build",
     "record_model_attn_plan",
     "record_handed_on",
+    "record_mhc",
     "record_mla_kv_cast_width",
     "record_ssm_scan",
     "record_model_loop",

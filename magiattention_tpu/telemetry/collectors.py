@@ -186,6 +186,14 @@ M_SSM_STATE_BYTES = "magi_ssm_state_bytes"
 M_SHARED_KV_READERS = "magi_shared_kv_readers"
 M_SHARED_KV_RECAST_ROWS = "magi_shared_kv_recast_rows_total"
 M_FLEX_PAD_LANE_SHARE = "magi_flex_pad_lane_share"
+# gauges — a pattern decoder's residual streams under hyper-connections
+# (models/pattern.py, ``hc_mult``): how many, the Sinkhorn rounds a
+# half-layer's mixing matrix takes, and the bytes a training step's stream
+# mix moves at the least (a half-layer reads the state twice and writes it
+# once forward, three passes more backward, and under remat forward again)
+M_MHC_STREAMS = "magi_mhc_streams"
+M_MHC_SINKHORN_ITERS = "magi_mhc_sinkhorn_iters"
+M_MHC_STREAM_BYTES = "magi_mhc_stream_bytes"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1304,6 +1312,20 @@ def record_handed_on(
         reg.gauge_set(M_SSM_DOCUMENTS, float(documents))
     reg.gauge_set(M_SHARED_KV_READERS, float(kv_readers))
     reg.counter_inc(M_SHARED_KV_RECAST_ROWS, recast_rows)
+    reg.gauge_set(M_FLEX_PAD_LANE_SHARE, float(pad_lane_share))
+
+
+def record_mhc(*, streams: int, sinkhorn_iters: int, stream_bytes: int,
+               pad_lane_share: float) -> None:
+    """A pattern decoder's residual streams
+    (``models/pattern.build_magi_pattern``, host side), and the padded
+    share of the lanes its latent heads' q and k ride into the kernels on."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.gauge_set(M_MHC_STREAMS, float(streams))
+    reg.gauge_set(M_MHC_SINKHORN_ITERS, float(sinkhorn_iters))
+    reg.gauge_set(M_MHC_STREAM_BYTES, float(stream_bytes))
     reg.gauge_set(M_FLEX_PAD_LANE_SHARE, float(pad_lane_share))
 
 

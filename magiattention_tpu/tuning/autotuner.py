@@ -135,6 +135,7 @@ def select_block_config(
     cp_size: int = 1,
     measure_fn=None,
     include_sparse: bool = True,
+    v_head_dim: int | None = None,
 ) -> TuningDecision | None:
     """Resolve (block_q, block_k, head_block, grid) for one workload.
 
@@ -175,6 +176,7 @@ def select_block_config(
         max_block_q=max_block_q,
         max_block_k=max_block_k,
         include_sparse=include_sparse,
+        v_head_dim=v_head_dim,
     )
     cache = get_tuning_cache()
     rec, layer = cache.get(fp)
@@ -261,6 +263,7 @@ def select_block_config(
         max_block_k=max_block_k,
         cp_size=cp_size,
         include_sparse=include_sparse,
+        v_head_dim=v_head_dim,
     )
     if not scores:
         return None  # constraints excluded every rung
@@ -665,6 +668,7 @@ def resolve_block_config(
     hkv: int,
     head_dim: int,
     out_dtype: str,
+    v_head_dim: int | None = None,
 ) -> tuple[int, int, int] | None:
     """Plan-aware block config for a distributed plan (keyed runtime or
     model-harness builder), or None for the legacy env-flag blocking.
@@ -707,6 +711,7 @@ def resolve_block_config(
         max_block_k=shard_k,
         cp_size=cp_size,
         include_sparse=False,
+        v_head_dim=v_head_dim,
     )
     if decision is None:
         return None

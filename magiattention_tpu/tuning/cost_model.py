@@ -157,6 +157,7 @@ def step_bytes(
     group: int,
     head_dim: int,
     itemsize: int,
+    v_head_dim: int | None = None,
 ) -> int:
     """Bytes one live step of ``kernel`` (``fwd`` | ``bwd``, and the
     ``dq`` | ``dkv`` the ranking was calibrated on) moves to and from HBM.
@@ -172,13 +173,15 @@ def step_bytes(
     Not counted: the tiles that stay while a block's entries run (q in the
     forward; K, V, dk and dv in the backward). Each is brought or written
     once a block, so over a call they are the tensors' own size whatever
-    the rung: they move no order between rungs."""
+    the rung: they move no order between rungs. ``v_head_dim``: the width
+    of V and dO where it is not that of K and q (``head_dim``)."""
+    dv = head_dim if v_head_dim is None else v_head_dim
     if kernel in ("dkv", "bwd"):
-        row = 2 * head_dim * itemsize + 2 * _STAT_LANES * 4
+        row = (head_dim + dv) * itemsize + 2 * _STAT_LANES * 4
         if kernel == "bwd":
             row += 2 * head_dim * 4  # the float32 dq tile, in and out
         return head_block * block_q * row
-    return 2 * max(head_block // group, 1) * block_k * head_dim * itemsize
+    return max(head_block // group, 1) * block_k * (head_dim + dv) * itemsize
 
 # Sparse-only blockings: smaller tiles than any row-major rung carries.
 # On the row-major grid small tiles lose to grid-step overhead (the
@@ -556,6 +559,7 @@ def rank_candidates(
     cp_size: int = 1,
     include_sparse: bool = True,
     rungs=None,
+    v_head_dim: int | None = None,
 ) -> list[CandidateScore]:
     """Score every candidate rung for the workload, best first.
 
@@ -600,10 +604,15 @@ def rank_candidates(
     (wide-tile rungs first) with ``feasible=False`` throughout — callers
     keep the old behavior of launching the least-bad rung and letting the
     kernel's SMEM check raise a descriptive error.
+
+    ``v_head_dim``: the value heads' width where it is not ``head_dim``:
+    a tile's ``Q K^T`` is ``head_dim`` deep and its ``P V``
+    ``v_head_dim`` wide, and V's bytes are its own (:func:`step_bytes`).
     """
     from .. import env
     from ..ops.flex_attn import _auto_head_block
 
+    dv = head_dim if v_head_dim is None else int(v_head_dim)
     q, k, t = _normalize_slices(q_ranges, k_ranges, attn_type_map)
     extent = 0
     if q.size:
@@ -630,11 +639,11 @@ def rank_candidates(
         else:
             dead = max(grid_rows * nq * steps - live, 0)
             step_s = live * STEP_OVERHEAD_S + dead * DEAD_STEP_OVERHEAD_S
-        mxu_s = 4.0 * head_dim * hq * entries * bq * bk / eff_flops
+        mxu_s = 2.0 * (head_dim + dv) * hq * entries * bq * bk / eff_flops
         streamed = {
             kern: grid_rows
             * (bwd_entries if kern == "dkv" else entries)
-            * step_bytes(kern, bq, bk, hb, group, head_dim, itemsize)
+            * step_bytes(kern, bq, bk, hb, group, head_dim, itemsize, dv)
             / hbm_rate
             for kern in RANKED_KERNELS
         }

@@ -69,6 +69,11 @@ class WorkloadFingerprint:
     # decision for the SAME mask are different answers and must not
     # share a cache slot in either direction
     sparse_rungs: int = 1
+    # the value heads' width where it is not ``head_dim`` (latent
+    # attention's 128 beside keys of 192); 0: one width, and the key's
+    # payload leaves the field out, so a key made before the kernels took
+    # a value width reads as it did
+    v_head_dim: int = 0
 
     # v4 (ISSUE 33): at cp = 1 the SMEM test reads the exact entry count,
     # which admits small rungs for band masks; a (1024, 1024, 1) cached for
@@ -80,6 +85,8 @@ class WorkloadFingerprint:
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
+        if not d["v_head_dim"]:
+            del d["v_head_dim"]
         d["entry_est"] = [list(e) for e in self.entry_est]
         d["step_est"] = [list(e) for e in self.step_est]
         d["sparse_entry_est"] = [list(e) for e in self.sparse_entry_est]
@@ -105,6 +112,7 @@ def make_fingerprint(
     max_block_q: int | None = None,
     max_block_k: int | None = None,
     include_sparse: bool = True,
+    v_head_dim: int | None = None,
 ) -> WorkloadFingerprint:
     """Derive the fingerprint from host-side slice ranges.
 
@@ -137,6 +145,7 @@ def make_fingerprint(
         int(max_block_q or 0),
         int(max_block_k or 0),
         int(bool(include_sparse)),
+        0 if v_head_dim in (None, head_dim) else int(v_head_dim),
     )
     fp = _FP_MEMO.get(key)
     if fp is None:
@@ -318,6 +327,7 @@ def _make_fingerprint_impl(
     max_block_q: int,
     max_block_k: int,
     sparse_rungs: int,
+    v_head_dim: int = 0,
 ) -> WorkloadFingerprint:
     import numpy as np
 
@@ -371,4 +381,5 @@ def _make_fingerprint_impl(
         step_est=step_est,
         sparse_entry_est=sparse_entry_est,
         sparse_rungs=sparse_rungs,
+        v_head_dim=v_head_dim,
     )

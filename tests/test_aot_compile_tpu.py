@@ -345,3 +345,124 @@ def test_selective_scan_at_the_sambay_cells_size(topo, chunk, block):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), *args)
     for kernel in ("magi_ssm_scan_fwd_kernel", "magi_ssm_scan_bwd_kernel"):
         assert kernel in text, kernel
+
+
+def _xing_cell():
+    """(configuration, traffic) of ``xing4-train-8k-traces``."""
+    import json
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*parts):
+        with open(os.path.join(here, "benchmarks", *parts)) as f:
+            return json.load(f)
+
+    return (
+        load("configs", "xing4.0-29b-a4b.json"),
+        load("traffic", "train-8k-packed-mhc.json"),
+    )
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("d", [256, 192], ids=["keys-on-256", "keys-at-192"])
+def test_a_value_width_of_its_own_at_the_mhc_cells_size(topo, d, grid):
+    """Forward and backward with keys wider than values (ISSUE 49): 32
+    query = 32 key-value heads, q and k at 192 as they are (what the cell
+    runs: a block of one and a half vregs, 0.8% faster on the chip) and on
+    256 lanes (zeros in the last 64), v of 128, on the cell's 8,192-token mask at
+    the rung the tuner gives it. The build counter says which widths the
+    kernels were built at."""
+    from benchmarks import masks
+    from magiattention_tpu import telemetry
+
+    _cfg, tr = _xing_cell()
+    t = int(tr["total_tokens"])
+    mask = masks.build_mask(tr["mask"], t, index=0)
+    qr, kr = [list(r) for r in mask.q_ranges], [list(r) for r in mask.k_ranges]
+    rung = (256, 512, 8)
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, list(mask.types), grid=grid, block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], interpret=False,
+        )
+        assert out.shape == (t, 32, 128)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    reg = telemetry.get_registry()
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    reg.clear_metric("magi_flex_kernel_build_total")
+    try:
+        text = _compile(
+            jax.value_and_grad(loss, argnums=(0, 1, 2)),
+            _on(chip, (t, 32, d)), _on(chip, (t, 32, d)),
+            _on(chip, (t, 32, 128)),
+        )
+        for kernel, form in _FORMS.items():
+            assert reg.counter_value(
+                "magi_flex_kernel_build_total", kernel=kernel,
+                heads_per_step=rung[2], grid=grid, v_head_dim="128", **form,
+            ) == 1, kernel
+    finally:
+        reg.clear_metric("magi_flex_kernel_build_total")
+        telemetry.set_enabled(was)
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
+
+
+def test_the_mhc_cells_step_fits_one_chip(topo):
+    """``xing4-train-8k-traces``'s whole AdamW step at the published
+    widths, the cell's five layers, 8 of 64 experts and an eighth of the
+    vocabulary, on its 8,192-token mask: arguments and temporaries stay
+    under the 17.18 x 10^9 bytes one v5e holds (14.16 x 10^9 when this was
+    written: 9.11 of float32 weights and AdamW's moments, 5.05 of
+    gradients, the four-stream states kept at the layers' boundaries and
+    one layer's recomputation), and the tuner's rung is the pinned one."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks import masks
+    from benchmarks.kinds import train_mhc
+    from magiattention_tpu.models.pattern import init_pattern_params
+
+    cfg, tr = _xing_cell()
+    total = int(tr["total_tokens"])
+    with pytest.MonkeyPatch.context() as patch:
+        # the kind's Job passes no interpret=: the kernels ask the backend
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        job = train_mhc.Job(cfg, tr, 0, topo.devices[:1])
+        model, _meta = job.build(masks.build_mask(tr["mask"], total, index=0))
+        (params,) = model.attn_params.values()
+        assert (params.block_q, params.block_k, params.head_block) == (256, 512, 8)
+        assert not params.interpret
+        opt = optax.adamw(float(tr["learning_rate"]))
+        held = NamedSharding(job.mesh, P())
+        weights = jax.eval_shape(
+            lambda r: init_pattern_params(r, job.pcfg), jax.random.PRNGKey(0)
+        )
+
+        def on_chip(tree):
+            return jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=held),
+                tree,
+            )
+
+        batch = jax.ShapeDtypeStruct(
+            (1, total), jnp.int32, sharding=NamedSharding(job.mesh, P("dp", "cp"))
+        )
+        exe = model.make_train_step(opt).lower(
+            on_chip(weights), on_chip(jax.eval_shape(opt.init, weights)),
+            batch, batch, batch,
+        ).compile()
+    mem = exe.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 17.18e9
+    # a layer's forward kernel once (its out and lse are kept), its
+    # backward once: 5 layers (the grouped matmuls are custom calls too)
+    calls = [
+        line for line in exe.as_text().splitlines()
+        if "custom-call(" in line and "tpu_custom_call" in line
+    ]
+    for kernel in ("magi_flex_fwd_kernel", "magi_flex_bwd_kernel"):
+        assert sum(kernel in line for line in calls) == 5, kernel

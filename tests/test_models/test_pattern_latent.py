@@ -200,8 +200,12 @@ def test_the_latent_scopes_and_the_cast_width_gauge(glm_params):
 
 
 def test_latent_config_rejects_what_is_not_built():
-    with pytest.raises(ValueError, match="one head width"):
-        glm4_moe_lite_config(dict(GLM_HF, v_head_dim=16))
+    # a value width of its own is built since ISSUE 49 (the kernels take
+    # it; tests/test_models/test_pattern_mhc.py runs one against its
+    # reference); the key heads' width is no value width of its own
+    assert glm4_moe_lite_config(dict(GLM_HF, v_head_dim=16)).v_head_dim == 16
+    cfg = _glm()[1]
+    assert cfg.v_head_dim == 0 and cfg.kernel_heads is cfg
     with pytest.raises(ValueError, match="n_group"):
         glm4_moe_lite_config(dict(GLM_HF, n_group=2))
     with pytest.raises(ValueError, match="latent attention needs"):

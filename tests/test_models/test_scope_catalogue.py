@@ -1,7 +1,8 @@
 """Every operation the device runs lies under exactly one *part* scope
 that a per-layer metric reads (``docs/observability.md``, "Device
 scopes"): the six toy models' train steps and the bare attention call
-(seven with the decoder-hybrid-decoder's) are compiled here, on the CPU, their ``op_name``s read as the benchmark
+(seven with the decoder-hybrid-decoder's, eight with the residual
+streams') are compiled here, on the CPU, their ``op_name``s read as the benchmark
 reads them (``trace_reduce.hlo_scopes``), and held against the patterns
 of the metric files themselves, so a scope that is renamed, dropped or
 wrapped round another part fails here and not as a silent zero on the
@@ -31,6 +32,7 @@ from tests.test_models import test_pattern_blockdiff as blockdiff
 from tests.test_models.test_pattern_cca import _zaya
 from tests.test_models.test_pattern_latent import _glm
 from tests.test_models.test_pattern_looped import _ouro
+from tests.test_models.test_pattern_mhc import _xing
 from tests.test_models.test_pattern_sambay import _sambay
 
 METRICS = os.path.join(
@@ -60,6 +62,7 @@ PARTS = {
         "ssm_scan": "train_ssm_scan_share",
         "ssm_mix": "train_ssm_mix_share",
         "diff_combine": "train_diff_combine_share",
+        "mhc": "train_mhc_share",
         "optimizer": "train_optimizer_share",
     }.items()
 }
@@ -69,7 +72,9 @@ EXPERT_PARTS = {
 }
 # parts newer than the remainder's pattern, which is the benchmark's and
 # would read them too: their cells are not on its list (PERF.md section 7)
-NEWER_THAN_THE_REMAINDER = {"cca_mix", "ssm_scan", "ssm_mix", "diff_combine"}
+NEWER_THAN_THE_REMAINDER = {
+    "cca_mix", "ssm_scan", "ssm_mix", "diff_combine", "mhc",
+}
 REMAINDERS = {
     "step": _pattern("train_unscoped_share"),
     "attn": _pattern("attn_unscoped_fwdbwd_ms"),
@@ -121,6 +126,13 @@ CASES = {
         r"checkpoint/magi_ssm_scan", r"checkpoint/magi_ssm_mix",
         r"checkpoint/magi_gmu", r"checkpoint/magi_diff_combine",
     ],
+    "mhc": STEP + EXPERTS + [
+        "magi_head", "magi_attn_full", "magi_mla_q", "magi_mtp",
+        # siblings of magi_proj and magi_ffn, not inside them
+        r"checkpoint/magi_mhc_coef", r"checkpoint/magi_mhc_read",
+        r"checkpoint/magi_mhc_write",
+        r"magi_mtp\S*magi_mhc_write",  # the module's layer has its streams
+    ],
     "blockdiff": STEP + [s for s in EXPERTS if s != "magi_moe_shared"] + [
         "magi_head", "magi_attn_full",
         # a cross-cut: its operations carry their part too
@@ -153,6 +165,7 @@ def toy_model(name: str):
     cfg = {
         "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
         "looped": _ouro()[1], "cca": _zaya()[1], "sambay": _sambay()[1],
+        "mhc": _xing()[1],
     }[name]
     model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
     return model, init_pattern_params(jax.random.PRNGKey(0), cfg)
@@ -261,6 +274,8 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
                 "magi_proj/magi_cca_mix", "magi_cca_mix/magi_proj"],
         "blockdiff": ["magi_mla_", "magi_mtp", "magi_exit_head",
                       "magi_moe_shared", "magi_cca_mix"],
+        "mhc": ["magi_exit_head", "magi_attn_sliding", "magi_proj/magi_mhc",
+                "magi_ffn/magi_mhc", "magi_mhc_read/magi_proj"],
         "sambay": ["magi_moe_", "magi_mla_", "magi_mtp", "magi_exit_head",
                    "magi_cca_mix", "magi_proj/magi_ssm", "magi_proj/magi_gmu",
                    "magi_proj/magi_diff_combine", "magi_ssm_mix/magi_proj"],
@@ -273,6 +288,7 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
         own = {
             "cca": {"cca_mix", "moe"}, "blockdiff": {"moe"},
             "sambay": {"ffn", "ssm_scan", "ssm_mix"},
+            "mhc": {"ffn", "moe", "mhc"},
         }.get(case, {"ffn"})
         assert {"proj", "flex", "layout", "embed"} | own <= set(seen), seen
         assert ("exit_head" if case == "looped" else "head") in seen, seen

@@ -172,6 +172,9 @@ class KernelCase:
     hq: int = 4
     hk: int = 2
     d: int = 32
+    # the value heads' width (v, out, dO, dv), where it is not the key
+    # heads' ``d`` (q, k, dq, dk): latent attention's 128 beside 192; 0: ``d``
+    dv: int = 0
     block_q: int = 64
     block_k: int = 64
     head_block: int = 1
@@ -207,6 +210,10 @@ class KernelCase:
     def tokens(self):
         return MASKS[self.mask][:2]
 
+    @property
+    def value_width(self) -> int:
+        return self.dv or self.d
+
     def oracle_key(self) -> "KernelCase":
         """The case with what the dense jnp backend cannot see set to its
         default: which body walks which grid, the dtype the kernels round
@@ -219,8 +226,9 @@ class KernelCase:
 
 
 class Run(NamedTuple):
-    """``got`` / ``ref``: out, lse, rowmax [hq, tq(, d)], dq [hq, tq, d],
-    dk, dv [hk, tk, d] and, under a sink, dsink [hq], of the Pallas kernels
+    """``got`` / ``ref``: out [hq, tq, dv], lse, rowmax [hq, tq], dq
+    [hq, tq, d], dk [hk, tk, d], dv [hk, tk, dv] and, under a sink, dsink
+    [hq], of the Pallas kernels
     and of ``_fwd_jnp``, as numpy. ``seen``: what crossed the backward
     kernel's boundary on a case that has ``watch`` set (``lse_lanes``,
     ``delta`` and ``delta_rows``, ``dlse`` or None, ``dq_kernel`` as the
@@ -252,21 +260,23 @@ def block_meta(case: KernelCase):
 
 
 def operands(case: KernelCase) -> dict:
-    """q, do [hq, tq, d], k, v [hk, tk, d], w [hq, tq] (the lse cotangent),
-    sink [hq]: float32, head-major, drawn from ``case.seed``."""
+    """q [hq, tq, d], k [hk, tk, d], v [hk, tk, dv], do [hq, tq, dv], w
+    [hq, tq] (the lse cotangent), sink [hq]: float32, head-major, drawn
+    from ``case.seed``."""
     tq, tk = case.tokens
     return _operands(
-        tq, tk, case.hq, case.hk, case.d, case.seed, case.amp, case.sign
+        tq, tk, case.hq, case.hk, case.d, case.value_width, case.seed,
+        case.amp, case.sign,
     )
 
 
 @functools.lru_cache(maxsize=64)
-def _operands(tq, tk, hq, hk, d, seed, amp, sign):
+def _operands(tq, tk, hq, hk, d, dv, seed, amp, sign):
     rng = np.random.default_rng(seed)
     make = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
     x = dict(
-        q=make(hq, tq, d), k=make(hk, tk, d), v=make(hk, tk, d),
-        do=make(hq, tq, d), w=make(hq, tq), sink=make(hq),
+        q=make(hq, tq, d), k=make(hk, tk, d), v=make(hk, tk, dv),
+        do=make(hq, tq, dv), w=make(hq, tq), sink=make(hq),
     )
     if sign:
         x["q"], x["k"] = np.abs(x["q"]), sign * np.abs(x["k"])

@@ -227,3 +227,53 @@ def test_a_pair_of_factors_no_file_reached(pair, body):
         assert (np.isneginf(got["lse"]) == ~live).all()
         assert_close(got["out"], ref["out"], atol=3e-5, rtol=3e-5, msg="out")
         assert_close(got["lse"][live], ref["lse"][live], atol=3e-5, rtol=3e-5, msg="lse")
+
+
+# A value width of its own (ISSUE 49): latent attention's keys of 192 (as
+# they are, and on 256 lanes) beside values of 128, per head on the
+# row-major grid and head-batched on the compact one, forward and backward.
+VALUE_WIDTHS = {
+    f"{d}|{dv}-{body}": KernelCase(
+        "four_docs", hq=4, hk=4, d=d, dv=dv, head_block=hb, grid=grid,
+        sink=False, seed=49,
+    )
+    for d, dv in ((192, 128), (256, 128))
+    for body, hb, grid in (
+        ("per-head-row_major", 1, "row_major"), ("batched-sparse", 4, "sparse"),
+    )
+}
+
+
+@pytest.mark.parametrize("name", list(VALUE_WIDTHS))
+def test_a_value_width_of_its_own_against_the_plain_reference(name):
+    """out [.., dv], lse and dq, dk [.., d], dv [.., dv] of the kernels
+    against ``benchmarks/reference.py``'s ``attention_rows``, which reads
+    the ranges' dense mask and no table, and against the jnp backend on the
+    same tables."""
+    from benchmarks import reference
+
+    case = VALUE_WIDTHS[name]
+    tq, tk, qr, kr, ts = MASKS[case.mask]
+    got, ref, _ = run(case)
+    assert got["out"].shape == (case.hq, tq, case.dv)
+    assert got["dq"].shape == (case.hq, tq, case.d)
+    assert got["dk"].shape == (case.hk, tk, case.d)
+    assert got["dv"].shape == (case.hk, tk, case.dv)
+    assert_grads(case)
+    x = operands(case)
+    allow = jnp.asarray(make_attn_mask_from_ranges(qr, kr, ts, tq, tk))
+    rows = lambda a: jnp.transpose(jnp.asarray(a), (1, 0, 2))  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        out, lse, dq, dk, dv = reference.attention_rows(
+            rows(x["q"]), rows(x["k"]), rows(x["v"]), allow, rows(x["do"]),
+            jnp.asarray(x["w"]).T,
+        )
+    live = ~np.isneginf(np.asarray(lse).T)
+    assert (np.isneginf(got["lse"]) == ~live).all()
+    for nm, want in (("out", out), ("dq", dq), ("dk", dk), ("dv", dv)):
+        assert_close(
+            got[nm], np.transpose(np.asarray(want), (1, 0, 2)),
+            atol=1e-4, rtol=1e-4, msg=nm,
+        )
+    assert_close(got["lse"][live], np.asarray(lse).T[live], atol=3e-5,
+                 rtol=3e-5, msg="lse")

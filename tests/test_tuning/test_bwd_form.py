@@ -1,7 +1,7 @@
 """The backward's form (ISSUE 43): one k-major kernel that computes S, dP
 and dS once a tile, keeps dk / dv in VMEM and adds dq into a float32 buffer
 in HBM. What the cost model says a step of it moves, at the rungs the
-benchmark's twelve cells run; and the form each cell's plan records, read
+benchmark's cells run; and the form each cell's plan records, read
 from the plan's own span (host only: nothing runs on a device)."""
 
 import json
@@ -69,6 +69,7 @@ DQ_VISITS = {
     "sdar30b-train-16k-blockdiff": [5.875],
     # the full plan (ZAYA's mask), then the window of 512: one block_k
     "phi4flash-train-16k-traces": [6.0, 1.9375],
+    "xing4-train-8k-traces": [3.5],  # ZAYA's mask halved, at block_q 256
 }
 
 

@@ -149,6 +149,14 @@ CELLS = {
     "zaya1-train-16k-traces": [
         ((128, 512, 8), "sparse", 2 * 128 * 16 + 33 * 64, 3 * 768, 3 * 764),
     ],
+    # ZAYA's mask halved at 32 query = 32 key-value heads, keys of 192
+    # beside values of 128 (ISSUE 49): group 1, the GLM and
+    # Ouro cells' (256, 512, 8), here with no cheaper rung passed over for
+    # its bytes: 32 q blocks of at most 8 entries, 17 k blocks of at most 16, 105
+    # entries padded to 112
+    "xing4-train-8k-traces": [
+        ((256, 512, 8), "sparse", 2 * 32 * 8 + 17 * 16, 3 * 112, 3 * 105),
+    ],
 }
 
 
