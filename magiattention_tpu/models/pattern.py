@@ -1154,6 +1154,172 @@ def route(h, layer: dict, cfg: PatternConfig, r=None):
     return idx, w * cfg.route_scale, r
 
 
+def _token_rows(h, tok, n: int):
+    """``h[tok]`` for the first ``n`` of ``tok``'s entries, zeros past
+    them: a token's row (or number) at each of its pairs, in ``tok``'s
+    order."""
+    pad = ((0, tok.shape[0] - n),) + ((0, 0),) * (h.ndim - 1)
+    return jnp.pad(h[tok[:n]], pad)
+
+
+def _slot_sums(rows, inv, t: int):
+    """``rows[inv]``, the pairs' rows back in pair order, and a token's
+    slots summed in float32: ``[t, ...]``."""
+    slots = rows[inv].reshape(t, -1, *rows.shape[1:])
+    return slots.astype(jnp.float32).sum(1)
+
+
+def _chunk_groups(lo, rows: int, starts, ends, n_here):
+    """Of pair rows ``[lo, lo + rows)`` in the sort's order: how many each
+    held expert has, and which rows hold a held pair."""
+    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
+    return sizes, valid
+
+
+def _grouped_ffn(xs, sizes, valid, we_gate, we_up, we_down):
+    """The held experts' SwiGLU on a chunk of pair rows, a group an
+    expert."""
+
+    def grouped(x, w):
+        # rows past the held pairs belong to no group: what the
+        # kernel leaves there, and what its transpose leaves in
+        # their cotangent, is not data (the chip leaves NaNs)
+        x = jnp.where(valid, x, 0)
+        return jnp.where(valid, jax.lax.ragged_dot(x, w, sizes), 0)
+
+    a, b = grouped(xs, we_gate), grouped(xs, we_up)
+    return grouped(jax.nn.silu(a) * b, we_down)
+
+
+def _whole_chunk(xs, ws, lo, bounds, weights):
+    """One chunk where every chunk runs: the grouped matmuls take all its
+    rows (the zero rows are the last group's), and it weighs its own
+    output rows in float32, so its backward, which has them again, makes
+    the weights' gradient and no row of them outlives the chunk."""
+    rows = xs.shape[0]
+    with named_scope("magi_moe_sort"):
+        sizes, valid = _chunk_groups(lo, rows, *bounds)
+        sizes = sizes.at[-1].add(rows - sizes.sum())
+    with named_scope("magi_moe_matmul"):
+        o = _grouped_ffn(xs, sizes, valid, *weights)
+    with named_scope("magi_moe_scatter"):
+        return o.astype(jnp.float32) * ws[:, None]
+
+
+def _chunk_of(stack, lo, rows: int):
+    return jax.lax.dynamic_slice_in_dim(stack, lo, rows, 0)
+
+
+def _each_chunk(n_chunks: int, rows: int, body, init):
+    """``body(lo, carry)`` a chunk of ``rows`` in turn (at top-1 the one
+    chunk is the whole stack, and there is no loop)."""
+    if n_chunks == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(
+        0, n_chunks, lambda c, carry: body(c * rows, carry), init
+    )
+
+
+def _sorted_rows_forward(rows, h, w, order, bounds, weights):
+    t, k = w.shape
+    n, n_rows = t * k, order.shape[0]
+    with named_scope("magi_moe_sort"):
+        pairs = order[:n]
+        inv = jnp.zeros_like(pairs).at[pairs].set(
+            jnp.arange(n, dtype=jnp.int32), unique_indices=True
+        )
+        tok = order // k
+    with named_scope("magi_moe_gather"):
+        xs = _token_rows(h, tok, n)
+        # a pair's weight, in the sort's order: each pair its own
+        ws = _token_rows(w.reshape(-1), order, n)
+
+    def chunk(lo, out):
+        with named_scope("magi_moe_gather"):
+            x, wc = _chunk_of(xs, lo, rows), _chunk_of(ws, lo, rows)
+        o = _whole_chunk(x, wc, lo, bounds, weights)
+        with named_scope("magi_moe_scatter"):
+            return jax.lax.dynamic_update_slice_in_dim(out, o, lo, 0)
+
+    with named_scope("magi_moe_scatter"):
+        out = jnp.zeros((n_rows, h.shape[1]), jnp.float32)
+    out = _each_chunk(n_rows // rows, rows, chunk, out)
+    with named_scope("magi_moe_scatter"):
+        y = _slot_sums(out, inv, t)
+    return y, (xs, ws, tok, inv, bounds, weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_on_sorted_rows(rows: int, h, w, order, bounds, weights):
+    """sum_k w_k expert_k(h) where every chunk of ``rows`` pair rows runs:
+    ``order`` ``[n_rows]`` is the pairs sorted by expert (whole chunks:
+    entries past ``n = t top_k`` are padding), ``bounds`` the held
+    experts' (starts, ends, pairs here) in it, ``weights`` their three
+    stacked matrices. The chunks' rows end to end are all pairs in the
+    sort's order, so the rows move by that permutation and its inverse:
+    :func:`_token_rows` lays the token rows (and the pairs' weights) out
+    by one gather a layer, a chunk takes its slice, writes its weighed
+    float32 output rows into the layer's stack, and :func:`_slot_sums`
+    gathers the stack back by the inverse permutation and sums a token's
+    ``top_k`` slots. Each of the two is the other's transpose, and the
+    backward below is written with them: a chunk gathers its rows of the
+    cotangent (``dy[tok]``, never a stack to slice), is differentiated
+    with its forward made again, and writes its rows' gradient into a
+    stack that one gather by the inverse permutation sums back a token.
+    No pass scatter-adds rows. The loops are written out, and not a
+    ``lax.scan`` over stacked inputs and outputs, so that a chunk's slice
+    and write carry the scope of the end they belong to: the dispatch's
+    under ``magi_moe_gather``, the combine's under ``magi_moe_scatter``
+    (a scan's own copies carry the scan's name alone)."""
+    return _sorted_rows_forward(rows, h, w, order, bounds, weights)[0]
+
+
+def _sorted_rows_fwd(rows, h, w, order, bounds, weights):
+    for end in ("dispatch", "combine"):
+        telemetry.record_moe_rows_permuted(end)
+    return _sorted_rows_forward(rows, h, w, order, bounds, weights)
+
+
+def _sorted_rows_bwd(rows, res, g):
+    xs, ws, tok, inv, bounds, weights = res
+    t, n_rows = g.shape[0], xs.shape[0]
+
+    def chunk(lo, carry):
+        dxs, dws, dweights = carry
+        with named_scope("magi_moe_gather"):
+            x, wc = _chunk_of(xs, lo, rows), _chunk_of(ws, lo, rows)
+        with named_scope("magi_moe_scatter"):
+            # padding's rows read token 0's: no held pair, so no gradient
+            g_rows = g[_chunk_of(tok, lo, rows)]
+        _, vjp = jax.vjp(
+            lambda x, wc, weights: _whole_chunk(x, wc, lo, bounds, weights),
+            x, wc, weights,
+        )
+        dx, dwc, dchunk = vjp(g_rows)
+        with named_scope("magi_moe_gather"):
+            dxs = jax.lax.dynamic_update_slice_in_dim(dxs, dx, lo, 0)
+            dws = jax.lax.dynamic_update_slice_in_dim(dws, dwc, lo, 0)
+        with named_scope("magi_moe_matmul"):
+            dweights = jax.tree.map(jnp.add, dweights, dchunk)
+        return dxs, dws, dweights
+
+    with named_scope("magi_moe_gather"):
+        dxs, dws = jnp.zeros_like(xs), jnp.zeros_like(ws)
+    with named_scope("magi_moe_matmul"):
+        dweights = jax.tree.map(jnp.zeros_like, weights)
+    dxs, dws, dweights = _each_chunk(
+        n_rows // rows, rows, chunk, (dxs, dws, dweights)
+    )
+    with named_scope("magi_moe_gather"):
+        dh = _slot_sums(dxs, inv, t).astype(xs.dtype)
+        dw = dws[inv].reshape(t, -1)
+    return dh, dw, None, None, dweights
+
+
+_experts_on_sorted_rows.defvjp(_sorted_rows_fwd, _sorted_rows_bwd)
+
+
 def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     """sum_k w_k expert_k(h) over the token-expert pairs whose expert
     this rank holds; returns (y [t, dim] float32, pairs a held expert
@@ -1176,7 +1342,34 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     is skipped: a step does the work of ``top_k t`` pairs whatever the
     router sends here (PERF.md section 6, PR 42: a chunk costs a layer
     21 ms of a step, and ran for one seed's router and not for
-    another's)."""
+    another's).
+
+    The rows move in one of two forms, chosen by whether every chunk runs
+    (``flat_expert_rows``; PERF.md section 6, PR 50). Where it does, the
+    chunks' rows laid end to end are all ``top_k t`` pairs in the sort's
+    order, a permutation, and :func:`_experts_on_sorted_rows` moves them
+    by it and its inverse: one gather a layer lays the token rows (and
+    the pairs' weights) out, a chunk weighs its bf16 output rows in
+    float32, and one gather by the inverse permutation with a float32 sum
+    over a token's ``top_k`` slots brings them back; each is the other's
+    transpose, so no pass scatter-adds rows. The weights are applied
+    inside the chunk, whose backward has its output again: applied after
+    the gather, their gradient would keep every chunk's output alive
+    across the layer's remat, and the layer's recomputed forward would
+    run all the grouped matmuls a third time (measured: SDAR's step
+    1,028 ms that way, 978 this way).
+    Where chunks are skipped (Trinity, GLM), a chunk gathers and
+    scatter-adds its own ``2 t`` rows; the TPU compiler re-sorts a
+    scatter-add's float32 update rows by token first. They keep that form
+    for its memory: the other stacks every slot's float32 output row, at
+    Trinity's shapes (32,768 tokens, top-8, 2,048 wide) 2.1 GB a layer
+    where a chunk's are 0.5, on a compiled step that holds 13.76 of the
+    15.5 GB there are. Whether the gathers would also be slower there is
+    not measured: by bytes the two are even at top-8 with one chunk
+    running (3.2 GB a pass), but a row gather runs at a third of the
+    scatters' pace on the chip, so the count in bytes decides nothing.
+    When the experts' exchange brings real rows to every slot (ROADMAP
+    R7) the scatter form goes."""
     dt = cfg.jnp_dtype
     t, k = idx.shape
     rows = min(2, k) * t
@@ -1191,35 +1384,28 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
         ends = jnp.cumsum(counts)
         starts = ends - counts
         n_here = ends[-1]
-    w_flat = w.reshape(-1)
     with named_scope("magi_moe_matmul"):
         we_gate, we_up, we_down = (
             layer[n].astype(dt) for n in ("we_gate", "we_up", "we_down")
         )
 
+    bounds = (starts, ends, n_here)
+    weights = (we_gate, we_up, we_down)
+    if cfg.flat_expert_rows:
+        y = _experts_on_sorted_rows(rows, h, w, order, bounds, weights)
+        return y, counts
+
+    w_flat = w.reshape(-1)
+
     @jax.checkpoint  # a chunk keeps its inputs only
     def chunk(h, w_flat, pairs, lo):
         with named_scope("magi_moe_sort"):
-            sizes = (
-                jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
-            )
-            valid = (lo + jnp.arange(rows, dtype=jnp.int32) < n_here)[:, None]
-            if cfg.flat_expert_rows:  # the zero rows: the last group's
-                sizes = sizes.at[-1].add(rows - sizes.sum())
+            sizes, valid = _chunk_groups(lo, rows, *bounds)
             tok = pairs // k
-
-        def grouped(x, w):
-            # rows past the held pairs belong to no group: what the
-            # kernel leaves there, and what its transpose leaves in
-            # their cotangent, is not data (the chip leaves NaNs)
-            x = jnp.where(valid, x, 0)
-            return jnp.where(valid, jax.lax.ragged_dot(x, w, sizes), 0)
-
         with named_scope("magi_moe_gather"):
             xs = h[tok]
         with named_scope("magi_moe_matmul"):
-            a, b = grouped(xs, we_gate), grouped(xs, we_up)
-            o = grouped(jax.nn.silu(a) * b, we_down)
+            o = _grouped_ffn(xs, sizes, valid, *weights)
         with named_scope("magi_moe_scatter"):
             o = o.astype(jnp.float32) * w_flat[pairs][:, None]
             return jnp.zeros((t, cfg.dim), jnp.float32).at[tok].add(o)
@@ -1235,8 +1421,6 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
             with named_scope("magi_moe_scatter"):
                 return y + o
 
-        if cfg.flat_expert_rows:  # the same work whatever reaches it
-            return add_chunk(y), None
         return jax.lax.cond(reached, add_chunk, lambda y: y, y), None
 
     with named_scope("magi_moe_sort"):

@@ -75,6 +75,7 @@ from .collectors import (  # noqa: F401
     record_mask_step,
     record_flex_kernel_build,
     record_model_attn_plan,
+    record_moe_rows_permuted,
     record_handed_on,
     record_mhc,
     record_mla_kv_cast_width,
@@ -370,6 +371,7 @@ __all__ = [
     "record_mask_step",
     "record_flex_kernel_build",
     "record_model_attn_plan",
+    "record_moe_rows_permuted",
     "record_handed_on",
     "record_mhc",
     "record_mla_kv_cast_width",

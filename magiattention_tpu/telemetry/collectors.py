@@ -154,6 +154,11 @@ M_FLEX_FORWARD_KEPT = "magi_flex_forward_kept_total"
 # over the mean of the held experts: {layer=}
 M_MOE_PAIRS_HERE = "magi_moe_pairs_here"
 M_MOE_LOAD_MAX_OVER_MEAN = "magi_moe_load_max_over_mean"
+# counter — expert layers differentiated with their rows moved by the
+# sort's permutation (models/pattern.held_expert_ffn where every chunk of
+# pair rows runs), counted once at each end of the path where the rule's
+# forward is traced: {end=combine|dispatch}
+M_MOE_ROWS_PERMUTED = "magi_moe_rows_permuted_total"
 # gauge — what a key-value cast carries a token under latent attention,
 # in elements, set where a latent model is built (build_magi_pattern):
 # {form=expanded} every head's k and v as the kernels take them (what the
@@ -1246,6 +1251,16 @@ def record_flex_forward_kept(kind: str) -> None:
     if not _enabled():
         return
     get_registry().counter_inc(M_FLEX_FORWARD_KEPT, kind=kind)
+
+
+def record_moe_rows_permuted(end: str) -> None:
+    """One expert layer differentiated with its rows moved by the sort's
+    permutation at ``end`` (``combine`` / ``dispatch``;
+    ``models/pattern.held_expert_ffn`` where every chunk runs, while jax
+    traces the rule: never inside a compiled step)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_MOE_ROWS_PERMUTED, end=end)
 
 
 def record_moe_load(layer: int, counts) -> None:

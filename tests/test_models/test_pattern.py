@@ -159,20 +159,27 @@ def _dense_experts(h, idx, w, layer, e0, e1):
     return y
 
 
+ROWS_PERMUTED = "magi_moe_rows_permuted_total"
+
+
+@pytest.mark.parametrize("flat", [False, True], ids=["follows", "flat"])
 @pytest.mark.parametrize(
     "held,k,case",
     [((2, 6), 2, "uneven"), ((0, 8), 2, "every pair here"),
      ((2, 6), 2, "no pair here"), ((0, 8), 5, "a second chunk runs"),
      ((2, 5), 5, "a second chunk is skipped")],
 )
-def test_the_expert_layer_alone(held, k, case):
+def test_the_expert_layer_alone(held, k, case, flat):
     """Against a plain loop over the held experts, value and gradients,
     with one expert taking most pairs and one none; with every pair held
     here; with none; and at top-5, where the pairs fill three chunks of
     2 t rows (the last one padded) and the held ones reach the second
-    (every expert held) or stop in the first."""
+    (every expert held) or stop in the first. In both forms of row
+    movement: a chunk's own gather and scatter-add where the chunks
+    follow the pairs, the sort's permutation and its inverse where every
+    chunk runs (``flat_expert_rows``)."""
     t = 96
-    cfg = dataclasses.replace(CFG, expert_range=held)
+    cfg = dataclasses.replace(CFG, expert_range=held, flat_expert_rows=flat)
     rng = np.random.default_rng(11)
     with jax.enable_x64(False):
         layer = init_pattern_params(jax.random.PRNGKey(1), cfg)["layers"][1]
@@ -197,14 +204,30 @@ def test_the_expert_layer_alone(held, k, case):
 
         probe = jnp.asarray(rng.standard_normal((t, cfg.dim)), jnp.float32)
         outs = []
-        for fn in (got, want):
-            y, grads = jax.value_and_grad(
-                lambda *a: (fn(*a) * probe).sum(), argnums=(0, 1, 2)
-            )(h, w, layer)
-            outs.append((fn(h, w, layer), grads))
+        reg = telemetry.get_registry()
+        telemetry.set_enabled(True)
+        reg.clear_metric(ROWS_PERMUTED)
+        try:
+            for fn in (got, want):
+                y, grads = jax.value_and_grad(
+                    lambda *a: (fn(*a) * probe).sum(), argnums=(0, 1, 2)
+                )(h, w, layer)
+                outs.append((fn(h, w, layer), grads))
+            permuted = {
+                end: int(reg.counter_value(ROWS_PERMUTED, end=end))
+                for end in ("dispatch", "combine")
+            }
+        finally:
+            reg.clear_metric(ROWS_PERMUTED)
+            telemetry.set_enabled(False)
         counts = np.asarray(held_expert_ffn(h, idx, w, layer, cfg)[1])
-    flat = np.asarray(idx).ravel()
-    assert counts.tolist() == [int((flat == e).sum()) for e in range(*held)]
+    # one expert layer differentiated once: each end of the path counted
+    # where the rule is traced, and only where every chunk runs
+    assert permuted == dict.fromkeys(("dispatch", "combine"), int(flat))
+    chosen = np.asarray(idx).ravel()
+    assert counts.tolist() == [
+        int((chosen == e).sum()) for e in range(*held)
+    ]
     if case == "uneven":
         assert counts.max() > 4 * max(counts.mean(), 1) * 0.5 and 0 in counts
     if case == "every pair here":
@@ -215,6 +238,59 @@ def test_the_expert_layer_alone(held, k, case):
     assert _worst(outs[0][1], outs[1][1]) <= 1e-4 or case == "no pair here"
     if case == "no pair here":
         assert not np.asarray(outs[0][0]).any()
+
+
+@pytest.mark.parametrize("k,rows", [(2, 24), (5, 24), (1, 12)])
+def test_the_two_row_movements_are_each_others_transpose(k, rows):
+    """``_token_rows`` and ``_slot_sums``, the two ends of the expert path
+    where every chunk runs, on a random permutation of ``t k`` pairs in
+    whole chunks of ``rows`` (a padded tail at top-5): the second sums
+    back, ``top_k`` times over, what the first laid out, each is jax's own
+    transpose of the other (what ``_experts_on_sorted_rows``'s backward
+    relies on), and a token's number a pair (the weights) goes the same
+    way as its row."""
+    t, dim = 12, 8
+    n = t * k
+    rng = np.random.default_rng(5)
+    with jax.enable_x64(False):
+        order = jnp.asarray(rng.permutation(n), jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        order = jnp.pad(order, (0, -n % rows))
+        tok = order // k
+        n_rows = order.shape[0]
+        assert (n_rows > n) == (k == 5)
+        h = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
+        laid = jnp.asarray(rng.standard_normal((n_rows, dim)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal(n), jnp.float32)
+
+        xs, out_vjp = jax.vjp(lambda h: pattern._token_rows(h, tok, n), h)
+        back, back_vjp = jax.vjp(
+            lambda r: pattern._slot_sums(r, inv, t), laid
+        )
+        np.testing.assert_array_equal(xs[:n], h[tok[:n]])
+        assert xs.shape == laid.shape and not np.asarray(xs[n:]).any()
+        np.testing.assert_allclose(
+            back, laid[inv].reshape(t, k, dim).sum(1), rtol=1e-6, atol=1e-6
+        )
+        # the pairs' rows, summed back a token, are top_k times its row
+        np.testing.assert_allclose(
+            pattern._slot_sums(xs, inv, t), k * h, rtol=1e-6, atol=1e-6
+        )
+        g_xs = jnp.asarray(rng.standard_normal(xs.shape), jnp.float32)
+        g_back = jnp.asarray(rng.standard_normal(back.shape), jnp.float32)
+        np.testing.assert_allclose(
+            pattern._slot_sums(g_xs, inv, t), out_vjp(g_xs)[0],
+            rtol=1e-6, atol=1e-6,
+        )
+        np.testing.assert_array_equal(
+            pattern._token_rows(g_back, tok, n), back_vjp(g_back)[0]
+        )
+        # a pair's weight: each pair its own token, one slot
+        ws, ws_vjp = jax.vjp(lambda w: pattern._token_rows(w, order, n), w)
+        np.testing.assert_array_equal(ws[:n], w[order[:n]])
+        assert not np.asarray(ws[n:]).any()
+        g_ws = jnp.asarray(rng.standard_normal(n_rows), jnp.float32)
+        np.testing.assert_array_equal(ws_vjp(g_ws)[0], g_ws[inv])
 
 
 def test_llama_is_a_pattern_with_the_extras_off():

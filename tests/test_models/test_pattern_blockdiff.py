@@ -292,6 +292,18 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
 
+def _scatter_adds_into(jaxpr, shape) -> int:
+    """The ``scatter-add`` equations of ``jaxpr``, its sub-jaxprs too,
+    whose operand has ``shape``."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add":
+            found += eqn.invars[0].aval.shape == shape
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _scatter_adds_into(sub, shape)
+    return found
+
+
 @pytest.mark.parametrize("to_held", [0, 1, 4], ids="held{}of4".format)
 def test_every_chunk_runs_whatever_the_router_sends_here(
     params, to_held, monkeypatch
@@ -301,7 +313,10 @@ def test_every_chunk_runs_whatever_the_router_sends_here(
     program has no ``cond``, every grouped matmul of both chunks takes
     ``2 t`` rows whether the rows choose no held expert, one or four, and
     the output and the gradients are those of the form that follows the
-    pairs (which skips the second chunk unless a pair reaches it)."""
+    pairs (which skips the second chunk unless a pair reaches it). The
+    rows here move by the sort's permutation: no ``[t, dim]`` operand is
+    scatter-added into, forward or backward, where the other form's every
+    chunk scatter-adds into two."""
     _hf, flat = _sdar()
     plain = dataclasses.replace(flat, flat_expert_rows=False)
     t = 48
@@ -335,6 +350,16 @@ def test_every_chunk_runs_whatever_the_router_sends_here(
             for cfg in (flat, plain)
         }
         assert " cond[" not in text[True] and " cond[" in text[False]
+        into_rows = {
+            cfg.flat_expert_rows: _scatter_adds_into(
+                jax.make_jaxpr(
+                    jax.grad(lambda h: out(cfg, h, layer)[0])
+                )(h).jaxpr,
+                (t, flat.dim),
+            )
+            for cfg in (flat, plain)
+        }
+        assert into_rows == {True: 0, False: 4}  # y and dh, two chunks
         monkeypatch.setattr(jax.lax, "ragged_dot", spy)
         _, (_, counts) = out(flat, h, layer)
         jax.effects_barrier()
