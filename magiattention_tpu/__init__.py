@@ -18,6 +18,30 @@ Layering (mirrors reference SURVEY.md layer map, re-designed TPU-first):
 - ``testing/``  : reference oracles + precision harness
 """
 
+import sys as _sys
+import time as _time
+
+# the package's first statement, as a clock read: where ``process_boot``
+# ends and ``package_import`` begins (telemetry/events.post_boot_spans
+# turns these marks into spans the first time telemetry is on; nothing
+# is recorded here, telemetry on or off)
+_BOOT = {
+    "began": _time.perf_counter(),
+    "jax_before": "jax" in _sys.modules,
+    "backend_before": False,
+    "jax_import_s": 0.0,
+}
+if _BOOT["jax_before"]:
+    from .utils.compat import backends_are_initialized as _ready  # noqa: E402
+
+    _BOOT["backend_before"] = _ready()
+else:
+    # the package imports jax below whoever asks for it (telemetry does):
+    # first here, so that its share of the import is known
+    import jax as _jax  # noqa: E402,F401
+
+    _BOOT["jax_import_s"] = _time.perf_counter() - _BOOT["began"]
+
 __version__ = "0.4.0"
 
 # reference magi_attention/__init__.py:61-83 — an explicitly-set
@@ -73,3 +97,5 @@ __all__ = [
     "utils",
     "__version__",
 ]
+
+_BOOT["ended"] = _time.perf_counter()  # the package's last statement

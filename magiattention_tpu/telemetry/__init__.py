@@ -147,6 +147,7 @@ from .events import (  # noqa: F401
     annotate_span,
     get_event_buffer,
     key_id,
+    post_boot_spans,
     record_event,
     span,
     span_self_seconds,
@@ -248,10 +249,14 @@ def set_enabled(value: bool | None) -> None:
     usually just set the env var. While it is on, the persistent
     compilation cache's key holds the program's metadata, so that a
     traced run sees the scopes of the code it runs
-    (:func:`compile.key_cache_on_metadata`)."""
+    (:func:`compile.key_cache_on_metadata`). The first time it comes on
+    in a process, the ring gets the process's own two spans,
+    ``process_boot`` and ``package_import``
+    (:func:`events.post_boot_spans`)."""
     global _enabled_override
     _enabled_override = value
     key_cache_on_metadata(enabled())
+    post_boot_spans()
 
 
 def snapshot() -> dict:
@@ -262,7 +267,9 @@ def snapshot() -> dict:
 
 def reset() -> None:
     """Clear the global registry, the span ring buffer, and the
-    per-request trace sequence counters."""
+    per-request trace sequence counters. The process's two start-up
+    spans go with the ring: ``post_boot_spans(again=True)`` posts them
+    anew."""
     get_registry().reset()
     get_event_buffer().clear()
     reset_request_traces()
@@ -346,6 +353,7 @@ __all__ = [
     "merge_snapshots",
     "parse_prometheus_text",
     "plan_memory_ledger",
+    "post_boot_spans",
     "prefill_program_label",
     "profile_key_timeline",
     "profile_plan_timeline",

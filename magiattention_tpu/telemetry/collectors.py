@@ -1956,8 +1956,10 @@ def record_fleet_knob(knob: str, value: float) -> None:
 
 def telemetry_summary(snapshot: dict | None = None) -> str:
     """Human-readable block of the headline plan/comm metrics. Works on
-    any snapshot dict (defaults to the live registry's)."""
-    if snapshot is None:
+    any snapshot dict (defaults to the live registry's, and then ends
+    with how this process started, from its span ring)."""
+    live = snapshot is None
+    if live:
         snapshot = get_registry().snapshot()
     g = snapshot.get("gauges", {})
     c = snapshot.get("counters", {})
@@ -2091,5 +2093,25 @@ def telemetry_summary(snapshot: dict | None = None) -> str:
             f"  memory probe{labels}: predicted {fmt(pred)} B, "
             f"io delta {fmt(g.get(key))}, unattributed "
             f"{fmt(g.get(M_MEM_UNATTRIBUTED + labels))} B temp"
+        )
+    # how this process started (ISSUE 51): the ring's two start-up spans,
+    # the only line here that no registry series stands behind, so a
+    # snapshot handed in (merged, or another process's) does not get it
+    from .events import get_event_buffer
+
+    boot = {
+        ev["name"]: ev for ev in get_event_buffer().events()
+        if live and ev["name"] in ("process_boot", "package_import")
+    }
+    if len(boot) == 2:
+        args = boot["process_boot"]["args"]
+        lines.append(
+            f"  start-up: process boot "
+            f"{fmt(boot['process_boot']['dur'] / 1e6)} s "
+            f"(clock {args['source']}; jax imported before the package: "
+            f"{args['jax_imported_before']}, backend up: "
+            f"{args['backend_ready_before']})  package import "
+            f"{fmt(boot['package_import']['dur'] / 1e6)} s (jax "
+            f"{fmt(boot['package_import']['args']['jax_import_s'])} s)"
         )
     return "\n".join(lines)

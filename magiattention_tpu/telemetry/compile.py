@@ -449,14 +449,21 @@ def key_cache_on_metadata(on: bool) -> None:
         _key_metadata_was = None
 
 
+# the compat shim's verdict once the listeners are in: jax.monitoring has
+# no deregistration, so they go in once a process, whatever becomes of
+# the tracker (a second set would count every compile twice: ROADMAP D7)
+_ingestion: str | None = None
+
+
 def get_compile_tracker() -> CompileTracker:
-    """The process-wide tracker; first call installs the monitoring
-    listeners (via the compat shim — "monitoring", or "none" when the
-    hook is missing; the tracker still works for directly-planted
-    events either way) and, where telemetry is on by the env flag alone,
-    keys the persistent cache on metadata (:func:`key_cache_on_metadata`;
-    ``telemetry.set_enabled`` does it for the programmatic switch)."""
-    global _tracker
+    """The process-wide tracker; the first call of a process installs
+    the monitoring listeners (via the compat shim — "monitoring", or
+    "none" when the hook is missing; the tracker still works for
+    directly-planted events either way) and, where telemetry is on by
+    the env flag alone, keys the persistent cache on metadata
+    (:func:`key_cache_on_metadata`; ``telemetry.set_enabled`` does it
+    for the programmatic switch)."""
+    global _tracker, _ingestion
     if _tracker is None:
         with _tracker_lock:
             if _tracker is None:
@@ -464,9 +471,11 @@ def get_compile_tracker() -> CompileTracker:
                 from . import enabled
                 from ..utils.compat import register_compile_listeners
 
-                tracker.ingestion = register_compile_listeners(
-                    _on_event, _on_duration, _on_phase_start, _on_phase_end
-                )
+                if _ingestion is None:
+                    _ingestion = register_compile_listeners(
+                        _on_event, _on_duration, _on_phase_start, _on_phase_end
+                    )
+                tracker.ingestion = _ingestion
                 key_cache_on_metadata(enabled())
                 _tracker = tracker
     return _tracker

@@ -24,6 +24,10 @@ span's own time. While jax is loaded a live span is also written as
 ``jax.profiler.TraceAnnotation("magi:" + name)``, which the profiler
 keeps only while one of its sessions records: the same span then sits
 in the ``.xplane.pb`` on the profiler's clock, beside ``XLA Ops``.
+
+Two spans are the process's own (ISSUE 51): ``process_boot`` (process
+start to the package's first statement) and ``package_import``, posted
+once a process the first time telemetry is on (:func:`post_boot_spans`).
 """
 
 from __future__ import annotations
@@ -354,6 +358,8 @@ def begin_span(name: str, attrs: dict | None = None) -> LiveSpan:
     """Open a span by hand (the compile tracker's listeners learn of a
     jax phase's start and end in two separate calls); pair with
     :func:`end_span`. Call sites check :func:`telemetry.enabled`."""
+    if not _boot_posted:
+        post_boot_spans()  # telemetry came on by the env flag alone
     live = LiveSpan(name, _live.get(), dict(attrs) if attrs else {})
     _live.set(live)
     jax = sys.modules.get("jax")
@@ -404,6 +410,8 @@ def record_event(
 
     if not enabled():
         return
+    if not _boot_posted:
+        post_boot_spans()
     parent = _live.get()
     if parent is not None and "key" in parent.attrs:
         attrs = {"key": parent.attrs["key"], **(attrs or {})}
@@ -413,6 +421,69 @@ def record_event(
     )
     if parent is not None:
         parent._below.append(ev)
+
+
+# ---------------------------------------------------------------------------
+# the process's own two spans: boot and package import (ISSUE 51)
+# ---------------------------------------------------------------------------
+
+_PROC_STAT = "/proc/self/stat"
+_boot_posted = False
+
+
+def process_age_seconds() -> float | None:
+    """Seconds since this process started: field 22 of ``/proc/self/stat``
+    (its start, in clock ticks since the machine's boot) against
+    ``CLOCK_BOOTTIME``; None where that cannot be read (no ``/proc``)."""
+    try:
+        with open(_PROC_STAT) as f:
+            # the command (field 2) may hold spaces: count from its ")"
+            after_comm = f.read().rsplit(")", 1)[1].split()
+        started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if age >= 0.0 else None
+
+
+def post_boot_spans(again: bool = False) -> None:
+    """Post ``process_boot`` and ``package_import`` as ended spans, from
+    the clock reads that ``magiattention_tpu/__init__.py`` made as its
+    first and last statement. Once a process, the first time telemetry
+    is on (``set_enabled(True)``, or the first span under the env flag);
+    ``again=True`` posts them anew, for a reader whose ``telemetry.reset()``
+    cleared the ring. Nothing while telemetry is off.
+
+    ``process_boot`` starts at the process's start carried onto this
+    ring's clock (:func:`process_age_seconds`); where that is unknown it
+    has zero length and ``source="unknown"``. Its attributes say whether
+    jax's import and the backend's start (on a TPU host, seconds of
+    runtime start-up) lie inside it."""
+    global _boot_posted
+    if _boot_posted and not again:
+        return
+    from . import enabled
+    from .. import _BOOT as marks
+
+    if not enabled() or "ended" not in marks:  # off, or still importing
+        return
+    _boot_posted = True
+    began, ended = marks["began"], marks["ended"]
+    age = process_age_seconds()
+    start = began if age is None else min(time.perf_counter() - age, began)
+    buffer = get_event_buffer()
+    buffer.record(
+        "process_boot", start, began - start,
+        {
+            "source": "unknown" if age is None else "proc_stat",
+            "jax_imported_before": marks["jax_before"],
+            "backend_ready_before": marks["backend_before"],
+        },
+    )
+    buffer.record(
+        "package_import", began, ended - began,
+        {"jax_import_s": marks["jax_import_s"]},
+    )
 
 
 @contextlib.contextmanager

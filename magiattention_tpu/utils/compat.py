@@ -37,6 +37,18 @@ def shard_map(
     )
 
 
+def backends_are_initialized() -> bool:
+    """Has this process brought a jax backend up yet (on a TPU host:
+    has it started the TPU runtime)? ``jax._src.xla_bridge`` holds the
+    only answer; False where this jax has none."""
+    try:
+        from jax._src import xla_bridge
+
+        return bool(xla_bridge.backends_are_initialized())
+    except (ImportError, AttributeError):
+        return False
+
+
 def tpu_compiler_params(**kwargs):
     """``pltpu.CompilerParams`` — the flex-attention kernels and the
     serving decode kernel both launch through this."""

@@ -19,17 +19,43 @@ logger = logging.getLogger("magiattention_tpu.utils.instrument")
 
 
 def named_scope(name: str):
-    """Plain ``jax.named_scope`` context for traced regions (overlap-stage
-    kernels, group casts/reduces): the scope name survives into XLA
-    metadata, so ``jax.profiler`` / Perfetto device traces show
-    ``magi_stage0_cast``-style labels instead of anonymous fusions.
+    """``jax.named_scope`` context for traced regions (overlap-stage
+    kernels, group casts/reduces, a model's parts): the scope name
+    survives into XLA metadata, so ``jax.profiler`` / Perfetto device
+    traces show ``magi_stage0_cast``-style labels instead of anonymous
+    fusions, and a metric file reads a part's share of the device's time.
 
-    Trace-time-only cost (nothing at run time, nothing recorded host-side),
-    so it is applied unconditionally — unlike ``telemetry.span``, which
-    records host spans and must stay out of traced code."""
+    The package's one door to a device scope. Nothing at run time. At
+    trace time, with telemetry off, one predicate; with telemetry on and
+    a jax phase span live (jax is tracing the caller, or lowering a
+    kernel whose body Mosaic traces: ``telemetry/compile._live_phase``),
+    the region is also a host span ``trace_part`` with ``scope=<name>``,
+    child of the innermost live span: the Python time a trace spends in
+    each part (``docs/observability.md``, "Spans"). The ``jax.named_scope``
+    emitted is the same either way, and an eager call records nothing."""
     import jax
 
-    return jax.named_scope(name)
+    scope = jax.named_scope(name)
+    from ..telemetry import enabled
+
+    if enabled():
+        from ..telemetry.compile import _live_phase
+
+        if _live_phase() is not None:
+            return _trace_part(scope, name)
+    return scope
+
+
+@contextlib.contextmanager
+def _trace_part(scope, name: str):
+    from ..telemetry.events import begin_span, end_span
+
+    live = begin_span("trace_part", {"scope": name})
+    try:
+        with scope:
+            yield
+    finally:
+        end_span(live)
 
 
 # jax.profiler supports one trace session per process; this guard makes our

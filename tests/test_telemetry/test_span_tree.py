@@ -201,18 +201,22 @@ def test_live_profiler_session_holds_the_spans_nested(tmp_path):
 def test_jax_phases_land_as_spans_with_fun_name():
     tracker = telemetry.get_compile_tracker()
     assert tracker.ingestion == "monitoring"
+    # by order, not by what else this worker ran (ROADMAP D7): no span of
+    # another test's is live for the phases to be folded into, ...
+    assert events.current_span() is None
 
     def magi_span_probe(x):
-        # nested jitted jnp calls. A constant and a length no other test
-        # jits: the program is in no cache of this process, and far too
-        # quick to compile for the persistent one (which keeps what took a
-        # second), so it is compiled here, once
+        # nested jitted jnp calls, a constant and a length no other test
+        # jits: in no cache of this process. The persistent cache may hold
+        # it (the harness and the suite keep every program, however quick):
+        # a load is a backend-compile phase all the same
         return jnp.sin(x) * 3.0451 + jnp.cos(x)
 
-    x = jnp.arange(45.0)  # its own small program, before the mark
-    mark = tracker.mark()
-    with telemetry.span("around") as around:
-        jax.block_until_ready(jax.jit(magi_span_probe)(x))
+    x = jnp.arange(45.0)  # its own small program, before the probe's
+    # ... and the compile is counted on a label of the probe's own
+    with telemetry.program("magi_span_probe"):
+        with telemetry.span("around") as around:
+            jax.block_until_ready(jax.jit(magi_span_probe)(x))
     mine = {
         n: [e for e in evs if "magi_span_probe" in e["args"].get("fun_name", "")]
         for n, evs in _by_name().items()
@@ -221,15 +225,43 @@ def test_jax_phases_land_as_spans_with_fun_name():
         (ev,) = mine[name]  # one each: nested phases are folded in
         assert ev["args"]["parent"] == around.id
         assert ev["dur"] > 0
-    # the tracker's meaning is unchanged: backend compiles only
-    compiles, seconds = tracker.since(mark)
-    assert compiles == 1
+    # the tracker's meaning is unchanged: backend compiles only, each
+    # counted once (the listeners go in once a process)
+    stats = tracker.stats()["magi_span_probe"]
+    assert stats["count"] == 1
     # held by order, not by a ratio of two clocks (under six busy workers
-    # they drift apart: ROADMAP D7): the tracker's seconds are the
-    # compile's, and the compile happened inside the span
+    # they drift apart): the tracker's seconds are the compile's, and the
+    # compile happened inside the span
     (enclosing,) = [e for e in _by_name()["around"] if e["args"]["id"] == around.id]
-    assert 0 < seconds <= enclosing["dur"] / 1e6
+    assert 0 < stats["total_s"] <= enclosing["dur"] / 1e6
     assert events.current_span() is None
+
+
+def test_the_listeners_go_in_once_a_process(monkeypatch):
+    """A tracker made anew (a test that empties ``compile._tracker``)
+    shares the listeners the first one installed: ``jax.monitoring`` has
+    no deregistration, and a second set counted every compile twice."""
+    from magiattention_tpu.telemetry import compile as tc
+    from magiattention_tpu.utils import compat
+
+    first = telemetry.get_compile_tracker()
+    calls = []
+    monkeypatch.setattr(
+        compat, "register_compile_listeners",
+        lambda *a, **k: calls.append(a) or "monitoring",
+    )
+    monkeypatch.setattr(tc, "_tracker", None)
+    second = tc.get_compile_tracker()
+    assert second is not first and calls == []
+    assert second.ingestion == first.ingestion == "monitoring"
+
+    def magi_once_probe(x):
+        return jnp.cos(x) * 2.0451
+
+    x = jnp.arange(46.0)  # its own small program, outside the label
+    with telemetry.program("magi_once_probe"):
+        jax.block_until_ready(jax.jit(magi_once_probe)(x))
+    assert second.stats()["magi_once_probe"]["count"] == 1
 
 
 def test_phase_that_began_with_telemetry_off_is_recorded_whole():
