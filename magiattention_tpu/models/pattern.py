@@ -1760,6 +1760,103 @@ def _ffn_out(x, layer, carry, *, cfg, ffn_type):
 # ---------------------------------------------------------------------------
 
 
+def _bf16_pieces(a):
+    """``a`` as bfloat16 arrays whose float32 sum is ``a`` to the bit: a
+    bfloat16 array is its own one piece, a float32 array three (8 + 8 + 8
+    of its 24 significant bits). The residues are taken in float32 after
+    ``reduce_precision``, not after a cast to bfloat16 and back, which a
+    compiler that is allowed excess precision may take out."""
+    if a.dtype == jnp.bfloat16:
+        return [a]
+    a = a.astype(jnp.float32)
+    hi = jax.lax.reduce_precision(a, 8, 7)
+    mid = jax.lax.reduce_precision(a - hi, 8, 7)
+    return [p.astype(jnp.bfloat16) for p in (hi, mid, a - hi - mid)]
+
+
+def _bf16_dot(a, b):
+    """``a^T b^T``: one MXU pass of bfloat16 operands into float32."""
+    return jax.lax.dot_general(
+        a, b, (((0,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _pieces_dot(xs, ds):
+    """The float32 sum over ``i + j <= 2`` of ``xs[i]^T ds[j]^T``: the six
+    products of bf16 pieces that ``Precision.HIGHEST`` keeps of a float32
+    product's nine, each exact in float32, where the MXU sums them. One
+    pass a piece of ``xs``, ``ds``'s pieces side by side along their rows."""
+    total = 0.0
+    for i, x in enumerate(xs):
+        side = ds[: 3 - i]
+        out = _bf16_dot(x, jnp.concatenate(side))  # [dim, pieces k]
+        total = total + out.reshape(out.shape[0], len(side), -1).sum(axis=1)
+    return total
+
+
+def _mhc_normed_fwd(x, phi, eps, cdt, n):
+    with named_scope("magi_mhc_coef"):
+        xc = x.astype(cdt)
+        p = jax.lax.dot_general(  # [k, t]
+            phi, xc, (((0,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # float32 means float32
+            preferred_element_type=cdt,
+        )
+        r = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1) + eps)
+        return p * r[None], (x, phi, p, r)
+
+
+def _mhc_normed_bwd(eps, cdt, n, res, dm):
+    x, phi, p, r = res
+    with named_scope("magi_mhc_coef"):
+        phis, dps = _bf16_pieces(phi), _bf16_pieces(dm * r[None])
+        # as it is kept where that is bfloat16: one piece, one pass
+        xs = _bf16_pieces(x if x.dtype == jnp.bfloat16 else x.astype(cdt))
+        dphi = jnp.concatenate([  # [n dim, k], a stream at a time
+            _pieces_dot(stream, dps)
+            for stream in zip(*(_streams(piece, n) for piece in xs))
+        ])
+        # the state's cotangent: the same six products in one contraction,
+        # and the norm's term (d rsqrt(mean(x^2) + eps) = -r^3 x / width)
+        # added before the one rounding to the state's dtype
+        pairs = [(d, q) for i, d in enumerate(dps) for q in phis[: 3 - i]]
+        dx = _bf16_dot(  # [t, n dim]
+            jnp.concatenate([d for d, _ in pairs], axis=0),
+            jnp.concatenate([q for _, q in pairs], axis=1),
+        ).astype(cdt)
+        dr = (dm * p).sum(axis=0)
+        dx = dx - (dr * r**3 / x.shape[1])[:, None] * x.astype(cdt)
+        return dx.astype(x.dtype), dphi.astype(phi.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _mhc_normed(x, phi, eps, cdt, n):
+    """``(phi^T x) rsqrt(mean(x^2) + eps)`` [k, t] in ``cdt`` of a state
+    ``x`` [t, n dim] of ``n`` streams and ``phi`` [n dim, k] in ``cdt``,
+    float32 in every number where ``cdt`` is, forward and backward.
+
+    Forward, a ``Precision.HIGHEST`` product on the state's copy in
+    ``cdt``: the TPU compiler hands that convolution a bfloat16 state as
+    it is kept and spends nothing on its copy's zero pieces (PERF.md
+    section 6, PR 52), so there is nothing to write out. Backward, where
+    both operands of either product are float32 and ``HIGHEST`` makes six
+    real passes, the rule is written out in bf16 pieces
+    (:func:`_bf16_pieces`, :func:`_pieces_dot`): ``d phi = x^T [dm_hi |
+    dm_mid | dm_lo]`` in ONE pass over a bfloat16 state (one piece; three
+    over a float32 one), a stream at a time as the state is held, and
+    ``dx`` the six products of ``dm``'s and ``phi``'s pieces in one
+    contraction, each bf16 x bf16 product exact in float32, where the MXU
+    sums them. The norm's factor is inside the rule so that its term joins
+    ``dx`` before the state's dtype rounds it, once, as autodiff of the
+    float32 copy did. Residuals: ``x``, ``phi``, the product and the
+    factor ([k, t] and [t]): nothing of the state's size that a layer's
+    ``jax.checkpoint`` does not hold already."""
+    return _mhc_normed_fwd(x, phi, eps, cdt, n)[0]
+
+
+_mhc_normed.defvjp(_mhc_normed_fwd, _mhc_normed_bwd)
+
+
 def _mhc_coef(x, w: dict, cfg: PatternConfig):
     """A half-layer's per-token coefficients from the streams' state ``x``
     [t, n dim]: (``h_pre`` [n, t], ``h_post`` [n, t], ``h_res`` [n, n, t]),
@@ -1769,7 +1866,10 @@ def _mhc_coef(x, w: dict, cfg: PatternConfig):
 
     ``m = (x phi) rsqrt(mean(x^2) + eps)``: the projection first, the
     norm's factor after (one pass over ``x`` gives both; the norm's weight
-    is folded into ``phi``). ``h_pre = sigmoid(alpha_1 m[:n] + b[:n])``,
+    is folded into ``phi``), float32 whatever the state is kept in
+    (:func:`_mhc_normed`: a ``HIGHEST`` product forward, a backward rule
+    written out in bf16 pieces, one pass over a bfloat16 state).
+    ``h_pre = sigmoid(alpha_1 m[:n] + b[:n])``,
     ``h_post = 2 sigmoid(alpha_2 m[n:2n] + b[n:2n])``; the rest, an n x n
     matrix row by row, times ``alpha_3`` plus ``b``'s, clamped, through
     ``exp`` and ``hc_sinkhorn_iters`` rounds of (rows over their sum +
@@ -1777,14 +1877,12 @@ def _mhc_coef(x, w: dict, cfg: PatternConfig):
     neither grows nor shrinks what the streams carry."""
     n, cdt = cfg.hc_mult, jnp.dtype(cfg.hc_dtype)
     t = x.shape[0]
+    telemetry.record_mhc_coef(
+        "bf16_one_pass" if jnp.bfloat16 in (x.dtype, cdt)
+        else "float32_three_pass"
+    )
     with named_scope("magi_mhc_coef"):
-        xc = x.astype(cdt)
-        m = jax.lax.dot_general(  # [n^2 + 2n, t]
-            w["phi"].astype(cdt), xc, (((0,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,  # float32 means float32
-            preferred_element_type=cdt,
-        )
-        m = m * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1) + cfg.rms_eps)[None]
+        m = _mhc_normed(x, w["phi"].astype(cdt), cfg.rms_eps, cdt, n)
         a, b = w["alpha"].astype(cdt), w["b"].astype(cdt)[:, None]
         h_pre = jax.nn.sigmoid(a[0] * m[:n] + b[:n])
         h_post = 2.0 * jax.nn.sigmoid(a[1] * m[n : 2 * n] + b[n : 2 * n])

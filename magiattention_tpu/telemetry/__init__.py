@@ -78,6 +78,7 @@ from .collectors import (  # noqa: F401
     record_moe_rows_permuted,
     record_handed_on,
     record_mhc,
+    record_mhc_coef,
     record_mla_kv_cast_width,
     record_ssm_scan,
     record_model_loop,
@@ -382,6 +383,7 @@ __all__ = [
     "record_moe_rows_permuted",
     "record_handed_on",
     "record_mhc",
+    "record_mhc_coef",
     "record_mla_kv_cast_width",
     "record_ssm_scan",
     "record_model_loop",

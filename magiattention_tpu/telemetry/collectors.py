@@ -199,6 +199,10 @@ M_FLEX_PAD_LANE_SHARE = "magi_flex_pad_lane_share"
 M_MHC_STREAMS = "magi_mhc_streams"
 M_MHC_SINKHORN_ITERS = "magi_mhc_sinkhorn_iters"
 M_MHC_STREAM_BYTES = "magi_mhc_stream_bytes"
+# counter — half-layers whose coefficients were traced, by the passes their
+# product makes over the state: {form=bf16_one_pass|float32_three_pass}
+# (models/pattern._mhc_coef; a compiled step counts each half-layer once)
+M_MHC_COEF_HALVES = "magi_mhc_coef_halves"
 
 # gauges — measured stage timelines (telemetry/timeline.py): what the
 # hardware actually did, next to what the overlap solver predicted
@@ -1342,6 +1346,14 @@ def record_mhc(*, streams: int, sinkhorn_iters: int, stream_bytes: int,
     reg.gauge_set(M_MHC_SINKHORN_ITERS, float(sinkhorn_iters))
     reg.gauge_set(M_MHC_STREAM_BYTES, float(stream_bytes))
     reg.gauge_set(M_FLEX_PAD_LANE_SHARE, float(pad_lane_share))
+
+
+def record_mhc_coef(form: str) -> None:
+    """One half-layer's coefficients traced (``models/pattern._mhc_coef``,
+    trace time: once a compiled program, like the named scopes)."""
+    if not _enabled():
+        return
+    get_registry().counter_inc(M_MHC_COEF_HALVES, form=form)
 
 
 def record_mla_kv_cast_width(*, expanded: int, latent: int) -> None:

@@ -411,6 +411,40 @@ def test_a_value_width_of_its_own_at_the_mhc_cells_size(topo, d, grid):
     assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
+def test_the_coefficients_product_of_a_bf16_state_at_the_mhc_cells_size(topo):
+    """ISSUE 52: ``_mhc_normed`` and its backward rule on the cell's
+    8,192 x 14,336 bfloat16 state: six convolutions, the forward's alone at
+    ``highest``, ``d
+    phi``'s a stream each and ``dx``'s one a bf16 x bf16 pass, the state's
+    cotangent an output in bfloat16, and no temporary of the size of ONE
+    float32 copy of the state: ``dx``'s float32 sum with the norm's term
+    lives inside its fusion."""
+    from magiattention_tpu.models.pattern import _mhc_normed
+
+    t, width, k = 8192, 4 * 3584, 24
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def both(x, phi, dm):
+        m, vjp = jax.vjp(
+            lambda x, phi: _mhc_normed(x, phi, 1e-6, jnp.dtype("float32"), 4),
+            x, phi,
+        )
+        return m, vjp(dm)
+
+    exe = jax.jit(both).lower(
+        _on(chip, (t, width)), _on(chip, (width, k), jnp.float32),
+        _on(chip, (k, t), jnp.float32),
+    ).compile()
+    text = exe.as_text()
+    products = [line for line in text.splitlines() if " convolution(" in line]
+    assert len(products) == 6, products
+    at_highest = [line for line in products if "highest" in line]
+    assert len(at_highest) == 1 and f"f32[{k},{t}]" in at_highest[0]
+    results = text.split("ENTRY")[1].split("\n")[0].split("->")[1]
+    assert f"bf16[{t},{width}]" in results and f"f32[{t},{width}]" not in results
+    assert exe.memory_analysis().temp_size_in_bytes < t * width * 4
+
+
 def test_the_mhc_cells_step_fits_one_chip(topo):
     """``xing4-train-8k-traces``'s whole AdamW step at the published
     widths, the cell's five layers, 8 of 64 experts and an eighth of the

@@ -155,6 +155,214 @@ def test_the_seeds_mixing_matrix_is_doubly_stochastic_and_a_tokens_own(
     np.testing.assert_allclose(np.asarray(h_pre).T, want[0], rtol=2e-5)
 
 
+MHC_GAUGES = ("magi_mhc_streams", "magi_mhc_sinkhorn_iters",
+              "magi_mhc_stream_bytes", "magi_flex_pad_lane_share")
+
+
+def _nested_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _nested_eqns(sub)
+
+
+def _highest_normed(x, phi, eps, cdt, n):
+    """What ``_mhc_normed`` replaces: a ``Precision.HIGHEST`` product on
+    the state's float32 copy, the norm's factor after, autodiff's rule."""
+    xc = x.astype(cdt)
+    m = jax.lax.dot_general(
+        phi, xc, (((0,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=cdt,
+    )
+    return m * jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1) + eps)[None]
+
+
+STATES = [
+    ("bfloat16", "the outputs"), ("float32", "the outputs"),
+    ("bfloat16", "the gradients"), ("float32", "the gradients"),
+    ("bfloat16", "the rule's products against float64"),
+    ("bfloat16", "the products' operands"),
+    ("float32", "the products' operands"),
+]
+
+
+@pytest.mark.parametrize("dtype,what", STATES)
+def test_the_coefficients_from_bf16_passes_are_the_float32_products(
+    xing_params, dtype, what, monkeypatch,
+):
+    """``_mhc_normed`` (forward the ``HIGHEST`` product; backward a rule
+    written out in bf16 pieces: the six products ``HIGHEST`` sums, a
+    bfloat16 state read once as it is kept, the state's cotangent rounded
+    once) against the ``HIGHEST`` product on the state's float32 copy
+    under autodiff: the same numbers, summed in another order."""
+    _hf, cfg = _xing()
+    t, rng = 96, np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((t, 4 * cfg.dim)), dtype)
+    w = xing_params["layers"][1]["hc_attn"]
+    weights = [
+        jnp.asarray(rng.standard_normal(s), jnp.float32)
+        for s in ((4, t), (4, t), (4, 4, t))
+    ]
+
+    def scalar(x, w):
+        outs = pattern._mhc_coef(x, w, cfg)
+        return sum((o * r).sum() for o, r in zip(outs, weights))
+
+    def under(form, f, *args):
+        with monkeypatch.context() as patch:
+            patch.setattr(pattern, "_mhc_normed", form)
+            return f(*args)
+
+    with jax.enable_x64(False):
+        if what == "the products' operands":
+            _assert_every_product_is_a_bf16_pass(scalar, x, w)
+            return
+        if what == "the outputs":
+            got = pattern._mhc_coef(x, w, cfg)
+            want = under(_highest_normed, pattern._mhc_coef, x, w, cfg)
+            for g, wt in zip(got, want):
+                assert g.dtype == jnp.float32 and g.shape == wt.shape
+                assert _worst(g, wt) <= 1e-6
+            return
+        grad = jax.grad(scalar, argnums=(0, 1))
+        want_dx, want_dw = under(_highest_normed, grad, x, w)
+        if what == "the rule's products against float64":
+            _assert_the_rules_products_are_float32s(x, w["phi"], rng)
+            return
+        dx, dw = grad(x, w)
+    errs = {k: _worst(dw[k], want_dw[k]) for k in ("phi", "alpha", "b")}
+    assert max(errs.values()) <= 1e-5, errs
+    assert dx.dtype == want_dx.dtype == x.dtype
+    if dtype == "float32":
+        assert _worst(dx, want_dx) <= 1e-5
+        return
+    # both round one float32 sum of the product's and the norm's terms to
+    # the state's dtype: a last bit apart where the sums' orders show
+    dx, want_dx = (np.asarray(a, np.float64) for a in (dx, want_dx))
+    assert (dx == want_dx).mean() >= 0.999
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(abs(want_dx), 1e-30))) - 7)
+    assert (abs(dx - want_dx) <= ulp).all()
+
+
+def _assert_the_rules_products_are_float32s(x, phi, rng):
+    """``d phi`` and ``dx`` of the rule alone against float64: ``d phi``
+    to float32's rounding (plain autodiff through bf16 pieces would round
+    it to bf16 piece by piece, 2e-3), ``dx`` to bf16's one rounding."""
+    t, width = x.shape
+    dm = jnp.asarray(rng.standard_normal((24, t)), jnp.float32)
+    _m, vjp = jax.vjp(
+        lambda x, phi: pattern._mhc_normed(
+            x, phi, 1e-6, jnp.dtype("float32"), 4
+        ), x, phi,
+    )
+    dx, dphi = vjp(dm)
+    x64, p64, d64 = (
+        np.asarray(a.astype(jnp.float32), np.float64) for a in (x, phi, dm)
+    )
+    r = (np.mean(x64 * x64, axis=-1) + 1e-6) ** -0.5
+    prod = (x64 @ p64).T
+    want_dphi = x64.T @ (d64 * r).T
+    want_dx = (d64 * r).T @ p64.T - (
+        (d64 * prod).sum(0) * r**3 / width
+    )[:, None] * x64
+    rel = lambda a, b: np.linalg.norm(np.asarray(a, np.float64) - b) / np.linalg.norm(b)  # noqa: E731
+    assert rel(dphi, want_dphi) <= 1e-6
+    assert dx.dtype == jnp.bfloat16
+    assert rel(dx.astype(jnp.float32), want_dx) <= 2.0 ** -8
+
+
+def _assert_every_product_is_a_bf16_pass(scalar, x, w):
+    """The gradient program's products: the forward's one at ``HIGHEST`` on
+    the state's float32 copy (what the TPU compiler hands a bfloat16 state
+    as it is kept); the backward's bfloat16 operands at the default
+    precision into float32 (``d phi``: one a piece of the state a stream;
+    ``dx``: one), and a bfloat16 state meets those as it is kept: nothing
+    of a stream's size is cast to bfloat16 on the way but ``dx``, once."""
+    jaxpr = jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1)))(x, w).jaxpr
+    eqns = list(_nested_eqns(jaxpr))
+    products = [e for e in eqns if e.primitive.name == "dot_general"]
+    pieces = 1 if x.dtype == jnp.bfloat16 else 3
+    assert len(products) == 1 + 4 * pieces + 1
+    forward, backward = products[0], products[1:]
+    assert set(forward.params["precision"]) == {jax.lax.Precision.HIGHEST}
+    assert {v.aval.dtype for v in forward.invars} == {jnp.dtype("float32")}
+    for eqn in backward:
+        assert eqn.params["precision"] is None
+        assert {v.aval.dtype for v in eqn.invars} == {jnp.dtype("bfloat16")}
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+    casts_of_a_stream = [
+        e for e in eqns
+        if e.primitive.name == "convert_element_type"
+        and e.params["new_dtype"] == jnp.bfloat16
+        and e.outvars[0].aval.shape[0] == x.shape[0]
+        and e.outvars[0].aval.shape[1:] in ((x.shape[1],), (x.shape[1] // 4,))
+    ]
+    # on a bfloat16 state the one result, dx, rounded once; on a float32
+    # one its three pieces
+    assert len(casts_of_a_stream) == (1 if pieces == 1 else 3)
+
+
+def test_the_coefficients_rules_lie_under_the_coefficients_scope():
+    """Every equation of ``_mhc_normed``'s forward and backward rules,
+    differentiated under a layer's ``jax.checkpoint``, carries
+    ``magi_mhc_coef``: the part metrics read all of them."""
+    x = jnp.zeros((96, 256), jnp.bfloat16)
+    phi, dm = jnp.zeros((256, 24), jnp.float32), jnp.zeros((24, 96), jnp.float32)
+    with jax.enable_x64(False):
+        jaxpr = jax.make_jaxpr(
+            lambda x, phi, dm: jax.vjp(
+                jax.checkpoint(
+                    lambda x, phi: pattern._mhc_normed(
+                        x, phi, 1e-6, jnp.dtype("float32"), 4
+                    )
+                ), x, phi,
+            )[1](dm)
+        )(x, phi, dm).jaxpr
+    leaves = [
+        e for e in _nested_eqns(jaxpr)
+        if not list(jax.core.jaxprs_in_params(e.params))
+    ]
+    # the forward's, remat's, d phi's four (a stream each), dx's
+    assert sum(e.primitive.name == "dot_general" for e in leaves) == 7
+    for eqn in leaves:
+        assert "magi_mhc_coef" in str(eqn.source_info.name_stack), eqn
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_counter_says_how_the_half_layers_read_the_state(
+    xing_params, dtype,
+):
+    """``magi_mhc_coef_halves{form}``: each of the 8 half-layers (3 layers
+    and the module's) once a traced loss and gradient, under the passes
+    the model's dtype makes the product take over the state."""
+    _hf, cfg = _xing(dtype=dtype)
+    forms = {"bfloat16": "bf16_one_pass", "float32": "float32_three_pass"}
+    telemetry.set_enabled(True)
+    reg = telemetry.get_registry()
+    try:
+        reg.clear_metric("magi_mhc_coef_halves")
+        with jax.enable_x64(False):
+            model, _meta = build_magi_pattern(cfg, _mesh(1), CU, chunk_size=CHUNK)
+            batch = jnp.zeros((1, TOTAL), jnp.int32)
+            jax.eval_shape(
+                jax.value_and_grad(model.loss_fn),
+                xing_params, batch, batch, batch, model.sharded_tables(),
+            )
+        counts = {
+            form: reg.counter_value("magi_mhc_coef_halves", form=form)
+            for form in forms.values()
+        }
+    finally:
+        for n in (*MHC_GAUGES, "magi_mla_kv_cast_width", "magi_mhc_coef_halves"):
+            reg.clear_metric(n)
+        telemetry.set_enabled(None)
+    assert counts == {
+        **dict.fromkeys(forms.values(), 0),
+        forms[dtype]: 2 * (cfg.n_layers + cfg.n_mtp),
+    }
+
+
 def test_one_stream_with_identity_coefficients_is_the_glm_path():
     """``hc_mult`` 1 with ``H_pre = H_post = H_res = 1`` is the plain
     residual path: the loss and the shared parameters' gradients of
@@ -236,8 +444,7 @@ def test_the_mhc_scopes_the_gauges_and_the_spans_value_width(xing_params):
     _hf, cfg = _xing()
     telemetry.set_enabled(True)
     reg = telemetry.get_registry()
-    names = ("magi_mhc_streams", "magi_mhc_sinkhorn_iters",
-             "magi_mhc_stream_bytes", "magi_flex_pad_lane_share")
+    names = MHC_GAUGES
     try:
         with jax.enable_x64(False):
             model, _meta = build_magi_pattern(cfg, _mesh(1), CU, chunk_size=CHUNK)
@@ -254,7 +461,7 @@ def test_the_mhc_scopes_the_gauges_and_the_spans_value_width(xing_params):
         plan = [e["args"] for e in events if e["name"] == "plan_flex_attn"][-1]
         build = [e["args"] for e in events if e["name"] == "attn_fn_build"][-1]
     finally:
-        for n in (*names, "magi_mla_kv_cast_width"):
+        for n in (*names, "magi_mla_kv_cast_width", "magi_mhc_coef_halves"):
             reg.clear_metric(n)
         telemetry.set_enabled(None)
     for scope in ("magi_mhc_coef", "magi_mhc_read", "magi_mhc_write",
