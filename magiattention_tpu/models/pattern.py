@@ -94,6 +94,14 @@ key heads (``v_head_dim`` 128 beside 128 + 64): the kernels take both
 widths (``KernelHeads.v_head_dim``), and YaRN stretches the rotary lanes
 (``rope_yarn``).
 
+A layer's router reads the FFN half's normed input, or
+(``router_input = "attn"``; SmallThinker / ``smallthinker_config``) the
+ATTENTION half's: the route is then made before the attention call, on
+rows no collective has touched, and the FFN half consumes it
+(:func:`_route`, a key of the layer's own ``carry``). The same
+configuration's experts gate through ReLU (``expert_act``), its full
+layers carry no position and its window layers rotary.
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -130,6 +138,7 @@ DENSE, EXPERTS = "dense", "experts"
 GQA, LATENT, CCA, DIFF = "gqa", "latent", "cca", "diff"
 RMS, LAYER = "rms", "layer"  # the norm's forms
 SIGMOID, MLP, SOFTMAX = "sigmoid", "mlp", "softmax"  # the router's forms
+_EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}  # on the gate
 _SHORT = {SLIDING: "sliding", FULL: "full"}  # spans, counters, scopes
 
 
@@ -182,6 +191,15 @@ class PatternConfig:
     # PR 39), at the price of the matmuls a full chunk takes; past top-2
     # every chunk runs, where the pairs' form skips one nothing reaches
     flat_expert_rows: bool = False
+    # what the router reads: "ffn", the FFN half's own normed input, or
+    # "attn" (SmallThinker): the ATTENTION half's normed input, so a
+    # layer's route is made before its attention call and the FFN half
+    # consumes it (:func:`_attention_out`, :func:`_ffn_out`)
+    router_input: str = "ffn"
+    # the activation on an expert's gate: "silu" (SwiGLU) or "relu"
+    # (ReGLU: a gate that is exactly zero for the rows it turns off); the
+    # dense FFN and the shared expert are SwiGLU either way
+    expert_act: str = "silu"
     dtype: str = "bfloat16"
     remat: bool = False
     # the attention's form, every layer's: GQA (q, k, v from the hidden
@@ -256,6 +274,8 @@ class PatternConfig:
         bad |= {self.attn_form} - {GQA, LATENT, CCA, DIFF}
         bad |= {self.router_form} - {SIGMOID, MLP, SOFTMAX}
         bad |= {self.norm_form} - {RMS, LAYER}
+        bad |= {self.router_input} - {"ffn", "attn"}
+        bad |= {self.expert_act} - set(_EXPERT_ACTS)
         if bad:
             raise ValueError(f"unknown layer kinds {sorted(bad)}")
         self._check_handed_on()
@@ -290,6 +310,13 @@ class PatternConfig:
             raise ValueError(
                 "the MLP router needs router_hidden, and hands its state "
                 "from layer to layer: an MTP module has no layer before"
+            )
+        if self.router_input == "attn" and (
+            self.router_form == MLP or self.hc_mult
+        ):
+            raise ValueError(
+                "router_input 'attn' with an MLP router's state or under "
+                "residual streams (hc_mult): no reference states one"
             )
         if self.n_mtp > 1:
             raise ValueError(
@@ -772,6 +799,82 @@ def sdar_moe_config(
     )
 
 
+def smallthinker_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    expert_range: tuple[int, int] | None = None,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``smallthinker`` ``config.json`` (SmallThinker-21BA3B,
+    arXiv:2507.20984) as a pattern: a GQA decoder with no qk-norm, gate,
+    bias or post-norm; a layer whose ``sliding_window_layout`` entry is 1
+    sees ``sliding_window_size`` keys (itself included) and carries
+    rotary, a layer at 0 sees its whole document and carries no position
+    (``rope_layout``: the two lists have to agree, since a
+    ``PatternConfig`` says which kinds carry rotary, not which layers);
+    every layer ``moe_num_primary_experts`` ReLU-gated experts, top
+    ``moe_num_active_primary_experts`` of a softmax router that reads the
+    ATTENTION half's normed input (``router_input``), renormalised over
+    the chosen (``norm_topk_prob``); no shared expert, untied head. The
+    layouts are read up to ``num_hidden_layers`` (a cut keeps the
+    published lists whole). ``expert_range`` and ``vocab_size`` give one
+    rank's share, as in :func:`afmoe_config`; ``flat_expert_rows`` is the
+    configuration file's own key (absent: off)."""
+    n = int(hf["num_hidden_layers"])
+    rope = [int(v) for v in hf["rope_layout"][:n]]
+    window = [int(v) for v in hf["sliding_window_layout"][:n]]
+    if len(rope) != n or len(window) != n:
+        raise ValueError(
+            f"rope_layout / sliding_window_layout are shorter than the "
+            f"{n} layers"
+        )
+    if rope != window or set(rope) - {0, 1}:
+        raise ValueError(
+            "a smallthinker configuration whose rope_layout and "
+            "sliding_window_layout differ (a layer windowed without "
+            "rotary, or rotary without a window) is not built: "
+            f"{rope} / {window}"
+        )
+    if not hf["moe_primary_router_apply_softmax"]:
+        raise ValueError(
+            "moe_primary_router_apply_softmax false (sigmoid scores, then "
+            "renormalised) is not built"
+        )
+    if hf.get("rope_scaling"):
+        raise ValueError("a smallthinker configuration with rope_scaling")
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=int(hf["hidden_size"]),
+        n_heads=int(hf["num_attention_heads"]),
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf["head_dim"]),
+        layer_types=tuple(SLIDING if w else FULL for w in window),
+        ffn_types=(EXPERTS,) * n,
+        ffn_hidden=0,  # no dense layer
+        sliding_window=int(hf["sliding_window_size"]),
+        rope_theta=float(hf["rope_theta"]),
+        rope_kinds=(SLIDING,),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=False,
+        rms_eps=float(hf["rms_norm_eps"]),
+        n_experts=int(hf["moe_num_primary_experts"]),
+        top_k=int(hf["moe_num_active_primary_experts"]),
+        expert_hidden=int(hf["moe_ffn_hidden_size"]),
+        route_norm=bool(hf["norm_topk_prob"]),
+        router_form=SOFTMAX,
+        router_input="attn",
+        expert_act="relu",
+        expert_range=expert_range,
+        flat_expert_rows=bool(hf.get("flat_expert_rows", False)),
+        dtype=dtype,
+        remat=remat,
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+    )
+
+
 def ouro_config(
     hf: dict, *, dtype: str = "bfloat16", remat: bool = False
 ) -> PatternConfig:
@@ -1177,8 +1280,9 @@ def _chunk_groups(lo, rows: int, starts, ends, n_here):
     return sizes, valid
 
 
-def _grouped_ffn(xs, sizes, valid, we_gate, we_up, we_down):
-    """The held experts' SwiGLU on a chunk of pair rows, a group an
+def _grouped_ffn(act: str, xs, sizes, valid, we_gate, we_up, we_down):
+    """The held experts' gated FFN (``act`` on the gate:
+    ``PatternConfig.expert_act``) on a chunk of pair rows, a group an
     expert."""
 
     def grouped(x, w):
@@ -1189,10 +1293,10 @@ def _grouped_ffn(xs, sizes, valid, we_gate, we_up, we_down):
         return jnp.where(valid, jax.lax.ragged_dot(x, w, sizes), 0)
 
     a, b = grouped(xs, we_gate), grouped(xs, we_up)
-    return grouped(jax.nn.silu(a) * b, we_down)
+    return grouped(_EXPERT_ACTS[act](a) * b, we_down)
 
 
-def _whole_chunk(xs, ws, lo, bounds, weights):
+def _whole_chunk(act, xs, ws, lo, bounds, weights):
     """One chunk where every chunk runs: the grouped matmuls take all its
     rows (the zero rows are the last group's), and it weighs its own
     output rows in float32, so its backward, which has them again, makes
@@ -1202,7 +1306,7 @@ def _whole_chunk(xs, ws, lo, bounds, weights):
         sizes, valid = _chunk_groups(lo, rows, *bounds)
         sizes = sizes.at[-1].add(rows - sizes.sum())
     with named_scope("magi_moe_matmul"):
-        o = _grouped_ffn(xs, sizes, valid, *weights)
+        o = _grouped_ffn(act, xs, sizes, valid, *weights)
     with named_scope("magi_moe_scatter"):
         return o.astype(jnp.float32) * ws[:, None]
 
@@ -1221,7 +1325,7 @@ def _each_chunk(n_chunks: int, rows: int, body, init):
     )
 
 
-def _sorted_rows_forward(rows, h, w, order, bounds, weights):
+def _sorted_rows_forward(rows, act, h, w, order, bounds, weights):
     t, k = w.shape
     n, n_rows = t * k, order.shape[0]
     with named_scope("magi_moe_sort"):
@@ -1238,7 +1342,7 @@ def _sorted_rows_forward(rows, h, w, order, bounds, weights):
     def chunk(lo, out):
         with named_scope("magi_moe_gather"):
             x, wc = _chunk_of(xs, lo, rows), _chunk_of(ws, lo, rows)
-        o = _whole_chunk(x, wc, lo, bounds, weights)
+        o = _whole_chunk(act, x, wc, lo, bounds, weights)
         with named_scope("magi_moe_scatter"):
             return jax.lax.dynamic_update_slice_in_dim(out, o, lo, 0)
 
@@ -1250,9 +1354,11 @@ def _sorted_rows_forward(rows, h, w, order, bounds, weights):
     return y, (xs, ws, tok, inv, bounds, weights)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts_on_sorted_rows(rows: int, h, w, order, bounds, weights):
-    """sum_k w_k expert_k(h) where every chunk of ``rows`` pair rows runs:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts_on_sorted_rows(rows: int, act: str, h, w, order, bounds,
+                            weights):
+    """sum_k w_k expert_k(h) (the gate under ``act``) where every chunk of
+    ``rows`` pair rows runs:
     ``order`` ``[n_rows]`` is the pairs sorted by expert (whole chunks:
     entries past ``n = t top_k`` are padding), ``bounds`` the held
     experts' (starts, ends, pairs here) in it, ``weights`` their three
@@ -1272,16 +1378,16 @@ def _experts_on_sorted_rows(rows: int, h, w, order, bounds, weights):
     and write carry the scope of the end they belong to: the dispatch's
     under ``magi_moe_gather``, the combine's under ``magi_moe_scatter``
     (a scan's own copies carry the scan's name alone)."""
-    return _sorted_rows_forward(rows, h, w, order, bounds, weights)[0]
+    return _sorted_rows_forward(rows, act, h, w, order, bounds, weights)[0]
 
 
-def _sorted_rows_fwd(rows, h, w, order, bounds, weights):
+def _sorted_rows_fwd(rows, act, h, w, order, bounds, weights):
     for end in ("dispatch", "combine"):
         telemetry.record_moe_rows_permuted(end)
-    return _sorted_rows_forward(rows, h, w, order, bounds, weights)
+    return _sorted_rows_forward(rows, act, h, w, order, bounds, weights)
 
 
-def _sorted_rows_bwd(rows, res, g):
+def _sorted_rows_bwd(rows, act, res, g):
     xs, ws, tok, inv, bounds, weights = res
     t, n_rows = g.shape[0], xs.shape[0]
 
@@ -1293,7 +1399,9 @@ def _sorted_rows_bwd(rows, res, g):
             # padding's rows read token 0's: no held pair, so no gradient
             g_rows = g[_chunk_of(tok, lo, rows)]
         _, vjp = jax.vjp(
-            lambda x, wc, weights: _whole_chunk(x, wc, lo, bounds, weights),
+            lambda x, wc, weights: _whole_chunk(
+                act, x, wc, lo, bounds, weights
+            ),
             x, wc, weights,
         )
         dx, dwc, dchunk = vjp(g_rows)
@@ -1392,7 +1500,9 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     bounds = (starts, ends, n_here)
     weights = (we_gate, we_up, we_down)
     if cfg.flat_expert_rows:
-        y = _experts_on_sorted_rows(rows, h, w, order, bounds, weights)
+        y = _experts_on_sorted_rows(
+            rows, cfg.expert_act, h, w, order, bounds, weights
+        )
         return y, counts
 
     w_flat = w.reshape(-1)
@@ -1405,7 +1515,7 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
         with named_scope("magi_moe_gather"):
             xs = h[tok]
         with named_scope("magi_moe_matmul"):
-            o = _grouped_ffn(xs, sizes, valid, *weights)
+            o = _grouped_ffn(cfg.expert_act, xs, sizes, valid, *weights)
         with named_scope("magi_moe_scatter"):
             o = o.astype(jnp.float32) * w_flat[pairs][:, None]
             return jnp.zeros((t, cfg.dim), jnp.float32).at[tok].add(o)
@@ -1434,10 +1544,20 @@ def held_expert_ffn(h, idx, w, layer: dict, cfg: PatternConfig):
     return y, counts
 
 
-def _expert_ffn(h, layer: dict, cfg: PatternConfig, r=None):
-    dt = cfg.jnp_dtype
+def _route(h, layer: dict, cfg: PatternConfig, r=None):
+    """A layer's :func:`route` under its scope: made once a layer, on the
+    FFN half's normed input (:func:`_ffn_out`) or, under
+    ``cfg.router_input == "attn"``, on the attention half's
+    (:func:`_attention_out`)."""
     with named_scope("magi_moe_router"):
-        idx, w, r = route(h, layer, cfg, r)
+        return route(h, layer, cfg, r)
+
+
+def _expert_ffn(h, layer: dict, cfg: PatternConfig, routed):
+    """The expert half on its normed input ``h`` by the route ``routed``
+    (:func:`_route`'s, wherever it was made)."""
+    dt = cfg.jnp_dtype
+    idx, w, r = routed
     with named_scope("magi_moe_experts"):
         y, counts = held_expert_ffn(h, idx, w, layer, cfg)
         with named_scope("magi_moe_scatter"):
@@ -1676,7 +1796,10 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
     ``cfg.memory_layer``), ``kv`` the keys and values of
     ``cfg.kv_layer`` as the kernels take them. The carry this layer
     hands on, what it was handed and what it made, leaves under
-    ``carry`` of the stats for the caller to pass to the next.
+    ``carry`` of the stats for the caller to pass to the next. One key
+    lives inside the layer alone: ``route``, the expert half's route where
+    the attention half makes it (``cfg.router_input``), which
+    :func:`_ffn_out` pops.
     ``index``: the layer's place in ``cfg.layer_types``. Under
     ``cfg.hc_mult`` ``x`` is the streams' state [t, hc_mult x dim] and each
     half reads and writes it through its mixer (:func:`_mhc_half`)."""
@@ -1685,6 +1808,7 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
         cfg=cfg, layer_type=layer_type, tables=tables, plans=plans,
         attn_params=attn_params, axis_name=axis_name, shift_plan=shift_plan,
         index=index,
+        route_ahead=ffn_type == EXPERTS and cfg.router_input == "attn",
     )
     if cfg.hc_mult:
         x = _mhc_half(
@@ -1734,7 +1858,8 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
 def _ffn_out(x, layer, carry, *, cfg, ffn_type):
     """(the FFN half's output on ``x``, norm first, before the residual
     sum; the expert layer's routing stats). An MLP router's state goes
-    into ``carry``. An expert layer's magi_moe_* scopes are siblings
+    into ``carry``; a route the attention half made comes out of it. An
+    expert layer's magi_moe_* scopes are siblings
     between its two magi_ffn blocks, so magi_ffn holds no expert."""
     dt = cfg.jnp_dtype
     with named_scope("magi_ffn"):
@@ -1746,7 +1871,11 @@ def _ffn_out(x, layer, carry, *, cfg, ffn_type):
                 h, layer["w_gate"], layer["w_up"], layer["w_down"], dt
             )
     else:
-        out, stats = _expert_ffn(h, layer, cfg, carry.get("r"))
+        # made before the attention call (``router_input``), or here
+        routed = carry.pop("route", None) or _route(
+            h, layer, cfg, carry.get("r")
+        )
+        out, stats = _expert_ffn(h, layer, cfg, routed)
         if "router_state" in stats:
             carry["r"] = stats.pop("router_state")
     with named_scope("magi_ffn"):
@@ -1966,10 +2095,12 @@ def _attention_half(x, pos, layer, carry, **how):
 
 
 def _attention_out(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
-                   attn_params, axis_name, shift_plan, index):
+                   attn_params, axis_name, shift_plan, index,
+                   route_ahead=False):
     """The attention half of a SLIDING, FULL or CROSS layer on ``x``, norm
     first, before the residual sum; the keys and values ``cfg.kv_layer``
-    makes go into ``carry``."""
+    makes go into ``carry``, and with ``route_ahead`` the expert half's
+    route, made on this half's normed input before the attention call."""
     dt = cfg.jnp_dtype
     t = x.shape[0]
     eps = cfg.rms_eps
@@ -1999,6 +2130,10 @@ def _attention_out(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
             q = (h @ layer["wq"].astype(dt)).reshape(t, -1, cfg.head_dim)
             k = (h @ layer["wk"].astype(dt)).reshape(t, -1, cfg.head_dim)
             v = (h @ layer["wv"].astype(dt)).reshape(t, -1, cfg.head_dim)
+    if route_ahead:
+        # a sibling of magi_proj, as the attention call is: token-local,
+        # on the dispatched rows, so it depends on nothing the call casts
+        carry["route"] = _route(h, layer, cfg, carry.get("r"))
     if cfg.attn_form == CCA:
         # a sibling of magi_proj, as the attention call is
         with named_scope("magi_cca_mix"):
@@ -2612,6 +2747,10 @@ def build_magi_pattern(
         ),
     )
     telemetry.record_model_loop(cfg.n_loops, cfg.n_layers)
+    ffn_kinds = cfg.ffn_types + cfg.ffn_types[-1:] * cfg.n_mtp  # modules'
+    telemetry.record_moe_route_ahead(
+        ffn_kinds.count(EXPERTS) if cfg.router_input == "attn" else 0
+    )
     if cfg.attn_form == DIFF:
         readers = cfg.layer_types.count(CROSS)
         telemetry.record_handed_on(

@@ -76,6 +76,7 @@ from .collectors import (  # noqa: F401
     record_flex_kernel_build,
     record_model_attn_plan,
     record_moe_rows_permuted,
+    record_moe_route_ahead,
     record_handed_on,
     record_mhc,
     record_mhc_coef,
@@ -381,6 +382,7 @@ __all__ = [
     "record_flex_kernel_build",
     "record_model_attn_plan",
     "record_moe_rows_permuted",
+    "record_moe_route_ahead",
     "record_handed_on",
     "record_mhc",
     "record_mhc_coef",

@@ -159,6 +159,11 @@ M_MOE_LOAD_MAX_OVER_MEAN = "magi_moe_load_max_over_mean"
 # pair rows runs), counted once at each end of the path where the rule's
 # forward is traced: {end=combine|dispatch}
 M_MOE_ROWS_PERMUTED = "magi_moe_rows_permuted_total"
+# gauge — the expert layers whose route is made on the attention half's
+# normed input, before the layer's attention call
+# (PatternConfig.router_input "attn"), set where the model is built
+# (build_magi_pattern); 0 where every router reads the FFN half's input
+M_MOE_ROUTE_AHEAD_LAYERS = "magi_moe_route_ahead_layers"
 # gauge — what a key-value cast carries a token under latent attention,
 # in elements, set where a latent model is built (build_magi_pattern):
 # {form=expanded} every head's k and v as the kernels take them (what the
@@ -1265,6 +1270,15 @@ def record_moe_rows_permuted(end: str) -> None:
     if not _enabled():
         return
     get_registry().counter_inc(M_MOE_ROWS_PERMUTED, end=end)
+
+
+def record_moe_route_ahead(layers: int) -> None:
+    """The expert layers of a pattern decoder whose route is made before
+    the layer's attention call (``models/pattern.build_magi_pattern``,
+    host side)."""
+    if not _enabled():
+        return
+    get_registry().gauge_set(M_MOE_ROUTE_AHEAD_LAYERS, float(layers))
 
 
 def record_moe_load(layer: int, counts) -> None:

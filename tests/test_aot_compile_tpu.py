@@ -317,6 +317,97 @@ def test_group_one_geometries(topo, geometry, t, rung, grid):
     assert text.count("tpu_custom_call") == 2  # fwd, bwd
 
 
+def _smallthinker_mask(which: str, kind: str):
+    """(q_ranges, k_ranges, types, rows) of the SmallThinker cell's mask
+    (``which`` "mask": the window's 16,384 rows; "check_mask": the check's
+    8,192) as ``build_magi_pattern`` makes it for a layer ``kind``: the
+    documents' causal mask, or the same under the published window."""
+    import json
+
+    from magiattention_tpu.api.functools import infer_attn_mask_from_cu_seqlens
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        here, "benchmarks", "traffic", "train-16k-packed-prerouted.json"
+    )) as f:
+        lengths = json.load(f)[which]["lengths"]
+    with open(os.path.join(
+        here, "benchmarks", "configs", "smallthinker-21b-a3b.json"
+    )) as f:
+        window = json.load(f)["sliding_window_size"]
+    cu = [0]
+    for n in lengths:
+        cu.append(cu[-1] + n)
+    if kind == "full":
+        q, k, t = infer_attn_mask_from_cu_seqlens(cu, causal=True)
+    else:
+        q, k, t = infer_attn_mask_from_cu_seqlens(
+            cu, causal=False, window_size=(window - 1, 0)
+        )
+    return q.to_naive_ranges(), k.to_naive_ranges(), [int(x) for x in t], cu[-1]
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize(
+    "which,kind,rung",
+    [("mask", "full", None), ("mask", "window", None),
+     ("check_mask", "full", None), ("check_mask", "window", None),
+     ("mask", "full", (128, 512, 8)), ("mask", "full", (256, 512, 8)),
+     ("mask", "window", (256, 1024, 2)), ("mask", "full", (512, 768, 4)),
+     ("mask", "window", (1024, 1024, 1)), ("mask", "full", (512, 2048, 1))],
+    ids=["tuner-16k-full", "tuner-16k-window", "tuner-8k-check-full",
+         "tuner-8k-check-window", "seven-heads-a-step", "block-q-256",
+         "widest-row-major-step", "widest-compact-step",
+         "per-head-long-rung", "escalation-rung"],
+)
+def test_group_seven_geometry(topo, which, kind, rung, grid):
+    """Forward and backward at 28 query / 4 key-value heads of 128
+    (SmallThinker, ISSUE 53): the first GQA group that is no power of two,
+    so every head-batched rung snaps to 7 heads a step and a key-value
+    head's step stacks 7 x ``block_q`` query rows (896, 1,792, 3,584)
+    where every shape compiled before stacked 1, 2, 4 or 8. On both grids,
+    on the cell's own masks (documents 10,240 / 4,096 / 1,536 / 512 and
+    the check's 6,144 / 1,536 / 512, each as the full layers and as the
+    window-4,096 layers see them): at the rung the tuner returns for each
+    of the four plans, and at every other step its table holds for the
+    geometry, so that whatever a mask makes it return compiles. The
+    forward builds at the rung's heads a step; the backward at what
+    ``_bwd_head_block`` allows it (one head a step past 32 MiB of live
+    float32 tiles: 7 x 512 rows)."""
+    from magiattention_tpu import telemetry
+    from magiattention_tpu.ops.flex_attn import (
+        _BWD_HB_LIVE_BYTES, _auto_head_block,
+    )
+    from magiattention_tpu.tuning.autotuner import resolve_block_config
+
+    hq, hk, d = 28, 4, 128
+    qr, kr, ts, t = _smallthinker_mask(which, kind)
+    if rung is None:
+        rung = resolve_block_config(
+            qr, kr, tuple(ts), t, t, 1, hq, hk, d, "bfloat16"
+        )
+    rung = (*rung[:2], _auto_head_block(rung[2], hq, hq // hk))
+    assert rung[2] in (1, 7)
+    fits = 4 * 4 * rung[2] * rung[0] * rung[1] <= _BWD_HB_LIVE_BYTES
+    heads = {"fwd": rung[2], "bwd": rung[2] if fits else 1}
+    chip = SingleDeviceSharding(topo.devices[0])
+    reg = telemetry.get_registry()
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    reg.clear_metric("magi_flex_kernel_build_total")
+    try:
+        text = _compile_fwd_bwd(chip, (qr, kr, ts), t, hq, hk, d, rung, grid)
+        for kernel, form in _FORMS.items():
+            assert reg.counter_value(
+                "magi_flex_kernel_build_total", kernel=kernel,
+                heads_per_step=heads[kernel], grid=grid, **form,
+            ) >= 1, (kernel, rung)
+    finally:
+        reg.clear_metric("magi_flex_kernel_build_total")
+        telemetry.set_enabled(was)
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
+
+
 @pytest.mark.parametrize("chunk,block", [(128, 1024), (256, 512)])
 def test_selective_scan_at_the_sambay_cells_size(topo, chunk, block):
     """The selective scan's kernel pair (ISSUE 46) at the Phi-4-mini-flash

@@ -175,6 +175,33 @@ def _experts_step(mesh):
     )
 
 
+def _prerouted_step(mesh):
+    """SmallThinker's step as its cell runs it: the published widths, its
+    rank's share of the experts and the vocabulary, all 8 kept layers
+    (2 full, 6 window-4,096), remat, the cell's 16,384-token mask."""
+    from benchmarks import masks
+    from magiattention_tpu.models.pattern import (
+        build_magi_pattern, init_pattern_params, smallthinker_config,
+    )
+
+    hf = _benchmark_json("configs", "smallthinker-21b-a3b.json")
+    tr = _benchmark_json("traffic", "train-16k-packed-prerouted.json")
+    cfg = smallthinker_config(
+        hf, dtype=tr["dtype"], remat=bool(tr["remat"]),
+        expert_range=tuple(hf["experts_here"]), vocab_size=hf["vocab_here"],
+    )
+    total = int(tr["total_tokens"])
+    mask = masks.build_mask(tr["mask"], total, index=0)
+    model, _ = build_magi_pattern(
+        cfg, mesh, mask.cu_seqlens, chunk_size=int(tr["chunk_size"]),
+        interpret=False,
+    )
+    return (
+        model, (lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)),
+        total,
+    )
+
+
 _STEPS = {"dense": _dense_step, "experts": _experts_step}
 
 
@@ -192,6 +219,35 @@ def _update_in_step(topo, which: str, barrier: bool):
             patch.setattr(jax.lax, "optimization_barrier", lambda x: x)
         exe = _compile_train_step(model, init, optax.adamw(3e-4), total)
     return update_fusions(exe.as_text()), exe.memory_analysis().temp_size_in_bytes
+
+
+def test_the_prerouted_cells_step_fits_the_chip(topo):
+    """The SmallThinker cell's whole AdamW step compiled for one described
+    v5e at its size (ISSUE 53): 28 / 4 heads of 128 on the full plan's and
+    the window plan's rungs, the route made before each layer's attention
+    kernel, the ReLU-gated held experts on whole chunks. Its arguments and
+    temporaries fit the chip with room (15.5 of its 16 GB are the
+    program's), no weight's update is fused into its gradient's matmul,
+    and a layer's forward kernel is in the program once (kept across
+    remat): 2 flex kernels a layer."""
+    import optax
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
+    model, init, total = _prerouted_step(mesh)
+    exe = _compile_train_step(model, init, optax.adamw(3e-4), total)
+    mem = exe.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.5e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    text = exe.as_text()
+    fused, under_scope = update_fusions(text)
+    assert fused == [] and under_scope > 0
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for kernel in ("magi_flex_fwd_kernel", "magi_flex_bwd_kernel"):
+        launched = sum(f"/{kernel}/pallas_call" in ln for ln in calls)
+        assert launched == model.cfg.n_layers, (kernel, launched)
+    assert "magi_moe_router" in text
+    print(f"arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes}")
 
 
 @pytest.mark.parametrize("which", list(_STEPS))

@@ -280,7 +280,9 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                 k: v[first:first + 16] if k.startswith("we_") else v
                 for k, v in whole.items()
             }
-            y, stats = pattern._expert_ffn(h, share, share_cfg)
+            y, stats = pattern._expert_ffn(
+                h, share, share_cfg, pattern._route(h, share, share_cfg)
+            )
             counted += int(stats["expert_counts"].sum())
             # the reference's own share, the same rank
             part, _ = reference_sdar.expert_ffn(

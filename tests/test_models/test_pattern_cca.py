@@ -245,7 +245,9 @@ def test_the_two_shares_add_up_to_the_uncut_layer():
                 k: v[first:first + 4] if k.startswith("we_") else v
                 for k, v in whole.items()
             }
-            y, stats = pattern._expert_ffn(h, share, share_cfg, r)
+            y, stats = pattern._expert_ffn(
+                h, share, share_cfg, pattern._route(h, share, share_cfg, r)
+            )
             counted += int(stats["expert_counts"].sum())
             np.testing.assert_allclose(
                 stats["router_state"], want_r, rtol=1e-5, atol=1e-6
@@ -271,7 +273,9 @@ def test_top_1_drops_no_token_under_a_skewed_router(to):
             np.random.default_rng(2).standard_normal((t, cfg.dim)), jnp.float32
         )
         r = jnp.zeros((t, cfg.router_hidden), jnp.float32)
-        y, stats = pattern._expert_ffn(h, layer, cfg, r)
+        y, stats = pattern._expert_ffn(
+            h, layer, cfg, pattern._route(h, layer, cfg, r)
+        )
         with jax.default_matmul_precision("highest"):
             want, _r, (idx, _margins) = reference_zaya.expert_ffn(h, r, layer, hf)
     assert (np.asarray(stats["expert_idx"]) == to).all()
@@ -313,7 +317,9 @@ def test_flat_expert_rows_are_the_same_function_on_whole_chunks(
         r = jnp.asarray(rng.standard_normal((t, flat.router_hidden)), jnp.float32)
 
         def out(cfg, h, layer):
-            y, stats = pattern._expert_ffn(h, layer, cfg, r)
+            y, stats = pattern._expert_ffn(
+                h, layer, cfg, pattern._route(h, layer, cfg, r)
+            )
             return jnp.sum(y * jnp.cos(y)), (y, stats["expert_counts"])
 
         got = jax.value_and_grad(out, (1, 2), has_aux=True)(flat, h, layer)

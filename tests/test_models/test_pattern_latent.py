@@ -160,7 +160,9 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                 k: v[first:first + 2] if k.startswith("we_") else v
                 for k, v in whole.items()
             }
-            y, stats = pattern._expert_ffn(h, share, share_cfg)
+            y, stats = pattern._expert_ffn(
+                h, share, share_cfg, pattern._route(h, share, share_cfg)
+            )
             assert int(stats["expert_counts"].sum()) > 0
             total = total + y
         shared = pattern._swiglu(

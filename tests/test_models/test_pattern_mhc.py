@@ -432,7 +432,9 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
                 k: v[first:first + 1] if k.startswith("we_") else v
                 for k, v in whole.items()
             }
-            y, _stats = pattern._expert_ffn(h, share, share_cfg)
+            y, _stats = pattern._expert_ffn(
+                h, share, share_cfg, pattern._route(h, share, share_cfg)
+            )
             total = total + y
         shared = pattern._swiglu(
             h, whole["ws_gate"], whole["ws_up"], whole["ws_down"], jnp.float32

@@ -70,6 +70,9 @@ DQ_VISITS = {
     # the full plan (ZAYA's mask), then the window of 512: one block_k
     "phi4flash-train-16k-traces": [6.0, 1.9375],
     "xing4-train-8k-traces": [3.5],  # ZAYA's mask halved, at block_q 256
+    # four long documents: the full plan per head at (1024, 1024), then the
+    # window of 4,096 at (128, 512), 7 heads a step (ISSUE 53)
+    "smallthinker-train-16k-traces": [8.5, 5.9375],
 }
 
 
