@@ -2058,10 +2058,13 @@ def _auto_head_block(pref: int, hq: int, group: int) -> int:
 
 
 _LONG_SEQ_BLOCK_THRESHOLD = 16384
-# >= 16k tokens: only the big-tile rungs are candidates — the round-5
+# >= 16k tokens: the big-tile rungs lead the static table — the round-5
 # chained sweep measured (1024, 1024) fastest for both fwd (108.5 TF/s)
 # and fwd+bwd (106.9) at 64k causal, with (512, 2048) within 2-4% as the
-# entry-budget escalation; small rungs are grid-bound at this scale.
+# entry-budget escalation; small rungs are grid-bound at this scale. One
+# dense slice at 64 / 8 heads: the tuner's tie order takes the lead only
+# for a mask at a quarter of its square and more
+# (tuning/cost_model._preference_order, ISSUE 54).
 _LONG_SEQ_CONFIGS = tuple(
     c for c in _AUTO_BLOCK_CONFIGS if c[0] * c[1] >= 1024 * 1024
 )

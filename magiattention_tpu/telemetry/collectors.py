@@ -1172,7 +1172,11 @@ def record_autotune_decision(decision) -> None:
     rung = f"{decision.block_q}x{decision.block_k}x{decision.head_block}"
     reg.gauge_set(M_AUTOTUNE_CHOICE, 1, rung=rung, source=decision.source)
     if decision.bound:  # a flex rung the cost model priced
-        reg.counter_inc(M_AUTOTUNE_DECISIONS, bound=decision.bound)
+        reg.counter_inc(
+            M_AUTOTUNE_DECISIONS,
+            bound=decision.bound,
+            tie_order=decision.tie_order,
+        )
     _marker_event(
         "autotune_decision",
         {
@@ -1188,6 +1192,9 @@ def record_autotune_decision(decision) -> None:
             "hbm_seconds": decision.hbm_seconds,
             "bound": decision.bound,
             "rejected_bytes": decision.rejected_bytes,
+            # the preference order the ranking broke its tie by: how
+            # often the long-sequence lead was used (ISSUE 54)
+            "tie_order": decision.tie_order,
             # the form of the backward the rung's kernels run: one value
             # since PR 43, kept so that a later form reads against it
             "bwd_form": BWD_FORM,

@@ -64,6 +64,11 @@ class TuningDecision:
     hbm_seconds: float = 0.0
     bound: str = ""
     rejected_bytes: int = 0
+    # which measured preference order the ranking broke its tie by
+    # (``cost_model._preference_order``): ``long_seq``, the 64k dense
+    # slice's big-tile lead, or ``measured``, the table's own order (every
+    # mask under 16,384 rows or under ``SPARSE_DENSITY_THRESHOLD``)
+    tie_order: str = ""
 
     @property
     def config(self) -> tuple[int, int, int]:
@@ -75,9 +80,10 @@ class TuningDecision:
 
 
 def _bytes_verdict(rec: TuningRecord) -> dict:
-    """The operand-bytes fields of a decision, from the ranking's
-    candidates as a record keeps them (``CandidateScore.as_dict``): the
-    same answer on a miss and on a hit."""
+    """The operand-bytes fields of a decision and its ranking's tie
+    order, from the ranking's candidates as a record keeps them
+    (``CandidateScore.as_dict``): the same answer on a miss and on a
+    hit."""
     rung = ("block_q", "block_k", "head_block", "grid")
     chosen = next(
         (
@@ -100,6 +106,7 @@ def _bytes_verdict(rec: TuningRecord) -> dict:
             and c["compute_seconds"] < chosen["compute_seconds"]
             for c in rec.candidates
         ),
+        tie_order=chosen.get("tie_order", ""),
     )
 
 

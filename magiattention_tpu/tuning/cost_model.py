@@ -206,7 +206,10 @@ SPARSE_ONLY_CONFIGS: tuple[tuple[int, int, int], ...] = (
 # density vs 101-113 TF/s on >= 0.5-density dense causal) — per-step
 # overheads the analytic model cannot price dominate. Ties are then
 # resolved toward the sparse grid with the FEWEST total grid slots
-# instead of the dense-measured preference order.
+# instead of the dense-measured preference order (where sparse candidates
+# are ranked: no distributed plan, ``include_sparse=False``). Under it the
+# preference order itself drops its long-sequence lead, for every caller
+# (:func:`_preference_order`, ISSUE 54).
 SPARSE_DENSITY_THRESHOLD = 0.25
 # Tie band in that regime: the model's residual on the one measured
 # heterogeneous workload is ~8x (8.44 TF/s measured vs ~70 modeled), so
@@ -245,6 +248,9 @@ class CandidateScore:
     # what forward, dq and dkv take beyond their MXU time where the HBM's is
     # the longer, forward-sized (0.0 wherever the bytes are slack)
     hbm_excess_seconds: float = 0.0
+    # the order the ranking this rung came from breaks a tie by
+    # (:func:`_preference_order`): ``long_seq`` | ``measured`` | ``given``
+    tie_order: str = ""
 
     @property
     def bound(self) -> str:
@@ -526,22 +532,29 @@ def any_feasible_rung(
     )
 
 
-def _preference_order(extent: int):
-    """The measured rung preference for this extent class — the old static
-    table's ordering, reused as the tie-breaker (on-chip measurements
-    outrank the model inside its error bar)."""
+def _preference_order(extent: int, density: float):
+    """``(name, rungs)``: the measured rung preference for this extent
+    class and mask density — the old static table's ordering, reused as
+    the tie-breaker (on-chip measurements outrank the model inside its
+    error bar). The long-sequence lead was measured on one dense causal
+    slice at 64k; a mask under :data:`SPARSE_DENSITY_THRESHOLD` is not the
+    mask it was measured on and gets the table's own order whatever its
+    extent."""
     from ..ops.flex_attn import (
         _AUTO_BLOCK_CONFIGS,
         _LONG_SEQ_BLOCK_THRESHOLD,
         _LONG_SEQ_CONFIGS,
     )
 
-    if extent >= _LONG_SEQ_BLOCK_THRESHOLD:
+    if (
+        extent >= _LONG_SEQ_BLOCK_THRESHOLD
+        and density >= SPARSE_DENSITY_THRESHOLD
+    ):
         rest = tuple(
             c for c in _AUTO_BLOCK_CONFIGS if c not in _LONG_SEQ_CONFIGS
         )
-        return _LONG_SEQ_CONFIGS + rest
-    return _AUTO_BLOCK_CONFIGS
+        return "long_seq", _LONG_SEQ_CONFIGS + rest
+    return "measured", _AUTO_BLOCK_CONFIGS
 
 
 def rank_candidates(
@@ -583,16 +596,18 @@ def rank_candidates(
 
     The returned order is cost-ascending EXCEPT that candidates within
     :data:`TIE_TOLERANCE` of the best are resolved by the measured
-    preference order for the workload's extent — so dense workloads keep
-    the on-chip-measured winners while shape-sensitive workloads (narrow
-    varlen blocks, SWA bands) escape to occupancy-correct rungs. A rung
+    preference order for the workload's extent and density
+    (:func:`_preference_order`; which one, on :attr:`CandidateScore.tie_order`)
+    — so dense workloads keep the on-chip-measured winners while
+    shape-sensitive workloads (narrow varlen blocks, SWA bands, a few long
+    packed documents) escape to occupancy-correct rungs. A rung
     whose own price says its steps run at the HBM's pace
     (:attr:`CandidateScore.bound`) is no tie with one that does not: the
     preference order was measured where no rung streams, and is not asked.
 
     ``rungs``: the (block_q, block_k, head_block) table to rank in place of
-    the extent's preference order (a probe's rungs beside the chip's
-    readings of them).
+    the mask's preference order (a probe's rungs beside the chip's
+    readings of them), ties in the order given.
 
     ``max_block_q``/``max_block_k`` drop rungs larger than the caller's
     shard geometry (distributed plans: a tile wider than the per-rank
@@ -614,9 +629,13 @@ def rank_candidates(
 
     dv = head_dim if v_head_dim is None else int(v_head_dim)
     q, k, t = _normalize_slices(q_ranges, k_ranges, attn_type_map)
-    extent = 0
-    if q.size:
-        extent = max(int(q[:, 1].max()), int(k[:, 1].max()))
+    sq = int(q[:, 1].max()) if q.size else 0
+    sk = int(k[:, 1].max()) if k.size else 0
+    density = exact_mask_area(q, k, t) / max(sq * sk, 1)
+    if rungs:
+        tie_order = "given"
+    else:
+        tie_order, rungs = _preference_order(max(sq, sk), density)
     gen = generation if generation is not None else env.tpu_generation()
     spec = TPU_PEAK_SPECS.get(gen) or TPU_PEAK_SPECS["v5e"]
     eff_flops = spec.bf16_tflops * 1e12 * spec.mfu
@@ -667,6 +686,7 @@ def rank_candidates(
             smem_count=smem.count,
             hbm_seconds=streamed["fwd"],
             hbm_excess_seconds=excess,
+            tie_order=tie_order,
         )
 
     scores: list[CandidateScore] = []
@@ -687,7 +707,7 @@ def rank_candidates(
         seen.add(key)
         scores.append(cand)
 
-    for bq, bk, hb_pref in rungs or _preference_order(extent):
+    for bq, bk, hb_pref in rungs:
         # row-major FIRST: tied candidates resolve by generation order,
         # and inside the model's error bar the on-chip-measured
         # row-major rungs outrank the unmeasured sparse pricing
@@ -709,9 +729,6 @@ def rank_candidates(
             key=lambda s: (-s.block_q * s.block_k, -s.block_k, s.smem_entries),
         )
     best = min(s.cost_seconds for s in feasible)
-    sq = int(q[:, 1].max()) if q.size else 0
-    sk = int(k[:, 1].max()) if k.size else 0
-    density = exact_mask_area(q, k, t) / max(sq * sk, 1)
     hetero = (
         include_sparse
         and density < SPARSE_DENSITY_THRESHOLD

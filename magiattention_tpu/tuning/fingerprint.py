@@ -81,7 +81,11 @@ class WorkloadFingerprint:
     # v5 (ISSUE 35): the price holds the bytes a step streams from HBM; a
     # (128, 512, hb) cached for a GQA group 1 mask when no bytes were priced
     # is not served
-    FINGERPRINT_VERSION = 5
+    # v6 (ISSUE 54): under ``SPARSE_DENSITY_THRESHOLD`` the tie is broken by
+    # the table's own order at any extent; a (1024, 1024, 1) cached for a
+    # packed mask of a few long documents by the long-sequence lead is not
+    # served
+    FINGERPRINT_VERSION = 6
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)

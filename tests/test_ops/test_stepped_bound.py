@@ -361,9 +361,10 @@ def test_overlapping_stepped_slices_are_refused():
 # ---------------------------------------------------------------------------
 
 
-def _fingerprint(cell_name):
+def _fingerprint(cell_name, pinned=None):
     """What the planner and the tuner make of a cell's masks: the slices'
-    digest, the tuner's rung, the entry tables' bytes at that rung."""
+    digest, the tuner's rung (``pinned[kind]`` in its place, where
+    given), the entry tables' bytes at that rung."""
     from benchmarks import harness, masks
     from magiattention_tpu.api.functools import infer_attn_mask_from_cu_seqlens
     from magiattention_tpu.tuning.autotuner import resolve_block_config
@@ -388,7 +389,7 @@ def _fingerprint(cell_name):
     )
     out = {}
     for kind, (q, k, t) in found.items():
-        rung = resolve_block_config(
+        rung = (pinned or {}).get(kind) or resolve_block_config(
             [tuple(x) for x in q], [tuple(x) for x in k],
             tuple(int(x) for x in t), total, total, cell.chips, hq, hk, d,
             "bfloat16",
@@ -424,6 +425,19 @@ with open(os.path.join(HERE, "data", "step1_goldens.json")) as _f:
 
 
 KERNELS = GOLDENS.pop("_kernels")
+# plans whose rung the tuner has moved since the goldens were written: what
+# it gives now, and the tables at that rung. The planner is still held to
+# the golden, at the golden's own rung. ISSUE 54: the cp=4 packed mask (3.7%
+# of its square) no longer gets the long-sequence lead of the tie order
+MOVED = {
+    "magi64x8-attn-cp4-256k-varlen/full": {
+        "rung": [256, 512, 8],
+        "entries": [20656, 20656],
+        "tables": (
+            "1b99fb4a94e224ea076d79457e6d660a516f7f6f5b62f77eba2acf99101c6e55"
+        ),
+    },
+}
 
 
 def _step_one_programs(differentiated: bool) -> list[str]:
@@ -476,10 +490,18 @@ def test_step_one_traces_the_parents_kernels(program):
 def test_step_one_is_the_parents_plan(cell, monkeypatch):
     """Every mask of the eleven cells that stood before steps existed:
     the digest, the tuner's decision and the entry tables, byte for byte
-    what the parent commit built (the file holds the parent's)."""
+    what the parent commit built (the file holds the parent's). Where a
+    later tuner change moved a plan's rung (``MOVED``) the decision and the
+    tables are held to what it gives now, and the tables at the golden's
+    own rung to the golden."""
     for var in ("MAGI_ATTENTION_BLOCK_Q", "MAGI_ATTENTION_BLOCK_K",
                 "MAGI_ATTENTION_AUTOTUNE", "MAGI_ATTENTION_GRID"):
         monkeypatch.delenv(var, raising=False)
     want = {k: v for k, v in GOLDENS.items() if k.split("/")[0] == cell}
     with jax.enable_x64(False):
-        assert _fingerprint(cell) == want
+        assert _fingerprint(cell) == {
+            k: {**v, **MOVED.get(k, {})} for k, v in want.items()
+        }
+        for k in want.keys() & MOVED.keys():
+            at_the_goldens = {k.split("/")[1]: tuple(want[k]["rung"])}
+            assert _fingerprint(cell, at_the_goldens)[k] == want[k]

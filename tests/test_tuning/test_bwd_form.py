@@ -18,7 +18,8 @@ from .test_grid_choice import ROOT, _build_cell, _decisions, telemetry_on  # noq
 
 # (rung, GQA group, head_dim) -> bytes a live step moves, and FLOPs a byte
 RUNGS = {
-    "dense 64k, chunk-causal, cp4 packed": ((1024, 1024, 1), 8, 128, 2_621_440, 512),
+    "dense 64k, chunk-causal": ((1024, 1024, 1), 8, 128, 2_621_440, 512),
+    "cp4 packed": ((256, 512, 8), 8, 128, 5_242_880, 256),  # since ISSUE 54
     "packed 64k, window, Trinity, SDAR": ((128, 512, 8), 8, 128, 2_621_440, 256),
     "Mistral, ZAYA": ((128, 512, 8), 4, 128, 2_621_440, 256),
     "Ouro": ((256, 512, 8), 1, 128, 5_242_880, 256),
@@ -58,7 +59,7 @@ DQ_VISITS = {
     "magi64x8-attn-64k-varlen": [7.109],
     "magi64x8-attn-64k-causal": [32.625],
     "mistral7b-train-16k-onemask": [3.125],
-    "magi64x8-attn-cp4-256k-varlen": [12.75],
+    "magi64x8-attn-cp4-256k-varlen": [21.375],  # (256, 512, 8) since ISSUE 54
     "trinitymini-train-32k-packed": [4.406, 3.344],
     "magi64x8-attn-64k-swa1024": [2.984],
     "glm47flash-train-16k-packed": [3.375],
@@ -70,9 +71,11 @@ DQ_VISITS = {
     # the full plan (ZAYA's mask), then the window of 512: one block_k
     "phi4flash-train-16k-traces": [6.0, 1.9375],
     "xing4-train-8k-traces": [3.5],  # ZAYA's mask halved, at block_q 256
-    # four long documents: the full plan per head at (1024, 1024), then the
-    # window of 4,096 at (128, 512), 7 heads a step (ISSUE 53)
-    "smallthinker-train-16k-traces": [8.5, 5.9375],
+    # four long documents: the full plan, then the window of 4,096, both at
+    # (128, 512), 7 heads a step (ISSUE 53; the full plan ran per head at
+    # (1024, 1024) and 8.5 visits until ISSUE 54 took the long-sequence lead
+    # off masks under the density line)
+    "smallthinker-train-16k-traces": [7.9375, 5.9375],
 }
 
 

@@ -358,7 +358,10 @@ def test_a_band_mask_gets_a_rung_that_fits_the_band():
     assert [(s.block_q, s.block_k) for s in old if not s.feasible] == [
         (256, 512), (128, 512),
     ]
-    assert (old[0].block_q, old[0].block_k) == (1024, 1024)
+    # what was left tied on (1024, 1024, 1) by the long-sequence lead; since
+    # ISSUE 54 a mask at 3% of the square does not get the lead either way
+    assert (old[0].block_q, old[0].block_k) == (256, 1024)
+    assert {s.tie_order for s in ranked + old} == {"measured"}
 
 
 def test_a_table_that_really_passes_the_budget_stays_infeasible():
@@ -374,7 +377,9 @@ def test_a_table_that_really_passes_the_budget_stays_infeasible():
 @pytest.mark.parametrize(
     "family,verdicts,first",
     [
-        ("cp4_packed", (False, True, True, True, True), (1024, 1024, 1)),
+        # ISSUE 54: at 3.7% of the square the table's own order breaks the
+        # 15% tie, not the dense slice's lead: (1024, 1024, 1) until then
+        ("cp4_packed", (False, True, True, True, True), (256, 512, 8)),
         # nothing fits: the all-infeasible escalation order's widest tile
         ("cp4_causal", (False,) * 5, (512, 2048, 1)),
     ],
@@ -384,8 +389,9 @@ def test_per_rank_tables_keep_the_bound_and_its_verdicts(
 ):
     """cp = 4 (the box times 2 / cp): the global slices cannot count a
     rank's table, so the estimate stays every slice's bounding box times
-    the rank's share, and both cp=4 cells keep the verdicts and the rung
-    they had (whether the dense one should is ROADMAP S6's, on four chips)."""
+    the rank's share, and both cp=4 cells keep the verdicts they had, the
+    dense one its rung too (whether it should is ROADMAP S6's, on four
+    chips)."""
     qr, kr, ts, total = _bench_mask(family)
     assert tuple(
         smem_entries(qr, kr, ts, bq, bk, 4).feasible
@@ -603,3 +609,149 @@ def test_an_hbm_bound_rung_is_no_tie_with_one_that_is_not():
     ]
     gqa = rank_candidates(m.q_ranges, m.k_ranges, m.types, 64, 8, **args)
     assert (gqa[0].block_q, gqa[0].block_k, gqa[0].head_block) == (128, 512, 8)
+
+
+# -- the tie order's long-sequence lead (ISSUE 54) --------------------------
+def _lengths_mask(lengths):
+    from benchmarks import masks
+
+    return masks.build_mask(
+        {"type": "varlen_block_causal", "lengths": list(lengths)}, sum(lengths)
+    )
+
+
+def _first(ranked):
+    return (ranked[0].block_q, ranked[0].block_k, ranked[0].head_block)
+
+
+def test_a_few_long_documents_get_the_checks_rung_not_the_dense_cells():
+    """SmallThinker's timed mask (four documents in 16,384 rows, 23.1% of
+    the square) at 28 / 4 heads: (1024, 1024, 1) is 8% over the cheapest
+    rung, inside the 15% tie, and led the tie order from an extent of
+    16,384 on. The lead was measured on a dense 64k slice; under the
+    ranker's own density line the table's order breaks the tie, and the
+    timed full plan walks the rung the check's (8,192 rows: no lead at any
+    density) always did."""
+    from magiattention_tpu.tuning.cost_model import SPARSE_DENSITY_THRESHOLD
+
+    timed = _lengths_mask((10240, 4096, 1536, 512))
+    check = _lengths_mask((6144, 1536, 512))
+    assert timed.area / timed.total**2 < SPARSE_DENSITY_THRESHOLD
+    ranked = {
+        m.total: rank_candidates(
+            m.q_ranges, m.k_ranges, m.types, 28, 4, max_block_q=m.total,
+            max_block_k=m.total, include_sparse=False,
+        )
+        for m in (timed, check)
+    }
+    assert _first(ranked[16384]) == _first(ranked[8192]) == (128, 512, 7)
+    assert {s.tie_order for r in ranked.values() for s in r} == {"measured"}
+    # the price did not move: the per-head rung is in the tie as it was
+    cost = {(s.block_q, s.block_k): s.cost_seconds for s in ranked[16384]}
+    assert 1.07 < cost[1024, 1024] / min(cost.values()) < 1.09
+    assert min(cost, key=cost.get) == (256, 512)
+
+
+@pytest.mark.parametrize("family", ["causal", "chunk_causal"])
+def test_the_masks_the_lead_was_measured_on_keep_it(family):
+    """Dense causal and chunk-causal at 65,536 rows and 64 / 8 heads (half
+    the square and over): (1024, 1024, 1), by the long-sequence lead."""
+    qr, kr, ts, total = _bench_mask(family)
+    for include_sparse in (False, True):
+        ranked = rank_candidates(
+            qr, kr, ts, 64, 8, max_block_q=total, max_block_k=total,
+            include_sparse=include_sparse,
+        )
+        assert _first(ranked) == (1024, 1024, 1)
+        assert ranked[0].grid == "row_major"
+        assert {s.tie_order for s in ranked} == {"long_seq"}
+
+
+# mask -> (q ranges, k ranges, types, rows, cp); a dozen and more with the
+# head geometries below: every family a cell runs, and masks either side of
+# the density line at either side of 16,384 rows
+_LEAD_MASKS = {
+    "packed": lambda: (*_bench_mask("packed"), 1),
+    "causal": lambda: (*_bench_mask("causal"), 1),
+    "chunk_causal": lambda: (*_bench_mask("chunk_causal"), 1),
+    "swa1024": lambda: (*_bench_mask("swa1024"), 1),
+    "swa4096": lambda: (*_bench_mask("swa4096"), 1),
+    "cp4_packed": lambda: (*_bench_mask("cp4_packed"), 4),
+    "cp4_causal": lambda: (*_bench_mask("cp4_causal"), 4),
+    "packed16k": lambda: (*_bench_mask("packed16k"), 1),
+    "packed32k": lambda: (*_bench_mask("packed32k"), 1),
+    "four_docs_16k": lambda: _of(_lengths_mask((10240, 4096, 1536, 512))),
+    "three_docs_8k": lambda: _of(_lengths_mask((6144, 1536, 512))),
+    "five_docs_16k": lambda: _of(_lengths_mask((8192, 4096, 2048, 1536, 512))),
+    "two_docs_32k": lambda: _of(_lengths_mask((16384, 16384))),
+    "one_doc_16k": lambda: _of(_lengths_mask((16384,))),
+}
+
+
+def _of(m):
+    return m.q_ranges, m.k_ranges, m.types, m.total, 1
+
+
+@pytest.mark.parametrize(
+    "heads",
+    [(64, 8, 128, None), (28, 4, 128, None), (16, 16, 128, None),
+     (20, 20, 256, None), (32, 32, 192, 128)],
+    ids=lambda h: "x".join(str(x) for x in h if x),
+)
+@pytest.mark.parametrize("mask", list(_LEAD_MASKS))
+def test_the_lead_decides_only_a_plan_it_put_on_a_long_sequence_rung(
+    mask, heads
+):
+    """The tied set is the same set whichever order breaks the tie, and the
+    long-sequence rungs close the table's own order: a ranking whose winner
+    WITH the lead is neither of them had neither in its tie, and keeps its
+    winner without it. So taking the lead off a mask can move only a plan
+    that sat on (1024, 1024, 1) or (512, 2048, 1). The ranker's own choice
+    of order reads the mask's density and extent and nothing else."""
+    from magiattention_tpu.ops.flex_attn import (
+        _LONG_SEQ_BLOCK_THRESHOLD,
+        _LONG_SEQ_CONFIGS,
+    )
+    from magiattention_tpu.tuning.cost_model import (
+        SPARSE_DENSITY_THRESHOLD,
+        _preference_order,
+        exact_mask_area,
+    )
+
+    qr, kr, ts, total, cp = _LEAD_MASKS[mask]()
+    hq, hk, d, dv = heads
+    # both orders, whatever this mask would get: a dense long mask's, and
+    # a sparse one's
+    orders = dict(
+        _preference_order(_LONG_SEQ_BLOCK_THRESHOLD, density)
+        for density in (1.0, 0.0)
+    )
+    assert orders["measured"] == _AUTO_BLOCK_CONFIGS
+    assert orders["long_seq"][:2] == _LONG_SEQ_CONFIGS
+    dense = exact_mask_area(qr, kr, ts) / total**2 >= SPARSE_DENSITY_THRESHOLD
+    want = (
+        "long_seq" if dense and total >= _LONG_SEQ_BLOCK_THRESHOLD
+        else "measured"
+    )
+    for include_sparse in (False, True):
+        args = dict(
+            head_dim=d, v_head_dim=dv, max_block_q=total // cp,
+            max_block_k=total // cp, cp_size=cp,
+            include_sparse=include_sparse,
+        )
+        given = {
+            name: rank_candidates(qr, kr, ts, hq, hk, rungs=order, **args)
+            for name, order in orders.items()
+        }
+        key = lambda s: (s.block_q, s.block_k, s.head_block, s.grid)  # noqa: E731
+        # the same candidates at the same prices, in either order
+        assert {key(s): s.cost_seconds for s in given["long_seq"]} == {
+            key(s): s.cost_seconds for s in given["measured"]
+        }
+        led = given["long_seq"][0]
+        if (led.block_q, led.block_k) not in {c[:2] for c in _LONG_SEQ_CONFIGS}:
+            assert key(given["measured"][0]) == key(led)
+        own = rank_candidates(qr, kr, ts, hq, hk, **args)
+        assert {s.tie_order for s in own} == {want}
+        assert [key(s) for s in own] == [key(s) for s in given[want]]
+        assert {s.tie_order for s in given[want]} == {"given"}

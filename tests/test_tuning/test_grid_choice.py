@@ -101,8 +101,12 @@ CELLS = {
     "mistral7b-train-16k-onemask": [
         ((128, 512, 8), "sparse", 2 * 128 * 9 + 33 * 31, 3 * 400, 1182),
     ],
+    # ISSUE 54: 3.7% of the square, so the tie is broken by the table's own
+    # order and not by the 64k dense slice's lead: (256, 512, 8), the first
+    # tied rung whose per-rank bound fits, where it had (1024, 1024, 1)
+    # (12,060 / 2,448 / 2,306.25)
     "magi64x8-attn-cp4-256k-varlen": [
-        ((1024, 1024, 1), "sparse", 12060, 2448, 2306.25),
+        ((256, 512, 8), "sparse", 91556, 16416, 15894.75),
     ],
     "trinitymini-train-32k-packed": [
         ((128, 512, 8), "sparse", 2 * 256 * 16 + 65 * 62, 3 * 1128, 3363),
@@ -209,9 +213,15 @@ def test_every_cells_decision_says_which_roof_binds_its_rung(
     from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
 
     monkeypatch.delenv("MAGI_ATTENTION_GRID", raising=False)
-    counted = telemetry.get_registry().counter_value(
-        "magi_autotune_decisions_total", bound="mxu"
-    )
+    def counted_mxu():  # the counter's series, one a tie order (ISSUE 54)
+        return sum(
+            telemetry.get_registry().counter_value(
+                "magi_autotune_decisions_total", bound="mxu", tie_order=o
+            )
+            for o in ("long_seq", "measured")
+        )
+
+    counted = counted_mxu()
     got = _decisions(lambda: _build_cell(cell), "autotune_decision")
     assert len(got) == len(CELLS[cell])
     for args, (rung, *_rest) in zip(got, CELLS[cell]):
@@ -222,9 +232,7 @@ def test_every_cells_decision_says_which_roof_binds_its_rung(
             args["hbm_seconds"] * cost_model.HBM_PRICE_SHARE
             < args["mxu_seconds"] * TPU_PEAK_SPECS["v5e"].mfu
         )
-    assert telemetry.get_registry().counter_value(
-        "magi_autotune_decisions_total", bound="mxu"
-    ) == counted + len(got)
+    assert counted_mxu() == counted + len(got)
     if cell in GROUP_ONE:
         from benchmarks import harness, masks
 
@@ -280,7 +288,7 @@ def test_a_version_3_record_for_a_band_mask_is_not_served(
     fingerprint does not match. Either way the mask is ranked anew."""
     from benchmarks import masks
 
-    assert WorkloadFingerprint.FINGERPRINT_VERSION == 5
+    assert WorkloadFingerprint.FINGERPRINT_VERSION == 6
     monkeypatch.setenv("MAGI_ATTENTION_AUTOTUNE_CACHE_DIR", str(tmp_path))
     monkeypatch.delenv("MAGI_ATTENTION_AUTOTUNE", raising=False)
     total = 65536
