@@ -102,6 +102,17 @@ rows no collective has touched, and the FFN half consumes it
 configuration's experts gate through ReLU (``expert_act``), its full
 layers carry no position and its window layers rotary.
 
+A Mamba-2 hybrid (Granite 4.0-H / ``granitemoehybrid_config``) is the
+plain GQA skeleton with one more layer kind, ``state_space_dual``: the
+Mamba-2 mixer of ``models/ssm.py`` on the chunked scan of
+``ops/ssd_scan.py`` (nine such layers to one NoPE ``full_attention``
+layer), and the family's four scalars: ``embed_scale`` on the embedding,
+``residual_scale`` on both residual adds (:func:`_residual`),
+``softmax_scale`` (1/64 at 64-wide heads, not 1/8) and
+``logits_scaling`` under the logits. At their defaults (1, 1,
+``head_dim ** -0.5``, 1) no operation is added to another
+configuration's program.
+
 Like ``llama.py`` the whole decoder runs inside one ``shard_map`` over a
 (dp, cp) mesh with parameters replicated, so the train step is a single
 jit (``_common.make_model_train_step``).
@@ -134,6 +145,7 @@ from .llama import _rms_norm, _rope
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the kinds that are not attention over their own q, k, v
 SSM, GMU, CROSS = "state_space", "gated_memory", "cross_attention"
+SSD = "state_space_dual"  # the Mamba-2 mixer, under GQA
 DENSE, EXPERTS = "dense", "experts"
 GQA, LATENT, CCA, DIFF = "gqa", "latent", "cca", "diff"
 RMS, LAYER = "rms", "layer"  # the norm's forms
@@ -149,7 +161,8 @@ class PatternConfig:
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    # SLIDING | FULL, a layer; under DIFF also SSM | GMU | CROSS
+    # SLIDING | FULL, a layer; under DIFF also SSM | GMU | CROSS; under GQA
+    # also SSD
     layer_types: tuple[str, ...]
     ffn_types: tuple[str, ...]  # DENSE | EXPERTS, a layer
     ffn_hidden: int  # the dense FFN's width
@@ -162,6 +175,11 @@ class PatternConfig:
     attn_gate: bool = True
     post_norms: bool = True
     embed_scale: float = 1.0
+    # Granite's scalars beside ``embed_scale`` and ``softmax_scale``: what a
+    # half-layer's output is multiplied by before the residual add, and
+    # what the logits are divided by
+    residual_scale: float = 1.0
+    logits_scaling: float = 1.0
     rms_eps: float = 1e-5
     # the expert FFN
     n_experts: int = 0  # the router's width
@@ -243,6 +261,11 @@ class PatternConfig:
     ssm_state: int = 0
     ssm_conv: int = 0
     ssm_dt_rank: int = 0
+    # the Mamba-2 mixer of an SSD layer: ``ssm_heads`` heads of ``ssm_inner
+    # / ssm_heads`` channels, one group of ``ssm_state`` states, the scan's
+    # chunk (0: the kernel's own)
+    ssm_heads: int = 0
+    ssm_chunk: int = 0
     scan_state_dtype: str = "float32"  # bfloat16: the benchmark's control
     # a layer's index in the published model where the layers are a cut
     # of it (DIFF's lambda_init reads it); (): the layer's own place
@@ -269,7 +292,7 @@ class PatternConfig:
     def __post_init__(self):
         if len(self.layer_types) != len(self.ffn_types):
             raise ValueError("layer_types and ffn_types differ in length")
-        bad = set(self.layer_types) - {SLIDING, FULL, SSM, GMU, CROSS}
+        bad = set(self.layer_types) - {SLIDING, FULL, SSM, GMU, CROSS, SSD}
         bad |= set(self.ffn_types) - {DENSE, EXPERTS}
         bad |= {self.attn_form} - {GQA, LATENT, CCA, DIFF}
         bad |= {self.router_form} - {SIGMOID, MLP, SOFTMAX}
@@ -353,6 +376,28 @@ class PatternConfig:
 
     def _check_handed_on(self):
         kinds = self.layer_types
+        if SSD in kinds:
+            if (
+                self.attn_form != GQA or self.hc_mult or self.n_loops > 1
+                or self.diffusion_block or self.n_mtp
+                or EXPERTS in self.ffn_types
+            ):
+                raise ValueError(
+                    "state-space-dual layers are stated beside plain GQA "
+                    "layers and dense FFNs: no reference states another"
+                )
+            if min(
+                self.ssm_inner, self.ssm_state, self.ssm_conv, self.ssm_heads
+            ) < 1 or self.ssm_inner % self.ssm_heads:
+                raise ValueError(
+                    "a state-space-dual layer needs the mixer's sizes: "
+                    "ssm_inner in whole heads, ssm_state, ssm_conv"
+                )
+        if self.residual_scale != 1.0 and self.hc_mult:
+            raise ValueError(
+                "a residual multiplier under residual streams (hc_mult): "
+                "no reference states one"
+            )
         if self.attn_form != DIFF:
             if set(kinds) & {SSM, GMU, CROSS}:
                 raise ValueError(
@@ -454,7 +499,7 @@ class PatternConfig:
     def shift_taps(self) -> tuple[int, ...]:
         """How far back a layer reads outside the attention call: the two
         convolutions one after the other (and the value's one token)."""
-        if SSM in self.layer_types:  # the mixer's causal convolution
+        if {SSM, SSD} & set(self.layer_types):  # the mixer's convolution
             return tuple(range(1, self.ssm_conv))
         if self.attn_form != CCA:
             return ()
@@ -466,7 +511,7 @@ class PatternConfig:
 
     def plan_kind(self, layer_type: str) -> str | None:
         """The plan a layer's attention runs on; None: no attention."""
-        if layer_type in (SSM, GMU):
+        if layer_type in (SSM, GMU, SSD):
             return None
         if layer_type == SLIDING and self.sliding_window is not None:
             return SLIDING
@@ -977,6 +1022,74 @@ def phi4flash_config(
     )
 
 
+def granitemoehybrid_config(
+    hf: dict,
+    *,
+    dtype: str = "bfloat16",
+    remat: bool = False,
+    vocab_size: int | None = None,
+) -> PatternConfig:
+    """A published ``granitemoehybrid`` ``config.json`` (Granite 4.0-H) as
+    a pattern: ``layer_types`` read up to ``num_hidden_layers`` (a cut
+    keeps the published list whole), ``mamba`` the Mamba-2 mixer (SSD)
+    and ``attention`` GQA over the whole document with no position
+    (``position_embedding_type`` nope) at ``hidden_size /
+    num_attention_heads`` a head; every layer the shared dense SwiGLU
+    (``num_local_experts`` 0 is the only form built); RMSNorm, no bias but
+    the convolution's, the embedding tied; and the family's four scalars:
+    ``embedding_multiplier`` on the embedding, ``residual_multiplier`` on
+    both residual adds, ``attention_multiplier`` as the softmax scale,
+    ``logits_scaling`` under the logits. ``vocab_size`` gives one rank's
+    share of the rows."""
+    n = int(hf["num_hidden_layers"])
+    kinds = list(hf["layer_types"][:n])
+    if len(kinds) != n or set(kinds) - {"mamba", "attention"}:
+        raise ValueError(f"layer_types of {n} layers: {kinds}")
+    if hf.get("num_local_experts") or hf.get("num_experts_per_tok"):
+        raise ValueError("a granitemoehybrid configuration with experts is not built")
+    if hf["position_embedding_type"] != "nope" or hf.get("rope_scaling"):
+        raise ValueError("a granitemoehybrid configuration with rotary is not built")
+    if (
+        hf["mamba_n_groups"] != 1 or not hf["mamba_conv_bias"]
+        or hf["mamba_proj_bias"] or hf["attention_bias"]
+        or hf.get("normalization_function", "rmsnorm") != "rmsnorm"
+    ):
+        raise ValueError(
+            "built: one group, a bias on the convolution alone, RMSNorm"
+        )
+    dim, heads = int(hf["hidden_size"]), int(hf["num_attention_heads"])
+    inner = int(hf["mamba_expand"]) * dim
+    if inner != int(hf["mamba_n_heads"]) * int(hf["mamba_d_head"]):
+        raise ValueError("mamba_expand x hidden_size is not the heads' width")
+    return PatternConfig(
+        vocab_size=int(vocab_size or hf["vocab_size"]),
+        dim=dim,
+        n_heads=heads,
+        n_kv_heads=int(hf["num_key_value_heads"]),
+        head_dim=int(hf.get("head_dim") or dim // heads),
+        layer_types=tuple(SSD if k == "mamba" else FULL for k in kinds),
+        ffn_types=(DENSE,) * n,
+        ffn_hidden=int(hf["shared_intermediate_size"]),
+        rope_kinds=(),
+        qk_norm=False,
+        attn_gate=False,
+        post_norms=False,
+        embed_scale=float(hf["embedding_multiplier"]),
+        residual_scale=float(hf["residual_multiplier"]),
+        logits_scaling=float(hf["logits_scaling"]),
+        softmax_scale=float(hf["attention_multiplier"]),
+        rms_eps=float(hf["rms_norm_eps"]),
+        dtype=dtype,
+        remat=remat,
+        tie_embeddings=bool(hf["tie_word_embeddings"]),
+        ssm_inner=inner,
+        ssm_state=int(hf["mamba_d_state"]),
+        ssm_conv=int(hf["mamba_d_conv"]),
+        ssm_heads=int(hf["mamba_n_heads"]),
+        ssm_chunk=int(hf["mamba_chunk_size"]),
+    )
+
+
 def llama_pattern(cfg) -> PatternConfig:
     """``models/llama.py``'s decoder as a pattern: every layer (full,
     dense), rotary everywhere, the AFMoE extras off. ``init_params`` of
@@ -1008,6 +1121,8 @@ def _init_layer(key: jax.Array, cfg: PatternConfig, ffn: str,
     k = jax.random.split(key, 12)
     if layer_type == SSM:
         layer = ssm.init_mamba(jax.random.fold_in(key, 4), cfg)
+    elif layer_type == SSD:
+        layer = ssm.init_mamba2(jax.random.fold_in(key, 4), cfg)
     elif layer_type == GMU:
         layer = ssm.init_gmu(jax.random.fold_in(key, 4), cfg)
     elif layer_type == CROSS:  # a query alone
@@ -1841,6 +1956,18 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
             carry["m"] = m
         with named_scope("magi_proj"):
             x = x + out
+    elif layer_type == SSD:
+        with named_scope("magi_proj"):
+            h = _norm(x, layer, "attn_norm", cfg)
+        shift_tabs = tables["shift"]
+        out = ssm.mamba2_mixer(
+            h, layer, cfg,
+            lambda u: shift_local(u, shift_tabs, shift_plan, axis_name),
+            ~shift_valid(shift_tabs)[0],
+            interpret=next(iter(attn_params.values())).interpret,
+        )
+        with named_scope("magi_proj"):
+            x = _residual(x, out, cfg)
     elif layer_type == GMU:
         with named_scope("magi_gmu"):
             h = _norm(x, layer, "attn_norm", cfg)
@@ -1852,7 +1979,14 @@ def _layer_local(x, pos, layer, carry=None, *, cfg, layer_type, ffn_type,
     if carry:
         stats["carry"] = carry
     with named_scope("magi_ffn"):
-        return x + out, stats
+        return _residual(x, out, cfg), stats
+
+
+def _residual(x, out, cfg: PatternConfig):
+    """``x`` + a half-layer's output under ``cfg.residual_scale``."""
+    if cfg.residual_scale != 1.0:
+        out = out * jnp.asarray(cfg.residual_scale, out.dtype)
+    return x + out
 
 
 def _ffn_out(x, layer, carry, *, cfg, ffn_type):
@@ -2091,7 +2225,7 @@ def _attention_half(x, pos, layer, carry, **how):
     (:func:`_attention_out`)."""
     out = _attention_out(x, pos, layer, carry, **how)
     with named_scope("magi_proj"):
-        return x + out
+        return _residual(x, out, how["cfg"])
 
 
 def _attention_out(x, pos, layer, carry, *, cfg, layer_type, tables, plans,
@@ -2226,7 +2360,10 @@ def _logits(x, params, cfg: PatternConfig):
         head = params["embed"].astype(cfg.jnp_dtype).T
     else:
         head = params["lm_head"].astype(cfg.jnp_dtype)
-    return (x @ head).astype(jnp.float32)
+    logits = (x @ head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _head(x, w: dict, params, cfg: PatternConfig):
@@ -2689,7 +2826,8 @@ def build_magi_pattern(
     cu = [int(c) for c in cu_seqlens]
     data_tokens = cu[-1]
     cp_size, _ = _cp_geometry(mesh, cp_axis)
-    if SSM in cfg.layer_types and cp_size > 1:
+    scans = {SSM, SSD} & set(cfg.layer_types)
+    if scans and cp_size > 1:
         raise NotImplementedError(
             f"a state-space layer at cp = {cp_size}: the scan runs on one "
             "rank's rows in sequence order; the hand-over of its state "
@@ -2728,7 +2866,7 @@ def build_magi_pattern(
         plans[kind], attn_params[kind] = plan_flex_attn_on_dispatch(
             heads, mesh, meta, *mask(kind), kind=_SHORT[kind], **common,
         )
-    if SSM in cfg.layer_types and not np.array_equal(
+    if scans and not np.array_equal(
         np.asarray(meta.perm_idx), np.arange(total)
     ):
         raise NotImplementedError(
@@ -2751,6 +2889,20 @@ def build_magi_pattern(
     telemetry.record_moe_route_ahead(
         ffn_kinds.count(EXPERTS) if cfg.router_input == "attn" else 0
     )
+    if SSD in cfg.layer_types:
+        from ..ops.ssd_scan import CHUNK
+
+        chunk = cfg.ssm_chunk or CHUNK
+        telemetry.record_ssd_model(
+            documents=len(cu) - 1,
+            # the chunks with a document's start strictly inside
+            reset_chunks=len({c // chunk for c in cu[1:-1] if c % chunk}),
+            multipliers={
+                "embed": cfg.embed_scale, "residual": cfg.residual_scale,
+                "softmax": cfg.softmax_scale or cfg.head_dim ** -0.5,
+                "logits": cfg.logits_scaling,
+            },
+        )
     if cfg.attn_form == DIFF:
         readers = cfg.layer_types.count(CROSS)
         telemetry.record_handed_on(

@@ -24,6 +24,20 @@ The convolution reads a token's ``cfg.ssm_conv - 1`` predecessors through
 wherever dispatch put them); the scan runs on rows in sequence order, so
 a state-space layer needs cp = 1 until a rank hands its state to the next
 (ROADMAP R8).
+
+Mamba-2 (arXiv:2405.21060; granite-4.0-h-micro's ``mamba`` layers) is the
+second mixer, :func:`mamba2_mixer`: ``H`` = ``cfg.ssm_heads`` heads of
+``P`` = ``E / H`` channels, one group of ``N`` states, a scalar decay a
+head, so the scan is the chunked state-space-dual form of
+``ops/ssd_scan.py`` (matmuls; imported where the mixer runs, not with the
+package)::
+
+    [z | xBC | dl] = h W_in         # E | E + 2 N | H        (magi_proj)
+    xBC = silu(conv(xBC) + b_c)     # depthwise, causal, inside the document
+    [x | B | C] = xBC               # E | N | N             (magi_ssm_mix)
+    dt  = softplus(dl + b_dt)                               # float32
+    y   = ssd_scan(x, dt, -exp(A_log), B, C, D)             # magi_ssd_scan
+    out = (rmsnorm(y * silu(z)) w_n) W_out   # the gated norm: magi_ssm_mix
 """
 
 from __future__ import annotations
@@ -69,6 +83,33 @@ def init_mamba(key: jax.Array, cfg) -> dict:
     }
 
 
+def init_mamba2(key: jax.Array, cfg) -> dict:
+    """The Mamba-2 mixer's parameters, the seed's draws as
+    :func:`init_mamba`'s: ``A_log`` the log of a draw in [1, 16] a head,
+    ``b_dt`` the inverse softplus of a step log-uniform in [1e-3, 1e-1],
+    ``D`` and the gated norm's weight ones."""
+    e, n, h, taps = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_conv
+    k = jax.random.split(key, 6)
+    step = jnp.exp(
+        jax.random.uniform(k[4], (h,), F32) * (np.log(1e-1) - np.log(1e-3))
+        + np.log(1e-3)
+    )
+    return {
+        # z's columns, then x's, B's and C's, then the step's a head
+        "ssd_in": _dense(k[0], (cfg.dim, 2 * e + 2 * n + h)),
+        # tap j reads the token j before: a weight a channel a tap
+        "ssd_conv_w": _dense(k[1], (taps, e + 2 * n)),
+        "ssd_conv_b": 0.02 * jax.random.normal(k[2], (e + 2 * n,), F32),
+        "ssd_dt_b": step + jnp.log(-jnp.expm1(-step)),
+        "ssd_a_log": jnp.log(
+            jax.random.uniform(k[3], (h,), F32, minval=1.0, maxval=16.0)
+        ),
+        "ssd_d": jnp.ones((h,), F32),
+        "ssd_norm": jnp.ones((e,), F32),
+        "ssd_out": _dense(k[5], (e, cfg.dim)),
+    }
+
+
 def init_gmu(key: jax.Array, cfg) -> dict:
     k1, k2 = jax.random.split(key)
     return {
@@ -111,6 +152,40 @@ def mamba_mixer(h, layer: dict, cfg, shift, start, *, interpret=None):
         gated = y * jax.nn.silu(z)
     with named_scope("magi_proj"):
         return gated @ layer["ssm_out"].astype(dt), y
+
+
+def mamba2_mixer(h, layer: dict, cfg, shift, start, *, interpret=None):
+    """The Mamba-2 mixer's output [t, dim] (module docstring); ``shift``,
+    ``start`` and ``interpret`` as :func:`mamba_mixer`'s."""
+    from ..ops.ssd_scan import ssd_scan
+
+    dt = cfg.jnp_dtype
+    e, n, heads = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+    t = h.shape[0]
+    with named_scope("magi_proj"):
+        zxd = h @ layer["ssd_in"].astype(dt)
+        z, xbc, dl = zxd[:, :e], zxd[:, e : 2 * e + 2 * n], zxd[:, 2 * e + 2 * n :]
+    with named_scope("magi_ssm_mix"):
+        w = layer["ssd_conv_w"]
+        conv = layer["ssd_conv_b"] + w[0] * xbc.astype(F32)
+        for j, back in enumerate(shift(xbc), start=1):
+            conv = conv + w[j] * back.astype(F32)
+        xbc = jax.nn.silu(conv).astype(dt)
+        delta = jax.nn.softplus(dl.astype(F32) + layer["ssd_dt_b"])
+        a = -jnp.exp(layer["ssd_a_log"])
+    with named_scope("magi_ssd_scan"):
+        y = ssd_scan(
+            xbc[:, :e].reshape(t, heads, e // heads), delta, a,
+            xbc[:, e : e + n], xbc[:, e + n :], layer["ssd_d"], start,
+            chunk=cfg.ssm_chunk or None, state_dtype=cfg.scan_state_dtype,
+            interpret=interpret,
+        ).reshape(t, e)
+    with named_scope("magi_ssm_mix"):
+        g = (y * jax.nn.silu(z)).astype(F32)
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + cfg.rms_eps)
+        gated = (g * layer["ssd_norm"]).astype(dt)
+    with named_scope("magi_proj"):
+        return gated @ layer["ssd_out"].astype(dt)
 
 
 def gmu(h, m, layer: dict, cfg):

@@ -73,6 +73,14 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def on_jnp_backend() -> bool:
+    """Whether ``MAGI_ATTENTION_KERNEL_BACKEND`` asks for the
+    ``jax.numpy`` form of a scan (``ops/ssd_scan.py`` asks here too)."""
+    from .. import env
+
+    return env.kernel_backend() in ("jnp", "jnp_online")
+
+
 def make_scan_params(
     rows: int, channels: int, *, chunk: int | None = None,
     channel_block: int | None = None, interpret: bool | None = None,
@@ -426,8 +434,6 @@ def selective_scan(
     """``y`` [T, C] in ``u``'s dtype (module docstring). ``start`` marks
     the rows at which a document starts; a row past the sequence's last
     document may be marked too (padding: it reads nothing)."""
-    from .. import env
-
     t, ch = u.shape
     p = make_scan_params(
         t, ch, chunk=chunk, channel_block=channel_block,
@@ -440,7 +446,7 @@ def selective_scan(
     )
     keep = jnp.pad(1 - start.astype(jnp.int32), (0, pad))
     a_t = a.astype(F32).T
-    if env.kernel_backend() in ("jnp", "jnp_online"):
+    if on_jnp_backend():
         y = _scan_jnp(uf, dtf, a_t, bp, cp, keep, p)
     else:
         y = _scan_pallas(uf, dtf, a_t, bp, cp, keep, p)

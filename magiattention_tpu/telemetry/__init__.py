@@ -81,6 +81,8 @@ from .collectors import (  # noqa: F401
     record_mhc,
     record_mhc_coef,
     record_mla_kv_cast_width,
+    record_ssd_model,
+    record_ssd_scan,
     record_ssm_scan,
     record_model_loop,
     record_shift,
@@ -387,6 +389,8 @@ __all__ = [
     "record_mhc",
     "record_mhc_coef",
     "record_mla_kv_cast_width",
+    "record_ssd_model",
+    "record_ssd_scan",
     "record_ssm_scan",
     "record_model_loop",
     "record_shift",

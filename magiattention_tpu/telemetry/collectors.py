@@ -188,6 +188,19 @@ M_SSM_SCAN_CALLS = "magi_ssm_scan_calls_total"
 M_SSM_DOCUMENTS = "magi_ssm_documents"
 M_SSM_CHUNKS = "magi_ssm_chunks"
 M_SSM_STATE_BYTES = "magi_ssm_state_bytes"
+# the state-space-dual scan (ops/ssd_scan.py). counter — scan calls
+# traced: {phase=fwd|bwd}; gauges — a call's heads, its chunks and the
+# bytes of the chunk boundaries' states its forward keeps for the
+# backward; the chunks with a document's start strictly inside (the reset
+# is a mask on their tile), set where the model is built
+M_SSD_SCAN_CALLS = "magi_ssd_scan_calls_total"
+M_SSD_HEADS = "magi_ssd_heads"
+M_SSD_CHUNKS = "magi_ssd_chunks"
+M_SSD_RESET_CHUNKS = "magi_ssd_reset_chunks"
+M_SSD_STATE_BYTES = "magi_ssd_state_bytes"
+# gauge — a pattern decoder's scalars on the embedding, the residual
+# adds, the softmax and the logits: {multiplier=embed|residual|softmax|logits}
+M_MODEL_MULTIPLIERS = "magi_model_multipliers"
 # gauge — layers that read the keys and values one layer handed on;
 # counter — rows of that pair a rank's casts carried for a reader after
 # the first (0 at cp = 1: every reader casts the pair again, ROADMAP R11);
@@ -1334,6 +1347,35 @@ def record_ssm_scan(phase: str, *, chunks: int, state_bytes: int) -> None:
     reg.counter_inc(M_SSM_SCAN_CALLS, 1, phase=phase)
     reg.gauge_set(M_SSM_CHUNKS, float(chunks))
     reg.gauge_set(M_SSM_STATE_BYTES, float(state_bytes))
+
+
+def record_ssd_scan(phase: str, *, heads: int, chunks: int,
+                    state_bytes: int) -> None:
+    """One state-space-dual scan traced (``ops/ssd_scan.py``, trace time:
+    once a compiled program, like the named scopes)."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.counter_inc(M_SSD_SCAN_CALLS, 1, phase=phase)
+    reg.gauge_set(M_SSD_HEADS, float(heads))
+    reg.gauge_set(M_SSD_CHUNKS, float(chunks))
+    reg.gauge_set(M_SSD_STATE_BYTES, float(state_bytes))
+
+
+def record_ssd_model(*, documents: int, reset_chunks: int,
+                     multipliers: dict[str, float]) -> None:
+    """A pattern decoder with state-space-dual layers
+    (``models/pattern.build_magi_pattern``, host side): the documents a
+    scan resets at, the scan chunks with a start strictly inside, and the
+    model's four scalars."""
+    if not _enabled():
+        return
+    reg = get_registry()
+    reg.gauge_set(M_SSM_DOCUMENTS, float(documents))
+    reg.gauge_set(M_SSD_RESET_CHUNKS, float(reset_chunks))
+    for name, value in multipliers.items():
+        # the label is ``multiplier``: ``name`` is the registry's own word
+        reg.gauge_set(M_MODEL_MULTIPLIERS, float(value), multiplier=name)
 
 
 def record_handed_on(

@@ -438,6 +438,123 @@ def test_selective_scan_at_the_sambay_cells_size(topo, chunk, block):
         assert kernel in text, kernel
 
 
+def _granite_mask(which: str):
+    """(q_ranges, k_ranges, types, rows) of the granite-4.0-h-micro cell's
+    documents (``which`` "mask": the window's 16,384 rows; "check_mask":
+    the check's 4,096), causal inside each, as ``build_magi_pattern``
+    makes it for the attention layer."""
+    import json
+
+    from magiattention_tpu.api.functools import infer_attn_mask_from_cu_seqlens
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+        here, "benchmarks", "traffic", "train-16k-packed-ssd.json"
+    )) as f:
+        lengths = json.load(f)[which]["lengths"]
+    cu = [0]
+    for n in lengths:
+        cu.append(cu[-1] + n)
+    q, k, t = infer_attn_mask_from_cu_seqlens(cu, causal=True)
+    return q.to_naive_ranges(), k.to_naive_ranges(), [int(x) for x in t], cu[-1]
+
+
+def _every_rung():
+    """(id, mask, rung, grid): every step of the tuner's table on the
+    cell's mask, the row-major rungs on both grids and the sparse-only
+    ones on theirs; the tuner's own choice on the cell's mask and the
+    check's (rung None)."""
+    from magiattention_tpu.ops.flex_attn import _AUTO_BLOCK_CONFIGS
+    from magiattention_tpu.tuning.cost_model import SPARSE_ONLY_CONFIGS
+
+    cases = [
+        (f"tuner-{which}-{grid}", which, None, grid)
+        for which in ("mask", "check_mask") for grid in ("row_major", "sparse")
+    ]
+    cases += [
+        ("x".join(map(str, rung)) + "-" + grid, "mask", rung, grid)
+        for rung in _AUTO_BLOCK_CONFIGS for grid in ("row_major", "sparse")
+    ]
+    cases += [
+        ("x".join(map(str, rung)) + "-sparse-only", "mask", rung, "sparse")
+        for rung in SPARSE_ONLY_CONFIGS if rung not in _AUTO_BLOCK_CONFIGS
+    ]
+    return cases
+
+
+@pytest.mark.parametrize(
+    "which,rung,grid", [c[1:] for c in _every_rung()],
+    ids=[c[0] for c in _every_rung()],
+)
+def test_group_four_at_heads_of_64(topo, which, rung, grid):
+    """Forward and backward at 32 query / 8 key-value heads of 64
+    (granite-4.0-h-micro, ISSUE 55): the first plan whose q, k AND v are
+    64 wide (phi4's 64-wide q and k ride 128-wide kernel heads beside a
+    128-wide value pair), under the published softmax scale 1/64. Every
+    rung of the tuner's table compiles for a v5e at that width on both
+    grids, on the cell's own masks, so the model takes the width as it is:
+    no zero lanes, ``magi_flex_pad_lane_share`` is not set."""
+    from magiattention_tpu.ops.flex_attn import _auto_head_block
+    from magiattention_tpu.tuning.autotuner import resolve_block_config
+
+    hq, hk, d = 32, 8, 64
+    qr, kr, ts, t = _granite_mask(which)
+    if rung is None:
+        rung = resolve_block_config(
+            qr, kr, tuple(ts), t, t, 1, hq, hk, d, "bfloat16"
+        )
+    rung = (*rung[:2], _auto_head_block(rung[2], hq, hq // hk))
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def loss(q, k, v):
+        out, lse = flex_flash_attn_func(
+            q, k, v, qr, kr, ts, grid=grid, block_q=rung[0],
+            block_k=rung[1], head_block=rung[2], scale=0.015625,
+            interpret=False,
+        )
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)),
+        _on(chip, (t, hq, d)), _on(chip, (t, hk, d)), _on(chip, (t, hk, d)),
+    )
+    assert text.count("tpu_custom_call") == 2  # fwd, bwd
+
+
+@pytest.mark.parametrize("rows,dtype", [
+    (16384, jnp.bfloat16), (4096, jnp.bfloat16), (4096, jnp.float32),
+], ids=["cell", "check", "scan-alone-float32"])
+def test_ssd_scan_at_the_granite_cells_size(topo, rows, dtype):
+    """The state-space-dual scan's kernel pair (ISSUE 55) at 64 heads of
+    64 channels and 128 states in chunks of 256: the cell's 16,384 rows
+    and the check's 4,096 in the cell's dtypes, and the check's scan
+    alone on float32 operands (every product at the highest precision).
+    What interpret mode shows none of: two 64-wide heads sharing a
+    128-lane tile, a head's column of the [chunk, heads] vectors taken by
+    a masked lane sum, its row by a traced sublane index, the products
+    with a transposed left operand in the backward, the carried state of
+    all head blocks in VMEM scratch."""
+    from magiattention_tpu.ops.ssd_scan import ssd_scan
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    h, p, n = 64, 64, 128
+    f32 = jnp.float32
+    args = (
+        _on(chip, (rows, h, p), dtype), _on(chip, (rows, h), f32),
+        _on(chip, (h,), f32), _on(chip, (rows, n), dtype),
+        _on(chip, (rows, n), dtype), _on(chip, (h,), f32),
+        _on(chip, (rows,), jnp.bool_),
+    )
+
+    def loss(x, delta, a, b, c, d, start):
+        y = ssd_scan(x, delta, a, b, c, d, start, chunk=256, interpret=False)
+        return y.astype(f32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), *args)
+    for kernel in ("magi_ssd_scan_fwd_kernel", "magi_ssd_scan_bwd_kernel"):
+        assert kernel in text, kernel
+
+
 def _xing_cell():
     """(configuration, traffic) of ``xing4-train-8k-traces``."""
     import json

@@ -202,6 +202,34 @@ def _prerouted_step(mesh):
     )
 
 
+def _ssd_step(mesh):
+    """granite-4.0-h-micro's step as its cell runs it: the published
+    widths, its rank's share of the vocabulary, all 10 kept layers (nine
+    Mamba-2, one attention), remat, the cell's 16,384-token mask."""
+    from benchmarks import masks
+    from benchmarks.kinds.train_ssd import model_keys
+    from magiattention_tpu.models.pattern import (
+        build_magi_pattern, granitemoehybrid_config, init_pattern_params,
+    )
+
+    hf = _benchmark_json("configs", "granite-4.0-h-micro.json")
+    tr = _benchmark_json("traffic", "train-16k-packed-ssd.json")
+    cfg = granitemoehybrid_config(
+        model_keys(hf), dtype=tr["dtype"], remat=bool(tr["remat"]),
+        vocab_size=hf["vocab_here"],
+    )
+    total = int(tr["total_tokens"])
+    mask = masks.build_mask(tr["mask"], total, index=0)
+    model, _ = build_magi_pattern(
+        cfg, mesh, mask.cu_seqlens, chunk_size=int(tr["chunk_size"]),
+        interpret=False,
+    )
+    return (
+        model, (lambda: init_pattern_params(jax.random.PRNGKey(0), cfg)),
+        total,
+    )
+
+
 _STEPS = {"dense": _dense_step, "experts": _experts_step}
 
 
@@ -246,6 +274,40 @@ def test_the_prerouted_cells_step_fits_the_chip(topo):
         launched = sum(f"/{kernel}/pallas_call" in ln for ln in calls)
         assert launched == model.cfg.n_layers, (kernel, launched)
     assert "magi_moe_router" in text
+    print(f"arguments {mem.argument_size_in_bytes} temporaries "
+          f"{mem.temp_size_in_bytes}")
+
+
+def test_the_ssd_cells_step_fits_the_chip(topo):
+    """The granite-4.0-h-micro cell's whole AdamW step compiled for one
+    described v5e at its size (ISSUE 55): nine Mamba-2 layers on the
+    state-space-dual scan's kernel pair at 64 heads of 64 x 128 states,
+    one attention layer at 32 / 8 heads of 64 (the width as it is: no
+    padded lanes). Its arguments and temporaries fit the chip, no
+    weight's update is fused into its gradient's matmul, the attention
+    layer's forward kernel is in the program once (kept across remat), and
+    a Mamba-2 layer's scan runs forward twice (remat keeps nothing of it)
+    and backward once."""
+    import optax
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "cp"))
+    model, init, total = _ssd_step(mesh)
+    exe = _compile_train_step(model, init, optax.adamw(3e-4), total)
+    mem = exe.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.8e9, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    text = exe.as_text()
+    fused, under_scope = update_fusions(text)
+    assert fused == [] and under_scope > 0
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    scans = model.cfg.layer_types.count("state_space_dual")
+    for kernel, launches in (
+        ("magi_flex_fwd_kernel", 1), ("magi_flex_bwd_kernel", 1),
+        ("magi_ssd_scan_fwd_kernel", 2 * scans),
+        ("magi_ssd_scan_bwd_kernel", scans),
+    ):
+        launched = sum(f"/{kernel}/pallas_call" in ln for ln in calls)
+        assert launched == launches, (kernel, launched)
     print(f"arguments {mem.argument_size_in_bytes} temporaries "
           f"{mem.temp_size_in_bytes}")
 

@@ -2,7 +2,7 @@
 that a per-layer metric reads (``docs/observability.md``, "Device
 scopes"): the six toy models' train steps and the bare attention call
 (seven with the decoder-hybrid-decoder's, eight with the residual
-streams') are compiled here, on the CPU, their ``op_name``s read as the benchmark
+streams', nine with the Mamba-2 hybrid's) are compiled here, on the CPU, their ``op_name``s read as the benchmark
 reads them (``trace_reduce.hlo_scopes``), and held against the patterns
 of the metric files themselves, so a scope that is renamed, dropped or
 wrapped round another part fails here and not as a silent zero on the
@@ -34,6 +34,7 @@ from tests.test_models.test_pattern_latent import _glm
 from tests.test_models.test_pattern_looped import _ouro
 from tests.test_models.test_pattern_mhc import _xing
 from tests.test_models.test_pattern_sambay import _sambay
+from tests.test_models.test_pattern_ssd import _granite
 
 METRICS = os.path.join(
     os.path.dirname(__file__), "..", "..", "benchmarks", "metrics"
@@ -61,6 +62,7 @@ PARTS = {
         "cca_mix": "train_cca_mix_share",
         "ssm_scan": "train_ssm_scan_share",
         "ssm_mix": "train_ssm_mix_share",
+        "ssd_scan": "train_ssd_scan_share",
         "diff_combine": "train_diff_combine_share",
         "mhc": "train_mhc_share",
         "optimizer": "train_optimizer_share",
@@ -73,7 +75,7 @@ EXPERT_PARTS = {
 # parts newer than the remainder's pattern, which is the benchmark's and
 # would read them too: their cells are not on its list (PERF.md section 7)
 NEWER_THAN_THE_REMAINDER = {
-    "cca_mix", "ssm_scan", "ssm_mix", "diff_combine", "mhc",
+    "cca_mix", "ssm_scan", "ssm_mix", "diff_combine", "mhc", "ssd_scan",
 }
 REMAINDERS = {
     "step": _pattern("train_unscoped_share"),
@@ -126,6 +128,12 @@ CASES = {
         r"checkpoint/magi_ssm_scan", r"checkpoint/magi_ssm_mix",
         r"checkpoint/magi_gmu", r"checkpoint/magi_diff_combine",
     ],
+    "ssd": STEP + [
+        "magi_head", "magi_attn_full",
+        "magi_ssd_scan_fwd_kernel", "magi_ssd_scan_bwd_kernel",
+        # siblings of magi_proj, as the attention call is, not inside it
+        r"checkpoint/magi_ssd_scan", r"checkpoint/magi_ssm_mix",
+    ],
     "mhc": STEP + EXPERTS + [
         "magi_head", "magi_attn_full", "magi_mla_q", "magi_mtp",
         # siblings of magi_proj and magi_ffn, not inside them
@@ -165,7 +173,7 @@ def toy_model(name: str):
     cfg = {
         "afmoe": AFMOE, "latent+mtp": _glm(1)[1],
         "looped": _ouro()[1], "cca": _zaya()[1], "sambay": _sambay()[1],
-        "mhc": _xing()[1],
+        "mhc": _xing()[1], "ssd": _granite()[1],
     }[name]
     model, _ = build_magi_pattern(cfg, mesh, toy.CU, chunk_size=toy.CHUNK)
     return model, init_pattern_params(jax.random.PRNGKey(0), cfg)
@@ -279,6 +287,11 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
         "sambay": ["magi_moe_", "magi_mla_", "magi_mtp", "magi_exit_head",
                    "magi_cca_mix", "magi_proj/magi_ssm", "magi_proj/magi_gmu",
                    "magi_proj/magi_diff_combine", "magi_ssm_mix/magi_proj"],
+        "ssd": ["magi_moe_", "magi_mla_", "magi_mtp", "magi_exit_head",
+                "magi_cca_mix", "magi_attn_sliding", "magi_gmu",
+                "magi_ssm_scan", "magi_diff_combine", "magi_proj/magi_ssd",
+                "magi_proj/magi_ssm", "magi_ssm_mix/magi_proj",
+                "magi_ssm_mix/magi_ssd"],
     }.get(case, ["magi_proj", "magi_ffn", "magi_head", "magi_optimizer"])
     for scope in absent:
         assert not any(scope in s for s in lines), scope
@@ -288,6 +301,9 @@ def test_every_heavy_operation_lies_under_exactly_one_part(case):
         own = {
             "cca": {"cca_mix", "moe"}, "blockdiff": {"moe"},
             "sambay": {"ffn", "ssm_scan", "ssm_mix"},
+            # Mamba-2's mix holds no matmul (its B, C and step come out of
+            # the one input projection): nothing heavy under magi_ssm_mix
+            "ssd": {"ffn", "ssd_scan"},
             "mhc": {"ffn", "moe", "mhc"},
         }.get(case, {"ffn"})
         assert {"proj", "flex", "layout", "embed"} | own <= set(seen), seen

@@ -47,6 +47,9 @@ PARENT = {
     "smallthinker-train-16k-traces": (
         ["1024x1024x1", "128x512x7"], ["128x512x7", "128x512x7"],
     ),
+    # no parent of ISSUE 54 had it (ISSUE 55's cell): the rungs it was
+    # handed in with, 32 / 8 heads of 64 on five and on three documents
+    "granite4hmicro-train-packed-traces": (["128x512x8"], ["128x512x8"]),
 }
 # the two plans ISSUE 54 moves: 23.1% and 3.7% of the square, tied within
 # 15% (+8% and +13% over the cheapest), decided until now by the lead
