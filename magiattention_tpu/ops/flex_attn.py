@@ -2011,6 +2011,15 @@ _AUTO_BLOCK_CONFIGS: tuple[tuple[int, int, int], ...] = (
     # (PERF.md section 6, PR 30). The tuner prices those bytes
     # (tuning/cost_model.step_bytes, ISSUE 35) and passes an HBM-bound rung
     # over for one whose block_q clears the balance.
+    # What this order decides for the tuner: which of the rungs priced
+    # within TIE_TOLERANCE of the cheapest wins, EXCEPT between
+    # (128, 512, hb) and (256, 512, hb) at one head_block, which stand in
+    # the order of their prices where 256 is the cheaper
+    # (tuning/cost_model.PAIR_PRICE_MARGIN, ISSUE 56: a packed mask of a few
+    # long documents or a band takes 256, one of many short documents keeps
+    # 128), and below the long-sequence lead for a mask at a quarter of its
+    # square and more. The static table (MAGI_ATTENTION_AUTOTUNE=off) still
+    # reads it top down.
     (128, 512, 8),
     # block_q 256 at eight heads a step, for GQA groups under 8 (at group 8
     # the next rung is this one already: head_block snaps to the group). On

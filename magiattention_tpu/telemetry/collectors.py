@@ -1206,7 +1206,8 @@ def record_autotune_decision(decision) -> None:
             "bound": decision.bound,
             "rejected_bytes": decision.rejected_bytes,
             # the preference order the ranking broke its tie by: how
-            # often the long-sequence lead was used (ISSUE 54)
+            # often the long-sequence lead was used (ISSUE 54), and how
+            # often the priced pair put block_q 256 first (ISSUE 56)
             "tie_order": decision.tie_order,
             # the form of the backward the rung's kernels run: one value
             # since PR 43, kept so that a later form reads against it

@@ -67,7 +67,9 @@ class TuningDecision:
     # which measured preference order the ranking broke its tie by
     # (``cost_model._preference_order``): ``long_seq``, the 64k dense
     # slice's big-tile lead, or ``measured``, the table's own order (every
-    # mask under 16,384 rows or under ``SPARSE_DENSITY_THRESHOLD``)
+    # mask under 16,384 rows or under ``SPARSE_DENSITY_THRESHOLD``); or
+    # ``priced_pair``: the chosen rung led its smaller ``block_q`` in the
+    # tie pool by its own price (``cost_model.PAIR_PRICE_MARGIN``)
     tie_order: str = ""
 
     @property

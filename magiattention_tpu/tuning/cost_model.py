@@ -218,6 +218,27 @@ SPARSE_DENSITY_THRESHOLD = 0.25
 # letting coarse-tile sparse candidates (fewest grid steps) through.
 SPARSE_TIE_TOLERANCE = 0.30
 
+# -- the priced pair (ISSUE 56) ----------------------------------------------
+# Inside the tie pool two head-batched row-major rungs that differ in
+# ``block_q`` alone, (128, 512, hb) and (256, 512, hb) today, are ordered by
+# their own price: the larger ``block_q`` leads where it is cheaper by this
+# share, else the table's order stands. The price may be trusted for this
+# pair and not for the pool at large because the two differ in exactly the
+# terms it counts from the mask itself, the computed tiles and the steps.
+# Set from the chip (v5e; my chip run, PR 56, PERF.md section 6), the pair
+# pinned by ``MAGI_ATTENTION_BLOCK_Q`` / ``_BLOCK_K`` / ``_HEAD_BLOCK``
+# against the tuner's (128, 512, 8), 256's price over 128's in brackets: the
+# packed 64k cell [-5.3%] forward 67.66 -> 62.18 ms, forward+backward 199.78
+# -> 189.06; the window-1024 cell [-5.0%] 35.93 -> 33.11 and 98.02 -> 92.20;
+# and the two masks at the break-even: Mistral's 17 documents [+1.1%, 8.1%
+# more tile area] 32,012.1 -> 32,020.2 tokens/s, every unit of six steps
+# faster than the other side's fastest, and Trinity's 29 documents with both
+# plans pinned [-0.2% global, +2.6% sliding] 33,455.7 -> 33,479.5, inside its
+# units' spread. The chip sided with 256 even where the price calls a dead
+# heat, so no margin holds 256 back: 0, and the pair stands in the order of
+# its prices.
+PAIR_PRICE_MARGIN = 0.0
+
 
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
@@ -249,7 +270,9 @@ class CandidateScore:
     # the longer, forward-sized (0.0 wherever the bytes are slack)
     hbm_excess_seconds: float = 0.0
     # the order the ranking this rung came from breaks a tie by
-    # (:func:`_preference_order`): ``long_seq`` | ``measured`` | ``given``
+    # (:func:`_preference_order`): ``long_seq`` | ``measured`` | ``given``;
+    # ``priced_pair`` on a rung its own price put ahead of its smaller
+    # ``block_q`` in the tie pool (:func:`_lead_pairs_by_price`)
     tie_order: str = ""
 
     @property
@@ -539,7 +562,10 @@ def _preference_order(extent: int, density: float):
     error bar). The long-sequence lead was measured on one dense causal
     slice at 64k; a mask under :data:`SPARSE_DENSITY_THRESHOLD` is not the
     mask it was measured on and gets the table's own order whatever its
-    extent."""
+    extent. What the table's order still decides: every tie but the one
+    between two head-batched rungs that differ in ``block_q`` alone, which
+    the price breaks where it prefers the larger by
+    :data:`PAIR_PRICE_MARGIN` (:func:`_lead_pairs_by_price`, ISSUE 56)."""
     from ..ops.flex_attn import (
         _AUTO_BLOCK_CONFIGS,
         _LONG_SEQ_BLOCK_THRESHOLD,
@@ -555,6 +581,36 @@ def _preference_order(extent: int, density: float):
         )
         return "long_seq", _LONG_SEQ_CONFIGS + rest
     return "measured", _AUTO_BLOCK_CONFIGS
+
+
+def _lead_pairs_by_price(tied: list) -> list:
+    """The tie pool with the priced pair applied (:data:`PAIR_PRICE_MARGIN`):
+    a head-batched row-major rung moves just ahead of the earliest rung
+    before it that shares its ``block_k`` and snapped ``head_block`` and has
+    a smaller ``block_q``, where its own ``cost_seconds`` is lower by the
+    margin; it then carries ``tie_order`` ``priced_pair``. Every other rung
+    keeps its place."""
+
+    def leads(big, small) -> bool:
+        return (
+            small.grid == big.grid == "row_major"
+            and big.head_block > 1
+            and small.block_k == big.block_k
+            and small.head_block == big.head_block
+            and small.block_q < big.block_q
+            and big.cost_seconds
+            < small.cost_seconds * (1.0 - PAIR_PRICE_MARGIN)
+        )
+
+    out = list(tied)
+    for big in tied:
+        at = out.index(big)
+        ahead = next((i for i in range(at) if leads(big, out[i])), None)
+        if ahead is not None:
+            out.insert(
+                ahead, dataclasses.replace(out.pop(at), tie_order="priced_pair")
+            )
+    return out
 
 
 def rank_candidates(
@@ -600,7 +656,14 @@ def rank_candidates(
     (:func:`_preference_order`; which one, on :attr:`CandidateScore.tie_order`)
     — so dense workloads keep the on-chip-measured winners while
     shape-sensitive workloads (narrow varlen blocks, SWA bands, a few long
-    packed documents) escape to occupancy-correct rungs. A rung
+    packed documents) escape to occupancy-correct rungs. One tie in the
+    pool is the price's own: two head-batched row-major rungs that share
+    ``block_k`` and the snapped ``head_block`` and differ in ``block_q``
+    alone ((128, 512, hb) and (256, 512, hb)) stand in the order of their
+    prices where the larger ``block_q`` is cheaper by
+    :data:`PAIR_PRICE_MARGIN`, and that rung's ``tie_order`` reads
+    ``priced_pair`` (a mask of many short documents keeps 128, one of a
+    few long documents or a band takes 256). A rung
     whose own price says its steps run at the HBM's pace
     (:attr:`CandidateScore.bound`) is no tie with one that does not: the
     preference order was measured where no rung streams, and is not asked.
@@ -741,6 +804,9 @@ def rank_candidates(
         # does not: the preference order starts at (128, 512, 8) and was
         # measured at GQA group 8, where nothing streams
         tied = [s for s in tied if s.bound == "mxu"]
+    rest = sorted(
+        (s for s in scores if s not in tied), key=lambda s: s.cost_seconds
+    )
     if hetero and any(s.grid == "sparse" for s in tied):
         # heterogeneous regime: inside the model's error bar, minimize
         # grid steps on the sparse grid — the measured 8.44 TF/s
@@ -750,11 +816,12 @@ def rank_candidates(
             tied,
             key=lambda s: (s.grid != "sparse", s.grid_slots, s.cost_seconds),
         )
-    rest = sorted(
-        (s for s in scores if s not in tied), key=lambda s: s.cost_seconds
-    )
+    elif tie_order != "given":
+        # the one tie the price breaks itself: the pair that differs in
+        # block_q alone (ISSUE 56)
+        tied = _lead_pairs_by_price(tied)
     # tied candidates keep the measured preference order they were
-    # generated in (dense regime) or the sparse slot-minimizing order
-    # (heterogeneous regime); clear winners sort ahead of the tie-pool's
-    # losers
+    # generated in, but for the priced pair (dense regime), or the sparse
+    # slot-minimizing order (heterogeneous regime); clear winners sort
+    # ahead of the tie-pool's losers
     return tied + rest

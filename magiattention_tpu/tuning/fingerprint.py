@@ -85,7 +85,10 @@ class WorkloadFingerprint:
     # the table's own order at any extent; a (1024, 1024, 1) cached for a
     # packed mask of a few long documents by the long-sequence lead is not
     # served
-    FINGERPRINT_VERSION = 6
+    # v7 (ISSUE 56): the tie between (128, 512, hb) and (256, 512, hb) is
+    # broken by their price; a (128, 512, hb) cached by the table's order
+    # for a mask whose 256 rung is the cheaper is not served
+    FINGERPRINT_VERSION = 7
 
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)

@@ -134,13 +134,18 @@ def test_zero_filled_dq_where_the_table_leaves_q_blocks_out(
         (65536, 64, 8, (128, 512, 8)),
         (16384, 32, 8, (128, 512, 8)),
         (16384, 64, 8, (256, 1024, 8)),
+        (65536, 64, 8, (256, 512, 8)),
+        (16384, 32, 8, (256, 512, 8)),
     ],
-    ids=["varlen-cell-64x8", "train-cell-32x8", "largest-tuner-step"],
+    ids=["varlen-cell-64x8", "train-cell-32x8", "largest-tuner-step",
+         "varlen-cell-64x8-at-256", "train-cell-32x8-at-256"],
 )
 def test_head_batched_bwd_at_the_cells_shapes(topo, t, hq, hk, rung, grid):
     """The head-batched forward and backward, on the row-major and on the
     compact grid, at the blocking the tuner gives the benchmark's packed
-    cells, (128, 512, 8) at head_dim 128: group 8 is one kv head a step,
+    cells, (128, 512, 8) at head_dim 128 and, where the pair's price puts
+    it ahead (ISSUE 56: the packed 64k cell, six training cells),
+    (256, 512, 8): group 8 is one kv head a step,
     group 4 two (the batched transposed contraction). And at the largest
     step a row-major rung of the tuner asks for: (256, 1024, 2), whose
     head_block snaps to 8 at group 8. They fit the VMEM the kernels ask

@@ -415,7 +415,8 @@ def test_cca_train_step_at_two_key_value_heads(topo):
         rungs.append((p.block_q, p.block_k, p.head_block, p.grid))
         if total == 4096:
             check = model
-    assert rungs[0] == rungs[1] == (128, 512, 8, "sparse")
+    # (128, 512, 8) until ISSUE 56: 256 is the cheaper of the pair on both
+    assert rungs[0] == rungs[1] == (256, 512, 8, "sparse")
     assert check.shift_plan.fwd.offsets == (1, 2)
     assert check.shift_plan.bwd.offsets == (-1, -2)
     text = _compile_train_step(

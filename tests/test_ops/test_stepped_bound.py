@@ -428,13 +428,43 @@ KERNELS = GOLDENS.pop("_kernels")
 # plans whose rung the tuner has moved since the goldens were written: what
 # it gives now, and the tables at that rung. The planner is still held to
 # the golden, at the golden's own rung. ISSUE 54: the cp=4 packed mask (3.7%
-# of its square) no longer gets the long-sequence lead of the tie order
+# of its square) no longer gets the long-sequence lead of the tie order.
+# ISSUE 56: four plans that sat on (128, 512, 8) take (256, 512, 8), the
+# cheaper of the pair by its own price
 MOVED = {
     "magi64x8-attn-cp4-256k-varlen/full": {
         "rung": [256, 512, 8],
         "entries": [20656, 20656],
         "tables": (
             "1b99fb4a94e224ea076d79457e6d660a516f7f6f5b62f77eba2acf99101c6e55"
+        ),
+    },
+    "magi64x8-attn-64k-varlen/full": {
+        "rung": [256, 512, 8],
+        "entries": [1872, 1872],
+        "tables": (
+            "09f670da18c5ab6defa7c983187928393a2ab41ed2e98d9aecb697a8f1fb6e38"
+        ),
+    },
+    "magi64x8-attn-64k-swa1024/full": {
+        "rung": [256, 512, 8],
+        "entries": [768, 768],
+        "tables": (
+            "c2d85951ffb9a760740e48a348d93dd1bed56f75bab785ad8538d0af3cd9fa30"
+        ),
+    },
+    "trinitymini-train-32k-packed/full": {
+        "rung": [256, 512, 8],
+        "entries": [608, 608],
+        "tables": (
+            "24712b4cd25a6fb9e46d2d36fa778cad695fbc69274e74ce276623da225a796d"
+        ),
+    },
+    "zaya1-train-16k-traces/full": {
+        "rung": [256, 512, 8],
+        "entries": [384, 384],
+        "tables": (
+            "9e9872cfbdc6e25c2296912116dfc67cb475bce8902d2503b4613a398211abe3"
         ),
     },
 }

@@ -33,14 +33,16 @@ def test_large_block_escalation_config():
 def test_auto_block_config_prefers_large_blocks_at_long_seq():
     """>= 16k tokens: the (1024, 1024) square rung is preferred (round-5
     chained on-chip winner for fwd AND fwd+bwd at 64k causal on the
-    row-major grid); below 16k the low-latency (128, 512) rung stays
-    first wherever its steps do not stream K and V at the HBM's pace (at
-    GQA group 1 they do, and the next rung is given: ISSUE 35); oversized
-    masks still escalate to (512, 2048)."""
+    row-major grid); below 16k the low-latency (., 512) rungs stay first:
+    of the pair (128, 512, 8) and (256, 512, 8) the cheaper by its own
+    price (ISSUE 56: a dense mask's tiles are the same area in half the
+    steps at 256), and at GQA group 1, where 128's steps stream K and V at
+    the HBM's pace, 256 by the bytes (ISSUE 35); oversized masks still
+    escalate to (512, 2048)."""
     from magiattention_tpu.ops.flex_attn import auto_block_config
 
-    # short dense causal -> small rung
-    assert auto_block_config([(0, 8192)], [(0, 8192)], 64, 8) == (128, 512, 8)
+    # short dense -> small rung
+    assert auto_block_config([(0, 8192)], [(0, 8192)], 64, 8) == (256, 512, 8)
     assert auto_block_config([(0, 8192)], [(0, 8192)], 8, 8) == (256, 512, 8)
     # long dense causal -> measured winner
     assert auto_block_config([(0, 32768)], [(0, 32768)], 8, 8)[:2] == (

@@ -19,9 +19,14 @@ from .test_grid_choice import ROOT, _build_cell, _decisions, telemetry_on  # noq
 # (rung, GQA group, head_dim) -> bytes a live step moves, and FLOPs a byte
 RUNGS = {
     "dense 64k, chunk-causal": ((1024, 1024, 1), 8, 128, 2_621_440, 512),
-    "cp4 packed": ((256, 512, 8), 8, 128, 5_242_880, 256),  # since ISSUE 54
-    "packed 64k, window, Trinity, SDAR": ((128, 512, 8), 8, 128, 2_621_440, 256),
-    "Mistral, ZAYA": ((128, 512, 8), 4, 128, 2_621_440, 256),
+    # cp4 packed since ISSUE 54; the others since ISSUE 56 (the pair's price)
+    "cp4 packed, packed 64k, window, Trinity global, SDAR": (
+        (256, 512, 8), 8, 128, 5_242_880, 256,
+    ),
+    "Trinity sliding": ((128, 512, 8), 8, 128, 2_621_440, 256),
+    "Mistral": ((128, 512, 8), 4, 128, 2_621_440, 256),
+    "ZAYA": ((256, 512, 8), 4, 128, 5_242_880, 256),  # since ISSUE 56
+    "SmallThinker": ((256, 512, 7), 7, 128, 4_587_520, 256),  # since ISSUE 56
     "Ouro": ((256, 512, 8), 1, 128, 5_242_880, 256),
     "GLM": ((256, 512, 5), 1, 256, 5_242_880, 320),
     "cp4 dense": ((512, 2048, 1), 8, 128, 1_310_720, 1024),
@@ -56,12 +61,14 @@ def test_the_ranking_prices_what_it_was_calibrated_on():
 # pads and dummies with them, over the q blocks they name): each moves the
 # tile's float32 sums once; the first reads nothing, the last writes dq
 DQ_VISITS = {
-    "magi64x8-attn-64k-varlen": [7.109],
+    # ISSUE 56 moved seven cells' plans to block_q 256 by the pair's price:
+    # a q tile of twice the rows meets the k blocks of both halves
+    "magi64x8-attn-64k-varlen": [7.3125],  # 7.109 at (128, 512, 8)
     "magi64x8-attn-64k-causal": [32.625],
     "mistral7b-train-16k-onemask": [3.125],
     "magi64x8-attn-cp4-256k-varlen": [21.375],  # (256, 512, 8) since ISSUE 54
-    "trinitymini-train-32k-packed": [4.406, 3.344],
-    "magi64x8-attn-64k-swa1024": [2.984],
+    "trinitymini-train-32k-packed": [4.75, 3.344],  # the global plan: 4.406
+    "magi64x8-attn-64k-swa1024": [3.0],  # 2.984
     "glm47flash-train-16k-packed": [3.375],
     "magi64x8-attn-64k-chunkcausal": [34.125],
     "magi64x8-attn-cp4-256k-causal": [64.625],
@@ -69,15 +76,17 @@ DQ_VISITS = {
     "zaya1-train-16k-traces": [6.0],
     "sdar30b-train-16k-blockdiff": [5.875],
     # the full plan (ZAYA's mask), then the window of 512: one block_k
-    "phi4flash-train-16k-traces": [6.0, 1.9375],
+    "phi4flash-train-16k-traces": [6.0, 2.0],  # the window: 1.9375 at 128
     "xing4-train-8k-traces": [3.5],  # ZAYA's mask halved, at block_q 256
     # four long documents: the full plan, then the window of 4,096, both at
-    # (128, 512), 7 heads a step (ISSUE 53; the full plan ran per head at
+    # (256, 512), 7 heads a step (ISSUE 53; the full plan ran per head at
     # (1024, 1024) and 8.5 visits until ISSUE 54 took the long-sequence lead
-    # off masks under the density line)
-    "smallthinker-train-16k-traces": [7.9375, 5.9375],
-    # five documents off the block grid, 32 / 8 heads of 64 (ISSUE 55)
-    "granite4hmicro-train-packed-traces": [6.4375],
+    # off masks under the density line, then both at (128, 512, 7) and
+    # 7.9375 / 5.9375 until ISSUE 56)
+    "smallthinker-train-16k-traces": [8.0, 6.0],
+    # five documents off the block grid, 32 / 8 heads of 64 (ISSUE 55);
+    # 6.4375 at (128, 512, 8)
+    "granite4hmicro-train-packed-traces": [6.625],
 }
 
 

@@ -343,9 +343,14 @@ def test_a_band_mask_gets_a_rung_that_fits_the_band():
         include_sparse=False,
     )
     assert all(s.feasible and s.smem_count == "exact" for s in ranked)
-    best = ranked[0]
-    assert (best.block_q, best.block_k, best.head_block) == (128, 512, 8)
-    assert (best.entries, best.smem_entries) == (1524, 1528)  # padded to 8
+    # ISSUE 56: of the two small rungs the cheaper, (256, 512, 8), ahead of
+    # the table's (128, 512, 8) of 1,524 entries
+    best, second = ranked[:2]
+    assert (best.block_q, best.block_k, best.head_block) == (256, 512, 8)
+    assert (best.entries, best.smem_entries) == (762, 768)  # padded to 8
+    assert (second.block_q, second.entries, second.smem_entries) == (
+        128, 1524, 1528,
+    )
     # the prices did not move: the small rungs were 21-25% cheaper all along
     cost = {(s.block_q, s.block_k): s.cost_seconds * 1e3 for s in ranked}
     assert cost[128, 512] == pytest.approx(36.89, abs=0.01)
@@ -361,7 +366,8 @@ def test_a_band_mask_gets_a_rung_that_fits_the_band():
     # what was left tied on (1024, 1024, 1) by the long-sequence lead; since
     # ISSUE 54 a mask at 3% of the square does not get the lead either way
     assert (old[0].block_q, old[0].block_k) == (256, 1024)
-    assert {s.tie_order for s in ranked + old} == {"measured"}
+    assert {s.tie_order for s in ranked[1:] + old} == {"measured"}
+    assert best.tie_order == "priced_pair"
 
 
 def test_a_table_that_really_passes_the_budget_stays_infeasible():
@@ -631,7 +637,8 @@ def test_a_few_long_documents_get_the_checks_rung_not_the_dense_cells():
     16,384 on. The lead was measured on a dense 64k slice; under the
     ranker's own density line the table's order breaks the tie, and the
     timed full plan walks the rung the check's (8,192 rows: no lead at any
-    density) always did."""
+    density) does: (128, 512, 7) by the table until ISSUE 56, (256, 512, 7)
+    since, the cheaper of the pair on both masks."""
     from magiattention_tpu.tuning.cost_model import SPARSE_DENSITY_THRESHOLD
 
     timed = _lengths_mask((10240, 4096, 1536, 512))
@@ -644,8 +651,9 @@ def test_a_few_long_documents_get_the_checks_rung_not_the_dense_cells():
         )
         for m in (timed, check)
     }
-    assert _first(ranked[16384]) == _first(ranked[8192]) == (128, 512, 7)
-    assert {s.tie_order for r in ranked.values() for s in r} == {"measured"}
+    assert _first(ranked[16384]) == _first(ranked[8192]) == (256, 512, 7)
+    assert {s.tie_order for r in ranked.values() for s in r[1:]} == {"measured"}
+    assert {r[0].tie_order for r in ranked.values()} == {"priced_pair"}
     # the price did not move: the per-head rung is in the tie as it was
     cost = {(s.block_q, s.block_k): s.cost_seconds for s in ranked[16384]}
     assert 1.07 < cost[1024, 1024] / min(cost.values()) < 1.09
@@ -752,6 +760,118 @@ def test_the_lead_decides_only_a_plan_it_put_on_a_long_sequence_rung(
         if (led.block_q, led.block_k) not in {c[:2] for c in _LONG_SEQ_CONFIGS}:
             assert key(given["measured"][0]) == key(led)
         own = rank_candidates(qr, kr, ts, hq, hk, **args)
-        assert {s.tie_order for s in own} == {want}
-        assert [key(s) for s in own] == [key(s) for s in given[want]]
+        # but for the rung its own price put ahead of its smaller block_q
+        # (ISSUE 56), which never is a long-sequence rung
+        led = [key(s) for s in own if s.tie_order == "priced_pair"]
+        assert {s.tie_order for s in own} - {"priced_pair"} == {want}
+        assert not {k[:2] for k in led} & {c[:2] for c in _LONG_SEQ_CONFIGS}
+        assert [key(s) for s in own if key(s) not in led] == [
+            key(s) for s in given[want] if key(s) not in led
+        ]
         assert {s.tie_order for s in given[want]} == {"given"}
+
+
+# -- the priced pair (ISSUE 56) ----------------------------------------------
+def _pair(ranked, hb):
+    """(the 128 rung, the 256 rung) of one ranking at one head_block."""
+    by = {(s.block_q, s.block_k, s.head_block, s.grid): s for s in ranked}
+    return by[128, 512, hb, "row_major"], by[256, 512, hb, "row_major"]
+
+
+# mask -> (slices, heads, the winner, its tie_order, 256's price over 128's)
+_PAIR_CASES = {
+    # Mistral's 17 documents in 16,384 rows: 256 computes 8.1% more tile
+    # area for half the steps, and the price says +1.1%: the table's order
+    "many_short_documents": (
+        lambda: _bench_mask("packed16k")[:3], (32, 8),
+        (128, 512, 8), "measured", (1.005, 1.02),
+    ),
+    # SmallThinker's four documents: the same tiles to 0.1%, half the steps
+    "a_few_long_documents": (
+        lambda: _of(_lengths_mask((10240, 4096, 1536, 512)))[:3], (28, 4),
+        (256, 512, 7), "priced_pair", (0.92, 0.94),
+    ),
+    # the packed 64k cell's 49 documents: 2.6% more tile area, half the steps
+    "the_packed_cell": (
+        lambda: _bench_mask("packed")[:3], (64, 8),
+        (256, 512, 8), "priced_pair", (0.94, 0.95),
+    ),
+    # a band of 1,024 keys in 65,536 rows: 762 tiles of 256 for 1,524 of 128
+    "a_band": (
+        lambda: _bench_mask("swa1024")[:3], (64, 8),
+        (256, 512, 8), "priced_pair", (0.94, 0.96),
+    ),
+    # GQA group 1: (128, 512, 8) streams K and V at the HBM's pace and is no
+    # tie with a rung that does not, so the pair never meets in the pool and
+    # the bytes' price decides as it did (ISSUE 35)
+    "gqa_group_1": (
+        lambda: _bench_mask("packed16k")[:3], (16, 16),
+        (256, 512, 8), "measured", (0.80, 0.90),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAIR_CASES))
+def test_the_pair_that_differs_in_block_q_alone_stands_in_the_order_of_its_prices(
+    case,
+):
+    """Inside the tie pool (128, 512, hb) and (256, 512, hb) are ordered by
+    their own price where 256 is the cheaper by ``PAIR_PRICE_MARGIN``; every
+    other rung keeps the place the preference order gave it."""
+    from magiattention_tpu.tuning.cost_model import PAIR_PRICE_MARGIN
+
+    slices, (hq, hk), want, order, (lo, hi) = _PAIR_CASES[case]
+    qr, kr, ts = slices()
+    args = dict(include_sparse=False, generation="v5e")
+    ranked = rank_candidates(qr, kr, ts, hq, hk, **args)
+    small, big = _pair(ranked, want[2])
+    assert lo < big.cost_seconds / small.cost_seconds < hi
+    assert (_first(ranked), ranked[0].tie_order) == (want, order)
+    key = lambda s: (s.block_q, s.block_k, s.head_block)  # noqa: E731
+    table = rank_candidates(
+        qr, kr, ts, hq, hk, rungs=_AUTO_BLOCK_CONFIGS, **args
+    )
+    led = [key(s) for s in ranked if s.tie_order == "priced_pair"]
+    if order == "priced_pair":
+        assert led == [want]
+        assert big.cost_seconds < small.cost_seconds * (1 - PAIR_PRICE_MARGIN)
+        # 256 stands just ahead of 128, and nothing else moved
+        at = [key(s) for s in ranked].index(want)
+        assert key(ranked[at + 1]) == (128, 512, want[2])
+    else:
+        assert led == []
+    assert [key(s) for s in ranked if key(s) not in led] == [
+        key(s) for s in table if key(s) not in led
+    ]
+    # a ranking over rungs the caller gave keeps the order given
+    assert {s.tie_order for s in table} == {"given"}
+
+
+@pytest.mark.parametrize("margin,want", [(0.06, 256), (0.07, 128)])
+def test_the_margins_edge(monkeypatch, margin, want):
+    """SmallThinker's four documents price 256 at 6.8% under 128: a margin
+    of 6% lets it lead, one of 7% leaves the table's order."""
+    from magiattention_tpu.tuning import cost_model
+
+    monkeypatch.setattr(cost_model, "PAIR_PRICE_MARGIN", margin)
+    m = _lengths_mask((10240, 4096, 1536, 512))
+    ranked = rank_candidates(
+        m.q_ranges, m.k_ranges, m.types, 28, 4, include_sparse=False,
+        generation="v5e",
+    )
+    small, big = _pair(ranked, 7)
+    assert 0.931 < big.cost_seconds / small.cost_seconds < 0.933
+    assert _first(ranked) == (want, 512, 7)
+    assert ranked[0].tie_order == (
+        "priced_pair" if want == 256 else "measured"
+    )
+
+
+def test_the_pair_rule_leaves_the_fewest_steps_order_alone():
+    """Where sparse rungs are ranked and the mask is under the density line
+    the tie goes to the compact grid with the fewest slots, as it did: the
+    priced pair is a row-major rule and does not reorder that pool."""
+    qr, kr, ts, _ = _bench_mask("packed16k")
+    ranked = rank_candidates(qr, kr, ts, 32, 8, generation="v5e")
+    assert ranked[0].grid == "sparse"
+    assert "priced_pair" not in {s.tie_order for s in ranked}

@@ -148,7 +148,7 @@ def test_fingerprint_v3_carries_sparse_rung_axes():
     with it the sparse-vs-row-major ranking) differs must not share a
     cached winner even when their aggregate statistics alias."""
     fp = make_fingerprint([(0, 4096)], [(0, 4096)], [1], 8, 8)
-    assert fp.version == WorkloadFingerprint.FINGERPRINT_VERSION == 6
+    assert fp.version == WorkloadFingerprint.FINGERPRINT_VERSION == 7
     assert fp.step_est and fp.sparse_entry_est
     # one uniform 4k doc vs 4 skewed docs with the same total: the
     # coarse aggregates may bucket together, the steps extent must not

@@ -91,9 +91,11 @@ def _build_cell(name: str):
 # build_block_meta: 512 x 34 and 128 x 132 for the packed cell, 3,640
 # entries. The plan has one k block more (the merged KV buffer ends in the
 # group cast's receive pad: 129 x 132) and 5 of the 3,640 are padding.
+# ISSUE 56: the pair's price puts (256, 512, 8) ahead: 256 q blocks of at
+# most 34 entries, 129 k blocks of at most 67, 1,865 entries padded to 1,872
 CELLS = {
     "magi64x8-attn-64k-varlen": [
-        ((128, 512, 8), "sparse", 2 * 512 * 34 + 129 * 132, 3 * 3640, 3 * 3635),
+        ((256, 512, 8), "sparse", 2 * 256 * 34 + 129 * 67, 3 * 1872, 3 * 1865),
     ],
     "magi64x8-attn-64k-causal": [
         ((1024, 1024, 1), "sparse", 2 * 64 * 64 + 65 * 64, 6248, 3 * 2080),
@@ -108,8 +110,11 @@ CELLS = {
     "magi64x8-attn-cp4-256k-varlen": [
         ((256, 512, 8), "sparse", 91556, 16416, 15894.75),
     ],
+    # ISSUE 56: the global plan's 256 rung is the cheaper by 0.2% (602
+    # entries for 1,121) and leads; the sliding plan's is 2.6% dearer and
+    # the table's order stands
     "trinitymini-train-32k-packed": [
-        ((128, 512, 8), "sparse", 2 * 256 * 16 + 65 * 62, 3 * 1128, 3363),
+        ((256, 512, 8), "sparse", 2 * 128 * 16 + 65 * 33, 3 * 608, 3 * 602),
         ((128, 512, 8), "sparse", 6680, 2568, 2562),
     ],
     # ISSUE 33: the SMEM test counts the band's own table, so the band gets
@@ -119,8 +124,10 @@ CELLS = {
     # q block at most and 12 a k block; the plan's 129th k block adds a
     # dummy, and both tables pad to 1,528. 36 of 4,620 row-major steps are
     # dead: under the flip margin
+    # ISSUE 56: (256, 512, 8) by the pair's price: 762 entries, 3 a q block
+    # and 6 a k block at most, padded to 768; 24 of 2,310 steps dead
     "magi64x8-attn-64k-swa1024": [
-        ((128, 512, 8), "row_major", 2 * 512 * 3 + 129 * 12, 3 * 1528, 3 * 1524),
+        ((256, 512, 8), "row_major", 2 * 256 * 3 + 129 * 6, 3 * 768, 3 * 762),
     ],
     # the Mistral cell's mask at 20 query = 20 key-value heads of width
     # 256 (ISSUE 30). ISSUE 35: at GQA group 1 a step of (128, 512, 5)
@@ -150,8 +157,11 @@ CELLS = {
     # GQA group 4, Mistral's, so (128, 512, 8) and the whole head group a
     # step; the 8,192-token document's last q block meets 16 k blocks and
     # its first k block 64 q blocks; 764 entries padded to 768
+    # ISSUE 56: (256, 512, 8) by the pair's price, half the entries on the
+    # same tile area (every document a multiple of 256 rows): 382, padded to
+    # 384, its first k block meeting 32 q blocks
     "zaya1-train-16k-traces": [
-        ((128, 512, 8), "sparse", 2 * 128 * 16 + 33 * 64, 3 * 768, 3 * 764),
+        ((256, 512, 8), "sparse", 2 * 64 * 16 + 33 * 32, 3 * 384, 3 * 382),
     ],
     # ZAYA's mask halved at 32 query = 32 key-value heads, keys of 192
     # beside values of 128 (ISSUE 49): group 1, the GLM and
@@ -213,12 +223,12 @@ def test_every_cells_decision_says_which_roof_binds_its_rung(
     from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
 
     monkeypatch.delenv("MAGI_ATTENTION_GRID", raising=False)
-    def counted_mxu():  # the counter's series, one a tie order (ISSUE 54)
+    def counted_mxu():  # the counter's series, one a tie order (ISSUE 54, 56)
         return sum(
             telemetry.get_registry().counter_value(
                 "magi_autotune_decisions_total", bound="mxu", tie_order=o
             )
-            for o in ("long_seq", "measured")
+            for o in ("long_seq", "measured", "priced_pair")
         )
 
     counted = counted_mxu()
@@ -288,7 +298,7 @@ def test_a_version_3_record_for_a_band_mask_is_not_served(
     fingerprint does not match. Either way the mask is ranked anew."""
     from benchmarks import masks
 
-    assert WorkloadFingerprint.FINGERPRINT_VERSION == 6
+    assert WorkloadFingerprint.FINGERPRINT_VERSION == 7
     monkeypatch.setenv("MAGI_ATTENTION_AUTOTUNE_CACHE_DIR", str(tmp_path))
     monkeypatch.delenv("MAGI_ATTENTION_AUTOTUNE", raising=False)
     total = 65536
@@ -313,16 +323,19 @@ def test_a_version_3_record_for_a_band_mask_is_not_served(
             )
 
     seen = len(telemetry.get_event_buffer().events())
-    assert resolve() == (128, 512, 8)
-    assert resolve() == (128, 512, 8)  # from the cache now: the same record
+    # a small rung again, since ISSUE 56 the pair's cheaper one
+    assert resolve() == (256, 512, 8)
+    assert resolve() == (256, 512, 8)  # from the cache now: the same record
     events = telemetry.get_event_buffer().events()[seen:]
     # the decision's record, the ``tile_choice`` span's child, says what the
-    # SMEM test read, on a miss and on a hit: 1,524 tiles, padded to 8
+    # SMEM test read, on a miss and on a hit: 762 tiles, padded to 8, and
+    # which order broke the tie
     got = [ev["args"] for ev in events if ev["name"] == "autotune_decision"]
     assert len(got) == 2
     for args in got:
-        assert (args["smem_entries"], args["smem_count"]) == (1528, "exact")
+        assert (args["smem_entries"], args["smem_count"]) == (768, "exact")
         assert args["rejected_smem"] == 0
+        assert args["tie_order"] == "priced_pair"
     assert [
         ev["args"]["cache_layer"]
         for ev in events
@@ -330,12 +343,13 @@ def test_a_version_3_record_for_a_band_mask_is_not_served(
     ] == ["none", "memory"]
     # and the record that replaced the planted one is a version-4 one
     rec = get_tuning_cache()._load_disk(fp.stable_hash(), fp)
-    assert (rec.block_q, rec.block_k, rec.head_block) == (128, 512, 8)
+    assert (rec.block_q, rec.block_k, rec.head_block) == (256, 512, 8)
 
 
 def test_the_packed_cells_dead_share_on_both_grids(telemetry_on, monkeypatch):
-    """79% of the row-major grid's steps do nothing in the packed cell,
-    and what is left on the compact one is the 5 padding entries a table;
+    """79% of the row-major grid's steps do nothing in the packed cell
+    (78.97% at (128, 512, 8), 78.52% at ISSUE 56's (256, 512, 8)), and what
+    is left on the compact one is the 7 padding entries a table;
     ``MAGI_ATTENTION_GRID`` pins the grid here as in ``auto_kernel_config``."""
     shares = {}
     for grid in ("row_major", "sparse"):
@@ -346,8 +360,8 @@ def test_the_packed_cells_dead_share_on_both_grids(telemetry_on, monkeypatch):
         )
         assert args["grid"] == grid
         shares[grid] = telemetry.snapshot()["gauges"]["magi_flex_dead_step_share"]
-    assert shares["row_major"] == pytest.approx(78.97, abs=0.01)
-    assert shares["sparse"] == pytest.approx(100 * 5 / 3640, abs=0.01)
+    assert shares["row_major"] == pytest.approx(78.52, abs=0.01)
+    assert shares["sparse"] == pytest.approx(100 * 7 / 1872, abs=0.01)
 
 
 def test_stacked_per_rank_tables_count_padded_entries_as_launched():
