@@ -5,7 +5,7 @@
 
 PY ?= python
 
-.PHONY: install test test-fast test-slow lint typecheck telemetry-check autotune-check timeline-demo serving-check sched-check comm-check analyze spmd-audit lifecycle-check resilience-check roofline-check trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check check
+.PHONY: install test test-fast test-slow lint typecheck telemetry-check autotune-check serving-check sched-check comm-check analyze spmd-audit lifecycle-check resilience-check trace-check distserve-check memory-check compile-check tick-check numerics-check plan-reuse-check check
 
 install:
 	$(PY) -m pip install -e . --no-build-isolation
@@ -51,12 +51,6 @@ telemetry-check:
 # an intentional recalibration)
 autotune-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_autotune_check.py
-
-# measured-timeline demo on the virtual CPU mesh: per-stage comm/compute
-# wall times, predicted-vs-measured overlap audit, cross-rank aggregate,
-# multi-track Chrome trace (docs/observability.md "Measured timelines")
-timeline-demo:
-	$(PY) exps/run_timeline_profile.py
 
 # serving drift guard (CPU, jnp backend): decode-vs-prefill parity on
 # causal masks over varied page sizes/split counts, cp=2 loopback merge
@@ -120,19 +114,9 @@ lifecycle-check:
 # evict-then-retry, plan/hops build fallbacks, prefill-fault page
 # release, tuning-io counters — and a no-chaos GUARD=check run is
 # bit-identical to off with the trace count unchanged
-# (docs/resilience.md; exps/run_resilience_check.py --overhead times
-# the guard modes with the timeline profiler)
+# (docs/resilience.md)
 resilience-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_resilience_check.py
-
-# roofline/occupancy gate (ISSUE 10, CPU): REQUIRED_ROOFLINE_METRICS on
-# a real cp=2 profile, occupancy map == brute-force block scan on random
-# slice lists, per-hop magi_hop_ms gauges on a cp=4 hops-impl profile
-# summing to ~the cast time, and --self-test proof that a planted
-# dead-block-heavy plan is attributed to dead steps
-# (exps/run_roofline_check.py exits non-zero on any violation)
-roofline-check:
-	JAX_PLATFORMS=cpu $(PY) exps/run_roofline_check.py --self-test
 
 # request-tracing & exposition gate (ISSUE 11, CPU): a multi-tenant
 # scheduler trace must reconstruct to complete, monotonically ordered
@@ -196,34 +180,20 @@ tick-check:
 numerics-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_numerics_check.py --self-test
 
-# fleet gate (ISSUE 19; CPU, logical-tick simulator over the stubbed
-# device layer): healthy fleet holds the SLO with every
-# REQUIRED_FLEET_METRICS name live, the closed-loop autopilot beats the
-# static config on the burst-arrival AND decode-replica-fault
-# adversarial scenarios with zero anti-oscillation violations,
-# exps/data/capacity_curve.json regenerated (users-per-chip at the p99
-# SLO), and --self-test proof that a planted oscillating controller is
-# caught by the action-log checker
-fleet-check:
-	JAX_PLATFORMS=cpu $(PY) exps/run_fleet_check.py --self-test
-
 # plan-reuse gate (ISSUE 20; CPU): fingerprint-bucketed plan reuse —
 # bucketed-adapter parity (fwd+grad, jnp AND pallas-interpret backends,
 # both the fingerprint-miss and bucket-hit flavors), exact-hit identity
 # (the exact LRU stays byte-for-byte in front of the fingerprint cache),
-# a zipf fleet replay through the real Scheduler clearing >= 90%
-# plan-cache hit rate with positive solver-ms-saved and live bucket/
-# incremental engagement, and --self-test proof that one stolen REAL
-# dispatch row trips the parity oracle
+# and --self-test proof that one stolen REAL dispatch row trips the
+# parity oracle
 plan-reuse-check:
 	JAX_PLATFORMS=cpu $(PY) exps/run_plan_reuse_check.py --self-test
 	JAX_PLATFORMS=cpu $(PY) exps/run_plan_reuse_check.py
 
 # the default check flow: syntax, static analysis, telemetry catalog +
-# timeline/aggregate semantics, autotuner rung expectations,
-# serving parity, shared-prefix/scheduler gate, group-collective
-# parity/volume, resilience gate, roofline/occupancy gate, request
-# tracing/exposition gate, disaggregated-serving gate, memory
-# observability gate, unified-tick gate, numerics observability gate,
-# fleet simulator + autopilot gate, plan-reuse gate — all CPU-safe
-check: lint analyze telemetry-check autotune-check serving-check sched-check comm-check resilience-check roofline-check trace-check distserve-check memory-check compile-check tick-check numerics-check fleet-check plan-reuse-check
+# aggregate semantics, autotuner rung expectations, serving parity,
+# shared-prefix/scheduler gate, group-collective parity/volume,
+# resilience gate, request tracing/exposition gate,
+# disaggregated-serving gate, memory observability gate, unified-tick
+# gate, numerics observability gate, plan-reuse gate — all CPU-safe
+check: lint analyze telemetry-check autotune-check serving-check sched-check comm-check resilience-check trace-check distserve-check memory-check compile-check tick-check numerics-check plan-reuse-check

@@ -30,13 +30,9 @@ and the guards cost nothing when off:
    (output differs) — documenting that numerical guards do not cover
    wrong-but-finite payloads (the degradation matrix's honest row).
 
-``--overhead`` additionally times the guarded modes with the PR 3
-timeline profiler (numbers quoted in docs/resilience.md).
-
 Exits non-zero on any violation.
 """
 
-import argparse
 import os
 import sys
 import tempfile
@@ -661,37 +657,7 @@ def check_degradation() -> int:
     return 0
 
 
-# ---------------------------------------------------------------------------
-# --overhead: guard cost via the PR 3 timeline profiler
-# ---------------------------------------------------------------------------
-
-
-def measure_overhead() -> int:
-    plan, mesh, params = fixture()
-    for mode in ("off", "check", "repair"):
-        set_env(guard=None if mode == "off" else mode)
-        telemetry.set_enabled(True)
-        tl = telemetry.profile_plan_timeline(
-            plan, mesh, params, num_heads=(HQ, HKV), head_dim=D,
-            reps=3, inner=2,
-        )
-        print(
-            f"overhead[{mode}]: pipelined {tl.measured_total_ms:.3f} ms  "
-            f"serial {tl.serial_total_ms:.3f} ms"
-        )
-        telemetry.set_enabled(None)
-    set_env()
-    return 0
-
-
 def main() -> int:
-    parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument(
-        "--overhead", action="store_true",
-        help="also time guard modes with the timeline profiler",
-    )
-    args = parser.parse_args()
-
     checks = [
         check_transparency,
         check_stage_guards,
@@ -707,8 +673,6 @@ def main() -> int:
         if rc:
             set_env()
             return rc
-    if args.overhead:
-        measure_overhead()
     print(
         "resilience-check OK: every injector caught by its guard or "
         "degradation path; no-chaos guards bit-transparent and "

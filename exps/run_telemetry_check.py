@@ -10,9 +10,7 @@ Also sanity-checks the two structured exporters (metrics JSON + Chrome
 trace events JSON) and the disabled-mode no-op contract, so the guard
 covers the full acceptance surface of ISSUE 1 without needing devices.
 
-ISSUE 3 extensions: a measured-timeline profile on a tiny multi-stage
-CPU-mesh plan must populate every ``REQUIRED_TIMELINE_METRICS`` name the
-docs promise, cross-rank snapshot merging must keep its
+ISSUE 3 extensions: cross-rank snapshot merging must keep its
 counters-sum/gauge-skew/histogram-bucket semantics with deterministic
 ordering, and Chrome trace dumps must carry track-naming metadata
 events.
@@ -27,11 +25,13 @@ import os
 import sys
 import tempfile
 
+import numpy as np
+
 sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# the timeline step executes a real (tiny) distributed plan: virtual CPU
+# the keyed-interface step plans over a real (tiny) mesh: virtual CPU
 # mesh + the any-platform jnp kernel backend, set BEFORE jax initializes
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
@@ -104,13 +104,12 @@ def main() -> int:
     # 2b. plan-LRU visibility (ISSUE 9 satellite): one cold + one warm
     # resolution through the KEYED interface must tick the canonical
     # magi_plan_cache_hits/misses counters the docs promise
-    import numpy as _np
-    import jax as _jax
-    from jax.sharding import Mesh as _Mesh
+    import jax
+    from jax.sharding import Mesh
 
     from magiattention_tpu.api import magi_attn_flex_key
 
-    mesh_lru = _Mesh(_np.array(_jax.devices()[:2]), ("cp",))
+    mesh_lru = Mesh(np.array(jax.devices()[:2]), ("cp",))
     for _ in range(2):  # miss, then hit
         magi_attn_flex_key(
             [(0, 1024)], [(0, 1024)], [1], 1024, 1024, mesh_lru,
@@ -155,56 +154,14 @@ def main() -> int:
             )
             return 1
 
-    # 4. measured timeline: profile a tiny multi-stage plan on the CPU
-    # mesh and assert the documented magi_overlap_measured_* catalog
-    import numpy as np
-    import jax
-    from jax.sharding import Mesh
-
-    from magiattention_tpu.meta.solver.overlap_solver import OverlapConfig
-    from magiattention_tpu.parallel.dist_attn import make_attn_params
-
-    small_cp = 2  # same 2k mask, smaller mesh: keeps the check fast
-    mq2, _, bucket2 = make_dispatch_meta_from_qk_ranges(
-        qr, kr, [AttnMaskType.CAUSAL], total, total,
-        chunk_size=chunk, cp_size=small_cp,
-    )
-    plan2 = build_dist_attn_plan(
-        mq2, bucket2, block_q=64, block_k=64,
-        overlap_config=OverlapConfig(degree=2, min_stage_rows=64),
-    )
-    if len(plan2.stages) < 2:
-        print("FAIL: timeline-check plan did not produce >= 2 stages")
-        return 1
-    mesh = Mesh(np.array(jax.devices()[:small_cp]), ("cp",))
-    params = make_attn_params(plan2, 64, out_dtype="float32")
-    tl = telemetry.profile_plan_timeline(
-        plan2, mesh, params, num_heads=(2, 2), head_dim=64,
-        reps=1, inner=1,
-    )
-    snap = telemetry.snapshot()
-    missing = [
-        m for m in telemetry.REQUIRED_TIMELINE_METRICS
-        if not has_series(snap, m)
-    ]
-    if missing:
-        print(
-            "FAIL: documented timeline metrics missing after a "
-            f"profile_plan_timeline run (catalog drift): {missing}"
-        )
-        return 1
-    if not (0.0 <= tl.overlap_efficiency <= 1.0):
-        print(f"FAIL: overlap efficiency out of [0,1]: {tl}")
-        return 1
-
-    # 5. cross-rank aggregation semantics + deterministic ordering
+    # 4. cross-rank aggregation semantics + deterministic ordering
     snap_b = json.loads(json.dumps(snap))  # simulated second rank
     agg = telemetry.merge_snapshots([snap, snap_b], ranks=[0, 1])
     plan_builds = agg["counters"].get("magi_plan_builds_total")
     if plan_builds != 2 * snap["counters"]["magi_plan_builds_total"]:
         print(f"FAIL: aggregate counters are not summed: {plan_builds}")
         return 1
-    tot = agg["gauges"].get("magi_overlap_measured_total_ms")
+    tot = agg["gauges"].get("magi_plan_modeled_calc_seconds")
     if not tot or sorted(tot) != [
         "argmax", "max", "mean", "min", "per_rank",
     ] or sorted(tot["per_rank"]) != ["0", "1"]:
@@ -229,7 +186,7 @@ def main() -> int:
         print("FAIL: aggregate_across_mesh loopback mismatch")
         return 1
 
-    # 6. serving catalog: one tiny prefill + decode step through the
+    # 5. serving catalog: one tiny prefill + decode step through the
     # engine must populate every magi_decode_* / magi_kvcache_* metric
     import jax.numpy as jnp
 
@@ -262,7 +219,7 @@ def main() -> int:
         print(f"FAIL: summary lacks the serving section:\n{summary}")
         return 1
 
-    # 7. plan-sanitizer counters (ISSUE 7): one clean validate_plan must
+    # 6. plan-sanitizer counters (ISSUE 7): one clean validate_plan must
     # tick magi_validate_plan_checks; one seeded-bad validation must tick
     # magi_validate_failures — both names are documented catalog entries
     from magiattention_tpu.analysis.plan_sanity import (
@@ -291,7 +248,7 @@ def main() -> int:
         )
         return 1
 
-    # 8. resilience catalog (ISSUE 8): real guarded/degraded paths must
+    # 7. resilience catalog (ISSUE 8): real guarded/degraded paths must
     # populate every magi_guard_* / admission / degraded / tuning-io
     # metric the docs promise — exercised through the actual call sites
     # (decode guards, engine admission, comm build, tuning cache), not
@@ -404,7 +361,7 @@ def main() -> int:
         )
         return 1
 
-    # 9. shared-prefix + scheduler catalogs (ISSUE 9): a miss+hit+fork
+    # 8. shared-prefix + scheduler catalogs (ISSUE 9): a miss+hit+fork
     # admission with an unaligned prefix (forces a CoW split), pool
     # pressure (forces an LRU prefix eviction), then a few Scheduler
     # ticks over a mixed prefill/decode trace must populate every
@@ -463,7 +420,7 @@ def main() -> int:
         )
         return 1
 
-    # 10. analysis catalog (ISSUE 13): one smoke interleaving-checker
+    # 9. analysis catalog (ISSUE 13): one smoke interleaving-checker
     # exploration (clean: states > 0, counterexamples == 0) plus one
     # mutated exploration (the replanted PR 9 double-free: the
     # counterexample counter must move) populate the
@@ -499,7 +456,6 @@ def main() -> int:
     print(
         f"telemetry-check OK: {len(telemetry.REQUIRED_PLAN_METRICS)} plan "
         f"+ {len(telemetry.REQUIRED_PLAN_CACHE_METRICS)} plan-LRU "
-        f"+ {len(telemetry.REQUIRED_TIMELINE_METRICS)} timeline "
         f"+ {len(telemetry.REQUIRED_SERVING_METRICS)} serving "
         f"+ {len(telemetry.REQUIRED_PREFIX_METRICS)} prefix "
         f"+ {len(telemetry.REQUIRED_SCHED_METRICS)} scheduler "

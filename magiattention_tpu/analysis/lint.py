@@ -21,10 +21,10 @@ rules that keep the SPMD stack portable and legible:
   host-side uses.
 - **MAGI004** — every ``lax.ppermute`` / ``lax.all_to_all`` /
   ``lax.psum`` call site lexically wrapped in a ``named_scope`` so
-  profiler timelines and the measured-overlap audit stay legible.
+  profiler timelines stay legible.
   ISSUE 13 extends the rule to ``jax.device_put`` inside ``serving/``:
   there a device_put IS a wire hop (the page-stream / pool-pinning
-  transfer), and an unscoped hop is invisible on the hop timeline.
+  transfer), and an unscoped hop is invisible in a device trace.
 - **MAGI005** — no ``axis_index`` / ``process_index``-dependent host
   control flow (``if``/``while``/ternary) lexically guarding a
   collective issue site. Rank-gated host branching around a collective

@@ -1496,81 +1496,6 @@ def aggregate_telemetry_across_mesh(snapshot: dict | None = None) -> dict:
     return telemetry.aggregate_across_mesh(snapshot)
 
 
-def profile_attn_timeline(
-    key: "DistAttnRuntimeKey | None" = None, **kwargs
-):
-    """Measure the stage timeline of a planned runtime (default: the most
-    recent key): per-stage cast/kernel wall time with host fencing, the
-    pipelined-vs-serial overlap efficiency, and the predicted-vs-measured
-    delta against the overlap solver's timeline model. Returns a
-    :class:`telemetry.MeasuredTimeline` (see its ``report()``); records
-    ``magi_overlap_measured_*`` gauges while telemetry is enabled.
-    Keyword args are forwarded to
-    :func:`telemetry.timeline.profile_key_timeline` (reps/inner/warmup,
-    ``use_mesh_barrier`` for multi-chip meshes)."""
-    return telemetry.profile_key_timeline(key, **kwargs)
-
-
-def profile_roofline(
-    key: "DistAttnRuntimeKey | None" = None,
-    *,
-    measured_tflops: float | None = None,
-    measured_ms: float | None = None,
-    measure: bool = False,
-    workload: str | None = None,
-    record: bool = True,
-    **timeline_kwargs,
-):
-    """Mask-aware roofline analysis of a planned runtime's workload
-    (default: the most recent key): true-vs-scheduled FLOPs at the rung
-    the plan actually executes, mask density, and the measured-vs-peak
-    gap decomposed into dead-step / partial-tile / masked-entry-
-    overcompute fractions. Returns a :class:`telemetry.RooflineReport`
-    (see its ``report()``); records the ``magi_roofline_*`` gauges while
-    telemetry is enabled.
-
-    Pass ``measured_tflops`` (mask-FLOPs convention) or ``measured_ms``
-    from a bench, or ``measure=True`` to time the plan's full pipelined
-    path via :func:`profile_attn_timeline` (extra keyword args forward
-    there); with neither, the gap attribution is over the MODELED total.
-    """
-    from ..telemetry.roofline import analyze_workload
-
-    if key is None:
-        key = get_most_recent_key()
-    if measure:
-        tl = profile_attn_timeline(key, **timeline_kwargs)
-        measured_ms = tl.measured_total_ms
-        measured_tflops = None
-    bq, bk, hb = _blocking_from(
-        key.block_config, key.num_heads_q, key.num_heads_kv
-    )
-    rep = analyze_workload(
-        key.q_ranges,
-        key.k_ranges,
-        key.attn_type_map,
-        num_heads_q=key.num_heads_q,
-        num_heads_kv=key.num_heads_kv,
-        head_dim=key.head_dim,
-        block_q=bq,
-        block_k=bk,
-        head_block=hb,
-        bytes_per_elt=int(jnp.dtype(key.out_dtype).itemsize),
-        workload=(
-            workload
-            if workload is not None
-            else f"key_{key.total_seqlen_q}x{key.total_seqlen_k}"
-        ),
-        measured_tflops=measured_tflops,
-        measured_ms=measured_ms,
-        total_seqlen_q=key.total_seqlen_q,
-        total_seqlen_k=key.total_seqlen_k,
-    )
-    if record:
-        telemetry.record_roofline(rep)
-    return rep
-
-
 def clear_cache(mesh: "jax.sharding.Mesh | None" = None) -> None:
     """Drop cached runtime plans (reference clear_cache,
     api/magi_attn_interface.py:1157). With a ``mesh``, only keys planned

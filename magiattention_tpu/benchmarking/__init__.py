@@ -6,7 +6,6 @@ from .bench import (
     chained_ms,
     do_bench,
     enable_compile_cache,
-    mesh_barrier,
 )
 
 __all__ = [
@@ -15,5 +14,4 @@ __all__ = [
     "chained_ms",
     "do_bench",
     "enable_compile_cache",
-    "mesh_barrier",
 ]

@@ -10,50 +10,11 @@ the enqueue), plus jax device memory stats.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import statistics
 import time
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
-
-
-def mesh_barrier(mesh) -> None:
-    """Rendezvous every device of a mesh and block the host on the result
-    (role of the reference's ``maybe_dist_sync``: cuda.synchronize +
-    dist.barrier before each sweep, bench.py:328). One psum over all mesh
-    axes forces every device to reach this point."""
-    fn, zero = _barrier_cache(mesh)
-    jax.block_until_ready(fn(zero))
-
-
-@functools.lru_cache(maxsize=8)
-def _barrier_cache(mesh):
-    """Jitted barrier + placed scalar per mesh — a fresh closure each call
-    would retrace/compile every rep."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..utils.compat import shard_map
-    from ..utils.instrument import named_scope
-
-    names = tuple(mesh.axis_names)
-
-    def _psum_all(v):
-        with named_scope("magi_bench_barrier"):
-            return jax.lax.psum(v, names)
-
-    def _b(x):
-        return shard_map(
-            _psum_all,
-            mesh=mesh,
-            in_specs=P(),
-            out_specs=P(),
-            check_vma=False,
-        )(x)
-
-    zero = jax.device_put(jnp.zeros(()), NamedSharding(mesh, P()))
-    return jax.jit(_b), zero
 
 
 class MemoryRecorder:
@@ -182,15 +143,11 @@ def do_bench(
     rep: int = 10,
     inner: int = 5,
     record_memory: bool = False,
-    mesh=None,
     **kwargs,
 ) -> BenchResult:
     """Time fn(*args) with warmup; each rep runs ``inner`` calls between
     syncs so fixed sync latency amortizes (reference do_bench :79).
 
-    ``mesh``: rendezvous every device of the mesh before each timed rep
-    (:func:`mesh_barrier` — the reference's maybe_dist_sync role), so
-    multi-device sweeps never time one device's leftover queue.
     ``record_memory``: samples memory BETWEEN reps (after each sync, via
     :class:`MemoryRecorder.record` — no concurrent polling thread, so the
     memory_stats RPCs never perturb the timed regions; use a standalone
@@ -202,8 +159,6 @@ def do_bench(
     rec = MemoryRecorder() if record_memory else None
     times = []
     for _ in range(rep):
-        if mesh is not None:
-            mesh_barrier(mesh)
         t0 = time.perf_counter()
         for _ in range(inner):
             r = fn(*args, **kwargs)

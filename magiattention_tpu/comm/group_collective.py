@@ -393,8 +393,8 @@ class GroupCollectiveMeta:
 
     # ---- per-impl device array layouts ----------------------------------
     # The plan's flattened operand stream ships exactly these, in this
-    # order; consumers (dist_attn_local, qo_comm_attn_local, the timeline
-    # profiler) count via num_cast_arrays / num_reduce_arrays.
+    # order; consumers (dist_attn_local, qo_comm_attn_local) count
+    # via num_cast_arrays / num_reduce_arrays.
 
     def cast_device_arrays(self) -> tuple[np.ndarray, ...]:
         """Arrays the cast (and its AD transpose) needs: a2a ->

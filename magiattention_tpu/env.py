@@ -276,20 +276,6 @@ def recompile_storm_threshold() -> int:
     return v
 
 
-def timeline_reps() -> int:
-    """Timed reps per stage in the measured-timeline profiler
-    (``telemetry/timeline.py``); each rep is median-filtered by the
-    do_bench discipline."""
-    return _env_int("MAGI_ATTENTION_TIMELINE_REPS", 5)
-
-
-def timeline_inner() -> int:
-    """Calls per timed rep in the measured-timeline profiler (amortizes
-    the fixed per-dispatch host latency, which dominates sub-ms
-    stages)."""
-    return _env_int("MAGI_ATTENTION_TIMELINE_INNER", 2)
-
-
 def is_sanity_check_enabled() -> bool:
     """Deep invariant checks in the planners (reference env/general.py:75)."""
     return _env_bool("MAGI_ATTENTION_SANITY_CHECK")
@@ -573,61 +559,6 @@ def tier_token_budget(tier: str) -> int:
     return v
 
 
-def fleet_window_ticks() -> int:
-    """Scheduler ticks per autopilot evaluation window (``fleet/``,
-    ISSUE 19): the FleetSimulator snapshots the registry every N ticks
-    and hands the ``snapshot_delta`` to the autopilot. Smaller windows
-    react faster but see noisier SLO samples. Simulation-host behavior
-    only, NOT part of :func:`flags_fingerprint`."""
-    v = _env_int("MAGI_ATTENTION_FLEET_WINDOW", 16)
-    if v < 1:
-        raise ValueError(
-            f"MAGI_ATTENTION_FLEET_WINDOW={v} must be a positive tick count"
-        )
-    return v
-
-
-def fleet_cooldown_windows() -> int:
-    """Autopilot per-knob cooldown (``fleet/autopilot.py``): after a
-    knob moves, it is frozen for this many evaluation windows — the
-    anti-oscillation half of the controller contract (``make
-    fleet-check`` asserts no knob flips more than once per cooldown
-    under chaos). NOT part of :func:`flags_fingerprint`."""
-    v = _env_int("MAGI_ATTENTION_FLEET_COOLDOWN", 3)
-    if v < 1:
-        raise ValueError(
-            f"MAGI_ATTENTION_FLEET_COOLDOWN={v} must be a positive "
-            "window count"
-        )
-    return v
-
-
-def fleet_slo_ttft_ticks() -> float:
-    """Default p99 time-to-first-token SLO target in LOGICAL TICKS for
-    the fleet simulator (``fleet/autopilot.SLOTargets``); explicit
-    SLOTargets arguments win. NOT part of :func:`flags_fingerprint`."""
-    v = _env_float("MAGI_ATTENTION_FLEET_SLO_TTFT", 16.0)
-    if v <= 0:
-        raise ValueError(
-            f"MAGI_ATTENTION_FLEET_SLO_TTFT={v} must be a positive tick "
-            "count"
-        )
-    return v
-
-
-def fleet_slo_toklat_ticks() -> float:
-    """Default p99 per-token decode-latency SLO target in LOGICAL TICKS
-    (``fleet/autopilot.SLOTargets``); explicit arguments win. NOT part
-    of :func:`flags_fingerprint`."""
-    v = _env_float("MAGI_ATTENTION_FLEET_SLO_TOKLAT", 8.0)
-    if v <= 0:
-        raise ValueError(
-            f"MAGI_ATTENTION_FLEET_SLO_TOKLAT={v} must be a positive "
-            "tick count"
-        )
-    return v
-
-
 def decode_splits() -> int | None:
     """Split-KV decode split count (``serving/decode_attn.py``): an
     integer pins the number of KV splits per sequence; 'auto' (default)
@@ -653,25 +584,6 @@ def head_block_override() -> int | None:
 def tpu_generation() -> str:
     """TPU generation key for the cost model (utils/cost.py specs)."""
     return _env_str("MAGI_ATTENTION_TPU_GENERATION", "v5e")
-
-
-def peak_tflops_override() -> float | None:
-    """Explicit roofline peak rate (TF/s) for the mask-aware roofline
-    profiler (``telemetry/roofline.py``), or None to resolve through the
-    per-backend/per-generation peak table. Set it on hardware the table
-    doesn't know (or to re-anchor the efficiency denominator, e.g. to a
-    measured dense-kernel ceiling instead of the datasheet peak). Pure
-    observability — never influences planning, so NOT part of
-    :func:`flags_fingerprint`."""
-    v = os.environ.get("MAGI_ATTENTION_PEAK_TFLOPS")
-    if v is None or not v.strip():
-        return None
-    f = float(v)
-    if f <= 0:
-        raise ValueError(
-            f"MAGI_ATTENTION_PEAK_TFLOPS={v!r} must be a positive TF/s rate"
-        )
-    return f
 
 
 def group_coll_impl() -> str:

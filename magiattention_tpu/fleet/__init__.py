@@ -1,52 +1,12 @@
-"""Fleet layer (ISSUE 19): production-shaped load over the real serving
-stack, and the control loop above it.
+"""Serving traffic as an artifact (ISSUE 19): seeded, serializable
+request traces.
 
-The fourth layer of the system (kernels -> serving -> observability ->
-**fleet**): everything here runs on a LOGICAL tick clock over the
-lifecycle checker's stubbed device layer, so a million-user day of
-traffic replays in seconds of host time while every host-side decision
-(admission, eviction, tier placement, page streaming, requeue) is made
-by the REAL ``Scheduler``/``TieredScheduler`` + engine code paths.
-
-- :mod:`~magiattention_tpu.fleet.workload` — seeded, serializable trace
-  generators (Poisson / bursty-MMPP / diurnal arrivals, zipf-shared
-  prefixes, long-tail output lengths) and the ``FleetTrace`` JSON
-  artifact format.
-- :mod:`~magiattention_tpu.fleet.sim` — the discrete-event simulator:
-  replays a trace through the serving stack, emits the production
-  ``magi_*`` metrics plus the ``magi_fleet_*`` catalog
-  (``REQUIRED_FLEET_METRICS``), and snapshots ``snapshot_delta``
-  windows for the autopilot.
-- :mod:`~magiattention_tpu.fleet.autopilot` — the closed-loop SLO
-  controller: consumes windows, retunes live scheduler/engine knobs
-  through ``Scheduler.apply_knobs`` with hysteresis, per-knob cooldown
-  and bounded steps so a chaos-degraded fleet is never oscillated.
-- :mod:`~magiattention_tpu.fleet.capacity` — the capacity planner:
-  binary-searches users-per-chip at the p99 SLO per config and writes
-  ``exps/data/capacity_curve.json``.
-
-Gate: ``make fleet-check`` (``exps/run_fleet_check.py``); docs:
-``docs/fleet.md``.
+:mod:`~magiattention_tpu.fleet.workload` holds the generators (Poisson /
+bursty-MMPP / diurnal arrivals, zipf-shared prefixes, long-tail output
+lengths) and the ``FleetTrace`` JSON format. A trace is replayed by
+handing its requests to a ``serving.Scheduler``; nothing here runs one.
 """
 
-from .autopilot import (  # noqa: F401
-    Autopilot,
-    AutopilotDecision,
-    KnobSpec,
-    SLOTargets,
-    default_knob_specs,
-    find_oscillations,
-)
-from .capacity import (  # noqa: F401
-    DEFAULT_CAPACITY_CONFIGS,
-    capacity_search,
-    write_capacity_curve,
-)
-from .sim import (  # noqa: F401
-    FleetReport,
-    FleetSimulator,
-    TickClock,
-)
 from .workload import (  # noqa: F401
     FLEET_TRACE_FORMAT,
     FleetTrace,
@@ -55,20 +15,8 @@ from .workload import (  # noqa: F401
 )
 
 __all__ = [
-    "Autopilot",
-    "AutopilotDecision",
-    "DEFAULT_CAPACITY_CONFIGS",
     "FLEET_TRACE_FORMAT",
-    "FleetReport",
-    "FleetSimulator",
     "FleetTrace",
-    "KnobSpec",
-    "SLOTargets",
-    "TickClock",
     "TraceRequest",
-    "capacity_search",
-    "default_knob_specs",
-    "find_oscillations",
     "generate_trace",
-    "write_capacity_curve",
 ]

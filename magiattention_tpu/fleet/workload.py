@@ -15,10 +15,9 @@ Generators (:func:`generate_trace`):
 - **mmpp** — a 2-state Markov-modulated Poisson process: calm ticks at
   ``rate``, burst ticks at ``burst_rate``, with geometric dwell times
   (``burst_prob`` to enter, ``calm_prob`` to leave). The adversarial
-  burst-arrival scenario the autopilot gate replays.
+  burst-arrival scenario.
 - **diurnal** — a sinusoidal load curve (period ``diurnal_period``
-  ticks, amplitude 0..1 of ``rate``): the capacity planner's
-  peak-vs-trough shape.
+  ticks, amplitude 0..1 of ``rate``): a day's peak-vs-trough shape.
 
 Prefix sharing is zipf-distributed over a pool of ``prefix_pool``
 distinct page-aligned system prompts: a heavy-head zipf (most users on
@@ -81,10 +80,10 @@ class TraceRequest:
 
 @dataclasses.dataclass(frozen=True)
 class FleetTrace:
-    """A named, seeded arrival schedule — the simulator's replay unit.
+    """A named, seeded arrival schedule — the unit of replay.
 
-    ``horizon_ticks`` is the arrival horizon only; the simulator keeps
-    ticking past it until the backlog drains (or its own cap). ``meta``
+    ``horizon_ticks`` is the arrival horizon only; a replay keeps
+    ticking past it until the backlog drains. ``meta``
     records the generator parameters so an artifact is self-describing
     and regenerable."""
 
@@ -327,8 +326,8 @@ def generate_trace(
 
 def scale_rate(trace_kwargs: dict, rate: float) -> dict:
     """A copy of generator kwargs with the base rate replaced (burst
-    rate rescaled proportionally when it was explicit) — the capacity
-    planner's load dial."""
+    rate rescaled proportionally when it was explicit) — the load
+    dial of a sweep over one trace shape."""
     out = dict(trace_kwargs)
     old = float(out.get("rate", 1.0))
     out["rate"] = float(rate)
@@ -338,8 +337,7 @@ def scale_rate(trace_kwargs: dict, rate: float) -> dict:
 
 
 def validate_trace(trace: FleetTrace) -> list[str]:
-    """Structural lint of a trace artifact (the fleet-check gate runs
-    it on every scenario before replay): returns human-readable
+    """Structural lint of a trace artifact: returns human-readable
     problems, [] when clean."""
     errs: list[str] = []
     seen: set[int] = set()

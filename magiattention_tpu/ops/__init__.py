@@ -7,7 +7,6 @@ from .block_sparse import (
     TickSegment,
     block_sparse_attn_func,
     build_block_meta_from_block_mask,
-    build_block_meta_from_occupancy,
 )
 from .correction import (
     correct_attn_lse,
@@ -28,7 +27,6 @@ __all__ = [
     "TickEnumeration",
     "TickSegment",
     "block_sparse_attn_func",
-    "build_block_meta_from_occupancy",
     "correct_attn_lse",
     "correct_attn_lse_with_sink",
     "correct_attn_out",

@@ -20,11 +20,8 @@ prefill, decode, and cascade (ROADMAP item 1): a flattened major->minor
 block walk — per major row, the sorted list of minor blocks it touches —
 with the row tables and clamped entry lookup every sparse consumer
 needs. The flex kernels' compact sparse grid walks it over the entry
-tables (``ops/flex_attn.py``), the split-KV decode kernel walks it over
-the paged block table (``serving/decode_attn.py``), and the occupancy
-profiler's JSON artifact (``telemetry/occupancy.py``,
-``exps/data/occupancy_*.json``) loads straight into it
-(:meth:`BlockEnumeration.from_occupancy`).
+tables (``ops/flex_attn.py``) and the split-KV decode kernel walks it
+over the paged block table (``serving/decode_attn.py``).
 """
 
 from __future__ import annotations
@@ -36,7 +33,6 @@ import numpy as np
 
 from .block_meta import (
     FlexAttnBlockMeta,
-    _slice_k_span,
     _sub_area,
     assemble_block_meta,
 )
@@ -109,13 +105,6 @@ class BlockEnumeration:
         :func:`clamped_entry`)."""
         return clamped_entry(self.row_start, self.row_count, i, j)
 
-    def occupied_pairs(self) -> np.ndarray:
-        """[E, 2] (major, minor) pairs — the brute-force-scan parity
-        surface (host arrays only)."""
-        return np.stack(
-            [np.asarray(self.major), np.asarray(self.minor)], axis=1
-        )
-
     @staticmethod
     def from_sorted(major, minor, num_rows: int) -> "BlockEnumeration":
         """Wrap already-sorted (major, minor) arrays — the flex entry
@@ -128,52 +117,6 @@ class BlockEnumeration:
             row_start=rs,
             row_count=rc,
         )
-
-    @staticmethod
-    def from_active_lists(
-        active, num_rows: int | None = None
-    ) -> "BlockEnumeration":
-        """Host-side construction from per-row active-minor lists — the
-        exact ``active_k_blocks`` shape the occupancy profiler emits."""
-        rows = [sorted(int(b) for b in row) for row in active]
-        if num_rows is None:
-            num_rows = len(rows)
-        if len(rows) != num_rows:
-            raise ValueError(
-                f"block enumeration: {len(rows)} active rows != "
-                f"num_rows {num_rows}"
-            )
-        counts = np.asarray([len(r) for r in rows], dtype=np.int32)
-        major = np.repeat(
-            np.arange(num_rows, dtype=np.int32), counts
-        )
-        minor = np.asarray(
-            [b for row in rows for b in row], dtype=np.int32
-        ).reshape(-1)
-        starts = np.concatenate(
-            ([0], np.cumsum(counts)[:-1])
-        ).astype(np.int32)
-        return BlockEnumeration(
-            num_rows=int(num_rows),
-            major=major,
-            minor=minor,
-            row_start=starts,
-            row_count=counts,
-        )
-
-    @staticmethod
-    def from_occupancy(occ) -> "BlockEnumeration":
-        """From a ``telemetry.occupancy.BlockOccupancyMap`` or its
-        ``as_json()`` dict (the committed ``exps/data/occupancy_*.json``
-        artifact): the profiler's measurement output IS the sparse
-        grid's input format."""
-        if isinstance(occ, dict):
-            active = occ["active_k_blocks"]
-            num_rows = int(occ["num_q_blocks"])
-        else:
-            active = occ.active
-            num_rows = int(occ.num_q_blocks)
-        return BlockEnumeration.from_active_lists(active, num_rows)
 
     @staticmethod
     def from_block_table(
@@ -469,71 +412,6 @@ class TickEnumeration:
         return BlockEnumeration.from_block_table(
             self.block_tables(), num_splits
         )
-
-
-def build_block_meta_from_occupancy(
-    occ,
-    q_ranges,
-    k_ranges,
-    attn_type_map,
-    total_q: int,
-    total_k: int,
-) -> FlexAttnBlockMeta:
-    """Kernel plan from a precomputed block-occupancy map: one entry per
-    occupied (q-block, k-block) pair x intersecting slice, windows taken
-    from the slice geometry. Consumes exactly the per-q-block
-    active-k-block shape ``telemetry.occupancy.block_occupancy_map``
-    emits (and ``exps/data/occupancy_*.json`` stores), and — when the
-    occupancy map is exact — produces tables identical to
-    :func:`~.block_meta.build_block_meta` on the same slices (the parity
-    oracle in ``tests/test_ops/test_block_sparse_grid.py``)."""
-    enum = BlockEnumeration.from_occupancy(occ)
-    q_arr = np.asarray(q_ranges, dtype=np.int64).reshape(-1, 2)
-    k_arr = np.asarray(k_ranges, dtype=np.int64).reshape(-1, 2)
-    t_arr = np.asarray(attn_type_map, dtype=np.int64).reshape(-1)
-    slices = np.concatenate([q_arr, k_arr, t_arr[:, None]], axis=1)
-    if isinstance(occ, dict):
-        bq, bk = int(occ["block_q"]), int(occ["block_k"])
-    else:
-        bq, bk = int(occ.block_q), int(occ.block_k)
-
-    entries: list[tuple] = []
-    area = 0
-    minor = np.asarray(enum.minor).tolist()
-    row_start = np.asarray(enum.row_start).tolist()
-    row_count = np.asarray(enum.row_count).tolist()
-    for sid in range(slices.shape[0]):
-        qs, qe, ks, ke, mt = (int(x) for x in slices[sid])
-        if qs >= qe or ks >= ke:
-            continue
-        area += _sub_area(qs, qe, ks, ke, qs, qe, ks, ke, mt)
-        # only rows whose q-block range intersects the slice — the row
-        # tables make this O(slice rows + touched entries), not O(E)
-        for i in range(qs // bq, min(-(-qe // bq), enum.num_rows)):
-            gq_lo = max(qs, i * bq)
-            gq_hi = min(qe, (i + 1) * bq)
-            if gq_lo >= gq_hi:
-                continue
-            k_lo, k_hi = _slice_k_span(gq_lo, gq_hi, ks, ke, qs, qe, mt)
-            if k_hi <= k_lo:
-                continue
-            rs, rc = row_start[i], row_count[i]
-            for j in minor[rs : rs + rc]:
-                gk_lo = max(k_lo, j * bk)
-                gk_hi = min(k_hi, (j + 1) * bk)
-                if gk_lo >= gk_hi:
-                    continue
-                entries.append(
-                    (i, j, sid, gq_lo, gq_hi, gk_lo, gk_hi, 0, 0)
-                )
-    ent = (
-        np.asarray(entries, dtype=np.int64)
-        if entries
-        else np.empty((0, 9), dtype=np.int64)
-    )
-    return assemble_block_meta(
-        ent, slices, total_q, total_k, bq, bk, int(area)
-    )
 
 
 def build_block_meta_from_block_mask(

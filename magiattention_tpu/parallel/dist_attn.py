@@ -223,9 +223,8 @@ class StagePlan:
     comm: GroupCollectiveMeta
     tables: StageTables
     # mask area of the heaviest rank's kernel work in this stage (0 =
-    # legacy construction). The measured-timeline harness prices the
-    # predicted stage compute from this with the cost-model factors, so
-    # predicted-vs-measured deltas use exactly the plan that executes.
+    # legacy construction). The plan sanitizer sums it over the stages
+    # against the plan's total area (analysis/plan_sanity.py).
     max_rank_area: int = 0
 
 
@@ -256,8 +255,8 @@ class DistAttnPlan:
 
     # heaviest rank's host-stage (own-shard) mask area; 0 on the merged
     # degree-0 path (where max_rank_area covers the single kernel call)
-    # and on legacy constructions. Feeds the measured-timeline harness's
-    # predicted host compute (telemetry/timeline.py).
+    # and on legacy constructions. The plan sanitizer's per-stage area
+    # sum reads it (analysis/plan_sanity.py).
     host_max_rank_area: int = 0
 
     @property
@@ -309,7 +308,7 @@ class DistAttnPlan:
         :class:`~..telemetry.memory.MemoryLedger` with per-stage cast
         buffers taken from each stage's
         ``comm.scheduled_rows_per_rank`` — the same figure the overlap
-        solver and the timeline predictor price, so the byte accounting
+        solver prices, so the byte accounting
         can never drift from the cost model's — plus kernel
         partial/LSE scratch and operand/table/output buffers.
         ``make memory-check`` gates it against XLA's compiled
@@ -1103,7 +1102,7 @@ def dist_attn_local(
     error code as a 4th output (ISSUE 8 — every stage partial is guarded
     when ``MAGI_ATTENTION_GUARD`` != off; the keyed runtime consumes the
     code at the jit boundary). Default False keeps the 3-tuple contract
-    for direct callers (models, timeline profiler, trace audit).
+    for direct callers (models, trace audit).
 
     ``with_census``: additionally return the rank-local packed value
     census (ISSUE 18 — f32 ``[len(numerics.census_keys(sites))]``, the

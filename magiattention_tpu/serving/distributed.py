@@ -637,7 +637,7 @@ class TieredEngine:
         didx = jnp.asarray(dst_pages[:n], jnp.int32)
         with named_scope("magi_page_stream"):
             # the device_put IS the cross-tier wire hop — it lives
-            # inside the stream scope so the hop timeline sees it
+            # inside the stream scope so a device trace names it
             pk = jax.device_put(
                 src.k_pages[idx], kv_head_sharding(rep.mesh)
             )
@@ -884,43 +884,8 @@ class TieredScheduler(Scheduler):
 
     def _admission_headroom(self) -> int:
         # decode growth happens on the replicas' own pools, not the
-        # prefill pool admission draws from — only the autopilot's
-        # runtime watermark knob (ISSUE 19) applies
-        return self.admission_watermark
-
-    # -- runtime knobs (ISSUE 19) ----------------------------------------
-
-    _KNOB_NAMES = Scheduler._KNOB_NAMES + (
-        "prefill_budget", "decode_budget",
-    )
-
-    def knobs(self) -> dict:
-        out = super().knobs()
-        out["prefill_budget"] = self.prefill_budget
-        out["decode_budget"] = self.decode_budget
-        return out
-
-    def _knob_engines(self):
-        # cascade/decode-splits retunes reach every member engine: the
-        # prefill chip and each decode replica's (the decode replicas
-        # are where the decode-path knobs actually bite)
-        return [self.engine._prefill] + [
-            r.engine for r in self.engine.replicas
-        ]
-
-    def _coerce_knob(self, name: str, value):
-        if name in ("prefill_budget", "decode_budget"):
-            v = int(value)
-            if v < 1:
-                raise ValueError(f"knob {name}={value!r} must be >= 1")
-            return v
-        return super()._coerce_knob(name, value)
-
-    def _set_knob(self, name: str, value) -> None:
-        super()._set_knob(name, value)
-        if name in ("prefill_budget", "decode_budget"):
-            # keep the aggregate the base class reports consistent
-            self.token_budget = self.prefill_budget + self.decode_budget
+        # prefill pool admission draws from
+        return 0
 
     def _decode_states(self):
         # only sequences RESIDENT on the decode tier decode; a request

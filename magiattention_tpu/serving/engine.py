@@ -315,15 +315,6 @@ class ServingEngine:
         # host concern (reservation growth, CoW, append, telemetry)
         # from THIS engine. None = the standard flat/cascade paths.
         self._decode_attn_fn = decode_attn_fn
-        # runtime-retunable knobs (ISSUE 19): the fleet autopilot writes
-        # these between ticks through Scheduler.apply_knobs. None =
-        # defer to the env flag / autotuner exactly as before.
-        # cascade_override: 'auto'|'on'|'off' beats MAGI_ATTENTION_CASCADE
-        # when a decode_step/unified_tick caller passed cascade=None;
-        # decode_splits_override: pins the split-KV split count when the
-        # caller didn't.
-        self.cascade_override: str | None = None
-        self.decode_splits_override: int | None = None
         # what the last decode_step resolved (split count, cascade
         # grouping): the scheduler reads this to tag per-request
         # decode_step trace spans (ISSUE 11) — plain host state, not
@@ -833,11 +824,7 @@ class ServingEngine:
             self._ensure_reserved(s, self._lengths.get(s, 0) + 1)
             self._ensure_writable(s, self._lengths.get(s, 0))
         if cascade is None:
-            mode = (
-                self.cascade_override
-                if self.cascade_override is not None
-                else env.cascade_mode()
-            )
+            mode = env.cascade_mode()
         elif isinstance(cascade, str):
             mode = cascade
         else:
@@ -885,10 +872,7 @@ class ServingEngine:
             else:
                 # resolve the split count ONCE (fingerprint + cache
                 # lookup) and hand the concrete int down — decode is the
-                # per-token hot loop; the autopilot's decode-splits
-                # override stands in for the caller when it passed None
-                if kw.get("num_splits") is None:
-                    kw["num_splits"] = self.decode_splits_override
+                # per-token hot loop
                 kw["num_splits"] = resolved = resolve_num_splits(
                     kw.get("num_splits"), self.cache, batch.batch_size,
                     q.shape[1],
@@ -1071,11 +1055,7 @@ class ServingEngine:
                 self._ensure_reserved(slot, start + t)
                 self._ensure_writable(slot, start)
         if cascade is None:
-            mode = (
-                self.cascade_override
-                if self.cascade_override is not None
-                else env.cascade_mode()
-            )
+            mode = env.cascade_mode()
         elif isinstance(cascade, str):
             mode = cascade
         else:

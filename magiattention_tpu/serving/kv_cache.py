@@ -385,7 +385,7 @@ def shard_kv_cache(
     host = NamedSharding(mesh, PartitionSpec())
     with named_scope("magi_kvcache_shard"):
         # re-pinning moves pool storage across chips: a wire hop on
-        # real hardware, scoped so the hop timeline attributes it
+        # real hardware, scoped so a device trace attributes it
         return PagedKVCache(
             k_pages=jax.device_put(cache.k_pages, pages),
             v_pages=jax.device_put(cache.v_pages, pages),

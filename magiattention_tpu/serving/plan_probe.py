@@ -8,9 +8,9 @@ fingerprint-bucketed second-level cache (``meta/plan_fingerprint.py`` +
 plan. This probe is the bridge: it threads the REAL request shapes of a
 :class:`~magiattention_tpu.serving.scheduler.Scheduler`'s ticks through
 the REAL keyed-runtime planner (``magi_attn_flex_key`` /
-``magi_attn_varlen_key``), so the plan-cache hit-rate the gate reads
-(``exps/run_plan_reuse_check.py``) is measured against genuine fleet
-traffic, not synthetic key sequences.
+``magi_attn_varlen_key``), so a plan-cache hit rate
+(``magi_plan_cache_hits`` / ``_misses``) can be read against a
+scheduler's own traffic, not synthetic key sequences.
 
 Shape policy (the serving layer's half of the reuse bargain):
 
@@ -62,8 +62,8 @@ class PlanProbeStats:
 class PlanReuseProbe:
     """Resolve real runtime keys for each scheduler tick's shapes.
 
-    Attach via ``Scheduler(engine, plan_probe=PlanReuseProbe())`` (or the
-    ``FleetSimulator(..., plan_probe=...)`` passthrough). Planning runs on
+    Attach via ``Scheduler(engine, plan_probe=PlanReuseProbe())``.
+    Planning runs on
     a private 1-device CPU mesh — it exercises the full solver + cache
     stack without touching the serving engine's device state, and works
     under the stubbed device layer the serving tests use (the stub patches

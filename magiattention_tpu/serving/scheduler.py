@@ -208,11 +208,6 @@ class Scheduler:
         self.token_budget = int(token_budget)
         self.chunk = int(chunk) if chunk is not None else env.prefill_chunk()
         self.max_decode_batch = max_decode_batch
-        # runtime-retunable admission watermark (ISSUE 19): EXTRA free
-        # pages an evictionless admission must leave beyond the base
-        # decode-growth headroom — the autopilot raises it to shed load
-        # before pool exhaustion, lowers it to admit harder
-        self.admission_watermark = 0
         self._clock = clock
         self._queue: list[RequestState] = []
         self._active: dict[int, RequestState] = {}  # rid -> state
@@ -400,123 +395,11 @@ class Scheduler:
 
     def _admission_headroom(self) -> int:
         """Free pages an admission must leave for decode growth: one
-        per decoding sequence sharing THIS allocator's pool, plus the
-        runtime ``admission_watermark`` knob (ISSUE 19). The
-        TieredScheduler overrides the base term to 0 — its decode pools
+        per decoding sequence sharing THIS allocator's pool. The
+        TieredScheduler overrides it to 0 — its decode pools
         live on the replicas, disjoint from the admission-facing
         prefill pool — and skips the decode-state scan entirely."""
-        return len(self._decode_states()) + self.admission_watermark
-
-    # -- runtime knobs (ISSUE 19) ----------------------------------------
-
-    # the knob catalog the autopilot may retune between ticks; each
-    # subclass extends _KNOB_NAMES and _coerce_knob/_set_knob for its
-    # extra knobs. Every knob is host state consulted fresh each tick —
-    # no retrace, no plan rebuild.
-    _KNOB_NAMES: tuple[str, ...] = (
-        "token_budget",
-        "chunk",
-        "max_decode_batch",
-        "admission_watermark",
-        "mem_pressure_threshold",
-        "cascade",
-        "decode_splits",
-    )
-
-    def knobs(self) -> dict:
-        """Current value of every runtime-retunable knob."""
-        return {
-            "token_budget": self.token_budget,
-            "chunk": self.chunk,
-            "max_decode_batch": self.max_decode_batch,
-            "admission_watermark": self.admission_watermark,
-            "mem_pressure_threshold": self._mem_watcher.threshold,
-            "cascade": getattr(
-                self._knob_engines()[0], "cascade_override", None
-            ),
-            "decode_splits": getattr(
-                self._knob_engines()[0], "decode_splits_override", None
-            ),
-        }
-
-    def apply_knobs(self, **updates) -> dict:
-        """Retune live knobs between ticks (the fleet autopilot's write
-        surface, ISSUE 19). Validates EVERY update first, then applies
-        atomically — a bad value changes nothing. Returns the coerced
-        ``{knob: new_value}`` map actually applied. Unknown knob names
-        raise ``ValueError`` listing the catalog."""
-        staged = {}
-        for name, value in updates.items():
-            if name not in self._KNOB_NAMES:
-                raise ValueError(
-                    f"unknown scheduler knob {name!r}; retunable knobs "
-                    f"are {sorted(self._KNOB_NAMES)}"
-                )
-            staged[name] = self._coerce_knob(name, value)
-        for name, value in staged.items():
-            self._set_knob(name, value)
-        return staged
-
-    def _knob_engines(self):
-        """The engines the cascade/decode-splits knobs write through
-        (the TieredScheduler fans out to prefill + every replica)."""
-        return [self.engine]
-
-    def _coerce_knob(self, name: str, value):
-        from .. import env as env_mod
-
-        if name in ("token_budget",):
-            v = int(value)
-            if v < 1:
-                raise ValueError(f"knob {name}={value!r} must be >= 1")
-            return v
-        if name in ("chunk", "max_decode_batch", "decode_splits"):
-            if value is None:
-                return None
-            v = int(value)
-            if v < 1:
-                raise ValueError(
-                    f"knob {name}={value!r} must be >= 1 (or None)"
-                )
-            return v
-        if name == "admission_watermark":
-            v = int(value)
-            if v < 0:
-                raise ValueError(
-                    f"knob admission_watermark={value!r} must be >= 0"
-                )
-            return v
-        if name == "mem_pressure_threshold":
-            v = float(value)
-            if not 0.0 <= v <= 1.0:
-                raise ValueError(
-                    f"knob mem_pressure_threshold={value!r} must be in "
-                    "[0, 1] (a free-page fraction; 0 disables)"
-                )
-            return v
-        if name == "cascade":
-            if value is None:
-                return None
-            v = str(value).strip().lower()
-            if v not in env_mod.CASCADE_MODES:
-                raise ValueError(
-                    f"knob cascade={value!r} must be one of "
-                    f"{env_mod.CASCADE_MODES} (or None = env)"
-                )
-            return v
-        raise ValueError(f"unknown scheduler knob {name!r}")
-
-    def _set_knob(self, name: str, value) -> None:
-        if name == "mem_pressure_threshold":
-            self._mem_watcher.threshold = value
-        elif name == "cascade":
-            for eng in self._knob_engines():
-                eng.cascade_override = value
-        elif name == "decode_splits":
-            for eng in self._knob_engines():
-                eng.decode_splits_override = value
-        else:
-            setattr(self, name, value)
+        return len(self._decode_states())
 
     def _handle_eviction(self, slot: int) -> None:
         """A live sequence was priority-evicted by the engine: push its

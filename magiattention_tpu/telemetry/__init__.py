@@ -41,17 +41,14 @@ from .collectors import (  # noqa: F401
     REQUIRED_ANALYSIS_METRICS,
     REQUIRED_COMPILE_METRICS,
     REQUIRED_DISTSERVE_METRICS,
-    REQUIRED_FLEET_METRICS,
     REQUIRED_MEMORY_METRICS,
     REQUIRED_NUMERICS_METRICS,
     REQUIRED_PLAN_CACHE_METRICS,
     REQUIRED_PLAN_METRICS,
     REQUIRED_PREFIX_METRICS,
     REQUIRED_RESILIENCE_METRICS,
-    REQUIRED_ROOFLINE_METRICS,
     REQUIRED_SCHED_METRICS,
     REQUIRED_SERVING_METRICS,
-    REQUIRED_TIMELINE_METRICS,
     REQUIRED_TRACE_METRICS,
     REQUIRED_VALIDATE_METRICS,
     record_admission,
@@ -95,7 +92,6 @@ from .collectors import (  # noqa: F401
     record_guard_violation,
     record_hbm_sample,
     record_kvcache_state,
-    record_measured_timeline,
     record_memory_comparison,
     record_memory_ledger,
     record_memory_measurement,
@@ -113,17 +109,10 @@ from .collectors import (  # noqa: F401
     record_prefix_eviction,
     record_prefix_lookup,
     record_prefix_registered,
-    record_roofline,
     record_request_queue_time,
     record_request_token_latency,
     record_request_ttft,
     record_runtime_costs,
-    record_fleet_autopilot_action,
-    record_fleet_autopilot_hold,
-    record_fleet_finished,
-    record_fleet_knob,
-    record_fleet_offered,
-    record_fleet_window,
     record_sched_step,
     record_shadow_check,
     record_stream_queue_depth,
@@ -179,10 +168,6 @@ from .trace import (  # noqa: F401
     reset_flight_recorder,
     reset_request_traces,
 )
-from .occupancy import (  # noqa: F401
-    BlockOccupancyMap,
-    block_occupancy_map,
-)
 from .memory import (  # noqa: F401
     LedgerEntry,
     MemoryComparison,
@@ -198,12 +183,6 @@ from .memory import (  # noqa: F401
     serving_memory_ledger,
     tiered_memory_ledger,
 )
-from .roofline import (  # noqa: F401
-    RooflineReport,
-    analyze_workload,
-    profile_roofline,
-    resolve_peak_tflops,
-)
 from .numerics import (  # noqa: F401
     DEFAULT_BUDGETS,
     DivergenceReport,
@@ -217,13 +196,6 @@ from .numerics import (  # noqa: F401
     nudge_ulps,
     reset_numerics_census,
     ulp_distance,
-)
-from .timeline import (  # noqa: F401
-    HopTiming,
-    MeasuredTimeline,
-    StageTiming,
-    profile_key_timeline,
-    profile_plan_timeline,
 )
 from .logger import configure_logging, get_logger  # noqa: F401
 from .registry import (  # noqa: F401
@@ -290,13 +262,10 @@ def dump_events(path: str) -> str:
 
 
 __all__ = [
-    "BlockOccupancyMap",
     "CompileTracker",
     "EventBuffer",
     "FlightRecorder",
-    "HopTiming",
     "LedgerEntry",
-    "MeasuredTimeline",
     "MemPressureWatcher",
     "MemoryComparison",
     "MemoryLedger",
@@ -310,27 +279,20 @@ __all__ = [
     "PoolFragmentationMap",
     "REQUIRED_ANALYSIS_METRICS",
     "REQUIRED_COMPILE_METRICS",
-    "REQUIRED_FLEET_METRICS",
     "REQUIRED_MEMORY_METRICS",
     "REQUIRED_NUMERICS_METRICS",
     "REQUIRED_PLAN_METRICS",
     "REQUIRED_RESILIENCE_METRICS",
-    "REQUIRED_ROOFLINE_METRICS",
     "REQUIRED_SERVING_METRICS",
-    "REQUIRED_TIMELINE_METRICS",
     "REQUIRED_TRACE_METRICS",
     "REQUIRED_VALIDATE_METRICS",
     "RequestTrace",
-    "RooflineReport",
-    "StageTiming",
     "add_solver_seconds",
     "aggregate_across_mesh",
     "annotate_span",
-    "analyze_workload",
     "assert_within_budget",
     "budget_for_dtype",
     "divergence_report",
-    "block_occupancy_map",
     "configure_logging",
     "current_program",
     "decode_program_label",
@@ -359,9 +321,6 @@ __all__ = [
     "plan_memory_ledger",
     "post_boot_spans",
     "prefill_program_label",
-    "profile_key_timeline",
-    "profile_plan_timeline",
-    "profile_roofline",
     "program",
     "record_admission",
     "record_admission_watermark",
@@ -403,7 +362,6 @@ __all__ = [
     "record_guard_repair",
     "record_guard_violation",
     "record_hbm_sample",
-    "record_measured_timeline",
     "record_memory_comparison",
     "record_memory_ledger",
     "record_memory_measurement",
@@ -416,14 +374,7 @@ __all__ = [
     "record_plan_cache_eviction",
     "record_plan_incremental",
     "record_plan_solver",
-    "record_fleet_autopilot_action",
-    "record_fleet_autopilot_hold",
-    "record_fleet_finished",
-    "record_fleet_knob",
-    "record_fleet_offered",
-    "record_fleet_window",
     "record_prefill",
-    "record_roofline",
     "record_request_span",
     "record_runtime_costs",
     "record_tick_programs",
@@ -435,7 +386,6 @@ __all__ = [
     "reset_flight_recorder",
     "reset_numerics_census",
     "reset_request_traces",
-    "resolve_peak_tflops",
     "record_tuning_cache_io_error",
     "ulp_distance",
     "record_validate",

@@ -232,8 +232,9 @@ def merge_chrome_traces(
     ``{"traceEvents": [...]}`` payload ``dump_events`` writes or a bare
     event list. Rank-local metadata events are dropped and re-emitted
     against the remapped pids — with the rank's own ``thread_name``
-    labels preserved, so named synthetic tracks (the per-hop comm spans)
-    stay one distinctly-named track per rank x hop after the merge.
+    labels preserved, so named synthetic tracks (``EventBuffer.record``'s
+    ``track=``) stay one distinctly-named track per rank x name after
+    the merge.
     """
     from .events import trace_metadata_events
 

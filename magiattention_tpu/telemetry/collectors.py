@@ -222,42 +222,6 @@ M_MHC_STREAM_BYTES = "magi_mhc_stream_bytes"
 # (models/pattern._mhc_coef; a compiled step counts each half-layer once)
 M_MHC_COEF_HALVES = "magi_mhc_coef_halves"
 
-# gauges — measured stage timelines (telemetry/timeline.py): what the
-# hardware actually did, next to what the overlap solver predicted
-M_TL_MEASURED_TOTAL_MS = "magi_overlap_measured_total_ms"  # pipelined e2e
-M_TL_SERIAL_MS = "magi_overlap_measured_serial_ms"  # sum of fenced pieces
-M_TL_COMM_MS = "magi_overlap_measured_comm_ms"  # {stage=}
-M_TL_CALC_MS = "magi_overlap_measured_calc_ms"  # {stage=} incl stage=host
-# fraction of hideable stage-cast time the schedule actually hid [0, 1]
-M_TL_EFFICIENCY = "magi_overlap_measured_efficiency"
-M_TL_PREDICTED_MS = "magi_overlap_predicted_total_ms"  # solver's model
-M_TL_PRED_ERROR = "magi_overlap_prediction_error_ratio"  # measured/pred
-
-# gauges — mask-aware roofline profiler (telemetry/roofline.py; see
-# docs/observability.md "Roofline & occupancy"): true-vs-scheduled FLOPs
-# accounting and the waste decomposition of the measured-vs-peak gap.
-# Measured TF/s are on the mask-FLOPs convention; the peak comes from
-# the per-backend/per-generation table (MAGI_ATTENTION_PEAK_TFLOPS
-# overrides)
-M_ROOF_PEAK = "magi_roofline_peak_tflops"
-M_ROOF_ACHIEVED = "magi_roofline_achieved_tflops"
-M_ROOF_EFFICIENCY = "magi_roofline_efficiency"  # achieved / peak [0, ~1]
-M_ROOF_MASK_FLOPS = "magi_roofline_mask_flops"  # true (A)
-M_ROOF_SCHED_FLOPS = "magi_roofline_scheduled_flops"  # tile-granular (B)
-M_ROOF_DENSITY = "magi_roofline_mask_density"  # A / dense Sq*Sk
-# gap attribution fractions (of measured - ideal; modeled when no
-# measurement): dead grid slots, block-quantization padding, in-tile
-# masked-entry overcompute — plus the live-step fee and the honest
-# unattributed residual in the snapshot via the same labels
-M_ROOF_DEAD_FRAC = "magi_roofline_dead_step_fraction"
-M_ROOF_PARTIAL_FRAC = "magi_roofline_partial_tile_fraction"
-M_ROOF_MASKED_FRAC = "magi_roofline_masked_overcompute_fraction"
-# per-hop comm attribution (telemetry/timeline.py): wall ms of each hop
-# of a hop-scheduled group cast, timed as its own jitted program —
-# {hop=<shift|inter|intra>, axis=<mesh axis>, stage=} so the DCN-aware
-# two-axis pricing (ROADMAP item 3) lands against measured hop costs
-M_HOP_MS = "magi_hop_ms"
-
 # counters + gauges — serving subsystem (serving/; see docs/serving.md).
 # decode layer: per continuous-batching step
 M_DECODE_STEPS = "magi_decode_steps_total"
@@ -466,35 +430,6 @@ REQUIRED_PLAN_CACHE_METRICS: tuple[str, ...] = (
     M_PLAN_CACHE_MISSES,
 )
 
-# populated by one profile_plan_timeline run (telemetry/timeline.py);
-# asserted by make telemetry-check's timeline step, documented in
-# docs/observability.md "Measured timelines & overlap audit"
-REQUIRED_TIMELINE_METRICS: tuple[str, ...] = (
-    M_TL_MEASURED_TOTAL_MS,
-    M_TL_SERIAL_MS,
-    M_TL_COMM_MS,
-    M_TL_CALC_MS,
-    M_TL_EFFICIENCY,
-    M_TL_PREDICTED_MS,
-    M_TL_PRED_ERROR,
-)
-
-# populated by one record_roofline with a measured rate (a real profile
-# through profile_roofline / the plan-timeline driver); asserted by
-# make roofline-check (exps/run_roofline_check.py), documented in
-# docs/observability.md "Roofline & occupancy"
-REQUIRED_ROOFLINE_METRICS: tuple[str, ...] = (
-    M_ROOF_PEAK,
-    M_ROOF_ACHIEVED,
-    M_ROOF_EFFICIENCY,
-    M_ROOF_MASK_FLOPS,
-    M_ROOF_SCHED_FLOPS,
-    M_ROOF_DENSITY,
-    M_ROOF_DEAD_FRAC,
-    M_ROOF_PARTIAL_FRAC,
-    M_ROOF_MASKED_FRAC,
-)
-
 # populated by one prefill + one ServingEngine decode step; asserted by
 # make telemetry-check's serving step and make serving-check, documented
 # in docs/observability.md "Serving metrics" + docs/serving.md
@@ -685,56 +620,6 @@ REQUIRED_NUMERICS_METRICS: tuple[str, ...] = (
     H_NUMERICS_SHADOW_DIVERGENCE,
     M_NUMERICS_SHADOW_BREACHES,
 )
-
-# counters + gauges + histograms — fleet simulation & autopilot
-# (fleet/; ISSUE 19). The fleet layer runs on a LOGICAL tick clock, so
-# the latency histograms are in ticks, not seconds, and "QPS" figures
-# are requests per tick window (snapshot_delta's counters_per_s over a
-# tick-denominated window). offered counts arrivals the trace presented
-# (whether or not admission took them), served counts requests that
-# FINISHED; the gap between the two rates is shed load. goodput counts
-# only the tokens of requests that finished inside their SLO — the
-# figure the autopilot maximizes. autopilot actions are labelled
-# {knob=,direction=up|down}; holds are windows where the controller
-# deliberately did nothing ({reason=steady|cooldown|hysteresis|fault|
-# bounds|reversal}); knob gauges ({knob=}) expose the live value every
-# retune writes.
-M_FLEET_OFFERED = "magi_fleet_offered_requests_total"
-M_FLEET_SERVED = "magi_fleet_served_requests_total"
-M_FLEET_SLO_OK = "magi_fleet_slo_ok_total"  # finished inside SLO
-M_FLEET_SLO_ATTAINMENT = "magi_fleet_slo_attainment"  # gauge 0..1 window
-M_FLEET_GOODPUT = "magi_fleet_goodput_tokens_total"
-M_FLEET_CONCURRENT = "magi_fleet_concurrent_requests"  # gauge: in flight
-M_FLEET_AUTOPILOT_ACTIONS = "magi_fleet_autopilot_actions_total"
-M_FLEET_AUTOPILOT_HOLDS = "magi_fleet_autopilot_holds_total"
-M_FLEET_KNOB = "magi_fleet_knob_value"  # gauge {knob=}
-H_FLEET_TTFT_TICKS = "magi_fleet_ttft_ticks"
-H_FLEET_TOKLAT_TICKS = "magi_fleet_token_latency_ticks"
-
-# tick-denominated latency bounds: a healthy fleet's TTFT sits in the
-# single-digit-tick buckets; a saturated one spills past the decade
-_FLEET_TICK_BOUNDS = (
-    1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0, 512.0,
-)
-
-# populated by one FleetSimulator.run() over any trace with the
-# autopilot attached; asserted by make fleet-check
-# (exps/run_fleet_check.py), documented in docs/fleet.md +
-# docs/observability.md "Fleet"
-REQUIRED_FLEET_METRICS: tuple[str, ...] = (
-    M_FLEET_OFFERED,
-    M_FLEET_SERVED,
-    M_FLEET_SLO_OK,
-    M_FLEET_SLO_ATTAINMENT,
-    M_FLEET_GOODPUT,
-    M_FLEET_CONCURRENT,
-    M_FLEET_AUTOPILOT_ACTIONS,
-    M_FLEET_AUTOPILOT_HOLDS,
-    M_FLEET_KNOB,
-    H_FLEET_TTFT_TICKS,
-    H_FLEET_TOKLAT_TICKS,
-)
-
 
 def record_numerics_census(
     layer: str, site: str, stats: dict
@@ -1000,91 +885,6 @@ def record_runtime_costs(
     reg.gauge_set(M_MODELED_CALC_S, plan.max_rank_area * calc_f)
     reg.gauge_set(
         M_MODELED_COMM_S, max(comm.recv_total, default=0) * comm_f
-    )
-
-
-def record_roofline(report) -> None:
-    """One mask-aware roofline analysis (``telemetry/roofline.py``
-    :class:`RooflineReport`): the true/scheduled FLOPs accounting, the
-    achieved fraction of peak (when a measurement exists), and the gap
-    attribution fractions — labeled with the workload name so sweeps
-    keep one series per workload."""
-    if not _enabled():
-        return
-    reg = get_registry()
-    w = report.workload
-    reg.gauge_set(M_ROOF_PEAK, report.peak_tflops, workload=w)
-    reg.gauge_set(M_ROOF_MASK_FLOPS, report.mask_flops, workload=w)
-    reg.gauge_set(M_ROOF_SCHED_FLOPS, report.scheduled_flops, workload=w)
-    reg.gauge_set(M_ROOF_DENSITY, report.mask_density, workload=w)
-    f = report.gap_fractions()
-    reg.gauge_set(M_ROOF_DEAD_FRAC, f["dead_steps"], workload=w)
-    reg.gauge_set(M_ROOF_PARTIAL_FRAC, f["partial_tile"], workload=w)
-    reg.gauge_set(M_ROOF_MASKED_FRAC, f["masked_overcompute"], workload=w)
-    if report.measured_tflops is not None:
-        reg.gauge_set(M_ROOF_ACHIEVED, report.measured_tflops, workload=w)
-        reg.gauge_set(M_ROOF_EFFICIENCY, report.efficiency, workload=w)
-    else:
-        # a measurement-less re-record must not leave an earlier run's
-        # achieved/efficiency paired with this run's fresh fractions
-        reg.clear_series(M_ROOF_ACHIEVED, workload=w)
-        reg.clear_series(M_ROOF_EFFICIENCY, workload=w)
-    _marker_event(
-        "roofline",
-        {
-            "workload": w,
-            "rung": f"{report.block_q}x{report.block_k}x{report.head_block}"
-            + (f":{report.grid}" if report.grid != "row_major" else ""),
-            "mask_density": report.mask_density,
-            "measured_tflops": report.measured_tflops,
-            "efficiency": report.efficiency,
-            "dominant_waste": report.dominant_waste,
-        },
-    )
-
-
-def record_measured_timeline(tl) -> None:
-    """One measured stage timeline (``telemetry/timeline.py``): per-stage
-    comm/calc wall time next to the solver's prediction, the pipelined
-    vs serial totals, and the achieved overlap efficiency — plus, for
-    hop-scheduled casts, the per-hop ``magi_hop_ms`` attribution.
-    Stage-labeled families are cleared first — a re-profile at a
-    different degree must not leave stale stage series behind."""
-    if not _enabled():
-        return
-    reg = get_registry()
-    reg.clear_metric(M_TL_COMM_MS)
-    reg.clear_metric(M_TL_CALC_MS)
-    reg.clear_metric(M_HOP_MS)
-    for ht in getattr(tl, "hops", ()):
-        reg.gauge_set(
-            M_HOP_MS, ht.ms, hop=ht.hop, axis=ht.axis, stage=ht.stage
-        )
-    for st in tl.stages:
-        if st.stage != "host":  # the host stage has no cast by definition
-            reg.gauge_set(M_TL_COMM_MS, st.comm_ms, stage=st.stage)
-        reg.gauge_set(M_TL_CALC_MS, st.calc_ms, stage=st.stage)
-    reg.gauge_set(M_TL_MEASURED_TOTAL_MS, tl.measured_total_ms)
-    reg.gauge_set(M_TL_SERIAL_MS, tl.serial_total_ms)
-    reg.gauge_set(M_TL_EFFICIENCY, tl.overlap_efficiency)
-    # predicted gauges clear-then-set: a re-profile whose prediction could
-    # not be priced must not pair fresh measured numbers with a stale
-    # prediction from an earlier plan
-    reg.clear_metric(M_TL_PREDICTED_MS)
-    reg.clear_metric(M_TL_PRED_ERROR)
-    if tl.predicted_total_ms is not None:
-        reg.gauge_set(M_TL_PREDICTED_MS, tl.predicted_total_ms)
-    if tl.prediction_error_ratio is not None:
-        reg.gauge_set(M_TL_PRED_ERROR, tl.prediction_error_ratio)
-    _marker_event(
-        "measured_timeline",
-        {
-            "overlap_degree": tl.overlap_degree,
-            "measured_total_ms": tl.measured_total_ms,
-            "serial_total_ms": tl.serial_total_ms,
-            "overlap_efficiency": tl.overlap_efficiency,
-            "predicted_total_ms": tl.predicted_total_ms,
-        },
     )
 
 
@@ -1954,77 +1754,6 @@ def record_tier_state(
     reg.gauge_set(M_TIER_ACTIVE, int(active), tier=tier)
 
 
-def record_fleet_offered(n: int = 1) -> None:
-    """``n`` trace arrivals presented to the fleet this tick (counted
-    whether or not admission accepted them — offered load)."""
-    if not _enabled():
-        return
-    get_registry().counter_inc(M_FLEET_OFFERED, int(n))
-
-
-def record_fleet_finished(
-    *, ttft_ticks: float, token_latency_ticks: float,
-    tokens: int, slo_ok: bool,
-) -> None:
-    """One request finished: served counter, tick-unit latency
-    histograms, and — only when it met its SLO — the slo-ok counter and
-    its tokens into goodput."""
-    if not _enabled():
-        return
-    reg = get_registry()
-    reg.counter_inc(M_FLEET_SERVED)
-    reg.histogram_observe(
-        H_FLEET_TTFT_TICKS, float(ttft_ticks), bounds=_FLEET_TICK_BOUNDS
-    )
-    reg.histogram_observe(
-        H_FLEET_TOKLAT_TICKS, float(token_latency_ticks),
-        bounds=_FLEET_TICK_BOUNDS,
-    )
-    if slo_ok:
-        reg.counter_inc(M_FLEET_SLO_OK)
-        reg.counter_inc(M_FLEET_GOODPUT, int(tokens))
-
-
-def record_fleet_window(
-    *, slo_attainment: float, concurrent: int
-) -> None:
-    """End of one autopilot window: the window's SLO attainment (of the
-    requests that finished in it) and the in-flight request count."""
-    if not _enabled():
-        return
-    reg = get_registry()
-    reg.gauge_set(M_FLEET_SLO_ATTAINMENT, float(slo_attainment))
-    reg.gauge_set(M_FLEET_CONCURRENT, int(concurrent))
-
-
-def record_fleet_autopilot_action(
-    knob: str, direction: str, value: float
-) -> None:
-    """The autopilot retuned one knob (``direction`` up|down) to
-    ``value`` — action counter + live knob gauge."""
-    if not _enabled():
-        return
-    reg = get_registry()
-    reg.counter_inc(M_FLEET_AUTOPILOT_ACTIONS, knob=knob,
-                    direction=direction)
-    reg.gauge_set(M_FLEET_KNOB, float(value), knob=knob)
-
-
-def record_fleet_autopilot_hold(reason: str) -> None:
-    """The autopilot evaluated a window and deliberately did NOT act
-    (``reason``: steady|cooldown|hysteresis|fault|bounds|reversal)."""
-    if not _enabled():
-        return
-    get_registry().counter_inc(M_FLEET_AUTOPILOT_HOLDS, reason=reason)
-
-
-def record_fleet_knob(knob: str, value: float) -> None:
-    """Seed/refresh a knob gauge without an action (initial values)."""
-    if not _enabled():
-        return
-    get_registry().gauge_set(M_FLEET_KNOB, float(value), knob=knob)
-
-
 # ---------------------------------------------------------------------------
 # summaries
 # ---------------------------------------------------------------------------
@@ -2095,34 +1824,6 @@ def telemetry_summary(snapshot: dict | None = None) -> str:
             f"predicted {fmt(g.get(M_AUTOTUNE_PREDICTED_MS))} ms  "
             f"cache hits/misses: {fmt(hits)}/"
             f"{fmt(c.get(M_AUTOTUNE_CACHE_MISSES, 0))}"
-        )
-    # one line per profiled workload: achieved % of peak + the dead-step
-    # share of the gap (the satellite's headline pair). Keyed on the
-    # peak gauge, which record_roofline ALWAYS sets — a static analysis
-    # (no measurement, so no efficiency gauge) still gets its line
-    roof_keys = [k for k in g if k.startswith(M_ROOF_PEAK + "{")]
-    if g.get(M_ROOF_PEAK) is not None:
-        roof_keys.append(M_ROOF_PEAK)
-    for key in sorted(roof_keys):
-        labels = key[len(M_ROOF_PEAK):]
-        eff = g.get(M_ROOF_EFFICIENCY + labels)
-        achieved = (
-            f"achieved {eff:.1%} of" if eff is not None else "modeled vs"
-        )
-        lines.append(
-            f"  roofline probe{labels or ''}: {achieved} "
-            f"{fmt(g.get(key))} TF/s peak "
-            f"({fmt(g.get(M_ROOF_ACHIEVED + labels))} TF/s), "
-            f"dead-step fraction "
-            f"{fmt(g.get(M_ROOF_DEAD_FRAC + labels))}, "
-            f"density {fmt(g.get(M_ROOF_DENSITY + labels))}"
-        )
-    if g.get(M_TL_MEASURED_TOTAL_MS) is not None:
-        lines.append(
-            f"  measured overlap: e2e {fmt(g.get(M_TL_MEASURED_TOTAL_MS))} ms"
-            f"  serial {fmt(g.get(M_TL_SERIAL_MS))} ms"
-            f"  efficiency {fmt(g.get(M_TL_EFFICIENCY))}"
-            f"  predicted {fmt(g.get(M_TL_PREDICTED_MS))} ms"
         )
     if c.get(M_DECODE_STEPS):
         lines.append(

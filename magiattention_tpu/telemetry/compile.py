@@ -1,9 +1,8 @@
 """Program observability: the compile tracker (ISSUE 16 tentpole).
 
-The observability stack explains time (measured timelines), FLOPs
-(roofline), requests (traces) and bytes (memory ledger) — this module is
-the fifth pillar: *programs*. It answers three questions no other layer
-can:
+The observability stack explains requests (traces) and bytes (memory
+ledger) — this module explains *programs*. It answers three questions no
+other layer can:
 
 - **How many distinct XLA executables does this process build, and how
   expensive are they?** A process-wide :class:`CompileTracker` ingests

@@ -66,9 +66,9 @@ class EventBuffer:
 
     Spans land on the recording thread's track by default; ``track=``
     puts a span on a named synthetic track instead (a small stable tid +
-    a ``thread_name`` metadata event at dump time) — how the per-hop
-    comm timeline gets one Perfetto track per hop instead of burying
-    every measurement on the host thread."""
+    a ``thread_name`` metadata event at dump time) — how the scheduler's
+    tick decomposition gets a Perfetto track of its own instead of
+    burying every span on the host thread."""
 
     def __init__(self, maxlen: int = 4096):
         self._lock = threading.Lock()

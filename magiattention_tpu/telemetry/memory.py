@@ -1,16 +1,15 @@
 """Memory observability: HBM ledger, measured confirmation, pool forensics.
 
-The fourth observability pillar (ISSUE 14): the stack can explain *time*
-(``timeline.py``), *FLOPs* (``roofline.py``) and *requests*
-(``trace.py``) — this module explains *bytes*, in the same
-measured-vs-modeled discipline the roofline established:
+ISSUE 14: the stack can explain *requests* (``trace.py``) and
+*programs* (``compile.py``) — this module explains *bytes*, a modeled
+ledger next to what XLA reports:
 
 1. **Static memory ledger** (:func:`plan_memory_ledger` /
    :func:`serving_memory_ledger` / :func:`tiered_memory_ledger`): price
    a :class:`~..parallel.dist_attn.DistAttnPlan` or a serving
    configuration from the structures that already exist — per-stage comm
    buffers from the comm meta's ``scheduled_rows_per_rank`` (the SAME
-   accounting the solver and the timeline predictor price), kernel
+   accounting the solver prices), kernel
    partials/LSE scratch per stage, page-pool bytes split
    live/trie-resident/free (CoW-shared pages counted once — the memory
    win the refcounts buy), decode split partials. The result is a
@@ -30,8 +29,8 @@ measured-vs-modeled discipline the roofline established:
 
 3. **Pool forensics** (:func:`fragmentation_map` /
    :class:`PoolFragmentationMap` / :class:`MemPressureWatcher`):
-   per-pool page-state maps (ASCII heatmap + JSON dump/load, in the
-   ``occupancy.py`` artifact style), a fragmentation ratio defined as
+   per-pool page-state maps (ASCII heatmap + JSON dump/load), a
+   fragmentation ratio defined as
    the unusable-free-run fraction at the current reservation
    granularity, allocator high-water marks, and the OOM-forensics
    triggers — ``pool_exhausted`` admissions, rejection storms and
@@ -187,7 +186,7 @@ def plan_memory_ledger(
     buffer is ``comm.scheduled_rows_per_rank`` rows — the rows the
     selected impl actually schedules on the wire (NOT the true-row
     lower bound, NOT the legacy global pad), exactly the figure the
-    auto-degree search and the timeline predictor price stages with —
+    auto-degree search prices stages with —
     times the K+V row bytes. Kernel scratch is the per-stage partial
     ``(out, lse)`` pair the LSE-merge tree folds, in the accumulation
     dtype (``acc_bytes``).
@@ -201,8 +200,7 @@ def plan_memory_ledger(
     self-attention plans (the KV shard is the same dispatched token
     shard). Cross-attention plans, or callers whose KV shard length
     differs from the padded Q shard, MUST pass the real per-rank KV
-    length or ``operand_kv`` is mispriced (the same ``shard_k_len``
-    convention as ``profile_plan_timeline``).
+    length or ``operand_kv`` is mispriced.
     """
     sq = int(plan.shard_q_pad)
     sk = int(shard_k_len if shard_k_len is not None else plan.shard_q_pad)

@@ -207,16 +207,6 @@ class MetricsRegistry:
                 },
             }
 
-    def clear_series(self, name: str, **labels) -> None:
-        """Drop ONE labeled series of a metric (exact label match) —
-        for collectors that re-record a single workload's family and
-        must not leave a stale member behind without wiping the other
-        workloads' series (contrast :meth:`clear_metric`)."""
-        key = series_key(name, labels)
-        with self._lock:
-            for d in (self._counters, self._gauges, self._histograms):
-                d.pop(key, None)
-
     def clear_metric(self, name: str) -> None:
         """Drop every series of one metric (bare and labeled). Collectors
         use this before re-recording per-rank families whose label set can

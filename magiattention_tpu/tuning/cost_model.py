@@ -386,10 +386,8 @@ def slice_block_k_spans(
     """Per-q-block attended k-intervals of ONE slice: (q_block_idx,
     row_lo, row_hi, k_lo, k_hi) vectors, mask-type-aware — the same
     affine spans ``block_meta._slice_k_span`` emits. Blocks whose span is
-    empty have ``k_hi <= k_lo``. THE single counting primitive shared by
-    the autotuner's entry estimator and the roofline/occupancy profiler
-    (``telemetry/roofline.py``, ``telemetry/occupancy.py``) so the two
-    can never disagree about what the kernel schedules."""
+    empty have ``k_hi <= k_lo``. The counting primitive of the
+    autotuner's entry estimator."""
     idx = np.arange(q0 // block_q, _cdiv(q1, block_q), dtype=np.int64)
     lo = np.maximum(q0, idx * block_q)  # first row (inclusive)
     hi = np.minimum(q1, (idx + 1) * block_q)  # last row (exclusive)
@@ -436,10 +434,10 @@ def _estimate_entries_impl(
 
 def exact_mask_area(q_ranges, k_ranges, attn_type_map) -> int:
     """EXACT valid-entry count of the mask (row-wise, vectorized numpy —
-    O(total q rows) per slice, host planning scale). This is the area the
-    true-FLOPs side of the roofline divides by; memoized on the canonical
-    slice digest like the entry counts (the profiler and the bench
-    density field hit the same workloads repeatedly).
+    O(total q rows) per slice, host planning scale). This is the area a
+    mask's true FLOPs are counted from; memoized on the canonical
+    slice digest like the entry counts (the ranker's density test hits
+    the same workloads repeatedly).
 
     Summed PER SLICE — the kernel's own work convention (every slice's
     entries run through the softmax; the runtime rejects masks whose

@@ -1,4 +1,4 @@
-"""Benchmark harness: do_bench, mesh barrier and memory recorder sanity."""
+"""Benchmark harness: do_bench and memory recorder sanity."""
 
 import jax.numpy as jnp
 
@@ -11,27 +11,6 @@ def test_do_bench_times_and_memory():
     r = do_bench(f, x, warmup=1, rep=3, inner=2, record_memory=True)
     assert r.min_ms <= r.median_ms <= r.max_ms
     assert r.tflops(1e9) > 0
-
-
-def test_mesh_barrier_and_synced_bench():
-    """mesh_barrier rendezvouses the 8-device mesh; do_bench(mesh=...)
-    still produces sane timings through the barrier."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from magiattention_tpu.benchmarking import do_bench, mesh_barrier
-
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("a", "b"))
-    mesh_barrier(mesh)  # must not deadlock or crash
-
-    sh = NamedSharding(mesh, P("a"))
-    x = jax.device_put(jnp.ones((16, 8)), sh)
-    f = jax.jit(lambda x: x * 2.0)
-    res = do_bench(f, x, warmup=1, rep=2, inner=2, mesh=mesh)
-    assert res.median_ms > 0
-    assert res.reps == 2
 
 
 def test_memory_recorder_graceful_on_cpu():

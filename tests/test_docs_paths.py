@@ -5,7 +5,7 @@ every path it names that ends in ``.py``, ``.json``, ``.jsonl`` or
 ``.md`` (so every ``exps/run_*.py``), every script of a ``python <file>``
 command and every ``make <target>`` exists in the tree / the Makefile.
 A path is ours when its first segment is a top-level entry of this repo
-or of the package (``telemetry/roofline.py``); the reference's
+or of the package (``telemetry/collectors.py``); the reference's
 ``magi_attention/...`` and ``cp_benchmark.md:...`` citations are not.
 """
 

@@ -6,12 +6,8 @@ grid == the row-major grid == the dense reference — for fwd
 out/lse/max-logits AND grads, on both kernel backends
 (pallas-interpret and the jnp dense reference). Plus:
 
-- ``BlockEnumeration`` (flex entry tables, occupancy lists, decode
-  block tables all walk through ONE primitive), with the
-  occupancy-driven enumeration checked against a brute-force dense
-  block scan of the mask,
-- ``build_block_meta_from_occupancy``: the occupancy artifact's shape
-  rebuilds the exact kernel plan ``build_block_meta`` emits.
+- ``BlockEnumeration`` (flex entry tables and decode block tables walk
+  through ONE primitive).
 """
 
 import jax
@@ -21,11 +17,8 @@ import pytest
 
 from magiattention_tpu.ops import (
     BlockEnumeration,
-    build_block_meta,
-    build_block_meta_from_occupancy,
     flex_flash_attn_func,
 )
-from magiattention_tpu.telemetry.occupancy import block_occupancy_map
 from magiattention_tpu.testing import assert_close, ref_attn_from_ranges
 
 
@@ -222,57 +215,6 @@ def test_bad_grid_value_raises():
 # ---------------------------------------------------------------------------
 
 
-def _brute_force_pairs(qr, kr, ts, total, bq, bk):
-    """Dense-mask block scan: the oracle the occupancy-driven
-    enumeration must match."""
-    dense = np.zeros((total, total), bool)
-    for (q0, q1), (k0, k1), mt in zip(qr, kr, ts):
-        qi = np.arange(q0, q1)[:, None]
-        ki = np.arange(k0, k1)[None, :]
-        m = np.ones((q1 - q0, k1 - k0), bool)
-        if mt & 1:
-            m &= (ki - k1) <= (qi - q1)
-        if mt & 2:
-            m &= (ki - k0) >= (qi - q0)
-        dense[q0:q1, k0:k1] |= m
-    nq, nk = -(-total // bq), -(-total // bk)
-    pairs = set()
-    for i in range(nq):
-        for j in range(nk):
-            if dense[i * bq : (i + 1) * bq, j * bk : (j + 1) * bk].any():
-                pairs.add((i, j))
-    return pairs
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_occupancy_enumeration_matches_brute_force(seed):
-    """occupancy-map-driven enumeration == brute-force dense block scan
-    on random slice lists (the satellite's oracle)."""
-    rng = np.random.default_rng(seed)
-    total = 512
-    slices = []
-    start = 0
-    while start < total:
-        ln = int(rng.integers(32, 160))
-        end = min(start + ln, total)
-        mt = int(rng.choice([0, 1, 2]))
-        k0 = int(rng.integers(0, max(end - 16, 1)))
-        slices.append((start, end, k0, end, mt))
-        start = end
-    qr, kr, ts = _split(slices)
-    bq, bk = int(rng.choice([32, 64, 128])), int(rng.choice([64, 128]))
-    occ = block_occupancy_map(qr, kr, ts, bq, bk)
-    enum = occ.to_enumeration()
-    got = {(int(a), int(b)) for a, b in enum.occupied_pairs()}
-    assert got == _brute_force_pairs(qr, kr, ts, total, bq, bk)
-    # row tables agree with the flattened walk
-    for i in range(enum.num_rows):
-        rs, rc = int(enum.row_start[i]), int(enum.row_count[i])
-        assert sorted(occ.active[i]) == [
-            int(m) for m in np.asarray(enum.minor[rs : rs + rc])
-        ]
-
-
 def test_enumeration_from_block_table_matches_flat_indexing():
     """The decode walk: clamped lookup over a block table == the direct
     ``b * mpp + s * pps + p`` flat indexing it replaced."""
@@ -298,34 +240,14 @@ def test_enumeration_from_block_table_rejects_bad_splits():
 
 
 def test_enumeration_clamps_past_row_end():
-    enum = BlockEnumeration.from_active_lists([[3, 5], [], [7]])
+    enum = BlockEnumeration.from_sorted(
+        np.array([0, 0, 2], np.int32), np.array([3, 5, 7], np.int32), 3
+    )
     assert enum.num_rows == 3 and enum.num_entries == 3
     # step past the row count clamps to the last live entry
     assert int(enum.entry(0, 5)) == 1
     # empty rows have count 0 and clamp onto their (empty) start
     assert int(enum.row_count[1]) == 0
-
-
-def test_build_block_meta_from_occupancy_matches_direct_build():
-    """The committed occupancy artifact's shape rebuilds the EXACT
-    kernel plan the slice-driven builder emits."""
-    slices = _block_causal(768, 5, 6)
-    qr, kr, ts = _split(slices)
-    for bq, bk in ((64, 128), (128, 128)):
-        occ = block_occupancy_map(qr, kr, ts, bq, bk)
-        direct = build_block_meta(qr, kr, ts, 768, 768, block_q=bq, block_k=bk)
-        via_occ = build_block_meta_from_occupancy(
-            occ.as_json(), qr, kr, ts, 768, 768
-        )
-        for f in (
-            "fwd_q_block", "fwd_k_block", "fwd_slice_id", "fwd_runs",
-            "bwd_k_block", "bwd_q_block", "bwd_slice_id", "bwd_runs",
-            "slice_bounds",
-        ):
-            np.testing.assert_array_equal(
-                getattr(direct, f), getattr(via_occ, f), err_msg=f
-            )
-        assert direct.total_area == via_occ.total_area
 
 
 def test_row_major_pin_restricts_ranking_to_row_major_rungs():

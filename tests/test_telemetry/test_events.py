@@ -31,6 +31,41 @@ def test_dump_events_emits_track_metadata(tmp_path):
     assert [(e["pid"], e["tid"]) for e in thr] == [(pid, tid)]
 
 
+def _two_named_tracks(tmp_path):
+    buf = EventBuffer(maxlen=16)
+    buf.record("cast", 0.0, 0.1, {}, track="hop 1 (cp)")
+    buf.record("cast", 0.1, 0.1, {}, track="hop 2 (cp)")
+    buf.record("cast", 0.2, 0.1, {}, track="hop 1 (cp)")
+    with open(buf.dump(str(tmp_path / "tracks.json"))) as f:
+        return json.load(f)
+
+
+def _thread_names(trace):
+    return [
+        (e["pid"], e["args"]["name"])
+        for e in trace["traceEvents"]
+        if e.get("ph") == "M" and e["name"] == "thread_name"
+    ]
+
+
+def test_named_tracks_get_a_tid_and_a_name_each(tmp_path):
+    trace = _two_named_tracks(tmp_path)
+    names = sorted(n for _, n in _thread_names(trace))
+    assert names == ["hop 1 (cp)", "hop 2 (cp)"]
+    tids = [e["tid"] for e in trace["traceEvents"] if e.get("ph") == "X"]
+    assert tids[0] == tids[2] != tids[1]
+
+
+def test_merge_keeps_one_named_track_per_rank(tmp_path):
+    trace = _two_named_tracks(tmp_path)
+    merged = telemetry.merge_chrome_traces(
+        [trace, json.loads(json.dumps(trace))], labels=["r0", "r1"]
+    )
+    named = _thread_names(merged)
+    assert len(named) == len(set(named)) == 4
+    assert {pid for pid, _ in named} == {0, 1}
+
+
 def test_trace_metadata_events_ignores_existing_metadata():
     events = [
         {"name": "x", "ph": "X", "pid": 1, "tid": 2},

@@ -180,3 +180,13 @@ def test_estimate_percentiles_is_shared_helper():
     assert estimate_percentiles((1.0,), [0, 0], 0, 0.0, 0.0) == [
         None, None, None,
     ]
+
+
+def test_estimate_percentiles_survives_single_event_histograms():
+    """A one-sample histogram must report that sample for every
+    percentile, not interpolate into a bucket edge or divide by zero."""
+    from magiattention_tpu.telemetry.registry import estimate_percentiles
+
+    bounds = (1e-5, 1e-4, 1e-3, 1e-2)
+    p50, p95, p99 = estimate_percentiles(bounds, [0, 0, 1, 0, 0], 1, 3e-4, 3e-4)
+    assert p50 == p95 == p99 == pytest.approx(3e-4)

@@ -875,3 +875,35 @@ def test_the_pair_rule_leaves_the_fewest_steps_order_alone():
     ranked = rank_candidates(qr, kr, ts, 32, 8, generation="v5e")
     assert ranked[0].grid == "sparse"
     assert "priced_pair" not in {s.tie_order for s in ranked}
+
+
+def _disjoint_slices(seed, total=192):
+    """Random varlen-style slices with DISJOINT q ranges — the kernel's
+    no-(q,k)-overlap contract, under which per-slice area == the dense
+    union mask's popcount."""
+    rng = np.random.default_rng(seed)
+    cuts = np.sort(
+        rng.choice(np.arange(1, total), int(rng.integers(2, 6)),
+                   replace=False)
+    )
+    bounds = [0, *[int(c) for c in cuts], total]
+    qr, kr, ts = [], [], []
+    for a, b in zip(bounds, bounds[1:]):
+        c, d = sorted(rng.integers(0, total, 2).tolist())
+        if c == d:
+            continue
+        qr.append((a, b))
+        kr.append((c, d))
+        ts.append(int(rng.choice([0, 1, 2])))
+    return qr, kr, ts
+
+
+@pytest.mark.parametrize("seed", [0, 2, 5, 9])
+def test_exact_mask_area_matches_oracle(seed):
+    from magiattention_tpu.testing.ref_attn import make_attn_mask_from_ranges
+    from magiattention_tpu.tuning.cost_model import exact_mask_area
+
+    total = 192
+    qr, kr, ts = _disjoint_slices(seed, total)
+    mask = np.asarray(make_attn_mask_from_ranges(qr, kr, ts, total, total))
+    assert exact_mask_area(qr, kr, ts) == int(mask.sum())
