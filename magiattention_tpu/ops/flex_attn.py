@@ -92,9 +92,10 @@ def _flex_pallas_call(
     (``magi_flex_kernel_build_total{kernel=, heads_per_step=, grid=}``), so
     a snapshot says which of the per-head and head-batched forms ran, and
     on which of :data:`GRID_KINDS`. ``form``: the labels that say in which
-    form a side operand crosses this kernel's boundary (the forward's
-    ``stats=compact|lanes``, the backward's ``delta=xla`` and
-    ``dq=visits|zero_filled``; :func:`stats_form`, :func:`dq_form`), and
+    form a side operand crosses this kernel's boundary (``stats=compact|
+    lanes`` the forward's, :func:`stats_form`; the backward's is always
+    ``compact``, beside its ``delta=xla`` and ``dq=visits|zero_filled``,
+    :func:`dq_form`), and
     ``v_head_dim`` where v is not as wide as k."""
     from .. import telemetry
 
@@ -190,10 +191,9 @@ class FlexAttnParams:
     # the host beside bwd_steps (dq_form). None = not counted
     bwd_unnamed_q: int | None = None
     # the attention kind of a layer whose ``jax.checkpoint`` keeps this
-    # call's out and compact lse (models/_common.layer_under_remat, the
+    # call's out and lse [hq, tqp] (models/_common.layer_under_remat, the
     # only place that sets it, beside the policy that saves KEPT_NAMES);
-    # "": the call stands alone and its residual is the kernel's
-    # lane-replicated lse (_flex_attn_core_fwd)
+    # "": the call stands alone (the same residual, under no name)
     kept: str = ""
 
     @property
@@ -279,9 +279,11 @@ _BIG = 1 << 30
 
 
 def _entry_interval_mask(
-    bounds, runs, sid_e, e, row0, col0, bq, bk, stepped: bool = False
+    bounds, runs, sid_e, e, row0, col0, bq, bk, stepped: bool = False,
+    transposed: bool = False,
 ):
-    """Boolean [bq, bk] mask for one entry via per-row k-intervals.
+    """Boolean [bq, bk] mask for one entry via per-row k-intervals
+    ([bk, bq], keys along sublanes, under ``transposed``).
 
     Every mask condition an entry can impose — run window, slice bounds,
     causal (bit0), inv-causal (bit1) — is an affine k-interval in the row:
@@ -299,7 +301,37 @@ def _entry_interval_mask(
     and the block index counted from the aligned corner is one AND of the
     row column with ``-step``; no operand, no table. Off, the arithmetic
     below is what it was before steps existed, to the instruction.
+
+    ``transposed`` (static: the backward, whose logits are ``K Q^T``): the
+    same predicate with the q rows along lanes, so lo/hi are [1, bq] rows
+    (bq / 128 vregs where the columns are bq / 8) and the iota runs down
+    the sublanes. There the interval test is ONE unsigned
+    compare, ``cl - lo < hi - lo``: a key under ``lo`` wraps to a huge
+    value, an empty interval has width 0. The same values as the two
+    compares and their AND, which on the chip cost this orientation 4.6%
+    of the dense 64k backward against the parent orientation's 1.0%; the
+    one compare costs it 0.9% (PERF.md section 6, PR 58). The forward
+    keeps the two compares: its program is not this change's to move.
     """
+    lo, hi = _entry_intervals(
+        bounds, runs, sid_e, e, row0, bq, stepped, transposed
+    )
+    tile, col_axis = ((bk, bq), 0) if transposed else ((bq, bk), 1)
+    cl = col0 + jax.lax.broadcasted_iota(jnp.int32, tile, col_axis)  # local cols
+    if transposed:
+        unsigned = lambda x: jax.lax.bitcast_convert_type(x, jnp.uint32)  # noqa: E731
+        return unsigned(cl - lo) < unsigned(jnp.maximum(hi - lo, 0))
+    return (cl >= lo) & (cl < hi)
+
+
+def _entry_intervals(
+    bounds, runs, sid_e, e, row0, bq, stepped: bool, transposed: bool
+):
+    """(lo, hi) of :func:`_entry_interval_mask`: the first local key a q
+    row of the entry may see and the one past the last, int32 [bq, 1]
+    columns ([1, bq] rows under ``transposed``); an empty interval
+    (``_BIG``, ``-_BIG``) on a row the entry does not reach."""
+    rows, row_axis = ((1, bq), 1) if transposed else ((bq, 1), 0)
     rbase = e * RUN_FIELDS
     ql0 = runs[rbase + 0]
     ql1 = runs[rbase + 1]
@@ -316,7 +348,7 @@ def _entry_interval_mask(
     is_causal = (typ & 1) == 1
     is_inv = (typ & 2) == 2
 
-    rl = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)  # local rows
+    rl = row0 + jax.lax.broadcasted_iota(jnp.int32, rows, row_axis)  # local rows
     row_ok = (rl >= ql0) & (rl < ql1) & (rl + qoff >= q0) & (rl + qoff < q1)
     if stepped:
         # floor(x / step) * step, also below zero (rows outside the slice)
@@ -340,8 +372,7 @@ def _entry_interval_mask(
     hi = jnp.where(is_causal, jnp.minimum(hi, causal_hi()), hi)
     lo = jnp.where(row_ok, lo, _BIG)
     hi = jnp.where(row_ok, hi, -_BIG)
-    cl = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)  # local cols
-    return (cl >= lo) & (cl < hi)
+    return lo, hi
 
 
 def _entry_mask(bounds, runs, sid_e, e, row0, col0, bq, bk):
@@ -567,8 +598,8 @@ def _per_row(x, like):
 def _fwd_update(s, v, m_scr, l_scr, acc_scr):
     """One live step of the forward's online softmax, for both forward
     bodies (per head: 2-D ``s`` (bq, bk) and ``v`` (bk, d); head-batched:
-    (HB, G*bq, bk) and (HB, bk, d)), as :func:`_bwd_p_ds` is the one copy
-    of the backward's block. ``s`` holds :data:`_MASK` off the mask.
+    (HB, G*bq, bk) and (HB, bk, d)), as :func:`_bwd_tile` is the one copy
+    of the backward's. ``s`` holds :data:`_MASK` off the mask.
 
     What a step pays per row, not per logit, is this function. On a v5e
     the older form (one-lane ``m`` and ``l`` columns, two cross-lane
@@ -594,9 +625,7 @@ def _fwd_update(s, v, m_scr, l_scr, acc_scr):
       exact; it is kept replicated in all 128 lanes of ``m_scr`` and
       used at that shape: whole-vreg loads and stores (71.2 ms), and no
       lane broadcast into the logit tile, which is taken 128 lanes at a
-      time (62.1 ms). :func:`_bwd_p_ds` takes its tiles against lse and
-      delta the same way (worth under 3% of dq and dkv at this rung, 3-4%
-      per head: the backward's excess is not in its elementwise block)."""
+      time (62.1 ms)."""
     nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -625,8 +654,7 @@ def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
     """What a q block holds once its last entry is done: (out f32
     (..., rows, d), lse (..., rows, LANES), rowmax (..., rows, LANES)), the
     two statistics replicated over lanes, which is the form the state is
-    kept in and the form the backward reads lse in (its residual).
-    What is returned to the caller crosses the boundary with rows along
+    kept in. What leaves the kernel crosses the boundary with rows along
     lanes instead, 1/128 of the bytes (:func:`_write_stats`).
     ``sinks``: the rows' sink logits, (..., rows, 1) or one scalar, or None.
 
@@ -656,11 +684,13 @@ def _fwd_finalize(m_scr, l_scr, acc_scr, sinks):
 
 
 def stats_form(block_q: int) -> str:
-    """The form in which the per-row statistics a caller reads (lse, the
-    row maximum, the lse cotangent) cross the kernels' boundary:
+    """The form in which lse and the row maximum leave the forward kernel:
     ``"compact"``, rows along lanes, 4 bytes a row, where a q block is
-    whole vregs of rows; ``"lanes"``, every row's value in all 128 lanes
-    as the kernels keep it, 512 bytes a row, for small test blocks."""
+    whole vregs of rows (the kernel turns its lane-replicated state 128
+    rows at a time, :func:`_store_rows_along_lanes`); ``"lanes"``, every
+    row's value in all 128 lanes, 512 bytes a row, for small test blocks.
+    The backward reads lse and delta compact at every block
+    (:func:`_bwd_pallas`): it turns nothing."""
     return "compact" if block_q % LANES == 0 else "lanes"
 
 
@@ -691,15 +721,11 @@ def _write_stats(lse, rowmax, refs, compact: bool):
     form: as they are, into two (heads, bq, LANES) blocks. ``compact``
     form: both with rows along lanes into ONE ``(1, 1, 2, heads, bq)``
     block (on the compact grid every blocked operand costs a table lookup
-    a step: 0.9 ms a call of 233 k steps, docs/block_sparse.md), and lse
-    once more as it is where a second block asks for it: the residual the
-    backward reads, which only the differentiated forward writes."""
-    if not compact:
+    a step: 0.9 ms a call of 233 k steps, docs/block_sparse.md)."""
+    if compact:
+        _store_rows_along_lanes(refs[0], (lse, rowmax))
+    else:
         refs[0][...], refs[1][...] = lse, rowmax
-        return
-    _store_rows_along_lanes(refs[0], (lse, rowmax))
-    for ref in refs[1:]:
-        ref[...] = lse
 
 
 def _fwd_kernel_hb(
@@ -846,13 +872,21 @@ def _rows_from_compact(x, hq: int, tqp: int):
     return tuple(x.reshape(x.shape[0], hq, tqp))
 
 
-def _fwd_pallas(
-    q, k, v, sink2d, tables, params: FlexAttnParams, residual: bool = False
-):
+def _rows_to_compact(stats, hbg: int, bq: int):
+    """n arrays [hq, tqp] -> (hq / HBG, nq, n, HBG, bq): the blocks of
+    :func:`_compact_spec` at a head block and a q block of the caller's
+    (the backward's head block may be 1 where the forward's was 8)."""
+    x = jnp.stack(stats)
+    n, hq, tqp = x.shape
+    x = x.reshape(n, hq // hbg, hbg, tqp // bq, bq)
+    return jnp.transpose(x, (1, 3, 0, 2, 4))
+
+
+def _fwd_pallas(q, k, v, sink2d, tables, params: FlexAttnParams):
     """q [hq, tqp, d]; k [hk, tkp, d]; v [hk, tkp, dv]; tables from
     fwd_tables(). Returns (out [hq, tqp, dv], lse [hq, tqp], rowmax
-    [hq, tqp], and lse replicated over lanes [hq, tqp, LANES] if
-    ``residual``: what the backward reads, else None). The value width
+    [hq, tqp]), differentiated or not: the backward's residual is that
+    lse (:func:`_bwd_pallas` makes its kernel's operand). The value width
     ``dv`` is v's own (latent attention's 128 beside keys of 192): v, out
     and the accumulator take it, q and k the key width ``d``; a step's
     ``P V`` is then ``dv`` lanes wide and its ``Q K^T`` ``d`` deep.
@@ -860,9 +894,8 @@ def _fwd_pallas(
     The two statistics leave the kernel in :func:`stats_form`'s form. In
     the ``compact`` one the kernel writes one ``(hq / HBG, nq, 2, HBG, bq)``
     array, rows along lanes (:func:`_compact_spec`), which XLA turns to
-    two [hq, tqp] on 4 bytes a row, and writes the lane-replicated lse
-    only as the residual. In the ``lanes`` one it writes both replicated
-    and XLA takes lane 0.
+    two [hq, tqp] on 4 bytes a row. In the ``lanes`` one it writes both
+    replicated and XLA takes lane 0.
 
     Row-major grid (hq/HBG, nq, steps): the q/out/lse index maps are
     static in the inner dimension; dead steps (j >= row count) clamp the K
@@ -907,18 +940,16 @@ def _fwd_pallas(
         params.grid, hq // hbg, qblk, nq, params.fwd_steps, k_head
     )
 
-    lanes_shape = jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32)
     if compact:
         stat_specs = [_compact_spec(2, hbg, bq, qmap)]
         stat_shapes = [
             jax.ShapeDtypeStruct((hq // hbg, nq, 2, hbg, bq), jnp.float32)
         ]
-        if residual:
-            stat_specs.append(pl.BlockSpec((hbg, bq, LANES), qmap))
-            stat_shapes.append(lanes_shape)
     else:
         stat_specs = [pl.BlockSpec((hbg, bq, LANES), qmap) for _ in range(2)]
-        stat_shapes = [lanes_shape] * 2
+        stat_shapes = [
+            jax.ShapeDtypeStruct((hq, tqp, LANES), jnp.float32)
+        ] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -953,10 +984,8 @@ def _fwd_pallas(
     )(qblk, kblk, sid, runs, bounds, rs, rc, q, k, v, sink2d)
     with named_scope("magi_layout"):
         if compact:
-            lse, rowmax = _rows_from_compact(stats[0], hq, tqp)
-            return out, lse, rowmax, stats[1] if residual else None
-        lse_lanes, rowmax_lanes = stats
-        return out, lse_lanes[:, :, 0], rowmax_lanes[:, :, 0], lse_lanes
+            return (out, *_rows_from_compact(stats[0], hq, tqp))
+        return (out, *(x[:, :, 0] for x in stats))
 
 
 # ---------------------------------------------------------------------------
@@ -964,68 +993,119 @@ def _fwd_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _bwd_p_ds(
-    s, lse_ref, do_ref, v_ref, delta_ref, params: FlexAttnParams, hb=None
+def _p_ds(s, dp, lse, delta, softcap: float):
+    """``p = exp(s - lse)`` and ``ds = p * (dP - delta)`` with the softcap
+    derivative and the off-mask NaN guard, element-wise on float32 values
+    of one shape up to broadcasting: the backward's numerically delicate
+    block. ``s`` keeps ``-inf`` off the mask: ``exp(-inf - lse)`` is
+    exactly 0 there."""
+    # rows no entry covers: lse = -inf, and s = -inf all along them, so
+    # any finite value here makes p exactly 0 (-inf - -inf would be nan)
+    lse = jnp.maximum(lse, jnp.float32(jnp.finfo(jnp.float32).min))
+    p = jnp.exp(s - lse)
+    ds = p * (dp - delta)
+    if softcap > 0.0:
+        ds = ds * (1.0 - (s / jnp.float32(softcap)) ** 2)
+        ds = jnp.where(jnp.isneginf(s), 0.0, ds)  # nan guard off-mask
+    return p, ds
+
+
+def _matmul_dims(nb: int, lhs_t: bool = False, rhs_t: bool = False):
+    """Dimension numbers of a matmul under ``nb`` leading batch dimensions,
+    either operand taken transposed: ``A B``, ``A^T B``, ``A B^T``."""
+    batch = tuple(range(nb))
+    return (((nb + (not lhs_t),), (nb + rhs_t,)), (batch, batch))
+
+
+def _stat_rows(stats_ref, hb, group: int):
+    """lse and delta of a step's q heads out of its compact
+    ``(1, 1, 2, HBG, bq)`` block, each as the rows the transposed tile
+    takes them in: ``(1, bq)`` per head, ``(HB, 1, G*bq)`` head-batched (a
+    kv head's ``G`` q heads side by side along the lanes, as its stacked q
+    rows are). One sublane a head is loaded; nothing crosses lanes."""
+    if hb is None:
+        return stats_ref[0, 0, 0], stats_ref[0, 0, 1]
+    return tuple(
+        jnp.stack([
+            jnp.concatenate(
+                [
+                    stats_ref[0, 0, n, h : h + 1, :]
+                    for h in range(b * group, (b + 1) * group)
+                ],
+                axis=-1,
+            )
+            for b in range(hb)
+        ])
+        for n in (0, 1)
+    )
+
+
+def _bwd_tile(
+    q_ref, k_ref, v_ref, do_ref, stats_ref, entry, params: FlexAttnParams,
+    group: int, hb,
 ):
-    """Shared core of both backward bodies (per head and head-batched,
-    each on both grids): probabilities from the stored lse and the masked
-    logits, then ``ds = p * (dP - delta)`` with the softcap derivative and
-    the off-mask NaN guard. This block is numerically delicate and MUST
-    stay in lockstep across bodies — one copy only, as :func:`_fwd_update`
-    is the forward's.
+    """The five matmuls of one live backward tile, for both bodies (per
+    head: ``hb=None``, (1, rows, .) blocks; head-batched: ``hb=HB``, the
+    q-side blocks (HBG, bq, .) stacked per kv head to (HB, G*bq, .)).
+    Returns (this tile's ``P^T dO`` (..., bk, dv), its ``dS^T Q`` (..., bk,
+    d) before the scale, and a function that gives ``scale * dS K`` as
+    (heads, bq, d)).
 
-    ``hb=None``: the per-head kernels' (1, rows, .) blocks and 2-D ``s``.
-    ``hb=HB``: the head-batched kernels' blocks, the q-side ones
-    (HBG, bq, .) stacked per kv head to (HB, G*bq, .) like ``s``.
-
-    ``lse`` and ``delta`` arrive replicated over the 128 lanes (the
-    differentiated forward writes lse so, its residual; delta is made so
-    before the kernel, :func:`_bwd_delta`) and are used at that shape, as
-    the forward uses its running maximum (:func:`_probs`): the (rows, bk) tiles ``s`` and ``dP`` are
-    taken in static, vreg-aligned slices of 128 lanes, each of the shape
-    of the two statistics, so nothing is cut to a one-lane column and
-    broadcast back over the tile, and the uncovered-row guard is one
-    ``max`` on whole vregs. ``s`` keeps ``-inf`` off the mask:
-    ``exp(-inf - lse)`` is exactly 0 there. A tile that is not a multiple
-    of a vreg's lanes (small test blocks) is its own one slice, against
-    lane 0 of each statistic as a column, as in :func:`_probs`. These are
-    the float32 operations of the plain column form on the same values
-    (``tests/test_ops/test_flex_attn.py`` keeps it as the reference, bit
-    for bit); what the chip read: PERF.md section 6, PR 31, and
-    docs/block_sparse.md."""
+    The tile is computed transposed, ``S^T = K Q^T`` and ``dP^T = V dO^T``
+    (..., bk, rows), keys down the sublanes and q rows along the lanes. A
+    head's lse and delta then are ``(1, bq)`` rows that broadcast down the
+    sublanes, which is the form they cross the boundary in (4 bytes a row,
+    :func:`_stat_rows`), ``P^T dO`` and ``dS^T Q`` are plain contractions,
+    and only ``dS K`` contracts a transposed left operand. The tile's mask
+    is built in that orientation too; ``entry``: the leading arguments of
+    :func:`_entry_interval_mask`, up to the tile's corner. One body at
+    every ``block_q``: a block under a vreg's 128 lanes (a pinned block,
+    the CPU tests') takes the same steps on part of a vreg."""
+    nb = 0 if hb is None else 1
+    bq = params.block_q
 
     def rows(ref):
         if hb is None:
             return ref[0]
-        return ref[...].reshape(hb, -1, ref.shape[2])
+        return ref[...].reshape(hb, group * bq, ref.shape[2])
 
-    nb = s.ndim - 2  # leading batch dims: 0 per head, 1 head-batched
-    bk = s.shape[-1]
-    width = bk if bk % LANES else LANES  # a narrow tile is its one slice
-    lse, delta = rows(lse_ref), rows(delta_ref)
-    if width != LANES:
-        lse, delta = lse[..., :1], delta[..., :1]
-    # rows no entry covers: lse = -inf, and s = -inf all along them, so
-    # any finite value here makes p exactly 0 (-inf - -inf would be nan)
-    lse = jnp.maximum(lse, jnp.float32(jnp.finfo(jnp.float32).min))
-    dp = jax.lax.dot_general(
-        rows(do_ref),
-        v_ref[0] if hb is None else v_ref[...],
-        dimension_numbers=(
-            ((nb + 1,), (nb + 1,)),
-            (tuple(range(nb)), tuple(range(nb))),
-        ),
-        preferred_element_type=jnp.float32,
-    )
-    p_c, ds_c = [], []
-    for c in range(0, bk, width):
-        p_c.append(jnp.exp(s[..., c : c + width] - lse))
-        ds_c.append(p_c[-1] * (dp[..., c : c + width] - delta))
-    p, ds = jnp.concatenate(p_c, axis=-1), jnp.concatenate(ds_c, axis=-1)
+    def cols(ref):
+        return ref[0] if hb is None else ref[...]
+
+    def matmul(a, b, **transposed):
+        return jax.lax.dot_general(
+            a, b, dimension_numbers=_matmul_dims(nb, **transposed),
+            preferred_element_type=jnp.float32,
+        )
+
+    q, do, k, v = rows(q_ref), rows(do_ref), cols(k_ref), cols(v_ref)
+    s = matmul(k, q, rhs_t=True) * jnp.float32(params.scale)
     if params.softcap > 0.0:
-        ds = ds * (1.0 - (s / jnp.float32(params.softcap)) ** 2)
-        ds = jnp.where(jnp.isneginf(s), 0.0, ds)  # nan guard off-mask
-    return p, ds
+        s = jnp.float32(params.softcap) * jnp.tanh(
+            s / jnp.float32(params.softcap)
+        )
+    mask = _entry_interval_mask(
+        *entry, bq, params.block_k, params.mask_step > 1, transposed=True
+    )
+    if hb is None:
+        s = jnp.where(mask, s, NEG_INF)
+    else:  # a kv head's G q heads lie side by side along the lanes
+        s = jnp.concatenate(
+            [
+                jnp.where(mask[None], s[..., g * bq : (g + 1) * bq], NEG_INF)
+                for g in range(group)
+            ],
+            axis=-1,
+        )
+    lse, delta = _stat_rows(stats_ref, hb, group)
+    p, ds = _p_ds(s, matmul(v, do, rhs_t=True), lse, delta, params.softcap)
+    ds = ds.astype(q.dtype)
+
+    def dq():
+        x = jnp.float32(params.scale) * matmul(ds, k, lhs_t=True)
+        return x[None] if hb is None else x.reshape(q_ref.shape[0], bq, -1)
+
+    return matmul(p.astype(do.dtype), do), matmul(ds, q), dq
 
 
 def _dq_step(qblk, runs, e, head0, g=0, group: int = 1):
@@ -1225,8 +1305,7 @@ def _bwd_kernel(
     k_ref,
     v_ref,
     do_ref,
-    lse_ref,
-    delta_ref,
+    stats_ref,  # (1, 1, 2, 1, bq): lse, delta (:func:`_bwd_pallas`)
     dk_ref,
     dv_ref,
     dq_out,  # [hq, tqp, d] in the inputs' dtype, in HBM
@@ -1247,7 +1326,7 @@ def _bwd_kernel(
     k block while Q/dO/lse/delta stream through the entry lookups, and
     ``scale * dS K`` is added to the tile's q rows of dq in HBM
     (:func:`_dq_accumulate`): S, the mask, P, dP and dS are computed once
-    a tile."""
+    a tile (:func:`_bwd_tile`)."""
     bq, bk = params.block_q, params.block_k
     w = _Walk(params.grid, kblk, rs, rc, inner=True)
     i, e, g = w.i, w.e, w.g
@@ -1260,38 +1339,13 @@ def _bwd_kernel(
 
     @w.when_live
     def _compute():
-        s = _scores(q_ref[0], k_ref[0], params.scale, params.softcap)
-        s = jnp.where(
-            _entry_interval_mask(
-                bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk,
-                params.mask_step > 1,
-            ),
-            s,
-            NEG_INF,
+        dv, dk, dq = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, stats_ref,
+            (bounds, runs, sid[e], e, qblk[e] * bq, i * bk), params, group,
+            None,
         )
-        p, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype),
-            do_ref[0],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = ds.astype(q_ref.dtype)
-        dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds,
-            q_ref[0],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-        def dq():
-            return jnp.float32(params.scale) * jax.lax.dot_general(
-                ds,
-                k_ref[0],
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )[None]
-
+        dv_scr[...] += dv
+        dk_scr[...] += jnp.float32(params.scale) * dk
         _dq_accumulate(
             dq_acc, dq_out, dq_buf, dq_stage, dq_sem, dq_st, dq, bq=bq,
             d=k_ref.shape[2],
@@ -1316,8 +1370,7 @@ def _bwd_kernel_hb(
     k_ref,  # (HB, bk, d)
     v_ref,
     do_ref,  # (HBG, bq, d)
-    lse_ref,  # (HBG, bq, LANES)
-    delta_ref,
+    stats_ref,  # (1, 1, 2, HBG, bq): lse, delta (:func:`_bwd_pallas`)
     dk_ref,  # (HB, bk, d)
     dv_ref,
     dq_out,  # [hq, tqp, d] in the inputs' dtype, in HBM
@@ -1352,36 +1405,13 @@ def _bwd_kernel_hb(
 
     @w.when_live
     def _compute():
-        s = _scores_hb(q_ref, k_ref, params, group)
-        mask = _entry_interval_mask(
-            bounds, runs, sid[e], e, qblk[e] * bq, i * bk, bq, bk,
-            params.mask_step > 1,
+        dv, dk, dq = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, stats_ref,
+            (bounds, runs, sid[e], e, qblk[e] * bq, i * bk), params, group,
+            hb,
         )
-        s = _mask_hb(s, mask, group)
-        p, ds = _bwd_p_ds(s, lse_ref, do_ref, v_ref, delta_ref, params, hb)
-        rows_t = (((1,), (1,)), ((0,), (0,)))  # contract the stacked rows
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype),
-            do_ref[...].reshape(hb, group * bq, do_ref.shape[2]),
-            dimension_numbers=rows_t,
-            preferred_element_type=jnp.float32,
-        )
-        ds = ds.astype(q_ref.dtype)
-        dk_scr[...] += jnp.float32(params.scale) * jax.lax.dot_general(
-            ds,
-            q_ref[...].reshape(hb, group * bq, q_ref.shape[2]),
-            dimension_numbers=rows_t,
-            preferred_element_type=jnp.float32,
-        )
-
-        def dq():
-            return jnp.float32(params.scale) * jax.lax.dot_general(
-                ds,
-                k_ref[...],
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            ).reshape(hbg, bq, -1)
-
+        dv_scr[...] += dv
+        dk_scr[...] += jnp.float32(params.scale) * dk
         _dq_accumulate(
             dq_acc, dq_out, dq_buf, dq_stage, dq_sem, dq_st, dq, bq=bq,
             d=k_ref.shape[2],
@@ -1413,7 +1443,13 @@ def dq_form(params: FlexAttnParams, q_block, num_q_blocks: int) -> str:
 
 def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     """(dq, dk, dv in their inputs' dtype) from one kernel over the k-major
-    table. dq is two buffers ``[hq, tqp, d]`` in HBM (``memory_space=ANY``)
+    table. ``lse`` and ``delta`` are [hq, tqp] float32 and cross the
+    boundary as ONE operand ``(hq / HBG, nq, 2, HBG, bq)``, lse at index 0
+    and delta at 1, rows along lanes at this kernel's own head block
+    (:func:`_bwd_head_block` may give 1 where the forward had 8), 4 bytes
+    a row each, which the step uses as it lies (:func:`_bwd_tile`), at
+    every ``block_q``: the block's last two dimensions are the array's.
+    dq is two buffers ``[hq, tqp, d]`` in HBM (``memory_space=ANY``)
     that the steps move by their own DMAs (:func:`_dq_accumulate`): the
     float32 sums, scratch of the walk (written on a q block's visits but
     the last, read on all but the first, dropped here; aliased to an
@@ -1437,9 +1473,12 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
     nk = tkp // bk
     rs, rc = _row_tables(kblk, nk)
 
+    kernel = functools.partial(
+        _bwd_kernel_hb if hbg > 1 else _bwd_kernel, params=params,
+        group=group,
+    )
     if hbg > 1:
         hb = hbg // group
-        body = functools.partial(_bwd_kernel_hb, params=params, group=group)
         kv_scratch = [
             pltpu.VMEM((hb, bk, w), jnp.float32) for w in (d, dv)
         ]
@@ -1449,7 +1488,6 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         )
     else:
         hb = 1
-        body = functools.partial(_bwd_kernel, params=params, group=group)
         kv_scratch = [pltpu.VMEM((bk, w), jnp.float32) for w in (d, dv)]
         grid, kmap, qmap, semantics = _walk_grid(
             params.grid, hk, kblk, nk, params.bwd_steps,
@@ -1457,7 +1495,9 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         )
     dq_shape = (hq, tqp, -(-d // LANES) * LANES)
     form = dq_form(params, qblk, tqp // bq)
-    operands = [kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do, lse, delta]
+    operands = [kblk, qblk, sid, runs, bounds, rs, rc, q, k, v, do]
+    with named_scope("magi_layout"):
+        operands.append(_rows_to_compact((lse, delta), hbg, bq))
     n_blocked = len(operands)
     # Operands in HBM that only give two outputs their buffers (aliased,
     # never read through these refs: the body is not handed them). The
@@ -1484,7 +1524,7 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         # it the program holds the float32 sums, the result and every
         # operand at once, one dq in the inputs' dtype more than before
         aliases[10] = 2  # (the seven tables, q, k, v, then dO)
-    kernel, n_fills = body, len(operands) - n_blocked
+    n_fills = len(operands) - n_blocked
     body = lambda *refs: kernel(*refs[:n_blocked], *refs[n_blocked + n_fills :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -1494,8 +1534,7 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
             pl.BlockSpec((hb, bk, d), kmap),
             pl.BlockSpec((hb, bk, dv), kmap),
             pl.BlockSpec((hbg, bq, dv), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
-            pl.BlockSpec((hbg, bq, LANES), qmap),
+            _compact_spec(2, hbg, bq, qmap),
             *[pl.BlockSpec(memory_space=pl.ANY)] * n_fills,
         ],
         out_specs=[
@@ -1517,7 +1556,10 @@ def _bwd_pallas(q, k, v, do, lse, delta, tables, params: FlexAttnParams):
         hbg,
         params.grid,
         body,
-        form={"delta": "xla", "dq": form, **_value_width_label(d, dv)},
+        form={
+            "stats": "compact", "delta": "xla", "dq": form,
+            **_value_width_label(d, dv),
+        },
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((hk, tkp, d), k.dtype),
@@ -1536,17 +1578,16 @@ def _bwd_delta(do, out, dlse):
     """``delta = rowsum(dO * out) - dlse`` [hq, tqp] float32 (the lse
     cotangent folds into it: with out = softmax(s) @ v and lse =
     logsumexp(s), dL/ds = p * (dP - (delta - dlse)), which is what makes
-    multi-stage lse-merging differentiable with stage-local lse), and the
-    same replicated over lanes, the form the kernel reads it in
-    (:func:`_bwd_p_ds`). On the k-major walk a q block has no first step
-    to make it in, so it is made before the kernel."""
+    multi-stage lse-merging differentiable with stage-local lse). On the
+    k-major walk a q block has no first step to make it in, so it is made
+    before the kernel."""
     with named_scope("magi_bwd_delta"):
         delta = jnp.sum(
             do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
         )
         if dlse is not None:
             delta = delta - dlse.astype(jnp.float32)
-        return delta, jnp.broadcast_to(delta[:, :, None], (*delta.shape, LANES))
+        return delta
 
 
 # ---------------------------------------------------------------------------
@@ -1560,15 +1601,13 @@ def _zero_tangents(tables):
     )
 
 
-def _fwd_dispatch(
-    q, k, v, sink2d, ftab, params: FlexAttnParams, residual: bool = False
-):
+def _fwd_dispatch(q, k, v, sink2d, ftab, params: FlexAttnParams):
     if params.grid not in GRID_KINDS:
         raise ValueError(
             f"flex-attn: params.grid={params.grid!r} must be one of "
             f"{GRID_KINDS}"
         )
-    return _fwd_pallas(q, k, v, sink2d, ftab, params, residual)
+    return _fwd_pallas(q, k, v, sink2d, ftab, params)
 
 
 # The names a kept call gives its out and its lse [hq, tqp]
@@ -1581,24 +1620,19 @@ KEPT_NAMES = ("magi_flex_out", "magi_flex_lse")
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _flex_attn_core(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
-    """(out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp]). Undifferentiated
-    (evaluation; a checkpointed layer's first forward, and where
-    ``params.kept`` its only one) no lane-replicated statistic is written
-    at all."""
-    return _fwd_dispatch(q, k, v, sink2d, ftab, params)[:3]
+    """(out [hq, tqp, d], lse [hq, tqp], rowmax [hq, tqp]): the same
+    forward kernel differentiated or not."""
+    return _fwd_dispatch(q, k, v, sink2d, ftab, params)
 
 
 def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
-    """The residual beside q, k, v and out: the lse replicated over lanes,
-    as the differentiated forward kernel writes it and the backward kernel
-    reads it; under ``params.kept`` the compact lse, 1 / 128 of that, which
-    the checkpoint round the layer saves with out by name."""
+    """The residual beside q, k, v and out: the lse [hq, tqp], 4 bytes a
+    row, which under ``params.kept`` the checkpoint round the layer saves
+    with out by name."""
     # symbolic_zeros: every argument arrives as a CustomVJPPrimal
     q, k, v, sink2d = q.value, k.value, v.value, sink2d.value
     ftab, btab = (tuple(t.value for t in tab) for tab in (ftab, btab))
-    out, lse, rowmax, lse_res = _fwd_dispatch(
-        q, k, v, sink2d, ftab, params, residual=not params.kept
-    )
+    out, lse, rowmax = _fwd_dispatch(q, k, v, sink2d, ftab, params)
     if params.kept:
         from .. import telemetry
 
@@ -1606,27 +1640,11 @@ def _flex_attn_core_fwd(q, k, v, sink2d, ftab, btab, params: FlexAttnParams):
         # the primal outputs are the named arrays too: what reads out
         # after the call reads the saved one in the recomputation
         out, lse = map(checkpoint_name, (out, lse), KEPT_NAMES)
-        lse_res = lse
-    return (out, lse, rowmax), (
-        q,
-        k,
-        v,
-        sink2d,
-        out,
-        lse_res,
-        ftab,
-        btab,
-    )
+    return (out, lse, rowmax), (q, k, v, sink2d, out, lse, ftab, btab)
 
 
 def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
-    q, k, v, sink2d, out, lse_res, ftab, btab = residuals
-    if params.kept:  # the compact lse: the kernel's operand is made here
-        lse = lse_res
-        with named_scope("magi_layout"):
-            lse_lanes = jnp.broadcast_to(lse[:, :, None], (*lse.shape, LANES))
-    else:
-        lse_lanes = lse_res
+    q, k, v, sink2d, out, lse, ftab, btab = residuals
     # The lse cotangent is first-class (it folds into delta, _bwd_delta);
     # a model that never reads lse hands a symbolic zero, and then nothing
     # is subtracted. rowmax stays non-diff.
@@ -1638,13 +1656,11 @@ def _flex_attn_core_bwd(params: FlexAttnParams, residuals, grads):
             do = dout.astype(q.dtype)
     if isinstance(dlse, SymbolicZero):
         dlse = None
-    delta, delta_lanes = _bwd_delta(do, out, dlse)
-    dq, dk, dv = _bwd_pallas(q, k, v, do, lse_lanes, delta_lanes, btab, params)
+    delta = _bwd_delta(do, out, dlse)
+    dq, dk, dv = _bwd_pallas(q, k, v, do, lse, delta, btab, params)
     with named_scope("magi_bwd_delta"):
         if params.has_sink:
             # dL/dsink_h = -sum_q exp(sink_h - lse_hq) * delta_eff_hq
-            if not params.kept:
-                lse = lse_lanes[:, :, 0]
             sink = sink2d[:, :1]
             w = jnp.where(lse == NEG_INF, 0.0, jnp.exp(sink - lse))
             dsink = -(w * delta).sum(axis=1, keepdims=True)
@@ -1887,7 +1903,7 @@ def flex_attn_headmajor(
         # a shard_map evaluated eagerly (no jit round it, nothing to
         # differentiate) runs a custom_vjp's primal and drops its rules,
         # but jax 0.9 refuses one registered with symbolic zeros there
-        return _fwd_dispatch(q, k, v, sink2d, ftab, params)[:3]
+        return _fwd_dispatch(q, k, v, sink2d, ftab, params)
 
 
 def flex_attn_with_meta(

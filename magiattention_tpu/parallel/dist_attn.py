@@ -970,9 +970,10 @@ def _choose_grid(params: FlexAttnParams, tabs) -> str:
     telemetry.annotate_span(
         rung=(params.block_q, params.block_k, params.head_block),
         grid=grid,
-        # the form the kernels' side operands cross their boundary in
-        # (the build counter's labels of the same names), and the form of
-        # the backward: one k-major kernel, delta made before it
+        # the form lse and the row maximum leave the forward kernel in
+        # (the backward reads its statistics compact at every block), and
+        # the form of the backward: one k-major kernel, delta made before
+        # it (the build counter's labels of the same names)
         stats=stats_form(params.block_q),
         delta="xla",
         bwd_form=BWD_FORM,

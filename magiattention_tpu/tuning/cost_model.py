@@ -144,9 +144,6 @@ KERNEL_FLOP_WEIGHTS = {"fwd": 1.0, "dq": 1.5, "dkv": 2.0, "bwd": 2.5}
 # HBM-bound rungs are the same either way (a k-major step's bytes follow
 # ``block_q`` x heads in ``dkv`` and in ``bwd`` alike).
 RANKED_KERNELS = ("fwd", "dq", "dkv")
-# lse (the forward's residual) and delta reach the k-major backward
-# replicated over a vreg's lanes
-_STAT_LANES = 128
 
 
 def step_bytes(
@@ -165,10 +162,10 @@ def step_bytes(
     the key-value heads its ``head_block`` query heads share (a per-head
     step, ``head_block`` 1, brings one pair whatever the group). The
     backward walks k-major: a step brings q, dO and the two float32
-    lane-replicated statistics (lse, delta) of its query heads (``dkv``),
-    and ``bwd`` also reads and writes their float32 dq tile: at head_dim
-    128 in bf16 2,560 bytes a row and head, 0.5 x ``block_k`` FLOPs a byte
-    whatever the GQA group.
+    statistics (lse, delta) of its query heads with rows along lanes, 8
+    bytes a row and head (``dkv``), and ``bwd`` also reads and writes
+    their float32 dq tile: at head_dim 128 in bf16 1,544 bytes a row and
+    head, 0.83 x ``block_k`` FLOPs a byte whatever the GQA group.
 
     Not counted: the tiles that stay while a block's entries run (q in the
     forward; K, V, dk and dv in the backward). Each is brought or written
@@ -177,7 +174,7 @@ def step_bytes(
     of V and dO where it is not that of K and q (``head_dim``)."""
     dv = head_dim if v_head_dim is None else v_head_dim
     if kernel in ("dkv", "bwd"):
-        row = (head_dim + dv) * itemsize + 2 * _STAT_LANES * 4
+        row = (head_dim + dv) * itemsize + 2 * 4
         if kernel == "bwd":
             row += 2 * head_dim * 4  # the float32 dq tile, in and out
         return head_block * block_q * row

@@ -24,11 +24,15 @@ from .aot import _GROUP_ONE, _as_on_the_chip, _compile, _on, topo  # noqa: F401
 
 
 # the build counter's labels at the cells' blockings (block_q a multiple of
-# 128): the statistics cross the forward's boundary with rows along lanes
-# (ISSUE 40), delta is made before the one backward kernel (ISSUE 43), and
-# every q block of these masks has a key, so dq is written by its blocks'
-# last visits and nothing is zero-filled (ISSUE 44)
-_FORMS = {"fwd": {"stats": "compact"}, "bwd": {"delta": "xla", "dq": "visits"}}
+# 128): the statistics cross both kernels' boundaries with rows along lanes
+# (ISSUE 40 the forward's, ISSUE 58 the backward's), delta is made before
+# the one backward kernel (ISSUE 43), and every q block of these masks has a
+# key, so dq is written by its blocks' last visits and nothing is
+# zero-filled (ISSUE 44)
+_FORMS = {
+    "fwd": {"stats": "compact"},
+    "bwd": {"stats": "compact", "delta": "xla", "dq": "visits"},
+}
 
 
 def _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid, softcap=0.0) -> str:
@@ -119,7 +123,8 @@ def test_zero_filled_dq_where_the_table_leaves_q_blocks_out(
         text = _compile_fwd_bwd(chip, mask, t, hq, hk, d, rung, grid)
         assert reg.counter_value(
             "magi_flex_kernel_build_total", kernel="bwd", grid=grid,
-            heads_per_step=rung[2], delta="xla", dq="zero_filled",
+            heads_per_step=rung[2], stats="compact", delta="xla",
+            dq="zero_filled",
         ) == 1
     finally:
         reg.clear_metric("magi_flex_kernel_build_total")

@@ -193,13 +193,12 @@ class KernelCase:
     pad: int = 0
     entry_pad: int = 8
     # record what crosses the backward kernel's boundary (``Run.seen``): the
-    # one thing that patches the kernel module, and [hq, tq, 128] float32
-    # arrays kept for the process's life, so only the tests that read it
-    # ask. A file's tests that share configurations all ask, or none
+    # one thing that patches the kernel module, so only the tests that read
+    # it ask. A file's tests that share configurations all ask, or none
     watch: bool = False
     # ``FlexAttnParams.kept``: the form a checkpointed layer's call takes
-    # (the residual is the compact lse, named with out; the backward makes
-    # the lanes). The values are the bare call's
+    # (out and the lse [hq, tq] are named for the checkpoint's policy). The
+    # values are the bare call's
     kept: str = ""
     # q and k scaled by ``amp``; ``sign`` -1 makes every logit negative
     amp: float = 1.0
@@ -230,10 +229,12 @@ class Run(NamedTuple):
     [hq, tq, d], dk [hk, tk, d], dv [hk, tk, dv] and, under a sink, dsink
     [hq], of the Pallas kernels
     and of ``_fwd_jnp``, as numpy. ``seen``: what crossed the backward
-    kernel's boundary on a case that has ``watch`` set (``lse_lanes``,
-    ``delta`` and ``delta_rows``, ``dlse`` or None, ``dq_kernel`` as the
-    launcher returned it, ``dq_form``), padded to whole blocks; else
-    empty. All read-only."""
+    kernel's boundary on a case that has ``watch`` set (``lse`` and
+    ``delta`` [hq, tqp] as the launcher was handed them, ``stats``: the
+    statistics among the kernel's own operands, one compact array or two
+    lane-replicated ones, ``dlse`` or None, ``dq_kernel`` as the launcher
+    returned it, ``dq_form``), padded to whole blocks; else empty. All
+    read-only."""
 
     got: dict
     ref: dict
@@ -358,29 +359,59 @@ def _differentiate(case: KernelCase, attn, q, k, v, sink, tables, jit: bool):
 def _watch_the_boundary(seen: dict):
     """Record what the backward kernel is handed and what it hands back
     (an eager step: the values are concrete)."""
-    bwd_delta, bwd_pallas = fa._bwd_delta, fa._bwd_pallas
+    bwd_delta, bwd_pallas, build = (
+        fa._bwd_delta, fa._bwd_pallas, fa._flex_pallas_call
+    )
 
     def delta_spy(do, out, dlse):
-        res = bwd_delta(do, out, dlse)
-        seen.update(
-            dlse=None if dlse is None else _frozen(dlse),
-            delta_rows=_frozen(res[0]), delta=_frozen(res[1]),
-        )
-        return res
+        seen.update(dlse=None if dlse is None else _frozen(dlse))
+        return bwd_delta(do, out, dlse)
 
     def pallas_spy(q, k, v, do, lse, delta, tables, params):
         res = bwd_pallas(q, k, v, do, lse, delta, tables, params)
         seen.update(
-            lse_lanes=_frozen(lse), dq_kernel=_frozen(res[0]),
+            lse=_frozen(lse), delta=_frozen(delta), dq_kernel=_frozen(res[0]),
             dq_form=fa.dq_form(params, tables[1], q.shape[1] // params.block_q),
         )
         return res
 
-    fa._bwd_delta, fa._bwd_pallas = delta_spy, pallas_spy
+    def build_spy(role, heads, grid, body, form=None, **kwargs):
+        call = build(role, heads, grid, body, form, **kwargs)
+        if role != "bwd":
+            return call
+
+        def launch(*operands):
+            # the seven tables, q, k, v, dO; then the statistics, up to
+            # the buffers in HBM that only give dq its places
+            blocked = sum(
+                s.block_shape is not None for s in kwargs["grid_spec"].in_specs
+            )
+            seen.update(stats=[_frozen(x) for x in operands[11 : 7 + blocked]])
+            return call(*operands)
+
+        return launch
+
+    fa._bwd_delta, fa._bwd_pallas, fa._flex_pallas_call = (
+        delta_spy, pallas_spy, build_spy
+    )
     try:
         yield
     finally:
-        fa._bwd_delta, fa._bwd_pallas = bwd_delta, bwd_pallas
+        fa._bwd_delta, fa._bwd_pallas, fa._flex_pallas_call = (
+            bwd_delta, bwd_pallas, build
+        )
+
+
+def kernel_stats(case: KernelCase, seen: dict):
+    """(lse, delta) [hq, tqp] as the backward kernel's own statistic
+    operand holds them at every ``block_q``: out of the one compact ``(hq /
+    HBG, nq, 2, HBG, bq)`` array, whose head block is the backward's
+    own."""
+    (x,) = seen["stats"]
+    hq, tqp = seen["lse"].shape
+    assert x.dtype == np.float32
+    assert x.shape[2:] == (2, hq // x.shape[0], case.block_q), x.shape
+    return tuple(np.asarray(r) for r in fa._rows_from_compact(x, hq, tqp))
 
 
 def _attn(case: KernelCase, params):

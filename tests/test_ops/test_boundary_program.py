@@ -2,8 +2,9 @@
 40): no XLA pass that only reformats a kernel's side operand. The forward
 makes no lane-replicated statistic at all. Forward+backward (ISSUE 43: one
 backward kernel over the k-major table) makes delta before the kernel,
-once, and nothing else: since ISSUE 44 dq's float32 buffer is neither
-zero-filled before the kernel nor rounded after it."""
+once, on 4 bytes a row, and nothing else: since ISSUE 44 dq's float32
+buffer is neither zero-filled before the kernel nor rounded after it, and
+since ISSUE 58 neither delta nor lse is replicated over lanes for it."""
 
 import jax
 import jax.numpy as jnp
@@ -81,8 +82,10 @@ def test_forward_holds_no_lane_replicated_statistic(programs):
 
 def test_forward_backward_makes_delta_once_and_nothing_of_dq_in_xla(programs):
     """ISSUE 43: the backward is one k-major kernel. A q block has no first
-    step on that walk, so delta is made before the kernel, once, in the
-    lane-replicated form the kernel reads. ISSUE 44: dq leaves the kernel
+    step on that walk, so delta is made before the kernel, once; ISSUE 58:
+    [hq, tqp], and it enters the kernel with lse as one ``(hq / HBG, nq,
+    2, HBG, bq)`` operand, rows along lanes: no [hq, tqp, 128] float32
+    array is made anywhere in the program. ISSUE 44: dq leaves the kernel
     in the inputs' dtype, written by each q block's last visit; the
     float32 buffer it is summed in is an output nobody reads, aliased to
     an operand nobody has written (``lax.empty``: no fill), the result has
@@ -100,13 +103,19 @@ def test_forward_backward_makes_delta_once_and_nothing_of_dq_in_xla(programs):
     assert dk.dtype == dv.dtype == dq.dtype == jnp.bfloat16
     assert acc.dtype == jnp.float32
     # the float32 sums' buffer comes in as an operand nobody has written
-    # (13, aliased to the fourth output; PERF.md section 6, PR 44, says why
+    # (12, aliased to the fourth output; PERF.md section 6, PR 44, says why
     # it is an operand at all); the result has no fill behind it: this
     # mask names every q block
-    assert dict(bwd.params["input_output_aliases"]) == {13: 3}
-    assert len(bwd.invars) == 7 + 6 + 1  # tables; q, k, v, dO, lse, delta
+    assert dict(bwd.params["input_output_aliases"]) == {12: 3}
+    assert len(bwd.invars) == 7 + 5 + 1  # tables; q, k, v, dO, lse | delta
+    stats = bwd.invars[11].aval
+    block_q = stats.shape[-1]
+    assert block_q % LANES == 0 and stats.dtype == jnp.float32
+    assert stats.shape == (
+        HQ // stats.shape[3], dq.shape[1] // block_q, 2, stats.shape[3], block_q
+    )
     (made,) = [
-        e for e in _outside_kernels(fwdbwd) if bwd.invars[13] in e.outvars
+        e for e in _outside_kernels(fwdbwd) if bwd.invars[12] in e.outvars
     ]
     assert made.primitive.name == "empty"  # no pass on the chip
     used = {id(v) for e in _outside_kernels(fwdbwd) for v in e.invars}
@@ -133,23 +142,17 @@ def test_forward_backward_makes_delta_once_and_nothing_of_dq_in_xla(programs):
                 and out.aval.size >= grad_sized
             ):
                 rounded.append(out.aval.shape)
-    assert to_lanes == [(HQ, dq.shape[1], LANES)]  # delta
+    assert to_lanes == []
     assert rounded == []
 
-    # the lane-replicated arrays are lse, the forward's residual, and delta
-    def makes(eqn):  # not hands on: a shard_map, the custom_vjp's call
-        if eqn.primitive.name == "broadcast_in_dim":
-            return bool(eqn.invars[0].aval.ndim)
-        return eqn.primitive.name == "pallas_call" or not list(
-            jax.core.jaxprs_in_params(eqn.params)
-        )
-
+    # nothing anywhere is a statistic replicated over lanes
     lanes = [
         e.primitive.name
         for e in _outside_kernels(fwdbwd)
         for v in e.outvars
-        if v.aval.shape[-1:] == (LANES,) and v.aval.ndim == 3 and makes(e)
+        if v.aval.shape[-1:] == (LANES,) and v.aval.ndim == 3
         # dq's padded lanes are no statistic, nor is its sums' buffer
-        and v not in (*bwd.outvars[2:], bwd.invars[13])
+        and v not in (*bwd.outvars[2:], bwd.invars[12])
+        and not list(jax.core.jaxprs_in_params(e.params))  # hands on
     ]
-    assert sorted(lanes) == ["broadcast_in_dim", "pallas_call"]
+    assert lanes == []

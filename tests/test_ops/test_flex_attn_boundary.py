@@ -4,7 +4,8 @@ A side operand crosses once, in the form its consumer wants: lse and the row
 maximum leave the forward with rows along lanes, dk / dv leave in the
 inputs' dtype. Since ISSUE 43 the backward is one k-major kernel: dq leaves
 it in the inputs' dtype too (ISSUE 44), delta is made before the kernel (a q
-block has no first step on that walk). Each against the form it replaced,
+block has no first step on that walk), and lse and delta enter it with rows
+along lanes as one operand (ISSUE 58). Each against the form it replaced,
 which stays here as the reference. Cases come from ``kernel_cases.run`` (mask
 ``edge``), which records what the backward kernel was handed; the side of a
 comparison that patches the kernel module is traced here.
@@ -18,7 +19,8 @@ import pytest
 from magiattention_tpu.testing import assert_close
 
 from .kernel_cases import (
-    KernelCase, launch_args, operands, run, trace, uncovered_rows,
+    KernelCase, kernel_stats, launch_args, operands, run, trace,
+    uncovered_rows,
 )
 
 _EDGE_T = 768
@@ -45,31 +47,36 @@ def test_compact_stats_are_lane_0_of_the_replicated_ones(
     with_sink, hq, hk, head_block, block_q, grid, monkeypatch
 ):
     """lse and the row maximum written with rows along lanes are lane 0 of
-    the lane-replicated ones bit for bit: against the residual the same
-    kernel writes for the backward, and against the ``lanes`` form of the
-    whole forward. ``-inf`` (under a sink, the sink) stands on rows no
-    entry covers; the undifferentiated forward writes no residual."""
+    the lane-replicated ones bit for bit, against the ``lanes`` form of
+    the whole forward, and ``-inf`` (under a sink, the sink) stands on rows
+    no entry covers. ISSUE 58, the backward's side of the same boundary:
+    the launcher is handed that lse and delta as [hq, tqp] and its kernel
+    reads ONE ``(hq / HBG, nq, 2, HBG, bq)`` operand that holds both bit
+    for bit, blocked at the backward's own head block; nothing replicated
+    over lanes is made for it."""
     from magiattention_tpu.ops import flex_attn as fa
 
     case = _edge(hq, hk, head_block, grid, block_q, with_sink, watch=True)
     q, k, v, sink, ftab, _btab, params = launch_args(case)
     sink2d = sink.reshape(hq, 1)
     assert fa.stats_form(block_q) == "compact"
-    got, _, seen = run(case)  # the differentiated forward: with its residual
-    out, lse, rowmax, lse_lanes = (
-        got["out"], got["lse"], got["rowmax"], seen["lse_lanes"]
-    )
-    assert jax.eval_shape(
+    got, _, seen = run(case)  # the differentiated forward
+    out, lse, rowmax = got["out"], got["lse"], got["rowmax"]
+    assert len(jax.eval_shape(
         lambda: fa._fwd_pallas(q, k, v, sink2d, ftab, params)
-    )[3] is None
+    )) == 3
+    (stats,) = seen["stats"]
+    assert stats.shape == (
+        hq // head_block, _EDGE_T // block_q, 2, head_block, block_q
+    )
+    assert seen["lse"].shape == seen["delta"].shape == (hq, _EDGE_T)
+    np.testing.assert_array_equal(seen["lse"], lse)
+    for nm, x in zip(("lse", "delta"), kernel_stats(case, seen)):
+        np.testing.assert_array_equal(x, seen[nm], err_msg=nm)
     monkeypatch.setattr(fa, "stats_form", lambda block_q: "lanes")
     old = fa._fwd_pallas(q, k, v, sink2d, ftab, params)
     assert lse.shape == rowmax.shape == (hq, _EDGE_T)
-    assert lse_lanes.shape == (hq, _EDGE_T, fa.LANES)
-    np.testing.assert_array_equal(lse, lse_lanes[:, :, 0])
-    for a, b, nm in zip(
-        (out, lse, rowmax, lse_lanes), old, ["out", "lse", "rowmax", "lse_lanes"]
-    ):
+    for a, b, nm in zip((out, lse, rowmax), old, ["out", "lse", "rowmax"]):
         np.testing.assert_array_equal(a, np.asarray(b), err_msg=nm)
     un = _EDGE_UNCOVERED
     assert np.isneginf(rowmax[:, un]).all()
@@ -128,7 +135,8 @@ def test_grads_written_in_bf16_are_the_float32_ones_cast(
         )
         # (the result in dO's place, operand 10, where nothing is filled:
         # a float32 result cannot take it, and needs no place of its own;
-        # float32 already: operand 13, the sums' own unwritten buffer)
+        # float32 already: the sums' own unwritten buffer, the first
+        # operand after the statistics)
         aliases = kwargs["input_output_aliases"]
         fills = {i for i, o in aliases.items() if i > 10 and o == 2}
         kwargs["input_output_aliases"] = {
@@ -168,32 +176,77 @@ def test_delta_is_made_before_the_kernel(
     use_lse, hq, hk, head_block, block_q, grid
 ):
     """``delta = sum(dO * out) - dlse`` is made once, before the one
-    backward kernel, and handed to it replicated over lanes: with an lse
+    backward kernel, [hq, tqp] float32, and the kernel reads it with rows
+    along lanes at every ``block_q`` (``kernel_stats``) bit for bit: with an lse
     cotangent that is a symbolic zero (nothing is subtracted) and with one
     that is not; dq, dk, dv, dsink against the jnp backend."""
-    from magiattention_tpu.ops import flex_attn as fa
-
     case = _edge(
         hq, hk, head_block, grid, block_q, use_lse=use_lse, watch=True
     )
     got, ref, seen = run(case)
     x = operands(case)
     delta = seen["delta"]
-    assert delta.shape == (hq, _EDGE_T, fa.LANES) and delta.dtype == np.float32
-    np.testing.assert_array_equal(
-        delta, np.broadcast_to(delta[..., :1], delta.shape)
-    )
-    np.testing.assert_array_equal(delta[..., 0], seen["delta_rows"])
+    assert delta.shape == (hq, _EDGE_T) and delta.dtype == np.float32
+    np.testing.assert_array_equal(kernel_stats(case, seen)[1], delta)
     want = np.sum(x["do"] * got["out"], axis=-1)
     if use_lse:
         np.testing.assert_array_equal(seen["dlse"], x["w"])
         want = want - x["w"]
     else:
         assert seen["dlse"] is None
-    assert_close(delta[..., 0], want, atol=1e-5, rtol=1e-5, msg="delta")
+    assert_close(delta, want, atol=1e-5, rtol=1e-5, msg="delta")
     if use_lse:  # rows with out = 0: delta is the cotangent alone, exactly
-        np.testing.assert_array_equal(delta[:, 500:, 0], -x["w"][:, 500:])
+        np.testing.assert_array_equal(delta[:, 500:], -x["w"][:, 500:])
     for nm in ("dq", "dk", "dv", "dsink"):
         assert np.isfinite(got[nm]).all(), nm
         assert_close(got[nm], ref[nm], atol=5e-5, rtol=5e-5, msg=nm)
+    assert not got["dq"][:, _EDGE_UNCOVERED].any()
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+@pytest.mark.parametrize("block_q", [64, 128, 256])
+@pytest.mark.parametrize("hq,hk,head_block", _EDGE_HEADS, ids=_EDGE_IDS)
+@pytest.mark.parametrize("use_lse", [False, True], ids=["zero-dlse", "dlse"])
+def test_the_transposed_tile_gives_the_plain_orientations_gradients(
+    use_lse, hq, hk, head_block, block_q, grid
+):
+    """ISSUE 58: the backward's step computes ``K Q^T`` against statistics
+    that lie with rows along lanes (``_bwd_tile``). The reference is the
+    orientation it replaced, written out in numpy on the mask the tables
+    describe: ``P = exp(Q K^T - lse)``, ``dS = P (dO V^T - delta)``, ``dq =
+    dS K``, ``dk = dS^T Q``, ``dv = P^T dO``, from the very lse and delta
+    the launcher was handed. Same mathematics, same dtypes: the two differ
+    only in which operand of a contraction is transposed, so dq, dk and dv
+    agree to float32 rounding, with an lse cotangent that is a symbolic
+    zero and with one that is not, rows no key covers (exact zeros in dq)
+    and q blocks no entry names."""
+    from magiattention_tpu.ops import flex_attn as fa
+
+    case = _edge(
+        hq, hk, head_block, grid, block_q, use_lse=use_lse, watch=True
+    )
+    got, _, seen = run(case)
+    q, k, v, _sink, ftab, _btab, params = launch_args(case)
+    tq, tk = case.tokens
+    group = hq // hk
+    do = np.zeros(q.shape, np.float32)
+    do[:, :tq] = operands(case)["do"]
+    mask = np.asarray(fa._dense_mask_from_tables(
+        ftab, q.shape[1], k.shape[1], block_q, params.block_k
+    ))
+    kf, vf = (np.repeat(np.asarray(x), group, axis=0) for x in (k, v))
+    s = np.float32(params.scale) * np.einsum("hqd,hkd->hqk", q, kf)
+    lse = np.where(np.isneginf(seen["lse"]), 0.0, seen["lse"])
+    p = np.where(mask[None], np.exp(s - lse[..., None]), np.float32(0.0))
+    ds = p * (np.einsum("hqd,hkd->hqk", do, vf) - seen["delta"][..., None])
+    per_kv = lambda x: x.reshape(hk, group, *x.shape[1:]).sum(axis=1)  # noqa: E731
+    want = dict(
+        dq=np.float32(params.scale) * np.einsum("hqk,hkd->hqd", ds, kf),
+        dk=per_kv(np.float32(params.scale) * np.einsum("hqk,hqd->hkd", ds, q)),
+        dv=per_kv(np.einsum("hqk,hqd->hkd", p, do)),
+    )
+    np.testing.assert_array_equal(seen["dq_kernel"][:, :tq], got["dq"])
+    for nm, rows in (("dq", tq), ("dk", tk), ("dv", tk)):
+        assert np.isfinite(got[nm]).all(), nm
+        assert_close(got[nm], want[nm][:, :rows], atol=2e-5, rtol=2e-5, msg=nm)
     assert not got["dq"][:, _EDGE_UNCOVERED].any()

@@ -8,11 +8,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from magiattention_tpu.ops import flex_attn as fa
 from magiattention_tpu.ops import flex_flash_attn_func
 from magiattention_tpu.testing import assert_close
 
-from .kernel_cases import MASKS, KernelCase, oracle, run, trace
+from .kernel_cases import MASKS, KernelCase, kernel_stats, oracle, run, trace
 
 TOKENS = 256
 
@@ -24,11 +23,10 @@ def test_dq_leaves_the_kernel_in_the_inputs_dtype(grid):
     is. Rows past the slice inside a named block are exact zeros, and so
     are the blocks no entry names (tokens 128 on), which come from the
     zero fill the output is aliased to: the form reads ``zero_filled``."""
-    got, _, seen = run(
-        KernelCase(
-            "short_doc", head_block=2, grid=grid, dtype="bfloat16", watch=True
-        )
+    case = KernelCase(
+        "short_doc", head_block=2, grid=grid, dtype="bfloat16", watch=True
     )
+    got, _, seen = run(case)
     dq = got["dq"]
     assert seen["dq_kernel"].dtype == dq.dtype == jnp.bfloat16
     assert seen["dq_kernel"].shape == (4, TOKENS, 32)
@@ -38,12 +36,10 @@ def test_dq_leaves_the_kernel_in_the_inputs_dtype(grid):
     )
     assert np.asarray(dq, np.float32)[:, :100].any()
     assert not np.asarray(dq, np.float32)[:, 100:].any()
-    for nm in ("lse_lanes", "delta"):  # what _bwd_p_ds reads at that shape
-        stat = seen[nm]
-        assert stat.shape == (4, TOKENS, fa.LANES) and stat.dtype == np.float32
-        np.testing.assert_array_equal(
-            stat, np.broadcast_to(stat[..., :1], stat.shape), err_msg=nm
-        )
+    # what _bwd_tile reads, rows along lanes at this block of 64 too
+    for nm, stat in zip(("lse", "delta"), kernel_stats(case, seen)):
+        assert stat.shape == (4, TOKENS) and seen[nm].dtype == np.float32
+        np.testing.assert_array_equal(stat, seen[nm], err_msg=nm)
 
 
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
@@ -71,7 +67,8 @@ def test_q_blocks_without_a_key_come_back_as_zeros(
     telemetry.set_enabled(True)
     reg = telemetry.get_registry()
     labels = dict(
-        kernel="bwd", grid=grid, heads_per_step=head_block, delta="xla"
+        kernel="bwd", grid=grid, heads_per_step=head_block, delta="xla",
+        stats="compact",  # at this block_q of 64 too: the one body
     )
     try:
         for mask, form in (("holes", "zero_filled"), ("holes_filled", "visits")):
@@ -101,8 +98,8 @@ def test_q_blocks_without_a_key_come_back_as_zeros(
 
 @pytest.mark.parametrize(
     "d,mask,aliases",
-    [(128, "causal", {13: 3, 10: 2}), (64, "causal", {13: 3}),
-     (128, "holes", {13: 3, 14: 2})],
+    [(128, "causal", {12: 3, 10: 2}), (64, "causal", {12: 3}),
+     (128, "holes", {12: 3, 13: 2})],
     ids=["result-in-dO's-place", "padded-lanes-own-buffer", "zero-fill"],
 )
 def test_where_the_result_lives(d, mask, aliases):
@@ -110,9 +107,9 @@ def test_where_the_result_lives(d, mask, aliases):
     Where the table names every q block it is aliased to dO (operand 10:
     a block's last visit is the last step to read its dO tile), unless the
     tile's lanes are padded (head_dim 64: the shapes differ); where blocks
-    are left out it is aliased to a zero fill of its own (operand 14). The
+    are left out it is aliased to a zero fill of its own (operand 13). The
     float32 sums' buffer is always aliased to an operand nobody has written
-    (13: ``lax.empty``, no fill)."""
+    (12, after the one statistic operand: ``lax.empty``, no fill)."""
     tq, _tk, qr, kr, ts = MASKS[mask]
     x = jnp.zeros((tq, 4, d), jnp.bfloat16)
 

@@ -10,7 +10,9 @@ import pytest
 
 from magiattention_tpu.testing import assert_close
 
-from .kernel_cases import KernelCase, operands, run, trace, uncovered_rows
+from .kernel_cases import (
+    KernelCase, kernel_stats, operands, run, trace, uncovered_rows,
+)
 
 # -- the forward's softmax state (ISSUE 29) ---------------------------------
 # ``_fwd_update`` keeps no -inf inside a step (a finite mask value, a lazy
@@ -34,7 +36,8 @@ def _state(head_block, grid, block_k, with_sink, softcap, d=128, **more):
     """The state mask at 4 q heads over 2 kv heads, seed 29. head_dim 128
     unless a test says otherwise, so that the tests of this file meet on
     the same points. The boundary is recorded on the eight points where
-    ``test_lse_and_delta_arrive_replicated_over_the_lanes`` reads it,
+    ``test_lse_and_delta_arrive_with_rows_along_lanes_at_a_small_block``
+    reads it,
     whichever test comes to them first."""
     watch = (block_k, softcap, d) == (128, 0.0, 128) and not more
     return KernelCase(
@@ -98,55 +101,37 @@ def test_fwd_finite_mask_value_never_meets_a_logit(sign, head_block, grid):
     )
 
 
-# -- the backward's P/dS block on whole vregs (ISSUE 31) --------------------
-# ``_bwd_p_ds`` uses lse and delta at the lane-replicated (rows, 128) shape
-# they arrive in and takes the logit tile 128 lanes at a time. The same
-# float32 operations on the same values as the column form it replaced,
-# which stays here as the reference.
+# -- the backward's P / dS block (ISSUE 31, ISSUE 58) ------------------------
+# ``_p_ds`` is the one copy of ``p = exp(s - lse)``, ``dS = p (dP - delta)``
+# with its guards, against lse and delta as ``(1, rows)`` rows that
+# broadcast down the transposed tile's sublanes. The same float32 operations
+# on the same values as the plain form, which stays here as the reference.
 
 
-def _bwd_p_ds_column(s, lse_ref, do_ref, v_ref, delta_ref, params, hb=None):
-    """The block as it was until PR 31: lane 0 of lse and of delta sliced
-    to (rows, 1) columns, the guard on the column, both broadcast over the
-    (rows, bk) tile."""
+def _p_ds_plain(s, dp, lse, delta, softcap):
+    """The block written the plain way: the ``lse == -inf`` rows guarded
+    by a select (``_p_ds``: one maximum), everything broadcast by numpy's
+    rules."""
     from magiattention_tpu.ops.flex_attn import NEG_INF
 
-    def rows(ref):
-        if hb is None:
-            return ref[0]
-        return ref[...].reshape(hb, -1, ref.shape[2])
-
-    nb = s.ndim - 2
-    lse = rows(lse_ref)[..., :1]
-    lse_safe = jnp.where(lse == NEG_INF, 0.0, lse)
-    p = jnp.exp(s - lse_safe)
-    dp = jax.lax.dot_general(
-        rows(do_ref),
-        v_ref[0] if hb is None else v_ref[...],
-        dimension_numbers=(
-            ((nb + 1,), (nb + 1,)),
-            (tuple(range(nb)), tuple(range(nb))),
-        ),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - rows(delta_ref)[..., :1])
-    if params.softcap > 0.0:
-        ds = ds * (1.0 - (s / jnp.float32(params.softcap)) ** 2)
+    p = jnp.exp(s - jnp.where(lse == NEG_INF, 0.0, lse))
+    ds = p * (dp - delta)
+    if softcap > 0.0:
+        ds = ds * (1.0 - (s / jnp.float32(softcap)) ** 2)
         ds = jnp.where(jnp.isneginf(s), 0.0, ds)
     return p, ds
 
 
-# The factors that reach ``_bwd_p_ds``, read from its arguments (s,
-# lse_ref, do_ref, v_ref, delta_ref, params, hb) and its body
-# (``ops/flex_attn.py``): ``block_k`` is ``s.shape[-1]`` (64: the narrow
-# form, one slice against lane 0 as a column; 128: one slice of 128 lanes;
-# 256: two, concatenated); ``softcap`` is the one field of ``params`` it
-# reads; ``hb`` picks the per-head or the stacked (HB, G*bq, .) layout; the
-# head_dim is the contraction of its one matmul (dP = dO V^T). Two factors
-# of the 96 cases this test ran until PR 45 cannot reach it, and went:
+# The factors that reach ``_p_ds``, read from ``_bwd_tile``
+# (``ops/flex_attn.py``): ``block_k`` is the tile's sublanes (64, 128, 256:
+# under, at and over a vreg's lanes, where the replaced ``lanes`` body took
+# three different paths); ``softcap`` is the one field of ``params`` it
+# reads; ``hb`` picks the per-head or the stacked (HB, bk, G*bq) layout; the
+# head_dim is the contraction of dP^T = V dO^T. Two factors of the 96 cases
+# this test ran until PR 45 cannot reach it, and went:
 #   grid     ``params.grid`` is read by ``_Walk`` and ``_walk_grid`` alone,
 #            which decide WHICH entries run a step; a live step hands
-#            ``_bwd_p_ds`` the same (s, refs) on either grid, and both sides
+#            ``_bwd_tile`` the same refs on either grid, and both sides
 #            of this comparison walk the same grid. (The sparse grid stays:
 #            it has no dead step to hide a difference behind.)
 #   sink     ``params.has_sink`` is read where the forward bodies finalize
@@ -162,26 +147,25 @@ def _bwd_p_ds_column(s, lse_ref, do_ref, v_ref, delta_ref, params, hb=None):
 @pytest.mark.parametrize("d", [128, 256])
 @pytest.mark.parametrize("block_k", [64, 128, 256])
 @pytest.mark.parametrize("softcap", [0.0, 8.0], ids=["nocap", "softcap"])
-def test_bwd_block_on_whole_vregs_is_the_column_form(
+def test_the_bwd_block_is_the_plain_form(
     softcap, block_k, d, head_block, monkeypatch
 ):
     """dq, dk, dv of the two backward bodies (per head and head-batched)
-    bit for bit what the column form gives: block_k 128 and 256 run the
-    128-lane slices, 64 the narrow form. The loss reads lse too
-    (``delta - dlse``), rows 64..100 and 128..192 have ``lse = -inf``:
-    their dq is exactly zero and nothing is non-finite. And all of it
-    within the oracle's tolerances."""
+    bit for bit what the plain form of the block gives, at tiles of 64,
+    128 and 256 keys. The loss reads lse too (``delta - dlse``), rows
+    64..100 and 128..192 have ``lse = -inf``: their dq is exactly zero and
+    nothing is non-finite. And all of it within the oracle's tolerances."""
     from magiattention_tpu.ops import flex_attn as fa
 
     case = _state(head_block, "sparse", block_k, False, softcap, d=d)
     got, ref, _ = run(case)
     traced = []
 
-    def column_form(*args):
+    def plain_form(*args):
         traced.append(1)
-        return _bwd_p_ds_column(*args)
+        return _p_ds_plain(*args)
 
-    monkeypatch.setattr(fa, "_bwd_p_ds", column_form)
+    monkeypatch.setattr(fa, "_p_ds", plain_form)
     old, _ = trace(case)
     assert traced  # the bodies did trace the reference, not a cached program
     grads = [nm for nm in got if nm.startswith("d")]
@@ -196,35 +180,34 @@ def test_bwd_block_on_whole_vregs_is_the_column_form(
 @pytest.mark.parametrize("grid", ["row_major", "sparse"])
 @pytest.mark.parametrize("head_block", [1, 4], ids=["per-head", "hb=4"])
 @pytest.mark.parametrize("with_sink", [False, True], ids=["nosink", "sink"])
-def test_lse_and_delta_arrive_replicated_over_the_lanes(
+def test_lse_and_delta_arrive_with_rows_along_lanes_at_a_small_block(
     with_sink, head_block, grid
 ):
-    """The contract ``_bwd_p_ds`` leans on: what the backward kernel is
-    handed as lse (the differentiated forward's residual, from either
-    forward body on either grid) and as delta (made before the kernel,
-    ``_bwd_delta``, the lse cotangent folded in) is equal in all 128
-    lanes, on covered rows and on rows no entry covers (``-inf``, or the
-    sink)."""
+    """The contract ``_bwd_tile`` leans on, at a q block under a vreg's
+    lanes (64; blocks of 128 and 256: test_flex_attn_boundary.py): the
+    launcher is handed lse (the forward's residual, from either forward
+    body on either grid, which at this block leaves its kernel replicated
+    over lanes) and delta (made before the kernel, ``_bwd_delta``, the lse
+    cotangent folded in) as [hq, tqp], and what it hands the kernel is
+    those two in ONE compact operand, bit for bit, on covered rows and on
+    rows no entry covers (``-inf``, or the sink)."""
     from magiattention_tpu.ops import flex_attn as fa
 
     case = _state(head_block, grid, 128, with_sink, 0.0)
     got, _, seen = run(case)
     sink = operands(case)["sink"]
-    for nm in ("lse_lanes", "delta"):
-        x = seen[nm]
-        assert x.shape == (4, _STATE_T, fa.LANES) and x.dtype == np.float32
-        np.testing.assert_array_equal(
-            x, np.broadcast_to(x[..., :1], x.shape), err_msg=nm
-        )
-    np.testing.assert_array_equal(seen["lse_lanes"][..., 0], got["lse"])
+    assert fa.stats_form(case.block_q) == "lanes" and len(seen["stats"]) == 1
+    for nm, x in zip(("lse", "delta"), kernel_stats(case, seen)):
+        assert seen[nm].shape == (4, _STATE_T) and x.dtype == np.float32
+        np.testing.assert_array_equal(x, seen[nm], err_msg=nm)
+    np.testing.assert_array_equal(seen["lse"], got["lse"])
     un = _STATE_UNCOVERED
     covered = np.setdiff1d(np.arange(_STATE_T), un)
-    assert np.isfinite(seen["lse_lanes"][:, covered]).all()
+    assert np.isfinite(seen["lse"][:, covered]).all()
     assert np.isfinite(seen["delta"]).all() and seen["delta"].any()
     np.testing.assert_array_equal(
-        seen["lse_lanes"][:, un],
+        seen["lse"][:, un],
         np.broadcast_to(
-            sink[:, None, None] if with_sink else -np.inf,
-            (4, un.size, fa.LANES),
+            sink[:, None] if with_sink else -np.inf, (4, un.size)
         ),
     )

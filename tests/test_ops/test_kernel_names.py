@@ -159,5 +159,43 @@ def test_build_counter_carries_heads_per_step_and_grid(
         "stats=compact}": 1,
         # (ISSUE 44: the dense causal table names every q block)
         f"{name}{{delta=xla,dq=visits,grid={grid},"
-        f"heads_per_step={bwd_heads},kernel=bwd}}": 1,
+        f"heads_per_step={bwd_heads},kernel=bwd,stats=compact}}": 1,
     }
+
+
+@pytest.mark.parametrize("grid", ["row_major", "sparse"])
+def test_build_counter_says_how_the_statistics_cross_the_backward(
+    telemetry_on, grid
+):
+    """ISSUE 58: the backward's build carries the label the forward's has.
+    On the rung the tuner itself returns for the mask (block_q a multiple
+    of 128, as every rung it can return) lse and delta enter the kernel
+    with rows along lanes, ``stats=compact``, and so they do at a 64-row
+    test block, where the forward still writes its own replicated over
+    lanes, ``stats=lanes``: the backward has the one body."""
+    from magiattention_tpu import telemetry
+
+    t, hq, hk, d = 2048, 8, 2, 64
+    q = jnp.ones((t, hq, d), jnp.float32)
+    kv = jnp.ones((t, hk, d), jnp.float32)
+    name = "magi_flex_kernel_build_total"
+
+    def stats_by_kernel():
+        got = {}
+        for key in _builds():
+            labels = dict(
+                kv.split("=") for kv in key[len(name) + 1 : -1].split(",")
+            )
+            got[labels["kernel"]] = labels["stats"]
+        return got
+
+    jax.make_jaxpr(_grad_fn(t, None, None, None, grid))(q, kv, kv)
+    (said,) = [
+        ev["args"] for ev in telemetry.get_event_buffer().events()
+        if ev["name"] == "autotune_decision"
+    ][-1:]
+    assert int(said["rung"].split("x")[0]) % 128 == 0  # the tuner's own
+    assert stats_by_kernel() == {"fwd": "compact", "bwd": "compact"}
+    telemetry.get_registry().clear_metric(name)
+    jax.make_jaxpr(_grad_fn(t, 1, 64, 64, grid))(q, kv, kv)
+    assert stats_by_kernel() == {"fwd": "lanes", "bwd": "compact"}

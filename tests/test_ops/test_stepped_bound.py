@@ -218,7 +218,9 @@ def test_a_bad_type_word_is_a_typed_error():
 @pytest.mark.parametrize("s,base,r", CASES)
 def test_the_kernels_two_masks_agree(s, base, r):
     """``_entry_interval_mask`` (the Pallas kernels') == ``_entry_mask``
-    (the jnp backends') == the definition, tile by tile."""
+    (the jnp backends') == the definition, tile by tile; and the form the
+    backward's transposed tile takes it in (ISSUE 58: keys down the
+    sublanes, one unsigned compare) is its transpose."""
     from magiattention_tpu.ops.flex_attn import (
         _entry_interval_mask, _entry_mask, bounds_mask_step,
     )
@@ -239,6 +241,8 @@ def test_the_kernels_two_masks_agree(s, base, r):
                 bq, bk)
         a = np.asarray(_entry_interval_mask(*args, stepped=s > 1))
         assert (a == np.asarray(_entry_mask(*args))).all()
+        t = _entry_interval_mask(*args, stepped=s > 1, transposed=True)
+        assert t.shape == (bk, bq) and (np.asarray(t).T == a).all()
         got[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk] |= a
     assert (got == want).all()
 
@@ -506,9 +510,12 @@ def test_step_one_traces_the_parents_kernels(program):
     character for character, so at step 1 the chip's compiler is handed
     what it was handed before steps existed. ``fwd``: the forward, still
     the program of the commit before steps (fadb98e). ``fwd_bwd``: with
-    the backward, whose golden is ISSUE 44's tree: ISSUE 43 made dq and dkv
-    one kernel and ISSUE 44 changed that kernel's dq protocol (and took two
-    XLA passes from round it), so the older texts cannot come back."""
+    the backward, whose golden is ISSUE 58's tree: ISSUE 43 made dq and dkv
+    one kernel, ISSUE 44 changed that kernel's dq protocol (and took two
+    XLA passes from round it) and ISSUE 58 its statistics' boundary and
+    the orientation of its tile, so the older texts cannot come back. That
+    the forward's golden still holds under ISSUE 58 is the point of it:
+    the differentiated forward is the forward nobody differentiates."""
     texts = _step_one_programs(program == "fwd_bwd")
     assert sum(map(len, texts)) == KERNELS[program]["chars"]
     assert hashlib.sha256("\n".join(texts).encode()).hexdigest() == (

@@ -89,7 +89,7 @@ def test_a_stage_that_names_only_some_q_blocks_fills_its_dq(cp):
     def built(form):
         return reg.counter_value(
             "magi_flex_kernel_build_total", kernel="bwd", grid=params.grid,
-            heads_per_step=1, delta="xla", dq=form,
+            heads_per_step=1, stats="compact", delta="xla", dq=form,
         )
 
     before = {form: built(form) for form in ("visits", "zero_filled")}

@@ -16,33 +16,37 @@ from magiattention_tpu.utils.cost import TPU_PEAK_SPECS
 
 from .test_grid_choice import ROOT, _build_cell, _decisions, telemetry_on  # noqa: F401
 
-# (rung, GQA group, head_dim) -> bytes a live step moves, and FLOPs a byte
+# (rung, GQA group, head_dim) -> bytes a live step moves (lse and delta cross
+# compact since ISSUE 58: 8 bytes a row and head, not 1,024)
 RUNGS = {
-    "dense 64k, chunk-causal": ((1024, 1024, 1), 8, 128, 2_621_440, 512),
+    "dense 64k, chunk-causal": ((1024, 1024, 1), 8, 128, 1_581_056),
     # cp4 packed since ISSUE 54; the others since ISSUE 56 (the pair's price)
     "cp4 packed, packed 64k, window, Trinity global, SDAR": (
-        (256, 512, 8), 8, 128, 5_242_880, 256,
+        (256, 512, 8), 8, 128, 3_162_112,
     ),
-    "Trinity sliding": ((128, 512, 8), 8, 128, 2_621_440, 256),
-    "Mistral": ((128, 512, 8), 4, 128, 2_621_440, 256),
-    "ZAYA": ((256, 512, 8), 4, 128, 5_242_880, 256),  # since ISSUE 56
-    "SmallThinker": ((256, 512, 7), 7, 128, 4_587_520, 256),  # since ISSUE 56
-    "Ouro": ((256, 512, 8), 1, 128, 5_242_880, 256),
-    "GLM": ((256, 512, 5), 1, 256, 5_242_880, 320),
-    "cp4 dense": ((512, 2048, 1), 8, 128, 1_310_720, 1024),
+    "Trinity sliding": ((128, 512, 8), 8, 128, 1_581_056),
+    "Mistral": ((128, 512, 8), 4, 128, 1_581_056),
+    "ZAYA": ((256, 512, 8), 4, 128, 3_162_112),  # since ISSUE 56
+    "SmallThinker": ((256, 512, 7), 7, 128, 2_766_848),  # since ISSUE 56
+    "Ouro": ((256, 512, 8), 1, 128, 3_162_112),
+    "GLM": ((256, 512, 5), 1, 256, 3_942_400),
+    "cp4 dense": ((512, 2048, 1), 8, 128, 790_528),
 }
 
 
 @pytest.mark.parametrize("cells", list(RUNGS))
 def test_step_bytes_of_the_fused_backward_at_the_rungs_in_use(cells):
     """A q row and head: q and dO in bf16 (4d bytes), lse and delta in
-    float32 over 128 lanes (1,024), the float32 dq tile in and out (8d):
-    2,560 bytes at head_dim 128, so 0.5 x block_k FLOPs a byte whatever
-    the GQA group (10 x rows x block_k x d FLOPs a step)."""
-    (bq, bk, hb), group, d, want, flops_a_byte = RUNGS[cells]
+    float32 with rows along lanes (8), the float32 dq tile in and out (8d):
+    1,544 bytes at head_dim 128 where the lane-replicated pair made it
+    2,560, so 0.83 x block_k FLOPs a byte for 0.5, whatever the GQA group
+    (10 x rows x block_k x d FLOPs a step)."""
+    (bq, bk, hb), group, d, want = RUNGS[cells]
     got = cost_model.step_bytes("bwd", bq, bk, hb, group, d, 2)
-    assert got == want == hb * bq * (4 * d + 1024 + 8 * d)
-    assert 10 * hb * bq * bk * d / got == flops_a_byte
+    assert got == want == hb * bq * (4 * d + 8 + 8 * d)
+    assert 10 * hb * bq * bk * d / got == pytest.approx(
+        bk * 10 * d / (12 * d + 8)
+    )
     # what it adds to the k-major step it replaced: the dq tile, both ways
     assert got - cost_model.step_bytes("dkv", bq, bk, hb, group, d, 2) == (
         hb * bq * 8 * d
@@ -151,12 +155,7 @@ def test_every_cells_plan_takes_the_fused_backward(telemetry_on, cell, monkeypat
                 / (spec.hbm_gbps * 1e9),
             )
 
-        # at heads of 64 (ISSUE 55) the two lane-replicated statistics a
-        # row are as many bytes as q and dO together and the step is the
-        # HBM's: the fused form still wins, by 14.5% where 128-wide heads
-        # give 15% and more
-        under = 0.86 if d == 64 else 0.85
-        assert step_s("bwd") < under * (step_s("dq") + step_s("dkv")), args
+        assert step_s("bwd") < 0.85 * (step_s("dq") + step_s("dkv")), args
     if cell == "sdar30b-train-16k-blockdiff":
         # the share's one reader of the flag word takes bit 0 alone: the
         # k-major word's visit bits left the cell's reading where it was

@@ -440,11 +440,11 @@ def test_per_rank_tables_keep_the_bound_and_its_verdicts(
         ("fwd", (128, 512, 8), 8, 128, 2, 262_144),  # one pair a group
         ("dq", (1024, 1024, 1), 8, 128, 2, 524_288),  # per head: one pair
         ("fwd", (512, 2048, 1), 1, 64, 4, 2 * 2048 * 64 * 4),  # float32
-        # k-major: q, dO and the two 128-lane float32 statistics
-        ("dkv", (128, 512, 5), 1, 256, 2, 5 * 128 * (1024 + 1024)),
-        ("dkv", (256, 512, 4), 1, 128, 2, 4 * 256 * (512 + 1024)),
-        ("dkv", (128, 512, 8), 8, 128, 2, 1_572_864),
-        ("dkv", (1024, 1024, 1), 8, 128, 2, 1024 * 1536),
+        # k-major: q, dO and the two float32 statistics, rows along lanes
+        ("dkv", (128, 512, 5), 1, 256, 2, 5 * 128 * (1024 + 8)),
+        ("dkv", (256, 512, 4), 1, 128, 2, 4 * 256 * (512 + 8)),
+        ("dkv", (128, 512, 8), 8, 128, 2, 532_480),
+        ("dkv", (1024, 1024, 1), 8, 128, 2, 1024 * 520),
     ],
 )
 def test_step_bytes_against_a_hand_count(kernel, rung, group, d, itemsize, want):
